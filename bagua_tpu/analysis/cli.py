@@ -46,9 +46,6 @@ def _ensure_cpu_sim() -> None:
     if "--xla_force_host_platform_device_count" not in os.environ["XLA_FLAGS"]:
         os.environ["XLA_FLAGS"] += " --xla_force_host_platform_device_count=8"
     os.environ["JAX_PLATFORMS"] = "cpu"
-    from .. import env
-
-    env.sanitize_cpu_sim_env(os.environ)
 
 
 def _default_paths() -> List[str]:

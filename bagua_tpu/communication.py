@@ -30,7 +30,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
-from .compat import shard_map
+from jax import shard_map
 
 from . import env
 from .parallel.mesh import build_mesh, get_global_mesh, hierarchical_mesh, mesh_axis_size, set_global_mesh
@@ -684,20 +684,18 @@ def init_process_group(
     JAX coordination service), after which every host sees the full device
     set and the global mesh spans all chips.
     """
+    from .compile_cache import configure_compile_cache
+
+    configure_compile_cache()
+    # the sidecar is FORKED from this process: do it before
+    # jax.distributed.initialize starts its service threads and before any
+    # backend exists — a child forked from a process that holds the chip
+    # (or a half-copied thread pool) fails or hangs
+    if env.get_rank() == 0 and env.get_bagua_service_port() > 0:
+        start_autotune_server()
     env_addr = env.get_coordinator_addr()
     if coordinator_address is not None or env_addr:
         addr = coordinator_address or env_addr
-        # CPU-simulation multiprocess runs need an explicit cross-process
-        # collectives backend on jax versions where the CPU default is
-        # "none" ("Multiprocess computations aren't implemented on the CPU
-        # backend"); gloo is the stdlib-shipped one.  TPU/GPU unaffected.
-        plat = os.environ.get("JAX_PLATFORMS", "") or str(
-            getattr(jax.config, "jax_platforms", None) or "")
-        if "cpu" in plat.lower():
-            try:
-                jax.config.update("jax_cpu_collectives_implementation", "gloo")
-            except Exception:  # pragma: no cover - option renamed/removed
-                pass
         # pass None through when env vars are unset so jax auto-detects;
         # do NOT call jax.process_count() here — it would initialize the
         # local backend and break distributed bring-up
@@ -710,8 +708,6 @@ def init_process_group(
             num_processes=num_processes,
             process_id=process_id,
         )
-    if env.get_rank() == 0 and env.get_bagua_service_port() > 0:
-        start_autotune_server()
     if mesh is None:
         mesh = build_mesh()
     set_global_mesh(mesh)

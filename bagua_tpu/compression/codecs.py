@@ -76,10 +76,9 @@ def _pallas_ok(chunk_bytes: int, platform: Optional[str] = None) -> bool:
     if chunk_bytes < _PALLAS_MIN_CHUNK_BYTES:
         return False
     if platform is None:
-        try:
-            platform = jax.devices()[0].platform
-        except Exception:  # pragma: no cover - backend not initialized
-            return False
+        # a backend that fails to initialize raises here: it must never be
+        # read as "not a TPU, take the jnp codec"
+        platform = jax.devices()[0].platform
     return platform == "tpu" and not env.is_pallas_codec_disabled()
 
 

@@ -66,7 +66,8 @@ def decompress_chunked(mn: jax.Array, mx: jax.Array, payload: jax.Array) -> jax.
     return vals.reshape(-1)
 
 
-# measured crossover (BENCH_r05 kernel-level codec profile, v5e): the fused
+# measured crossover (round-5 kernel-level codec profile, v5e, earlier code —
+# BENCH_COMM.json; not re-measured under jax 0.9): the fused
 # Pallas compress beats the XLA lowering from ~1 MiB chunks up (+9% kernel
 # time) but LOSES below (grid/dispatch overhead dominates at 128 KB chunks);
 # jnp decompress (one elementwise map, fully fused by XLA) beat the Pallas

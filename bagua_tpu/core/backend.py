@@ -26,8 +26,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
-from jax.sharding import Mesh, PartitionSpec as P
-from ..compat import shard_map
+from jax import shard_map
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import env
 from ..algorithms.base import Algorithm, AlgorithmContext
@@ -1070,6 +1070,14 @@ class BaguaTrainer:
         self._flat_layout_live = True
         ctx = self._ctx(plan)
         mesh = self.mesh
+        # everything init returns is COMMITTED to the mesh, like every
+        # step's output: jax keys its trace cache on the input arrays' mesh,
+        # so a default-device state made the second train_step trace and
+        # compile the whole program again (chip_smoke PR 22: 64 s + 64 s
+        # for BERT-Large on one v5e chip)
+        replicated = NamedSharding(mesh, P())
+        step0 = jax.jit(lambda: jnp.zeros((), jnp.int32),
+                        out_shardings=replicated)()
 
         if algo.owns_optimizer:
             opt_init = algo.init_optimizer_state
@@ -1100,7 +1108,7 @@ class BaguaTrainer:
                           check_vma=False)
             )(params)
             return TrainState(
-                jnp.zeros((), jnp.int32), p_stacked, opt_state, algo_state
+                step0, p_stacked, opt_state, algo_state
             )
 
         if algo.replicated_params and algo.sharded_opt_state:
@@ -1173,7 +1181,7 @@ class BaguaTrainer:
                                          P(self.comm_axes)),
                               check_vma=False)
                 )(params)
-                return TrainState(jnp.zeros((), jnp.int32), zparams,
+                return TrainState(step0, zparams,
                                   opt_state, algo_state)
 
             def init_fn(p):
@@ -1187,7 +1195,7 @@ class BaguaTrainer:
                           out_specs=(self._zero_opt_specs, P(self.comm_axes)),
                           check_vma=False)
             )(params)
-            return TrainState(jnp.zeros((), jnp.int32), params, opt_state, algo_state)
+            return TrainState(step0, params, opt_state, algo_state)
 
         if algo.replicated_params:
             # algo-state specs: replicated by default; the error-feedback
@@ -1200,9 +1208,11 @@ class BaguaTrainer:
                 # the flats natively — never a leaf-shaped moment in sight
                 zparams = jax.jit(
                     lambda p: {"flats": tuple(plan.flatten_tree(p)),
-                               "local": {}}
+                               "local": {}},
+                    out_shardings=replicated,
                 )(params)
-                opt_state = jax.jit(opt_init)(zparams)
+                opt_state = jax.jit(opt_init,
+                                    out_shardings=replicated)(zparams)
 
                 def init_fn(p):
                     return algo.init_state(ctx, p)
@@ -1211,9 +1221,17 @@ class BaguaTrainer:
                     shard_map(init_fn, mesh=mesh, in_specs=(P(),),
                               out_specs=aspecs, check_vma=False)
                 )(params)
-                return TrainState(jnp.zeros((), jnp.int32), zparams,
+                return TrainState(step0, zparams,
                                   opt_state, algo_state)
-            opt_state = jax.jit(opt_init)(params)
+            if self._shard_axis is None:
+                params = jax.jit(lambda p: p,
+                                 out_shardings=replicated)(params)
+                opt_state = jax.jit(opt_init,
+                                    out_shardings=replicated)(params)
+            else:
+                # tp/pp leaves take their placements from the step's
+                # in_specs at the first dispatch
+                opt_state = jax.jit(opt_init)(params)
 
             def init_fn(p):
                 return algo.init_state(ctx, p)
@@ -1235,7 +1253,7 @@ class BaguaTrainer:
                 self._opt_specs = self._tp_match_spec_tree(
                     opt_state, self._sharded_specs_by_name()
                 )
-            return TrainState(jnp.zeros((), jnp.int32), params, opt_state, algo_state)
+            return TrainState(step0, params, opt_state, algo_state)
 
         # per-rank (gossip) state: stack every leaf along a leading rank
         # axis.  Flat-resident gossip keeps the same stacked protocol over
@@ -1253,7 +1271,7 @@ class BaguaTrainer:
             shard_map(init_fn, mesh=mesh, in_specs=(P(),),
                       out_specs=(specs, specs, specs), check_vma=False)
         )(params)
-        return TrainState(jnp.zeros((), jnp.int32), p_stacked, opt_state, algo_state)
+        return TrainState(step0, p_stacked, opt_state, algo_state)
 
     # ---- gradient-health sentinel (traced helpers) -----------------------
 
@@ -1936,38 +1954,48 @@ class BaguaTrainer:
             "step_dt": round(self._step_dt, 6),
         })
 
-    def _maybe_prepare_mfu(self, state: TrainState, batch) -> None:
+    def _maybe_prepare_mfu(self, state: TrainState,
+                           batch) -> Optional[threading.Thread]:
         """Stash the current compiled step's cost-model flops for the
         cadence hook's MFU gauge.  The cost analysis is cached per
         step-cache key; a MISSING entry is harvested in a background
-        daemon thread from abstract avals captured here — jax's AOT
+        thread from abstract avals captured here — jax's AOT
         ``lower().compile()`` does not share the jit dispatch cache, so an
         inline harvest would pay a second full XLA compile on the
         train-step hot path at every new key (first step, autotune
         retunes, phase switches).  Skipped entirely when no silicon peak
-        is known — the null-with-rationale record needs no cost model."""
+        is known — the null-with-rationale record needs no cost model.
+
+        Returns the harvest thread UNSTARTED (or None): the caller starts
+        it after the dispatch, whose own compile has by then written the
+        program to the persistent compile cache — the harvest's compile of
+        the same module is then a cache hit, where starting it before the
+        dispatch compiled the whole step twice, concurrently."""
         if self._peak_flops is None:
             self._maybe_note_mfu()  # publish the rationale once
-            return
+            return None
         key = self._current_step_key
         cached = self._cost_analysis_cache.get(key)
         if cached is not None:
             self._mfu_flops = cached.get("flops")
-            return
+            return None
         # pause the gauge until THIS program's flops land: publishing the
         # previous key's flops against the new program's cadence (for the
         # whole duration of a background compile) would be wrong, not late
         self._mfu_flops = None
         if key in self._cost_analysis_pending:
-            return
+            return None
         done = threading.Event()
         self._cost_analysis_pending[key] = done
         fn = self._step_cache.get(key)
 
         def _abstract(x):
+            # the dispatched arrays' own placement, so the harvest lowers
+            # the SAME module the dispatch compiled (an uncommitted array
+            # is placed by jit, exactly as at dispatch)
             if not hasattr(x, "shape"):
                 return x
-            sharding = getattr(x, "sharding", None)
+            sharding = x.sharding if getattr(x, "committed", False) else None
             return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
         # host metadata only — live buffers are about to be donated to
@@ -1998,16 +2026,17 @@ class BaguaTrainer:
 
                 self._memory_analysis_cache[key] = \
                     compiled_memory_analysis(compiled)
-                if isinstance(analysis, (list, tuple)):
-                    analysis = analysis[0] if analysis else {}
                 self._cost_analysis_cache[key] = \
                     dict(analysis) if analysis else {}
             finally:
                 self._cost_analysis_pending.pop(key, None)
                 done.set()
 
-        threading.Thread(target=_harvest, name="bagua-obs-cost-analysis",
-                         daemon=True).start()
+        # NOT a daemon: a process that exits under a live lower()/compile()
+        # segfaults in jax's cache teardown (seen on the 4-chip host,
+        # PR 22); interpreter shutdown joins this thread first
+        return threading.Thread(target=_harvest,
+                                name="bagua-obs-cost-analysis")
 
     def _note_static_footprint(self, state: TrainState) -> None:
         """One-shot static HBM footprint of the live training state +
@@ -2127,8 +2156,9 @@ class BaguaTrainer:
                 # own execution wall either way
                 self._ledger_window_class = "state_migration"
         fn = self._get_step_fn()
+        mfu_harvest = None
         if self._obs_enabled:
-            self._maybe_prepare_mfu(state, batch)
+            mfu_harvest = self._maybe_prepare_mfu(state, batch)
             if not self._footprint_noted:
                 self._note_static_footprint(state)
         # poison accounting reads the persisted state.step BEFORE dispatch:
@@ -2137,8 +2167,14 @@ class BaguaTrainer:
         # trainer-local call counter
         self._note_traced_fault_fires(state)
         _dispatch_t0 = time.monotonic()
-        with trace_span("step/dispatch"):
-            out = fn(state, batch)
+        try:
+            with trace_span("step/dispatch"):
+                out = fn(state, batch)
+        finally:
+            if mfu_harvest is not None:
+                # also after a failed dispatch: the thread clears its
+                # pending entry, which step_cost_analysis callers wait on
+                mfu_harvest.start()
         self.note_phase_duration("dispatch",
                                  time.monotonic() - _dispatch_t0)
         if self.grad_guard != "off":
@@ -2151,11 +2187,9 @@ class BaguaTrainer:
             out = (new_state, loss)
         if self._watchdog is not None:
             # asynchronous watching: dispatch continues at full speed while
-            # the watchdog's waiter thread reads the loss back inside a
-            # watched section (a host readback — block_until_ready-family
-            # signals can return while work is still queued on tunneled
-            # transports, which would blind the watchdog to real hangs).
-            # A cross-rank deadlock pins the waiter past the timeout.
+            # the watchdog's waiter thread reads the scalar loss back
+            # inside a watched section.  A cross-rank deadlock pins the
+            # waiter past the timeout.
             self._watchdog.watch_result(
                 out[1], f"train_step[{self._step_counter}]"
             )
@@ -2400,8 +2434,6 @@ class BaguaTrainer:
         from ..obs.memory import compiled_memory_analysis
 
         self._memory_analysis_cache[key] = compiled_memory_analysis(compiled)
-        if isinstance(analysis, (list, tuple)):
-            analysis = analysis[0] if analysis else {}
         result = dict(analysis) if analysis else {}
         if not result:
             logger.warning(
@@ -2433,10 +2465,7 @@ class BaguaTrainer:
         to extract a construction's collective sequence (mesh-axis binding,
         ``cond``-branch divergence, overlap-vs-serialized multiset
         equality)."""
-        fn = self._get_step_fn()
-        if hasattr(fn, "trace"):  # jax >= 0.4.34 jit-stages API
-            return fn.trace(state, batch).jaxpr
-        return jax.make_jaxpr(lambda s, b: fn(s, b))(state, batch)
+        return self._get_step_fn().trace(state, batch).jaxpr
 
     def _make_eval_fn(self, state_specs, batch_spec):
         algo = self.algorithm

@@ -5,8 +5,10 @@ Bagua flags + env injection :360-398,578-600, elastic_launch with gang-restart
 semantics :116-129,603-628) and the legacy subprocess launcher ``launch.py``.
 
 TPU shape: one JAX process per host drives all local chips, so
-``--nproc_per_node`` defaults to 1 (it exists for CPU-simulation runs and
-hosts with multiple isolated accelerator sets).  Rendezvous is the JAX
+``--nproc_per_node`` defaults to 1 and any other value is REFUSED on a host
+with TPU chips: nothing binds a local rank to a chip, so N processes would
+each claim every chip and all but the first fail or hang (the option exists
+for CPU-simulation runs).  Rendezvous is the JAX
 coordination service (``BAGUA_COORDINATOR_ADDR`` consumed by
 ``bagua_tpu.init_process_group``) instead of a c10d store.  Elastic behavior
 is the honest XLA equivalent of torchelastic's: ANY worker failure kills the
@@ -55,6 +57,14 @@ logger = logging.getLogger("bagua_tpu.launcher")
 _STORE_RETRY_ERRORS = (
     ConnectionError, OSError, TimeoutError, _futures.TimeoutError,
 )
+
+
+def _local_tpu_chips() -> int:
+    """TPU chips attached to this host, from a PCI scan — no backend is
+    initialized (the launcher must never hold the chip its workers need)."""
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
 
 
 def parse_args(argv=None):
@@ -112,6 +122,19 @@ def parse_args(argv=None):
     p.add_argument("training_script", type=str)
     p.add_argument("training_script_args", nargs=argparse.REMAINDER)
     args = p.parse_args(argv)
+    # "tpu,cpu" still runs on the chips: only a cpu-FIRST platform list is a
+    # CPU rehearsal
+    on_cpu = args.simulate_cpu_devices or os.environ.get(
+        "JAX_PLATFORMS", "").split(",")[0].strip().lower() == "cpu"
+    chips = 0 if on_cpu or args.nproc_per_node == 1 else _local_tpu_chips()
+    if chips:
+        p.error(
+            f"--nproc_per_node {args.nproc_per_node} on a host with "
+            f"{chips} TPU chip(s): nothing binds a local rank "
+            "to a chip, so every process would claim all of them and all "
+            "but the first fail or hang.  The supported TPU shape is ONE "
+            "process per host driving all local chips (--nproc_per_node 1); "
+            "--simulate_cpu_devices N rehearses multi-process runs on CPU")
     if ":" in args.nnodes:
         lo, _, hi = args.nnodes.partition(":")
         try:
@@ -252,6 +275,10 @@ def build_env(args, local_rank: int, spec=None,
         # processes never race each other onto the same port (a lost
         # race would still only degrade to an ephemeral port)
         env["BAGUA_OBS_HTTP_PORT"] = str(http_base + 1 + local_rank)
+    # one persistent compile cache for the gang and for every gang restart
+    from ..compile_cache import CACHE_DIR_ENV, resolve_cache_dir
+
+    env[CACHE_DIR_ENV] = resolve_cache_dir()
     if args.simulate_cpu_devices:
         env["JAX_PLATFORMS"] = "cpu"
         env["JAX_PLATFORM_NAME"] = "cpu"
@@ -259,9 +286,6 @@ def build_env(args, local_rank: int, spec=None,
             env.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={args.simulate_cpu_devices}"
         )
-        from ..env import sanitize_cpu_sim_env
-
-        sanitize_cpu_sim_env(env)
     return env
 
 

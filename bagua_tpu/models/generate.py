@@ -180,7 +180,7 @@ def generate_tp(
     wedge on collectives over a device subset after full-device work ran
     in the same process.
     """
-    from ..compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..tensor import _name_of_path

@@ -142,16 +142,7 @@ def compiled_memory_analysis(compiled) -> Optional[Dict[str, int]]:
     return out
 
 
-def live_memory_stats(device=None) -> Dict[str, Any]:
-    """One poll of ``device.memory_stats()`` (the first local device by
-    default): ``{"available": True, bytes_in_use, peak_bytes_in_use,
-    bytes_limit, headroom_bytes}`` on runtimes that expose it (TPU), else
-    ``{"available": False, "rationale": ...}`` — null-with-rationale, so a
-    fleet view can show *why* a rank has no live-memory column."""
-    import jax
-
-    if device is None:
-        device = jax.local_devices()[0]
+def _device_memory_record(device) -> Dict[str, Any]:
     try:
         stats = device.memory_stats()
     except Exception as e:  # noqa: BLE001 - backend-dependent surface
@@ -164,7 +155,8 @@ def live_memory_stats(device=None) -> Dict[str, Any]:
                 "rationale": f"device {device.device_kind!r} reports no "
                              "memory_stats (cpu-sim has no HBM)"}
     record: Dict[str, Any] = {"available": True,
-                              "device_kind": device.device_kind}
+                              "device_kind": device.device_kind,
+                              "device_id": device.id}
     for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit",
                 "largest_alloc_size"):
         if key in stats:
@@ -174,3 +166,28 @@ def live_memory_stats(device=None) -> Dict[str, Any]:
     if limit is not None and peak is not None:
         record["headroom_bytes"] = int(limit - peak)
     return record
+
+
+def live_memory_stats(device=None) -> Dict[str, Any]:
+    """One poll of ``device.memory_stats()``: ``{"available": True,
+    bytes_in_use, peak_bytes_in_use, bytes_limit, headroom_bytes}`` on
+    runtimes that expose it (TPU), else ``{"available": False,
+    "rationale": ...}`` — null-with-rationale, so a fleet view can show
+    *why* a rank has no live-memory column.
+
+    Default: EVERY local device is polled and the record is the one with
+    the least headroom (``device_id`` names it, ``devices_polled`` counts
+    them) — one process drives all chips of a host, and the chip that runs
+    out first is the one the capacity gauges must show (``init`` stages
+    the whole state through chip 0, so the chips are not symmetric)."""
+    import jax
+
+    if device is not None:
+        return _device_memory_record(device)
+    records = [_device_memory_record(d) for d in jax.local_devices()]
+    unavailable = [r for r in records if not r.get("available")]
+    if unavailable:
+        return unavailable[0]
+    tightest = min(records,
+                   key=lambda r: r.get("headroom_bytes", float("inf")))
+    return {**tightest, "devices_polled": len(records)}

@@ -235,8 +235,8 @@ def trace_op_profile(run, log_dir=None, finalize=None) -> dict:
     """Like :func:`trace_memory_traffic` but returns the PER-OP kernel
     profile (:func:`parse_xplane_op_profile`) — the tool for measuring one
     kernel's on-device time and HBM traffic in isolation, where wall-clock
-    timing would measure the host dispatch round-trip instead (on tunneled
-    transports that is milliseconds against a microsecond kernel)."""
+    timing of a microsecond kernel would measure the host dispatch
+    instead."""
     import shutil
     import tempfile
 

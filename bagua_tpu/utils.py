@@ -21,20 +21,6 @@ import numpy as np
 from .define import TensorDtype
 
 
-def device_fence(tree):
-    """Force every array in ``tree`` to completion and return the tree.
-
-    ``jax.block_until_ready`` is not a reliable fence on tunneled/remote
-    device transports (it can return while work is still queued on the far
-    side), so benchmarks and sync points that must observe REAL completion
-    read one element of each leaf back to the host — a readback cannot
-    complete before the producing computation has."""
-    for leaf in jax.tree.leaves(tree):
-        if hasattr(leaf, "ravel"):
-            np.asarray(jax.device_get(leaf.ravel()[:1]))
-    return tree
-
-
 def to_bagua_datatype(dtype) -> TensorDtype:
     """jnp/np dtype -> wire datatype name (reference utils.py:205-216)."""
     d = jnp.dtype(dtype)

@@ -14,10 +14,8 @@ to disable).  Always-on is affordable because watching is asynchronous: the
 trainer hands each step's loss array to a background *waiter* thread that
 performs the reliable host readback inside a watched section — the main
 thread keeps dispatching at full speed, and a wedged collective surfaces as
-the waiter stuck past the timeout.  (``jax.Array.is_ready`` polling would be
-cheaper still, but ``block_until_ready``-family signals have been observed
-returning early on tunneled transports; an actual readback is the fence that
-cannot lie.)
+the waiter stuck past the timeout.  (The readback is one scalar per step,
+and it doubles as the value the non-finite-loss check needs on the host.)
 
 On firing, the watchdog raises the cooperative abort flag
 (:func:`bagua_tpu.communication.abort`) so control loops stop, then dumps

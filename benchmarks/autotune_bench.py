@@ -34,6 +34,8 @@ os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
 os.environ.pop("BAGUA_SERVICE_PORT", None)
 os.environ["BAGUA_OBS"] = "on"
 os.environ["BAGUA_AUTOTUNE_GOODPUT"] = "1"
+# no persistent compile cache here: recompile badput is what the
+# goodput-scored windows measure
 
 import json
 import statistics

@@ -25,20 +25,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from bagua_tpu.compat import shard_map
+from jax import shard_map
 
 
 def _bench(fn, x, iters=10, warmup=3):
-    from bagua_tpu.utils import device_fence
-
     compiled = jax.jit(fn)
     for _ in range(warmup):
         out = compiled(x)
-    device_fence(out)
+    jax.block_until_ready(out)
     t0 = time.perf_counter()
     for _ in range(iters):
         out = compiled(x)
-    device_fence(out)  # readback: block_until_ready is not a real fence
+    jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters
 
 
@@ -48,6 +46,9 @@ def main():
                     default=[1, 4, 16, 64])
     ap.add_argument("--iters", type=int, default=10)
     args = ap.parse_args()
+    from bagua_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     from bagua_tpu.communication import BaguaCommunicator, ReduceOp
     from bagua_tpu.parallel.mesh import build_mesh

@@ -240,6 +240,7 @@ def audit(n_devices, families):
 
 
 def main():
+    # no persistent compile cache here: compile seconds ARE the measurement
     ap = argparse.ArgumentParser()
     ap.add_argument("--devices", type=int, nargs="+", default=[32, 64])
     ap.add_argument("--families", nargs="+", default=[

@@ -178,6 +178,8 @@ def build_efficiency_record(raw: dict) -> dict:
 
 
 def main(argv=None) -> int:
+    # no persistent compile cache here: the ledger's `compile` class is
+    # part of the measured attribution
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     ap.add_argument("--out", default=os.path.join(REPO, "EFFICIENCY.json"))
     ap.add_argument("--steps", type=int, default=STEPS)

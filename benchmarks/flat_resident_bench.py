@@ -176,6 +176,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_FLAT.json")
     args = ap.parse_args()
+    from bagua_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run_suite(args.out)
 
 

@@ -257,6 +257,9 @@ def main():
     ap.add_argument("--out", default="BENCH_HIERARCHICAL.json")
     ap.add_argument("--trials", type=int, default=3)
     args = ap.parse_args()
+    from bagua_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run_suite(args.out, trials=args.trials)
 
 

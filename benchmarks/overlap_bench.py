@@ -253,6 +253,9 @@ def main():
     ap.add_argument("--chunk-sweep", action="store_true",
                     help="also sweep ring chunk sizes (overlap=on, accum=1)")
     args = ap.parse_args()
+    from bagua_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     run_suite(args.out, chunk_sweep=args.chunk_sweep)
 
 

@@ -258,6 +258,9 @@ def main(argv=None) -> int:
     ap.add_argument("--smoke", action="store_true",
                     help="short trace for the CI smoke stage")
     args = ap.parse_args(argv)
+    from bagua_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
 
     records = run_bench(smoke=args.smoke)
 

@@ -216,6 +216,9 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default="BENCH_STRAGGLER.json")
     args = ap.parse_args()
+    from bagua_tpu.compile_cache import configure_compile_cache
+
+    configure_compile_cache()
     import jax
 
     jax.config.update("jax_platforms", "cpu")

@@ -25,9 +25,6 @@ if os.environ.get("BAGUA_ZOO_REAL_DEVICES", "0") != "1":
     os.environ["JAX_PLATFORMS"] = "cpu"
 
 import jax  # noqa: E402
-
-if os.environ.get("BAGUA_ZOO_REAL_DEVICES", "0") != "1":
-    jax.config.update("jax_platforms", "cpu")
 import dataclasses  # noqa: E402
 
 import jax.numpy as jnp  # noqa: E402

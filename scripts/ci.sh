@@ -198,17 +198,15 @@ python -m pytest tests/ -q --ignore=tests/test_faults.py
 
 echo "=== multichip dryrun (virtual CPU mesh) ==="
 JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-python -c "import jax; jax.config.update('jax_platforms','cpu'); \
-import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun OK')"
+python -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun OK')"
 
 echo "=== deterministic loss goldens (CPU) ==="
 JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-python -c "import jax; jax.config.update('jax_platforms','cpu'); \
-import runpy, sys; sys.argv=['bench.py','--goldens']; \
-runpy.run_path('bench.py', run_name='__main__')"
+python bench.py --goldens
 
 if [[ "${RUN_TPU_BENCH:-0}" == "1" ]]; then
-  echo "=== TPU headline bench ==="
+  echo "=== chip smoke, then the TPU headline bench ==="
+  python chip_smoke.py
   python bench.py
 fi
 

@@ -39,6 +39,7 @@ MODULES = [
     "bagua_tpu.watchdog",
     "bagua_tpu.faults.inject",
     "bagua_tpu.env",
+    "bagua_tpu.compile_cache",
     "bagua_tpu.telemetry",
     "bagua_tpu.obs.spans",
     "bagua_tpu.obs.recorder",

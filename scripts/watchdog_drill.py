@@ -5,8 +5,8 @@ chip: a device program wedges inside ``trainer.train_step`` at step 4; the
 hang watchdog must fire at ``BAGUA_COMM_TIMEOUT_S``, flush queued async
 checkpoint saves, exit 3; the launcher restarts the gang; the restarted
 worker resumes from the orbax checkpoint and completes.  Writes the full
-log to ``WATCHDOG_DRILL_TPU.log`` and a verdict line to
-``WATCHDOG_DRILL_TPU.json``.
+log and a verdict line to ``chiprun_out/watchdog_drill.{log,json}`` (the
+directory a chip run brings back; not committed).
 
 Usage: python scripts/watchdog_drill.py
 """
@@ -50,7 +50,9 @@ def main():
         cmd, cwd=REPO, env=env, capture_output=True, text=True, timeout=1200
     )
     log = out.stdout + out.stderr
-    with open(os.path.join(REPO, "WATCHDOG_DRILL_TPU.log"), "w") as f:
+    out_dir = os.path.join(REPO, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
+    with open(os.path.join(out_dir, "watchdog_drill.log"), "w") as f:
         f.write(log)
     # two legitimate failure modes funnel into the same
     # abort->restart->resume chain: the watchdog's own timeout
@@ -81,7 +83,7 @@ def main():
         if k not in ("exit_code", "wall_s", "failure_mode")
     ) and out.returncode == 0
     print(json.dumps(checks, indent=1))
-    with open(os.path.join(REPO, "WATCHDOG_DRILL_TPU.json"), "w") as f:
+    with open(os.path.join(out_dir, "watchdog_drill.json"), "w") as f:
         json.dump(checks, f, indent=1)
     sys.exit(0 if checks["ok"] else 1)
 

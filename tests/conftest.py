@@ -16,6 +16,11 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
+# tests assert on compile windows (ledger `compile` class, anomaly skip,
+# autotune recompile accounting): a persistent-cache hit left by an earlier
+# run would shrink them, so the in-process suite opts out of the cache that
+# init_process_group places at <checkout>/.jax_cache
+jax.config.update("jax_enable_compilation_cache", False)
 
 import pytest  # noqa: E402
 

@@ -28,7 +28,7 @@ from bagua_tpu.analysis.jaxpr_check import (
     make_family_tracer,
     multiset,
 )
-from bagua_tpu.compat import shard_map
+from jax import shard_map
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 PKG = os.path.dirname(os.path.abspath(bagua_tpu.__file__))

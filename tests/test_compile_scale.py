@@ -71,7 +71,7 @@ def test_exchange_period_cap_is_explicit_error(monkeypatch):
     def f(x, step):
         return comm.exchange_with_peer(x, rotate_peer, step)
 
-    from bagua_tpu.compat import shard_map
+    from jax import shard_map
 
     fn = jax.jit(shard_map(
         f, mesh=mesh, in_specs=(P("dp"), P()), out_specs=P("dp"),

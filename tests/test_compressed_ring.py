@@ -40,7 +40,7 @@ from bagua_tpu.algorithms import (
     QAdamAlgorithm,
 )
 from bagua_tpu.communication import BaguaCommunicator, ReduceOp
-from bagua_tpu.compat import shard_map
+from jax import shard_map
 from bagua_tpu.compression.codecs import (
     CODECS,
     get_codec,

@@ -95,7 +95,7 @@ def test_compressed_scatter_gather_matches_numpy_sim(average):
     comm = get_backend("").global_communicator
     from jax.sharding import PartitionSpec as P
 
-    from bagua_tpu.compat import shard_map
+    from jax import shard_map
 
     fn = jax.jit(
         shard_map(

@@ -9,7 +9,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-from bagua_tpu.compat import shard_map
+from jax import shard_map
 
 from bagua_tpu.contrib import SyncBatchNorm
 from bagua_tpu.parallel.mesh import build_mesh

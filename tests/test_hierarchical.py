@@ -51,7 +51,7 @@ from bagua_tpu.communication import (
     largest_divisor_leq,
     ring_chunks_for,
 )
-from bagua_tpu.compat import shard_map
+from jax import shard_map
 from bagua_tpu.models import MLP
 from bagua_tpu.parallel.mesh import build_mesh
 

@@ -16,42 +16,17 @@ import pytest
 
 import bench
 
-# python bench.py --goldens  (8-device CPU mesh, 30 steps)
-# Goldens are TOOLCHAIN-specific as well as platform-specific:
-# jax.random changed its sampling between release lines, so the
-# fixed-seed task DATA differs across jax versions, not just reduction
-# order — keyed by jax minor version so each toolchain keeps its own
-# certified values.  The gate's claim is unchanged on every line: each
-# synchronous family is bit-deterministic on this seed/task/mesh.
-import jax
-
-_GOLDENS_BY_JAX = {
-    # jax 0.4 line (regenerated on 0.4.37).  bytegrad/qadam regenerated
-    # for ISSUE 15's one-pass allgather leg: the compressed scatter-gather
-    # now quantizes the reduced chunk against the sources' combined
-    # [mn, mx] bounds instead of recomputing min/max (provenance:
-    # bytegrad 0.907037 -> 0.907104, qadam 1.162559 -> 1.164100 on this
-    # toolchain; all other families bit-unchanged).
-    "0.4": {
-        "gradient_allreduce": 0.907066,
-        "bytegrad": 0.907104,
-        "qadam": 1.164100,
-        "decentralized": 0.858617,
-        "low_precision_decentralized": 0.822391,
-        "zero": 0.175103,
-        "zero_hierarchical": 0.175103,
-    },
-}
-# modern-jax values (the line the package primarily targets; certified by
-# earlier rounds — "existing goldens re-verified unchanged").  NOTE:
-# bytegrad/qadam predate ISSUE 15's one-pass allgather leg — regenerate
-# with `python bench.py --goldens` on that toolchain (expect a last-digits
-# shift like the 0.4 table's provenance above; every other family is
-# untouched by the change).
-_GOLDENS_MODERN = {
+# python bench.py --goldens  (8-device CPU mesh, 30 steps; jax 0.9.0).
+# Goldens are toolchain- as well as platform-specific (jax.random's sampling
+# and XLA:CPU's reduction order both feed the last digits): after a
+# toolchain change, re-pin every family from one `--goldens` run.
+# bytegrad/qadam re-pinned in PR 22 — their previous values predated ISSUE
+# 15's one-pass allgather leg (0.888740 -> 0.888764, 1.180702 -> 1.181477);
+# the other families reproduced bit-unchanged.
+GOLDENS = {
     "gradient_allreduce": 0.888789,
-    "bytegrad": 0.888740,
-    "qadam": 1.180702,
+    "bytegrad": 0.888764,
+    "qadam": 1.181477,
     "decentralized": 0.824863,
     "low_precision_decentralized": 0.764226,
     "zero": 0.210334,
@@ -60,8 +35,6 @@ _GOLDENS_MODERN = {
     # allreduce(inter) reassociation difference is below rounding)
     "zero_hierarchical": 0.210334,
 }
-_JAX_MINOR = ".".join(jax.__version__.split(".")[:2])
-GOLDENS = _GOLDENS_BY_JAX.get(_JAX_MINOR, _GOLDENS_MODERN)
 ASYNC_BOUND = 1.0  # async final loss is timing-dependent; must still converge
 
 
@@ -72,17 +45,8 @@ def final_losses():
 
 @pytest.mark.parametrize("family", sorted(GOLDENS))
 def test_family_loss_golden(final_losses, family):
-    atol = 1.5e-6
-    if GOLDENS is _GOLDENS_MODERN and family in ("bytegrad", "qadam"):
-        # these two values predate ISSUE 15's one-pass allgather leg and
-        # cannot be re-certified from the 0.4 container that change
-        # shipped on.  The measured shift there was 6.7e-5 (bytegrad) /
-        # 1.5e-3 (qadam), so a 5e-3 tolerance keeps a real regression
-        # tripwire on this line until `python bench.py --goldens`
-        # re-pins the exact values (then drop this branch).
-        atol = 5e-3
     np.testing.assert_allclose(
-        final_losses[family], GOLDENS[family], rtol=0, atol=atol
+        final_losses[family], GOLDENS[family], rtol=0, atol=1.5e-6
     )
 
 

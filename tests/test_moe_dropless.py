@@ -109,7 +109,7 @@ def test_dropless_trains():
 
 
 def test_dropless_ep_matches_single_shard_forward():
-    from bagua_tpu.compat import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from bagua_tpu.parallel.mesh import build_mesh

@@ -33,7 +33,7 @@ from bagua_tpu.algorithms import (
     ZeroOptimizerAlgorithm,
 )
 from bagua_tpu.communication import BaguaCommunicator, ReduceOp, ring_chunks_for
-from bagua_tpu.compat import shard_map
+from jax import shard_map
 from bagua_tpu.models import MLP
 from bagua_tpu.parallel.mesh import build_mesh
 

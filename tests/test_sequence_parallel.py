@@ -6,7 +6,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 from jax.sharding import PartitionSpec as P
-from bagua_tpu.compat import shard_map
+from jax import shard_map
 
 from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
 from bagua_tpu.core.backend import BaguaTrainer

@@ -13,8 +13,7 @@ The FULL failure chain in one scripted run, through the production paths:
 4. the launcher sees the nonzero exit and restarts the gang;
 5. the restarted worker resumes from the checkpoint and completes.
 
-Run on the real TPU by ``scripts/watchdog_drill.py`` (artifact:
-``WATCHDOG_DRILL_TPU.log``); the CPU twin runs in CI
+Run on the real TPU by ``scripts/watchdog_drill.py``; the CPU twin runs in CI
 (tests/test_launcher.py::test_watchdog_hang_restart_resume).  The wedge is
 a dynamic-trip-count ``fori_loop`` so the same compiled step serves both
 the normal (spin=0) and wedged paths — no recompile masks the hang.
