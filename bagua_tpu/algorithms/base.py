@@ -42,6 +42,7 @@ _CODEC_DROP_WARNED: set = set()
 _EF_STATELESS_WARNED: set = set()
 
 from ..bucket import BucketPlan
+from ..obs.spans import phase_scope
 from ..communication import BaguaCommunicator, ReduceOp
 from ..define import TensorDeclaration
 from ..tensor import NamedParam
@@ -770,13 +771,20 @@ class Algorithm:
         (DCN-dominant buckets first on hierarchical two-tier meshes under
         the overlap scheduler); results assemble in plan order."""
         flats = ctx.bucket_flats(grads)
-        flats, algo_state = self.compensate_flats(ctx, flats, algo_state)
+        with phase_scope("bagua.layout"):
+            flats, algo_state = self.compensate_flats(ctx, flats, algo_state)
         order = ctx.bucket_launch_order(getattr(self, "hierarchical", False),
                                         dcn_codec=self.wire_codec_dcn)
         reduced: List = [None] * len(flats)
         for i in order:
-            reduced[i] = self.reduce_bucket_grad(ctx, i, flats[i])
-        return self.grads_from_reduced(ctx, reduced, grads, algo_state, step)
+            # a child of the trainer's bagua.comm scope: which declared
+            # bucket a compiled collective came from (XLA's combiner keeps
+            # ONE constituent's name on a combined collective)
+            with phase_scope(f"bucket_{i}"):
+                reduced[i] = self.reduce_bucket_grad(ctx, i, flats[i])
+        with phase_scope("bagua.layout"):
+            return self.grads_from_reduced(ctx, reduced, grads, algo_state,
+                                           step)
 
     # ---- flat-resident layout hooks (supports_flat_resident families) ----
 

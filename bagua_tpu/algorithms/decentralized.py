@@ -34,6 +34,7 @@ from jax import lax
 
 from ..communication import BaguaCommunicator, ReduceOp
 from ..compression import compress_chunked, decompress_chunked
+from ..obs.spans import phase_scope
 from .base import Algorithm, AlgorithmContext
 
 
@@ -114,7 +115,10 @@ class DecentralizedAlgorithm(Algorithm):
         flats = ctx.bucket_flats(params)
 
         def do_comm(fs):
-            return [self._exchange(ctx, f, step) for f in fs]
+            # the weight exchange runs inside the trainer's bagua.optimizer
+            # scope and names itself bagua.comm (innermost bagua.* wins)
+            with phase_scope("bagua.comm"):
+                return [self._exchange(ctx, f, step) for f in fs]
 
         if self.communication_interval > 1:
             # non-communication steps must KEEP the previously tracked
@@ -225,7 +229,8 @@ class LowPrecisionDecentralizedAlgorithm(Algorithm):
             fs, st = operand
             new_fs, nl, nr, nw = [], [], [], []
             for f, l, r, w in zip(fs, st["left"], st["right"], st["self"]):
-                f2, l2, r2, w2 = self._ring_step(ctx, f, l, r, w)
+                with phase_scope("bagua.comm"):
+                    f2, l2, r2, w2 = self._ring_step(ctx, f, l, r, w)
                 new_fs.append(f2)
                 nl.append(l2)
                 nr.append(r2)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 
 from ..communication import LINK_DCN, LINK_ICI, ReduceOp
 from ..compression import compressed_scatter_gather_allreduce
+from ..obs.spans import phase_scope
 from .base import Algorithm, AlgorithmContext
 
 
@@ -142,32 +143,40 @@ class QAdamAlgorithm(Algorithm):
             and ctx.intranode.nranks() > 1
         )
         out = []
-        for f in flats:
-            if use_two_level:
-                f = ctx.tier_reduce_scatter(f, ReduceOp.AVG)
-                f = ctx.tier_allreduce(f, ReduceOp.AVG, codec=self.codec)
-                f = ctx.tier_allgather(f)
-            elif use_hier:
-                f = ctx.intranode.allreduce(f, ReduceOp.AVG)
-                # the knob's `off` escape hatch holds on the legacy leg
-                # too: full-precision inter average (tier_allreduce, so
-                # the DCN chunk knob's ring schedule survives) instead
-                # of the codec
-                if ctx.codec_for(LINK_DCN, self.codec) is None:
-                    f = ctx.tier_allreduce(f, ReduceOp.AVG)
-                else:
-                    f = compressed_scatter_gather_allreduce(
-                        ctx.internode, f, average=True)
-            elif ctx.comm.nranks() > 1:
-                if ctx.codec_for(LINK_ICI, self.codec) is None:
-                    # bucket_allreduce keeps the chunk knobs' ring
-                    # schedule on the full-precision escape hatch
-                    f = ctx.bucket_allreduce(f, ReduceOp.AVG, False)
-                else:
-                    f = compressed_scatter_gather_allreduce(
-                        ctx.comm, f, average=True)
+        for i, f in enumerate(flats):
+            # inside the trainer's bagua.optimizer scope: the momentum
+            # exchange names itself bagua.comm (innermost bagua.* wins)
+            with phase_scope(f"bagua.comm/bucket_{i}"):
+                f = self._communicate_bucket(ctx, f, use_two_level, use_hier)
             out.append(f)
         return ctx.from_bucket_flats(out, exp_avg)
+
+    def _communicate_bucket(self, ctx: AlgorithmContext, f, use_two_level,
+                            use_hier):
+        if use_two_level:
+            f = ctx.tier_reduce_scatter(f, ReduceOp.AVG)
+            f = ctx.tier_allreduce(f, ReduceOp.AVG, codec=self.codec)
+            f = ctx.tier_allgather(f)
+        elif use_hier:
+            f = ctx.intranode.allreduce(f, ReduceOp.AVG)
+            # the knob's `off` escape hatch holds on the legacy leg
+            # too: full-precision inter average (tier_allreduce, so
+            # the DCN chunk knob's ring schedule survives) instead
+            # of the codec
+            if ctx.codec_for(LINK_DCN, self.codec) is None:
+                f = ctx.tier_allreduce(f, ReduceOp.AVG)
+            else:
+                f = compressed_scatter_gather_allreduce(
+                    ctx.internode, f, average=True)
+        elif ctx.comm.nranks() > 1:
+            if ctx.codec_for(LINK_ICI, self.codec) is None:
+                # bucket_allreduce keeps the chunk knobs' ring
+                # schedule on the full-precision escape hatch
+                f = ctx.bucket_allreduce(f, ReduceOp.AVG, False)
+            else:
+                f = compressed_scatter_gather_allreduce(
+                    ctx.comm, f, average=True)
+        return f
 
     def optimizer_update(self, ctx, params, grads, opt_state: QAdamOptState, algo_state, step):
         beta1, beta2 = self.betas

@@ -47,6 +47,7 @@ import jax.numpy as jnp
 import optax
 
 from ..communication import ReduceOp
+from ..obs.spans import phase_scope
 from .base import Algorithm, AlgorithmContext
 
 
@@ -304,7 +305,12 @@ class ZeroOptimizerAlgorithm(Algorithm):
         gflats = ctx.plan.flatten_tree(grads)
         pflats = ctx.plan.flatten_tree(params)
         # grad averaging and sharding in one collective per bucket
-        gchunks = [ctx.comm.reduce_scatter(gf, ReduceOp.AVG) for gf in gflats]
+        # (collectives inside the trainer's bagua.optimizer scope name
+        # themselves bagua.comm/...: the innermost bagua.* scope wins)
+        gchunks = []
+        for i, gf in enumerate(gflats):
+            with phase_scope(f"bagua.comm/bucket_{i}"):
+                gchunks.append(ctx.comm.reduce_scatter(gf, ReduceOp.AVG))
         local_g = self._local_named(ctx, grads)
 
         if self.clip_global_norm is not None:
@@ -322,17 +328,20 @@ class ZeroOptimizerAlgorithm(Algorithm):
             ssq = sum(
                 jnp.sum(jnp.square(g.astype(jnp.float32))) for g in gchunks
             )
-            gnorm = jnp.sqrt(ctx.comm.allreduce(ssq, ReduceOp.SUM))
+            with phase_scope("bagua.comm/clip_norm"):
+                gnorm = jnp.sqrt(ctx.comm.allreduce(ssq, ReduceOp.SUM))
             scale = jnp.minimum(1.0, self.clip_global_norm / (gnorm + 1e-12))
             gchunks = [(g * scale.astype(g.dtype)) for g in gchunks]
 
         new_pflats, new_states = [], []
-        for gchunk, pf, st in zip(gchunks, pflats, opt_state["buckets"]):
+        for i, (gchunk, pf, st) in enumerate(
+                zip(gchunks, pflats, opt_state["buckets"])):
             pchunk = self._my_chunk(ctx, pf)
             updates, st = self.optimizer.update(gchunk, st, pchunk)
             pchunk = optax.apply_updates(pchunk, updates)
             # re-replicate the updated params (rank chunks in rank order)
-            new_pflats.append(ctx.comm.allgather(pchunk, tiled=True))
+            with phase_scope(f"bagua.comm/bucket_{i}"):
+                new_pflats.append(ctx.comm.allgather(pchunk, tiled=True))
             new_states.append(st)
         named = ctx.plan.unflatten_to_named(new_pflats)
 
@@ -358,7 +367,10 @@ class ZeroOptimizerAlgorithm(Algorithm):
             # inside the overlap window (grads_from_reduced)
             gchunks = list(grads["chunks"])
         else:
-            gchunks = [self._avg_scatter(ctx, gf) for gf in grads["flats"]]
+            gchunks = []
+            for i, gf in enumerate(grads["flats"]):
+                with phase_scope(f"bagua.comm/bucket_{i}"):
+                    gchunks.append(self._avg_scatter(ctx, gf))
         if self.clip_global_norm is not None:
             # chunks across the SHARD axis tile the whole flat exactly once
             # (staged: chunks are replicated over inter, so summing over
@@ -367,13 +379,14 @@ class ZeroOptimizerAlgorithm(Algorithm):
             ssq = sum(
                 jnp.sum(jnp.square(g.astype(jnp.float32))) for g in gchunks
             )
-            gnorm = jnp.sqrt(shard.allreduce(ssq, ReduceOp.SUM))
+            with phase_scope("bagua.comm/clip_norm"):
+                gnorm = jnp.sqrt(shard.allreduce(ssq, ReduceOp.SUM))
             scale = jnp.minimum(1.0, self.clip_global_norm / (gnorm + 1e-12))
             gchunks = [(g * scale.astype(g.dtype)) for g in gchunks]
 
         new_flats, new_states = [], []
-        for gchunk, pf, st in zip(gchunks, params["flats"],
-                                  opt_state["buckets"]):
+        for i, (gchunk, pf, st) in enumerate(
+                zip(gchunks, params["flats"], opt_state["buckets"])):
             pchunk = self._my_chunk(ctx, pf)
             updates, st = self.optimizer.update(gchunk, st, pchunk)
             pchunk = optax.apply_updates(pchunk, updates)
@@ -383,10 +396,11 @@ class ZeroOptimizerAlgorithm(Algorithm):
             # Both gathers are chunk-aware, so the ring pair stays
             # layout-symmetric when overlap chunking is on (the staged one
             # against the ICI tier's target).
-            new_flats.append(
-                ctx.bucket_allgather(pchunk) if shard is ctx.comm
-                else ctx.tier_allgather(pchunk)
-            )
+            with phase_scope(f"bagua.comm/bucket_{i}"):
+                new_flats.append(
+                    ctx.bucket_allgather(pchunk) if shard is ctx.comm
+                    else ctx.tier_allgather(pchunk)
+                )
             new_states.append(st)
         new_params = {"flats": tuple(new_flats), "local": params["local"]}
         return new_params, {"buckets": tuple(new_states),

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .define import TensorDeclaration, TensorDtype, DTYPE_BYTES
+from .obs.spans import phase_scope
 from .tensor import NamedParam, leaves_by_name
 from .utils import from_bagua_datatype
 
@@ -149,19 +150,25 @@ class BucketPlan:
         concatenation).  Equivalent of bucket.py:95-123 ``_flatten_``."""
         named = leaves_by_name(tree)
         flats = []
-        for b in self.buckets:
-            parts = [jnp.ravel(named[t.name]).astype(b.dtype) for t in b.tensors]
-            if b.padding:
-                parts.append(jnp.zeros((b.padding,), dtype=b.dtype))
-            flats.append(jnp.concatenate(parts) if len(parts) > 1 else parts[0])
+        # bagua.layout: what the bucket plan costs on the device besides
+        # the wire (ravel, cast, pad, concatenate; slices on the way back)
+        with phase_scope("bagua.layout"):
+            for b in self.buckets:
+                parts = [jnp.ravel(named[t.name]).astype(b.dtype)
+                         for t in b.tensors]
+                if b.padding:
+                    parts.append(jnp.zeros((b.padding,), dtype=b.dtype))
+                flats.append(jnp.concatenate(parts) if len(parts) > 1
+                             else parts[0])
         return flats
 
     def unflatten_to_named(self, flats: Sequence[jax.Array]) -> Dict[str, jax.Array]:
         named = {}
-        for b, flat in zip(self.buckets, flats):
-            for t, off in zip(b.tensors, b.offsets()):
-                seg = jax.lax.slice_in_dim(flat, off, off + t.numel)
-                named[t.name] = seg.reshape(t.shape).astype(t.dtype)
+        with phase_scope("bagua.layout"):
+            for b, flat in zip(self.buckets, flats):
+                for t, off in zip(b.tensors, b.offsets()):
+                    seg = jax.lax.slice_in_dim(flat, off, off + t.numel)
+                    named[t.name] = seg.reshape(t.shape).astype(t.dtype)
         return named
 
     def unflatten_tree(self, flats: Sequence[jax.Array], tree_like):

@@ -192,6 +192,7 @@ def compress_chunked_pallas(
                 jax.ShapeDtypeStruct((n_chunks * rows, _LANE), jnp.uint8),
             ],
             interpret=interpret,
+            name="codec_minmax_compress",
         )(xp)
     else:
         n_tiles = rows // _TILE_ROWS
@@ -209,6 +210,7 @@ def compress_chunked_pallas(
                 (n_chunks * _STATS_ROWS, _LANE), jnp.float32
             ),
             interpret=interpret,
+            name="codec_minmax_stats_tile",
         )(xp)
         payload = pl.pallas_call(
             _quantize_tile_kernel,
@@ -226,6 +228,7 @@ def compress_chunked_pallas(
             out_shape=jax.ShapeDtypeStruct((n_chunks * rows, _LANE),
                                            jnp.uint8),
             interpret=interpret,
+            name="codec_minmax_quantize_tile",
         )(stats, xp)
     payload = payload.reshape(n_chunks, padded)[:, :chunk]
     stats = stats.reshape(n_chunks, _STATS_ROWS, _LANE)
@@ -305,6 +308,7 @@ def absmax_chunked_pallas(
                 (n_chunks * _STATS_ROWS, _LANE), jnp.float32
             ),
             interpret=interpret,
+            name="codec_absmax",
         )(xp)
     else:
         n_tiles = rows // _TILE_ROWS
@@ -322,6 +326,7 @@ def absmax_chunked_pallas(
                 (n_chunks * _STATS_ROWS, _LANE), jnp.float32
             ),
             interpret=interpret,
+            name="codec_absmax_tile",
         )(xp)
     return stats.reshape(n_chunks, _STATS_ROWS, _LANE)[:, 0, 0]
 
@@ -440,6 +445,7 @@ def sign_compress_chunked_pallas(
                 jax.ShapeDtypeStruct((n_chunks * br, _LANE), jnp.uint8),
             ],
             interpret=interpret,
+            name="codec_sign_pack",
         )(xp)
         scale = stats.reshape(n_chunks, _STATS_ROWS, _LANE)[:, 0, 0]
         return scale, payload.reshape(n_chunks, br * _LANE)
@@ -463,6 +469,7 @@ def sign_compress_chunked_pallas(
             (n_chunks * _STATS_ROWS, _LANE), jnp.float32
         ),
         interpret=interpret,
+        name="codec_sumabs_tile",
     )(xp)
     scale = stats.reshape(n_chunks, _STATS_ROWS, _LANE)[:, 0, 0]
     return scale, _jnp_sign_pack(x2d)
@@ -507,6 +514,7 @@ def sign_decompress_chunked_pallas(
         out_shape=jax.ShapeDtypeStruct((n_chunks * rows, _LANE),
                                        jnp.float32),
         interpret=interpret,
+        name="codec_sign_unpack",
     )(block.reshape(n_chunks * _STATS_ROWS, _LANE), pp)
     return out.reshape(n_chunks, rows * _LANE)
 
@@ -550,5 +558,6 @@ def decompress_chunked_pallas(
         out_specs=data_spec,
         out_shape=jax.ShapeDtypeStruct((n_chunks * rows, _LANE), jnp.float32),
         interpret=interpret,
+        name="codec_minmax_decompress",
     )(block.reshape(n_chunks * _STATS_ROWS, _LANE), pp)
     return out.reshape(n_chunks, padded)[:, :chunk].reshape(-1)

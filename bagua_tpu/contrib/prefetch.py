@@ -18,6 +18,8 @@ from __future__ import annotations
 import collections
 from typing import Iterable, Iterator, Optional
 
+from ..obs.spans import trace_span
+
 __all__ = ["prefetch_to_device"]
 
 
@@ -65,11 +67,17 @@ def prefetch_to_device(
         it = iter(iterable)
 
         def fill():
+            # both halves run on the consumer's thread inside its
+            # ``next(batches)``: the spans divide that wait into the user's
+            # iterator and the program's own placement
             while len(queue) < size:
                 try:
-                    queue.append(place(next(it)))
+                    with trace_span("input/source"):
+                        batch = next(it)
                 except StopIteration:
                     return
+                with trace_span("input/place"):
+                    queue.append(place(batch))
 
         fill()
         while queue:

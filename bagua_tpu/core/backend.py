@@ -34,7 +34,7 @@ from ..algorithms.base import Algorithm, AlgorithmContext
 from ..bucket import BucketPlan, split_bucket_by_bucket_size
 from ..communication import BaguaCommunicator, ReduceOp, collapse_trivial_axes
 from ..obs import spans as _obs_spans
-from ..obs.spans import trace_span
+from ..obs.spans import phase_scope, trace_span
 from ..parallel.mesh import build_mesh, hierarchical_mesh, mesh_axis_size
 from ..tensor import build_params, _name_of_path
 from ..utils import StatisticalAverage
@@ -1390,18 +1390,28 @@ class BaguaTrainer:
             a for a in dp + ((self.seq_axis,) if self.seq_axis else ())
             if mesh.shape[a] > 1
         )
-        if self._flat_resident:
-            leaf_view = self._flat_leaf_view
+        leaf_view = self._flat_leaf_view if self._flat_resident else None
 
-            def loss_on(zp, b):
-                # flat-resident params: materialize the leaf view (slicing —
-                # XLA fuses it); autodiff w.r.t. zp scatters grads straight
-                # back into bucket-flat layout
-                return self.loss_fn(leaf_view(zp), b)
-        else:
-            loss_on = self.loss_fn
+        def loss_on(zp, b):
+            # ONE scope around the loss: JAX's own transform wrappers split
+            # it into forward (jvp(bagua.loss)), backward
+            # (transpose(jvp(bagua.loss))) and remat replay
+            # (.../rematted_computation/...) in every instruction's op_name
+            with phase_scope("bagua.loss"):
+                if leaf_view is not None:
+                    # flat-resident params: materialize the leaf view
+                    # (slicing — XLA fuses it); autodiff w.r.t. zp scatters
+                    # grads straight back into bucket-flat layout.  The
+                    # slicing names itself bagua.layout (bucket.py).
+                    zp = leaf_view(zp)
+                return self.loss_fn(zp, b)
 
-        def per_shard(state: TrainState, batch):
+        # the compiled module is named after this function (jit_bagua_step).
+        # The name is part of the persistent compile-cache key; scope
+        # metadata is NOT (JAX strips locations from the key), so a cache
+        # shared with a checkout that predates the phase scopes would hand
+        # back an executable without them under the old name
+        def bagua_step(state: TrainState, batch):
             params = state.params
             opt_state = state.opt_state
             algo_state = state.algo_state
@@ -1468,8 +1478,9 @@ class BaguaTrainer:
                 # chaos: traced NaN/Inf injection into the accumulated
                 # gradient (pre-comm, so detection sees exactly what the
                 # collectives would spread)
-                grads = self._apply_grad_poison(plan, grads, step,
-                                                poison_specs)
+                with phase_scope("bagua.guard"):
+                    grads = self._apply_grad_poison(plan, grads, step,
+                                                    poison_specs)
             health_vec = None
             if self.pp_axis is not None and mesh.shape[self.pp_axis] > 1:
                 # replicated-leaf grads are PARTIAL per pipeline stage: the
@@ -1506,44 +1517,51 @@ class BaguaTrainer:
                     order = ctx.bucket_launch_order(
                         hier, dcn_codec=algo.wire_codec_dcn
                     )
-                    # error-feedback compensation folds the residual into
-                    # the flats BEFORE the streamed collectives (identity
-                    # — zero traced ops — unless a stateful codec rides)
-                    flats, algo_state = algo.compensate_flats(
-                        ctx, list(grads["flats"]), algo_state
-                    )
-                    reduced = [None] * len(flats)
-                    for i in order:
-                        # tier estimates report COMPRESSED wire bytes when
-                        # a codec rides the tier, so the spans (and
-                        # obs/device_comm_dcn_s attribution downstream)
-                        # describe what actually crosses the wire
-                        tiers = ctx.bucket_tier_bytes(
-                            i, hier, dcn_codec=algo.wire_codec_dcn,
-                            flat_codec=algo.wire_codec_flat,
-                        )
-                        with trace_span(
-                            "trace/bucket_collective", bucket=i,
-                            bytes=tiers["bytes"], tier=tiers["tier"],
-                            ici_bytes=tiers["ici_bytes"],
-                            dcn_bytes=tiers["dcn_bytes"],
-                            dcn_codec=tiers["dcn_codec"],
-                        ):
-                            reduced[i] = algo.reduce_bucket_grad(
-                                ctx, i, flats[i]
+                    with phase_scope("bagua.comm"):
+                        # error-feedback compensation folds the residual
+                        # into the flats BEFORE the streamed collectives
+                        # (identity — zero traced ops — unless a stateful
+                        # codec rides)
+                        with phase_scope("bagua.layout"):
+                            flats, algo_state = algo.compensate_flats(
+                                ctx, list(grads["flats"]), algo_state
                             )
-                    grads, algo_state = algo.grads_from_reduced(
-                        ctx, reduced, grads, algo_state, step
-                    )
+                        reduced = [None] * len(flats)
+                        for i in order:
+                            # tier estimates report COMPRESSED wire bytes
+                            # when a codec rides the tier, so the spans
+                            # (and obs/device_comm_dcn_s attribution
+                            # downstream) describe what actually crosses
+                            # the wire
+                            tiers = ctx.bucket_tier_bytes(
+                                i, hier, dcn_codec=algo.wire_codec_dcn,
+                                flat_codec=algo.wire_codec_flat,
+                            )
+                            with phase_scope(
+                                f"bucket_{i}",
+                                "trace/bucket_collective", bucket=i,
+                                bytes=tiers["bytes"], tier=tiers["tier"],
+                                ici_bytes=tiers["ici_bytes"],
+                                dcn_bytes=tiers["dcn_bytes"],
+                                dcn_codec=tiers["dcn_codec"],
+                            ):
+                                reduced[i] = algo.reduce_bucket_grad(
+                                    ctx, i, flats[i]
+                                )
+                        with phase_scope("bagua.layout"):
+                            grads, algo_state = algo.grads_from_reduced(
+                                ctx, reduced, grads, algo_state, step
+                            )
                 else:
-                    with trace_span("trace/comm_stage", overlap=True,
-                                    buckets=len(plan.buckets)):
+                    with phase_scope("bagua.comm", "trace/comm_stage",
+                                     overlap=True,
+                                     buckets=len(plan.buckets)):
                         grads, algo_state = algo.process_grads_bucketed(
                             ctx, grads, params, algo_state, step
                         )
             else:
-                with trace_span("trace/comm_stage", overlap=False,
-                                buckets=len(plan.buckets)):
+                with phase_scope("bagua.comm", "trace/comm_stage",
+                                 overlap=False, buckets=len(plan.buckets)):
                     grads, algo_state = algo.process_grads(
                         ctx, grads, params, algo_state, step
                     )
@@ -1557,8 +1575,10 @@ class BaguaTrainer:
                 ep_size = mesh.shape[expert]
 
                 def expert_grad(g):
-                    g = g / ep_size
-                    return jax.lax.pmean(g, expert_dp) if expert_dp else g
+                    with phase_scope("bagua.comm/expert"):
+                        g = g / ep_size
+                        return (jax.lax.pmean(g, expert_dp) if expert_dp
+                                else g)
 
                 grads = jax.tree_util.tree_map_with_path(
                     lambda path, g: (
@@ -1576,7 +1596,8 @@ class BaguaTrainer:
                 def tp_grad(path, g):
                     if not self._is_sharded(_name_of_path(path)) or not tp_dp:
                         return g
-                    return jax.lax.pmean(g, tp_dp)
+                    with phase_scope("bagua.comm/model_parallel"):
+                        return jax.lax.pmean(g, tp_dp)
 
                 grads = jax.tree_util.tree_map_with_path(tp_grad, grads)
             if guard != "off" and replicated_health:
@@ -1585,10 +1606,12 @@ class BaguaTrainer:
                 # any rank survives the sum — so per-bucket isfinite on
                 # them is a globally consistent verdict, no extra
                 # collective launched
-                health_vec = self._grad_health_vec(plan, grads)
-            params, algo_state = algo.process_pre_step(ctx, params, algo_state, step)
-            with trace_span("trace/optimizer_apply",
-                            owned=algo.owns_optimizer):
+                with phase_scope("bagua.guard"):
+                    health_vec = self._grad_health_vec(plan, grads)
+            with phase_scope("bagua.optimizer", "trace/optimizer_apply",
+                             owned=algo.owns_optimizer):
+                params, algo_state = algo.process_pre_step(
+                    ctx, params, algo_state, step)
                 if algo.owns_optimizer:
                     params, opt_state, algo_state = algo.optimizer_update(
                         ctx, params, grads, opt_state, algo_state, step
@@ -1597,7 +1620,8 @@ class BaguaTrainer:
                     updates, opt_state = self._opt.update(grads, opt_state,
                                                           params)
                     params = optax.apply_updates(params, updates)
-            params, algo_state = algo.process_post_step(ctx, params, algo_state, step)
+                params, algo_state = algo.process_post_step(
+                    ctx, params, algo_state, step)
             if guard != "off" and not replicated_health:
                 # families whose post-comm gradient representation is not
                 # rank-replicated detect on the UPDATED params instead:
@@ -1612,11 +1636,14 @@ class BaguaTrainer:
                 # rank rewinds its own).  Model-parallel slices live only
                 # on their shard, so those meshes fuse verdicts with one
                 # tiny pmin.
-                health_vec = self._grad_health_vec(plan, params)
-                if mp_health and health_axes:
-                    health_vec = jax.lax.pmin(health_vec, health_axes)
+                with phase_scope("bagua.guard"):
+                    health_vec = self._grad_health_vec(plan, params)
+                    if mp_health and health_axes:
+                        with phase_scope("bagua.comm/health"):
+                            health_vec = jax.lax.pmin(health_vec, health_axes)
 
-            loss = ctx.comm.allreduce(loss, ReduceOp.AVG)
+            with phase_scope("bagua.comm/loss"):
+                loss = ctx.comm.allreduce(loss, ReduceOp.AVG)
             if stacked:
                 params, opt_state, algo_state = (
                     _stack(params), _stack(opt_state), _stack(algo_state)
@@ -1637,17 +1664,20 @@ class BaguaTrainer:
                 # forever.  keep=True selects the new values bitwise —
                 # with healthy gradients the trajectory is byte-identical
                 # to guard "off".
-                keep = jnp.min(health_vec) > 0.5
+                with phase_scope("bagua.guard"):
+                    keep = jnp.min(health_vec) > 0.5
 
-                def sel(n, o):
-                    return jnp.where(keep, n, o)
+                    def sel(n, o):
+                        return jnp.where(keep, n, o)
 
-                new_state = TrainState(
-                    new_state.step,
-                    jax.tree.map(sel, new_state.params, state.params),
-                    jax.tree.map(sel, new_state.opt_state, state.opt_state),
-                    jax.tree.map(sel, new_state.algo_state, state.algo_state),
-                )
+                    new_state = TrainState(
+                        new_state.step,
+                        jax.tree.map(sel, new_state.params, state.params),
+                        jax.tree.map(sel, new_state.opt_state,
+                                     state.opt_state),
+                        jax.tree.map(sel, new_state.algo_state,
+                                     state.algo_state),
+                    )
             # a leading row axis: rank-uniform verdicts replicate ([1, b]),
             # per-rank (gossip) verdicts stack over the dp axes ([ranks, b])
             return new_state, loss, health_vec[None]
@@ -1691,7 +1721,7 @@ class BaguaTrainer:
             else (state_specs, P(), health_spec)
         )
         fn = shard_map(
-            per_shard,
+            bagua_step,
             mesh=mesh,
             in_specs=(state_specs, batch_spec),
             out_specs=out_specs,
@@ -1779,6 +1809,13 @@ class BaguaTrainer:
                             buckets=len(self._plan.buckets),
                             overlap=self._overlap_active()):
                 self._step_cache[key] = self._make_step_fn(self._plan)
+            from ..telemetry import counters
+
+            # what the plan asks of the wire per step; what XLA's combiner
+            # makes of it is a count over the compiled text
+            counters.set_gauge(
+                "comm/buckets_per_step",
+                len(self._plan.buckets) if self._comm.nranks() > 1 else 0)
             # the step that triggers this compile produces a garbage-slow
             # speed sample; _auto_record_speed drops it — and the anomaly
             # detector skips the window, and the goodput ledger attributes
@@ -1987,6 +2024,8 @@ class BaguaTrainer:
             return None
         done = threading.Event()
         self._cost_analysis_pending[key] = done
+        # resolved HERE: _get_step_fn writes trainer state, which only the
+        # dispatching thread may do
         fn = self._step_cache.get(key)
 
         def _abstract(x):
@@ -2009,7 +2048,7 @@ class BaguaTrainer:
                     # compile overlaps step windows on another thread, and
                     # a mapped span here would wrongly deduct from them
                     with trace_span("obs/cost_analysis_async"):
-                        compiled = fn.lower(a_state, a_batch).compile()
+                        compiled = self._compile_step(fn, a_state, a_batch)
                         analysis = compiled.cost_analysis()
                 except Exception as e:  # noqa: BLE001 - backend-dependent
                     logger.warning(
@@ -2081,6 +2120,13 @@ class BaguaTrainer:
             logger.debug("device memory poll failed: %s", e)
 
     def train_step(self, state: TrainState, batch) -> Tuple[TrainState, jax.Array]:
+        # the root span of the step (and the profiler's step annotation):
+        # everything the trainer does on the host for one step is inside
+        # it, and its self time is what the child spans below leave over
+        with _obs_spans.trace_step_span(self._step_counter + 1):
+            return self._train_step(state, batch)
+
+    def _train_step(self, state: TrainState, batch):
         from ..communication import check_abort
         from ..faults import inject as _inject
 
@@ -2090,24 +2136,27 @@ class BaguaTrainer:
             # every span opened while this step is driven (including the
             # watchdog waiter's) carries the step number
             _obs_spans.set_current_step(self._step_counter)
-        if self._profiler is not None:
-            self._profiler.on_step(self._step_counter - 1)
-        # step.straggle: a slow peer gates this step only when the family's
-        # step synchronizes with every rank (per-step gradient collective);
-        # async families pay at their own negotiated boundaries instead
-        self._note_step_cadence()
-        if self._profiler is not None and self._obs_enabled:
-            closed = self._profiler.consume_closed_dir()
-            if closed:
-                self._note_device_attribution(closed)
-        self._last_straggle_sleep = _inject.maybe_straggle(
-            "step", base_dt=self._step_dt,
-            gated=self.algorithm.straggler_gates_step,
-        )
-        self._note_stall_phase(self._last_straggle_sleep)
-        if self._ledger is not None and self._last_straggle_sleep > 0:
-            self._ledger.note_class_window("stall", self._last_straggle_sleep)
-        state = self.algorithm.host_pre_step(self, state)
+        with trace_span("step/hooks"):
+            if self._profiler is not None:
+                self._profiler.on_step(self._step_counter - 1)
+            # step.straggle: a slow peer gates this step only when the
+            # family's step synchronizes with every rank (per-step gradient
+            # collective); async families pay at their own negotiated
+            # boundaries instead
+            self._note_step_cadence()
+            if self._profiler is not None and self._obs_enabled:
+                closed = self._profiler.consume_closed_dir()
+                if closed:
+                    self._note_device_attribution(closed)
+            self._last_straggle_sleep = _inject.maybe_straggle(
+                "step", base_dt=self._step_dt,
+                gated=self.algorithm.straggler_gates_step,
+            )
+            self._note_stall_phase(self._last_straggle_sleep)
+            if self._ledger is not None and self._last_straggle_sleep > 0:
+                self._ledger.note_class_window("stall",
+                                               self._last_straggle_sleep)
+            state = self.algorithm.host_pre_step(self, state)
         if self.algorithm.need_reset(self._step_counter - 1):
             self._phase += 1
             # reference re-runs init_tensors + rebucketing at phase switches
@@ -2166,17 +2215,18 @@ class BaguaTrainer:
         # state.step (which resumes from checkpoints), not the
         # trainer-local call counter
         self._note_traced_fault_fires(state)
-        _dispatch_t0 = time.monotonic()
         try:
-            with trace_span("step/dispatch"):
+            with trace_span("step/dispatch") as dispatch:
                 out = fn(state, batch)
         finally:
             if mfu_harvest is not None:
                 # also after a failed dispatch: the thread clears its
                 # pending entry, which step_cost_analysis callers wait on
                 mfu_harvest.start()
-        self.note_phase_duration("dispatch",
-                                 time.monotonic() - _dispatch_t0)
+        if dispatch is not None:
+            # the anomaly detector's phase breakdown reads the span's own
+            # clock pair (obs off: no span, and no detector to feed)
+            self.note_phase_duration("dispatch", dispatch.dur_s)
         if self.grad_guard != "off":
             new_state, loss, health_vec = out
             self.step_metrics = {
@@ -2190,9 +2240,10 @@ class BaguaTrainer:
             # the watchdog's waiter thread reads the scalar loss back
             # inside a watched section.  A cross-rank deadlock pins the
             # waiter past the timeout.
-            self._watchdog.watch_result(
-                out[1], f"train_step[{self._step_counter}]"
-            )
+            with trace_span("step/watchdog_handoff"):
+                self._watchdog.watch_result(
+                    out[1], f"train_step[{self._step_counter}]"
+                )
         self._auto_record_speed(batch)
         if self._obs_enabled:
             # fleet view, worker half: refresh this rank's beacon so the
@@ -2390,6 +2441,26 @@ class BaguaTrainer:
         if dt > 0:
             self._speed_tracker.record(leaves[0].shape[0] / dt)
 
+    def compiled_step(self, state: TrainState, batch) -> jax.stages.Compiled:
+        """The ``jax.stages.Compiled`` of the step program the CURRENT
+        configuration selects (the step-cache key of the next
+        ``train_step``): its optimized HLO text (``.as_text()``, where
+        every instruction's ``op_name`` carries the ``bagua.*`` phase
+        scope), cost and memory analyses.  Lowered and compiled here on
+        every call — jax's AOT path does not share the jit dispatch cache
+        (a persistent compile cache makes it a load) — and not retained:
+        holding an executable would hold its device memory.  ``state`` and
+        ``batch`` may be ``jax.ShapeDtypeStruct`` trees; nothing is
+        executed or donated."""
+        return self._compile_step(self._get_step_fn(), state, batch)
+
+    @staticmethod
+    def _compile_step(fn, state, batch) -> jax.stages.Compiled:
+        """Lower and compile a step function of the step cache — the one
+        place that does (:meth:`compiled_step`, and the MFU harvest's
+        thread, which may not resolve the function itself)."""
+        return fn.lower(state, batch).compile()
+
     def step_cost_analysis(self, state: TrainState, batch) -> Dict[str, Any]:
         """XLA's cost model for the current compiled train step ("flops",
         "bytes accessed", ...) — feeds bench.py's achieved-TFLOP/s and MFU
@@ -2404,8 +2475,7 @@ class BaguaTrainer:
         so the silent-{} path is visible in the fleet view."""
         from ..telemetry import counters
 
-        fn = self._get_step_fn()
-        key = self._current_step_key
+        key = self._step_key()
         cached = self._cost_analysis_cache.get(key)
         if cached is not None:
             return dict(cached)
@@ -2420,7 +2490,7 @@ class BaguaTrainer:
                 return dict(cached)
         try:
             with trace_span("step/cost_analysis"):
-                compiled = fn.lower(state, batch).compile()
+                compiled = self.compiled_step(state, batch)
                 analysis = compiled.cost_analysis()
         except Exception as e:  # pragma: no cover - backend-dependent
             logger.warning(

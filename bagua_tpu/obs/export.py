@@ -103,6 +103,11 @@ def _declare(name: str, kind: str, doc: str) -> None:
 
 
 # -- communication / watchdog --
+_declare("comm/buckets_per_step", "gauge",
+         "Buckets of the plan that the current compiled step exchanges (0 "
+         "when the comm world is one rank), set when a step program is "
+         "built.  What XLA's collective combiner makes of them is a count "
+         "over the compiled text (the benchmark's comm_calls_compiled).")
 _declare("comm/aborts", "counter",
          "Cooperative abort flag raises (watchdog fire, grad-guard abort, "
          "user abort()).")
@@ -265,8 +270,11 @@ _declare("obs/hbm_static_footprint_bytes", "gauge",
          "shard bytes + one set of per-bucket gradient flats "
          "(bagua_tpu.obs.memory.static_footprint; exact on cpu-sim).")
 _declare("obs/hbm_peak_bytes", "gauge",
-         "Live device.memory_stats() peak_bytes_in_use from the last "
-         "beacon-cadence poll (real TPU only; absent on cpu-sim).")
+         "Live high-water mark of the fullest local device from the last "
+         "beacon-cadence poll: max(peak_bytes_in_use, bytes_in_use + "
+         "bytes_reserved) of device.memory_stats() — peak_bytes_in_use "
+         "alone misses a running program's temporaries on a TPU (real TPU "
+         "only; absent on cpu-sim).")
 _declare("obs/hbm_headroom_bytes", "gauge",
          "bytes_limit minus the live peak from the last memory poll — the "
          "capacity-planning margin (real TPU only).")
@@ -550,9 +558,9 @@ def note_hbm_live(record: Dict[str, Any]) -> None:
     with _SUMMARY_LOCK:
         _LAST_HBM_LIVE = dict(record)
     if record.get("available"):
-        if record.get("peak_bytes_in_use") is not None:
+        if record.get("peak_bytes") is not None:
             counters.set_gauge("obs/hbm_peak_bytes",
-                               int(record["peak_bytes_in_use"]))
+                               int(record["peak_bytes"]))
         if record.get("headroom_bytes") is not None:
             counters.set_gauge("obs/hbm_headroom_bytes",
                                int(record["headroom_bytes"]))
@@ -662,7 +670,7 @@ def local_obs_summary() -> Optional[dict]:
         summary["hbm_static_footprint_bytes"] = footprint.get("total_bytes")
     if hbm_live:
         if hbm_live.get("available"):
-            summary["hbm_peak_bytes"] = hbm_live.get("peak_bytes_in_use")
+            summary["hbm_peak_bytes"] = hbm_live.get("peak_bytes")
             summary["hbm_headroom_bytes"] = hbm_live.get("headroom_bytes")
         else:
             summary["hbm_live_rationale"] = hbm_live.get("rationale")

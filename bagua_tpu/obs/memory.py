@@ -15,10 +15,11 @@ Three complementary views, cheapest first:
   ``BaguaTrainer.step_cost_analysis`` when the backend provides one
   (TPU does; cpu-sim reports null-with-rationale).
 * **live peaks** (:func:`live_memory_stats`) — ``device.memory_stats()``
-  polled off the hot path (the trainer's ~2 s beacon cadence): real
-  ``peak_bytes_in_use`` and the headroom against ``bytes_limit``.  TPU
-  runtimes expose it; cpu-sim returns null-with-rationale, like
-  ``trace_overlap``.
+  polled off the hot path (the trainer's ~2 s beacon cadence): the
+  high-water mark (:func:`peak_bytes` — NOT ``peak_bytes_in_use`` alone,
+  which on a TPU never sees a running program's temporaries) and the
+  headroom against ``bytes_limit``.  TPU runtimes expose it; cpu-sim
+  returns null-with-rationale, like ``trace_overlap``.
 
 Footprint and headroom ride the per-rank obs summary → health beacon →
 fleet snapshot as gauges, and land in ``EFFICIENCY.json``.  Host-side
@@ -36,7 +37,7 @@ logger = logging.getLogger(__name__)
 
 __all__ = [
     "plan_flat_bytes", "tree_device_bytes", "static_footprint",
-    "compiled_memory_analysis", "live_memory_stats",
+    "compiled_memory_analysis", "live_memory_stats", "peak_bytes",
 ]
 
 
@@ -142,6 +143,21 @@ def compiled_memory_analysis(compiled) -> Optional[Dict[str, int]]:
     return out
 
 
+def peak_bytes(stats: Dict[str, Any]) -> int:
+    """High-water mark of one device from its ``memory_stats()`` dict: the
+    larger of the buffers' own peak (``peak_bytes_in_use``) and buffers +
+    the programs' reservation now (``bytes_in_use + bytes_reserved``).  On
+    a TPU ``peak_bytes_in_use`` counts buffers only; a running program's
+    temporaries sit in ``bytes_reserved``, sized to the largest program run
+    so far (v5e, BERT-Large step, PR 23: the counter read 7.455 GB after
+    365 steps while 5.55 GB of activations sat in the reservation —
+    11.59 GB is the true mark).  The same arithmetic as the benchmark's
+    ``perfbench/drivers/train.py::peak_memory_bytes``."""
+    return int(max(stats.get("peak_bytes_in_use", 0),
+                   stats.get("bytes_in_use", 0)
+                   + stats.get("bytes_reserved", 0)))
+
+
 def _device_memory_record(device) -> Dict[str, Any]:
     try:
         stats = device.memory_stats()
@@ -157,20 +173,22 @@ def _device_memory_record(device) -> Dict[str, Any]:
     record: Dict[str, Any] = {"available": True,
                               "device_kind": device.device_kind,
                               "device_id": device.id}
-    for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit",
-                "largest_alloc_size"):
+    for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+                "bytes_limit", "largest_alloc_size"):
         if key in stats:
             record[key] = int(stats[key])
-    limit = record.get("bytes_limit")
-    peak = record.get("peak_bytes_in_use", record.get("bytes_in_use"))
-    if limit is not None and peak is not None:
-        record["headroom_bytes"] = int(limit - peak)
+    if "bytes_in_use" in stats or "peak_bytes_in_use" in stats:
+        record["peak_bytes"] = peak_bytes(stats)
+        if "bytes_limit" in record:
+            record["headroom_bytes"] = (record["bytes_limit"]
+                                        - record["peak_bytes"])
     return record
 
 
 def live_memory_stats(device=None) -> Dict[str, Any]:
     """One poll of ``device.memory_stats()``: ``{"available": True,
-    bytes_in_use, peak_bytes_in_use, bytes_limit, headroom_bytes}`` on
+    bytes_in_use, peak_bytes_in_use, bytes_reserved, peak_bytes
+    (:func:`peak_bytes`), bytes_limit, headroom_bytes}`` on
     runtimes that expose it (TPU), else ``{"available": False,
     "rationale": ...}`` — null-with-rationale, so a fleet view can show
     *why* a rank has no live-memory column.

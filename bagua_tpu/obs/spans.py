@@ -25,6 +25,12 @@ Design constraints, in order:
   at step 10^6 and still leave a readable tail.
 * **Import-light.**  No jax import: the launcher and the watchdog waiter
   thread open spans too.
+* **On the profiler's clock when there is one.**  A span mirrors itself to
+  ``jax.profiler.TraceAnnotation("bagua/<name>")`` when the process has
+  already imported jax (looked up in ``sys.modules``, never imported
+  here), so a ``BAGUA_PROFILE_DIR`` / ``jax.profiler`` capture shows the
+  program's own spans on the host plane next to the device timeline.
+  With no capture running the annotation is a ~0.4 us no-op.
 
 ``BAGUA_OBS=off`` turns every hook into a cheap early return (one module
 flag read) — the default-compatible mode.
@@ -32,6 +38,8 @@ flag read) — the default-compatible mode.
 
 from __future__ import annotations
 
+import contextlib
+import sys
 import threading
 import time
 from collections import deque
@@ -39,8 +47,14 @@ from typing import Any, Dict, List, Optional
 
 from .. import env as _env
 
-__all__ = ["trace_span", "recorder", "span_ring", "SpanRecorder", "enabled",
-           "set_enabled", "set_current_step", "set_ledger_sink"]
+__all__ = ["trace_span", "trace_step_span", "phase_scope", "recorder",
+           "span_ring", "SpanRecorder", "enabled", "set_enabled",
+           "set_current_step", "set_ledger_sink"]
+
+#: prefix of every span's mirror on the profiler's host plane
+ANNOTATION_PREFIX = "bagua/"
+#: name of the per-step ``StepTraceAnnotation`` the root span opens
+STEP_ANNOTATION = "bagua_train"
 
 #: resolved master switch; None = not yet read from BAGUA_OBS
 _ENABLED: Optional[bool] = None
@@ -139,16 +153,22 @@ class SpanRecorder:
             self._spans = deque(maxlen=self._capacity)
             self._dropped = 0
 
-    # -- depth bookkeeping (per thread, so nesting renders correctly even
-    # with the watchdog waiter recording concurrently) ----------------------
+    # -- nesting bookkeeping (per thread, so depth and parent render
+    # correctly even with the watchdog waiter recording concurrently) ------
 
-    def _enter(self) -> int:
-        d = getattr(self._local, "depth", 0)
-        self._local.depth = d + 1
-        return d
+    def _enter(self, name: str):
+        """Push ``name``; returns (depth, enclosing span's name or None)."""
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        depth, parent = len(stack), stack[-1] if stack else None
+        stack.append(name)
+        return depth, parent
 
     def _exit(self) -> None:
-        self._local.depth = max(0, getattr(self._local, "depth", 1) - 1)
+        stack = getattr(self._local, "stack", None)
+        if stack:
+            stack.pop()
 
     def open_span(self, key: int, stub: Dict[str, Any]) -> None:
         with self._lock:
@@ -196,21 +216,48 @@ recorder = SpanRecorder()
 span_ring = recorder
 
 
+def _open_annotations(name: str, step_num: Optional[int]) -> tuple:
+    """The span's mirror on the profiler's clock, entered: a
+    ``TraceAnnotation("bagua/<name>")`` (inside a ``StepTraceAnnotation``
+    for the root span of a train step), innermost last.  Empty in a
+    process that has not imported jax — this module never does."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None while jax is importing
+    if profiler is None:
+        return ()
+    opened = ()
+    if step_num is not None:
+        # (an annotation's clock starts when it is constructed: the step
+        # first, so that it encloses the span)
+        step = profiler.StepTraceAnnotation(STEP_ANNOTATION,
+                                            step_num=step_num)
+        step.__enter__()
+        opened = (step,)
+    annotation = profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    annotation.__enter__()
+    return (annotation,) + opened
+
+
 class _Span:
     """The context manager behind :func:`trace_span` — a plain class with
     ``__slots__`` instead of ``contextlib.contextmanager`` because the
     enter/exit pair sits on the train-step hot path (measured in
     ``tests/test_obs.py`` against the <2%-of-step-time budget)."""
 
-    __slots__ = ("name", "attrs", "t0", "step", "ledger_cls")
+    __slots__ = ("name", "attrs", "t0", "step", "ledger_cls", "depth",
+                 "parent", "step_num", "annotations", "dur_s")
 
-    def __init__(self, name: str, attrs: Dict[str, Any]):
+    def __init__(self, name: str, attrs: Dict[str, Any],
+                 step_num: Optional[int] = None):
         self.name = name
         self.attrs = attrs
+        self.step_num = step_num
+        self.dur_s = None
 
     def __enter__(self):
         self.step = self.attrs.pop("step", _CURRENT_STEP)
-        depth = recorder._enter()
+        self.depth, self.parent = recorder._enter(self.name)
+        self.annotations = _open_annotations(self.name, self.step_num)
         # ledger ownership resolves at open (outermost mapped span wins);
         # one global read when no sink is installed
         self.ledger_cls = (
@@ -222,14 +269,17 @@ class _Span:
             "t0": self.t0,
             "rank": _cached_rank(),
             "step": self.step,
-            "depth": depth,
+            "depth": self.depth,
+            "parent": self.parent,
             "thread": threading.current_thread().name,
         })
         return self
 
     def __exit__(self, exc_type, exc, tb):
         t1 = time.monotonic()
-        depth = getattr(recorder._local, "depth", 1) - 1
+        self.dur_s = t1 - self.t0
+        for annotation in self.annotations:
+            annotation.__exit__(exc_type, exc, tb)
         recorder._exit()
         if self.ledger_cls is not None and _LEDGER_SINK is not None:
             _LEDGER_SINK.span_exit(self.ledger_cls, t1 - self.t0)
@@ -237,10 +287,11 @@ class _Span:
             "name": self.name,
             "t0": self.t0,
             "t1": t1,
-            "dur_s": t1 - self.t0,
+            "dur_s": self.dur_s,
             "rank": _cached_rank(),
             "step": self.step,
-            "depth": depth,
+            "depth": self.depth,
+            "parent": self.parent,
             "thread": threading.current_thread().name,
         }
         if exc_type is not None:
@@ -271,10 +322,41 @@ def trace_span(name: str, **attrs):
             ...
 
     Records monotonic start/end, duration, rank, the trainer's current step
-    (override with ``step=``), nesting depth, thread name, and the given
-    key=value attrs into the process ring buffer.  A no-op (returns a
-    shared null context) while ``BAGUA_OBS=off``.  Attrs must be host
-    values (ints/floats/strings) — never tracers."""
+    (override with ``step=``), nesting depth, the enclosing span's name
+    (``parent``), thread name, and the given key=value attrs into the
+    process ring buffer, and mirrors itself to the profiler as
+    ``bagua/<name>``.  ``with ... as span`` yields the span, whose
+    ``dur_s`` is set on exit (None while ``BAGUA_OBS=off``: the shared null
+    context yields None).  Attrs must be host values (ints/floats/strings)
+    — never tracers."""
     if not enabled():
         return _NULL
     return _Span(name, attrs)
+
+
+def trace_step_span(step_num: int):
+    """The root span of one ``BaguaTrainer.train_step`` call,
+    ``step/train_step``: a :func:`trace_span` that also opens the
+    profiler's ``StepTraceAnnotation("bagua_train", step_num=...)``, so a
+    capture groups host and device activity by training step."""
+    if not enabled():
+        return _NULL
+    return _Span("step/train_step", {"step": step_num}, step_num)
+
+
+@contextlib.contextmanager
+def phase_scope(scope: str, span: Optional[str] = None, **attrs):
+    """Name a phase of the compiled step, for code that runs while JAX
+    TRACES the step: a ``jax.named_scope(scope)`` — metadata only, the
+    ``op_name`` path of every instruction traced inside, so a device trace
+    and the optimized HLO text can be read by phase (``bagua.loss``,
+    ``bagua.layout``, ``bagua.comm/bucket_<i>``, ``bagua.optimizer``,
+    ``bagua.guard``) — and, where ``span`` is given, the trace-time ring
+    span of the same site (launch order and byte accounting of the
+    schedule).  One construct for both, so the schedule and the program
+    cannot name different things.  The scope is unconditional: the program
+    is the same with ``BAGUA_OBS`` on or off."""
+    import jax  # tracing code only: jax is imported by whoever traces
+
+    with trace_span(span, **attrs) if span else _NULL, jax.named_scope(scope):
+        yield

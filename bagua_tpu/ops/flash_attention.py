@@ -139,6 +139,7 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((bh, 8, s), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(q, k, v)
 
 
@@ -284,6 +285,7 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
             jax.ShapeDtypeStruct((bh, s, d), v.dtype),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     qb_spec = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
@@ -298,6 +300,7 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
         out_specs=qb_spec,
         out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 

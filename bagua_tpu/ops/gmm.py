@@ -97,6 +97,7 @@ def _gmm_padded(lhs_p, rhs, g_of_block, block_rows, block_f, interpret):
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((padded_rows, f), lhs_p.dtype),
         interpret=interpret,
+        name="gmm_fwd",
     )(g_of_block, lhs_p, rhs)
 
 
@@ -135,6 +136,7 @@ def _gmm_drhs_padded(lhs_p, gout_p, n_groups, d, f, g_of_block, block_rows,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_groups, d, f), jnp.float32),
         interpret=interpret,
+        name="gmm_bwd_drhs",
     )(g_of_block, lhs_p, gout_p)
 
 

@@ -132,14 +132,19 @@ def uses_pallas(fn, *args) -> bool:
 
 
 def device_memory() -> list:
-    """``bytes_in_use`` / ``peak_bytes_in_use`` per device (None where the
-    runtime reports no memory stats, i.e. the CPU rehearsal)."""
+    """``bytes_in_use`` and the high-water mark per device (None where the
+    runtime reports no memory stats, i.e. the CPU rehearsal).  The mark is
+    ``obs.memory.peak_bytes``: ``peak_bytes_in_use`` alone does not see a
+    running program's temporaries on a TPU."""
     import jax
+
+    from bagua_tpu.obs.memory import peak_bytes
 
     out = []
     for d in jax.devices():
-        stats = d.memory_stats() or {}
-        out.append((stats.get("bytes_in_use"), stats.get("peak_bytes_in_use")))
+        stats = d.memory_stats()
+        out.append((stats.get("bytes_in_use"), peak_bytes(stats))
+                   if stats else (None, None))
     return out
 
 

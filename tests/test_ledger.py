@@ -253,6 +253,43 @@ def test_live_memory_null_with_rationale_on_cpu(ledger_on):
     assert "hbm_live_rationale" in summary
 
 
+class _FakeDevice:
+    device_kind, id = "TPU v5 lite", 0
+
+    def __init__(self, stats):
+        self._stats = stats
+
+    def memory_stats(self):
+        return self._stats
+
+
+#: the v5e after 365 BERT-Large steps (PERF.md, PR 23): the buffers' own
+#: peak is the init staging mark; the step's activations sit in the
+#: programs' reservation, which peak_bytes_in_use never shows
+_V5E_STATS = {"bytes_in_use": 6_037_000_000, "peak_bytes_in_use": 7_455_000_000,
+              "bytes_reserved": 5_550_000_000, "bytes_limit": 16_900_000_000}
+
+
+@pytest.mark.parametrize("stats, peak", [
+    (_V5E_STATS, 11_587_000_000),                       # buffers + reservation
+    ({**_V5E_STATS, "bytes_reserved": 0}, 7_455_000_000),  # nothing has run yet
+    ({"bytes_in_use": 5, "bytes_limit": 100}, 5),       # a sparse runtime
+])
+def test_live_memory_peak_sees_the_programs_reservation(stats, peak):
+    assert obs_memory.peak_bytes(stats) == peak
+    rec = obs_memory.live_memory_stats(_FakeDevice(stats))
+    assert rec["available"] and rec["peak_bytes"] == peak
+    assert rec["headroom_bytes"] == stats["bytes_limit"] - peak
+    obs_export.note_hbm_live(rec)
+    try:
+        # the gauges keep their names and now carry the true mark
+        assert telemetry.counters.get("obs/hbm_peak_bytes") == peak
+        assert telemetry.counters.get("obs/hbm_headroom_bytes") \
+            == stats["bytes_limit"] - peak
+    finally:
+        obs_export.reset_local_summary()
+
+
 def test_memory_analysis_cached_per_step_key(ledger_on):
     t, s, b = _golden_trainer()
     s, _ = t.train_step(s, b)

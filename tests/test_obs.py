@@ -422,34 +422,18 @@ def test_step_program_identical_obs_on_off():
 
 
 def test_span_overhead_under_two_percent(obs_on):
-    """Span overhead budget: (spans per step) x (per-span cost) must stay
-    under 2% of the measured host step time on the 8-dev cpu-sim bench."""
+    """Span overhead budget: (spans per steady step) x (per-span cost),
+    each factor bounded on its own (tests/span_budget.py): the count
+    exactly, the cost against an absolute ceiling — 5 x 50 us is 0.3 % of
+    the shortest step the benchmark measures, well under the 2 % budget."""
+    from span_budget import assert_span_budget
+
     t, s, b = _golden_trainer()
     before = len(obs_spans.recorder.snapshot())
     for _ in range(5):
         s, loss = t.train_step(s, b)
     float(loss)
-    step_dt = t.measured_step_dt()
-    assert step_dt and step_dt > 0
-    # steady-state spans per step (exclude the one-time build/trace spans)
-    spans = obs_spans.recorder.snapshot()[before:]
-    per_step = [sp for sp in spans if sp.get("step") == t._step_counter
-                and not sp["name"].startswith(("trace/", "step/build"))]
-    n_spans = max(1, len(per_step))
-    reps = 2000
-    batches = []
-    for _ in range(3):  # best-of-3: intrinsic span cost, not machine load
-        t0 = time.perf_counter()
-        for _ in range(reps):
-            with obs_spans.trace_span("overhead_probe"):
-                pass
-        batches.append((time.perf_counter() - t0) / reps)
-    per_span = min(batches)
-    overhead = n_spans * per_span
-    assert overhead < 0.02 * step_dt, (
-        f"{n_spans} spans x {per_span * 1e6:.2f}us = {overhead * 1e6:.1f}us "
-        f">= 2% of step_dt {step_dt * 1e3:.2f}ms"
-    )
+    assert_span_budget(t, obs_spans.recorder.snapshot()[before:])
 
 
 # ---- exporter wiring through the trainer ----------------------------------

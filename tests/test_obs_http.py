@@ -375,9 +375,12 @@ def test_step_program_identical_with_http_and_historian(monkeypatch):
 
 
 def test_span_overhead_budget_with_http_and_historian(monkeypatch):
-    """The <2% span-overhead budget re-asserted with the HTTP server
-    serving and the historian ingesting in-process (ISSUE 7's gate must
-    survive ISSUE 14's additions)."""
+    """The span-overhead budget (tests/span_budget.py: exact count per
+    steady step, absolute ceiling per span) re-asserted with the HTTP
+    server serving and the historian ingesting in-process (ISSUE 7's gate
+    must survive ISSUE 14's additions)."""
+    from span_budget import assert_span_budget
+
     obs_spans.set_enabled(True)
     srv = ObsHTTPServer(port=0).start()
     historian = Historian(capacity=64, window_s=600.0)
@@ -394,26 +397,7 @@ def test_span_overhead_budget_with_http_and_historian(monkeypatch):
                 "efficiency": {"ranks": {}},
             })
         float(loss)
-        step_dt = t.measured_step_dt()
-        assert step_dt and step_dt > 0
-        spans = obs_spans.recorder.snapshot()[before:]
-        per_step = [sp for sp in spans if sp.get("step") == t._step_counter
-                    and not sp["name"].startswith(("trace/", "step/build"))]
-        n_spans = max(1, len(per_step))
-        reps = 2000
-        batches = []
-        for _ in range(3):
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                with obs_spans.trace_span("overhead_probe"):
-                    pass
-            batches.append((time.perf_counter() - t0) / reps)
-        per_span = min(batches)
-        overhead = n_spans * per_span
-        assert overhead < 0.02 * step_dt, (
-            f"{n_spans} spans x {per_span * 1e6:.2f}us = "
-            f"{overhead * 1e6:.1f}us >= 2% of step_dt {step_dt * 1e3:.2f}ms"
-        )
+        assert_span_budget(t, obs_spans.recorder.snapshot()[before:])
     finally:
         srv.stop()
         obs_spans.recorder.clear()
