@@ -21,9 +21,11 @@ from typing import Any, Callable, Optional
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 
 from ..parallel.mesh import axis_bound as _axis_bound
+from ..utils import remat_wrap
 
 
 @dataclasses.dataclass(frozen=True)
@@ -37,12 +39,20 @@ class TransformerConfig:
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = False
-    #: rematerialization policy when ``remat`` is on: None = recompute the
-    #: whole block (lowest memory), "dots" = save every matmul output,
-    #: "dots_no_batch" = save matmul outputs without batch dims.  Saving
-    #: dots skips recomputing the projections/FFN in the backward at
-    #: ~b*s*d_ff bytes per layer of extra HBM — measured +5.7% tokens/s on
-    #: the seq-4096 LM on v5e (100.0k -> 105.7k, "dots_no_batch")
+    #: what a rematerialized block keeps for the backward pass
+    #: (``utils.remat_wrap`` builds the policy).  None: its input only, the
+    #: whole block is recomputed (lowest memory).  "dots": every matmul
+    #: output, and the flash kernel's ``o`` and ``lse``.  "dots_no_batch":
+    #: q / k / v, the FFN's gate and up, ``o`` and ``lse`` — what the
+    #: block tags (``KEPT_QKV``, ``KEPT_FFN_IN``) and the kernel tags; the
+    #: attention out-projection's output, as large as ``o``, is rebuilt
+    #: from ``o`` in the replay (one matmul), so the kernel is not run a
+    #: second time and memory stays level.  A custom MLP tags nothing and
+    #: keeps what the dots rule gives it (then the out-projection too).
+    #: Measured on one v5e, gpt2-medium at seq 1024, batch 8,
+    #: "dots_no_batch" (PERF.md §6, PR 27): 32,803 -> 34,166 tokens/s/chip
+    #: at 15.262 -> 15.281 GB against replaying the kernel; keeping the
+    #: out-projection's output as well reads 34,827 at 15.664 GB
     remat_policy: Optional[str] = None
     #: sequence-parallel mesh axis: when set and bound (inside shard_map),
     #: each shard holds a contiguous sequence chunk and position embeddings
@@ -134,6 +144,14 @@ TRASH_PAGE = 1
 RESERVED_PAGES = 2
 
 
+#: ``checkpoint_name`` tags on the matmul outputs a stock :class:`Block`
+#: keeps under remat (q / k / v; FFN gate and up).  With the attention
+#: kernel's ``o`` and ``lse`` they are the block's whole kept set under
+#: ``remat_policy="dots_no_batch"`` (see ``TransformerConfig.remat_policy``)
+KEPT_QKV = "attn_qkv"
+KEPT_FFN_IN = "ffn_in"
+
+
 def _tp_active(cfg) -> bool:
     return (
         cfg.tp_axis is not None and cfg.tp_size > 1
@@ -158,7 +176,7 @@ class Attention(nn.Module):
             (h, d), axis=-1, name=name, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, use_bias=False,
         )
-        q, k, v = dense("q")(x), dense("k")(x), dense("v")(x)
+        q, k, v = (checkpoint_name(dense(n)(x), KEPT_QKV) for n in "qkv")
         if cfg.decode and cfg.page_size > 0:
             o = self._paged_decode_attend(q, k, v, slots)
         elif cfg.decode:
@@ -308,6 +326,7 @@ class MLPBlock(nn.Module):
                         param_dtype=cfg.param_dtype, name="wi_gate")(x)
         up = nn.Dense(d_ff, use_bias=False, dtype=cfg.dtype,
                       param_dtype=cfg.param_dtype, name="wi_up")(x)
+        gate, up = (checkpoint_name(t, KEPT_FFN_IN) for t in (gate, up))
         y = nn.silu(gate) * up
         out = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                        param_dtype=cfg.param_dtype, name="wo")(y)
@@ -392,14 +411,13 @@ class TransformerLM(nn.Module):
                     pos_index.value = start + s
             pos_slice = jax.lax.dynamic_slice_in_dim(pos, start, s, axis=0)
             x = x + pos_slice[None].astype(cfg.dtype)
-        if cfg.remat:
-            from ..utils import remat_wrap
-
-            block_cls = remat_wrap(Block, cfg.remat_policy)
-        else:
-            block_cls = Block
         for i in range(cfg.n_layers):
             mlp = self.mlp_factory(i) if self.mlp_factory is not None else None
+            block_cls = Block
+            if cfg.remat:
+                # a custom MLP tags nothing: its matmuls keep the dots rule
+                own = (KEPT_QKV, KEPT_FFN_IN) if mlp is None else ()
+                block_cls = remat_wrap(Block, cfg.remat_policy, own)
             blk = block_cls(cfg, self.attn_fn, mlp, name=f"block_{i}")
             x = blk(x) if slots is None else blk(x, slots)
         x = RMSNorm(cfg.dtype, cfg.param_dtype, name="final_norm")(x)

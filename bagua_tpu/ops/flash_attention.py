@@ -33,6 +33,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -316,9 +317,22 @@ def _flash_lse(q, k, v, causal, block_q, block_k, interpret):
     return o, lse[:, 0, :]
 
 
+#: ``checkpoint_name`` tags on what the forward kernel made.  A remat policy
+#: that keeps matmul outputs keeps these too (``utils.remat_wrap``): to
+#: ``jax.checkpoint`` the kernel is an opaque ``pallas_call``, and without
+#: the tags the backward pass runs ``flash_fwd`` again to get them back.
+KEPT_O = "flash_o"
+KEPT_LSE = "flash_lse"
+
+
 def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
     o, lse = _fwd(q, k, v, causal, block_q, block_k, interpret)
-    return (o, lse[:, 0, :]), (q, k, v, o, lse[:, :1, :])
+    # tagged HERE so the value returned and the residual are one variable
+    # (a tag on the caller's side names a copy and the kernel is replayed);
+    # the [bh, 1, s] row, not the 8-sublane stripe the kernel writes
+    o = checkpoint_name(o, KEPT_O)
+    lse = checkpoint_name(lse[:, :1, :], KEPT_LSE)
+    return (o, lse[:, 0, :]), (q, k, v, o, lse)
 
 
 def _flash_lse_bwd(causal, block_q, block_k, interpret, res, cts):

@@ -214,19 +214,39 @@ def find_free_port(low: int = 20000, high: int = 65000) -> int:
 logger = logging.getLogger("bagua_tpu")
 
 
-def remat_wrap(block_cls, remat_policy=None):
+def remat_wrap(block_cls, remat_policy=None, matmul_names=()):
     """Wrap a flax module class in ``nn.checkpoint`` with a NAMED policy —
     the single source of the policy-name map shared by the transformer and
-    ResNet ``remat``/``remat_policy`` knobs (None = recompute everything;
-    "dots" keeps dot_general results; "dots_no_batch" its no-batch-dims
-    variant)."""
-    import flax.linen as nn
-    import jax
+    ResNet ``remat``/``remat_policy`` knobs.  What the backward pass finds
+    kept, per policy:
 
-    policy = {
-        None: None,
-        "dots": jax.checkpoint_policies.dots_saveable,
-        "dots_no_batch":
-            jax.checkpoint_policies.dots_with_no_batch_dims_saveable,
+    - ``None``: the block's input only; everything is recomputed.
+    - ``"dots"``: every ``dot_general`` output, and the flash-attention
+      kernel's ``o`` and ``lse`` (two matmuls in one ``pallas_call``, which
+      the dots rule cannot see: they are kept by their ``checkpoint_name``
+      tags, ``ops.flash_attention.KEPT_O`` / ``KEPT_LSE``).
+    - ``"dots_no_batch"``: ``dot_general`` outputs without batch dims, and
+      ``o`` / ``lse``.  A block that tags its own matmul outputs passes the
+      tags as ``matmul_names``, and then keeps exactly the named values: a
+      matmul it left untagged (the transformer's attention out-projection,
+      as large as the ``o`` it reads) is rebuilt in the replay.
+
+    Tags nobody wrote are inert (ResNet blocks; attention off the kernel).
+    """
+    import flax.linen as nn
+
+    from .ops.flash_attention import KEPT_LSE, KEPT_O
+
+    if remat_policy is None:
+        return nn.checkpoint(block_cls, policy=None)
+    cp = jax.checkpoint_policies
+    dots_rule = {
+        "dots": cp.dots_saveable,
+        "dots_no_batch": cp.dots_with_no_batch_dims_saveable,
     }[remat_policy]
+    names = cp.save_only_these_names(KEPT_O, KEPT_LSE, *matmul_names)
+    if matmul_names and remat_policy == "dots_no_batch":
+        policy = names
+    else:
+        policy = cp.save_from_both_policies(dots_rule, names)
     return nn.checkpoint(block_cls, policy=policy)

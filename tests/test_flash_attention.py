@@ -2,6 +2,9 @@
 (SURVEY.md §4: every fused/native op is validated against a pure
 reimplementation; same pattern as the codec goldens)."""
 
+import functools
+import importlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -113,3 +116,95 @@ def test_model_dispatch_unchanged_on_cpu():
     want = reference_attention(q, k, v, jnp.float32)
     got = causal_attention(q, k, v, jnp.float32)
     np.testing.assert_allclose(got, want, atol=1e-6, rtol=1e-6)
+
+
+# --- what a remat policy keeps of the kernel's outputs -----------------------
+
+
+def _kernel_calls(jaxpr, counts=None):
+    """``{kernel name: pallas_call eqns}`` of a jaxpr, nested jaxprs included."""
+    from bagua_tpu.analysis.jaxpr_check import _sub_jaxprs
+
+    counts = {} if counts is None else counts
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            counts[eqn.params["name"]] = counts.get(eqn.params["name"], 0) + 1
+        for _, inner in _sub_jaxprs(eqn):
+            _kernel_calls(inner, counts)
+    return counts
+
+
+def _forced_flash(q, k, v, dtype):
+    return flash_attention(q, k, v, dtype, causal=True, interpret=True,
+                           force=True)
+
+
+@pytest.mark.parametrize("remat_policy,forwards_per_layer",
+                         [(None, 2), ("dots", 1), ("dots_no_batch", 1)])
+def test_dots_policies_keep_the_forward_kernels_outputs(remat_policy,
+                                                        forwards_per_layer):
+    """A policy that keeps matmul outputs keeps ``o`` and ``lse`` too, so the
+    backward pass does not run ``flash_fwd`` a second time; with nothing
+    kept (``None``) the replay runs it again, as it must."""
+    import optax
+
+    from bagua_tpu.models.transformer import TransformerConfig, TransformerLM
+
+    layers = 2
+    cfg = TransformerConfig(vocab_size=97, d_model=128, n_heads=2,
+                            n_layers=layers, d_ff=256, max_seq_len=128,
+                            remat=True, remat_policy=remat_policy)
+    model = TransformerLM(cfg, attn_fn=_forced_flash)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 129), 0, 97)
+    params = jax.eval_shape(
+        lambda: model.init(jax.random.PRNGKey(1), tokens[:, :-1])["params"])
+
+    def loss_fn(p):
+        logits = model.apply({"params": p}, tokens[:, :-1])
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, tokens[:, 1:]).mean()
+
+    calls = _kernel_calls(jax.make_jaxpr(jax.grad(loss_fn))(params).jaxpr)
+    assert calls == {"flash_fwd": forwards_per_layer * layers,
+                     "flash_bwd_dq": layers, "flash_bwd_dkv": layers}
+
+
+@pytest.mark.parametrize("kept", [False, True])
+def test_tags_leave_the_lse_cotangent_path_as_it_was(kept, monkeypatch):
+    """Ring attention consumes ``lse``: with a non-zero ``dlse`` the tagged
+    rule's gradients equal those of the rule without tags, bit for bit —
+    also where a names policy keeps the tagged values (``kept``)."""
+    # the package exports the function under the module's name
+    fa = importlib.import_module("bagua_tpu.ops.flash_attention")
+
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+    def untagged(q, k, v, causal, block_q, block_k, interpret):
+        o, lse = fa._fwd(q, k, v, causal, block_q, block_k, interpret)
+        return o, lse[:, 0, :]
+
+    def untagged_fwd(q, k, v, causal, block_q, block_k, interpret):
+        o, lse = fa._fwd(q, k, v, causal, block_q, block_k, interpret)
+        return (o, lse[:, 0, :]), (q, k, v, o, lse[:, :1, :])
+
+    untagged.defvjp(untagged_fwd, fa._flash_lse_bwd)
+
+    q, k, v = _qkv(jax.random.PRNGKey(7), b=1, s=256, h=2, d=64)
+    g = jax.random.normal(jax.random.PRNGKey(8), q.shape, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(9), (1, 2, 256), jnp.float32)
+
+    def grads(rule):
+        def f(q, k, v):
+            o, lse = fa.flash_attention_with_lse(q, k, v, causal=True,
+                                                 interpret=True)
+            return (o * g).sum() + (lse * w).sum()
+
+        if kept:
+            f = jax.checkpoint(
+                f, policy=jax.checkpoint_policies.save_only_these_names(
+                    fa.KEPT_O, fa.KEPT_LSE))
+        monkeypatch.setattr(fa, "_flash_lse", rule)
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    for got, want, name in zip(grads(fa._flash_lse), grads(untagged), "qkv"):
+        assert jnp.abs(want).max() > 0
+        np.testing.assert_array_equal(got, want, err_msg=f"d{name}")

@@ -1,7 +1,9 @@
 """Model smoke + training tests (tiny shapes, 8-device CPU mesh)."""
 
+import flax.linen as nn
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 import pytest
 
@@ -111,16 +113,37 @@ def test_vgg_trains():
     assert losses[-1] < losses[0]
 
 
-def test_remat_policies_preserve_gradients():
+def _forced_flash(q, k, v, dtype):
+    """The Pallas kernels in interpret mode: on the CPU the model's own
+    dispatch takes the plain path."""
+    from bagua_tpu.ops.flash_attention import flash_attention
+
+    return flash_attention(q, k, v, dtype, causal=True, interpret=True,
+                           force=True)
+
+
+#: attention -> (attn_fn, d_model, n_heads, seq): the kernel wants a
+#: 128-multiple sequence and a 64-wide head
+_REMAT_SHAPES = {"plain": (None, 64, 4, 32),
+                 "flash": (_forced_flash, 128, 2, 128)}
+
+
+@pytest.mark.parametrize("policy", [None, "dots", "dots_no_batch"])
+@pytest.mark.parametrize("attention", ["plain", "flash"])
+def test_remat_policies_preserve_gradients(attention, policy):
     """remat_policy changes WHAT is saved for the backward, never the math:
     loss and gradients must match the no-remat run bitwise-closely for
-    every policy."""
-    cfg0 = TransformerConfig(vocab_size=97, d_model=64, n_heads=4,
-                             n_layers=2, d_ff=128, max_seq_len=32)
-    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 33), 0, 97)
+    every policy — also where the policy keeps the flash kernel's ``o`` and
+    ``lse`` and rebuilds the out-projection from them."""
+    import dataclasses
+
+    attn_fn, d_model, n_heads, seq = _REMAT_SHAPES[attention]
+    cfg0 = TransformerConfig(vocab_size=97, d_model=d_model, n_heads=n_heads,
+                             n_layers=2, d_ff=2 * d_model, max_seq_len=seq)
+    tokens = jax.random.randint(jax.random.PRNGKey(0), (2, seq + 1), 0, 97)
 
     def loss_and_grads(cfg):
-        model = TransformerLM(cfg)
+        model = TransformerLM(cfg, attn_fn=attn_fn)
         params = TransformerLM(cfg0).init(
             jax.random.PRNGKey(1), tokens[:, :-1]
         )["params"]
@@ -134,17 +157,75 @@ def test_remat_policies_preserve_gradients():
         return jax.jit(jax.value_and_grad(loss_fn))(params)
 
     l0, g0 = loss_and_grads(cfg0)
-    import dataclasses
+    l1, g1 = loss_and_grads(
+        dataclasses.replace(cfg0, remat=True, remat_policy=policy))
+    # bf16 compute: rematerialization reorders fusions, so tiny numeric
+    # drift is expected — the check is "same math", not bit-equality
+    assert abs(float(l0) - float(l1)) < 1e-4, (policy, float(l0), float(l1))
+    jax.tree.map(
+        lambda a, b: np.testing.assert_allclose(a, b, rtol=3e-2, atol=3e-3),
+        g0, g1,
+    )
 
-    for policy in (None, "dots", "dots_no_batch"):
-        cfg = dataclasses.replace(cfg0, remat=True, remat_policy=policy)
-        l1, g1 = loss_and_grads(cfg)
-        # bf16 compute: rematerialization reorders fusions, so tiny numeric
-        # drift is expected — the check is "same math", not bit-equality
-        assert abs(float(l0) - float(l1)) < 1e-4, (policy, float(l0), float(l1))
-        jax.tree.map(
-            lambda a, b: __import__("numpy").testing.assert_allclose(
-                a, b, rtol=3e-2, atol=3e-3
-            ),
-            g0, g1,
-        )
+
+class _ForeignMLP(nn.Module):
+    """An MLP from outside the model file: it tags nothing."""
+
+    @nn.compact
+    def __call__(self, x):
+        h = nn.Dense(384, use_bias=False, dtype=x.dtype, name="wi")(x)
+        return nn.Dense(x.shape[-1], use_bias=False, dtype=x.dtype,
+                        name="wo")(nn.gelu(h))
+
+
+@pytest.mark.parametrize("policy,mlp,keeps_out_projection", [
+    ("dots_no_batch", None, False),
+    ("dots_no_batch", _ForeignMLP, True),
+    ("dots", None, True),
+])
+def test_what_a_rematted_block_keeps(policy, mlp, keeps_out_projection):
+    """The kept set of one block against the bare dots rule's (what the
+    block kept before the kernel's outputs were tagged): ``o`` as
+    ``[bh, s, d]`` and ``lse`` as the one-row ``[bh, 1, s]`` f32 (never the
+    kernel's 8-sublane stripe) come on top; a stock block under
+    ``"dots_no_batch"`` gives up the equally large out-projection output
+    for ``o`` and so grows by the ``lse`` row alone."""
+    from jax._src.ad_checkpoint import saved_residuals  # 0.9.0 exports only
+    # the printer, print_saved_residuals
+
+    from bagua_tpu.models.transformer import (
+        KEPT_FFN_IN, KEPT_QKV, Block)
+    from bagua_tpu.utils import remat_wrap
+
+    b, s, h, d = 2, 128, 2, 64
+    cfg = TransformerConfig(vocab_size=97, d_model=h * d, n_heads=h,
+                            n_layers=1, d_ff=384, max_seq_len=s)
+    x = jax.random.normal(jax.random.PRNGKey(0), (b, s, h * d), cfg.dtype)
+    rules = {"dots": jax.checkpoint_policies.dots_saveable,
+             "dots_no_batch":
+                 jax.checkpoint_policies.dots_with_no_batch_dims_saveable}
+
+    def kept(block_cls):
+        block = block_cls(cfg, _forced_flash, mlp)
+        params = block.init(jax.random.PRNGKey(1), x)
+        out = [(tuple(aval.shape), aval.dtype)
+               for aval, why in saved_residuals(block.apply, params, x)
+               if "from the argument" not in why]
+        return out, sum(int(np.prod(shape)) * dtype.itemsize
+                        for shape, dtype in out)
+
+    before, bytes_before = kept(nn.checkpoint(Block, policy=rules[policy]))
+    after, bytes_after = kept(remat_wrap(
+        Block, policy,
+        matmul_names=(KEPT_QKV, KEPT_FFN_IN) if mlp is None else ()))
+
+    o = ((b * h, s, d), jnp.bfloat16)
+    lse = ((b * h, 1, s), jnp.float32)
+    out_projection = ((b, s, h * d), jnp.bfloat16)
+    assert out_projection in before and o not in before and lse not in before
+    assert after.count(o) == 1 and after.count(lse) == 1
+    assert ((b * h, 8, s), jnp.float32) not in after
+    assert (out_projection in after) == keeps_out_projection
+    o_bytes, lse_bytes = b * h * s * d * 2, b * h * s * 4
+    assert bytes_after - bytes_before == lse_bytes + (
+        o_bytes if keeps_out_projection else 0)
