@@ -991,23 +991,35 @@ class BaguaTrainer:
         flat buffer group inside arbitrary optax state nesting."""
         return isinstance(x, dict) and set(x.keys()) == {"flats", "local"}
 
-    def _relayout_tree(self, tree, old_plan, new_plan):
+    def _relayout_tree(self, tree, old_plan, new_plan, consume=False):
         """Migrate every flat-resident subtree of ``tree`` (params, or an
         optimizer state mirroring them) from ``old_plan`` to ``new_plan``.
         Elementwise optimizer state is exactly as relayout-safe as the
         params it mirrors: its flat buffers share the plan's offsets, and
-        bucket padding stays zero under elementwise updates."""
+        bucket padding stays zero under elementwise updates.
+
+        ``consume`` frees each old flat buffer as soon as its successor
+        exists, as the donating step would have: the caller's reference to
+        the old state otherwise keeps a second copy of it on the device
+        while the recompiled step loads (RESOURCE_EXHAUSTED for BERT-Large
+        at ``accum_steps=4`` on a 16 GB v5e, PR 28)."""
         from ..bucket import relayout_flats
 
         is_zp = self._is_flat_container
+        # one program a tree, not a dispatch (and on a chip a compile) per
+        # tensor segment; params and every moment share it by shape
+        relayout = jax.jit(
+            lambda flats: relayout_flats(old_plan, new_plan, flats)
+        )
 
         def fix(x):
             if is_zp(x):
-                return {
-                    "flats": tuple(relayout_flats(old_plan, new_plan,
-                                                  x["flats"])),
-                    "local": x["local"],
-                }
+                flats = tuple(relayout(tuple(x["flats"])))
+                if consume:
+                    # every new flat is a fresh buffer (slices, concatenated)
+                    for f in x["flats"]:
+                        f.delete()
+                return {"flats": flats, "local": x["local"]}
             return x
 
         return jax.tree.map(fix, tree, is_leaf=is_zp)
@@ -1025,10 +1037,14 @@ class BaguaTrainer:
                 self._stashed_opt_state = self._relayout_tree(
                     self._stashed_opt_state, old_plan, new_plan
                 )
+            # the state handed to train_step is donated to the step; a
+            # migration in front of it consumes it the same way
+            consume = self.donate
             return state._replace(
-                params=self._relayout_tree(state.params, old_plan, new_plan),
+                params=self._relayout_tree(state.params, old_plan, new_plan,
+                                           consume),
                 opt_state=self._relayout_tree(state.opt_state, old_plan,
-                                              new_plan),
+                                              new_plan, consume),
                 algo_state=self.algorithm.relayout_algo_state(
                     old_plan, new_plan, state.algo_state
                 ),

@@ -54,22 +54,33 @@ def top1_gating(
 
 
 def topk_routing(
-    logits: jax.Array, k: int
+    logits: jax.Array, k: int, *, renormalize: bool = True,
+    balance_over_topk: bool = False,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Dropless top-k routing: no capacity, no dispatch tensor.
+    """Dropless top-k routing for any ``k``: no capacity, no dispatch tensor.
 
-    Returns (expert_idx [tokens, k], gate_weights [tokens, k], l_aux) with
-    the same gate conventions as the capacity gates: top-1 keeps the raw
-    chosen probability, top-k>1 renormalizes over the winners; the aux loss
-    is computed over the top-1 assignment (GShard eq. 4).  Consumed by the
-    sort + grouped-matmul (``bagua_tpu.ops.gmm``) dropless MoE path.
+    Returns (expert_idx [tokens, k], gate_weights [tokens, k], l_aux).
+    Consumed by the sort + grouped-matmul (``bagua_tpu.ops.gmm``) dropless
+    MoE path.
+
+    Defaults are the capacity gates' conventions (GShard): top-1 keeps the
+    raw chosen probability, ``k > 1`` renormalizes over the winners, and the
+    aux loss is over the top-1 assignment (GShard eq. 4).
+    ``renormalize=False`` keeps the raw softmax probabilities of the winners
+    (HF ``norm_topk_prob: false``, OLMoE).  ``balance_over_topk=True`` takes
+    the balance loss over all ``k`` assignments of a token, as HF's
+    ``load_balancing_loss_func`` does: ``n_experts * sum_e (assignments on
+    e / tokens) * mean_t probs[t, e]``.
     """
     probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
     n_experts = probs.shape[-1]
     gates, eidx = jax.lax.top_k(probs, k)
-    mask1 = jax.nn.one_hot(eidx[:, 0], n_experts, dtype=jnp.float32)
-    l_aux = _load_balancing_loss(probs, mask1)
-    if k > 1:
+    if balance_over_topk:
+        mask = jax.nn.one_hot(eidx, n_experts, dtype=jnp.float32).sum(axis=1)
+    else:
+        mask = jax.nn.one_hot(eidx[:, 0], n_experts, dtype=jnp.float32)
+    l_aux = _load_balancing_loss(probs, mask)
+    if k > 1 and renormalize:
         gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
     return eidx.astype(jnp.int32), gates, l_aux
 

@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ...obs.spans import phase_scope
 from ...parallel.mesh import axis_bound as _axis_bound
 from .gating import top1_gating, top2_gating
 
@@ -41,7 +42,9 @@ class MoEMLP(nn.Module):
     n_experts: int
     d_ff: int
     ep_size: int = 1
-    k: int = 2                      # top-k routing (1 or 2)
+    #: experts a token is routed to.  The capacity path has gates for 1
+    #: and 2 only; ``dropless`` routes any ``k`` (OLMoE: 8 of 64)
+    k: int = 2
     capacity_factor: float = 1.25
     axis_name: str = "ep"
     dtype: Any = jnp.bfloat16
@@ -54,20 +57,34 @@ class MoEMLP(nn.Module):
     #: ``alltoall_v``, communicators/mod.rs:632-676) instead of dense
     #: capacity slots.
     #:
-    #: Regime selection, MEASURED on v5e (E=8, k=2, d_model 512 — full
-    #: table in bench.py:bench_moe_dropless): capacity wins below ~12K
-    #: tokens per shard per layer (1.16x at 4K), dropless wins above
-    #: (1.49x at 32K, where capacity's O(T^2/E) dispatch tensor collapses
-    #: it).  The default stays False because the two paths have different
-    #: TRAINING semantics (capacity drops overflow tokens; dropless never
-    #: drops) — switching must be the user's modelling decision, made with
-    #: the perf table in hand.
+    #: Regime selection: the only speed record of capacity against
+    #: dropless is ``bench.py::bench_moe_dropless`` at TOY widths (E=8, k=2,
+    #: d_model 512; "capacity wins below ~12K tokens per shard per layer,
+    #: dropless above") — older than the benchmark, wrong source by PERF.md
+    #: §6 (PR 23), unverified since.  Measured at published widths, through
+    #: ``perfbench`` (cell ``olmoe-1b-7b.pretrain4096-dp1``: E=64, k=8,
+    #: d_model 2048, expert width 1024, 8,192 tokens a step on one v5e):
+    #: PERF.md §5 / §6 (PR 28); no capacity run exists there (its [T, E, C]
+    #: dispatch tensor at these sizes is 8,192 x 64 x 1,280).  The default
+    #: stays False because the two paths have different TRAINING semantics
+    #: (capacity drops overflow tokens; dropless never drops) — switching
+    #: is the user's modelling decision.
     dropless: bool = False
     #: dropless EP transfer via ``lax.ragged_all_to_all`` (exact counts on
     #: the wire).  Off by default: XLA:CPU cannot execute the ragged HLO, so
     #: the virtual-mesh test/dryrun environments use the dense-slot
     #: ``all_to_all`` path; enable on real multi-chip TPU meshes.
     use_ragged: bool = False
+    #: gated experts (OLMoE, Mixtral, DeepSeek): ``(silu(x wg) * (x wi)) wo``
+    #: with the extra leaf ``expert_wg``; False: ``silu(x wi) wo``
+    gated: bool = False
+    #: renormalize the ``k > 1`` winners' probabilities to sum to one (HF
+    #: ``norm_topk_prob``; OLMoE: False).  ``dropless`` only
+    norm_topk_prob: bool = True
+    #: balance loss over all ``k`` assignments of a token (HF's
+    #: ``load_balancing_loss_func``) instead of the top-1 (GShard eq. 4).
+    #: ``dropless`` only
+    balance_over_topk: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -79,10 +96,11 @@ class MoEMLP(nn.Module):
 
         # router in f32 (small, precision-sensitive; reference TopKGate
         # casts to fp32 too, sharded_moe.py:241-303)
-        logits = nn.Dense(
-            self.n_experts, use_bias=False, dtype=jnp.float32,
-            param_dtype=jnp.float32, name="router",
-        )(xt.astype(jnp.float32))
+        with phase_scope("bagua.moe/route"):
+            logits = nn.Dense(
+                self.n_experts, use_bias=False, dtype=jnp.float32,
+                param_dtype=jnp.float32, name="router",
+            )(xt.astype(jnp.float32))
 
         # one definition of the expert weights for both routing paths —
         # always the LOCAL table [n_experts // ep_size, ...]
@@ -95,9 +113,25 @@ class MoEMLP(nn.Module):
             (n_local, self.d_ff, d), self.param_dtype,
         )
 
-        if self.dropless:
-            return self._dropless(xt, logits, wi, wo).reshape(b, s, d)
+        # the gate projection of gated experts; created after wi / wo so
+        # that an ungated layer's parameters are what they always were
+        wg = self.param(
+            "expert_wg", nn.initializers.lecun_normal(batch_axis=(0,)),
+            (n_local, d, self.d_ff), self.param_dtype,
+        ) if self.gated else None
 
+        if self.dropless:
+            return self._dropless(xt, logits, wi, wo, wg).reshape(b, s, d)
+
+        if self.k > 2:
+            raise ValueError(
+                f"the capacity path gates top-1 and top-2 only, not k="
+                f"{self.k}: set dropless=True")
+        if wg is not None or not self.norm_topk_prob or self.balance_over_topk:
+            raise ValueError(
+                "gated experts, norm_topk_prob=False and "
+                "balance_over_topk=True are options of the dropless path: "
+                "set dropless=True")
         capacity = max(1, math.ceil(self.k * tokens * self.capacity_factor
                                     / self.n_experts))
         gate = top1_gating if self.k == 1 else top2_gating
@@ -136,7 +170,28 @@ class MoEMLP(nn.Module):
         y = jnp.einsum("tec,ecd->td", combine.astype(self.dtype), out)
         return y.reshape(b, s, d)
 
-    def _dropless(self, xt, logits, wi, wo):
+    def _experts(self, rows, sizes, wi, wo, wg):
+        """The expert FFN on rows grouped by (local) expert: two grouped
+        matmuls, three where the experts are gated."""
+        from ...ops.gmm import gmm, kernel_rows
+        from ...telemetry import counters
+
+        if not self.is_initializing():
+            # what the kernels really multiply: under ``ep`` the rows of the
+            # worst-case receive buffer, padded
+            counters.set_gauge(
+                "moe/padded_rows_per_step",
+                kernel_rows(rows.shape[0], sizes.shape[0], rows.shape[1],
+                            self.d_ff))
+        with phase_scope("bagua.moe/experts"):
+            h = gmm(rows, wi.astype(self.dtype), sizes)
+            if wg is None:
+                h = nn.silu(h)
+            else:
+                h = nn.silu(gmm(rows, wg.astype(self.dtype), sizes)) * h
+            return gmm(h, wo.astype(self.dtype), sizes)
+
+    def _dropless(self, xt, logits, wi, wo, wg=None):
         """Sort-by-expert + grouped matmul: every routed (token, expert)
         pair is computed — the capacity-overflow drops of the GShard path
         (sharded_moe.py:93-238) cannot happen.
@@ -147,42 +202,59 @@ class MoEMLP(nn.Module):
         experts (worst-case receive buffer: every peer routes all its rows
         here).  Expert outputs ride the symmetric reverse transfer back to
         their source rows, and gates are applied at the source.
+
+        The compiled step reads by phase: ``bagua.moe/route`` (router,
+        softmax, top-k, balance loss), ``/dispatch`` (sort, gather),
+        ``/experts`` (the grouped matmuls, their padded layout, the gate),
+        ``/combine`` (weight, scatter-add).
         """
-        from ...ops.gmm import gmm
+        from ...telemetry import counters
         from .gating import topk_routing
 
         n_local = self.n_experts // self.ep_size
-        eidx, gates, l_aux = topk_routing(logits, self.k)
+        with phase_scope("bagua.moe/route"):
+            eidx, gates, l_aux = topk_routing(
+                logits, self.k, renormalize=self.norm_topk_prob,
+                balance_over_topk=self.balance_over_topk)
         self.sow("intermediates", "l_aux", l_aux)
 
-        flat_e = eidx.reshape(-1)                       # [T*k]
-        order = jnp.argsort(flat_e)                     # stable: ties by token
-        token_of_row = order // self.k
-        x_rows = xt[token_of_row].astype(self.dtype)    # [T*k, d] grouped
-        e_rows = flat_e[order]
+        with phase_scope("bagua.moe/dispatch"):
+            flat_e = eidx.reshape(-1)                   # [T*k]
+            order = jnp.argsort(flat_e)                 # stable: ties by token
+            token_of_row = order // self.k
+            x_rows = xt[token_of_row].astype(self.dtype)  # [T*k, d] grouped
+            e_rows = flat_e[order]
+        if not self.is_initializing():
+            # trace-time facts of this layer's step (not of ``init``'s stub
+            # batch), for the operator and the benchmark's moe_padding_share
+            counters.set_gauge("moe/experts", n_local)
+            counters.set_gauge("moe/rows_per_step", x_rows.shape[0])
 
         inside_mesh = self.ep_size > 1 and _axis_bound(self.axis_name)
         if inside_mesh:
-            y_rows = self._dropless_exchange(x_rows, e_rows, wi, wo, n_local)
+            y_rows = self._dropless_exchange(x_rows, e_rows, wi, wo, wg,
+                                             n_local)
         else:
             # single shard — or the init trace outside shard_map, where only
             # shapes matter: fold global expert ids onto the local table
-            eid = e_rows if self.ep_size == 1 else e_rows % n_local
-            sizes = jnp.bincount(eid, length=n_local)
-            local_order = jnp.argsort(eid) if self.ep_size > 1 else None
-            rows = x_rows if local_order is None else x_rows[local_order]
-            h = nn.silu(gmm(rows, wi.astype(self.dtype), sizes))
-            y = gmm(h, wo.astype(self.dtype), sizes)
+            with phase_scope("bagua.moe/dispatch"):
+                eid = e_rows if self.ep_size == 1 else e_rows % n_local
+                sizes = jnp.bincount(eid, length=n_local)
+                local_order = jnp.argsort(eid) if self.ep_size > 1 else None
+                rows_in = (x_rows if local_order is None
+                           else x_rows[local_order])
+            y = self._experts(rows_in, sizes, wi, wo, wg)
             if local_order is None:
                 y_rows = y
             else:
                 y_rows = jnp.zeros_like(y).at[local_order].set(y)
 
-        w = gates.reshape(-1)[order].astype(self.dtype)
-        out = jnp.zeros((xt.shape[0], xt.shape[1]), self.dtype)
-        return out.at[token_of_row].add(y_rows * w[:, None])
+        with phase_scope("bagua.moe/combine"):
+            w = gates.reshape(-1)[order].astype(self.dtype)
+            out = jnp.zeros((xt.shape[0], xt.shape[1]), self.dtype)
+            return out.at[token_of_row].add(y_rows * w[:, None])
 
-    def _dropless_exchange(self, x_rows, e_rows, wi, wo, n_local):
+    def _dropless_exchange(self, x_rows, e_rows, wi, wo, wg, n_local):
         """EP dispatch for dropless routing: [T*k, d] rows grouped by global
         expert → owning shards → local grouped matmul → reverse transfer.
 
@@ -248,10 +320,7 @@ class MoEMLP(nn.Module):
         # sort last, fall outside the grouped range, and are zero
         local_order = jnp.argsort(lid_recv)
         rows = x_recv[local_order]
-        from ...ops.gmm import gmm
-
-        h = nn.silu(gmm(rows, wi.astype(self.dtype), sizes))
-        y_sorted = gmm(h, wo.astype(self.dtype), sizes)
+        y_sorted = self._experts(rows, sizes, wi, wo, wg)
         y_local = jnp.zeros_like(y_sorted).at[local_order].set(y_sorted)
 
         # reverse transfer over the same slots, then gather my rows back
@@ -276,7 +345,7 @@ class MoEMLP(nn.Module):
 # ``param.expert = True`` flags (experts.py:26-29) — never by substring, so a
 # user param that merely contains "expert" in its name can't be silently
 # pulled out of the data-parallel plan.
-EXPERT_PARAM_NAMES = frozenset({"expert_wi", "expert_wo"})
+EXPERT_PARAM_NAMES = frozenset({"expert_wi", "expert_wo", "expert_wg"})
 
 
 def is_expert_param(name: str) -> bool:

@@ -85,6 +85,17 @@ class TransformerConfig:
     #: (masked writes of inactive slots land there) — the serving
     #: allocator never hands either out.
     num_pages: int = 0
+    #: rotary position embedding base (RoPE, rotate-half over the whole
+    #: head).  None keeps the learned absolute ``pos_embed`` table; set,
+    #: ``TransformerLM`` creates no table and ``Attention`` rotates q and k
+    #: (sin/cos in f32, positions offset by the ``sp_axis`` chunk like the
+    #: table).  The decode paths do not implement it.
+    rope_theta: Optional[float] = None
+    #: RMSNorm on the flat ``n_heads * head_dim`` wide q and k, before the
+    #: head split (OLMoE's ``q_norm`` / ``k_norm``)
+    qk_norm: bool = False
+    #: epsilon of every RMSNorm of the model
+    norm_eps: float = 1e-6
 
     @property
     def head_dim(self) -> int:
@@ -106,6 +117,7 @@ def bert_large_config(**kw) -> TransformerConfig:
 class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
+    eps: float = 1e-6
 
     @nn.compact
     def __call__(self, x):
@@ -114,8 +126,25 @@ class RMSNorm(nn.Module):
         )
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        y = x32 * jax.lax.rsqrt(var + 1e-6)
+        y = x32 * jax.lax.rsqrt(var + self.eps)
         return (y * scale).astype(self.dtype)
+
+
+def rope_rotate(x, theta: float, start=0):
+    """Rotary position embedding, rotate-half form (GPT-NeoX / HF): the
+    head's two halves are the pairs, every one of its ``head_dim`` lanes is
+    rotated.  ``x``: [batch, seq, heads, head_dim] at positions ``start ..
+    start + seq - 1``; angles, sin and cos in f32, result in ``x.dtype``."""
+    seq, d = x.shape[1], x.shape[3]
+    inv_freq = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
+    pos = (jnp.arange(seq, dtype=jnp.int32) + start).astype(jnp.float32)
+    angles = pos[:, None] * inv_freq[None, :]                 # [seq, d/2]
+    angles = jnp.concatenate([angles, angles], axis=-1)       # [seq, d]
+    cos, sin = jnp.cos(angles)[None, :, None], jnp.sin(angles)[None, :, None]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., : d // 2], x32[..., d // 2:]
+    rotated = jnp.concatenate([-x2, x1], axis=-1)
+    return (x32 * cos + rotated * sin).astype(x.dtype)
 
 
 def causal_attention(q, k, v, dtype):
@@ -177,6 +206,24 @@ class Attention(nn.Module):
             param_dtype=cfg.param_dtype, use_bias=False,
         )
         q, k, v = (checkpoint_name(dense(n)(x), KEPT_QKV) for n in "qkv")
+        if cfg.qk_norm:
+            if _tp_active(cfg):
+                raise NotImplementedError(
+                    "qk_norm normalizes over all heads; they are sharded "
+                    "under tensor parallelism")
+            flat_norm = lambda name, t: RMSNorm(
+                cfg.dtype, cfg.param_dtype, cfg.norm_eps, name=name,
+            )(t.reshape(*t.shape[:-2], h * d)).reshape(t.shape)
+            q, k = flat_norm("q_norm", q), flat_norm("k_norm", k)
+        if cfg.rope_theta is not None:
+            if cfg.decode:
+                raise NotImplementedError(
+                    "rope_theta is not implemented for the decode paths")
+            start = 0
+            if cfg.sp_axis is not None and _axis_bound(cfg.sp_axis):
+                start = jax.lax.axis_index(cfg.sp_axis) * q.shape[1]
+            q = rope_rotate(q, cfg.rope_theta, start)
+            k = rope_rotate(k, cfg.rope_theta, start)
         if cfg.decode and cfg.page_size > 0:
             o = self._paged_decode_attend(q, k, v, slots)
         elif cfg.decode:
@@ -345,12 +392,14 @@ class Block(nn.Module):
     @nn.compact
     def __call__(self, x, slots=None):
         cfg = self.cfg
-        y = RMSNorm(cfg.dtype, cfg.param_dtype, name="attn_norm")(x)
+        y = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
+                    name="attn_norm")(x)
         attn = Attention(cfg, self.attn_fn, name="attn")
         # dense/training call sites keep their exact one-arg form (the
         # goldens pin those programs); only paged decode threads slots
         x = x + (attn(y) if slots is None else attn(y, slots))
-        y = RMSNorm(cfg.dtype, cfg.param_dtype, name="mlp_norm")(x)
+        y = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
+                    name="mlp_norm")(x)
         mlp = self.mlp() if self.mlp is not None else MLPBlock(cfg, name="mlp")
         x = x + mlp(y)
         return x
@@ -376,41 +425,42 @@ class TransformerLM(nn.Module):
             cfg.vocab_size, cfg.d_model, name="embed",
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
         )(tokens)
-        pos = self.param(
-            "pos_embed", nn.initializers.normal(0.02),
-            (cfg.max_seq_len, cfg.d_model), cfg.param_dtype,
-        )
-        s = tokens.shape[1]
-        if cfg.decode and cfg.page_size > 0:
-            # paged decode: every slot sits at its OWN position (continuous
-            # batching admits requests mid-flight), so the position comes
-            # from the scheduler's per-slot lengths, not a shared counter.
-            # During init (no slots yet) position 0 stands in.
-            if slots is None:
-                pos_ids = jnp.zeros((tokens.shape[0], s), jnp.int32)
+        if cfg.rope_theta is None:  # learned absolute positions
+            pos = self.param(
+                "pos_embed", nn.initializers.normal(0.02),
+                (cfg.max_seq_len, cfg.d_model), cfg.param_dtype,
+            )
+            s = tokens.shape[1]
+            if cfg.decode and cfg.page_size > 0:
+                # paged decode: every slot sits at its OWN position (continuous
+                # batching admits requests mid-flight), so the position comes
+                # from the scheduler's per-slot lengths, not a shared counter.
+                # During init (no slots yet) position 0 stands in.
+                if slots is None:
+                    pos_ids = jnp.zeros((tokens.shape[0], s), jnp.int32)
+                else:
+                    pos_ids = (slots["lengths"][:, None]
+                               + jnp.arange(s, dtype=jnp.int32)[None, :])
+                # pos[idx] equals the dense path's dynamic_slice row for the
+                # same position — elementwise identical, per slot
+                pos_slice = jnp.take(pos, pos_ids, axis=0)  # [b, s, d_model]
+                x = x + pos_slice.astype(cfg.dtype)
             else:
-                pos_ids = (slots["lengths"][:, None]
-                           + jnp.arange(s, dtype=jnp.int32)[None, :])
-            # pos[idx] equals the dense path's dynamic_slice row for the
-            # same position — elementwise identical, per slot
-            pos_slice = jnp.take(pos, pos_ids, axis=0)  # [b, s, d_model]
-            x = x + pos_slice.astype(cfg.dtype)
-        else:
-            start = 0
-            if cfg.sp_axis is not None and _axis_bound(cfg.sp_axis):
-                start = jax.lax.axis_index(cfg.sp_axis) * s
-            if cfg.decode:
-                # autoregressive position counter (mirrors the attention
-                # cache; same init-pass guard — see Attention._decode_attend)
-                advance = self.has_variable("cache", "pos_index")
-                pos_index = self.variable(
-                    "cache", "pos_index", lambda: jnp.zeros((), jnp.int32)
-                )
-                if advance:
-                    start = pos_index.value
-                    pos_index.value = start + s
-            pos_slice = jax.lax.dynamic_slice_in_dim(pos, start, s, axis=0)
-            x = x + pos_slice[None].astype(cfg.dtype)
+                start = 0
+                if cfg.sp_axis is not None and _axis_bound(cfg.sp_axis):
+                    start = jax.lax.axis_index(cfg.sp_axis) * s
+                if cfg.decode:
+                    # autoregressive position counter (mirrors the attention
+                    # cache; same init-pass guard — see Attention._decode_attend)
+                    advance = self.has_variable("cache", "pos_index")
+                    pos_index = self.variable(
+                        "cache", "pos_index", lambda: jnp.zeros((), jnp.int32)
+                    )
+                    if advance:
+                        start = pos_index.value
+                        pos_index.value = start + s
+                pos_slice = jax.lax.dynamic_slice_in_dim(pos, start, s, axis=0)
+                x = x + pos_slice[None].astype(cfg.dtype)
         for i in range(cfg.n_layers):
             mlp = self.mlp_factory(i) if self.mlp_factory is not None else None
             block_cls = Block
@@ -420,7 +470,8 @@ class TransformerLM(nn.Module):
                 block_cls = remat_wrap(Block, cfg.remat_policy, own)
             blk = block_cls(cfg, self.attn_fn, mlp, name=f"block_{i}")
             x = blk(x) if slots is None else blk(x, slots)
-        x = RMSNorm(cfg.dtype, cfg.param_dtype, name="final_norm")(x)
+        x = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
+                    name="final_norm")(x)
         if not self.head:
             return x.astype(jnp.float32)
         logits = nn.Dense(

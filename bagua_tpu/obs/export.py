@@ -108,6 +108,17 @@ _declare("comm/buckets_per_step", "gauge",
          "when the comm world is one rank), set when a step program is "
          "built.  What XLA's collective combiner makes of them is a count "
          "over the compiled text (the benchmark's comm_calls_compiled).")
+# -- mixture of experts (set when a step with a dropless MoEMLP is traced) --
+_declare("moe/experts", "gauge",
+         "Experts held by this rank in the MoE layer last traced.")
+_declare("moe/rows_per_step", "gauge",
+         "Rows one dropless MoE layer routes per step on this rank: tokens "
+         "x experts per token.")
+_declare("moe/padded_rows_per_step", "gauge",
+         "Rows each of that layer's grouped matmuls really multiplies: the "
+         "block-aligned padded layout of ops/gmm.py where the kernel runs, "
+         "the routed rows where its dense fallback does.  1 - rows / "
+         "padded is the benchmark's moe_padding_share.")
 _declare("comm/aborts", "counter",
          "Cooperative abort flag raises (watchdog fire, grad-guard abort, "
          "user abort()).")

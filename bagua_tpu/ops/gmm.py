@@ -51,13 +51,19 @@ def _round_up(x, m):
     return -(-x // m) * m
 
 
+def _padded_rows(rows: int, n_groups: int, block: int) -> int:
+    """Static row count of the padded layout: every group may waste up to
+    ``block - 1`` rows."""
+    return _round_up(rows + n_groups * (block - 1), block)
+
+
 def _padded_layout(group_sizes, rows: int, n_groups: int, block: int):
     """Map ragged rows to block-aligned padded slots.
 
     Returns (pos [rows] padded position per row, g_of_block [n_blocks],
     padded_rows static int).
     """
-    padded_rows = _round_up(rows + n_groups * (block - 1), block)
+    padded_rows = _padded_rows(rows, n_groups, block)
     sizes = group_sizes.astype(jnp.int32)
     offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
     padded = _round_up(sizes, block)
@@ -188,6 +194,26 @@ def _gmm_vjp_bwd(block_rows, block_f, interpret, res, gout):
 _gmm.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
 
 
+def _use_kernel(rows: int, d: int, f: int, block_rows: int) -> bool:
+    return (
+        jax.default_backend() == "tpu"
+        and d % 128 == 0
+        and f % 128 == 0
+        and rows >= block_rows
+    )
+
+
+def kernel_rows(rows: int, n_groups: int, d: int, f: int, *,
+                block_rows: int = _BLOCK_ROWS) -> int:
+    """Rows one :func:`gmm` call of these shapes really multiplies: the
+    block-aligned padded layout's static row count where the kernel runs
+    (every group rounded up to ``block_rows``, at the worst case of the
+    group sizes), ``rows`` where the dense fallback does."""
+    if not _use_kernel(rows, d, f, block_rows):
+        return rows
+    return _padded_rows(rows, n_groups, block_rows)
+
+
 def gmm(lhs, rhs, group_sizes, *, block_rows: int = _BLOCK_ROWS,
         block_f: int = _BLOCK_F, interpret: bool = False,
         force: bool = False):
@@ -200,12 +226,6 @@ def gmm(lhs, rhs, group_sizes, *, block_rows: int = _BLOCK_ROWS,
     """
     rows, d = lhs.shape
     f = rhs.shape[2]
-    use_kernel = force or (
-        jax.default_backend() == "tpu"
-        and d % 128 == 0
-        and f % 128 == 0
-        and rows >= block_rows
-    )
-    if not use_kernel:
+    if not (force or _use_kernel(rows, d, f, block_rows)):
         return gmm_reference(lhs, rhs, group_sizes)
     return _gmm(lhs, rhs, group_sizes, block_rows, block_f, interpret)

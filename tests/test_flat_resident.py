@@ -339,6 +339,32 @@ def test_rebucket_migrates_resident_state():
     np.testing.assert_array_equal(np.array(losses), base)
 
 
+@pytest.mark.parametrize("donate", [True, False])
+def test_rebucket_migration_consumes_the_old_flats(donate):
+    """The migration in front of a donating step frees the old plan's
+    buffers itself: a caller that still names the old state (a driver's
+    ``job.state``) must not keep a second copy of it on the device while
+    the recompiled step loads.  Without donation the old state stays."""
+    trainer = BaguaTrainer(
+        _loss_fn, optax.adam(1e-2), GradientAllReduceAlgorithm(),
+        bucket_bytes=256, autotune=False, flat_resident="on", donate=donate,
+    )
+    state = trainer.init(_params())
+    state, _ = trainer.train_step(state, _batches(1)[0])
+    held = state  # what a caller may still hold across the step
+    decls = [t.declaration() for b in trainer._plan.buckets
+             for t in b.tensors]
+    trainer.rebucket(split_bucket_by_bucket_size(decls, 1024))
+    migrated = trainer._pending_state_migration(held)
+    old = [f for tree in (held.params, held.opt_state)
+           for x in jax.tree.leaves(tree, is_leaf=trainer._is_flat_container)
+           if trainer._is_flat_container(x) for f in x["flats"]]
+    assert old and all(f.is_deleted() == donate for f in old)
+    trainer._pending_state_migration = None
+    state, loss = trainer.train_step(migrated, _batches(1)[0])
+    assert np.isfinite(float(loss))
+
+
 def test_rebucket_migrates_gossip_peer_state():
     """Plan-keyed algorithm state (tracked peer weights) migrates through
     the Algorithm.relayout_algo_state hook — stacked rank axis included."""
