@@ -8,10 +8,14 @@ all-to-all → local experts → all-to-all → einsum combine) + experts.py
 TPU-first shape: the all-to-all is ``lax.all_to_all`` over an ``'ep'`` mesh
 axis inside the jitted step (the reference drives
 ``torch.distributed.all_to_all_single`` from autograd, sharded_moe.py:77-90);
-expert weights live as one leaf ``[n_experts, ...]`` sharded over ``'ep'``,
-batched per-expert matmuls run on the MXU via a single einsum.  Parameters
-whose name contains ``"expert"`` are excluded from the data-parallel bucket
-plan by the trainer (the analog of ``param.expert`` flags).
+expert weights live as one leaf ``[n_experts, ...]`` sharded over ``'ep'``.
+Two routings share them: the capacity path (dense ``[T, E, C]`` dispatch
+and combine einsums around batched per-expert matmuls) and the dropless
+path (rows sorted by expert through the grouped-matmul kernels of
+:mod:`bagua_tpu.ops.gmm`, resident in their padded layout from dispatch to
+combine).  Parameters whose name contains ``"expert"`` are excluded from
+the data-parallel bucket plan by the trainer (the analog of
+``param.expert`` flags).
 """
 
 from __future__ import annotations
@@ -37,6 +41,14 @@ class MoEMLP(nn.Module):
     ``n_experts // ep_size`` experts.  Outside shard_map (e.g. ``model.init``)
     the all-to-all is skipped and only the local expert slice is computed —
     parameter shapes are identical, so init-outside / apply-inside works.
+
+    ``dropless=False`` (default) is the GShard capacity path: top-1 / top-2
+    gates, a dense ``[T, E, C]`` dispatch einsum, one batched einsum per
+    expert matmul, overflow tokens dropped.  ``dropless=True`` computes every
+    routed (token, expert) pair for any ``k`` (:meth:`_dropless`): the rows
+    are gathered once into the grouped-matmul kernels' padded layout, the
+    expert FFN (gated or not) runs there, and each token gathers its ``k``
+    rows back out.
     """
 
     n_experts: int
@@ -170,89 +182,115 @@ class MoEMLP(nn.Module):
         y = jnp.einsum("tec,ecd->td", combine.astype(self.dtype), out)
         return y.reshape(b, s, d)
 
-    def _experts(self, rows, sizes, wi, wo, wg):
-        """The expert FFN on rows grouped by (local) expert: two grouped
-        matmuls, three where the experts are gated."""
-        from ...ops.gmm import gmm, kernel_rows
+    def _experts(self, x_p, layout, wi, wo, wg):
+        """The expert FFN on rows resident in ``layout`` (grouped by local
+        expert, padding rows zero): two grouped matmuls, three where the
+        experts are gated, and the elementwise work between them, all on
+        padded rows — ``silu(0) * 0`` keeps the padding rows zero.  What
+        the backward pass keeps is ``x_p``, the in-projections and the
+        result; the hidden rows are rebuilt there (one elementwise pass)."""
+        from ...ops.gmm import gmm_padded
         from ...telemetry import counters
 
         if not self.is_initializing():
-            # what the kernels really multiply: under ``ep`` the rows of the
-            # worst-case receive buffer, padded
-            counters.set_gauge(
-                "moe/padded_rows_per_step",
-                kernel_rows(rows.shape[0], sizes.shape[0], rows.shape[1],
-                            self.d_ff))
+            # what the kernels really multiply (under ``ep`` the rows of
+            # the worst-case receive buffer, padded), and whether they run
+            counters.set_gauge("moe/padded_rows_per_step", x_p.shape[0])
+            counters.set_gauge("moe/padded_resident_layers",
+                               int(layout.block_rows > 1))
+
+        @jax.checkpoint
+        def down(up, gate, wo):
+            h = nn.silu(up) if gate is None else nn.silu(gate) * up
+            return gmm_padded(h, wo, layout)
+
         with phase_scope("bagua.moe/experts"):
-            h = gmm(rows, wi.astype(self.dtype), sizes)
-            if wg is None:
-                h = nn.silu(h)
-            else:
-                h = nn.silu(gmm(rows, wg.astype(self.dtype), sizes)) * h
-            return gmm(h, wo.astype(self.dtype), sizes)
+            up = gmm_padded(x_p, wi.astype(self.dtype), layout)
+            gate = (None if wg is None
+                    else gmm_padded(x_p, wg.astype(self.dtype), layout))
+            return down(up, gate, wo.astype(self.dtype))
 
     def _dropless(self, xt, logits, wi, wo, wg=None):
         """Sort-by-expert + grouped matmul: every routed (token, expert)
         pair is computed — the capacity-overflow drops of the GShard path
         (sharded_moe.py:93-238) cannot happen.
 
+        The routed rows enter the grouped-matmul kernels' padded layout
+        (:class:`bagua_tpu.ops.gmm.PaddedLayout`, computed once a layer)
+        by ONE gather straight from the tokens, stay there through the
+        expert FFN, and leave by ONE gather: each token reads its ``k``
+        rows, weights them by its gates and sums them in float32.  Both
+        maps are injective and carried with their inverses (``slots``:
+        routed pair -> padded slot, ``reader``: padded slot -> routed
+        pair), so the backward pass is gathers too — no scatter of rows in
+        either direction.  Off the TPU, or at shapes the kernels do not
+        take, the layout is the sorted rows themselves and the products are
+        the dense reference's (:func:`bagua_tpu.ops.gmm.kernel_layout`).
+
         With expert parallelism the exchange is a ragged all-to-all with
         exact counts: rows sorted by global expert are already grouped by
         owning shard, so shard p receives only the rows routed to its
         experts (worst-case receive buffer: every peer routes all its rows
-        here).  Expert outputs ride the symmetric reverse transfer back to
-        their source rows, and gates are applied at the source.
+        here).  The receiver pads once from its receive buffer and unpads
+        once into it; expert outputs ride the symmetric reverse transfer
+        back to their source rows, and gates are applied at the source.
 
         The compiled step reads by phase: ``bagua.moe/route`` (router,
-        softmax, top-k, balance loss), ``/dispatch`` (sort, gather),
-        ``/experts`` (the grouped matmuls, their padded layout, the gate),
-        ``/combine`` (weight, scatter-add).
+        softmax, top-k, balance loss), ``/dispatch`` (sort, the layout and
+        its index maps, the gather in; backward: the sum over a token's
+        ``k`` rows), ``/experts`` (the grouped matmuls, the gate, the
+        hidden rows rebuilt in the backward pass), ``/combine`` (the
+        weighted gather out; backward: the gather of the output's
+        cotangent into the layout and the gates' gradient).
         """
+        from ...ops.gmm import kernel_layout, pad_rows
         from ...telemetry import counters
         from .gating import topk_routing
 
         n_local = self.n_experts // self.ep_size
+        tokens, k = xt.shape[0], self.k
         with phase_scope("bagua.moe/route"):
             eidx, gates, l_aux = topk_routing(
-                logits, self.k, renormalize=self.norm_topk_prob,
+                logits, k, renormalize=self.norm_topk_prob,
                 balance_over_topk=self.balance_over_topk)
         self.sow("intermediates", "l_aux", l_aux)
-
-        with phase_scope("bagua.moe/dispatch"):
-            flat_e = eidx.reshape(-1)                   # [T*k]
-            order = jnp.argsort(flat_e)                 # stable: ties by token
-            token_of_row = order // self.k
-            x_rows = xt[token_of_row].astype(self.dtype)  # [T*k, d] grouped
-            e_rows = flat_e[order]
         if not self.is_initializing():
             # trace-time facts of this layer's step (not of ``init``'s stub
             # batch), for the operator and the benchmark's moe_padding_share
             counters.set_gauge("moe/experts", n_local)
-            counters.set_gauge("moe/rows_per_step", x_rows.shape[0])
+            counters.set_gauge("moe/rows_per_step", tokens * k)
 
         inside_mesh = self.ep_size > 1 and _axis_bound(self.axis_name)
+        with phase_scope("bagua.moe/dispatch"):
+            flat_e = eidx.reshape(-1)                   # [T*k] routed pairs
+            if self.ep_size > 1 and not inside_mesh:
+                # the init trace outside shard_map, where only shapes
+                # matter: fold global expert ids onto the local table
+                flat_e = flat_e % n_local
+            order = jnp.argsort(flat_e)                 # stable: ties by token
+            rank = _inverse_permutation(order)          # pair -> sorted row
+            x = xt.astype(self.dtype)
         if inside_mesh:
-            y_rows = self._dropless_exchange(x_rows, e_rows, wi, wo, wg,
-                                             n_local)
-        else:
-            # single shard — or the init trace outside shard_map, where only
-            # shapes matter: fold global expert ids onto the local table
+            # the rows travel sorted; the combine reads them back as such
+            slots, reader = rank.reshape(tokens, k), order
             with phase_scope("bagua.moe/dispatch"):
-                eid = e_rows if self.ep_size == 1 else e_rows % n_local
-                sizes = jnp.bincount(eid, length=n_local)
-                local_order = jnp.argsort(eid) if self.ep_size > 1 else None
-                rows_in = (x_rows if local_order is None
-                           else x_rows[local_order])
-            y = self._experts(rows_in, sizes, wi, wo, wg)
-            if local_order is None:
-                y_rows = y
-            else:
-                y_rows = jnp.zeros_like(y).at[local_order].set(y)
-
+                x_rows = pad_rows(x, order // k, slots)
+            y = self._dropless_exchange(x_rows, flat_e[order], wi, wo, wg,
+                                        n_local)
+        else:
+            with phase_scope("bagua.moe/dispatch"):
+                # rows per expert by compare-and-sum, not ``bincount``'s
+                # scatter-add of 65,536 ones
+                sizes = (flat_e[:, None] == jnp.arange(n_local)[None, :]).sum(
+                    0, dtype=jnp.int32)
+                layout = kernel_layout(sizes, tokens * k, x.shape[1],
+                                       self.d_ff)
+                reader = _take_index(order, layout.src)
+                slots = layout.pos[rank].reshape(tokens, k)
+                x_p = pad_rows(x, reader // k, slots)
+            y = self._experts(x_p, layout, wi, wo, wg)
         with phase_scope("bagua.moe/combine"):
-            w = gates.reshape(-1)[order].astype(self.dtype)
-            out = jnp.zeros((xt.shape[0], xt.shape[1]), self.dtype)
-            return out.at[token_of_row].add(y_rows * w[:, None])
+            return _combine(y, gates, slots, reader)
 
     def _dropless_exchange(self, x_rows, e_rows, wi, wo, wg, n_local):
         """EP dispatch for dropless routing: [T*k, d] rows grouped by global
@@ -316,12 +354,18 @@ class MoEMLP(nn.Module):
                 x_send.reshape(ep, tk, d), ax, 0, 0, tiled=False
             ).reshape(cap, d)
 
-        # group received rows by local expert; sentinel (empty-slot) rows
-        # sort last, fall outside the grouped range, and are zero
-        local_order = jnp.argsort(lid_recv)
-        rows = x_recv[local_order]
-        y_sorted = self._experts(rows, sizes, wi, wo, wg)
-        y_local = jnp.zeros_like(y_sorted).at[local_order].set(y_sorted)
+        # group received rows by local expert, straight into the layout:
+        # sentinel (empty-slot) rows sort last, fall outside the grouped
+        # range and are not carried in; their slots read back zero
+        from ...ops.gmm import kernel_layout, pad_rows, unpad_rows
+
+        local_order = jnp.argsort(lid_recv)             # sorted row -> slot
+        layout = kernel_layout(sizes, cap, d, self.d_ff)
+        reader = _take_index(local_order, layout.src)   # padded -> receive
+        slots = layout.pos[_inverse_permutation(local_order)]
+        x_p = pad_rows(x_recv, reader, slots[:, None])
+        y_p = self._experts(x_p, layout, wi, wo, wg)
+        y_local = unpad_rows(y_p, slots, reader)
 
         # reverse transfer over the same slots, then gather my rows back
         if self.use_ragged:
@@ -338,6 +382,49 @@ class MoEMLP(nn.Module):
             y_local.reshape(ep, tk, d), ax, 0, 0, tiled=False
         ).reshape(cap, d)
         return y_back[slot]
+
+
+def _inverse_permutation(perm):
+    """``inv[perm[i]] = i``: a scatter over an int32 index vector, the only
+    kind of scatter the dropless path emits."""
+    iota = jnp.arange(perm.shape[0], dtype=perm.dtype)
+    return jnp.zeros_like(perm).at[perm].set(iota, unique_indices=True)
+
+
+def _take_index(index, src):
+    """``index[src]`` where ``src`` may point one past the end (a padding
+    slot of the layout): those read ``len(index)``, out of range in turn."""
+    return jnp.take(index, src, mode="fill", fill_value=index.shape[0])
+
+
+@jax.custom_vjp
+def _combine(y, gates, slots, reader):
+    """``out[t] = sum_j gates[t, j] * y[slots[t, j]]``, accumulated in
+    float32 and rounded once.  ``reader`` [len(y)] is the routed pair
+    ``t * k + j`` that reads each row of ``y`` (``T * k``, one past the
+    end, for a padding row): with it the transpose is a gather of ``out``'s
+    cotangent into ``y``'s layout, where the gates' gradient is a row
+    sum."""
+    rows = y[slots].astype(jnp.float32)
+    return (rows * gates[..., None]).sum(1).astype(y.dtype)
+
+
+def _combine_fwd(y, gates, slots, reader):
+    return _combine(y, gates, slots, reader), (y, gates, slots, reader)
+
+
+def _combine_bwd(res, g):
+    from ...ops.gmm import take_or_zero
+
+    y, gates, slots, reader = res
+    g_rows = take_or_zero(g, reader // slots.shape[1]).astype(jnp.float32)
+    w = take_or_zero(gates.reshape(-1), reader)
+    d_gates = (g_rows * y.astype(jnp.float32)).sum(-1)[slots]
+    return ((g_rows * w[:, None]).astype(y.dtype), d_gates.astype(gates.dtype),
+            None, None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 # The exact parameter names MoEMLP creates.  Marking is by path *segment*

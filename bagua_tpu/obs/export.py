@@ -119,6 +119,11 @@ _declare("moe/padded_rows_per_step", "gauge",
          "block-aligned padded layout of ops/gmm.py where the kernel runs, "
          "the routed rows where its dense fallback does.  1 - rows / "
          "padded is the benchmark's moe_padding_share.")
+_declare("moe/padded_resident_layers", "gauge",
+         "1 where the rows of the dropless MoE layer last traced stay in "
+         "that padded layout from dispatch to combine (one gather in, the "
+         "expert FFN on padded rows, one gather out): the kernels run.  0 "
+         "where the layer runs the dense fallback on the sorted rows.")
 _declare("comm/aborts", "counter",
          "Cooperative abort flag raises (watchdog fire, grad-guard abort, "
          "user abort()).")

@@ -8,20 +8,32 @@ matter how skewed the routing — the fix for GShard capacity overflow
 (the reference's gate drops tokens past ``capacity``,
 /root/reference/bagua/torch_api/model_parallel/moe/sharded_moe.py:93-238).
 
-TPU-first design: ragged row groups are scattered into block-aligned slots
-(each group padded up to the 128-row MXU tile), after which every row block
+TPU-first design: the kernels work on a block-aligned PADDED layout (each
+group's rows rounded up to the 128-row MXU tile), in which every row block
 belongs to exactly ONE group — a scalar-prefetched per-block group id then
 steers the ``rhs`` BlockSpec, so each grid step is a single dense MXU matmul
 with no masking.  The dK accumulation kernel walks row blocks innermost and
 revisits its (group, d, f) output block across consecutive steps, the
-standard Pallas accumulation pattern.  Backward is a custom VJP: d_lhs is
-the same kernel with ``rhs`` transposed; d_rhs is the grouped outer-product
-kernel.  Padded rows are zero, so they contribute nothing to any reduction.
+standard Pallas accumulation pattern.
+
+The layout is a value of its own (:class:`PaddedLayout`, from the group
+sizes alone), not a detail of one call: a run of products over the same
+groups — an expert FFN's two or three, with elementwise work between them —
+computes it once, moves its rows in once (:func:`pad_rows`: a gather,
+whose transpose is a gather through the layout's inverse map), multiplies
+in padded space (:func:`gmm_padded`) and moves the result out once
+(:func:`unpad_rows`).
+``gmm_padded``'s custom VJP stays in padded space: d_lhs is the forward
+kernel on the transposed matrices, d_rhs the grouped outer-product kernel,
+neither with a scatter or a gather.  Padding rows are zero going in, so
+they come out zero and contribute nothing to any reduction.  ``gmm`` is
+pad -> ``gmm_padded`` -> unpad for one product.
 """
 
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -57,25 +69,111 @@ def _padded_rows(rows: int, n_groups: int, block: int) -> int:
     return _round_up(rows + n_groups * (block - 1), block)
 
 
-def _padded_layout(group_sizes, rows: int, n_groups: int, block: int):
-    """Map ragged rows to block-aligned padded slots.
+class PaddedLayout(NamedTuple):
+    """Where rows sorted by group live in the kernels' block-aligned layout.
 
-    Returns (pos [rows] padded position per row, g_of_block [n_blocks],
-    padded_rows static int).
+    ``pos`` [rows]: padded slot of each row; ``src`` [padded rows]: row of
+    each slot, ``rows`` (one past the end: :func:`pad_rows` fills zero) for
+    a padding slot; ``g_of_block`` [padded rows / block]: group of each row
+    block; ``sizes`` [groups].  ``pos`` and ``src`` are each other's
+    inverse, so rows move in and out by gathers in both directions of
+    autodiff.  Rows beyond ``sizes.sum()`` (an expert-parallel receive
+    buffer's empty slots) are not carried in: their ``pos`` points at
+    trailing padding slots, which hold zero.
     """
-    padded_rows = _padded_rows(rows, n_groups, block)
+
+    pos: jax.Array
+    src: jax.Array
+    g_of_block: jax.Array
+    sizes: jax.Array
+
+    @property
+    def block_rows(self) -> int:
+        return self.src.shape[0] // self.g_of_block.shape[0]
+
+
+def padded_layout(group_sizes, rows: int, *,
+                  block_rows: int = _BLOCK_ROWS) -> PaddedLayout:
+    """The layout of ``rows`` rows in groups of ``group_sizes`` (traced:
+    routing counts change every step; every shape here is static).  With
+    ``block_rows=1`` nothing is padded and both maps are the identity."""
+    n_groups = group_sizes.shape[0]
+    padded_rows = _padded_rows(rows, n_groups, block_rows)
     sizes = group_sizes.astype(jnp.int32)
     offs = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(sizes)])
-    padded = _round_up(sizes, block)
+    padded = _round_up(sizes, block_rows)
     poffs = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(padded)])
+    # a row moves up by the padding of every group that ends at or before
+    # it: an elementwise [rows, G] compare-and-sum, where ``poffs[g_of_row]``
+    # would be a gather of ``rows`` scalars — on the TPU a quarter of the
+    # time of gathering as many whole rows
     r = jnp.arange(rows, dtype=jnp.int32)
-    g_of_row = jnp.searchsorted(offs[1:], r, side="right").astype(jnp.int32)
-    pos = poffs[g_of_row] + (r - offs[g_of_row])
-    starts = jnp.arange(padded_rows // block, dtype=jnp.int32) * block
+    ends_before = r[:, None] >= offs[None, 1:]
+    pos = r + jnp.where(ends_before, (padded - sizes)[None, :], 0).sum(1)
+    # slot -> row by block: a block belongs to one group
+    starts = jnp.arange(padded_rows // block_rows, dtype=jnp.int32) * block_rows
     g_of_block = jnp.clip(
         jnp.searchsorted(poffs, starts, side="right") - 1, 0, n_groups - 1
     ).astype(jnp.int32)
-    return pos, g_of_block, padded_rows
+    within = (starts - poffs[g_of_block])[:, None] + jnp.arange(
+        block_rows, dtype=jnp.int32)[None, :]
+    src = jnp.where(within < sizes[g_of_block][:, None],
+                    offs[g_of_block][:, None] + within, rows).reshape(-1)
+    return PaddedLayout(pos, src, g_of_block, sizes)
+
+
+def take_or_zero(x, idx):
+    """``x[idx]`` along the first axis, zero where ``idx`` is ``len(x)``, one
+    past the end (a padding slot's source).  The zero is a row appended to
+    ``x`` and not a select over the result, which on the TPU is a second
+    pass over the gathered rows: ``x`` is the smaller side wherever a
+    layout is entered."""
+    zero = jnp.zeros((1,) + x.shape[1:], x.dtype)
+    return jnp.take(jnp.concatenate([x, zero]), idx, axis=0, mode="clip")
+
+
+@jax.custom_vjp
+def pad_rows(x, src, slots):
+    """Rows into a layout: ``out[p] = x[src[p]]``, zero where ``src[p]`` is
+    ``len(x)``.  ``slots`` [len(x), m] is the inverse map, the (in-range)
+    ``p`` that read each row of ``x``, so the transpose is a gather as
+    well, ``dx[n] = sum_j g[slots[n, j]]`` (in float32 where ``m > 1``):
+    no scatter is emitted in either direction."""
+    return take_or_zero(x, src)
+
+
+def _pad_rows_fwd(x, src, slots):
+    return pad_rows(x, src, slots), slots
+
+
+def _pad_rows_bwd(slots, g):
+    read = g[slots]
+    if slots.shape[1] == 1:
+        return read[:, 0], None, None
+    return read.astype(jnp.float32).sum(1).astype(g.dtype), None, None
+
+
+pad_rows.defvjp(_pad_rows_fwd, _pad_rows_bwd)
+
+
+@jax.custom_vjp
+def unpad_rows(y_p, slots, src):
+    """Rows out of a layout, :func:`pad_rows`' transpose for ``m = 1``:
+    ``out[n] = y_p[slots[n]]``; ``src`` [len(y_p)] is the row that reads
+    each slot, ``len(slots)`` for a slot none reads, whose cotangent is
+    zero."""
+    return y_p[slots]
+
+
+def _unpad_rows_fwd(y_p, slots, src):
+    return unpad_rows(y_p, slots, src), src
+
+
+def _unpad_rows_bwd(src, g):
+    return take_or_zero(g, src), None, None
+
+
+unpad_rows.defvjp(_unpad_rows_fwd, _unpad_rows_bwd)
 
 
 def _fwd_kernel(gid_ref, lhs_ref, rhs_ref, out_ref):
@@ -146,52 +244,48 @@ def _gmm_drhs_padded(lhs_p, gout_p, n_groups, d, f, g_of_block, block_rows,
     )(g_of_block, lhs_p, gout_p)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
-def _gmm(lhs, rhs, group_sizes, block_rows, block_f, interpret):
-    out, _ = _gmm_fwd_impl(lhs, rhs, group_sizes, block_rows, block_f,
-                           interpret)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _gmm_padded_vjp(lhs_p, rhs, layout, block_f, interpret):
+    return _gmm_padded(lhs_p, rhs.astype(lhs_p.dtype), layout.g_of_block,
+                       layout.block_rows, block_f, interpret)
 
 
-def _gmm_fwd_impl(lhs, rhs, group_sizes, block_rows, block_f, interpret):
-    rows, d = lhs.shape
-    n_groups = rhs.shape[0]
-    pos, g_of_block, padded_rows = _padded_layout(
-        group_sizes, rows, n_groups, block_rows
-    )
-    lhs_p = jnp.zeros((padded_rows, d), lhs.dtype).at[pos].set(lhs)
-    out_p = _gmm_padded(lhs_p, rhs.astype(lhs.dtype), g_of_block, block_rows,
-                        block_f, interpret)
-    return out_p[pos], (pos, g_of_block, padded_rows)
+def _gmm_padded_fwd(lhs_p, rhs, layout, block_f, interpret):
+    out_p = _gmm_padded_vjp(lhs_p, rhs, layout, block_f, interpret)
+    return out_p, (lhs_p, rhs, layout)
 
 
-def _gmm_vjp_fwd(lhs, rhs, group_sizes, block_rows, block_f, interpret):
-    out, layout = _gmm_fwd_impl(lhs, rhs, group_sizes, block_rows, block_f,
-                                interpret)
-    return out, (lhs, rhs, group_sizes, layout)
-
-
-def _gmm_vjp_bwd(block_rows, block_f, interpret, res, gout):
-    lhs, rhs, group_sizes, (pos, g_of_block, padded_rows) = res
-    rows, d = lhs.shape
-    n_groups, _, f = rhs.shape
-    gout_p = jnp.zeros((padded_rows, f), gout.dtype).at[pos].set(gout)
+def _gmm_padded_bwd(block_f, interpret, res, gout_p):
+    lhs_p, rhs, layout = res
+    n_groups, d, f = rhs.shape
     # d_lhs = gout @ rhs^T (same grouped structure)
     dlhs_p = _gmm_padded(
-        gout_p, jnp.swapaxes(rhs, 1, 2).astype(gout.dtype), g_of_block,
-        block_rows, block_f, interpret,
+        gout_p, jnp.swapaxes(rhs, 1, 2).astype(gout_p.dtype),
+        layout.g_of_block, layout.block_rows, block_f, interpret,
     )
-    lhs_p = jnp.zeros((padded_rows, d), lhs.dtype).at[pos].set(lhs)
-    drhs = _gmm_drhs_padded(lhs_p, gout_p, n_groups, d, f, g_of_block,
-                            block_rows, block_f, interpret)
+    drhs = _gmm_drhs_padded(lhs_p, gout_p, n_groups, d, f, layout.g_of_block,
+                            layout.block_rows, block_f, interpret)
     # an empty group owns no row blocks, so its output block is never
     # written — select zero rather than uninitialized memory
-    mask = (group_sizes.astype(jnp.int32) > 0)[:, None, None]
-    drhs = jnp.where(mask, drhs, 0.0)
-    return dlhs_p[pos], drhs.astype(rhs.dtype), None
+    drhs = jnp.where((layout.sizes > 0)[:, None, None], drhs, 0.0)
+    return dlhs_p, drhs.astype(rhs.dtype), None
 
 
-_gmm.defvjp(_gmm_vjp_fwd, _gmm_vjp_bwd)
+_gmm_padded_vjp.defvjp(_gmm_padded_fwd, _gmm_padded_bwd)
+
+
+def gmm_padded(lhs_p, rhs, layout: PaddedLayout, *, block_f: int = _BLOCK_F,
+               interpret: bool = False):
+    """Grouped matmul in padded space: ``lhs_p`` [padded rows, d] laid out
+    by ``layout`` (padding rows zero) times ``rhs`` [G, d, f] -> [padded
+    rows, f], padding rows zero.  Differentiable in ``lhs_p`` and ``rhs``
+    without leaving the layout; a cotangent's padding rows add nothing to
+    d_rhs (``lhs_p`` is zero there).  On an unpadded layout
+    (``block_rows == 1``: :func:`kernel_layout` where no kernel runs) it is
+    the dense reference."""
+    if layout.block_rows == 1:
+        return gmm_reference(lhs_p, rhs, layout.sizes)
+    return _gmm_padded_vjp(lhs_p, rhs, layout, block_f, interpret)
 
 
 def _use_kernel(rows: int, d: int, f: int, block_rows: int) -> bool:
@@ -203,15 +297,12 @@ def _use_kernel(rows: int, d: int, f: int, block_rows: int) -> bool:
     )
 
 
-def kernel_rows(rows: int, n_groups: int, d: int, f: int, *,
-                block_rows: int = _BLOCK_ROWS) -> int:
-    """Rows one :func:`gmm` call of these shapes really multiplies: the
-    block-aligned padded layout's static row count where the kernel runs
-    (every group rounded up to ``block_rows``, at the worst case of the
-    group sizes), ``rows`` where the dense fallback does."""
-    if not _use_kernel(rows, d, f, block_rows):
-        return rows
-    return _padded_rows(rows, n_groups, block_rows)
+def kernel_layout(group_sizes, rows: int, d: int, f: int) -> PaddedLayout:
+    """The layout a run of ``[rows, d] x [G, d, f]`` products (and their
+    transposes) lives in: block-aligned where the kernels run, the sorted
+    rows themselves (``block_rows=1``) where the dense fallback does."""
+    block = _BLOCK_ROWS if _use_kernel(rows, d, f, _BLOCK_ROWS) else 1
+    return padded_layout(group_sizes, rows, block_rows=block)
 
 
 def gmm(lhs, rhs, group_sizes, *, block_rows: int = _BLOCK_ROWS,
@@ -228,4 +319,8 @@ def gmm(lhs, rhs, group_sizes, *, block_rows: int = _BLOCK_ROWS,
     f = rhs.shape[2]
     if not (force or _use_kernel(rows, d, f, block_rows)):
         return gmm_reference(lhs, rhs, group_sizes)
-    return _gmm(lhs, rhs, group_sizes, block_rows, block_f, interpret)
+    layout = padded_layout(group_sizes, rows, block_rows=block_rows)
+    lhs_p = pad_rows(lhs, layout.src, layout.pos[:, None])
+    out_p = gmm_padded(lhs_p, rhs, layout, block_f=block_f,
+                       interpret=interpret)
+    return unpad_rows(out_p, layout.pos, layout.src)

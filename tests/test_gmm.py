@@ -6,7 +6,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from bagua_tpu.ops.gmm import gmm, gmm_reference
+from bagua_tpu.ops.gmm import (
+    gmm, gmm_padded, gmm_reference, kernel_layout, pad_rows, padded_layout,
+    take_or_zero, unpad_rows,
+)
+from tests.internal.jaxpr_walk import primitives
 
 
 def _case(key, rows, d, f, sizes):
@@ -72,3 +76,195 @@ def test_jit_with_traced_sizes():
             f(lhs, rhs, gs), gmm_reference(lhs, rhs, gs), atol=1e-4,
             rtol=1e-4,
         )
+
+
+# ---------------------------------------------------------------------------
+# the padded layout as a value, and the products that stay in it
+# ---------------------------------------------------------------------------
+
+#: ragged, empty at both ends and in the middle, one group, full blocks only
+LAYOUTS = [
+    [100, 156], [0, 256, 0], [37, 0, 1, 218], [256], [128, 128], [1, 0, 0, 0],
+]
+
+
+@pytest.mark.parametrize("block", [1, 8, 128])
+@pytest.mark.parametrize("sizes", LAYOUTS)
+def test_the_layout_maps_are_each_others_inverse(sizes, block):
+    rows = int(np.sum(sizes))
+    layout = padded_layout(jnp.array(sizes, jnp.int32), rows, block_rows=block)
+    pos, src = np.asarray(layout.pos), np.asarray(layout.src)
+    assert layout.block_rows == block
+    assert len(src) % block == 0 and len(src) >= rows
+    np.testing.assert_array_equal(src[pos], np.arange(rows))
+    used = src < rows
+    assert used.sum() == rows
+    np.testing.assert_array_equal(pos[src[used]], np.nonzero(used)[0])
+    # every group starts on a block, and a block holds rows of one group
+    starts = np.concatenate([[0], np.cumsum(sizes)])[:-1]
+    for g, (start, size) in enumerate(zip(starts, sizes)):
+        if size:
+            assert pos[start] % block == 0
+            blocks = pos[start:start + size] // block
+            np.testing.assert_array_equal(
+                np.asarray(layout.g_of_block)[blocks], g)
+    if block == 1:
+        np.testing.assert_array_equal(pos, np.arange(rows))
+
+
+@pytest.mark.parametrize("sizes", LAYOUTS)
+def test_rows_beyond_the_groups_are_left_out_of_the_layout(sizes):
+    """An expert-parallel receive buffer: more rows than the groups hold."""
+    rows = int(np.sum(sizes)) + 40
+    layout = padded_layout(jnp.array(sizes, jnp.int32), rows, block_rows=128)
+    pos, src = np.asarray(layout.pos), np.asarray(layout.src)
+    assert pos.max() < len(src) and len(set(pos)) == rows
+    assert (src < rows).sum() == rows - 40
+    assert (src[pos[rows - 40:]] == rows).all()     # they read back zero
+
+
+@pytest.mark.parametrize("sizes", LAYOUTS)
+def test_the_padded_product_matches_the_reference(sizes):
+    """``gmm_padded`` (interpret mode) between a pad and an unpad: values,
+    both gradients, and exact zeros in every padding row on the way."""
+    rows = int(np.sum(sizes))
+    lhs, rhs, gs = _case(jax.random.PRNGKey(5), rows, 128, 256, sizes)
+    g = jax.random.normal(jax.random.PRNGKey(6), (rows, 256), jnp.float32)
+    layout = padded_layout(gs, rows)
+    padding = np.asarray(layout.src) == rows
+    assert padding.any()
+
+    def padded(l, r):
+        l_p = pad_rows(l, layout.src, layout.pos[:, None])
+        return l_p, gmm_padded(l_p, r, layout, interpret=True)
+
+    lhs_p, out_p = padded(lhs, rhs)
+    assert not np.asarray(lhs_p)[padding].any()
+    assert not np.asarray(out_p)[padding].any()
+    want = gmm_reference(lhs, rhs, gs)
+    np.testing.assert_allclose(out_p[layout.pos], want, atol=1e-4, rtol=1e-4)
+
+    # the cotangent enters the layout zero-padded and d_lhs stays so
+    g_p = take_or_zero(g, layout.src)
+    _, vjp = jax.vjp(lambda l_p, r: gmm_padded(l_p, r, layout, interpret=True),
+                     lhs_p, rhs)
+    dlhs_p, drhs = vjp(g_p)
+    assert not np.asarray(dlhs_p)[padding].any()
+    want_l, want_r = jax.grad(
+        lambda l, r: (gmm_reference(l, r, gs) * g).sum(), argnums=(0, 1))(
+            lhs, rhs)
+    np.testing.assert_allclose(dlhs_p[layout.pos], want_l, atol=1e-4,
+                               rtol=1e-4)
+    np.testing.assert_allclose(drhs, want_r, atol=1e-4, rtol=1e-4)
+    # and the same through pad and unpad, end to end
+    got = jax.grad(lambda l, r: (unpad_rows(
+        padded(l, r)[1], layout.pos, layout.src) * g).sum(), argnums=(0, 1))(
+            lhs, rhs)
+    np.testing.assert_allclose(got[0], want_l, atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(got[1], want_r, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["ungated", "gated"])
+@pytest.mark.parametrize("sizes", [[100, 60, 0, 96], [256, 0, 0, 0],
+                                   [1, 127, 127, 1]])
+def test_an_expert_ffn_stays_zero_in_its_padding_rows(sizes, gated):
+    """Two or three products with elementwise work between them, all in
+    the layout: every padded intermediate and every padded cotangent is
+    exactly zero in the padding rows, and the whole agrees with the dense
+    form in values and in all gradients."""
+    keys = jax.random.split(jax.random.PRNGKey(7), 5)
+    rows = jax.random.normal(keys[0], (256, 128))
+    w_gate, w_up, w_down = (0.1 * jax.random.normal(k, (4, 128, 128))
+                            for k in keys[1:4])
+    g = jax.random.normal(keys[4], (256, 128))
+    gs = jnp.array(sizes, jnp.int32)
+    layout = padded_layout(gs, 256)
+    padding = np.asarray(layout.src) == 256
+    seen = {}
+
+    def ffn(mm, x, wg, wu, wd):
+        up = mm(x, wu)
+        h = jax.nn.silu(mm(x, wg)) * up if gated else jax.nn.silu(up)
+        seen.update(up=up, h=h)
+        return mm(h, wd)
+
+    def resident(x, wg, wu, wd):
+        x_p = pad_rows(x, layout.src, layout.pos[:, None])
+        y_p = ffn(lambda l, r: gmm_padded(l, r, layout, interpret=True),
+                  x_p, wg, wu, wd)
+        seen.update(x_p=x_p, y_p=y_p)
+        return unpad_rows(y_p, layout.pos, layout.src)
+
+    def dense(x, wg, wu, wd):
+        return ffn(lambda l, r: gmm_reference(l, r, gs), x, wg, wu, wd)
+
+    args = (rows, w_gate, w_up, w_down)
+    want = dense(*args)
+    np.testing.assert_allclose(resident(*args), want, atol=1e-4)
+    for name in ("x_p", "up", "h", "y_p"):
+        assert not np.asarray(seen[name])[padding].any(), name
+    x_p = seen["x_p"]
+    got = jax.grad(lambda *a: (resident(*a) * g).sum(), argnums=(0, 1, 2, 3))(
+        *args)
+    want = jax.grad(lambda *a: (dense(*a) * g).sum(), argnums=(0, 1, 2, 3))(
+        *args)
+    for name, a, b in zip(("x", "gate", "up", "down"), got, want):
+        if name == "gate" and not gated:
+            continue
+        np.testing.assert_allclose(a, b, atol=1e-3 * float(jnp.abs(b).max()),
+                                   err_msg=name)
+    # the cotangent of the rows in the layout, before it leaves it
+    dx_p = jax.grad(lambda x_p: (unpad_rows(ffn(
+        lambda l, r: gmm_padded(l, r, layout, interpret=True),
+        x_p, w_gate, w_up, w_down), layout.pos, layout.src) * g).sum())(x_p)
+    assert not np.asarray(dx_p)[padding].any()
+
+
+@pytest.mark.parametrize("m", [1, 4])
+def test_rows_move_by_gathers_in_both_directions(m):
+    """``pad_rows`` / ``unpad_rows`` against plain indexing under autodiff,
+    and no scatter in their gradients."""
+    n, d = 24, 8
+    x = jax.random.normal(jax.random.PRNGKey(8), (n, d))
+    perm = jax.random.permutation(jax.random.PRNGKey(9), n * m)
+    slots = (2 * perm).reshape(n, m)            # every other slot is padding
+    src = jnp.full((2 * n * m,), n).at[slots.reshape(-1)].set(
+        jnp.repeat(jnp.arange(n), m))
+    g = jax.random.normal(jax.random.PRNGKey(10), (2 * n * m, d))
+    plain = lambda x: jnp.where((src < n)[:, None], x[jnp.minimum(src, n - 1)], 0)
+    np.testing.assert_array_equal(pad_rows(x, src, slots), plain(x))
+    loss = lambda fn: lambda x: (fn(x) * g).sum()
+    np.testing.assert_allclose(
+        jax.grad(loss(lambda x: pad_rows(x, src, slots)))(x),
+        jax.grad(loss(plain))(x), atol=1e-5)
+    ops = primitives(jax.grad(loss(lambda x: pad_rows(x, src, slots))), x)
+    assert not [name for name, _ in ops if name.startswith("scatter")]
+    if m == 1:
+        y_p = jax.random.normal(jax.random.PRNGKey(11), (2 * n, d))
+        out = lambda y_p: unpad_rows(y_p, slots[:, 0], src)
+        np.testing.assert_array_equal(out(y_p), y_p[slots[:, 0]])
+        h = jax.random.normal(jax.random.PRNGKey(12), (n, d))
+        np.testing.assert_allclose(
+            jax.grad(lambda y_p: (out(y_p) * h).sum())(y_p),
+            jax.grad(lambda y_p: (y_p[slots[:, 0]] * h).sum())(y_p), atol=1e-6)
+        ops = primitives(jax.grad(lambda y_p: (out(y_p) * h).sum()), y_p)
+        assert not [name for name, _ in ops if name.startswith("scatter")]
+
+
+def test_a_forced_gmm_is_three_kernel_calls_and_no_row_scatter():
+    lhs, rhs, gs = _case(jax.random.PRNGKey(13), 256, 128, 128, [60, 0, 196])
+    ops = primitives(jax.grad(
+        lambda l, r: gmm(l, r, gs, interpret=True, force=True).sum(),
+        argnums=(0, 1)), lhs, rhs)
+    names = [name for name, _ in ops]
+    assert names.count("gmm_fwd") == 2 and names.count("gmm_bwd_drhs") == 1
+    assert not [n for n in names if n.startswith("scatter")]
+
+
+def test_off_the_kernel_the_layout_is_the_rows_themselves():
+    gs = jnp.array([10, 0, 6], jnp.int32)
+    layout = kernel_layout(gs, 16, 8, 8)        # the CPU, tiny widths
+    assert layout.block_rows == 1 and layout.src.shape == (16,)
+    lhs, rhs, _ = _case(jax.random.PRNGKey(14), 16, 8, 8, [10, 0, 6])
+    np.testing.assert_allclose(gmm_padded(lhs, rhs, layout),
+                               gmm_reference(lhs, rhs, gs), atol=1e-6)

@@ -24,7 +24,9 @@ from bagua_tpu.model_parallel.moe.layer import (
 from bagua_tpu.models.transformer import (
     Attention, RMSNorm, TransformerConfig, TransformerLM, rope_rotate,
 )
-from bagua_tpu.ops.gmm import _padded_rows, gmm, gmm_reference, kernel_rows
+from bagua_tpu.ops.gmm import (
+    _padded_rows, gmm, gmm_reference, kernel_layout, padded_layout,
+)
 
 
 def _reference():
@@ -316,8 +318,10 @@ def test_kernel_rows_is_the_padded_layout_of_the_cell():
     # 2 x 4096 tokens x 8 experts in 64 groups of 128-row blocks
     assert _padded_rows(65536, 64, 128) == 73728
     assert _padded_rows(256, 4, 128) == 768
+    sizes = jnp.full((64,), 1024, jnp.int32)
+    assert padded_layout(sizes, 65536).src.shape == (73728,)
     # off the TPU the dense fallback multiplies the routed rows only
-    assert kernel_rows(65536, 64, 2048, 1024) == 65536
+    assert kernel_layout(sizes, 65536, 2048, 1024).src.shape == (65536,)
 
 
 def test_dropless_equals_the_capacity_path_at_infinite_capacity():
@@ -520,3 +524,188 @@ def test_the_replayed_losses_agree_with_the_reference_through_adamw():
     assert not ref.agree([1.02, 1.0, 1.0], [1.0, 1.0, 1.0])
     assert not ref.agree([1.0, float("nan"), 1.0], [1.0, 1.0, 1.0])
     assert not ref.agree([1.0, 1.0], [1.0, 1.0, 1.0])
+
+
+# ---------------------------------------------------------------------------
+# the padded-resident path: rows enter the kernels' layout once, leave once
+# ---------------------------------------------------------------------------
+
+
+def _force_kernels(patch):
+    """The kernels' path on the CPU: ``_use_kernel`` says yes and every
+    ``pallas_call`` runs in interpret mode — steered here, in the test, not
+    by an option of the program."""
+    import bagua_tpu.ops.gmm as gmm_mod
+
+    real = gmm_mod.pl.pallas_call
+    patch.setattr(gmm_mod, "_use_kernel", lambda *a: True)
+    patch.setattr(gmm_mod.pl, "pallas_call",
+                  lambda *a, **kw: real(*a, **{**kw, "interpret": True}))
+
+
+@pytest.fixture
+def forced_kernels(monkeypatch):
+    _force_kernels(monkeypatch)
+
+
+def resident_layer(gated, k, ep_size=1):
+    """128 tokens of width 128 (the kernels' lane width): ``k = 8`` of 16
+    experts routes 1,024 rows into 3,072 padded ones."""
+    return MoEMLP(n_experts=8 if k == 2 else 16, d_ff=128, k=k,
+                  ep_size=ep_size, dropless=True, gated=gated,
+                  norm_topk_prob=False, balance_over_topk=True,
+                  dtype=jnp.float32)
+
+
+def layer_loss(layer, g):
+    def loss(params, x):
+        out, mutated = layer.apply({"params": params}, x,
+                                   mutable=["intermediates"])
+        l_aux = sum(jnp.sum(l) for l in jax.tree.leaves(mutated))
+        return jnp.sum(out * g) + l_aux, (out, l_aux)
+    return loss
+
+
+RESIDENT = [(True, 2), (True, 8), (False, 2), (False, 8)]
+QUANTITIES = ["out", "l_aux", "d_xt", "router", "expert_wi", "expert_wo",
+              "expert_wg"]
+
+
+@pytest.fixture(scope="module", params=RESIDENT,
+                ids=lambda c: f"{'gated' if c[0] else 'ungated'}-k{c[1]}")
+def resident_and_fallback(request):
+    """Output, balance loss and every gradient of one layer, computed once
+    on the padded-resident path (forced, interpret mode) and once on the
+    fallback."""
+    from bagua_tpu.telemetry import counters
+
+    gated, k = request.param
+    layer = resident_layer(gated, k)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 128))
+    g = jax.random.normal(jax.random.PRNGKey(1), (2, 64, 128))
+    params = layer.init(jax.random.PRNGKey(2), x)["params"]
+    grad = jax.value_and_grad(layer_loss(layer, g), argnums=(0, 1),
+                              has_aux=True)
+
+    def quantities():
+        (_, (out, l_aux)), (d_params, d_x) = grad(params, x)
+        found = {"out": out, "l_aux": l_aux, "d_xt": d_x,
+                 "router": d_params["router"]["kernel"],
+                 **{name: d_params[name] for name in d_params
+                    if name.startswith("expert_")}}
+        return found, counters.get("moe/padded_resident_layers")
+
+    fallback, engaged_fallback = quantities()
+    with pytest.MonkeyPatch.context() as patch:
+        _force_kernels(patch)
+        resident, engaged_resident = quantities()
+    assert (engaged_fallback, engaged_resident) == (0, 1)
+    return resident, fallback
+
+
+@pytest.mark.parametrize("quantity", QUANTITIES)
+def test_the_padded_resident_layer_is_the_fallback_layer(
+        resident_and_fallback, quantity):
+    resident, fallback = resident_and_fallback
+    if quantity not in fallback:
+        assert quantity == "expert_wg"      # an ungated layer has no gate
+        assert set(resident) == set(fallback)
+        return
+    got, want = resident[quantity], fallback[quantity]
+    scale = float(jnp.abs(want).max())
+    assert scale > 0, "a quantity that is zero everywhere tests nothing"
+    np.testing.assert_allclose(got, want, atol=2e-5 * scale, rtol=0)
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_the_padded_resident_step_moves_rows_by_gathers_alone(
+        forced_kernels, gated):
+    """Value-and-grad of the forced layer at ``T * k`` = 1,024 rows: no
+    scatter or scatter-add touches an operand of the row width, and the
+    kernels are called 3 + 3 and 3 times (2 + 2 and 2 ungated) — the hidden
+    rows' rebuild in the backward pass replays no product."""
+    from bagua_tpu.telemetry import counters
+    from tests.internal.jaxpr_walk import primitives
+
+    layer = resident_layer(gated, 8)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 128))
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    counters.set_gauge("moe/padded_resident_layers", -1)
+    ops = primitives(jax.value_and_grad(
+        layer_loss(layer, jnp.ones_like(x)), argnums=(0, 1), has_aux=True),
+        params, x)
+    assert counters.get("moe/padded_resident_layers") == 1
+    assert counters.get("moe/rows_per_step") == 1024
+    assert counters.get("moe/padded_rows_per_step") == 3072
+    names = [name for name, _ in ops]
+    products = 3 if gated else 2
+    assert names.count("gmm_fwd") == 2 * products
+    assert names.count("gmm_bwd_drhs") == products
+    scatters = [(name, shapes) for name, shapes in ops
+                if name.startswith("scatter")]
+    # what is left: the sort's inverse, the group sizes, the router's
+    # top-k transpose over [T, E] — index vectors and per-expert tables
+    assert scatters, "the inverse permutation is a scatter over int32"
+    for name, shapes in scatters:
+        assert all(shape[-1:] != (128,) for shape in shapes if shape), (
+            name, shapes)
+        assert max(int(np.prod(shape)) for shape in shapes) <= 128 * 16
+
+
+def test_the_fallback_layer_reports_no_padded_resident_layer():
+    from bagua_tpu.telemetry import counters
+
+    layer = resident_layer(True, 2)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 128))
+    params = layer.init(jax.random.PRNGKey(1), x)["params"]
+    counters.set_gauge("moe/padded_resident_layers", -1)
+    layer.apply({"params": params}, x)
+    assert counters.get("moe/padded_resident_layers") == 0
+    assert counters.get("moe/padded_rows_per_step") == 256
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "ungated"])
+def test_expert_parallel_pads_once_from_its_receive_buffer(
+        forced_kernels, gated):
+    """``ep`` 4 on the virtual mesh with the kernels forced: each shard
+    pads its worst-case receive buffer (empty slots and all) into the
+    layout once and unpads once — output and every gradient against the
+    single shard's."""
+    from jax import shard_map
+    from jax.sharding import PartitionSpec as P
+
+    from bagua_tpu.parallel.mesh import build_mesh
+    from bagua_tpu.telemetry import counters
+
+    ep = 4
+    single, sharded = resident_layer(gated, 2), resident_layer(gated, 2, ep)
+    x = jax.random.normal(jax.random.PRNGKey(0), (8, 16, 128))
+    g = jax.random.normal(jax.random.PRNGKey(1), (8, 16, 128))
+    params = single.init(jax.random.PRNGKey(2), x[:2])["params"]
+    mesh = build_mesh({"ep": ep}, jax.devices()[:ep])
+    pspec = jax.tree_util.tree_map_with_path(
+        lambda path, leaf: P("ep") if is_expert_param(
+            jax.tree_util.keystr(path)) else P(), params)
+
+    def on_mesh(p, xs):
+        return jax.jit(shard_map(
+            lambda p, xs: sharded.apply({"params": p}, xs), mesh=mesh,
+            in_specs=(pspec, P("ep")), out_specs=P("ep"), check_vma=False,
+        ))(p, xs)
+
+    loss = lambda fn: lambda p, xs: jnp.sum(fn(p, xs) * g)
+    want_out = single.apply({"params": params}, x)
+    want = jax.grad(loss(lambda p, xs: single.apply({"params": p}, xs)),
+                    argnums=(0, 1))(params, x)
+    got_out = on_mesh(params, x)
+    # 2 x 16 tokens x 2 a shard: a receive buffer of 4 x 64 rows, padded
+    assert counters.get("moe/padded_rows_per_step") == 512
+    assert counters.get("moe/padded_resident_layers") == 1
+    np.testing.assert_allclose(got_out, want_out, atol=2e-5)
+    got = jax.grad(loss(on_mesh), argnums=(0, 1))(params, x)
+    flat = lambda tree: {jax.tree_util.keystr(p): v for p, v in
+                         jax.tree_util.tree_flatten_with_path(tree)[0]}
+    for name, w in flat(want).items():
+        np.testing.assert_allclose(
+            flat(got)[name], w, atol=2e-5 * float(jnp.abs(w).max()),
+            err_msg=name)
