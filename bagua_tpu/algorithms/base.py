@@ -409,10 +409,10 @@ class AlgorithmContext:
         ``dcn_codec``/``flat_codec`` are the algorithm family's wire-codec
         defaults (``Algorithm.wire_codec_dcn``/``wire_codec_flat``); the
         tier knobs override them through :meth:`codec_for`, and the
-        estimate then reports COMPRESSED wire bytes — so the launch spans,
-        the DCN-first launch order, and ``obs/device_comm_dcn_s``
-        attribution describe what actually crosses the wire, not the fp32
-        operand the codec replaced."""
+        estimate then reports COMPRESSED wire bytes — so the DCN-first
+        launch order and the codecs' byte accounting describe what
+        actually crosses the wire, not the fp32 operand the codec
+        replaced."""
         import numpy as np
 
         b = self.plan.buckets[index]

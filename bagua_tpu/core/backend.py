@@ -32,12 +32,15 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from .. import env
 from ..algorithms.base import Algorithm, AlgorithmContext
 from ..bucket import BucketPlan, split_bucket_by_bucket_size
-from ..communication import BaguaCommunicator, ReduceOp, collapse_trivial_axes
-from ..obs import spans as _obs_spans
-from ..obs.spans import phase_scope, trace_span
+from ..communication import (
+    BaguaCommunicator, ReduceOp, abort, check_abort, collapse_trivial_axes,
+)
+from ..faults import inject as _inject
+from ..obs.spans import phase_scope, trace_span, trace_step_span
+from ..obs.step_observer import StepObserver
 from ..parallel.mesh import build_mesh, hierarchical_mesh, mesh_axis_size
+from ..telemetry import counters
 from ..tensor import build_params, _name_of_path
-from ..utils import StatisticalAverage
 
 logger = logging.getLogger(__name__)
 
@@ -529,10 +532,6 @@ class BaguaTrainer:
         self._autotune_client = None
         self._autotune_failures = 0
         self._autotune_completed = not self.autotune
-        #: previous goodput-ledger snapshot at the last check-in: the
-        #: ledger reports CUMULATIVE seconds, the autotune score needs the
-        #: WINDOW since the last report (same windowing as the speed)
-        self._autotune_ledger_prev = None
         self._telemetry_reported = False
         self._pending_state_migration = None
         self._stashed_opt_state = None
@@ -558,75 +557,10 @@ class BaguaTrainer:
         from ..profiling import StepProfiler
 
         self._profiler = StepProfiler.from_env()
-        # observability plane (docs/observability.md): resolved once — the
-        # per-step hooks below gate on this flag so BAGUA_OBS=off restores
-        # the exact pre-obs host behavior
-        self._obs_enabled = _obs_spans.enabled()
-        self._last_beacon_write = 0.0
-        #: goodput ledger (docs/observability.md, efficiency plane): every
-        #: wall-clock second of this process lands in exactly one class —
-        #: fed from the step-cadence windows, the span hook, stall reports,
-        #: and the grad guard's rewind verdicts below.  All host-side.
-        self._ledger = None
-        #: MFU denominator: peak silicon FLOP/s for this chip kind (None on
-        #: cpu-sim / unknown silicon -> obs/mfu stays null-with-rationale)
-        self._peak_flops = None
-        self._mfu_flops: Optional[float] = None
-        self._mfu_noted_unavailable = False
-        #: the CURRENT step's wall window contained a compile or state
-        #: migration: the cadence hook attributes the window there instead
-        #: of productive_step (the ledger mirror of _skip_next_speed_sample)
-        self._ledger_window_class: Optional[str] = None
-        self._footprint_noted = False
-        self._mem_poll_dead = False
-        self._mem_poll_failures = 0
-        if self._obs_enabled:
-            from ..obs import export as _obs_export
-            from ..obs import http as _obs_http
-            from ..obs import ledger as _obs_ledger
-            from ..obs import recorder as _obs_recorder
-
-            _obs_export.maybe_start_global_exporter(self)
-            # per-process HTTP status plane (off unless the operator sets
-            # BAGUA_OBS_HTTP_PORT; the launcher offsets each worker's
-            # port): /metrics serves the same prepared snapshot the
-            # exporter writes to metrics.prom
-            _obs_http.maybe_start_global_http_server()
-            _obs_recorder.maybe_install_signal_hook()
-            self._ledger = _obs_ledger.install()
-            self._peak_flops = _obs_ledger.peak_flops_for_device_kind(
-                jax.devices()[0].device_kind
-            )
-        #: step-time anomaly detector (docs/observability.md): rolling
-        #: median/MAD baseline over the RAW host cadence (injected stalls
-        #: included — a stall IS the anomaly an operator wants flagged,
-        #: while measured_step_dt subtracts it to stay an honest dilation
-        #: base) plus the per-phase host durations accumulated below
-        self.anomaly_detector = None
-        if self._obs_enabled and env.get_obs_anomaly_mode() == "on":
-            from ..obs.anomaly import StepAnomalyDetector
-
-            self.anomaly_detector = StepAnomalyDetector()
-        #: host phase durations of the step currently being driven
-        #: (dispatch / collective / optimizer); harvested into the anomaly
-        #: detector when the next cadence sample closes the window
-        self._phase_durations: Dict[str, float] = {}
-        #: the current step triggered a compile or a state migration: its
-        #: wall window is expected to be huge and is neither an anomaly
-        #: nor baseline material (the speed tracker's
-        #: ``_skip_next_speed_sample`` mirror)
-        self._anomaly_skip_window = False
-        self._speed_tracker = StatisticalAverage()
-        self._last_report_time = time.time()
-        self._last_speed_time = time.time()
-        self._manual_speed = False
-        self._skip_next_speed_sample = True
-        self._hyperparams_signature = None
-        # host dispatch cadence (one monotonic read per step): the base
-        # step time the step.straggle fault point dilates by its factor
-        self._last_step_mono: Optional[float] = None
-        self._step_dt: Optional[float] = None
-        self._last_straggle_sleep = 0.0
+        #: everything that watches a step on the host (cadence, goodput
+        #: ledger, anomaly detector, MFU / HBM gauges, beacon, speed
+        #: tracker, exporter start-up): docs/observability.md
+        self._observer = StepObserver()
 
     # ---- plan management -----------------------------------------------
 
@@ -1358,8 +1292,6 @@ class BaguaTrainer:
     # ---- step ------------------------------------------------------------
 
     def _make_step_fn(self, plan: BucketPlan):
-        from ..faults import inject as _inject
-
         algo = self.algorithm
         overlap = self._overlap_active()
         ctx = self._ctx(plan, overlap=overlap)
@@ -1510,77 +1442,19 @@ class BaguaTrainer:
                     return g * pp_size
 
                 grads = jax.tree_util.tree_map_with_path(pp_dense_grad, grads)
-            if overlap:
-                # streamed comm stage: one collective per bucket, issued in
-                # bucket (readiness) order on exactly that bucket's
-                # finalized gradient — the algorithm families plug in via
-                # reduce_bucket_grad (allreduce, bytegrad's codec pipeline,
-                # ZeRO's reduce-scatter all ride the same machinery).
-                # The spans here run at TRACE time (host-side only — the
-                # jaxpr is unchanged) and record the launch ORDER and byte
-                # accounting of the streamed schedule.
-                if self._flat_resident:
-                    # flat-resident grads are already the bucket flats.
-                    # Launch order is bandwidth-tier-aware: on a two-tier
-                    # mesh with the hierarchical path active, DCN-dominant
-                    # buckets are streamed first so the slow link is busy
-                    # for the whole backward window; the spans record each
-                    # launch's tier + per-tier byte estimate so
-                    # obs/attribution can split device comm seconds into
-                    # ICI vs DCN.  Results assemble in plan order — issue
-                    # order never changes the numerics.
-                    hier = getattr(algo, "hierarchical", False)
-                    order = ctx.bucket_launch_order(
-                        hier, dcn_codec=algo.wire_codec_dcn
-                    )
-                    with phase_scope("bagua.comm"):
-                        # error-feedback compensation folds the residual
-                        # into the flats BEFORE the streamed collectives
-                        # (identity — zero traced ops — unless a stateful
-                        # codec rides)
-                        with phase_scope("bagua.layout"):
-                            flats, algo_state = algo.compensate_flats(
-                                ctx, list(grads["flats"]), algo_state
-                            )
-                        reduced = [None] * len(flats)
-                        for i in order:
-                            # tier estimates report COMPRESSED wire bytes
-                            # when a codec rides the tier, so the spans
-                            # (and obs/device_comm_dcn_s attribution
-                            # downstream) describe what actually crosses
-                            # the wire
-                            tiers = ctx.bucket_tier_bytes(
-                                i, hier, dcn_codec=algo.wire_codec_dcn,
-                                flat_codec=algo.wire_codec_flat,
-                            )
-                            with phase_scope(
-                                f"bucket_{i}",
-                                "trace/bucket_collective", bucket=i,
-                                bytes=tiers["bytes"], tier=tiers["tier"],
-                                ici_bytes=tiers["ici_bytes"],
-                                dcn_bytes=tiers["dcn_bytes"],
-                                dcn_codec=tiers["dcn_codec"],
-                            ):
-                                reduced[i] = algo.reduce_bucket_grad(
-                                    ctx, i, flats[i]
-                                )
-                        with phase_scope("bagua.layout"):
-                            grads, algo_state = algo.grads_from_reduced(
-                                ctx, reduced, grads, algo_state, step
-                            )
-                else:
-                    with phase_scope("bagua.comm", "trace/comm_stage",
-                                     overlap=True,
-                                     buckets=len(plan.buckets)):
-                        grads, algo_state = algo.process_grads_bucketed(
-                            ctx, grads, params, algo_state, step
-                        )
-            else:
-                with phase_scope("bagua.comm", "trace/comm_stage",
-                                 overlap=False, buckets=len(plan.buckets)):
-                    grads, algo_state = algo.process_grads(
-                        ctx, grads, params, algo_state, step
-                    )
+            # one comm stage: the overlap scheduler streams the same
+            # per-bucket reduction the serialized stage issues (allreduce,
+            # bytegrad's codec pipeline, ZeRO's reduce-scatter all plug in
+            # via reduce_bucket_grad), on grads the peeled tail micro-batch
+            # left as open dataflow.  Under the flat-resident layout the
+            # grads already are the bucket flats.  The span runs at TRACE
+            # time (host-side only — the jaxpr is unchanged).
+            with phase_scope("bagua.comm", "trace/comm_stage",
+                             overlap=overlap, buckets=len(plan.buckets)):
+                stage = (algo.process_grads_bucketed if overlap
+                         else algo.process_grads)
+                grads, algo_state = stage(ctx, grads, params, algo_state,
+                                          step)
             if expert is not None:
                 # Expert grads bypass the bucket plan.  The all_to_all
                 # backward already SUMS every ep shard's loss contribution
@@ -1771,8 +1645,6 @@ class BaguaTrainer:
         """The step-cache key for the CURRENT configuration — also keys the
         cost/memory-analysis caches (one XLA cost-model query per compiled
         program, not per call)."""
-        from ..faults import inject as _inject
-
         overlap = self._overlap_active()
         return (
             self._plan.signature(),
@@ -1825,187 +1697,38 @@ class BaguaTrainer:
                             buckets=len(self._plan.buckets),
                             overlap=self._overlap_active()):
                 self._step_cache[key] = self._make_step_fn(self._plan)
-            from ..telemetry import counters
-
             # what the plan asks of the wire per step; what XLA's combiner
             # makes of it is a count over the compiled text
             counters.set_gauge(
                 "comm/buckets_per_step",
                 len(self._plan.buckets) if self._comm.nranks() > 1 else 0)
-            # the step that triggers this compile produces a garbage-slow
-            # speed sample; _auto_record_speed drops it — and the anomaly
-            # detector skips the window, and the goodput ledger attributes
-            # it to `compile`, for the same reason
-            self._skip_next_speed_sample = True
-            self._anomaly_skip_window = True
-            self._ledger_window_class = "compile"
+            # the wall window of the step that triggers this compile is
+            # garbage-slow: no speed sample, no anomaly, booked to `compile`
+            self._observer.note_window_class("compile")
         return self._step_cache[key]
 
+    # the observer's surface other modules call on the trainer
+    # (algorithms/async_model_average.py, obs/export.py, the drills)
+
     def measured_step_dt(self) -> Optional[float]:
-        """Host dispatch cadence of the previous step in seconds (injected
-        straggle stalls subtracted, so a dilation can never compound into
-        its own base).  Steady-state dispatch cadence equals device step
-        cadence — each dispatch consumes the previous state — which makes
-        this the honest base time for the ``step.straggle`` fault point."""
-        return self._step_dt
+        """Host dispatch cadence of the previous step in seconds
+        (:meth:`StepObserver.measured_step_dt`)."""
+        return self._observer.measured_step_dt()
 
     def note_injected_stall(self, seconds: float) -> None:
-        """Record an injected stall that happened inside the current step
-        (e.g. an async boundary's ``step.straggle`` sleep) so the next
-        cadence sample subtracts it — see :meth:`measured_step_dt`."""
-        self._last_straggle_sleep += float(seconds)
-        self._note_stall_phase(seconds)
-        if self._ledger is not None and seconds > 0:
-            self._ledger.note_class_window("stall", float(seconds))
+        """Record an injected stall inside the current step
+        (:meth:`StepObserver.note_injected_stall`)."""
+        self._observer.note_injected_stall(seconds)
 
     def note_phase_duration(self, phase: str, seconds: float) -> None:
         """Attribute host seconds of the current step to a phase
-        (``dispatch`` / ``collective`` / ``optimizer``) for the anomaly
-        detector's ``straggler_suspect`` breakdown.  Algorithms call this
-        around their host-visible waits (async negotiate/catch-up)."""
-        if self.anomaly_detector is None or seconds <= 0:
-            return
-        self._phase_durations[phase] = (
-            self._phase_durations.get(phase, 0.0) + float(seconds)
-        )
+        (:meth:`StepObserver.note_phase_duration`)."""
+        self._observer.note_phase_duration(phase, seconds)
 
-    def _note_stall_phase(self, seconds: float) -> None:
-        """Phase-attribute an injected ``step.straggle`` stall: the
-        straggler's OWN process is locally slow (``dispatch`` — that is
-        what a genuinely slow host looks like), a gated peer is *waiting*
-        (``collective``)."""
-        if self.anomaly_detector is None or seconds <= 0:
-            return
-        from ..faults import inject as _inject
-
-        self.note_phase_duration(
-            "dispatch" if _inject.straggle_targets_self() else "collective",
-            seconds,
-        )
-
-    def _note_device_attribution(self, trace_dir: str) -> None:
-        """A ``BAGUA_PROFILE_DIR`` auto-capture window just closed: parse
-        its xplane once and publish per-bucket device comm time + overlap
-        fraction (null-with-rationale on cpu-sim) into the obs summary /
-        exporter.  One-shot per window, exception-free, and OFF the
-        training step: a large model's xplane.pb can take seconds to
-        parse, which inline would stall a dispatch (and read as a
-        self-inflicted step anomaly) — a daemon thread publishes when
-        done.  The bucket launch schedule is harvested from the ring HERE
-        (cheap), not in the thread, so a concurrent recompile cannot skew
-        the match."""
-        from ..obs.attribution import bucket_launches_from_ring
-
-        try:
-            launches = bucket_launches_from_ring()
-        except Exception:  # noqa: BLE001
-            launches = []
-
-        def _parse():
-            try:
-                from ..obs import export as _obs_export
-                from ..obs.attribution import attribute_device_comm
-
-                record = attribute_device_comm(trace_dir,
-                                               bucket_launches=launches)
-                _obs_export.note_device_attribution(record)
-                if record.get("available"):
-                    logger.info(
-                        "device attribution: comm %.6fs/step, overlap "
-                        "%.1f%% (%s)", record.get("comm_s_per_step") or 0.0,
-                        100.0 * (record.get("overlap_fraction") or 0.0),
-                        trace_dir,
-                    )
-                else:
-                    logger.info("device attribution unavailable: %s",
-                                record.get("rationale"))
-            except Exception as e:  # noqa: BLE001
-                logger.warning("device attribution failed: %s", e)
-
-        threading.Thread(target=_parse, name="bagua-obs-attribution",
-                         daemon=True).start()
-
-    def _note_step_cadence(self) -> None:
-        now = time.monotonic()
-        if self._last_step_mono is not None:
-            raw = now - self._last_step_mono
-            dt = raw - self._last_straggle_sleep
-            if dt > 0:
-                self._step_dt = dt
-            window_cls = None
-            if self._ledger is not None and raw > 0:
-                # goodput ledger: the wall window that just closed belongs
-                # to the previous step; class windows noted inside it
-                # (checkpoint, async boundaries, stalls) were already
-                # deducted by the ledger.  The remainder is productive-step
-                # time — unless the window contained a trace+compile or a
-                # state migration (XLA compiles lazily on first dispatch,
-                # so the build span alone under-counts): then the whole
-                # remainder is that class's wall, mirroring
-                # _skip_next_speed_sample.
-                window_cls = self._ledger_window_class or "productive_step"
-                self._ledger_window_class = None
-                self._ledger.note_step_window(
-                    self._step_counter - 1, raw, window_cls)
-            if window_cls in (None, "productive_step"):
-                # MFU only from productive windows: a compile/migration
-                # window's dt would publish a garbage-low sample that
-                # rides the beacon to the fleet view
-                self._maybe_note_mfu()
-            if self.anomaly_detector is not None and raw > 0:
-                # the wall window that just closed belongs to the PREVIOUS
-                # step; its phase attributions were accumulated during it.
-                # A window that contained a compile or a state migration
-                # is skipped outright — an expected one-off stall must not
-                # flag (autotune retunes recompile every sample) nor enter
-                # the baseline.
-                phases, self._phase_durations = self._phase_durations, {}
-                if self._anomaly_skip_window:
-                    self._anomaly_skip_window = False
-                else:
-                    self.anomaly_detector.observe(
-                        self._step_counter - 1, raw, phases
-                    )
-        self._last_step_mono = now
-        if self._obs_enabled:
-            # fleet view: the per-rank step/step-dt summary the health
-            # beacon (and the metrics exporter) publish
-            from ..obs import export as _obs_export
-
-            _obs_export.note_step(self._step_counter, self._step_dt)
-
-    def _maybe_note_mfu(self) -> None:
-        """Per-step MFU gauge: the cached cost-model flops of the current
-        compiled step over (measured step cadence x peak silicon FLOP/s).
-        Null-with-rationale where the denominator is unknown (cpu-sim,
-        unlisted device kinds) — published once, like ``trace_overlap``."""
-        if not self._obs_enabled:
-            return
-        from ..obs import export as _obs_export
-
-        if self._peak_flops is None:
-            if not self._mfu_noted_unavailable:
-                self._mfu_noted_unavailable = True
-                _obs_export.note_mfu({
-                    "available": False,
-                    "rationale": (
-                        "no peak-FLOPS table entry for device kind "
-                        f"{jax.devices()[0].device_kind!r} (cpu-sim or "
-                        "unlisted silicon) — MFU needs a silicon peak "
-                        "denominator"
-                    ),
-                })
-            return
-        if not self._mfu_flops or not self._step_dt:
-            return
-        mfu = self._mfu_flops / self._step_dt / self._peak_flops
-        _obs_export.note_mfu({
-            "available": True,
-            "mfu": round(mfu, 4),
-            "flops_per_step": self._mfu_flops,
-            "peak_flops": self._peak_flops,
-            "step_dt": round(self._step_dt, 6),
-        })
+    @property
+    def anomaly_detector(self):
+        """The step-time anomaly detector (None with the plane off)."""
+        return self._observer.anomaly_detector
 
     def _maybe_prepare_mfu(self, state: TrainState,
                            batch) -> Optional[threading.Thread]:
@@ -2024,18 +1747,19 @@ class BaguaTrainer:
         program to the persistent compile cache — the harvest's compile of
         the same module is then a cache hit, where starting it before the
         dispatch compiled the whole step twice, concurrently."""
-        if self._peak_flops is None:
-            self._maybe_note_mfu()  # publish the rationale once
+        obs = self._observer
+        if obs.peak_flops is None:
+            obs.note_mfu()  # publish the rationale once
             return None
         key = self._current_step_key
         cached = self._cost_analysis_cache.get(key)
         if cached is not None:
-            self._mfu_flops = cached.get("flops")
+            obs.flops_per_step = cached.get("flops")
             return None
         # pause the gauge until THIS program's flops land: publishing the
         # previous key's flops against the new program's cadence (for the
         # whole duration of a background compile) would be wrong, not late
-        self._mfu_flops = None
+        obs.flops_per_step = None
         if key in self._cost_analysis_pending:
             return None
         done = threading.Event()
@@ -2071,16 +1795,12 @@ class BaguaTrainer:
                         "step_cost_analysis unavailable on %r backend: %s",
                         jax.default_backend(), e,
                     )
-                    from ..telemetry import counters
-
                     counters.incr("obs/cost_analysis_unavailable")
                     self._cost_analysis_cache[key] = {}
                     self._memory_analysis_cache[key] = None
                     return
-                from ..obs.memory import compiled_memory_analysis
-
                 self._memory_analysis_cache[key] = \
-                    compiled_memory_analysis(compiled)
+                    self._observer.compiled_memory_analysis(compiled)
                 self._cost_analysis_cache[key] = \
                     dict(analysis) if analysis else {}
             finally:
@@ -2093,65 +1813,18 @@ class BaguaTrainer:
         return threading.Thread(target=_harvest,
                                 name="bagua-obs-cost-analysis")
 
-    def _note_static_footprint(self, state: TrainState) -> None:
-        """One-shot static HBM footprint of the live training state +
-        bucket plan (:func:`bagua_tpu.obs.memory.static_footprint`) into
-        the obs summary / exporter gauges.  Host metadata only."""
-        self._footprint_noted = True
-        try:
-            from ..obs import export as _obs_export
-            from ..obs.memory import static_footprint
-
-            _obs_export.note_hbm_footprint(static_footprint(self, state))
-        except Exception as e:  # noqa: BLE001 - accounting must not kill
-            logger.debug("static footprint not computed: %s", e)
-
-    def _maybe_poll_device_memory(self) -> None:
-        """Live ``device.memory_stats()`` poll (real TPU: peak bytes +
-        headroom gauges), throttled to the beacon cadence.  A STABLE
-        unavailable answer (cpu-sim's "no HBM stats") disables polling
-        after publishing the rationale once; transient failures (a runtime
-        hiccup mid-run) keep polling until a consecutive-failure budget —
-        a multi-day run must not lose its capacity gauges to one flake."""
-        if self._mem_poll_dead:
-            return
-        try:
-            from ..obs import export as _obs_export
-            from ..obs.memory import live_memory_stats
-
-            record = live_memory_stats()
-            if record.get("available"):
-                self._mem_poll_failures = 0
-            elif record.get("transient"):
-                self._mem_poll_failures += 1
-                if self._mem_poll_failures >= 5:
-                    self._mem_poll_dead = True
-            else:
-                self._mem_poll_dead = True
-            _obs_export.note_hbm_live(record)
-        except Exception as e:  # noqa: BLE001
-            self._mem_poll_failures += 1
-            if self._mem_poll_failures >= 5:
-                self._mem_poll_dead = True
-            logger.debug("device memory poll failed: %s", e)
-
     def train_step(self, state: TrainState, batch) -> Tuple[TrainState, jax.Array]:
         # the root span of the step (and the profiler's step annotation):
         # everything the trainer does on the host for one step is inside
         # it, and its self time is what the child spans below leave over
-        with _obs_spans.trace_step_span(self._step_counter + 1):
+        with trace_step_span(self._step_counter + 1):
             return self._train_step(state, batch)
 
     def _train_step(self, state: TrainState, batch):
-        from ..communication import check_abort
-        from ..faults import inject as _inject
-
         check_abort()  # fail fast once a rank/watchdog flagged an abort
+        obs = self._observer
         self._step_counter += 1
-        if self._obs_enabled:
-            # every span opened while this step is driven (including the
-            # watchdog waiter's) carries the step number
-            _obs_spans.set_current_step(self._step_counter)
+        obs.begin_step(self._step_counter)
         with trace_span("step/hooks"):
             if self._profiler is not None:
                 self._profiler.on_step(self._step_counter - 1)
@@ -2159,19 +1832,10 @@ class BaguaTrainer:
             # family's step synchronizes with every rank (per-step gradient
             # collective); async families pay at their own negotiated
             # boundaries instead
-            self._note_step_cadence()
-            if self._profiler is not None and self._obs_enabled:
-                closed = self._profiler.consume_closed_dir()
-                if closed:
-                    self._note_device_attribution(closed)
-            self._last_straggle_sleep = _inject.maybe_straggle(
-                "step", base_dt=self._step_dt,
+            obs.note_injected_stall(_inject.maybe_straggle(
+                "step", base_dt=obs.measured_step_dt(),
                 gated=self.algorithm.straggler_gates_step,
-            )
-            self._note_stall_phase(self._last_straggle_sleep)
-            if self._ledger is not None and self._last_straggle_sleep > 0:
-                self._ledger.note_class_window("stall",
-                                               self._last_straggle_sleep)
+            ))
             state = self.algorithm.host_pre_step(self, state)
         if self.algorithm.need_reset(self._step_counter - 1):
             self._phase += 1
@@ -2214,18 +1878,12 @@ class BaguaTrainer:
             with trace_span("step/state_migration"):
                 state = self._pending_state_migration(state)
             self._pending_state_migration = None
-            self._anomaly_skip_window = True
-            if self._ledger_window_class is None:
-                # a migration usually triggers a recompile too, which then
-                # claims the window — the migration span already fed its
-                # own execution wall either way
-                self._ledger_window_class = "state_migration"
+            obs.note_window_class("state_migration")
         fn = self._get_step_fn()
         mfu_harvest = None
-        if self._obs_enabled:
+        if obs.enabled:
             mfu_harvest = self._maybe_prepare_mfu(state, batch)
-            if not self._footprint_noted:
-                self._note_static_footprint(state)
+            obs.note_static_footprint(self, state)
         # poison accounting reads the persisted state.step BEFORE dispatch:
         # the buffers are donated to fn, and the compiled fault fires on
         # state.step (which resumes from checkpoints), not the
@@ -2242,7 +1900,7 @@ class BaguaTrainer:
         if dispatch is not None:
             # the anomaly detector's phase breakdown reads the span's own
             # clock pair (obs off: no span, and no detector to feed)
-            self.note_phase_duration("dispatch", dispatch.dur_s)
+            obs.note_phase_duration("dispatch", dispatch.dur_s)
         if self.grad_guard != "off":
             new_state, loss, health_vec = out
             self.step_metrics = {
@@ -2260,20 +1918,8 @@ class BaguaTrainer:
                 self._watchdog.watch_result(
                     out[1], f"train_step[{self._step_counter}]"
                 )
-        self._auto_record_speed(batch)
-        if self._obs_enabled:
-            # fleet view, worker half: refresh this rank's beacon so the
-            # launcher's heartbeat carries a LIVE step/staleness summary,
-            # not only the unhealthy-event snapshots.  Throttled to ~one
-            # tiny file write per 2 s; no-op without the launcher-injected
-            # beacon path.
-            now = time.monotonic()
-            if now - self._last_beacon_write > 2.0:
-                self._last_beacon_write = now
-                self._maybe_poll_device_memory()
-                from ..elastic.membership import write_health_beacon
-
-                write_health_beacon()
+        # the only consumer of the speed tracker is the autotune check-in
+        obs.end_step(batch, track_speed=not self._autotune_completed)
         return out
 
     # ---- gradient-health sentinel (host-side policy) ---------------------
@@ -2304,10 +1950,6 @@ class BaguaTrainer:
         return np.asarray(arr.addressable_shards[0].data)
 
     def _consume_health(self, step_no: int, health_vec) -> None:
-        from ..communication import abort
-        from ..faults import inject as _inject
-        from ..telemetry import counters
-
         # min over verdict rows (rank-uniform verdicts replicate; per-rank
         # gossip verdicts stack — this process acts on ALL its local rows,
         # so multi-device processes see every local replica's verdict)
@@ -2322,19 +1964,10 @@ class BaguaTrainer:
                 )
         # the verdict readback is host optimizer-adjacent work: it blocks
         # on the previous step's update having completed
-        self.note_phase_duration("optimizer",
-                                 time.monotonic() - _verdict_t0)
+        self._observer.note_phase_duration("optimizer",
+                                           time.monotonic() - _verdict_t0)
         hv = hv.min(axis=0)
-        if self._obs_enabled:
-            # host-safe mirror of the verdict: the flight recorder
-            # republishes these from abort paths where touching a device
-            # array could hang
-            from ..obs import export as _obs_export
-
-            _obs_export.note_step_metrics({
-                "grad_health_step": step_no,
-                "grad_healthy": float(hv.min()),
-            })
+        self._observer.note_grad_verdict(step_no, float(hv.min()))
         if bool(hv.min() > 0.5):
             self._guard_skips = 0
             return
@@ -2363,10 +1996,7 @@ class BaguaTrainer:
             self._guard_skips += 1
             self._guard_rewinds_total += 1
             counters.incr("grad_guard/skipped_steps")
-            if self._ledger is not None:
-                # the step's wall was spent, its update discarded: move its
-                # recorded productive seconds to the rewind badput class
-                self._ledger.reclassify_step_rewind(step_no)
+            self._observer.note_rewind(step_no)
             _inject.record_recovery("grad.poison")
             logger.warning(
                 "grad guard: step %d produced non-finite gradients "
@@ -2390,16 +2020,12 @@ class BaguaTrainer:
         # non-finite gradients can be fenced out by the epoch/resize
         # machinery (no-op unless the launcher injected
         # BAGUA_ELASTIC_HEALTH_FILE)
-        from ..elastic.membership import write_health_beacon
-
-        write_health_beacon()
+        self._observer.publish_health()
         if abort_msg is not None:
             # flight recorder: grad-guard abort and skip-budget escalation
             # both land here — the post-mortem names the offending step and
             # buckets before the abort flag stops every control loop
-            from ..obs.recorder import dump_flight_record
-
-            dump_flight_record(
+            self._observer.dump_flight_record(
                 "grad_guard_abort", reason=abort_msg,
                 extra={"step": step_no, "unhealthy_buckets": bad,
                        "policy": self.grad_guard,
@@ -2415,8 +2041,6 @@ class BaguaTrainer:
         the trainer-local call counter restarts at 0.  The readback only
         happens while a poison spec is armed (drills), never in clean
         runs."""
-        from ..faults import inject as _inject
-
         specs = _inject.armed_traced_specs("grad.poison")
         if not specs:
             return
@@ -2428,34 +2052,6 @@ class BaguaTrainer:
                 fired = spec.count < 0 or traced_step < spec.count
             if fired:
                 _inject.note_traced_fire(spec)
-
-    def _auto_record_speed(self, batch) -> None:
-        """Feed the throughput tracker from the step itself (reference
-        measures its own speed with paired events in the forward-pre hook,
-        distributed.py:340-358).  The global batch's leading dim is the
-        sample count; dispatch cadence equals steady-state step cadence
-        because each step consumes the previous state, so the host paces to
-        device throughput.  An explicit :meth:`record_speed` call switches
-        to manual mode — autotune never silently scores 0 either way."""
-        if self._manual_speed or self._autotune_completed:
-            # manual mode, or nothing will ever read the tracker (the only
-            # consumer is the autotune check-in) — skip the per-step host work
-            return
-        leaves = jax.tree.leaves(batch)
-        if not leaves or not jnp.ndim(leaves[0]):
-            return
-        now = time.time()
-        dt = now - self._last_speed_time
-        self._prev_speed_time = self._last_speed_time
-        self._last_speed_time = now
-        if self._skip_next_speed_sample:
-            # this interval spanned trace+compile of a (re)built step — a
-            # garbage low sample that would skew the autotune score; start
-            # the clock here instead
-            self._skip_next_speed_sample = False
-            return
-        if dt > 0:
-            self._speed_tracker.record(leaves[0].shape[0] / dt)
 
     def compiled_step(self, state: TrainState, batch) -> jax.stages.Compiled:
         """The ``jax.stages.Compiled`` of the step program the CURRENT
@@ -2489,8 +2085,6 @@ class BaguaTrainer:
         NCCL/CUDA expose no per-step cost model) — logged at warning with
         the backend name and counted in ``obs/cost_analysis_unavailable``
         so the silent-{} path is visible in the fleet view."""
-        from ..telemetry import counters
-
         key = self._step_key()
         cached = self._cost_analysis_cache.get(key)
         if cached is not None:
@@ -2517,9 +2111,8 @@ class BaguaTrainer:
             self._cost_analysis_cache[key] = {}
             self._memory_analysis_cache[key] = None
             return {}
-        from ..obs.memory import compiled_memory_analysis
-
-        self._memory_analysis_cache[key] = compiled_memory_analysis(compiled)
+        self._memory_analysis_cache[key] = \
+            self._observer.compiled_memory_analysis(compiled)
         result = dict(analysis) if analysis else {}
         if not result:
             logger.warning(
@@ -2612,8 +2205,6 @@ class BaguaTrainer:
             self._eval_fn = self._make_eval_fn(self._state_specs,
                                                self._batch_spec())
             self._eval_key = key
-        from ..communication import check_abort
-
         check_abort()
         loss = self._eval_fn(state, batch)
         if self._watchdog is not None:
@@ -3139,19 +2730,9 @@ class BaguaTrainer:
         from ..define import BaguaHyperparameter
 
         rank = env.get_rank()
-        now = time.time()
-        # windowed throughput since the last report (reference
-        # distributed.py:223), NOT a cumulative total — the score must
-        # reflect only the current hyperparameter config
-        speed = self._speed_tracker.get(now - self._last_report_time)
-        self._last_report_time = now
-        # perf hints: anomaly detections since the last check-in ride
-        # along, so the scorer can tell "this config is slow" from
-        # "rank 5 got slow for environmental reasons" — tuning against
-        # the wrong one oscillates
-        from ..obs import anomaly as _obs_anomaly
-
-        hints = _obs_anomaly.drain_perf_hints()
+        obs = self._observer
+        speed = obs.speed_since_last_report()
+        hints = obs.drain_perf_hints()
         hints_delivered = False
         try:
             if self._autotune_client is None:
@@ -3164,7 +2745,7 @@ class BaguaTrainer:
                 hyperparameters=self._current_hyperparameters().model_dump(),
                 speed=speed,
                 perf_hints=hints or None,
-                obs=self._autotune_obs_window(),
+                obs=obs.autotune_window(),
             )
             hints_delivered = True
             rsp = client.ask_hyperparameters(
@@ -3178,7 +2759,7 @@ class BaguaTrainer:
             if hints and not hints_delivered:
                 # a transient sidecar hiccup must not discard the taint
                 # signal — the next successful check-in carries it
-                _obs_anomaly.requeue_perf_hints(hints)
+                obs.requeue_perf_hints(hints)
             self._autotune_failures += 1
             logger.warning("autotune check-in failed (%d/3): %s",
                            self._autotune_failures, e)
@@ -3187,68 +2768,6 @@ class BaguaTrainer:
                 # connection timeouts for the rest of the run
                 logger.warning("autotune disabled after repeated failures")
                 self.autotune = False
-
-    def _autotune_obs_window(self) -> Optional[dict]:
-        """The rank's windowed efficiency observations for the check-in
-        (the v2 scoring input): goodput fraction of the window since the
-        last report — delta of the CUMULATIVE ledger classes, so compile
-        and migration badput the current config caused lands in its own
-        score — plus MFU, the DCN share of the step, HBM headroom, and the
-        rank-local anomaly flag from the obs summary.  ``None`` when the
-        obs plane is off (``BAGUA_OBS=off``), goodput reporting is
-        disabled (``BAGUA_AUTOTUNE_GOODPUT=off``), or no window has
-        elapsed yet — the service then scores on summed speed as before.
-        """
-        if self._ledger is None or not env.get_autotune_goodput():
-            return None
-        try:
-            rep = self._ledger.report()
-        except Exception:  # the score input must never take down training
-            return None
-        if not rep:
-            return None
-        classes = dict(rep.get("classes") or {})
-        snap = {"wall_s": float(rep.get("wall_s") or 0.0), "classes": classes}
-        prev, self._autotune_ledger_prev = self._autotune_ledger_prev, snap
-        if prev is None:
-            # first check-in: the window opens at the ledger's first noted
-            # second, so the initial config's own compile lands in its own
-            # score — and EVERY window is goodput-scored from window one
-            # (one speed-scaled sample would dominate best() forever)
-            prev = {"wall_s": 0.0, "classes": {}}
-        dwall = snap["wall_s"] - prev["wall_s"]
-        if dwall <= 0:
-            return None
-        from ..obs.ledger import GOODPUT_CLASSES
-
-        dgood = sum(
-            classes.get(c, 0.0) - prev["classes"].get(c, 0.0)
-            for c in GOODPUT_CLASSES
-        )
-        obs = {
-            "goodput_fraction": max(0.0, min(1.0, dgood / dwall)),
-            "window_wall_s": round(dwall, 3),
-        }
-        try:
-            from ..obs import export as _obs_export
-
-            summary = _obs_export.local_obs_summary() or {}
-        except Exception:
-            summary = {}
-        if summary.get("mfu") is not None:
-            obs["mfu"] = summary["mfu"]
-        dcn = summary.get("device_comm_dcn_s_per_step")
-        if dcn is not None:
-            obs["dcn_s_per_step"] = dcn
-            dt = summary.get("step_dt_p50")
-            if dt:
-                obs["dcn_share"] = max(0.0, min(1.0, float(dcn) / float(dt)))
-        if summary.get("hbm_headroom_bytes") is not None:
-            obs["hbm_headroom_bytes"] = summary["hbm_headroom_bytes"]
-        if summary.get("straggler_suspect"):
-            # the service discards (re-measures) anomaly-flagged windows
-            obs["anomaly"] = True
-        return obs
 
     def _current_hyperparameters(self):
         from ..define import BaguaHyperparameter
@@ -3816,8 +3335,6 @@ class BaguaTrainer:
             algo_state=jax.tree.map(retile, restored.algo_state,
                                     state_like.algo_state),
         )
-        from ..telemetry import counters
-
         counters.incr("ckpt/stacked_resize_restores")
         logger.info(
             "restore_checkpoint: re-tiled stacked step %s from world %d "
@@ -3862,25 +3379,6 @@ class BaguaTrainer:
         return jax.tree_util.tree_map_with_path(fix, state.params)
 
     def record_speed(self, n_samples: float):
-        """Manual override of the automatic per-step speed tracking: count
-        ``n_samples`` since the previous call (reference's speed metrics,
-        distributed.py:340-358).  Use when the batch pytree's leading dim is
-        not the sample count (e.g. token-weighted scoring)."""
-        now = time.time()
-        if not self._manual_speed:
-            # first manual call: discard auto-recorded samples (possibly in
-            # different units), but DO record this one — against the
-            # interval the auto path measured for the same step (its
-            # pre-advance timestamp), not the microseconds since it ran —
-            # so a check-in landing before the second call never scores 0
-            self._manual_speed = True
-            self._speed_tracker = StatisticalAverage()
-            dt = now - getattr(self, "_prev_speed_time", self._last_speed_time)
-            self._last_speed_time = now
-            if dt > 0:
-                self._speed_tracker.record(n_samples / dt)
-            return
-        dt = now - self._last_speed_time
-        self._last_speed_time = now
-        if dt > 0:
-            self._speed_tracker.record(n_samples / dt)
+        """Manual override of the automatic per-step speed tracking
+        (:meth:`StepObserver.record_speed`)."""
+        self._observer.record_speed(n_samples)

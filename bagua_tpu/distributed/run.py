@@ -36,7 +36,6 @@ previous attempt out of the current one.
 from __future__ import annotations
 
 import argparse
-import concurrent.futures as _futures
 import logging
 import os
 import signal
@@ -49,14 +48,11 @@ from .. import env as _env
 
 logger = logging.getLogger("bagua_tpu.launcher")
 
-# Errors that mean "this store connection is dead, get a new one".
-# TimeoutError needs BOTH spellings: the builtin (an OSError subclass
-# since 3.10) and futures-style timeouts, which store clients can raise
-# as a NON-OSError class on older interpreters — a timed-out socket is
-# as dead as a reset one either way.
-_STORE_RETRY_ERRORS = (
-    ConnectionError, OSError, TimeoutError, _futures.TimeoutError,
-)
+# Errors that mean "this store connection is dead, get a new one": a
+# timed-out socket is as dead as a reset one.  ConnectionError and
+# TimeoutError are OSErrors, and concurrent.futures.TimeoutError has been
+# the builtin TimeoutError since Python 3.11.
+_STORE_RETRY_ERRORS = (OSError,)
 
 
 def _local_tpu_chips() -> int:

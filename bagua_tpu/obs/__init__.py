@@ -21,9 +21,6 @@ Plus the analysis layer on top of those signals:
 * :mod:`~bagua_tpu.obs.anomaly` — rolling median/MAD step-time anomaly
   detector: ``straggler_suspect`` phase breakdowns into the health beacon,
   throttled flight dumps, perf hints for the autotune service.
-* :mod:`~bagua_tpu.obs.attribution` — device-time attribution: per-bucket
-  device comm seconds + overlap fraction from profiler xplanes
-  (null-with-rationale on cpu-sim).
 * :mod:`~bagua_tpu.obs.regress` — bench-trend sentinel against the
   committed ``BENCH_*.json``/``EFFICIENCY.json`` records
   (``python -m bagua_tpu.obs.regress``).

@@ -73,7 +73,6 @@ __all__ = [
     "MetricsExporter", "render_prometheus", "prepared_snapshot",
     "local_obs_summary",
     "note_step", "note_step_metrics", "note_anomaly",
-    "note_device_attribution", "last_device_attribution",
     "note_mfu", "last_mfu", "note_hbm_footprint", "last_hbm_footprint",
     "note_hbm_live", "last_hbm_live", "note_ckpt_directory",
     "build_fleet_record", "write_fleet_snapshot", "validate_fleet_snapshot",
@@ -240,23 +239,6 @@ _declare("obs/step_anomalies", "counter",
 _declare("obs/perf_hints", "counter",
          "Perf hints published for the autotune service (anomaly "
          "detections and other environmental performance signals).")
-# -- device-time attribution (profiler-derived, TPU only) --
-_declare("obs/device_comm_s_per_step", "gauge",
-         "Measured device communication seconds per step from the last "
-         "closed profiler window (null-with-rationale on cpu-sim).")
-_declare("obs/device_comm_ici_s_per_step", "gauge",
-         "Slice-local (ICI-tier) share of the measured device comm seconds "
-         "per step: the reduce-scatter + allgather stages of the "
-         "hierarchical two-level decomposition (docs/hierarchical.md); "
-         "present only when the per-bucket positional match held on a "
-         "two-level launch schedule.")
-_declare("obs/device_comm_dcn_s_per_step", "gauge",
-         "Cross-slice (DCN-tier) share of the measured device comm seconds "
-         "per step: the inter-slice allreduce stage riding the slow link — "
-         "the number the two-level decomposition exists to shrink.")
-_declare("obs/device_overlap_fraction", "gauge",
-         "Fraction of device comm time hidden under compute in the last "
-         "closed profiler window (parse_xplane_overlap).")
 # -- efficiency plane: goodput ledger + MFU + HBM accounting --
 for _cls in LEDGER_CLASSES:
     _declare(f"obs/ledger/{_cls}_s", "gauge",
@@ -460,7 +442,6 @@ _STEP_DTS: deque = deque(maxlen=64)
 _LAST_STEP: Optional[int] = None
 _LAST_STEP_METRICS: Dict[str, Any] = {}
 _LAST_ANOMALY: Optional[Dict[str, Any]] = None
-_LAST_DEVICE_ATTRIBUTION: Optional[Dict[str, Any]] = None
 _LAST_MFU: Optional[Dict[str, Any]] = None
 _LAST_HBM_FOOTPRINT: Optional[Dict[str, Any]] = None
 _LAST_HBM_LIVE: Optional[Dict[str, Any]] = None
@@ -498,38 +479,6 @@ def note_anomaly(suspect: Dict[str, Any]) -> None:
     global _LAST_ANOMALY
     with _SUMMARY_LOCK:
         _LAST_ANOMALY = dict(suspect)
-
-
-def note_device_attribution(record: Dict[str, Any]) -> None:
-    """Publish a device-time attribution record
-    (:func:`bagua_tpu.obs.attribution.attribute_device_comm`): summary
-    gauges for the exporter, the full record for the obs summary.  An
-    unavailable record (cpu-sim) is kept too — null-with-rationale beats
-    silence."""
-    global _LAST_DEVICE_ATTRIBUTION
-    with _SUMMARY_LOCK:
-        _LAST_DEVICE_ATTRIBUTION = dict(record)
-    if record.get("available"):
-        if record.get("comm_s_per_step") is not None:
-            counters.set_gauge("obs/device_comm_s_per_step",
-                               float(record["comm_s_per_step"]))
-        if record.get("overlap_fraction") is not None:
-            counters.set_gauge("obs/device_overlap_fraction",
-                               float(record["overlap_fraction"]))
-        # per-tier breakdown (hierarchical two-level schedules only): the
-        # DCN gauge is the slow-link cost the decomposition shrinks
-        if record.get("comm_ici_s_per_step") is not None:
-            counters.set_gauge("obs/device_comm_ici_s_per_step",
-                               float(record["comm_ici_s_per_step"]))
-        if record.get("comm_dcn_s_per_step") is not None:
-            counters.set_gauge("obs/device_comm_dcn_s_per_step",
-                               float(record["comm_dcn_s_per_step"]))
-
-
-def last_device_attribution() -> Optional[Dict[str, Any]]:
-    with _SUMMARY_LOCK:
-        return (dict(_LAST_DEVICE_ATTRIBUTION)
-                if _LAST_DEVICE_ATTRIBUTION is not None else None)
 
 
 def note_mfu(record: Dict[str, Any]) -> None:
@@ -610,8 +559,6 @@ def local_obs_summary() -> Optional[dict]:
         step = _LAST_STEP
         dts = sorted(_STEP_DTS)
         anomaly = dict(_LAST_ANOMALY) if _LAST_ANOMALY else None
-        attribution = (dict(_LAST_DEVICE_ATTRIBUTION)
-                       if _LAST_DEVICE_ATTRIBUTION else None)
         mfu = dict(_LAST_MFU) if _LAST_MFU else None
         footprint = dict(_LAST_HBM_FOOTPRINT) if _LAST_HBM_FOOTPRINT else None
         hbm_live = dict(_LAST_HBM_LIVE) if _LAST_HBM_LIVE else None
@@ -643,25 +590,6 @@ def local_obs_summary() -> Optional[dict]:
         # the fleet's straggler question, answered per rank: latest flagged
         # step, how slow, and which phase dominated the excess
         summary["straggler_suspect"] = anomaly
-    if attribution:
-        if attribution.get("available"):
-            summary["device_comm_s_per_step"] = attribution.get(
-                "comm_s_per_step")
-            summary["device_overlap_fraction"] = attribution.get(
-                "overlap_fraction")
-            if attribution.get("comm_dcn_s_per_step") is not None:
-                # per-tier split of the comm seconds (two-level schedules):
-                # the coordinator's fleet view can see DCN seconds move out
-                # of the step when the hierarchical path lands
-                summary["device_comm_ici_s_per_step"] = attribution.get(
-                    "comm_ici_s_per_step")
-                summary["device_comm_dcn_s_per_step"] = attribution.get(
-                    "comm_dcn_s_per_step")
-        else:
-            # null-with-rationale, like trace_overlap's bench records
-            summary["device_comm_s_per_step"] = None
-            summary["device_attribution_rationale"] = attribution.get(
-                "rationale")
     # efficiency plane: goodput fraction + badput breakdown (the fleet
     # rollup names each rank's worst badput class from these), MFU, and the
     # HBM footprint/headroom — all host-side accounting
@@ -695,7 +623,7 @@ def local_obs_summary() -> Optional[dict]:
 
 def reset_local_summary() -> None:
     """Forget the per-rank summary (test isolation)."""
-    global _LAST_STEP, _LAST_ANOMALY, _LAST_DEVICE_ATTRIBUTION
+    global _LAST_STEP, _LAST_ANOMALY
     global _LAST_MFU, _LAST_HBM_FOOTPRINT, _LAST_HBM_LIVE
     global _LAST_CKPT_DIRECTORY
     with _SUMMARY_LOCK:
@@ -703,7 +631,6 @@ def reset_local_summary() -> None:
         _STEP_DTS.clear()
         _LAST_STEP_METRICS.clear()
         _LAST_ANOMALY = None
-        _LAST_DEVICE_ATTRIBUTION = None
         _LAST_MFU = None
         _LAST_HBM_FOOTPRINT = None
         _LAST_HBM_LIVE = None
@@ -829,9 +756,6 @@ class MetricsExporter:
         metrics = last_step_metrics()
         if metrics:
             record["step_metrics"] = metrics
-        attribution = last_device_attribution()
-        if attribution:
-            record["device_attribution"] = attribution
         trainer = self._trainer() if self._trainer is not None else None
         if trainer is not None:
             dt = getattr(trainer, "measured_step_dt", None)

@@ -216,10 +216,10 @@ class GoodputLedger:
 
     def note_step_window(self, step: int, raw_seconds: float,
                          cls: str = "productive_step") -> None:
-        """Close one step's wall window (the trainer's cadence hook): the
-        window minus the class windows noted inside it is productive-step
-        time.  A window that contained a trace+compile or a state
-        migration (the trainer's ``_skip_next_speed_sample`` mirror)
+        """Close one step's wall window (the step observer's cadence
+        hook): the window minus the class windows noted inside it is
+        productive-step time.  A window that contained a trace+compile or
+        a state migration (``StepObserver.note_window_class``)
         passes ``cls="compile"``/``"state_migration"`` instead — its
         remainder is attributed there, not dropped and not mistaken for a
         step's worth of progress."""

@@ -95,7 +95,6 @@ class StepProfiler:
         self.stop = stop
         self._active = False
         self._done = False
-        self._closed_dir: Optional[str] = None
 
     @classmethod
     def from_env(cls) -> Optional["StepProfiler"]:
@@ -137,17 +136,9 @@ class StepProfiler:
             jax.profiler.stop_trace()
             self._active = False
             self._done = True
-            self._closed_dir = self.log_dir
             if _TRACE_OWNER is self:
                 _TRACE_OWNER = None
             logger.info("profiler: trace written to %s", self.log_dir)
-
-    def consume_closed_dir(self) -> Optional[str]:
-        """The log dir of a JUST-closed trace window, once (None after the
-        first read, and until another window closes) — the trainer's hook
-        for post-trace analysis like device-time attribution."""
-        d, self._closed_dir = self._closed_dir, None
-        return d
 
 
 # ---------------------------------------------------------------------------
@@ -399,52 +390,6 @@ def trace_overlap(run_step, steps: int = 5, finalize=None) -> dict:
             return {}
     finally:
         shutil.rmtree(d, ignore_errors=True)
-
-
-def parse_xplane_comm_events(xplane_path: str) -> dict:
-    """Per-occurrence communication events from the first TPU plane, in
-    device-time order — the device half of per-bucket comm attribution
-    (``bagua_tpu.obs.attribution`` matches these against the host's
-    ``trace/bucket_collective`` launch schedule).
-
-    Returns ``{}`` when the trace has no TPU plane or no comm ops;
-    otherwise::
-
-        {"events": [{"name", "t0_s", "dur_s"}, ...],   # sorted by t0_s
-         "n_steps": ..., "step_s": mean device step seconds}
-
-    ``-start``/``-done`` halves of one async collective both match
-    :func:`is_comm_op`; the ``-start`` op carries the wire time, the
-    ``-done`` is the wait — callers see both, named."""
-    plane = _first_tpu_plane(_load_xspace(xplane_path))
-    if plane is None:
-        return {}
-    emd = plane.event_metadata
-    events = []
-    n_steps = 0
-    step_ps = 0
-    for line in plane.lines:
-        if line.name == "Steps":
-            n_steps = len(line.events)
-            step_ps = sum(e.duration_ps for e in line.events)
-        if line.name != "XLA Ops":
-            continue
-        for ev in line.events:
-            name = emd[ev.metadata_id].name
-            if is_comm_op(name):
-                events.append({
-                    "name": name,
-                    "t0_s": ev.offset_ps / 1e12,
-                    "dur_s": ev.duration_ps / 1e12,
-                })
-    if not events:
-        return {}
-    events.sort(key=lambda e: e["t0_s"])
-    out = {"events": events}
-    if n_steps and step_ps:
-        out["n_steps"] = n_steps
-        out["step_s"] = step_ps / n_steps / 1e12
-    return out
 
 
 def parse_xplane_memory_traffic(xplane_path: str) -> dict:

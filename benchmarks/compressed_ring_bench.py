@@ -422,9 +422,9 @@ def run_suite(out_path: str = "BENCH_COMPRESS.json", trials: int = 3) -> list:
         "device_comm_dcn_s_per_step": None,
         "rationale": (
             DEVICE_TIME_RATIONALE if platform != "tpu" else
-            "no profiler window captured by this bench — set "
-            "BAGUA_PROFILE_DIR on a training run; the per-tier gauges "
-            "populate from obs/attribution when the window closes"
+            "no profiler window captured by this bench — per-tier device "
+            "seconds are read from a trace by the collectives' "
+            "bagua.comm/bucket_<i> op_name (perfbench/scopes.py)"
         ),
         "gauges": ["obs/device_comm_ici_s_per_step",
                    "obs/device_comm_dcn_s_per_step"],

@@ -16,9 +16,9 @@ against the flat single-collective path on a 2-slice x 4-chip
   reflect XLA:CPU operand sizes/fusion, not DCN relief; the byte
   accounting is the portable signal, the real win needs a multi-slice
   mesh.  Records carry the rationale.
-* **per-tier device seconds** — ``obs/device_comm_{ici,dcn}_s_per_step``
-  from the profiler-derived attribution; null-with-rationale on cpu-sim
-  like every device-time figure in this suite.
+* **per-tier device seconds** — a null-with-rationale record, like every
+  device-time figure in this suite (a chip trace reads them by the
+  collectives' ``bagua.comm/bucket_<i>`` op_name).
 
 Usage: python benchmarks/hierarchical_bench.py [--out BENCH_HIERARCHICAL.json]
 """
@@ -240,9 +240,9 @@ def run_suite(out_path: str = "BENCH_HIERARCHICAL.json",
         "device_comm_dcn_s_per_step": None,
         "rationale": (
             DEVICE_TIME_RATIONALE if platform != "tpu" else
-            "no profiler window captured by this bench — set "
-            "BAGUA_PROFILE_DIR on a training run; the per-tier gauges "
-            "populate from obs/attribution when the window closes"
+            "no profiler window captured by this bench — per-tier device "
+            "seconds are read from a trace by the collectives' "
+            "bagua.comm/bucket_<i> op_name (perfbench/scopes.py)"
         ),
         "gauges": ["obs/device_comm_ici_s_per_step",
                    "obs/device_comm_dcn_s_per_step"],

@@ -400,10 +400,10 @@ def legs_full_width(sz: dict, cache: CacheCounter, dryrun: bool) -> None:
     analysis = trainer.step_cost_analysis(state, data)
     log(f"leg A: step_cost_analysis joined in {time.perf_counter() - t0:.2f}s"
         f", flops/step = {analysis.get('flops')}, peak table entry = "
-        f"{trainer._peak_flops}")
+        f"{trainer._observer.peak_flops}")
     if not dryrun:
         assert analysis.get("flops", 0) > 0, analysis
-        assert trainer._peak_flops, "no peak-FLOPS entry for this device"
+        assert trainer._observer.peak_flops, "no peak-FLOPS entry for this device"
     del trainer, state, data, loss
 
     # ---- leg B: the signature relaxation ---------------------------------

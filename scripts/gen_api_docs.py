@@ -46,12 +46,12 @@ MODULES = [
     "bagua_tpu.obs.export",
     "bagua_tpu.obs.timeline",
     "bagua_tpu.obs.anomaly",
-    "bagua_tpu.obs.attribution",
     "bagua_tpu.obs.regress",
     "bagua_tpu.obs.ledger",
     "bagua_tpu.obs.memory",
     "bagua_tpu.obs.historian",
     "bagua_tpu.obs.http",
+    "bagua_tpu.obs.step_observer",
     "bagua_tpu.autopilot.policy",
     "bagua_tpu.autopilot.engine",
     "bagua_tpu.podsim.util",

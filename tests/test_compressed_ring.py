@@ -614,7 +614,7 @@ def test_zero_flat_rings_honor_forced_codec():
         trainer2.trace_step(state2, batch2)))
 
 
-def test_bucket_tier_bytes_codec_aware():
+def _tier_ctx_factory():
     from bagua_tpu.algorithms.base import AlgorithmContext
     from bagua_tpu.bucket import BucketPlan
     from bagua_tpu.communication import collapse_trivial_axes
@@ -636,40 +636,83 @@ def test_bucket_tier_bytes_codec_aware():
             world_size=N, **kw,
         )
 
+    return ctx
+
+
+def _family_default_shrinks_dcn():
+    ctx = _tier_ctx_factory()
     full = ctx().bucket_tier_bytes(0, True)
     comp = ctx().bucket_tier_bytes(0, True, dcn_codec="minmax_uint8")
     assert full["dcn_codec"] is None and comp["dcn_codec"] == "minmax_uint8"
     # u8 payload + 8B sidecar vs f32 shard: close to 4x, >= 3x
     assert full["dcn_bytes"] / comp["dcn_bytes"] >= 3.0
     assert comp["ici_bytes"] == full["ici_bytes"]
-    # the knob overrides the family default in BOTH directions
+
+
+def _knob_overrides_family_default():
+    # ... in BOTH directions
+    ctx = _tier_ctx_factory()
+    full = ctx().bucket_tier_bytes(0, True)
     forced_off = ctx(inter_codec="off").bucket_tier_bytes(
         0, True, dcn_codec="minmax_uint8")
     assert forced_off["dcn_bytes"] == full["dcn_bytes"]
     forced_fp8 = ctx(inter_codec="fp8_e4m3").bucket_tier_bytes(0, True)
     assert forced_fp8["dcn_codec"] == "fp8_e4m3"
+
+
+def _flat_path_scatter_gather_codec():
     # flat path on the two-tier mesh: bytegrad's scatter-gather wire codec
+    ctx = _tier_ctx_factory()
     flat_comp = ctx().bucket_tier_bytes(0, False,
                                         flat_codec="minmax_uint8")
     flat_full = ctx().bucket_tier_bytes(0, False)
     assert flat_full["dcn_bytes"] / flat_comp["dcn_bytes"] >= 3.0
 
 
-def test_launch_spans_report_compressed_bytes():
-    from bagua_tpu.obs import spans as obs_spans
-    from bagua_tpu.obs.attribution import bucket_launches_from_ring
+def _trained_tiers(algo_factory):
+    """The byte accounting of every bucket of a TRAINED overlap trainer's
+    own context, in its streamed launch order — what the trace-time launch
+    spans used to carry."""
+    _, tr = _train(algo_factory, optax.sgd(0.1), 4, steps=1, overlap="on")
+    assert tr._overlap_active()
+    algo = tr.algorithm
+    ctx = tr._ctx(tr._plan, overlap=True)
+    order = ctx.bucket_launch_order(True, dcn_codec=algo.wire_codec_dcn)
+    assert sorted(order) == list(range(len(tr._plan.buckets)))
+    return [ctx.bucket_tier_bytes(i, True, dcn_codec=algo.wire_codec_dcn,
+                                  flat_codec=algo.wire_codec_flat)
+            for i in order]
 
-    obs_spans.recorder.clear()
-    _train(lambda: ByteGradAlgorithm(hierarchical=True), optax.sgd(0.1),
-           4, steps=1, overlap="on")
-    launches = bucket_launches_from_ring()
-    assert launches, "overlap scheduler recorded no bucket launches"
-    for l in launches:
-        assert l["tier"] == "two_level"
+
+def _two_level_schedule_streams_dcn_first():
+    tiers = _trained_tiers(
+        lambda: GradientAllReduceAlgorithm(hierarchical=True))
+    assert all(t["tier"] == "two_level" for t in tiers)
+    # the DCN stage carries the 1/intra shard
+    assert all(t["dcn_bytes"] <= t["bytes"] // INTRA for t in tiers)
+    # DCN-dominant-first: the launch order is descending DCN bytes
+    dcn = [t["dcn_bytes"] for t in tiers]
+    assert dcn == sorted(dcn, reverse=True)
+
+
+def _bytegrad_two_level_reports_compressed_bytes():
+    tiers = _trained_tiers(lambda: ByteGradAlgorithm(hierarchical=True))
+    for t in tiers:
+        assert t["tier"] == "two_level"
         # compressed estimate: u8 shard + sidecar, well under the f32
         # shard the tier would otherwise report
-        assert l["dcn_bytes"] < l["bytes"] // INTRA
-    obs_spans.recorder.clear()
+        assert t["dcn_bytes"] < t["bytes"] // INTRA
+
+
+@pytest.mark.parametrize("case", [
+    _family_default_shrinks_dcn,
+    _knob_overrides_family_default,
+    _flat_path_scatter_gather_codec,
+    _two_level_schedule_streams_dcn_first,
+    _bytegrad_two_level_reports_compressed_bytes,
+], ids=lambda f: f.__name__.lstrip("_"))
+def test_bucket_tier_bytes_codec_aware(case):
+    case()
 
 
 def test_env_registry_and_step_key():

@@ -396,10 +396,11 @@ def test_store_barrier_timeout_raises_clear_message(store_server):
     assert "restart/ready/0" in msg and "2 nodes" in msg and "timed out" in msg
 
 
-def test_restart_store_retries_non_oserror_timeout(store_server, monkeypatch):
-    """_RestartStore._retry must refresh the connection on TimeoutError
-    subclasses that are NOT OSError (futures-style timeouts), and log the
-    op it retried."""
+def test_restart_store_retries_client_timeout(store_server, monkeypatch):
+    """_RestartStore._retry must refresh the connection when the client
+    times out — under either spelling: futures-style timeouts are the
+    builtin TimeoutError (an OSError) on every interpreter the repo
+    targets — and complete the op it retried."""
     import concurrent.futures
 
     import bagua_tpu.distributed.run as run_mod
@@ -416,13 +417,12 @@ def test_restart_store_retries_non_oserror_timeout(store_server, monkeypatch):
             self.calls += 1
             raise concurrent.futures.TimeoutError("simulated client timeout")
 
-    assert not isinstance(
-        concurrent.futures.TimeoutError("x"), OSError
-    ), "this interpreter aliases futures.TimeoutError; test needs updating"
+    assert concurrent.futures.TimeoutError is TimeoutError
+    assert run_mod._STORE_RETRY_ERRORS == (OSError,)
     rs.set("elastic-retry-test", b"v")
     flaky = _FlakyClient(rs._client)
     rs._client = flaky
-    # the flaky client times out (non-OSError); _retry must reconnect and
+    # the flaky client times out; _retry must reconnect and
     # complete the SAME op on the fresh connection
     assert rs.get("elastic-retry-test") == b"v"
     assert flaky.calls == 1

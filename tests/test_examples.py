@@ -13,8 +13,13 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run_example(script, *args, timeout=420):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
+    # XLA:CPU aborts the process when the 8 device threads of one
+    # collective do not all arrive within 40 s — which a loaded host (the
+    # tier-1 run's six workers, eight virtual devices each) does not
+    # promise: the subprocess gets the test's own limit instead
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
+        f" --xla_cpu_collective_call_terminate_timeout_seconds={timeout}"
     )
     env["BAGUA_SERVICE_PORT"] = "-1"
     # scripts run by path get examples/ as sys.path[0]

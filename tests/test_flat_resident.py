@@ -120,8 +120,13 @@ def test_flat_matches_leaf(algo_factory, optimizer, exact, accum):
         jax.tree_util.tree_structure(_params())
     )
     if exact:
-        np.testing.assert_array_equal(l_flat, l_leaf)
-        _leaf_allclose(tr_flat, st_flat, tr_leaf, st_leaf)
+        # two differently fused programs: the flat step sums each bucket's
+        # gradient (and, for momentum, its update) as one 1-D buffer, the
+        # leaf step per leaf, so XLA:CPU may associate the same sums in
+        # another order — a few float32 ulp, never more
+        np.testing.assert_allclose(l_flat, l_leaf, rtol=1e-6, atol=0)
+        _leaf_allclose(tr_flat, st_flat, tr_leaf, st_leaf,
+                       rtol=1e-6, atol=1e-8)
     else:
         np.testing.assert_allclose(l_flat, l_leaf, rtol=0.05, atol=0.02)
 
@@ -461,7 +466,9 @@ def test_relayout_flats_unit():
 
 def test_checkpoint_flat_leaf_flat_continuity(tmp_path):
     """save-flat -> restore-leaf -> restore-flat (different plan) against
-    the uninterrupted golden-task trajectory, exactly."""
+    the uninterrupted golden-task trajectory: bit-equal while both sides
+    run the same compiled program, within a few float32 ulp once the
+    layout (and with it the program) differs."""
     import bench
 
     loss_fn, params, batch = bench.golden_task()
@@ -512,7 +519,12 @@ def test_checkpoint_flat_leaf_flat_continuity(tmp_path):
     assert set(s3.params.keys()) == {"flats", "local"}
     s3, l3 = run(t3, s3, 3)
 
-    np.testing.assert_array_equal(np.array(l1 + l2 + l3), np.array(base))
+    # same layout, same plan, same program: the bits
+    np.testing.assert_array_equal(np.array(l1), np.array(base[:3]))
+    # the leaf step and the re-planned flat step are other programs than
+    # the reference's: their bucket sums may associate differently
+    np.testing.assert_allclose(np.array(l2 + l3), np.array(base[3:]),
+                               rtol=1e-6, atol=0)
     mgr.close()
 
 

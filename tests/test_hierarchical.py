@@ -275,11 +275,11 @@ def test_two_tier_matches_flat_trajectory(algo_factory, optimizer, exact,
     l_flat, st_flat, tr_flat = _train(algo_factory, optimizer, accum, False)
     l_two, st_two, tr_two = _train(algo_factory, optimizer, accum, True)
     if exact:
-        # sgd-family loss trajectories are BIT-equal on this pinned
-        # workload (params drift only in the last ulp from sum
-        # association; over these 5 steps the scalar losses coincide
-        # bitwise — deterministic for fixed seeds on this platform)
-        np.testing.assert_array_equal(l_two, l_flat)
+        # two different programs: the two-level decomposition sums within
+        # a slice, then across slices, the flat collective over all ranks
+        # at once, so the same gradients associate in another order —
+        # params and the losses read off them agree to a few float32 ulp
+        np.testing.assert_allclose(l_two, l_flat, rtol=1e-6, atol=0)
         for a, b in zip(jax.tree.leaves(tr_two.unstack_params(st_two)),
                         jax.tree.leaves(tr_flat.unstack_params(st_flat))):
             np.testing.assert_allclose(
@@ -440,25 +440,6 @@ def test_bucket_launch_order_streams_dcn_dominant_first():
     assert flat_tiers["dcn_bytes"] > tiers["dcn_bytes"]
 
 
-def test_two_level_launch_spans_record_tier():
-    """The streamed schedule's spans carry tier + per-tier bytes so
-    obs/attribution can split device comm seconds into ICI vs DCN."""
-    from bagua_tpu.obs import spans as obs_spans
-    from bagua_tpu.obs.attribution import bucket_launches_from_ring
-
-    obs_spans.recorder.clear()
-    _train(lambda h: GradientAllReduceAlgorithm(hierarchical=h),
-           optax.sgd(0.1), 4, True, overlap="on", steps=1)
-    launches = bucket_launches_from_ring()
-    assert launches, "overlap scheduler recorded no bucket launches"
-    assert all(l["tier"] == "two_level" for l in launches)
-    assert all(l["dcn_bytes"] <= l["bytes"] // INTRA for l in launches)
-    # DCN-dominant-first: the recorded launch order is descending DCN bytes
-    dcn = [l["dcn_bytes"] for l in launches]
-    assert dcn == sorted(dcn, reverse=True)
-    obs_spans.recorder.clear()
-
-
 # ---- knobs: env/step-cache/autotune plumbing ---------------------------
 
 
@@ -552,140 +533,3 @@ def test_get_backend_invalidated_on_mesh_change():
     assert be_b is not be_a
     assert be_b.mesh is mesh_b
     assert be_b.global_communicator.mesh is mesh_b
-
-
-# ---- device-time attribution: per-tier split ---------------------------
-
-
-def _two_level_xplane(tmp_path, n_steps=2, buckets=((4096, 1024),
-                                                    (2048, 512)),
-                      phase_split=False):
-    """Synthetic TPU plane for a two-level schedule.  Default: per step
-    and bucket, three comm occurrences in issue order — ICI
-    reduce-scatter, DCN allreduce, ICI allgather (rs/ag sized by the
-    bucket, the DCN stage by its shard).  ``phase_split=True`` emits the
-    ZeRO-hierarchical shape instead: all (rs, ar) pairs in the backward
-    window, then all allgathers in the optimizer phase."""
-    pytest.importorskip("tensorflow.tsl.profiler.protobuf")
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
-
-    xs = xplane_pb2.XSpace()
-    plane = xs.planes.add(name="/device:TPU:0")
-    em = plane.event_metadata
-    em[1].id = 1
-    em[1].name = "reduce-scatter-start.1"
-    em[2].id = 2
-    em[2].name = "all-reduce-start.2"
-    em[3].id = 3
-    em[3].name = "all-gather-start.3"
-    steps = plane.lines.add(name="Steps")
-    for _ in range(n_steps):
-        ev = steps.events.add()
-        ev.duration_ps = int(0.010e12)
-    ops = plane.lines.add(name="XLA Ops")
-    t = 0
-
-    def _emit(mid, nbytes):
-        nonlocal t
-        ev = ops.events.add()
-        ev.metadata_id = mid
-        ev.offset_ps = t
-        ev.duration_ps = int(nbytes * 1e5)
-        t += ev.duration_ps
-
-    for _ in range(n_steps):
-        if phase_split:
-            for full, shard in buckets:
-                _emit(1, full)
-                _emit(2, shard)
-            for full, _ in buckets:
-                _emit(3, full)
-        else:
-            for full, shard in buckets:
-                for mid, nbytes in ((1, full), (2, shard), (3, full)):
-                    _emit(mid, nbytes)
-    (tmp_path / "hier.xplane.pb").write_bytes(xs.SerializeToString())
-
-
-def test_attribution_splits_two_level_schedule_per_tier(tmp_path):
-    from bagua_tpu.obs import export as obs_export
-    from bagua_tpu.obs.attribution import attribute_device_comm
-
-    _two_level_xplane(tmp_path)
-    launches = [
-        {"bucket": 0, "bytes": 4096, "tier": "two_level",
-         "ici_bytes": 2 * 4096, "dcn_bytes": 1024},
-        {"bucket": 1, "bytes": 2048, "tier": "two_level",
-         "ici_bytes": 2 * 2048, "dcn_bytes": 512},
-    ]
-    out = attribute_device_comm(str(tmp_path), bucket_launches=launches)
-    assert out["available"] is True
-    per = {b["bucket"]: b for b in out["per_bucket"]}
-    # stage durations were synthesized proportional to bytes: rs+ag = 2x
-    # the full bucket, the DCN allreduce = the shard
-    assert per[0]["device_ici_s"] == pytest.approx(2 * 4096 * 1e5 / 1e12)
-    assert per[0]["device_dcn_s"] == pytest.approx(1024 * 1e5 / 1e12)
-    assert per[0]["device_comm_s"] == pytest.approx(
-        per[0]["device_ici_s"] + per[0]["device_dcn_s"])
-    assert out["comm_dcn_s_per_step"] == pytest.approx(
-        (1024 + 512) * 1e5 / 1e12)
-    assert out["comm_ici_s_per_step"] == pytest.approx(
-        2 * (4096 + 2048) * 1e5 / 1e12)
-    # the gauges + obs summary carry the split
-    obs_export.reset_local_summary()
-    try:
-        obs_export.note_step(5, 0.01)
-        obs_export.note_device_attribution(out)
-        summary = obs_export.local_obs_summary()
-        assert summary["device_comm_dcn_s_per_step"] == pytest.approx(
-            out["comm_dcn_s_per_step"])
-        assert summary["device_comm_ici_s_per_step"] == pytest.approx(
-            out["comm_ici_s_per_step"])
-        from bagua_tpu.telemetry import counters
-
-        snap = counters.snapshot()
-        assert snap["obs/device_comm_dcn_s_per_step"] == pytest.approx(
-            out["comm_dcn_s_per_step"])
-    finally:
-        obs_export.reset_local_summary()
-
-
-def test_attribution_phase_split_schedule_degrades_per_bucket_only(tmp_path):
-    """ZeRO-hierarchical issues all (rs, ar) pairs in the backward window
-    and the allgathers later in the optimizer phase — NOT contiguous
-    per-bucket triples.  The per-bucket positional split must degrade
-    (rationale, never a mis-attribution), while the per-tier totals still
-    report correctly: they classify by op NAME, not position."""
-    from bagua_tpu.obs.attribution import attribute_device_comm
-
-    _two_level_xplane(tmp_path, phase_split=True)
-    launches = [
-        {"bucket": 0, "bytes": 4096, "tier": "two_level",
-         "ici_bytes": 2 * 4096, "dcn_bytes": 1024},
-        {"bucket": 1, "bytes": 2048, "tier": "two_level",
-         "ici_bytes": 2 * 2048, "dcn_bytes": 512},
-    ]
-    out = attribute_device_comm(str(tmp_path), bucket_launches=launches)
-    assert out["available"] is True
-    assert out["per_bucket"] is None
-    assert "contiguous" in out["per_bucket_rationale"]
-    # name-classified tier totals are order-independent and stay exact
-    assert out["comm_dcn_s_per_step"] == pytest.approx(
-        (1024 + 512) * 1e5 / 1e12)
-    assert out["comm_ici_s_per_step"] == pytest.approx(
-        2 * (4096 + 2048) * 1e5 / 1e12)
-
-
-def test_attribution_two_level_mismatch_degrades_with_rationale(tmp_path):
-    from bagua_tpu.obs.attribution import attribute_device_comm
-
-    _two_level_xplane(tmp_path)
-    # three launches cannot positionally absorb 2 buckets x 3 stages
-    launches = [
-        {"bucket": i, "bytes": 64, "tier": "two_level",
-         "ici_bytes": 128, "dcn_bytes": 16}
-        for i in range(3)
-    ]
-    out = attribute_device_comm(str(tmp_path), bucket_launches=launches)
-    assert out["available"] is True and out["per_bucket"] is None
-    assert "do not map" in out["per_bucket_rationale"]

@@ -247,7 +247,7 @@ def test_live_memory_null_with_rationale_on_cpu(ledger_on):
     assert rec["available"] is False
     assert rec["rationale"]
     t, s, b = _golden_trainer()
-    t._last_beacon_write = 0.0
+    t._observer._last_beacon_write = 0.0
     s, _ = t.train_step(s, b)  # beacon-cadence poll publishes the record
     summary = obs_export.local_obs_summary()
     assert "hbm_live_rationale" in summary

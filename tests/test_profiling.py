@@ -119,121 +119,3 @@ def test_newest_xplane_is_mtime_ordered(tmp_path):
     assert _newest_xplane(str(tmp_path)) == str(new)
     assert _newest_xplane(str(tmp_path / "plugins")) == str(old)
     assert _newest_xplane(str(tmp_path / "nope")) is None
-
-
-def _comm_xplane(tmp_path, n_steps=2, buckets=(4096, 8192, 1024)):
-    """Synthetic TPU plane: per step, one comm op occurrence per bucket
-    (duration scaled by bucket bytes) plus one compute fusion."""
-    pytest.importorskip("tensorflow.tsl.profiler.protobuf")
-    from tensorflow.tsl.profiler.protobuf import xplane_pb2
-
-    xs = xplane_pb2.XSpace()
-    plane = xs.planes.add(name="/device:TPU:0")
-    em = plane.event_metadata
-    em[1].id = 1
-    em[1].name = "all-reduce-start.1"
-    em[2].id = 2
-    em[2].name = "fusion.7"
-    steps = plane.lines.add(name="Steps")
-    for _ in range(n_steps):
-        ev = steps.events.add()
-        ev.duration_ps = int(0.010e12)
-    ops = plane.lines.add(name="XLA Ops")
-    t = 0
-    for _ in range(n_steps):
-        for nbytes in buckets:
-            ev = ops.events.add()
-            ev.metadata_id = 1
-            ev.offset_ps = t
-            ev.duration_ps = int(nbytes * 1e5)  # dur proportional to bytes
-            t += ev.duration_ps
-        ev = ops.events.add()
-        ev.metadata_id = 2
-        ev.offset_ps = t
-        ev.duration_ps = int(0.006e12)
-        t += ev.duration_ps
-    path = tmp_path / "comm.xplane.pb"
-    path.write_bytes(xs.SerializeToString())
-    return str(path)
-
-
-def test_parse_xplane_comm_events_synthetic(tmp_path):
-    from bagua_tpu.profiling import parse_xplane_comm_events
-
-    path = _comm_xplane(tmp_path)
-    out = parse_xplane_comm_events(path)
-    assert out["n_steps"] == 2
-    assert len(out["events"]) == 6            # 3 buckets x 2 steps
-    assert [e["t0_s"] for e in out["events"]] == sorted(
-        e["t0_s"] for e in out["events"])
-    assert all(e["name"].startswith("all-reduce") for e in out["events"])
-
-
-def test_device_attribution_per_bucket(tmp_path):
-    """Host bucket launches x device comm occurrences -> per-bucket device
-    comm seconds; occurrence durations scale with bucket bytes, so the
-    positional match must assign the big bucket the big time."""
-    from bagua_tpu.obs.attribution import attribute_device_comm
-
-    _comm_xplane(tmp_path, buckets=(4096, 8192, 1024))
-    launches = [{"bucket": 0, "bytes": 4096}, {"bucket": 1, "bytes": 8192},
-                {"bucket": 2, "bytes": 1024}]
-    out = attribute_device_comm(str(tmp_path), bucket_launches=launches)
-    assert out["available"] is True
-    per = {b["bucket"]: b for b in out["per_bucket"]}
-    assert per[1]["device_comm_s"] > per[0]["device_comm_s"] \
-        > per[2]["device_comm_s"]
-    assert per[1]["device_comm_s"] == pytest.approx(8192 * 1e5 / 1e12)
-    assert out["per_op"][0]["occurrences"] == 6
-    # mismatched bucket count degrades to per-op with a rationale
-    out2 = attribute_device_comm(str(tmp_path),
-                                 bucket_launches=launches[:2])
-    assert out2["available"] is True and out2["per_bucket"] is None
-    assert "do not map" in out2["per_bucket_rationale"]
-
-
-def test_device_attribution_null_with_rationale(tmp_path):
-    """cpu-sim convention: no TPU plane -> available False plus a human
-    rationale (like trace_overlap's bench records), and the summary path
-    carries it."""
-    from bagua_tpu.obs import export as obs_export
-    from bagua_tpu.obs.attribution import attribute_device_comm
-
-    out = attribute_device_comm(str(tmp_path))
-    assert out["available"] is False and out["rationale"]
-    obs_export.reset_local_summary()
-    try:
-        obs_export.note_step(5, 0.01)
-        obs_export.note_device_attribution(out)
-        summary = obs_export.local_obs_summary()
-        assert summary["device_comm_s_per_step"] is None
-        assert summary["device_attribution_rationale"] == out["rationale"]
-    finally:
-        obs_export.reset_local_summary()
-
-
-def test_bucket_launches_from_ring():
-    from bagua_tpu.obs import spans as obs_spans
-    from bagua_tpu.obs.attribution import bucket_launches_from_ring
-
-    spans = [
-        {"name": "trace/bucket_collective", "t0": 1.0, "t1": 1.1,
-         "attrs": {"bucket": 1, "bytes": 10}},
-        {"name": "trace/bucket_collective", "t0": 0.5, "t1": 0.6,
-         "attrs": {"bucket": 0, "bytes": 20}},
-        {"name": "step/dispatch", "t0": 0.4, "t1": 2.0},
-        # a re-trace of bucket 0 supersedes the earlier record
-        {"name": "trace/bucket_collective", "t0": 3.0, "t1": 3.1,
-         "attrs": {"bucket": 0, "bytes": 30}},
-    ]
-    out = bucket_launches_from_ring(spans)
-    # tier defaults: spans without tier attrs are flat single-collective
-    # launches (ici_bytes = the full operand, nothing on DCN)
-    assert out == [
-        {"bucket": 1, "bytes": 10, "tier": "flat", "ici_bytes": 10,
-         "dcn_bytes": 0},
-        {"bucket": 0, "bytes": 30, "tier": "flat", "ici_bytes": 30,
-         "dcn_bytes": 0},
-    ]
-    obs_spans.recorder.clear()
-    assert bucket_launches_from_ring() == []

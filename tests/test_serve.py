@@ -304,11 +304,14 @@ def test_serving_load_detects_corruption(model_and_params, tmp_path):
     d = str(tmp_path / "artifact")
     save_serving_artifact(d, params, step=1)
     save_serving_artifact(d, mutated, step=2)
+    # the array data, not orbax's own small JSON metadata (the first file
+    # over 256 bytes in glob order can be _CHECKPOINT_METADATA, which the
+    # restore does not need: the content digest rightly still verifies)
     files = [f for f in glob.glob(os.path.join(d, "2", "**"),
-                                  recursive=True)
-             if os.path.isfile(f) and os.path.getsize(f) > 256]
-    assert files, "expected a data file to corrupt"
-    with open(files[0], "r+b") as f:
+                                  recursive=True) if os.path.isfile(f)]
+    data = max(files, key=os.path.getsize)
+    assert os.path.getsize(data) > 4096, "expected a data file to corrupt"
+    with open(data, "r+b") as f:
         f.seek(128)
         f.write(b"\xff" * 64)
     before = counters.get("ckpt/fallback_restores")
