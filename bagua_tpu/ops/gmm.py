@@ -12,9 +12,31 @@ TPU-first design: the kernels work on a block-aligned PADDED layout (each
 group's rows rounded up to the 128-row MXU tile), in which every row block
 belongs to exactly ONE group — a scalar-prefetched per-block group id then
 steers the ``rhs`` BlockSpec, so each grid step is a single dense MXU matmul
-with no masking.  The dK accumulation kernel walks row blocks innermost and
-revisits its (group, d, f) output block across consecutive steps, the
-standard Pallas accumulation pattern.
+with no masking.
+
+What each kernel keeps resident and what it streams (Pallas copies no block
+whose index the grid step before had; a group's row blocks are consecutive):
+
+``gmm_fwd`` (the forward product, and d_lhs on the transposed matrices)
+    grid ``(f / bf, row blocks)``, rows inner.  RESIDENT: the group's
+    ``[d, bf]`` slab of its matrix, fetched once per group and ``f`` block —
+    the matrices are read once a call.  STREAMED: a ``[128, d]`` row block
+    in and a ``[128, bf]`` result block out every step, so the rows are read
+    ``f / bf`` times; ``bf`` is the widest block of ``f`` that fits VMEM
+    (the whole ``f`` at OLMoE's widths: a 4 MB slab, rows read once).
+``gmm_bwd_drhs`` (d_rhs, the grouped outer product)
+    grid ``(d / bd, f / bf, row blocks)``, rows innermost.  RESIDENT: the
+    group's float32 ``[bd, bf]`` output block, zeroed at the group's first
+    row block, accumulated over its rows and written back once.  STREAMED:
+    a ``[128, bd]`` and a ``[128, bf]`` row block every step — ``lhs`` is
+    read ``f / bf`` times and the cotangent ``d / bd`` times; ``(bd, bf)``
+    is the shape that fits VMEM under which that is least (the whole
+    ``[d, f]`` at OLMoE's widths: 8 MB of float32, both read once).
+
+The blocks are chosen from what a call can observe — ``d``, ``f``, the
+itemsize and the core's VMEM (:func:`_vmem_limit`, which the call also sets
+as its scoped limit: Mosaic's default of 16 MiB would be the binding one) —
+and the optional ``block_f`` only caps them.
 
 The layout is a value of its own (:class:`PaddedLayout`, from the group
 sizes alone), not a detail of one call: a run of products over the same
@@ -40,10 +62,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from .tiles import pick_block
+from .tiles import divisor_blocks
 
 _BLOCK_ROWS = 128
-_BLOCK_F = 512
 
 
 def gmm_reference(lhs, rhs, group_sizes):
@@ -176,30 +197,93 @@ def _unpad_rows_bwd(src, g):
 unpad_rows.defvjp(_unpad_rows_fwd, _unpad_rows_bwd)
 
 
+def _vmem_limit() -> int:
+    """The scoped VMEM a call asks of Mosaic: half of the core's, never
+    under the compiler's own default of 16 MiB (all of a v4's).  Where
+    Pallas knows no chip (interpret mode, a compile for a described chip)
+    it is the v5e's 64 of 128 MiB."""
+    try:
+        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:
+        capacity = 128 << 20
+    return max(capacity // 2, 16 << 20)
+
+
+def _fits(block_bytes: int, vmem_limit: int) -> bool:
+    """A quarter of the limit stays free for the compiler's own scratch."""
+    return 4 * block_bytes <= 3 * vmem_limit
+
+
+def _blocks_under(dim: int, cap: int) -> list[int]:
+    """``dim``'s candidate blocks of at most ``cap``, widest first; the
+    narrowest alone where ``cap`` is under all of them."""
+    blocks = divisor_blocks(dim)
+    return [b for b in blocks if b <= cap] or blocks[-1:]
+
+
+def _fwd_block_f(d, f, block_rows, itemsize, cap, vmem_limit) -> int:
+    """The widest block of ``f`` (at most ``cap``) whose blocks fit: matrix
+    slab, row block and result block, each double-buffered by the pipeline,
+    and the float32 product before it is cast.  The wider, the fewer times
+    the rows are streamed (``f / bf``) and the fewer grid steps."""
+    def block_bytes(bf):
+        return (2 * itemsize * (d * bf + block_rows * d + block_rows * bf)
+                + 4 * block_rows * bf)
+    blocks = _blocks_under(f, cap)
+    return next((bf for bf in blocks if _fits(block_bytes(bf), vmem_limit)),
+                blocks[-1])
+
+
+def _drhs_blocks(d, f, block_rows, itemsize, cap, vmem_limit):
+    """``(bd, bf)`` of the resident float32 output block, each at most
+    ``cap``: of the shapes that fit (the block double-buffered, a product
+    as large before it is added, two double-buffered row blocks) the one
+    under which the row operands are read least (``lhs`` ``f / bf`` times,
+    the cotangent ``d / bd`` times), the wider ``bf`` on a tie; the
+    narrowest where none fits."""
+    def block_bytes(bd, bf):
+        return 3 * 4 * bd * bf + 2 * itemsize * block_rows * (bd + bf)
+    shapes = [(bd, bf) for bd in _blocks_under(d, cap)
+              for bf in _blocks_under(f, cap)]
+    fitting = [s for s in shapes if _fits(block_bytes(*s), vmem_limit)]
+    return min(fitting or shapes[-1:],
+               key=lambda s: (1 / s[0] + 1 / s[1], -s[1]))
+
+
 def _fwd_kernel(gid_ref, lhs_ref, rhs_ref, out_ref):
     out_ref[:] = jnp.dot(
         lhs_ref[:], rhs_ref[0], preferred_element_type=jnp.float32
     ).astype(out_ref.dtype)
 
 
+def _fwd_grid_spec(padded_rows, d, f, block_rows, bf):
+    """``f`` blocks outer, row blocks inner: over a group's consecutive row
+    blocks the matrix index ``(gid[i], 0, j)`` does not change, and Pallas
+    copies no block whose index the step before had — a group's ``[d, bf]``
+    slab is fetched once per ``f`` block, not once per row block."""
+    return pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(f // bf, padded_rows // block_rows),
+        in_specs=[
+            pl.BlockSpec((block_rows, d), lambda j, i, gid: (i, 0)),
+            pl.BlockSpec((1, d, bf), lambda j, i, gid: (gid[i], 0, j)),
+        ],
+        out_specs=pl.BlockSpec((block_rows, bf), lambda j, i, gid: (i, j)),
+    )
+
+
 def _gmm_padded(lhs_p, rhs, g_of_block, block_rows, block_f, interpret):
     """lhs_p: [padded_rows, d] (group-blocked), rhs: [G, d, f]."""
     padded_rows, d = lhs_p.shape
     _, _, f = rhs.shape
-    bf = pick_block(f, block_f)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(padded_rows // block_rows, f // bf),
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda i, j, gid: (i, 0)),
-            pl.BlockSpec((1, d, bf), lambda i, j, gid: (gid[i], 0, j)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, bf), lambda i, j, gid: (i, j)),
-    )
+    vmem_limit = _vmem_limit()
+    bf = _fwd_block_f(d, f, block_rows, lhs_p.dtype.itemsize, block_f or f,
+                      vmem_limit)
     return pl.pallas_call(
         _fwd_kernel,
-        grid_spec=grid_spec,
+        grid_spec=_fwd_grid_spec(padded_rows, d, f, block_rows, bf),
         out_shape=jax.ShapeDtypeStruct((padded_rows, f), lhs_p.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name="gmm_fwd",
     )(g_of_block, lhs_p, rhs)
@@ -220,13 +304,11 @@ def _drhs_kernel(gid_ref, lhs_ref, g_ref, out_ref):
     )[None].astype(out_ref.dtype)
 
 
-def _gmm_drhs_padded(lhs_p, gout_p, n_groups, d, f, g_of_block, block_rows,
-                     block_f, interpret):
-    """d_rhs[g] = lhs_g^T @ gout_g over padded row blocks: [G, d, f] f32."""
-    padded_rows = lhs_p.shape[0]
-    bf = pick_block(f, block_f)
-    bd = pick_block(d, block_f)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
+def _drhs_grid_spec(padded_rows, d, f, block_rows, bd, bf):
+    """Row blocks innermost: the output block ``(gid[k], i, j)`` is the
+    resident one, accumulated over a group's consecutive row blocks and
+    written back once per group."""
+    return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(d // bd, f // bf, padded_rows // block_rows),
         in_specs=[
@@ -235,10 +317,20 @@ def _gmm_drhs_padded(lhs_p, gout_p, n_groups, d, f, g_of_block, block_rows,
         ],
         out_specs=pl.BlockSpec((1, bd, bf), lambda i, j, k, gid: (gid[k], i, j)),
     )
+
+
+def _gmm_drhs_padded(lhs_p, gout_p, n_groups, d, f, g_of_block, block_rows,
+                     block_f, interpret):
+    """d_rhs[g] = lhs_g^T @ gout_g over padded row blocks: [G, d, f] f32."""
+    padded_rows = lhs_p.shape[0]
+    vmem_limit = _vmem_limit()
+    bd, bf = _drhs_blocks(d, f, block_rows, lhs_p.dtype.itemsize,
+                          block_f or max(d, f), vmem_limit)
     return pl.pallas_call(
         _drhs_kernel,
-        grid_spec=grid_spec,
+        grid_spec=_drhs_grid_spec(padded_rows, d, f, block_rows, bd, bf),
         out_shape=jax.ShapeDtypeStruct((n_groups, d, f), jnp.float32),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
         name="gmm_bwd_drhs",
     )(g_of_block, lhs_p, gout_p)
@@ -274,15 +366,16 @@ def _gmm_padded_bwd(block_f, interpret, res, gout_p):
 _gmm_padded_vjp.defvjp(_gmm_padded_fwd, _gmm_padded_bwd)
 
 
-def gmm_padded(lhs_p, rhs, layout: PaddedLayout, *, block_f: int = _BLOCK_F,
-               interpret: bool = False):
+def gmm_padded(lhs_p, rhs, layout: PaddedLayout, *,
+               block_f: int | None = None, interpret: bool = False):
     """Grouped matmul in padded space: ``lhs_p`` [padded rows, d] laid out
     by ``layout`` (padding rows zero) times ``rhs`` [G, d, f] -> [padded
     rows, f], padding rows zero.  Differentiable in ``lhs_p`` and ``rhs``
     without leaving the layout; a cotangent's padding rows add nothing to
-    d_rhs (``lhs_p`` is zero there).  On an unpadded layout
-    (``block_rows == 1``: :func:`kernel_layout` where no kernel runs) it is
-    the dense reference."""
+    d_rhs (``lhs_p`` is zero there).  ``block_f`` caps the kernels' blocks
+    of ``d`` and ``f`` (default: as wide as VMEM holds).  On an unpadded
+    layout (``block_rows == 1``: :func:`kernel_layout` where no kernel runs)
+    it is the dense reference."""
     if layout.block_rows == 1:
         return gmm_reference(lhs_p, rhs, layout.sizes)
     return _gmm_padded_vjp(lhs_p, rhs, layout, block_f, interpret)
@@ -306,7 +399,7 @@ def kernel_layout(group_sizes, rows: int, d: int, f: int) -> PaddedLayout:
 
 
 def gmm(lhs, rhs, group_sizes, *, block_rows: int = _BLOCK_ROWS,
-        block_f: int = _BLOCK_F, interpret: bool = False,
+        block_f: int | None = None, interpret: bool = False,
         force: bool = False):
     """Grouped matmul: rows of ``lhs`` [rows, d], sorted so group ``g``
     occupies ``group_sizes[:g].sum() : group_sizes[:g+1].sum()``, each

@@ -66,7 +66,14 @@ def main():
         ({"tokens": tokens} for _ in range(n_steps)), trainer=trainer, size=2
     ):
         state, loss = trainer.train_step(state, batch)
-    print(f"final train loss: {float(loss):.5f}")
+        # one step in flight: on the virtual CPU mesh the eight device
+        # threads of a collective share a pool of as many threads as the
+        # host has cores, and with several steps queued behind each other
+        # a loaded 8-core host deadlocks in XLA:CPU's rendezvous (seven
+        # arrive, the eighth is never scheduled).  Reading the loss fences
+        # the step; on a TPU leave it out.
+        loss = float(loss)
+    print(f"final train loss: {loss:.5f}")
 
     trained = trainer.unstack_params(state)
     prompt = jnp.asarray(tokens[:2, :4])

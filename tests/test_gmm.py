@@ -1,6 +1,8 @@
 """Grouped-matmul kernel vs dense one-hot reference (golden-model pattern,
 SURVEY.md §4), including ragged/empty groups and the custom VJP."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -268,3 +270,149 @@ def test_off_the_kernel_the_layout_is_the_rows_themselves():
     lhs, rhs, _ = _case(jax.random.PRNGKey(14), 16, 8, 8, [10, 0, 6])
     np.testing.assert_allclose(gmm_padded(lhs, rhs, layout),
                                gmm_reference(lhs, rhs, gs), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the kernels' block shapes and grid order (PR 31): every operand block is
+# fetched once per reuse
+# ---------------------------------------------------------------------------
+
+#: name -> (d, f, group sizes, block_f).  OLMoE's two orientations at reduced
+#: rows (blocks as wide as ``d`` and ``f``: one ``f`` block, the rows streamed
+#: once), uneven groups with an empty one, one row block a group (no slab is
+#: reused), and widths that only 128 divides under a cap (several blocks of
+#: ``d`` and of ``f``: every index map with more than one value an axis)
+SHAPES = {
+    "olmoe_up": (2048, 1024, [300, 0, 129, 95], None),
+    "olmoe_down": (1024, 2048, [300, 0, 129, 95], None),
+    "uneven_groups": (256, 384, [1, 255, 130, 0, 126], None),
+    "one_block_a_group": (256, 256, [128, 100, 128, 7], None),
+    "only_128_divides": (640, 384, [200, 0, 57, 140], 256),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def _products_of(shape, dtype):
+    """(got, want) of the forward product, d_lhs and d_rhs for one case:
+    the kernels in interpret mode against ``gmm_reference`` and its
+    ``jax.grad`` in float32, on the same (``dtype``-rounded) operands."""
+    d, f, sizes, block_f = SHAPES[shape]
+    rows = int(np.sum(sizes))
+    lhs, rhs, gs = _case(jax.random.PRNGKey(15), rows, d, f, sizes)
+    lhs, rhs = lhs.astype(dtype), (rhs / np.sqrt(d)).astype(dtype)
+    g = jax.random.normal(jax.random.PRNGKey(16), (rows, f)).astype(dtype)
+
+    def all_three(fn, *operands):
+        out, vjp = jax.vjp(lambda l, r: fn(l, r, gs), *operands[:2])
+        return (out,) + vjp(operands[2].astype(out.dtype))
+
+    got = all_three(lambda l, r, s: gmm(l, r, s, block_f=block_f,
+                                        interpret=True, force=True),
+                    lhs, rhs, g)
+    want = all_three(gmm_reference, *(x.astype(jnp.float32)
+                                      for x in (lhs, rhs, g)))
+    return got, want
+
+
+@pytest.mark.parametrize("product", ["forward", "d_lhs", "d_rhs"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_each_product_matches_the_reference(shape, dtype, product):
+    got, want = _products_of(shape, dtype)
+    i = ["forward", "d_lhs", "d_rhs"].index(product)
+    assert got[i].dtype == jnp.dtype(dtype) and got[i].shape == want[i].shape
+    tol = 1e-4 if dtype == "float32" else 2e-2
+    np.testing.assert_allclose(
+        got[i].astype(jnp.float32), want[i], rtol=tol,
+        atol=tol * float(jnp.abs(want[i]).max()))
+    if product == "d_rhs":      # the group with no rows: exact zeros
+        empty = np.asarray(SHAPES[shape][2]) == 0
+        assert not np.asarray(got[i].astype(jnp.float32))[empty].any()
+
+
+def _olmoe_blocks(seed=0):
+    """``g_of_block`` of the OLMoE cell's shape: 65,536 routed rows over 64
+    experts by a uniform draw, 576 row blocks of 128."""
+    counts = np.bincount(np.random.default_rng(seed).integers(0, 64, 65536),
+                         minlength=64)
+    layout = padded_layout(jnp.asarray(counts, jnp.int32), 65536)
+    gid = np.asarray(layout.g_of_block)
+    assert gid.shape == (576,) and len(set(gid)) == 64
+    return gid
+
+
+def _fetches(grid, index_map, gid):
+    """Times a block is copied when ``grid`` is walked in order (last axis
+    fastest): Pallas copies none whose index the step before had."""
+    walk = [index_map(*step, gid) for step in np.ndindex(*grid)]
+    return 1 + sum(a != b for a, b in zip(walk, walk[1:]))
+
+
+@pytest.mark.parametrize("d,f,block_f", [
+    (2048, 1024, None), (1024, 2048, None), (2048, 1024, 512),
+    (1024, 2048, 512), (2048, 1024, 128),
+])
+def test_a_groups_matrix_slab_is_fetched_once_per_f_block(d, f, block_f):
+    """No kernel runs: the forward grid walked through its index maps.  The
+    matrix operand changes ``groups x f/bf`` times, not ``row blocks x
+    f/bf`` (2.4 GB of weights a call at OLMoE's shapes, PR 31)."""
+    from bagua_tpu.ops import gmm as G
+    gid = _olmoe_blocks()
+    bf = G._fwd_block_f(d, f, 128, 2, block_f or f, 64 << 20)
+    assert bf == (block_f or f)
+    spec = G._fwd_grid_spec(576 * 128, d, f, 128, bf)
+    assert spec.grid == (f // bf, 576)
+    assert _fetches(spec.grid, spec.in_specs[1].index_map, gid) == 64 * f // bf
+    # rows and result: every block of each, once per ``f`` block
+    assert _fetches(spec.grid, spec.in_specs[0].index_map, gid) == 576 * f // bf
+    assert _fetches(spec.grid, spec.out_specs.index_map, gid) == 576 * f // bf
+
+
+@pytest.mark.parametrize("d,f,block_f", [
+    (2048, 1024, None), (1024, 2048, None), (2048, 1024, 512),
+    (1024, 2048, 256),
+])
+def test_a_groups_gradient_block_stays_resident_over_its_rows(d, f, block_f):
+    """The d_rhs grid walked through its index maps: the float32 output
+    block changes (is written back) ``groups x d/bd x f/bf`` times."""
+    from bagua_tpu.ops import gmm as G
+    gid = _olmoe_blocks(1)
+    bd, bf = G._drhs_blocks(d, f, 128, 2, block_f or max(d, f), 64 << 20)
+    assert (bd, bf) == ((block_f, block_f) if block_f else (d, f))
+    spec = G._drhs_grid_spec(576 * 128, d, f, 128, bd, bf)
+    assert spec.grid == (d // bd, f // bf, 576)
+    blocks = (d // bd) * (f // bf)
+    assert _fetches(spec.grid, spec.out_specs.index_map, gid) == 64 * blocks
+    # the two row operands: once per block of the other dimension
+    for operand in spec.in_specs:
+        assert _fetches(spec.grid, operand.index_map, gid) == 576 * blocks
+
+
+@pytest.mark.parametrize("vmem_limit,fwd,drhs", [
+    (64 << 20, 1024, (2048, 1024)),     # v5e, v6e: as wide as the matrices
+    (32 << 20, 1024, (1024, 1024)),     # v5p: the 8 MB float32 block is out
+    (16 << 20, 1024, (512, 1024)),      # v4, and Mosaic's default
+    (8 << 20, 512, (512, 512)),         # the blocks of before PR 31
+    (4 << 20, 128, (256, 512)),
+    (1 << 10, 128, (128, 128)),         # nothing fits: the narrowest
+])
+def test_the_blocks_are_the_widest_that_fit_the_vmem_limit(vmem_limit, fwd,
+                                                           drhs):
+    from bagua_tpu.ops import gmm as G
+    assert G._fwd_block_f(2048, 1024, 128, 2, 1024, vmem_limit) == fwd
+    assert G._drhs_blocks(2048, 1024, 128, 2, 2048, vmem_limit) == drhs
+
+
+@pytest.mark.parametrize("dim,cap,block", [
+    (1024, 512, 512), (4096, 512, 512), (384, 512, 384), (768, 512, 384),
+    (640, 512, 128), (2048, 256, 256), (100, 512, 128),
+])
+def test_flash_attention_is_given_the_blocks_it_was(dim, cap, block):
+    """``pick_block`` serves the flash kernels (gpt2's and OLMoE's steps
+    compile to their kernels' text): capped at 512 from its four candidates,
+    whatever ``divisor_blocks`` offers ``gmm``."""
+    from bagua_tpu.ops.tiles import divisor_blocks, pick_block
+    assert pick_block(dim, cap) == block
+    assert pick_block(dim) == pick_block(dim, 512)
+    assert divisor_blocks(dim)[0] == dim
+    assert all(dim % b == 0 for b in divisor_blocks(dim))

@@ -73,7 +73,10 @@ def test_ledger_unit_conservation_and_classes():
     led = obs_ledger.GoodputLedger()
     t0 = time.monotonic()
     time.sleep(0.03)
-    led.note_class_window("checkpoint", 0.03)
+    # the measured wait, not the nominal one: the ledger anchors its wall
+    # at this window's start, and a sleep that overshoots on a loaded host
+    # would put the excess inside the step window but outside the wall
+    led.note_class_window("checkpoint", time.monotonic() - t0)
     time.sleep(0.05)
     led.note_step_window(1, time.monotonic() - t0)  # window spans the save
     t1 = time.monotonic()

@@ -386,6 +386,9 @@ def test_span_overhead_budget_with_http_and_historian(monkeypatch):
     historian = Historian(capacity=64, window_s=600.0)
     try:
         t, s, b = _golden_trainer()
+        # the ring is the process's: full of an earlier test's spans, its length
+        # no longer moves and the slice below would be empty
+        obs_spans.recorder.clear()
         before = len(obs_spans.recorder.snapshot())
         for i in range(5):
             s, loss = t.train_step(s, b)
