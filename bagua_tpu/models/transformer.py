@@ -50,9 +50,10 @@ class TransformerConfig:
     #: second time and memory stays level.  A custom MLP tags nothing and
     #: keeps what the dots rule gives it (then the out-projection too).
     #: Measured on one v5e, gpt2-medium at seq 1024, batch 8,
-    #: "dots_no_batch" (PERF.md §6, PR 27): 32,803 -> 34,166 tokens/s/chip
-    #: at 15.262 -> 15.281 GB against replaying the kernel; keeping the
-    #: out-projection's output as well reads 34,827 at 15.664 GB
+    #: "dots_no_batch" (PERF.md §6, PR 27, on the kernels' layout of then):
+    #: + 4.2 % tokens/s/chip for + 0.1 % peak memory against replaying the
+    #: kernel; keeping the out-projection's output as well, + 1.9 % more
+    #: for + 2.5 % more memory
     remat_policy: Optional[str] = None
     #: sequence-parallel mesh axis: when set and bound (inside shard_map),
     #: each shard holds a contiguous sequence chunk and position embeddings
@@ -155,9 +156,10 @@ def causal_attention(q, k, v, dtype):
 
     On TPU with block-aligned sequence lengths this dispatches to the fused
     Pallas flash-attention kernel (:mod:`bagua_tpu.ops.flash_attention`),
-    which never materializes the [seq, seq] score matrix; elsewhere it runs
-    the plain jnp form (identical math).  ``BAGUA_FLASH_ATTENTION=0``
-    disables the kernel.
+    which never materializes the [seq, seq] score matrix and reads q / k /
+    v as the projections write them (heads merged into the last axis by
+    reshape, no transposed copy); elsewhere it runs the plain jnp form
+    (identical math).  ``BAGUA_FLASH_ATTENTION=0`` disables the kernel.
     """
     from ..ops.flash_attention import flash_attention
 
@@ -181,6 +183,52 @@ KEPT_QKV = "attn_qkv"
 KEPT_FFN_IN = "ffn_in"
 
 
+class HeadsDense(nn.Module):
+    """``nn.DenseGeneral`` between a flat feature axis and a ``(heads,
+    head_dim)`` pair — the same parameter (``kernel``, its shape, its
+    initial values) — computed as ONE 2-D matmul over the merged ``heads *
+    head_dim`` axis, whose split into heads is a free reshape.  It is the
+    form :class:`Attention` gives its four projections where the flash
+    kernels run: they read and write the row-major ``[batch, seq, heads *
+    head_dim]``, and around ``DenseGeneral``'s contraction with two feature
+    axes the TPU compiler put a transposed copy before or behind every one
+    of their operands and results (gpt2-medium compiled for a v5e: 12 a
+    layer).  As plain matmuls the out-projection and the four backward
+    products take and give that layout as it is; the q / k / v results the
+    compiler still writes sequence-minor at gpt2-medium's sizes and re-lays
+    once for the kernel (PERF.md §7)."""
+    heads: int
+    head_dim: int
+    #: None: ``[..., in] -> [..., heads, head_dim]`` (q / k / v); set:
+    #: ``[..., heads, head_dim] -> [..., out_features]`` (the out-projection)
+    out_features: Optional[int] = None
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        merged = self.heads * self.head_dim
+        if self.out_features is None:
+            shape = (x.shape[-1], self.heads, self.head_dim)
+            flat = (x.shape[-1], merged)
+        else:
+            shape = (self.heads, self.head_dim, self.out_features)
+            flat = (merged, self.out_features)
+            x = x.reshape(*x.shape[:-2], merged)
+
+        def init(rng, shape, dtype):  # DenseGeneral's: drawn flat
+            return nn.initializers.lecun_normal()(rng, flat, dtype).reshape(
+                shape)
+
+        kernel = self.param("kernel", init, shape, self.param_dtype)
+        y = jax.lax.dot_general(
+            x.astype(self.dtype), kernel.reshape(flat).astype(self.dtype),
+            (((x.ndim - 1,), (0,)), ((), ())))
+        if self.out_features is None:
+            y = y.reshape(*y.shape[:-1], self.heads, self.head_dim)
+        return y
+
+
 def _tp_active(cfg) -> bool:
     return (
         cfg.tp_axis is not None and cfg.tp_size > 1
@@ -201,10 +249,20 @@ class Attention(nn.Module):
             from ..parallel.tensor_parallel import tp_gather_grad
 
             x = tp_gather_grad(x, cfg.tp_axis)
-        dense = lambda name: nn.DenseGeneral(
-            (h, d), axis=-1, name=name, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, use_bias=False,
-        )
+        # the projections hand the attention what it reads: the flash
+        # kernels [b, s, h * d] (HeadsDense), the plain einsums [b, s, h, d];
+        # same parameters either way
+        from ..ops.flash_attention import flash_supported
+
+        if not cfg.decode and flash_supported(x.shape[1], h, d):
+            dense = lambda name, out=None: HeadsDense(
+                h, d, out, name=name, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype)
+        else:
+            dense = lambda name, out=None: nn.DenseGeneral(
+                (h, d) if out is None else out,
+                axis=-1 if out is None else (-2, -1), name=name,
+                dtype=cfg.dtype, param_dtype=cfg.param_dtype, use_bias=False)
         q, k, v = (checkpoint_name(dense(n)(x), KEPT_QKV) for n in "qkv")
         if cfg.qk_norm:
             if _tp_active(cfg):
@@ -231,10 +289,7 @@ class Attention(nn.Module):
         else:
             fn = self.attn_fn or causal_attention
             o = fn(q, k, v, cfg.dtype)
-        out = nn.DenseGeneral(
-            cfg.d_model, axis=(-2, -1), name="o", dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, use_bias=False,
-        )(o)
+        out = dense("o", cfg.d_model)(o)
         if _tp_active(cfg):
             from ..parallel.tensor_parallel import tp_reduce
 

@@ -9,21 +9,37 @@ never materializes the [seq, seq] score matrix in HBM.
 
 Design (FlashAttention-2 style, TPU-first):
 
-- forward: grid over (batch*heads, q_blocks); K/V for the whole sequence are
-  resident in VMEM per grid step while each q block streams through, carrying
-  (o, m, l) in registers through a ``fori_loop`` over k blocks.  Causal
-  blocks above the diagonal are never visited (loop bound ``j+1``), the
-  diagonal block is masked in-register.
+- layout: the kernels read q / k / v / dO and write o / dq / dk / dv as
+  ``[batch, seq, heads * head_dim]`` — a free reshape of the model's
+  ``[batch, seq, heads, head_dim]``, which is what the projections make and
+  consume, so no transposed copy stands between a projection and a kernel.
+  A ``BlockSpec`` picks a head's lanes of the last axis by the head index:
+  one head a grid step where ``head_dim`` is a multiple of 128, and
+  ``128 // head_dim`` heads side by side in one 128-lane block below it
+  (two at 64).  Heads that share a block share its loads; a head's matmuls
+  contract over (or write) all 128 lanes with the other heads' lanes
+  masked to zero (or dropped by a select), which costs the MXU what a
+  64-wide operand padded to its 128 lanes costs.  The per-row statistics
+  (``lse``, ``delta``) are head-major f32 rows ``[batch * heads / g, g,
+  seq]``, ``g`` the heads of a block.
+- forward: grid over (batch * heads / g, q_blocks); K/V of the block's heads
+  for the whole sequence are resident in VMEM per grid step while each q
+  block streams through, carrying (o, m, l) through a ``fori_loop`` over k
+  blocks.  Causal blocks above the diagonal are never visited (loop bound
+  ``j+1``), the diagonal block is masked in-register.
 - backward: saves only the per-row logsumexp (``m + log l``) and recomputes
   probabilities blockwise — two kernels, one accumulating dK/dV over q
-  blocks at/after the diagonal, one accumulating dQ over k blocks at/before
-  it.  ``delta = rowsum(dO * O)`` is a cheap XLA-fused precompute.
+  blocks at/after the diagonal (Q/dO resident), one accumulating dQ over k
+  blocks at/before it (K/V resident).  ``delta = rowsum(dO * O)`` is a
+  cheap XLA-fused precompute.
 - all matmuls hit the MXU via ``dot_general(..., preferred_element_type=
   f32)``; softmax math is f32 on the VPU; inputs/outputs stay in the model
   dtype (bf16).
 
 Falls back to the plain jnp implementation off-TPU, for tiny/ragged
-sequence lengths, and under ``BAGUA_FLASH_ATTENTION=0``.
+sequence lengths, for heads the 128-lane blocks cannot take (``heads *
+head_dim`` no multiple of 128, an odd head count at 64), and under
+``BAGUA_FLASH_ATTENTION=0``.
 """
 
 from __future__ import annotations
@@ -55,15 +71,74 @@ def reference_attention(q, k, v, dtype, causal: bool = True):
 
 
 # ---------------------------------------------------------------------------
+# heads of one 128-lane block
+# ---------------------------------------------------------------------------
+
+
+def heads_per_block(heads: int, head_dim: int) -> int:
+    """How many heads the kernels take a grid step: 1 where ``head_dim``
+    fills whole 128-lane blocks, ``128 // head_dim`` side by side in one
+    block below that; 0 where the blocks cannot take the heads at all."""
+    if head_dim % _LANE == 0:
+        return 1
+    g = _LANE // head_dim
+    if _LANE % head_dim == 0 and heads % g == 0:
+        return g
+    return 0
+
+
+def _only_head(x, gi, heads):
+    """``x`` [rows, heads * d] with every lane outside head ``gi`` zeroed: a
+    contraction over all the lanes is then head ``gi``'s own."""
+    if heads == 1:
+        return x
+    d = x.shape[1] // heads
+    lane = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    keep = (lane >= gi * d) & (lane < (gi + 1) * d)
+    return jnp.where(keep, x, jnp.zeros_like(x))
+
+
+def _slots(parts, shape, axis):
+    """One value of ``shape`` that holds ``parts[k]`` in slot ``k`` of
+    ``axis`` — slots ``shape[axis] // len(parts)`` wide along the lanes
+    (axis 1), one sublane each along axis 0, the last part filling what is
+    left.  A part is of ``shape`` or broadcasts to it."""
+    out = parts[-1]
+    if len(parts) > 1:
+        width = shape[1] // len(parts) if axis else 1
+        at = lax.broadcasted_iota(jnp.int32, shape, axis)
+        for k in range(len(parts) - 2, -1, -1):
+            out = jnp.where(at < (k + 1) * width, parts[k], out)
+    return out
+
+
+def _by_head(parts, shape):
+    """One [rows, heads * d] value that holds, in head ``gi``'s lanes, those
+    lanes of ``parts[gi]`` ([rows, heads * d], or [rows, 1] broadcast)."""
+    return _slots(parts, shape, 1)
+
+
+def _dot(a, b, contract):
+    return lax.dot_general(a, b, (contract, ((), ())),
+                           preferred_element_type=jnp.float32)
+
+
+_NT = ((1,), (1,))  # a @ b.T
+_NN = ((1,), (0,))  # a @ b
+_TN = ((0,), (0,))  # a.T @ b
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, block_k,
-                scale):
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
+                scale, heads):
+    block_q, lanes = q_ref.shape[1], q_ref.shape[2]
     j = pl.program_id(1)
-    q = q_ref[0]  # keep model dtype: the MXU runs bf16 inputs at full rate
+    # keep model dtype: the MXU runs bf16 inputs at full rate
+    qs = [_only_head(q_ref[0], gi, heads) for gi in range(heads)]
     n_kb_total = k_ref.shape[1] // block_k
     if causal:
         # last k block overlapping [0, (j+1)*block_q)
@@ -77,71 +152,96 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, block_k,
     )
 
     def body(kb, carry):
-        o, m, l = carry
+        o, ms, ls = carry
         k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
         v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        logits = scale * lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
         if causal:
             k_pos = kb * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1
             )
-            logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
-        m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(logits - m_new)
-        l_new = l * corr + p.sum(axis=-1, keepdims=True)
-        pv = lax.dot_general(
-            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return o * corr + pv, m_new, l_new
+        ms_new, ls_new, corrs, pvs = [], [], [], []
+        for q, m, l in zip(qs, ms, ls):
+            logits = scale * _dot(q, k_blk, _NT)
+            if causal:
+                logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+            m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(logits - m_new)
+            ms_new.append(m_new)
+            ls_new.append(l * corr + p.sum(axis=-1, keepdims=True))
+            corrs.append(corr)
+            # all the block's lanes of p v; the head's own are kept below
+            pvs.append(_dot(p.astype(v_blk.dtype), v_blk, _NN))
+        o = o * _by_head(corrs, o.shape) + _by_head(pvs, o.shape)
+        return o, tuple(ms_new), tuple(ls_new)
 
-    o0 = jnp.zeros((block_q, d), jnp.float32)
+    o0 = jnp.zeros((block_q, lanes), jnp.float32)
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    o, m, l = lax.fori_loop(0, n_kb, body, (o0, m0, l0))
-    l = jnp.maximum(l, 1e-30)
-    o_ref[0] = (o / l).astype(o_ref.dtype)
-    # lse written as an 8-sublane stripe: (1, block_q) output blocks violate
-    # the TPU (8, 128) tile floor, so the row is broadcast over 8 sublanes
-    lse = (m + jnp.log(l)).reshape(1, block_q)
-    lse_ref[0] = jnp.broadcast_to(lse, (8, block_q))
+    o, ms, ls = lax.fori_loop(0, n_kb, body,
+                              (o0, (m0,) * heads, (l0,) * heads))
+    ls = [jnp.maximum(l, 1e-30) for l in ls]
+    o_ref[0] = (o / _by_head(ls, o.shape)).astype(o_ref.dtype)
+    # lse written as an 8-sublane stripe: (heads, block_q) output blocks
+    # violate the TPU (8, 128) tile floor, so head gi's row is sublane gi
+    # and the last head's row is repeated over the rest
+    rows = [jnp.broadcast_to((m + jnp.log(l)).reshape(1, block_q),
+                             (8, block_q)) for m, l in zip(ms, ls)]
+    lse_ref[0] = _slots(rows, (8, block_q), 0)
 
 
-def _fwd(q, k, v, causal, block_q, block_k, interpret):
-    """q/k/v: [bh, s, d] -> (o [bh, s, d], lse [bh, s] f32)."""
-    bh, s, d = q.shape
-    grid = (bh, s // block_q)
-    kv_spec = pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0),
-                           memory_space=pltpu.VMEM)
-    return pl.pallas_call(
+def _specs(s, heads, d):
+    """``(g, tensor, stat_rows)``: the heads of a block, and the ``BlockSpec``
+    makers over ``[b, s, heads * d]`` tensors and the ``[b * heads / g, g,
+    s]`` rows — ``tensor(rows)`` a ``rows``-long block j of the sequence (the
+    whole of it where ``rows == s``) of grid row i's ``g`` heads,
+    ``stat_rows`` those heads' whole f32 rows."""
+    g = heads_per_block(heads, d)
+    hp = heads // g
+
+    def tensor(rows):
+        if rows == s:
+            index = lambda i, j: (i // hp, 0, i % hp)
+        else:
+            index = lambda i, j: (i // hp, j, i % hp)
+        return pl.BlockSpec((1, rows, g * d), index, memory_space=pltpu.VMEM)
+
+    stat_rows = pl.BlockSpec((1, g, s), lambda i, j: (i, 0, 0),
+                             memory_space=pltpu.VMEM)
+    return g, tensor, stat_rows
+
+
+# jitted, like ``_bwd``: every layer of a model calls these with the same
+# shapes, and Pallas traces a kernel body anew at each ``pallas_call``; under
+# ``jit`` the layers share one trace.  gpt2-medium's 72 calls cost a warm
+# start 15 s of tracing on the chip's host otherwise (PERF.md §6, PR 32)
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _fwd(q, k, v, heads, causal, block_q, block_k, interpret):
+    """q/k/v: [b, s, heads * d] -> (o [b, s, heads * d], lse [b * heads / g,
+    g, s] f32: the head rows of the 8-sublane stripe the kernel writes)."""
+    b, s, hd = q.shape
+    d = hd // heads
+    g, tensor, _ = _specs(s, heads, d)
+    o, stripe = pl.pallas_call(
         functools.partial(
             _fwd_kernel, causal=causal, block_k=block_k,
-            scale=float(1.0 / (d ** 0.5)),
+            scale=1.0 / (d ** 0.5), heads=g,
         ),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
-            kv_spec,
-            kv_spec,
-        ],
+        grid=(b * heads // g, s // block_q),
+        in_specs=[tensor(block_q), tensor(s), tensor(s)],
         out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
-                         memory_space=pltpu.VMEM),
+            tensor(block_q),
             pl.BlockSpec((1, 8, block_q), lambda i, j: (i, 0, j),
                          memory_space=pltpu.VMEM),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), q.dtype),
-            jax.ShapeDtypeStruct((bh, 8, s), jnp.float32),
+            jax.ShapeDtypeStruct((b, s, hd), q.dtype),
+            jax.ShapeDtypeStruct((b * heads // g, 8, s), jnp.float32),
         ],
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
+    return o, stripe[:, :g, :]
 
 
 # ---------------------------------------------------------------------------
@@ -149,12 +249,17 @@ def _fwd(q, k, v, causal, block_q, block_k, interpret):
 # ---------------------------------------------------------------------------
 
 
+def _stat(ref, gi, start, rows):
+    """Head ``gi``'s ``rows`` statistics from ``start`` as a column."""
+    return ref[0, gi, pl.ds(start, rows)].reshape(rows, 1)
+
+
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, causal, block_q, scale):
-    block_k, d = k_ref.shape[1], k_ref.shape[2]
+                    dk_ref, dv_ref, *, causal, block_q, scale, heads):
+    block_k, lanes = k_ref.shape[1], k_ref.shape[2]
     kb = pl.program_id(1)
-    k_blk = k_ref[0]
-    v_blk = v_ref[0]
+    ks = [_only_head(k_ref[0], gi, heads) for gi in range(heads)]
+    vs = [_only_head(v_ref[0], gi, heads) for gi in range(heads)]
     n_qb_total = q_ref.shape[1] // block_q
     qb_start = (kb * block_k) // block_q if causal else 0
     k_pos = kb * block_k + lax.broadcasted_iota(
@@ -165,52 +270,42 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dk, dv = carry
         q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :]
         do_blk = do_ref[0, pl.ds(qb * block_q, block_q), :]
-        lse = lse_ref[0, 0, pl.ds(qb * block_q, block_q)].reshape(block_q, 1)
-        delta = (
-            delta_ref[0, 0, pl.ds(qb * block_q, block_q)].reshape(block_q, 1)
-        )
-        s_ij = scale * lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
         if causal:
             q_pos = qb * block_q + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0
             )
-            s_ij = jnp.where(q_pos >= k_pos, s_ij, NEG_INF)
-        p = jnp.exp(s_ij - lse).astype(k_blk.dtype)
-        # dV += P^T dO
-        dv = dv + lax.dot_general(
-            p, do_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dp = lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p.astype(jnp.float32) * (dp - delta)).astype(k_blk.dtype)
-        # dK += scale * dS^T Q
-        dk = dk + scale * lax.dot_general(
-            ds, q_blk, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dk, dv
+        dks, dvs = [], []
+        for gi, (k_h, v_h) in enumerate(zip(ks, vs)):
+            lse = _stat(lse_ref, gi, qb * block_q, block_q)
+            delta = _stat(delta_ref, gi, qb * block_q, block_q)
+            s_ij = scale * _dot(q_blk, k_h, _NT)
+            if causal:
+                s_ij = jnp.where(q_pos >= k_pos, s_ij, NEG_INF)
+            p = jnp.exp(s_ij - lse).astype(k_h.dtype)
+            # dV += P^T dO
+            dvs.append(_dot(p, do_blk, _TN))
+            dp = _dot(do_blk, v_h, _NT)
+            ds = (p.astype(jnp.float32) * (dp - delta)).astype(k_h.dtype)
+            # dK += scale * dS^T Q
+            dks.append(_dot(ds, q_blk, _TN))
+        return (dk + scale * _by_head(dks, dk.shape),
+                dv + _by_head(dvs, dv.shape))
 
-    dk0 = jnp.zeros((block_k, d), jnp.float32)
-    dv0 = jnp.zeros((block_k, d), jnp.float32)
-    dk, dv = lax.fori_loop(qb_start, n_qb_total, body, (dk0, dv0))
+    zeros = jnp.zeros((block_k, lanes), jnp.float32)
+    dk, dv = lax.fori_loop(qb_start, n_qb_total, body, (zeros, zeros))
     dk_ref[0] = dk.astype(dk_ref.dtype)
     dv_ref[0] = dv.astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *, causal, block_k, scale):
-    block_q, d = q_ref.shape[1], q_ref.shape[2]
+                   dq_ref, *, causal, block_k, scale, heads):
+    block_q, lanes = q_ref.shape[1], q_ref.shape[2]
     j = pl.program_id(1)
-    q_blk = q_ref[0]
-    do_blk = do_ref[0]
-    lse = lse_ref[0, 0, pl.ds(j * block_q, block_q)].reshape(block_q, 1)
-    delta = delta_ref[0, 0, pl.ds(j * block_q, block_q)].reshape(block_q, 1)
+    qs = [_only_head(q_ref[0], gi, heads) for gi in range(heads)]
+    dos = [_only_head(do_ref[0], gi, heads) for gi in range(heads)]
+    lses = [_stat(lse_ref, gi, j * block_q, block_q) for gi in range(heads)]
+    deltas = [_stat(delta_ref, gi, j * block_q, block_q)
+              for gi in range(heads)]
     n_kb_total = k_ref.shape[1] // block_k
     if causal:
         n_kb = lax.min(
@@ -225,81 +320,78 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     def body(kb, dq):
         k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
         v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
-        s_ij = scale * lax.dot_general(
-            q_blk, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
         if causal:
             k_pos = kb * block_k + lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1
             )
-            s_ij = jnp.where(q_pos >= k_pos, s_ij, NEG_INF)
-        p = jnp.exp(s_ij - lse)
-        dp = lax.dot_general(
-            do_blk, v_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = (p * (dp - delta)).astype(k_blk.dtype)
-        return dq + scale * lax.dot_general(
-            ds, k_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
+        dqs = []
+        for q_h, do_h, lse, delta in zip(qs, dos, lses, deltas):
+            s_ij = scale * _dot(q_h, k_blk, _NT)
+            if causal:
+                s_ij = jnp.where(q_pos >= k_pos, s_ij, NEG_INF)
+            p = jnp.exp(s_ij - lse)
+            dp = _dot(do_h, v_blk, _NT)
+            ds = (p * (dp - delta)).astype(k_blk.dtype)
+            dqs.append(_dot(ds, k_blk, _NN))
+        return dq + scale * _by_head(dqs, dq.shape)
 
-    dq = lax.fori_loop(0, n_kb, body, jnp.zeros((block_q, d), jnp.float32))
+    dq = lax.fori_loop(0, n_kb, body,
+                       jnp.zeros((block_q, lanes), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _bwd(q, k, v, o, lse, do, heads, causal, block_q, block_k, interpret,
          dlse=None):
-    """``lse``: [bh, 1, s] f32 (one sublane of the forward's stripe).
+    """``lse``: [b * heads / g, g, s] f32 (the head rows of the forward's
+    stripe).
 
-    ``dlse`` [bh, s]: cotangent of the logsumexp output (only when the
+    ``dlse``, like it: cotangent of the logsumexp output (only when the
     caller consumed lse, e.g. ring-attention merging).  It enters the
     standard backward as ``ds_ij += p_ij * dlse_i``, i.e. an effective
     ``delta_i - dlse_i`` — no kernel change needed.
     """
-    bh, s, d = q.shape
+    b, s, hd = q.shape
+    d = hd // heads
+    g, tensor, stat_rows = _specs(s, heads, d)
     delta = (
         (do.astype(jnp.float32) * o.astype(jnp.float32))
+        .reshape(b, s, heads, d)
         .sum(axis=-1)
-        .reshape(bh, 1, s)
+        .transpose(0, 2, 1)
+        .reshape(lse.shape)
     )
     if dlse is not None:
-        delta = delta - dlse.astype(jnp.float32).reshape(bh, 1, s)
+        delta = delta - dlse.astype(jnp.float32)
 
-    seq_spec = pl.BlockSpec((1, s, d), lambda i, j: (i, 0, 0),
-                            memory_space=pltpu.VMEM)
-    row_spec = pl.BlockSpec((1, 1, s), lambda i, j: (i, 0, 0),
-                            memory_space=pltpu.VMEM)
-    kb_spec = pl.BlockSpec((1, block_k, d), lambda i, j: (i, j, 0),
-                           memory_space=pltpu.VMEM)
+    scale = 1.0 / (d ** 0.5)
     dk, dv = pl.pallas_call(
         functools.partial(
-            _bwd_dkv_kernel, causal=causal, block_q=block_q,
-            scale=float(1.0 / (d ** 0.5)),
+            _bwd_dkv_kernel, causal=causal, block_q=block_q, scale=scale,
+            heads=g,
         ),
-        grid=(bh, s // block_k),
-        in_specs=[seq_spec, kb_spec, kb_spec, seq_spec, row_spec, row_spec],
-        out_specs=[kb_spec, kb_spec],
+        grid=(b * heads // g, s // block_k),
+        in_specs=[tensor(s), tensor(block_k), tensor(block_k), tensor(s),
+                  stat_rows, stat_rows],
+        out_specs=[tensor(block_k), tensor(block_k)],
         out_shape=[
-            jax.ShapeDtypeStruct((bh, s, d), k.dtype),
-            jax.ShapeDtypeStruct((bh, s, d), v.dtype),
+            jax.ShapeDtypeStruct((b, s, hd), k.dtype),
+            jax.ShapeDtypeStruct((b, s, hd), v.dtype),
         ],
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
-    qb_spec = pl.BlockSpec((1, block_q, d), lambda i, j: (i, j, 0),
-                           memory_space=pltpu.VMEM)
     dq = pl.pallas_call(
         functools.partial(
-            _bwd_dq_kernel, causal=causal, block_k=block_k,
-            scale=float(1.0 / (d ** 0.5)),
+            _bwd_dq_kernel, causal=causal, block_k=block_k, scale=scale,
+            heads=g,
         ),
-        grid=(bh, s // block_q),
-        in_specs=[qb_spec, seq_spec, seq_spec, qb_spec, row_spec, row_spec],
-        out_specs=qb_spec,
-        out_shape=jax.ShapeDtypeStruct((bh, s, d), q.dtype),
+        grid=(b * heads // g, s // block_q),
+        in_specs=[tensor(block_q), tensor(s), tensor(s), tensor(block_q),
+                  stat_rows, stat_rows],
+        out_specs=tensor(block_q),
+        out_shape=jax.ShapeDtypeStruct((b, s, hd), q.dtype),
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, do, lse, delta)
@@ -311,10 +403,11 @@ def _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-def _flash_lse(q, k, v, causal, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, causal, block_q, block_k, interpret)
-    return o, lse[:, 0, :]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_lse(q, k, v, heads, causal, block_q, block_k, interpret):
+    """q/k/v [b, s, heads * d] -> (o like q, lse [b * heads / g, g, s] f32:
+    row-major that is [b, heads, s])."""
+    return _fwd(q, k, v, heads, causal, block_q, block_k, interpret)
 
 
 #: ``checkpoint_name`` tags on what the forward kernel made.  A remat policy
@@ -325,24 +418,35 @@ KEPT_O = "flash_o"
 KEPT_LSE = "flash_lse"
 
 
-def _flash_lse_fwd(q, k, v, causal, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, causal, block_q, block_k, interpret)
+def _flash_lse_fwd(q, k, v, heads, causal, block_q, block_k, interpret):
+    o, lse = _fwd(q, k, v, heads, causal, block_q, block_k, interpret)
     # tagged HERE so the value returned and the residual are one variable
     # (a tag on the caller's side names a copy and the kernel is replayed);
-    # the [bh, 1, s] row, not the 8-sublane stripe the kernel writes
+    # the head rows, not the 8-sublane stripe the kernel writes
     o = checkpoint_name(o, KEPT_O)
-    lse = checkpoint_name(lse[:, :1, :], KEPT_LSE)
-    return (o, lse[:, 0, :]), (q, k, v, o, lse)
+    lse = checkpoint_name(lse, KEPT_LSE)
+    return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_lse_bwd(causal, block_q, block_k, interpret, res, cts):
+def _flash_lse_bwd(heads, causal, block_q, block_k, interpret, res, cts):
     q, k, v, o, lse = res
     do, dlse = cts
-    return _bwd(q, k, v, o, lse, do, causal, block_q, block_k, interpret,
-                dlse=dlse)
+    return _bwd(q, k, v, o, lse, do, heads, causal, block_q, block_k,
+                interpret, dlse=dlse)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
+
+
+def _in_model_layout(q, k, v, causal, block_q, block_k, interpret):
+    """The kernels over the model's ``[b, s, h, d]`` q / k / v: the heads
+    merge into the last axis and split out of it again by reshape, no
+    element moves.  -> (o [b, s, h, d], lse [b, h, s] f32)."""
+    b, s, h, d = q.shape
+    merged = lambda x: x.reshape(b, s, h * d)
+    o, lse = _flash_lse(merged(q), merged(k), merged(v), h, causal, block_q,
+                        block_k, interpret)
+    return o.reshape(b, s, h, d), lse.reshape(b, h, s)
 
 
 def flash_attention_with_lse(q, k, v, *, causal: bool, block_q: int = 0,
@@ -359,22 +463,17 @@ def flash_attention_with_lse(q, k, v, *, causal: bool, block_q: int = 0,
     assert k.shape[1] == s and v.shape[1] == s, (q.shape, k.shape, v.shape)
     block_q = block_q or pick_block(s)
     block_k = block_k or pick_block(s)
-    if s % block_q or s % block_k:
+    if s % block_q or s % block_k or not heads_per_block(h, d):
         # no silent fallback here (the caller gates on flash_supported):
         # a non-divisible grid would TRUNCATE the sequence
         raise ValueError(
             f"seq {s} is not a multiple of block sizes "
-            f"({block_q}, {block_k}); flash_attention_with_lse has no "
-            "reference fallback"
+            f"({block_q}, {block_k}), or {h} heads of {d} do not fill "
+            "128-lane blocks; flash_attention_with_lse has no reference "
+            "fallback"
         )
-
-    def fold(x):
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-
-    o, lse = _flash_lse(fold(q), fold(k), fold(v), causal, block_q, block_k,
-                        interpret)
-    o = o.reshape(b, h, s, d).transpose(0, 2, 1, 3).astype(jnp.float32)
-    return o, lse.reshape(b, h, s)
+    o, lse = _in_model_layout(q, k, v, causal, block_q, block_k, interpret)
+    return o.astype(jnp.float32), lse
 
 
 def _enabled() -> bool:
@@ -389,19 +488,25 @@ def _enabled() -> bool:
 MIN_FLASH_SEQ = 1024
 
 
-def flash_supported(seq: int, head_dim: int, block: int = _LANE) -> bool:
+def flash_supported(seq: int, heads: int, head_dim: int,
+                    block: int = _LANE) -> bool:
     """Whether the fused kernel pays: on-TPU, sequence long enough that the
     [seq, seq] HBM materialization hurts (measured crossover ~1k on v5p),
-    block-aligned, and K/V + Q/dO fitting the per-step VMEM budget."""
+    block-aligned, heads that fill 128-lane blocks of the model's layout
+    (:func:`heads_per_block`), and K/V + Q/dO fitting the per-step VMEM
+    budget."""
     if not _enabled():
         return False
     if jax.default_backend() != "tpu":
         return False
     if seq < MIN_FLASH_SEQ or seq % block:
         return False
-    # each kernel keeps 2 full-sequence operands resident (K+V fwd, Q+dO in
-    # the dK/dV pass), double-buffered by the pipeline: 4 bf16 seq×lane
-    # buffers must stay under the ~16 MB VMEM budget with headroom
+    if not heads_per_block(heads, head_dim):
+        return False
+    # each kernel keeps 2 full-sequence operands resident (K+V fwd and dQ,
+    # Q+dO in the dK/dV pass), a block's heads wide — 128 lanes, or
+    # head_dim above that — and double-buffered by the pipeline: 4 bf16
+    # seq×lane buffers must stay under the ~16 MB VMEM budget with headroom
     return 4 * seq * max(head_dim, _LANE) * 2 <= 12 * 1024 * 1024
 
 
@@ -412,8 +517,10 @@ def flash_attention(q, k, v, dtype=None, *, causal: bool = True,
     [batch, seq, heads, head_dim], returns [batch, seq, heads, head_dim] in
     ``dtype`` (default: q.dtype).
 
-    ``force`` skips the platform check (tests run the kernel in interpret
-    mode on CPU).
+    ``force`` skips the platform and sequence-length checks (tests run the
+    kernel in interpret mode on CPU); a shape no grid covers — a sequence
+    the blocks do not divide, heads that do not fill 128-lane blocks —
+    still takes the reference.
     """
     from .tiles import pick_block
 
@@ -421,17 +528,10 @@ def flash_attention(q, k, v, dtype=None, *, causal: bool = True,
     dtype = dtype or q.dtype
     block_q = block_q or pick_block(s)
     block_k = block_k or pick_block(s)
-    if not force and not flash_supported(s, d, max(block_q, block_k)):
+    if not force and not flash_supported(s, h, d, max(block_q, block_k)):
         return reference_attention(q, k, v, dtype, causal=causal)
-    if s % block_q or s % block_k:
+    if s % block_q or s % block_k or not heads_per_block(h, d):
         return reference_attention(q, k, v, dtype, causal=causal)
-
-    def fold(x):  # [b, s, h, d] -> [b*h, s, d]
-        return x.transpose(0, 2, 1, 3).reshape(b * h, s, d)
-
     # lse is discarded; its zero cotangent enters the backward as a no-op
-    o, _ = _flash_lse(fold(q), fold(k), fold(v), causal, block_q, block_k,
-                      interpret)
-    return (
-        o.reshape(b, h, s, d).transpose(0, 2, 1, 3).astype(dtype)
-    )
+    o, _ = _in_model_layout(q, k, v, causal, block_q, block_k, interpret)
+    return o.astype(dtype)

@@ -60,7 +60,7 @@ def make_ring_attention(sp_size: int, axis_name: str = "sp",
         from ..ops.flash_attention import flash_supported
 
         if use_flash == "always" or (
-            use_flash == "auto" and flash_supported(s, d)
+            use_flash == "auto" and flash_supported(s, h, d)
         ):
             return _ring_flash(q, k, v, dtype, sp_size, axis_name,
                                interpret=interpret)

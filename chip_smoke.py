@@ -179,7 +179,7 @@ def leg_kernels(sz: dict, dryrun: bool) -> None:
                for kx in (kq, kk, kv))
     w = jax.random.normal(kw, shape, jnp.float32)
     if not dryrun:
-        assert flash_supported(f["s"], f["d"]), (
+        assert flash_supported(f["s"], f["h"], f["d"]), (
             "flash_supported is False at the bench_longctx shape", f)
 
     def flash_loss(q, k, v):

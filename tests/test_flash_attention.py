@@ -12,8 +12,14 @@ import pytest
 
 from bagua_tpu.ops.flash_attention import (
     flash_attention,
+    flash_attention_with_lse,
+    heads_per_block,
     reference_attention,
 )
+
+#: (heads, head_dim): two heads side by side in a 128-lane block (one block,
+#: and two), and a head that fills a block by itself
+HEADS = [(2, 64), (4, 64), (2, 128)]
 
 
 def _qkv(key, b=2, s=256, h=2, d=64, dtype=jnp.float32):
@@ -26,9 +32,10 @@ def _qkv(key, b=2, s=256, h=2, d=64, dtype=jnp.float32):
     )
 
 
+@pytest.mark.parametrize("h,d", HEADS)
 @pytest.mark.parametrize("causal", [True, False])
-def test_forward_matches_reference(causal):
-    q, k, v = _qkv(jax.random.PRNGKey(0))
+def test_forward_matches_reference(causal, h, d):
+    q, k, v = _qkv(jax.random.PRNGKey(0), h=h, d=d)
     want = reference_attention(q, k, v, jnp.float32, causal=causal)
     got = flash_attention(q, k, v, jnp.float32, causal=causal,
                           interpret=True, force=True)
@@ -47,7 +54,7 @@ def test_forward_rectangular_blocks():
 def test_mismatched_blocks_fwd_and_bwd(block_q, block_k):
     # block_q != block_k exercises the causal loop bounds (n_kb ceil-div) and
     # the dkv kernel's qb_start floor-div with multi-block diagonals
-    q, k, v = _qkv(jax.random.PRNGKey(7), b=1, s=512, h=1, d=64)
+    q, k, v = _qkv(jax.random.PRNGKey(7), b=1, s=512, h=2, d=64)
     g = jax.random.normal(jax.random.PRNGKey(8), q.shape, jnp.float32)
 
     def loss(fn):
@@ -66,9 +73,10 @@ def test_mismatched_blocks_fwd_and_bwd(block_q, block_k):
                                    err_msg=f"d{name}")
 
 
+@pytest.mark.parametrize("h,d", HEADS)
 @pytest.mark.parametrize("causal", [True, False])
-def test_grads_match_reference(causal):
-    q, k, v = _qkv(jax.random.PRNGKey(1), b=1, s=256, h=2, d=64)
+def test_grads_match_reference(causal, h, d):
+    q, k, v = _qkv(jax.random.PRNGKey(1), b=1, s=256, h=h, d=d)
     g = jax.random.normal(jax.random.PRNGKey(2), q.shape, jnp.float32)
 
     def loss(fn):
@@ -88,6 +96,66 @@ def test_grads_match_reference(causal):
     for w, o, name in zip(want, got, "qkv"):
         np.testing.assert_allclose(o, w, atol=5e-5, rtol=5e-5,
                                    err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("h,d", HEADS)
+def test_lse_and_its_cotangent_match_reference(h, d):
+    """The ``[batch, heads, seq]`` logsumexp the ring merge consumes, and the
+    gradients where the loss reads it (the ``dlse`` path), two batch rows so
+    that a block's batch index is not always 0."""
+    b, s = 2, 256
+    q, k, v = _qkv(jax.random.PRNGKey(11), b=b, s=s, h=h, d=d)
+    g = jax.random.normal(jax.random.PRNGKey(12), q.shape, jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(13), (b, h, s), jnp.float32)
+
+    def plain(q, k, v):
+        logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(d)
+        logits = jnp.where(jnp.tril(jnp.ones((s, s), jnp.bool_)), logits,
+                           -1e30)
+        o = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(logits, -1), v)
+        return o, jax.nn.logsumexp(logits, axis=-1)
+
+    def kernels(q, k, v):
+        return flash_attention_with_lse(q, k, v, causal=True, interpret=True)
+
+    def loss(fn):
+        def f(q, k, v):
+            o, lse = fn(q, k, v)
+            return (o * g).sum() + (lse * w).sum()
+
+        return jax.grad(f, argnums=(0, 1, 2))
+
+    for got, want in zip(kernels(q, k, v), plain(q, k, v)):
+        assert got.shape == want.shape and got.dtype == jnp.float32
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+    for got, want, name in zip(loss(kernels)(q, k, v), loss(plain)(q, k, v),
+                               "qkv"):
+        np.testing.assert_allclose(got, want, atol=5e-5, rtol=5e-5,
+                                   err_msg=f"d{name}")
+
+
+@pytest.mark.parametrize("h,d,per_block", [
+    (16, 64, 2), (16, 128, 1), (8, 256, 1), (8, 32, 4),
+    (3, 64, 0),    # an odd head count at 64: the last block would be half
+    (6, 32, 0),    # four a block, six heads
+    (4, 96, 0),    # 96 neither divides 128 nor is divided by it
+])
+def test_heads_the_128_lane_blocks_take(h, d, per_block):
+    assert heads_per_block(h, d) == per_block
+
+
+def test_odd_head_count_at_64_takes_the_reference():
+    """Three heads of 64 do not fill 128-lane blocks: also under ``force``
+    the dispatcher answers with the plain path (no kernel in the jaxpr), and
+    the variant with no fallback refuses."""
+    q, k, v = _qkv(jax.random.PRNGKey(14), b=1, s=256, h=3, d=64)
+    forced = lambda q, k, v: flash_attention(q, k, v, jnp.float32,
+                                             interpret=True, force=True)
+    np.testing.assert_array_equal(
+        forced(q, k, v), reference_attention(q, k, v, jnp.float32))
+    assert _kernel_calls(jax.make_jaxpr(forced)(q, k, v).jaxpr) == {}
+    with pytest.raises(ValueError, match="128-lane"):
+        flash_attention_with_lse(q, k, v, causal=True, interpret=True)
 
 
 def test_bf16_forward_close():
@@ -121,17 +189,23 @@ def test_model_dispatch_unchanged_on_cpu():
 # --- what a remat policy keeps of the kernel's outputs -----------------------
 
 
-def _kernel_calls(jaxpr, counts=None):
-    """``{kernel name: pallas_call eqns}`` of a jaxpr, nested jaxprs included."""
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, nested jaxprs included."""
     from bagua_tpu.analysis.jaxpr_check import _sub_jaxprs
 
-    counts = {} if counts is None else counts
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            counts[eqn.params["name"]] = counts.get(eqn.params["name"], 0) + 1
+        yield eqn
         for _, inner in _sub_jaxprs(eqn):
-            _kernel_calls(inner, counts)
-    return counts
+            yield from _eqns(inner)
+
+
+def _kernel_calls(jaxpr):
+    """``{kernel name: pallas_call eqns}`` of a jaxpr."""
+    import collections
+
+    return dict(collections.Counter(
+        eqn.params["name"] for eqn in _eqns(jaxpr)
+        if eqn.primitive.name == "pallas_call"))
 
 
 def _forced_flash(q, k, v, dtype):
@@ -139,20 +213,14 @@ def _forced_flash(q, k, v, dtype):
                            force=True)
 
 
-@pytest.mark.parametrize("remat_policy,forwards_per_layer",
-                         [(None, 2), ("dots", 1), ("dots_no_batch", 1)])
-def test_dots_policies_keep_the_forward_kernels_outputs(remat_policy,
-                                                        forwards_per_layer):
-    """A policy that keeps matmul outputs keeps ``o`` and ``lse`` too, so the
-    backward pass does not run ``flash_fwd`` a second time; with nothing
-    kept (``None``) the replay runs it again, as it must."""
+def _two_layer_lm_grad_jaxpr(remat_policy):
+    """Gradient jaxpr of a two-layer LM whose attention is the kernels."""
     import optax
 
     from bagua_tpu.models.transformer import TransformerConfig, TransformerLM
 
-    layers = 2
     cfg = TransformerConfig(vocab_size=97, d_model=128, n_heads=2,
-                            n_layers=layers, d_ff=256, max_seq_len=128,
+                            n_layers=2, d_ff=256, max_seq_len=128,
                             remat=True, remat_policy=remat_policy)
     model = TransformerLM(cfg, attn_fn=_forced_flash)
     tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 129), 0, 97)
@@ -164,9 +232,53 @@ def test_dots_policies_keep_the_forward_kernels_outputs(remat_policy,
         return optax.softmax_cross_entropy_with_integer_labels(
             logits, tokens[:, 1:]).mean()
 
-    calls = _kernel_calls(jax.make_jaxpr(jax.grad(loss_fn))(params).jaxpr)
+    return jax.make_jaxpr(jax.grad(loss_fn))(params).jaxpr
+
+
+@pytest.mark.parametrize("remat_policy,forwards_per_layer",
+                         [(None, 2), ("dots", 1), ("dots_no_batch", 1)])
+def test_dots_policies_keep_the_forward_kernels_outputs(remat_policy,
+                                                        forwards_per_layer):
+    """A policy that keeps matmul outputs keeps ``o`` and ``lse`` too, so the
+    backward pass does not run ``flash_fwd`` a second time; with nothing
+    kept (``None``) the replay runs it again, as it must."""
+    layers = 2
+    calls = _kernel_calls(_two_layer_lm_grad_jaxpr(remat_policy))
     assert calls == {"flash_fwd": forwards_per_layer * layers,
                      "flash_bwd_dq": layers, "flash_bwd_dkv": layers}
+
+
+def test_the_layers_share_one_trace_of_each_kernel_body():
+    """``_fwd`` and ``_bwd`` are jitted so that a model's layers, which call
+    them with the same shapes, trace each kernel body once and not once a
+    layer (a warm start's time on the chip's host)."""
+    bodies = {}
+    for eqn in _eqns(_two_layer_lm_grad_jaxpr("dots_no_batch")):
+        if eqn.primitive.name == "pallas_call":
+            bodies.setdefault(eqn.params["name"], set()).add(
+                id(eqn.params["jaxpr"]))
+    assert {name: len(ids) for name, ids in bodies.items()} == {
+        "flash_fwd": 1, "flash_bwd_dq": 1, "flash_bwd_dkv": 1}
+
+
+@pytest.mark.parametrize("remat_policy", [None, "dots", "dots_no_batch"])
+def test_no_transpose_stands_between_a_projection_and_a_kernel(remat_policy):
+    """The kernels read and write ``[batch, seq, heads * head_dim]``, what
+    the projections make and consume by reshape: forward, backward and in
+    the remat replay the program writes no rank-4 ``transpose`` (the old
+    fold ``[b, s, h, d] -> [b * h, s, d]`` and its inverse), and every
+    tensor a kernel takes or gives has that shape."""
+    b, s, hd = 2, 128, 128
+    kernels = 0
+    for eqn in _eqns(_two_layer_lm_grad_jaxpr(remat_policy)):
+        if eqn.primitive.name == "transpose":
+            assert len(eqn.params["permutation"]) < 4, eqn
+        if eqn.primitive.name == "pallas_call":
+            kernels += 1
+            tensors = [v.aval.shape for v in (*eqn.invars, *eqn.outvars)
+                       if v.aval.dtype != jnp.float32]
+            assert tensors and set(tensors) == {(b, s, hd)}, tensors
+    assert kernels >= 6
 
 
 @pytest.mark.parametrize("kept", [False, True])
@@ -177,14 +289,13 @@ def test_tags_leave_the_lse_cotangent_path_as_it_was(kept, monkeypatch):
     # the package exports the function under the module's name
     fa = importlib.import_module("bagua_tpu.ops.flash_attention")
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
-    def untagged(q, k, v, causal, block_q, block_k, interpret):
-        o, lse = fa._fwd(q, k, v, causal, block_q, block_k, interpret)
-        return o, lse[:, 0, :]
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+    def untagged(q, k, v, heads, causal, block_q, block_k, interpret):
+        return fa._fwd(q, k, v, heads, causal, block_q, block_k, interpret)
 
-    def untagged_fwd(q, k, v, causal, block_q, block_k, interpret):
-        o, lse = fa._fwd(q, k, v, causal, block_q, block_k, interpret)
-        return (o, lse[:, 0, :]), (q, k, v, o, lse[:, :1, :])
+    def untagged_fwd(q, k, v, heads, causal, block_q, block_k, interpret):
+        o, lse = untagged(q, k, v, heads, causal, block_q, block_k, interpret)
+        return (o, lse), (q, k, v, o, lse)
 
     untagged.defvjp(untagged_fwd, fa._flash_lse_bwd)
 

@@ -168,6 +168,63 @@ def test_remat_policies_preserve_gradients(attention, policy):
     )
 
 
+@pytest.mark.parametrize("out_features", [None, 96])
+def test_heads_dense_is_dense_general_as_one_matmul(out_features):
+    """``HeadsDense`` (what ``Attention`` projects with where the flash
+    kernels run) is ``nn.DenseGeneral`` for both directions: the same
+    parameter tree from the same key, bit for bit, and the same product."""
+    from bagua_tpu.models.transformer import HeadsDense
+
+    h, d = 4, 32
+    if out_features is None:
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, 96))
+        general = nn.DenseGeneral((h, d), axis=-1, use_bias=False,
+                                  dtype=jnp.float32)
+    else:
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 16, h, d))
+        general = nn.DenseGeneral(out_features, axis=(-2, -1), use_bias=False,
+                                  dtype=jnp.float32)
+    merged = HeadsDense(h, d, out_features, dtype=jnp.float32)
+    want = general.init(jax.random.PRNGKey(1), x)
+    got = merged.init(jax.random.PRNGKey(1), x)
+    assert jax.tree.structure(got) == jax.tree.structure(want)
+    np.testing.assert_array_equal(got["params"]["kernel"],
+                                  want["params"]["kernel"])
+    np.testing.assert_allclose(merged.apply(got, x), general.apply(want, x),
+                               atol=1e-5, rtol=1e-5)
+
+
+def test_attention_projects_with_one_matmul_where_the_kernels_run(
+        monkeypatch):
+    """Where ``flash_supported`` says the kernels run, the four projections
+    are 2-D matmuls (no ``dot_general`` result or operand with a separate
+    heads axis); elsewhere they are ``DenseGeneral``'s, as they were; the
+    parameters are the same tree either way."""
+    import importlib
+
+    from bagua_tpu.models.transformer import Attention
+
+    fa = importlib.import_module("bagua_tpu.ops.flash_attention")
+    cfg = TransformerConfig(vocab_size=97, d_model=128, n_heads=2,
+                            n_layers=1, d_ff=256, max_seq_len=128)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 128, 128), cfg.dtype)
+    attn = Attention(cfg, _forced_flash)
+
+    def dot_ranks(supported):
+        monkeypatch.setattr(fa, "flash_supported", lambda *a, **k: supported)
+        params = jax.eval_shape(attn.init, jax.random.PRNGKey(1), x)
+        jaxpr = jax.make_jaxpr(attn.apply)(params, x)
+        ranks = {len(v.aval.shape) for eqn in jaxpr.eqns
+                 if eqn.primitive.name == "dot_general"
+                 for v in (*eqn.invars, *eqn.outvars)}
+        return jax.tree.map(lambda p: p.shape, params), ranks
+
+    plain_params, plain_ranks = dot_ranks(False)
+    merged_params, merged_ranks = dot_ranks(True)
+    assert merged_params == plain_params
+    assert max(merged_ranks) == 3 and max(plain_ranks) == 4
+
+
 class _ForeignMLP(nn.Module):
     """An MLP from outside the model file: it tags nothing."""
 
@@ -183,19 +240,27 @@ class _ForeignMLP(nn.Module):
     ("dots_no_batch", _ForeignMLP, True),
     ("dots", None, True),
 ])
-def test_what_a_rematted_block_keeps(policy, mlp, keeps_out_projection):
+def test_what_a_rematted_block_keeps(policy, mlp, keeps_out_projection,
+                                     monkeypatch):
     """The kept set of one block against the bare dots rule's (what the
     block kept before the kernel's outputs were tagged): ``o`` as
-    ``[bh, s, d]`` and ``lse`` as the one-row ``[bh, 1, s]`` f32 (never the
-    kernel's 8-sublane stripe) come on top; a stock block under
+    ``[b, s, h * d]`` and ``lse`` as the head rows ``[b * h / g, g, s]`` f32
+    (never the kernel's 8-sublane stripe) come on top; a stock block under
     ``"dots_no_batch"`` gives up the equally large out-projection output
     for ``o`` and so grows by the ``lse`` row alone."""
     from jax._src.ad_checkpoint import saved_residuals  # 0.9.0 exports only
     # the printer, print_saved_residuals
 
+    import importlib
+
     from bagua_tpu.models.transformer import (
         KEPT_FFN_IN, KEPT_QKV, Block)
     from bagua_tpu.utils import remat_wrap
+
+    # the block as it is where the kernels run: projections as one matmul
+    monkeypatch.setattr(importlib.import_module(
+        "bagua_tpu.ops.flash_attention"), "flash_supported",
+        lambda *a, **k: True)
 
     b, s, h, d = 2, 128, 2, 64
     cfg = TransformerConfig(vocab_size=97, d_model=h * d, n_heads=h,
@@ -219,13 +284,18 @@ def test_what_a_rematted_block_keeps(policy, mlp, keeps_out_projection):
         Block, policy,
         matmul_names=(KEPT_QKV, KEPT_FFN_IN) if mlp is None else ()))
 
-    o = ((b * h, s, d), jnp.bfloat16)
-    lse = ((b * h, 1, s), jnp.float32)
-    out_projection = ((b, s, h * d), jnp.bfloat16)
-    assert out_projection in before and o not in before and lse not in before
-    assert after.count(o) == 1 and after.count(lse) == 1
-    assert ((b * h, 8, s), jnp.float32) not in after
-    assert (out_projection in after) == keeps_out_projection
+    # two heads of 64 share a 128-lane block of the kernels: ``o`` has the
+    # shape the four projections write, the head rows are [b * h / 2, 2, s]
+    flat = ((b, s, h * d), jnp.bfloat16)
+    lse = ((b * h // 2, 2, s), jnp.float32)
+    # the bare rule keeps q, k, v and the out-projection as matmuls write
+    # them; a stock block keeps q / k / v under their tag's [b, s, h, d]
+    assert before.count(flat) == 4 and lse not in before
+    tagged_qkv = after.count(((b, s, h, d), jnp.bfloat16))
+    assert tagged_qkv == (3 if mlp is None else 0)
+    assert after.count(flat) + tagged_qkv == 3 + 1 + keeps_out_projection
+    assert after.count(lse) == 1
+    assert ((b * h // 2, 8, s), jnp.float32) not in after
     o_bytes, lse_bytes = b * h * s * d * 2, b * h * s * 4
     assert bytes_after - bytes_before == lse_bytes + (
         o_bytes if keeps_out_projection else 0)
