@@ -333,10 +333,18 @@ class AlgorithmContext:
         set on a single-axis comm world, else the fused psum path.  The
         serialized non-hierarchical construction (``overlap=off``, codec
         knobs at default) always takes the fused psum path."""
+        # the stages that cut a bucket into per-rank chunks take its 1-D
+        # run: a shaped bucket (bucket.py) ravels here, at the point of use,
+        # and has its shape back behind them; the fused psum takes the
+        # tensor as it is (both reshapes are no-ops on a 1-D flat)
+        def raveled(reduce):
+            return reduce(flat.reshape(-1)).reshape(flat.shape)
+
         if hierarchical and self.two_tier():
-            return self.hierarchical_allreduce(flat, op, True, dcn_codec)
+            return raveled(lambda f: self.hierarchical_allreduce(
+                f, op, True, dcn_codec))
         flat_codec = self.flat_ring_codec()
-        k = self._ring_chunks(flat.shape[0], flat.dtype.itemsize)
+        k = self._ring_chunks(flat.size, flat.dtype.itemsize)
         if flat_codec is not None:
             # a forced flat codec rides the ring for hierarchical
             # families too: past the branch above the hierarchical flag
@@ -344,10 +352,11 @@ class AlgorithmContext:
             # lower the same fused psum), and the byte accounting
             # resolves through the identical flat_ring_codec gate, so
             # honoring the knob here is what keeps the spans truthful
-            return self.comm.ring_allreduce(flat, op, num_chunks=k,
-                                            codec=flat_codec)
+            return raveled(lambda f: self.comm.ring_allreduce(
+                f, op, num_chunks=k, codec=flat_codec))
         if k > 1 and not hierarchical:
-            return self.comm.ring_allreduce(flat, op, num_chunks=k)
+            return raveled(lambda f: self.comm.ring_allreduce(
+                f, op, num_chunks=k))
         return self.hierarchical_allreduce(flat, op, hierarchical)
 
     def bucket_reduce_scatter(self, flat, op: ReduceOp):
@@ -623,10 +632,11 @@ class Algorithm:
     # feedback (EF-SignSGD, arXiv:1901.09847; 1-bit Adam, arXiv:2102.02888)
     # restores convergence by carrying the quantization error forward: each
     # step compresses ``grad + residual`` and keeps the part the wire lost.
-    # The residual lives in ``algo_state["ef"]["buckets"]`` as one fp32 flat
-    # per bucket ([1, padded_numel] per shard, stacked [world, padded_numel]
-    # globally) so it rides the existing state machinery: grad-guard skips
-    # rewind it with the step, rebuckets migrate it through
+    # The residual lives in ``algo_state["ef"]["buckets"]`` as one fp32 buffer
+    # per bucket ([1, *buffer_shape] per shard, stacked [world, *buffer_shape]
+    # globally: ``[1, padded_numel]`` for a 1-D bucket) so it rides the
+    # existing state machinery: grad-guard skips rewind it with the step,
+    # rebuckets migrate it through
     # ``relayout_flats``, and checkpoints carry it with a layout sidecar.
     #
     # One local encode/decode roundtrip per bucket models the wire error.
@@ -682,14 +692,14 @@ class Algorithm:
 
     def ef_init_state(self, ctx: AlgorithmContext, state: Any) -> Any:
         """Merge the error-feedback residual container into ``state``
-        (traced, per shard): one zero fp32 flat per bucket — this shard's
-        ``[1, padded_numel]`` row of the stacked ``[world, padded_numel]``
+        (traced, per shard): one zero fp32 buffer per bucket — this shard's
+        ``[1, *buffer_shape]`` row of the stacked ``[world, *buffer_shape]``
         global.  Identity when no EF codec is active, so families that
         build their own state just wrap it through here."""
         if self.ef_codec(ctx) is None:
             return state
         ef = {"buckets": tuple(
-            jnp.zeros((1, b.padded_numel), jnp.float32)
+            jnp.zeros((1,) + b.buffer_shape, jnp.float32)
             for b in ctx.plan.buckets
         )}
         if state is None:
@@ -701,7 +711,7 @@ class Algorithm:
         """shard_map partition specs (pytree prefixes) for this family's
         algo state: ``default`` is the trainer's replicated spec,
         ``stacked`` its per-rank stacked-leading-axis spec — which is what
-        the EF residual's ``[world, padded_numel]`` buckets ride."""
+        the EF residual's ``[world, *buffer_shape]`` buckets ride."""
         if self.ef_codec(ctx) is None:
             return default
         return {"ef": stacked}
@@ -724,8 +734,10 @@ class Algorithm:
         out, residuals = [], []
         for flat, res in zip(flats, ef["buckets"]):
             c = flat.astype(jnp.float32) + res[0]
-            dec = codec.decode(codec.encode(c[None, :]), c.shape[0])[0]
-            residuals.append((c - dec)[None, :])
+            # the codec quantizes a 1-D run (a shaped bucket ravels here)
+            c1 = c.reshape(-1)
+            dec = codec.decode(codec.encode(c1[None, :]), c1.shape[0])[0]
+            residuals.append((c - dec.reshape(c.shape))[None])
             out.append(c.astype(flat.dtype))
         new_state = dict(algo_state)
         new_state["ef"] = {"buckets": tuple(residuals)}

@@ -16,6 +16,8 @@ as a discrete scatter-gather stage between full-precision tier collectives.
 
 from __future__ import annotations
 
+import jax.numpy as jnp
+
 from ..communication import LINK_ICI, ReduceOp
 from ..compression import compressed_scatter_gather_allreduce
 from .base import Algorithm, AlgorithmContext
@@ -78,6 +80,11 @@ class ByteGradAlgorithm(Algorithm):
         )
 
     def reduce_bucket_grad(self, ctx: AlgorithmContext, index: int, flat):
+        if jnp.ndim(flat) != 1 and ctx.comm.nranks() > 1:
+            # a shaped bucket (bucket.py): the pipeline cuts per-rank
+            # chunks of a 1-D run, so ravel here, at the point of use
+            return self.reduce_bucket_grad(
+                ctx, index, flat.reshape(-1)).reshape(flat.shape)
         # the whole codec (compress → alltoall → decompress → chunk-reduce →
         # compress → allgather → decompress) runs per bucket, so under the
         # overlap scheduler it sits inside the overlap window: bucket i's

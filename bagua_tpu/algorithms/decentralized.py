@@ -185,6 +185,12 @@ class LowPrecisionDecentralizedAlgorithm(Algorithm):
     def _ring_step(self, ctx: AlgorithmContext, x, left, right, mine):
         """One compressed ring exchange for one bucket
         (decentralized_low_precision_synchronous.rs:45-151)."""
+        if jnp.ndim(x) != 1:
+            # a shaped bucket (bucket.py): the codec quantizes a 1-D run,
+            # so ravel here, at the point of use
+            outs = self._ring_step(
+                ctx, *(a.reshape(-1) for a in (x, left, right, mine)))
+            return tuple(o.reshape(x.shape) for o in outs)
         use_hier = (
             self.hierarchical
             and ctx.internode is not None

@@ -3,9 +3,17 @@
 Counterpart of /root/reference/bagua/torch_api/algorithms/gradient_allreduce.py:8-38
 plus its backing comm op
 (comm_ops/centralized_full_precision_synchronous.rs:16-56).  One fused
-``psum``/``pmean`` per bucket; XLA's latency-hiding scheduler overlaps the
-collectives with remaining backward compute, which is the whole job the
-reference's Rust scheduler + dedicated CUDA stream existed to do.
+``psum``/``pmean`` per bucket, issued where the bucket's gradient becomes
+ready.  That placement is all XLA gives on a TPU today: the all-reduces of
+the compiled step are *synchronous* — the compiler combines the per-bucket
+collectives (99 buckets -> 16 calls on four v5e chips) and none of them
+overlaps backward compute, so the whole exchange is exposed (32.5 ms of a
+120 ms BERT-Large step; PERF.md, "the dp4 exchange, read off the chip").
+The overlap the reference's Rust scheduler + dedicated CUDA stream bought is
+NOT had for free here; ROADMAP.md Queue 1 item 1 holds what was tried.
+
+A bucket that is one tensor keeps the tensor's own shape (bucket.py): the
+fused ``psum`` takes it as it takes a 1-D flat.
 """
 
 from __future__ import annotations
@@ -19,9 +27,10 @@ from .base import Algorithm, AlgorithmContext
 class GradientAllReduceAlgorithm(Algorithm):
     name = "gradient_allreduce"
     supports_overlap = True
-    #: the per-bucket allreduce consumes resident bucket flats directly
-    #: (zero repacking) — measured on-par-to-faster than the leaf layout
-    #: on the cpu-sim mesh (BENCH_FLAT.json), so ``auto`` takes it
+    #: the per-bucket allreduce consumes resident bucket buffers directly
+    #: (zero repacking; shaped or 1-D alike) — measured on-par-to-faster
+    #: than the leaf layout on the cpu-sim mesh (BENCH_FLAT.json), so
+    #: ``auto`` takes it
     supports_flat_resident = True
     #: reduced buckets are replicated (plain psum/ring sum — a NaN/Inf
     #: contribution from any rank survives into every rank's copy), so the

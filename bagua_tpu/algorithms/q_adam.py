@@ -153,6 +153,12 @@ class QAdamAlgorithm(Algorithm):
 
     def _communicate_bucket(self, ctx: AlgorithmContext, f, use_two_level,
                             use_hier):
+        if jnp.ndim(f) != 1:
+            # a shaped bucket (bucket.py): the tiers and the compressed
+            # scatter-gather cut per-rank chunks of a 1-D run, so ravel
+            # here, at the point of use
+            return self._communicate_bucket(
+                ctx, f.reshape(-1), use_two_level, use_hier).reshape(f.shape)
         if use_two_level:
             f = ctx.tier_reduce_scatter(f, ReduceOp.AVG)
             f = ctx.tier_allreduce(f, ReduceOp.AVG, codec=self.codec)

@@ -18,7 +18,8 @@ byte audit in tests/test_hlo_comm_bytes.py), while storing only
 On pure-dp meshes the params are FLAT-RESIDENT: ``TrainState.params`` holds
 the bucket flat buffers across steps and the trainer differentiates the
 loss w.r.t. the flats directly — the forward materializes leaf views by
-slicing (XLA fuses it) and autodiff's scatter-add IS the gradient flatten,
+slicing (a re-tiling copy on a TPU, except for a shaped bucket, whose buffer
+is the leaf: bucket.py) and autodiff's transpose IS the gradient flatten,
 so the per-step leaf->flat->leaf round trip the leaf layout paid is gone.
 Measured on one v5e chip (ResNet50, batch 128, comm a no-op, both families
 at the HBM roofline — 909 vs 920 GB/s): the leaf layout trailed plain
@@ -198,15 +199,17 @@ class ZeroOptimizerAlgorithm(Algorithm):
 
     def _chunk_size(self, ctx: AlgorithmContext, flat) -> int:
         n = self._shard_comm(ctx).nranks()
-        assert flat.shape[0] % n == 0, (
-            f"bucket numel {flat.shape[0]} not divisible by shard count {n}"
+        assert flat.size % n == 0, (
+            f"bucket numel {flat.size} not divisible by shard count {n}"
         )
-        return flat.shape[0] // n
+        return flat.size // n
 
     def _my_chunk(self, ctx: AlgorithmContext, flat):
+        # chunks are 1-D runs of the bucket: a shaped bucket (bucket.py)
+        # ravels here, at the point of use
         size = self._chunk_size(ctx, flat)
         start = self._shard_comm(ctx).rank() * size
-        return jax.lax.dynamic_slice(flat, (start,), (size,))
+        return jax.lax.dynamic_slice(flat.reshape(-1), (start,), (size,))
 
     def _avg_scatter(self, ctx: AlgorithmContext, flat):
         """Average ``flat`` over the whole comm world and return this rank's
@@ -214,6 +217,7 @@ class ZeroOptimizerAlgorithm(Algorithm):
         reduce_scatter over intra, then allreduce the owned chunk over inter
         — the global average with only ``1/intra`` of the bytes crossing the
         inter tier (avg-of-avgs is exact: intra rows are equal-sized)."""
+        flat = flat.reshape(-1)  # (a shaped bucket: chunks are 1-D runs)
         if not self._staged(ctx):
             # chunked ring when the overlap scheduler set a chunk size,
             # fused psum_scatter otherwise (identical chunk layout)
@@ -310,7 +314,8 @@ class ZeroOptimizerAlgorithm(Algorithm):
         gchunks = []
         for i, gf in enumerate(gflats):
             with phase_scope(f"bagua.comm/bucket_{i}"):
-                gchunks.append(ctx.comm.reduce_scatter(gf, ReduceOp.AVG))
+                gchunks.append(
+                    ctx.comm.reduce_scatter(gf.reshape(-1), ReduceOp.AVG))
         local_g = self._local_named(ctx, grads)
 
         if self.clip_global_norm is not None:
@@ -341,7 +346,8 @@ class ZeroOptimizerAlgorithm(Algorithm):
             pchunk = optax.apply_updates(pchunk, updates)
             # re-replicate the updated params (rank chunks in rank order)
             with phase_scope(f"bagua.comm/bucket_{i}"):
-                new_pflats.append(ctx.comm.allgather(pchunk, tiled=True))
+                new_pflats.append(
+                    ctx.comm.allgather(pchunk, tiled=True).reshape(pf.shape))
             new_states.append(st)
         named = ctx.plan.unflatten_to_named(new_pflats)
 
@@ -397,10 +403,10 @@ class ZeroOptimizerAlgorithm(Algorithm):
             # layout-symmetric when overlap chunking is on (the staged one
             # against the ICI tier's target).
             with phase_scope(f"bagua.comm/bucket_{i}"):
-                new_flats.append(
+                new_flats.append((
                     ctx.bucket_allgather(pchunk) if shard is ctx.comm
                     else ctx.tier_allgather(pchunk)
-                )
+                ).reshape(pf.shape))
             new_states.append(st)
         new_params = {"flats": tuple(new_flats), "local": params["local"]}
         return new_params, {"buckets": tuple(new_states),

@@ -31,7 +31,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import env
 from ..algorithms.base import Algorithm, AlgorithmContext
-from ..bucket import BucketPlan, split_bucket_by_bucket_size
+from ..bucket import (BucketPlan, conform_flats,
+                      split_bucket_by_bucket_size)
 from ..communication import (
     BaguaCommunicator, ReduceOp, abort, check_abort, collapse_trivial_axes,
 )
@@ -888,7 +889,7 @@ class BaguaTrainer:
                     len(plan.buckets),
                 )
                 ef = {"buckets": tuple(
-                    jnp.zeros((world, b.padded_numel), jnp.float32)
+                    jnp.zeros((world,) + b.buffer_shape, jnp.float32)
                     for b in plan.buckets
                 )}
                 return state._replace(algo_state={"ef": ef})
@@ -940,20 +941,35 @@ class BaguaTrainer:
         from ..bucket import relayout_flats
 
         is_zp = self._is_flat_container
+        # a tensor that is its own (shaped) bucket under both plans keeps
+        # its buffer: nothing to compute, and no second copy of it
+        tensors_of = lambda b: b.signature()[2]  # (name, shape, dtype), ...
+        own = {tensors_of(b): i for i, b in enumerate(old_plan.buckets)
+               if b.shaped}
+        kept = {i: own[tensors_of(b)]
+                for i, b in enumerate(new_plan.buckets)
+                if b.shaped and tensors_of(b) in own}
+        rest = [i for i in range(len(new_plan.buckets)) if i not in kept]
         # one program a tree, not a dispatch (and on a chip a compile) per
         # tensor segment; params and every moment share it by shape
-        relayout = jax.jit(
-            lambda flats: relayout_flats(old_plan, new_plan, flats)
-        )
+        relayout = jax.jit(lambda flats: [
+            relayout_flats(old_plan, new_plan, flats)[i] for i in rest
+        ])
 
         def fix(x):
             if is_zp(x):
-                flats = tuple(relayout(tuple(x["flats"])))
+                old = tuple(x["flats"])
+                flats = [old[kept[i]] if i in kept else None
+                         for i in range(len(new_plan.buckets))]
+                for i, f in zip(rest, relayout(old)):
+                    flats[i] = f
                 if consume:
-                    # every new flat is a fresh buffer (slices, concatenated)
-                    for f in x["flats"]:
-                        f.delete()
-                return {"flats": flats, "local": x["local"]}
+                    # every other new flat is a fresh buffer (slices,
+                    # concatenated)
+                    for i, f in enumerate(old):
+                        if i not in kept.values():
+                            f.delete()
+                return {"flats": tuple(flats), "local": x["local"]}
             return x
 
         return jax.tree.map(fix, tree, is_leaf=is_zp)
@@ -1071,8 +1087,9 @@ class BaguaTrainer:
             # Pure-dp meshes use the FLAT-RESIDENT layout (resolved above,
             # ``flat_resident="auto"`` default): params live as the bucket
             # flat buffers across steps and the step differentiates w.r.t.
-            # the flats directly — the forward unflatten is fusable slicing
-            # and autodiff's scatter-add IS the gradient flatten, so the
+            # the flats directly — the forward unflatten is slicing (a
+            # shaped bucket's buffer is the leaf itself: bucket.py) and
+            # autodiff's transpose of it IS the gradient flatten, so the
             # per-step leaf->flat->leaf round trip (the measured ~7%
             # single-chip ZeRO overhead, VERDICT r3 #4) disappears.
             # Model-parallel compositions (and flat_resident="off") keep
@@ -1275,7 +1292,8 @@ class BaguaTrainer:
             if self._is_flat_container(grads):
                 flats = list(grads["flats"])
                 f = flats[b]
-                flats[b] = jnp.where(fire, f.at[0].set(bad.astype(f.dtype)), f)
+                flats[b] = jnp.where(
+                    fire, f.at[(0,) * f.ndim].set(bad.astype(f.dtype)), f)
                 grads = {"flats": tuple(flats), "local": grads["local"]}
             else:
                 target = plan.buckets[b].tensors[0].name
@@ -1347,10 +1365,12 @@ class BaguaTrainer:
             # (.../rematted_computation/...) in every instruction's op_name
             with phase_scope("bagua.loss"):
                 if leaf_view is not None:
-                    # flat-resident params: materialize the leaf view
-                    # (slicing — XLA fuses it); autodiff w.r.t. zp scatters
-                    # grads straight back into bucket-flat layout.  The
-                    # slicing names itself bagua.layout (bucket.py).
+                    # flat-resident params: materialize the leaf view —
+                    # a shaped bucket's buffer is the leaf; the others are
+                    # sliced and reshaped out of their 1-D flats (a physical
+                    # re-tiling on a TPU, not a fused slice: bucket.py) and
+                    # autodiff w.r.t. zp pads the grads straight back into
+                    # that layout.  The slicing names itself bagua.layout.
                     zp = leaf_view(zp)
                 return self.loss_fn(zp, b)
 
@@ -1620,17 +1640,18 @@ class BaguaTrainer:
         return jax.jit(fn, donate_argnums=(0,) if self.donate else ())
 
     def _flat_leaf_view(self, zp):
-        """Materialize the leaf pytree from the flat-resident ZeRO layout
-        (traceable; slicing that XLA fuses).  The ONE implementation of the
+        """Materialize the leaf pytree from the flat-resident layout
+        (traceable: a shaped bucket's buffer passes through, the rest is
+        sliced out of the 1-D flats).  The ONE implementation of the
         flats->leaves contract, shared by the train step, eval step, and
         ``unstack_params``."""
         from ..tensor import tree_from_named
 
-        got = [int(jnp.shape(f)[-1]) for f in zp["flats"]]
-        want = [b.padded_numel for b in self._plan.buckets]
+        got = [tuple(jnp.shape(f)) for f in zp["flats"]]
+        want = [b.buffer_shape for b in self._plan.buckets]
         if got != want:
             raise ValueError(
-                f"flat-resident state carries bucket flats of sizes {got} "
+                f"flat-resident state carries bucket buffers shaped {got} "
                 f"but this trainer's plan expects {want} — the state was "
                 "built under a different bucket plan (another trainer, or "
                 "a pre-rebucket checkpoint).  Restore through "
@@ -1702,6 +1723,14 @@ class BaguaTrainer:
             counters.set_gauge(
                 "comm/buckets_per_step",
                 len(self._plan.buckets) if self._comm.nranks() > 1 else 0)
+            # how much of the plan is held in its tensors' own shapes
+            # (bucket.py: a tensor as large as a bucket is its own bucket)
+            nbytes = [b.padded_numel * np.dtype(b.dtype).itemsize
+                      for b in self._plan.buckets]
+            counters.set_gauge(
+                "comm/shaped_bytes_share",
+                sum(n for n, b in zip(nbytes, self._plan.buckets)
+                    if b.shaped) / max(1, sum(nbytes)))
             # the wall window of the step that triggers this compile is
             # garbage-slow: no speed sample, no anomaly, booked to `compile`
             self._observer.note_window_class("compile")
@@ -3029,10 +3058,14 @@ class BaguaTrainer:
             ef_plan = BucketPlan.from_layout_descriptor(
                 saved_ef["flat_layout"]
             )
+            # (a sidecar older than the shaped buckets wrote every
+            # residual 1-D: restore it so, conform it below)
+            ef_shapes = BucketPlan.saved_buffer_shapes(
+                saved_ef["flat_layout"])
             saved_container = {"ef": {"buckets": tuple(
-                jax.ShapeDtypeStruct((int(saved_ef["world"]),
-                                      b.padded_numel), np.dtype(np.float32))
-                for b in ef_plan.buckets
+                jax.ShapeDtypeStruct((int(saved_ef["world"]),) + shape,
+                                     np.dtype(np.float32))
+                for shape in ef_shapes
             )}}
 
         if has_live:
@@ -3091,6 +3124,9 @@ class BaguaTrainer:
                 return state._replace(
                     algo_state={**a2, "ef": zeros}
                 )
+            restored_ef = {"buckets": tuple(conform_flats(
+                ef_plan, restored_ef["buckets"], ef_shapes))}
+            a2 = {**a2, "ef": restored_ef}
             if ef_plan.signature() != live_plan.signature():
                 logger.info(
                     "restore_checkpoint: relaying out the error-feedback "
@@ -3103,7 +3139,7 @@ class BaguaTrainer:
                 return state._replace(
                     algo_state={**a2, "ef": migrated["ef"]}
                 )
-            return state
+            return state._replace(algo_state=a2)
 
         return state_like._replace(algo_state=adapted_algo), fixup
 
@@ -3127,6 +3163,16 @@ class BaguaTrainer:
             saved is not None
             and saved.get("plan_signature") == expected["plan_signature"]
         )
+        # the shapes the checkpoint's bucket buffers were written in: a
+        # sidecar older than the shaped buckets (bucket.py) holds every
+        # buffer 1-D, which the same PLAN may now hold in a tensor's shape
+        saved_shapes = (
+            BucketPlan.saved_buffer_shapes(saved["flat_layout"])
+            if saved_layout == "flat" and "flat_layout" in saved else None
+        )
+        same_form = saved_shapes is None or not same_plan or saved_shapes == [
+            b.buffer_shape for b in self._plan.buckets
+        ]
         saved_world = (saved or {}).get("world_size")
         if (
             not self.algorithm.replicated_params
@@ -3142,11 +3188,43 @@ class BaguaTrainer:
             return self._restore_stacked_resized(
                 manager, state_like, step, saved, int(saved_world)
             )
-        if saved is None or (same_layout and (saved_layout == "leaf"
-                                              or same_plan)):
+        if saved is None or (same_layout and (
+                saved_layout == "leaf" or (same_plan and same_form))):
             return direct()
         if saved_layout not in ("flat", "leaf"):
             return direct()
+        old_plan = (
+            BucketPlan.from_layout_descriptor(saved["flat_layout"])
+            if saved_shapes is not None else None
+        )
+        is_zp = self._is_flat_container
+
+        def saved_sds():
+            return {
+                "flats": tuple(
+                    jax.ShapeDtypeStruct(shape, np.dtype(b.dtype))
+                    for b, shape in zip(old_plan.buckets, saved_shapes)
+                ),
+                "local": {},
+            }
+
+        def conform(x):
+            # restored in the saved shapes -> old_plan's own buffer shapes
+            if is_zp(x):
+                return {"flats": tuple(conform_flats(
+                    old_plan, x["flats"], saved_shapes)), "local": x["local"]}
+            return x
+
+        if (same_layout and same_plan and not same_form
+                and self.algorithm.replicated_params):
+            # this trainer's own plan, written before its lone big tensors
+            # kept their shape: every chunk state and rank stack restores
+            # as it is, the resident buffers with one reshape each
+            step, restored = manager.restore(
+                jax.tree.map(lambda x: saved_sds() if is_zp(x) else x,
+                             state_like, is_leaf=is_zp),
+                step=step, expect_metadata=saved, mesh=self.mesh)
+            return step, jax.tree.map(conform, restored, is_leaf=is_zp)
         if self.algorithm.sharded_opt_state:
             # per-chunk optimizer states are keyed on bucket boundaries AND
             # rank count; no host-side conversion exists — surface the
@@ -3181,26 +3259,11 @@ class BaguaTrainer:
             # a bare-leaf param "tree" cannot be located structurally
             return direct()
 
-        old_plan = (
-            BucketPlan.from_layout_descriptor(saved["flat_layout"])
-            if saved_layout == "flat" else None
-        )
-        is_zp = self._is_flat_container
-
         def is_param_tree(x):
             try:
                 return jax.tree_util.tree_structure(x) == param_def
             except Exception:  # unhashable/exotic leaves
                 return False
-
-        def flat_sds(plan):
-            return {
-                "flats": tuple(
-                    jax.ShapeDtypeStruct((b.padded_numel,), np.dtype(b.dtype))
-                    for b in plan.buckets
-                ),
-                "local": {},
-            }
 
         # 1. rebuild the SAVED state's structure from the live template:
         # optimizer state mirrors the params, so substituting at every
@@ -3210,13 +3273,13 @@ class BaguaTrainer:
             saved_like = jax.tree.map(
                 lambda x: (
                     (self._param_template if saved_layout == "leaf"
-                     else flat_sds(old_plan)) if is_zp(x) else x
+                     else saved_sds()) if is_zp(x) else x
                 ),
                 state_like, is_leaf=is_zp,
             )
         else:
             saved_like = jax.tree.map(
-                lambda x: flat_sds(old_plan) if is_param_tree(x) else x,
+                lambda x: saved_sds() if is_param_tree(x) else x,
                 state_like, is_leaf=is_param_tree,
             )
         # expect the SAVED layout here: this restore deliberately targets
@@ -3247,7 +3310,9 @@ class BaguaTrainer:
         elif self._flat_resident:
             # replicated families only reach here (gossip took direct()),
             # so every plan-keyed buffer is behind a flat-container marker
-            converted = self._relayout_tree(restored, old_plan, self._plan)
+            converted = self._relayout_tree(
+                jax.tree.map(conform, restored, is_leaf=is_zp),
+                old_plan, self._plan)
         else:
             converted = jax.tree.map(from_flat, restored, is_leaf=is_zp)
         logger.info(

@@ -107,6 +107,12 @@ _declare("comm/buckets_per_step", "gauge",
          "when the comm world is one rank), set when a step program is "
          "built.  What XLA's collective combiner makes of them is a count "
          "over the compiled text (the benchmark's comm_calls_compiled).")
+_declare("comm/shaped_bytes_share", "gauge",
+         "Share of the bucket plan's bytes held in shaped buckets: one "
+         "tensor of at least bucket_bytes, no padding, kept in its own "
+         "shape as parameter, gradient and optimizer state (no re-tiling "
+         "between a 1-D flat and a matrix).  Set when a step program is "
+         "built.")
 # -- mixture of experts (set when a step with a dropless MoEMLP is traced) --
 _declare("moe/experts", "gauge",
          "Experts held by this rank in the MoE layer last traced.")

@@ -85,10 +85,14 @@ def _restore_with_layout(mgr: BaguaCheckpointManager, step: int,
     sidecar = mgr.read_layout(step)  # torn sidecar -> integrity error
     if sidecar and "flat_layout" in sidecar:
         plan = BucketPlan.from_layout_descriptor(sidecar["flat_layout"])
+        # in the shapes the artifact was written with (an older one holds
+        # every buffer 1-D; unflatten_to_named takes either)
         flats_like = {
             "flats": tuple(
-                jax.ShapeDtypeStruct((b.padded_numel,), np.dtype(b.dtype))
-                for b in plan.buckets
+                jax.ShapeDtypeStruct(shape, np.dtype(b.dtype))
+                for b, shape in zip(plan.buckets,
+                                    BucketPlan.saved_buffer_shapes(
+                                        sidecar["flat_layout"]))
             ),
         }
         # the expectation IS the sidecar's own constraint set (the flat

@@ -324,8 +324,9 @@ def test_layout_metadata_roundtrip_and_mismatch(tmp_path):
     assert np.isfinite(float(loss))
 
     # different bucket plan: actionable layout error, not an orbax shape
-    # error (32B buckets genuinely re-split this model; 128 would not)
-    t3 = new_trainer(bucket_bytes=32)
+    # error (4096B buckets genuinely re-split this model: one bucket of
+    # everything; at 32 every leaf would stand alone, as at 256)
+    t3 = new_trainer(bucket_bytes=4096)
     s3 = t3.init(params)
     assert t3._plan.signature() != t1._plan.signature()
     with pytest.raises(ValueError, match="checkpoint layout mismatch"):

@@ -107,6 +107,8 @@ def _residual_by_tensor(trainer, state):
     representation (bucket padding excluded)."""
     out = {}
     for b, flat in zip(trainer._plan.buckets, _ef(state)):
+        # (a shaped bucket's residual keeps the tensor's own shape)
+        flat = np.asarray(flat).reshape(flat.shape[0], -1)
         for t, off in zip(b.tensors, b.offsets()):
             out[t.name] = np.asarray(flat[:, off:off + t.numel])
     return out
@@ -121,7 +123,7 @@ def test_ef_state_created_and_trains(codec):
     assert trainer._ef_active()
     assert set(state.algo_state) == {"ef"}
     assert [tuple(b.shape) for b in _ef(state)] == [
-        (N, b.padded_numel) for b in trainer._plan.buckets
+        (N,) + b.buffer_shape for b in trainer._plan.buckets
     ]
     assert _residual_norm(state) == 0.0  # EF inits at zero
     batch = _batches(1)[0]  # fixed batch: per-step losses are comparable
@@ -200,7 +202,7 @@ def test_rebucket_migrates_ef_residual():
     assert np.isfinite(float(loss))
     # residual now laid out on the NEW plan
     assert [tuple(b.shape) for b in _ef(state)] == [
-        (N, b.padded_numel) for b in trainer._plan.buckets
+        (N,) + b.buffer_shape for b in trainer._plan.buckets
     ]
     assert all(bool(jnp.isfinite(b).all()) for b in _ef(state))
 
@@ -251,7 +253,7 @@ def test_checkpoint_cross_plan_relayouts_residual(tmp_path, caplog):
     # the accumulated error survived the relayout (not zero-reset) ...
     assert _residual_norm(restored) > 0.0
     assert [tuple(b.shape) for b in _ef(restored)] == [
-        (N, b.padded_numel) for b in other._plan.buckets
+        (N,) + b.buffer_shape for b in other._plan.buckets
     ]
     # ... element-for-element: relayout is slice+concat, so every tensor's
     # residual rows cross the boundary change bit-exactly (only old bucket
@@ -324,14 +326,14 @@ def test_world_resize_zero_resets_residual(caplog):
     # ... and the fixup converts a (simulated) restored state back to the
     # live world as zeros
     fake_restored = state._replace(algo_state={"ef": {"buckets": tuple(
-        jnp.ones((saved_world, b.padded_numel), jnp.float32)
+        jnp.ones((saved_world,) + b.buffer_shape, jnp.float32)
         for b in trainer._plan.buckets
     )}})
     with caplog.at_level(logging.WARNING, logger="bagua_tpu.core.backend"):
         fixed = fixup(fake_restored)
     assert any("elastic resize" in r.getMessage() for r in caplog.records)
     assert [tuple(b.shape) for b in _ef(fixed)] == [
-        (N, b.padded_numel) for b in trainer._plan.buckets
+        (N,) + b.buffer_shape for b in trainer._plan.buckets
     ]
     assert _residual_norm(fixed) == 0.0
 

@@ -361,10 +361,20 @@ def test_rebucket_migration_consumes_the_old_flats(donate):
              for t in b.tensors]
     trainer.rebucket(split_bucket_by_bucket_size(decls, 1024))
     migrated = trainer._pending_state_migration(held)
-    old = [f for tree in (held.params, held.opt_state)
-           for x in jax.tree.leaves(tree, is_leaf=trainer._is_flat_container)
-           if trainer._is_flat_container(x) for f in x["flats"]]
-    assert old and all(f.is_deleted() == donate for f in old)
+
+    def flats_of(st):
+        return [f for tree in (st.params, st.opt_state)
+                for x in jax.tree.leaves(tree,
+                                         is_leaf=trainer._is_flat_container)
+                if trainer._is_flat_container(x) for f in x["flats"]]
+
+    old, new = flats_of(held), flats_of(migrated)
+    # a tensor that is its own bucket under both plans (dense_0.bias) is
+    # MOVED: the new state holds the very buffer, so it is never freed
+    moved = [f for f in old if any(f is g for g in new)]
+    assert len(moved) == 3 and not any(f.is_deleted() for f in moved)
+    rest = [f for f in old if not any(f is g for g in moved)]
+    assert rest and all(f.is_deleted() == donate for f in rest)
     trainer._pending_state_migration = None
     state, loss = trainer.train_step(migrated, _batches(1)[0])
     assert np.isfinite(float(loss))
@@ -511,7 +521,7 @@ def test_checkpoint_flat_leaf_flat_continuity(tmp_path):
 
     # restore the LEAF checkpoint into a FLAT trainer under a DIFFERENT
     # bucket plan, 3 more steps
-    t3 = make("on", bucket_bytes=32)
+    t3 = make("on", bucket_bytes=4096)
     s3_init = t3.init(params)
     assert t3._plan.signature() != t1._plan.signature()
     step, s3 = t3.restore_checkpoint(mgr, s3_init, step=6)
@@ -557,7 +567,7 @@ def test_checkpoint_flat_to_flat_replan(tmp_path):
     assert t1.save_checkpoint(mgr, 3, s1)
     mgr.wait()
 
-    t2 = make(32)
+    t2 = make(4096)
     s2_init = t2.init(params)
     assert t2._plan.signature() != t1._plan.signature()
     step, s2 = t2.restore_checkpoint(mgr, s2_init)
@@ -588,7 +598,7 @@ def test_checkpoint_zero_cross_plan_still_blocked(tmp_path):
     mgr = BaguaCheckpointManager(str(tmp_path / "ckpt"), async_save=False)
     assert t1.save_checkpoint(mgr, 1, s1)
     mgr.wait()
-    t2 = make(32)
+    t2 = make(4096)
     s2_init = t2.init(params)
     assert t2._plan.signature() != t1._plan.signature()
     with pytest.raises(ValueError, match="checkpoint layout mismatch"):
@@ -613,3 +623,141 @@ def test_eval_and_unstack_under_flat_residency():
     reflat = trainer._plan.flatten_tree(leaves)
     for a, b in zip(reflat, state.params["flats"]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# ---- a tensor as large as a bucket is its own bucket, in its own shape ------
+
+
+def _shaped(trainer):
+    return {b.tensors[0].name: i for i, b in enumerate(trainer._plan.buckets)
+            if b.shaped}
+
+
+@pytest.mark.parametrize("accum", [1, 4])
+def test_shaped_flat_trajectory_is_the_leaf_layouts_bit_for_bit(accum):
+    """At 256-byte buckets both kernels of the MLP are as large as a bucket:
+    each stands alone and the resident state holds it — parameter, moments,
+    gradient — in its own shape.  The step then does to every leaf exactly
+    what the leaf layout's step does, so the trajectories agree to the bit.
+    ``accum=4`` goes through the overlap scheduler's one-time readiness
+    re-bucketing (same split rule, flat->flat migration) on the way."""
+    opt = optax.adam(1e-2)
+    l_leaf, st_leaf, tr_leaf = _train(GradientAllReduceAlgorithm, opt, "off",
+                                      accum, steps=5)
+    l_flat, st_flat, tr_flat = _train(GradientAllReduceAlgorithm, opt, "on",
+                                      accum, steps=5)
+    assert tr_flat._flat_resident and not tr_leaf._flat_resident
+    assert tr_flat._overlap_ordered == (accum > 1)
+    shaped = _shaped(tr_flat)
+    assert {"dense_0.kernel", "dense_1.kernel"} <= set(shaped)
+    leaves = tr_flat.unstack_params(st_flat)
+    mu = st_flat.opt_state[0].mu
+    for name, i in shaped.items():
+        layer, leaf = name.split(".")
+        want = leaves[layer][leaf].shape
+        assert st_flat.params["flats"][i].shape == want
+        assert mu["flats"][i].shape == want
+    np.testing.assert_array_equal(l_flat, l_leaf)
+    _leaf_allclose(tr_flat, st_flat, tr_leaf, st_leaf)
+
+
+def _as_written_before_shaped_buckets(trainer, state):
+    """``state`` and its sidecar as a trainer older than the shaped buckets
+    wrote them: every bucket buffer 1-D, no ``buffer_shape`` in the layout."""
+    is_zp = trainer._is_flat_container
+
+    def ravel(x):
+        if is_zp(x):
+            return {"flats": tuple(f.reshape(-1) for f in x["flats"]),
+                    "local": x["local"]}
+        return x
+
+    meta = trainer.checkpoint_layout_metadata()
+    meta["flat_layout"] = [
+        {k: v for k, v in b.items() if k != "buffer_shape"}
+        for b in meta["flat_layout"]
+    ]
+    old = state._replace(
+        params=jax.tree.map(ravel, state.params, is_leaf=is_zp),
+        opt_state=jax.tree.map(ravel, state.opt_state, is_leaf=is_zp))
+    return old, meta
+
+
+@pytest.mark.parametrize("target", [
+    "same_plan", "other_plan", "leaf", "zero_same_plan",
+])
+def test_restore_of_a_checkpoint_written_all_1d(tmp_path, target):
+    """A sidecar that describes the old all-1-D layout still restores: the
+    buffers hold the same elements in the same order, so the restore asks
+    the checkpoint for the shapes it was written in and reshapes once —
+    under the identical plan (ZeRO's plan-locked chunk states included),
+    across plans, and into the leaf layout."""
+    zero = target.startswith("zero")
+
+    def make(mode="on", bucket_bytes=256):
+        if zero:
+            return BaguaTrainer(
+                _loss_fn, None, ZeroOptimizerAlgorithm(optax.adam(1e-2)),
+                bucket_bytes=bucket_bytes, autotune=False)
+        return BaguaTrainer(
+            _loss_fn, optax.adam(1e-2), GradientAllReduceAlgorithm(),
+            bucket_bytes=bucket_bytes, autotune=False, flat_resident=mode)
+
+    batches = _batches(6)
+    t_ref = make()
+    s_ref = t_ref.init(_params())
+    base = []
+    for b in batches:
+        s_ref, loss = t_ref.train_step(s_ref, b)
+        base.append(float(loss))
+
+    t1 = make()
+    s1 = t1.init(_params())
+    for b in batches[:3]:
+        s1, _ = t1.train_step(s1, b)
+    assert _shaped(t1)  # the live state does hold shaped buffers
+    old_state, old_meta = _as_written_before_shaped_buckets(t1, s1)
+    mgr = BaguaCheckpointManager(str(tmp_path / "ckpt"), async_save=False)
+    assert mgr.save(3, old_state, metadata=old_meta)
+    mgr.wait()
+
+    t2 = {"same_plan": make, "zero_same_plan": make,
+          "other_plan": lambda: make(bucket_bytes=4096),
+          "leaf": lambda: make("off")}[target]()
+    step, s2 = t2.restore_checkpoint(mgr, t2.init(_params()))
+    assert step == 3
+    if target != "leaf":
+        assert [f.shape for f in s2.params["flats"]] == [
+            b.buffer_shape for b in t2._plan.buckets]
+    tail = []
+    for b in batches[3:]:
+        s2, loss = t2.train_step(s2, b)
+        tail.append(float(loss))
+    if target in ("same_plan", "zero_same_plan"):
+        np.testing.assert_array_equal(np.array(tail), np.array(base[3:]))
+    else:  # another program: bucket sums may associate differently
+        np.testing.assert_allclose(np.array(tail), np.array(base[3:]),
+                                   rtol=1e-6, atol=0)
+    mgr.close()
+
+
+def test_shaped_bytes_share_gauge_is_set_with_the_step_program():
+    from bagua_tpu.obs import export
+    from bagua_tpu.telemetry import counters
+
+    assert export.is_registered("comm/shaped_bytes_share")
+    _, _, trainer = _train(GradientAllReduceAlgorithm, optax.sgd(0.1), "on",
+                           steps=1)
+    nbytes = {b.tensors[0].name: b.numel * 4 for b in trainer._plan.buckets}
+    plan_bytes = sum(b.padded_numel * 4 for b in trainer._plan.buckets)
+    want = sum(nbytes[n] for n in _shaped(trainer)) / plan_bytes
+    assert 0.9 < want <= 1.0
+    assert counters.get("comm/shaped_bytes_share") == pytest.approx(want)
+    # a plan with nothing as large as a bucket holds nothing in its own shape
+    trainer = BaguaTrainer(
+        _loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
+        bucket_bytes=10 ** 6, autotune=False, flat_resident="on")
+    state = trainer.init(_params())
+    trainer.train_step(state, _batches(1)[0])
+    assert not _shaped(trainer)
+    assert counters.get("comm/shaped_bytes_share") == 0

@@ -55,10 +55,12 @@ def obs_on():
 
 def golden_trainer(algorithm="gradient_allreduce", **kw):
     loss_fn, params, batch = bench.golden_task()
-    # 256-byte buckets: the golden MLP's four leaves land in several
+    # 600-byte buckets: the golden MLP's 1024-byte kernel stands alone in
+    # its own shape, the three smaller leaves share a 1-D flat (at 256 every
+    # leaf would be its own bucket and nothing would run under bagua.layout)
     trainer = BaguaTrainer(
         loss_fn, optax.sgd(0.1), ALGORITHMS[algorithm](),
-        mesh=build_mesh({"dp": N_DEVICES}), autotune=False, bucket_bytes=256,
+        mesh=build_mesh({"dp": N_DEVICES}), autotune=False, bucket_bytes=600,
         **kw)
     state = trainer.init(params)
     return trainer, state, trainer.shard_batch(batch)
