@@ -38,9 +38,20 @@ class MoEMLP(nn.Module):
 
     Plugs into ``TransformerLM`` via ``mlp_factory``.  ``ep_size`` is the
     static expert-parallel degree (= mesh ``'ep'`` axis size); each shard owns
-    ``n_experts // ep_size`` experts.  Outside shard_map (e.g. ``model.init``)
-    the all-to-all is skipped and only the local expert slice is computed —
-    parameter shapes are identical, so init-outside / apply-inside works.
+    ``n_experts // ep_size`` experts, and its expert leaves are the LOCAL
+    table.  Inside a bound ``'ep'`` axis the shards exchange rows.  Outside
+    one (``model.init``; one chip running one rank of a larger deployment)
+    the layer computes ONE rank's share by itself, the dropless path for
+    rank ``ep_rank``: the router scores all ``n_experts``, every token
+    keeps its ``k`` winners and their gate weights, the pairs whose expert
+    is one of ``ep_rank * n_local .. + n_local - 1`` are computed, and the
+    rest add zero — what the absent ranks would have added is left out,
+    nothing stands in for them or for their traffic.  Summed over the
+    ``ep_size`` ranks the shares are the whole layer
+    (``tests/test_smallthinker.py``).  The capacity path outside the axis
+    still computes the first ``n_local`` experts' slots only.  Parameter
+    shapes are the same in and outside the axis, so init-outside /
+    apply-inside works.
 
     ``dropless=False`` (default) is the GShard capacity path: top-1 / top-2
     gates, a dense ``[T, E, C]`` dispatch einsum, one batched einsum per
@@ -97,10 +108,21 @@ class MoEMLP(nn.Module):
     #: ``load_balancing_loss_func``) instead of the top-1 (GShard eq. 4).
     #: ``dropless`` only
     balance_over_topk: bool = False
+    #: the experts' activation: ``silu`` (``silu(x wg) * (x wi)``) or
+    #: ``relu`` (ReLU-gated experts, SmallThinker's sparse ReGLU).  Both
+    #: keep a zero row zero.  ``dropless`` only
+    activation: str = "silu"
+    #: the expert-parallel rank whose share is computed outside a bound
+    #: ``axis_name`` axis (``dropless`` only; see the class docstring)
+    ep_rank: int = 0
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, route_x=None):
+        """``route_x`` [batch, seq, d_model]: what the router reads, where
+        that is not what the experts read (a router placed before attention
+        reads the block's input, the experts the post-attention norm)."""
         assert self.n_experts % self.ep_size == 0
+        assert 0 <= self.ep_rank < self.ep_size, (self.ep_rank, self.ep_size)
         n_local = self.n_experts // self.ep_size
         b, s, d = x.shape
         tokens = b * s
@@ -109,10 +131,11 @@ class MoEMLP(nn.Module):
         # router in f32 (small, precision-sensitive; reference TopKGate
         # casts to fp32 too, sharded_moe.py:241-303)
         with phase_scope("bagua.moe/route"):
+            routed = xt if route_x is None else route_x.reshape(tokens, d)
             logits = nn.Dense(
                 self.n_experts, use_bias=False, dtype=jnp.float32,
                 param_dtype=jnp.float32, name="router",
-            )(xt.astype(jnp.float32))
+            )(routed.astype(jnp.float32))
 
         # one definition of the expert weights for both routing paths —
         # always the LOCAL table [n_experts // ep_size, ...]
@@ -139,11 +162,13 @@ class MoEMLP(nn.Module):
             raise ValueError(
                 f"the capacity path gates top-1 and top-2 only, not k="
                 f"{self.k}: set dropless=True")
-        if wg is not None or not self.norm_topk_prob or self.balance_over_topk:
+        if (wg is not None or not self.norm_topk_prob or self.balance_over_topk
+                or self.activation != "silu" or self.ep_rank):
             raise ValueError(
-                "gated experts, norm_topk_prob=False and "
-                "balance_over_topk=True are options of the dropless path: "
-                "set dropless=True")
+                "gated experts, norm_topk_prob=False, balance_over_topk="
+                "True, an activation other than silu and a rank's share "
+                "(ep_rank) are options of the dropless path: set "
+                "dropless=True")
         capacity = max(1, math.ceil(self.k * tokens * self.capacity_factor
                                     / self.n_experts))
         gate = top1_gating if self.k == 1 else top2_gating
@@ -186,11 +211,13 @@ class MoEMLP(nn.Module):
         """The expert FFN on rows resident in ``layout`` (grouped by local
         expert, padding rows zero): two grouped matmuls, three where the
         experts are gated, and the elementwise work between them, all on
-        padded rows — ``silu(0) * 0`` keeps the padding rows zero.  What
+        padded rows — ``act(0) * 0`` keeps the padding rows zero.  What
         the backward pass keeps is ``x_p``, the in-projections and the
         result; the hidden rows are rebuilt there (one elementwise pass)."""
         from ...ops.gmm import gmm_padded
         from ...telemetry import counters
+
+        act = {"silu": nn.silu, "relu": nn.relu}[self.activation]
 
         if not self.is_initializing():
             # what the kernels really multiply (under ``ep`` the rows of
@@ -201,7 +228,7 @@ class MoEMLP(nn.Module):
 
         @jax.checkpoint
         def down(up, gate, wo):
-            h = nn.silu(up) if gate is None else nn.silu(gate) * up
+            h = act(up) if gate is None else act(gate) * up
             return gmm_padded(h, wo, layout)
 
         with phase_scope("bagua.moe/experts"):
@@ -258,15 +285,21 @@ class MoEMLP(nn.Module):
             # trace-time facts of this layer's step (not of ``init``'s stub
             # batch), for the operator and the benchmark's moe_padding_share
             counters.set_gauge("moe/experts", n_local)
+            counters.set_gauge("moe/experts_total", self.n_experts)
             counters.set_gauge("moe/rows_per_step", tokens * k)
 
         inside_mesh = self.ep_size > 1 and _axis_bound(self.axis_name)
         with phase_scope("bagua.moe/dispatch"):
             flat_e = eidx.reshape(-1)                   # [T*k] routed pairs
             if self.ep_size > 1 and not inside_mesh:
-                # the init trace outside shard_map, where only shapes
-                # matter: fold global expert ids onto the local table
-                flat_e = flat_e % n_local
+                # one rank's share by itself: ids on this rank's table,
+                # and for a pair another rank holds the sentinel
+                # ``n_local``, which sorts last, counts in no group and is
+                # not carried into the layout (its slot is one of the
+                # trailing padding slots, which hold zero)
+                local = flat_e - self.ep_rank * n_local
+                flat_e = jnp.where((local >= 0) & (local < n_local), local,
+                                   n_local)
             order = jnp.argsort(flat_e)                 # stable: ties by token
             rank = _inverse_permutation(order)          # pair -> sorted row
             x = xt.astype(self.dtype)

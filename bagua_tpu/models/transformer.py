@@ -97,11 +97,55 @@ class TransformerConfig:
     qk_norm: bool = False
     #: epsilon of every RMSNorm of the model
     norm_eps: float = 1e-6
+    #: key / value heads, each read by ``n_heads // n_kv_heads`` consecutive
+    #: query heads (grouped-query attention); None: one per query head
+    n_kv_heads: Optional[int] = None
+    #: width of a head where it is not ``d_model // n_heads`` (the q and o
+    #: projections are then ``d_model x n_heads * d_head``); read it as
+    #: ``cfg.head_dim``
+    d_head: Optional[int] = None
+    #: causal window of the windowed layers: query ``i`` sees keys ``i -
+    #: window < j <= i`` (its own position counts)
+    window: Optional[int] = None
+    #: which layers are windowed, a period of 0 / 1 repeated over the depth
+    #: (``(0, 1, 1, 1)``: full attention on every fourth layer, the window
+    #: on the three behind it); None: every layer where ``window`` is set
+    window_layers: Optional[tuple] = None
+    #: which layers rotate q and k by ``rope_theta``, a period like
+    #: ``window_layers``; a 0 layer has no positional encoding at all
+    #: (NoPE).  None: every layer
+    rope_layers: Optional[tuple] = None
+    #: the MLP's router reads the block's INPUT (before the attention norm)
+    #: and its experts the post-attention norm: ``Block`` hands a custom MLP
+    #: that input as ``route_x`` (``MoEMLP``)
+    route_before_attention: bool = False
 
     @property
     def head_dim(self) -> int:
+        if self.d_head is not None:
+            return self.d_head
         assert self.d_model % self.n_heads == 0
         return self.d_model // self.n_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.n_heads if self.n_kv_heads is None else self.n_kv_heads
+
+    def layer_window(self, layer: int) -> Optional[int]:
+        """The window of layer ``layer``'s attention, None where it is
+        full."""
+        if self.window is None:
+            return None
+        pattern = self.window_layers
+        return self.window if (pattern is None
+                               or pattern[layer % len(pattern)]) else None
+
+    def layer_rotary(self, layer: int) -> bool:
+        """Whether layer ``layer`` rotates q and k."""
+        if self.rope_theta is None:
+            return False
+        pattern = self.rope_layers
+        return pattern is None or bool(pattern[layer % len(pattern)])
 
 
 def bert_large_config(**kw) -> TransformerConfig:
@@ -148,11 +192,13 @@ def rope_rotate(x, theta: float, start=0):
     return (x32 * cos + rotated * sin).astype(x.dtype)
 
 
-def causal_attention(q, k, v, dtype):
+def causal_attention(q, k, v, dtype, window=None):
     """Causal attention; softmax in f32, matmuls in ``dtype``.
 
-    ``q/k/v``: [batch, seq, heads, head_dim].  The SP paths (ring/Ulysses)
-    provide drop-in replacements with the same signature.
+    ``q``: [batch, seq, heads, head_dim], ``k/v`` the same or with fewer
+    (key / value) heads; ``window``: a layer's causal window.  The SP paths
+    (ring/Ulysses) provide drop-in replacements with the four-argument
+    signature.
 
     On TPU with block-aligned sequence lengths this dispatches to the fused
     Pallas flash-attention kernel (:mod:`bagua_tpu.ops.flash_attention`),
@@ -163,7 +209,7 @@ def causal_attention(q, k, v, dtype):
     """
     from ..ops.flash_attention import flash_attention
 
-    return flash_attention(q, k, v, dtype, causal=True)
+    return flash_attention(q, k, v, dtype, causal=True, window=window)
 
 
 #: reserved page ids of the paged decode pool (see
@@ -239,12 +285,24 @@ def _tp_active(cfg) -> bool:
 class Attention(nn.Module):
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None
+    #: this layer's kind (``TransformerConfig.layer_window`` /
+    #: ``.layer_rotary`` of its index): its causal window, None for full
+    #: attention, and whether it rotates q and k
+    window: Optional[int] = None
+    rotary: bool = True
 
     @nn.compact
     def __call__(self, x, slots=None):
         cfg = self.cfg
         assert cfg.n_heads % cfg.tp_size == 0, (cfg.n_heads, cfg.tp_size)
+        assert cfg.kv_heads % cfg.tp_size == 0, (cfg.kv_heads, cfg.tp_size)
+        assert cfg.n_heads % cfg.kv_heads == 0, (cfg.n_heads, cfg.kv_heads)
         h, d = cfg.n_heads // cfg.tp_size, cfg.head_dim  # local heads
+        kv_h = cfg.kv_heads // cfg.tp_size
+        if cfg.decode and (kv_h != h or self.window is not None):
+            raise NotImplementedError(
+                "grouped key / value heads and windows are not implemented "
+                "for the decode paths")
         if _tp_active(cfg):
             from ..parallel.tensor_parallel import tp_gather_grad
 
@@ -254,16 +312,19 @@ class Attention(nn.Module):
         # same parameters either way
         from ..ops.flash_attention import flash_supported
 
-        if not cfg.decode and flash_supported(x.shape[1], h, d):
-            dense = lambda name, out=None: HeadsDense(
-                h, d, out, name=name, dtype=cfg.dtype,
+        if not cfg.decode and flash_supported(x.shape[1], h, d,
+                                              kv_heads=kv_h):
+            dense = lambda name, out=None, heads=h: HeadsDense(
+                heads, d, out, name=name, dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype)
         else:
-            dense = lambda name, out=None: nn.DenseGeneral(
-                (h, d) if out is None else out,
+            dense = lambda name, out=None, heads=h: nn.DenseGeneral(
+                (heads, d) if out is None else out,
                 axis=-1 if out is None else (-2, -1), name=name,
                 dtype=cfg.dtype, param_dtype=cfg.param_dtype, use_bias=False)
-        q, k, v = (checkpoint_name(dense(n)(x), KEPT_QKV) for n in "qkv")
+        q, k, v = (checkpoint_name(
+            dense(n, heads=h if n == "q" else kv_h)(x), KEPT_QKV)
+            for n in "qkv")
         if cfg.qk_norm:
             if _tp_active(cfg):
                 raise NotImplementedError(
@@ -271,9 +332,9 @@ class Attention(nn.Module):
                     "under tensor parallelism")
             flat_norm = lambda name, t: RMSNorm(
                 cfg.dtype, cfg.param_dtype, cfg.norm_eps, name=name,
-            )(t.reshape(*t.shape[:-2], h * d)).reshape(t.shape)
+            )(t.reshape(*t.shape[:-2], t.shape[-2] * d)).reshape(t.shape)
             q, k = flat_norm("q_norm", q), flat_norm("k_norm", k)
-        if cfg.rope_theta is not None:
+        if cfg.rope_theta is not None and self.rotary:
             if cfg.decode:
                 raise NotImplementedError(
                     "rope_theta is not implemented for the decode paths")
@@ -288,7 +349,10 @@ class Attention(nn.Module):
             o = self._decode_attend(q, k, v)
         else:
             fn = self.attn_fn or causal_attention
-            o = fn(q, k, v, cfg.dtype)
+            # a full layer keeps the four-argument call the sequence-
+            # parallel drop-ins take
+            o = (fn(q, k, v, cfg.dtype) if self.window is None
+                 else fn(q, k, v, cfg.dtype, window=self.window))
         out = dense("o", cfg.d_model)(o)
         if _tp_active(cfg):
             from ..parallel.tensor_parallel import tp_reduce
@@ -443,19 +507,30 @@ class Block(nn.Module):
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None
     mlp: Optional[Callable[[], nn.Module]] = None  # MoE drops in here
+    #: index of the layer: its attention's kind under the configuration's
+    #: layer patterns
+    layer: int = 0
 
     @nn.compact
     def __call__(self, x, slots=None):
         cfg = self.cfg
+        block_in = x
         y = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
                     name="attn_norm")(x)
-        attn = Attention(cfg, self.attn_fn, name="attn")
+        attn = Attention(cfg, self.attn_fn, cfg.layer_window(self.layer),
+                         cfg.layer_rotary(self.layer), name="attn")
         # dense/training call sites keep their exact one-arg form (the
         # goldens pin those programs); only paged decode threads slots
         x = x + (attn(y) if slots is None else attn(y, slots))
         y = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
                     name="mlp_norm")(x)
         mlp = self.mlp() if self.mlp is not None else MLPBlock(cfg, name="mlp")
+        if cfg.route_before_attention:
+            if self.mlp is None:
+                raise ValueError(
+                    "route_before_attention names a router: it needs an "
+                    "expert MLP (mlp_factory) that takes `route_x`")
+            return x + mlp(y, route_x=block_in)
         x = x + mlp(y)
         return x
 
@@ -516,6 +591,17 @@ class TransformerLM(nn.Module):
                         pos_index.value = start + s
                 pos_slice = jax.lax.dynamic_slice_in_dim(pos, start, s, axis=0)
                 x = x + pos_slice[None].astype(cfg.dtype)
+        if not self.is_initializing() and not cfg.decode:
+            # trace-time facts of this model's step, for the operator and
+            # the benchmark's readers of the windowed kernels
+            from ..telemetry import counters
+
+            windowed = sum(cfg.layer_window(i) is not None
+                           for i in range(cfg.n_layers))
+            counters.set_gauge("attn/kv_heads", cfg.kv_heads // cfg.tp_size)
+            counters.set_gauge("attn/window", cfg.window or 0)
+            counters.set_gauge("attn/window_layers", windowed)
+            counters.set_gauge("attn/full_layers", cfg.n_layers - windowed)
         for i in range(cfg.n_layers):
             mlp = self.mlp_factory(i) if self.mlp_factory is not None else None
             block_cls = Block
@@ -523,7 +609,7 @@ class TransformerLM(nn.Module):
                 # a custom MLP tags nothing: its matmuls keep the dots rule
                 own = (KEPT_QKV, KEPT_FFN_IN) if mlp is None else ()
                 block_cls = remat_wrap(Block, cfg.remat_policy, own)
-            blk = block_cls(cfg, self.attn_fn, mlp, name=f"block_{i}")
+            blk = block_cls(cfg, self.attn_fn, mlp, i, name=f"block_{i}")
             x = blk(x) if slots is None else blk(x, slots)
         x = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
                     name="final_norm")(x)

@@ -113,9 +113,28 @@ _declare("comm/shaped_bytes_share", "gauge",
          "shape as parameter, gradient and optimizer state (no re-tiling "
          "between a 1-D flat and a matrix).  Set when a step program is "
          "built.")
+# -- attention kinds (set when a TransformerLM step is traced) --
+_declare("attn/kv_heads", "gauge",
+         "Key / value heads of the model last traced (on this tensor-"
+         "parallel rank); fewer than its query heads under grouped-query "
+         "attention, where the flash kernels read K / V once a kv head.")
+_declare("attn/window", "gauge",
+         "Causal window of that model's windowed layers, in positions (the "
+         "query's own counts); 0 where no layer is windowed.")
+_declare("attn/window_layers", "gauge",
+         "Layers of that model whose attention is windowed (kernels "
+         "flash_win_fwd / flash_win_bwd_dq / flash_win_bwd_dkv where the "
+         "flash kernels run).")
+_declare("attn/full_layers", "gauge",
+         "Layers of that model with full causal attention (kernels "
+         "flash_fwd / flash_bwd_dq / flash_bwd_dkv).")
 # -- mixture of experts (set when a step with a dropless MoEMLP is traced) --
 _declare("moe/experts", "gauge",
          "Experts held by this rank in the MoE layer last traced.")
+_declare("moe/experts_total", "gauge",
+         "Experts the router of that layer scores: all of the model's, of "
+         "which this rank holds moe/experts (an expert-parallel rank, or "
+         "one rank's share computed by itself outside the ep axis).")
 _declare("moe/rows_per_step", "gauge",
          "Rows one dropless MoE layer routes per step on this rank: tokens "
          "x experts per token.")

@@ -36,6 +36,18 @@ Design (FlashAttention-2 style, TPU-first):
   f32)``; softmax math is f32 on the VPU; inputs/outputs stay in the model
   dtype (bf16).
 
+- grouped key / value heads (``k`` / ``v`` with fewer heads than ``q``, one
+  head a 128-lane block): the k / v ``BlockSpec``s pick the kv head
+  ``head // group``, whose block index does not change over the group's
+  consecutive grid rows, so K / V are fetched once a kv head and never
+  repeated in HBM; the dK/dV kernel walks the group on an innermost grid
+  axis and sums its query heads' contributions in float32 scratch.
+- a causal window (``window``: query ``i`` sees keys ``i - window < j <=
+  i``): the three loops start and stop at the band's blocks, those outside
+  it are never visited, and the band's two edges are masked in registers.
+  Windowed calls are named ``flash_win_fwd`` / ``flash_win_bwd_dq`` /
+  ``flash_win_bwd_dkv``.
+
 Falls back to the plain jnp implementation off-TPU, for tiny/ragged
 sequence lengths, for heads the 128-lane blocks cannot take (``heads *
 head_dim`` no multiple of 128, an odd head count at 64), and under
@@ -57,14 +69,22 @@ NEG_INF = -1e30
 _LANE = 128
 
 
-def reference_attention(q, k, v, dtype, causal: bool = True):
+def reference_attention(q, k, v, dtype, causal: bool = True,
+                        window: int | None = None):
     """Plain (materializing) attention; the fallback and the test golden.
-    ``q/k/v``: [batch, seq, heads, head_dim]."""
+    ``q``: [batch, seq, heads, head_dim]; ``k/v`` the same, or with fewer
+    (key / value) heads, each shared by ``heads // kv_heads`` consecutive
+    query heads.  ``window`` (causal only): query ``i`` sees the keys
+    ``i - window < j <= i``."""
     b, s, h, d = q.shape
+    if k.shape[2] != h:
+        k, v = (jnp.repeat(t, h // t.shape[2], axis=2) for t in (k, v))
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     if causal:
         mask = jnp.tril(jnp.ones((s, s), jnp.bool_))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((s, s), jnp.bool_), -window)
         logits = jnp.where(mask[None, None], logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1).astype(dtype)
     return jnp.einsum("bhqk,bkhd->bqhd", probs, v)
@@ -129,24 +149,57 @@ _TN = ((0,), (0,))  # a.T @ b
 
 
 # ---------------------------------------------------------------------------
+# the band a block visits
+# ---------------------------------------------------------------------------
+
+
+def _keep(q_pos, k_pos, window):
+    """The causal mask of a visited block pair, cut to the window's band."""
+    keep = q_pos >= k_pos
+    if window is not None:
+        keep &= q_pos - k_pos < window
+    return keep
+
+
+def _k_blocks(j, block_q, block_k, n_kb_total, causal, window):
+    """``(first, end)`` of the k blocks q block ``j`` visits: to the
+    diagonal where causal, and from the block that holds the first row's
+    oldest key, ``j * block_q - (window - 1)``, where windowed."""
+    if not causal:
+        return 0, n_kb_total
+    # last k block overlapping [0, (j+1)*block_q)
+    end = lax.min((((j + 1) * block_q + block_k - 1) // block_k), n_kb_total)
+    if window is None:
+        return 0, end
+    return lax.max(j * block_q - (window - 1), 0) // block_k, end
+
+
+def _q_blocks(kb, block_q, block_k, n_qb_total, causal, window):
+    """``(first, end)`` of the q blocks k block ``kb`` is visited by: from
+    the diagonal where causal, and to the block that holds the last query
+    of its last key, ``(kb + 1) * block_k - 1 + window - 1``."""
+    if not causal:
+        return 0, n_qb_total
+    first = (kb * block_k) // block_q
+    if window is None:
+        return first, n_qb_total
+    return first, lax.min(((kb + 1) * block_k + window - 2) // block_q + 1,
+                          n_qb_total)
+
+
+# ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, block_k,
-                scale, heads):
+                scale, heads, window):
     block_q, lanes = q_ref.shape[1], q_ref.shape[2]
     j = pl.program_id(1)
     # keep model dtype: the MXU runs bf16 inputs at full rate
     qs = [_only_head(q_ref[0], gi, heads) for gi in range(heads)]
-    n_kb_total = k_ref.shape[1] // block_k
-    if causal:
-        # last k block overlapping [0, (j+1)*block_q)
-        n_kb = lax.min(
-            (((j + 1) * block_q + block_k - 1) // block_k), n_kb_total
-        )
-    else:
-        n_kb = n_kb_total
+    kb_first, n_kb = _k_blocks(j, block_q, block_k,
+                               k_ref.shape[1] // block_k, causal, window)
     q_pos = j * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
@@ -163,7 +216,12 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, block_k,
         for q, m, l in zip(qs, ms, ls):
             logits = scale * _dot(q, k_blk, _NT)
             if causal:
-                logits = jnp.where(q_pos >= k_pos, logits, NEG_INF)
+                # a row whose keys of this block are all outside the band
+                # accumulates exp(0) here; the first block that holds one
+                # of its keys (the diagonal at the latest) rescales that
+                # by exp(NEG_INF - m) = 0
+                logits = jnp.where(_keep(q_pos, k_pos, window), logits,
+                                   NEG_INF)
             m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
             corr = jnp.exp(m - m_new)
             p = jnp.exp(logits - m_new)
@@ -178,7 +236,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, block_k,
     o0 = jnp.zeros((block_q, lanes), jnp.float32)
     m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
     l0 = jnp.zeros((block_q, 1), jnp.float32)
-    o, ms, ls = lax.fori_loop(0, n_kb, body,
+    o, ms, ls = lax.fori_loop(kb_first, n_kb, body,
                               (o0, (m0,) * heads, (l0,) * heads))
     ls = [jnp.maximum(l, 1e-30) for l in ls]
     o_ref[0] = (o / _by_head(ls, o.shape)).astype(o_ref.dtype)
@@ -190,45 +248,55 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, causal, block_k,
     lse_ref[0] = _slots(rows, (8, block_q), 0)
 
 
-def _specs(s, heads, d):
-    """``(g, tensor, stat_rows)``: the heads of a block, and the ``BlockSpec``
-    makers over ``[b, s, heads * d]`` tensors and the ``[b * heads / g, g,
-    s]`` rows — ``tensor(rows)`` a ``rows``-long block j of the sequence (the
-    whole of it where ``rows == s``) of grid row i's ``g`` heads,
-    ``stat_rows`` those heads' whole f32 rows."""
+def _specs(s, heads, d, kv_heads=None):
+    """``(g, tensor, kv_tensor, stat_rows)``: the heads of a block, and the
+    ``BlockSpec`` makers over ``[b, s, heads * d]`` tensors and the ``[b *
+    heads / g, g, s]`` rows — ``tensor(rows)`` a ``rows``-long block j of
+    the sequence (the whole of it where ``rows == s``) of grid row i's ``g``
+    heads, ``kv_tensor(rows)`` the same of the key / value head those heads
+    read (``tensor`` itself without grouping: with it, consecutive grid rows
+    of a group name one block and Pallas fetches it once), ``stat_rows``
+    those heads' whole f32 rows."""
     g = heads_per_block(heads, d)
     hp = heads // g
+    group = heads // (kv_heads or heads)
 
-    def tensor(rows):
-        if rows == s:
-            index = lambda i, j: (i // hp, 0, i % hp)
-        else:
-            index = lambda i, j: (i // hp, j, i % hp)
-        return pl.BlockSpec((1, rows, g * d), index, memory_space=pltpu.VMEM)
+    def maker(head_of):
+        def tensor(rows):
+            if rows == s:
+                index = lambda i, j: (i // hp, 0, head_of(i % hp))
+            else:
+                index = lambda i, j: (i // hp, j, head_of(i % hp))
+            return pl.BlockSpec((1, rows, g * d), index,
+                                memory_space=pltpu.VMEM)
+        return tensor
 
+    tensor = maker(lambda head: head)
+    kv_tensor = tensor if group == 1 else maker(lambda head: head // group)
     stat_rows = pl.BlockSpec((1, g, s), lambda i, j: (i, 0, 0),
                              memory_space=pltpu.VMEM)
-    return g, tensor, stat_rows
+    return g, tensor, kv_tensor, stat_rows
 
 
 # jitted, like ``_bwd``: every layer of a model calls these with the same
 # shapes, and Pallas traces a kernel body anew at each ``pallas_call``; under
 # ``jit`` the layers share one trace.  gpt2-medium's 72 calls cost a warm
 # start 15 s of tracing on the chip's host otherwise (PERF.md §6, PR 32)
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
-def _fwd(q, k, v, heads, causal, block_q, block_k, interpret):
-    """q/k/v: [b, s, heads * d] -> (o [b, s, heads * d], lse [b * heads / g,
-    g, s] f32: the head rows of the 8-sublane stripe the kernel writes)."""
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8))
+def _fwd(q, k, v, heads, causal, block_q, block_k, interpret, window=None):
+    """q: [b, s, heads * d], k/v: [b, s, kv_heads * d] -> (o like q, lse
+    [b * heads / g, g, s] f32: the head rows of the 8-sublane stripe the
+    kernel writes)."""
     b, s, hd = q.shape
     d = hd // heads
-    g, tensor, _ = _specs(s, heads, d)
+    g, tensor, kv_tensor, _ = _specs(s, heads, d, k.shape[2] // d)
     o, stripe = pl.pallas_call(
         functools.partial(
             _fwd_kernel, causal=causal, block_k=block_k,
-            scale=1.0 / (d ** 0.5), heads=g,
+            scale=1.0 / (d ** 0.5), heads=g, window=window,
         ),
         grid=(b * heads // g, s // block_q),
-        in_specs=[tensor(block_q), tensor(s), tensor(s)],
+        in_specs=[tensor(block_q), kv_tensor(s), kv_tensor(s)],
         out_specs=[
             tensor(block_q),
             pl.BlockSpec((1, 8, block_q), lambda i, j: (i, 0, j),
@@ -239,7 +307,9 @@ def _fwd(q, k, v, heads, causal, block_q, block_k, interpret):
             jax.ShapeDtypeStruct((b * heads // g, 8, s), jnp.float32),
         ],
         interpret=interpret,
-        name="flash_fwd",
+        # a windowed call carries a name of its own: a reader of the trace
+        # tells a window layer's calls from a full layer's
+        name="flash_fwd" if window is None else "flash_win_fwd",
     )(q, k, v)
     return o, stripe[:, :g, :]
 
@@ -255,13 +325,18 @@ def _stat(ref, gi, start, rows):
 
 
 def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                    dk_ref, dv_ref, *, causal, block_q, scale, heads):
+                    dk_ref, dv_ref, *sums, causal, block_q, scale, heads,
+                    window, group):
+    """One k block of one (query) head.  With grouped key / value heads
+    (``group > 1``) the grid's innermost axis walks the group's query
+    heads: ``sums`` are the k block's float32 dK and dV, begun at the
+    group's first head and written out, rounded once, at its last."""
     block_k, lanes = k_ref.shape[1], k_ref.shape[2]
     kb = pl.program_id(1)
     ks = [_only_head(k_ref[0], gi, heads) for gi in range(heads)]
     vs = [_only_head(v_ref[0], gi, heads) for gi in range(heads)]
-    n_qb_total = q_ref.shape[1] // block_q
-    qb_start = (kb * block_k) // block_q if causal else 0
+    qb_start, qb_end = _q_blocks(kb, block_q, block_k,
+                                 q_ref.shape[1] // block_q, causal, window)
     k_pos = kb * block_k + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 1
     )
@@ -280,7 +355,7 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             delta = _stat(delta_ref, gi, qb * block_q, block_q)
             s_ij = scale * _dot(q_blk, k_h, _NT)
             if causal:
-                s_ij = jnp.where(q_pos >= k_pos, s_ij, NEG_INF)
+                s_ij = jnp.where(_keep(q_pos, k_pos, window), s_ij, NEG_INF)
             p = jnp.exp(s_ij - lse).astype(k_h.dtype)
             # dV += P^T dO
             dvs.append(_dot(p, do_blk, _TN))
@@ -292,13 +367,32 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dv + _by_head(dvs, dv.shape))
 
     zeros = jnp.zeros((block_k, lanes), jnp.float32)
-    dk, dv = lax.fori_loop(qb_start, n_qb_total, body, (zeros, zeros))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
+    dk, dv = lax.fori_loop(qb_start, qb_end, body, (zeros, zeros))
+    if group == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+    dk_sum, dv_sum = sums
+    member = pl.program_id(2)
+
+    @pl.when(member == 0)
+    def _():
+        dk_sum[...] = dk
+        dv_sum[...] = dv
+
+    @pl.when(member > 0)
+    def _():
+        dk_sum[...] += dk
+        dv_sum[...] += dv
+
+    @pl.when(member == group - 1)
+    def _():
+        dk_ref[0] = dk_sum[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sum[...].astype(dv_ref.dtype)
 
 
 def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                   dq_ref, *, causal, block_k, scale, heads):
+                   dq_ref, *, causal, block_k, scale, heads, window):
     block_q, lanes = q_ref.shape[1], q_ref.shape[2]
     j = pl.program_id(1)
     qs = [_only_head(q_ref[0], gi, heads) for gi in range(heads)]
@@ -306,13 +400,8 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     lses = [_stat(lse_ref, gi, j * block_q, block_q) for gi in range(heads)]
     deltas = [_stat(delta_ref, gi, j * block_q, block_q)
               for gi in range(heads)]
-    n_kb_total = k_ref.shape[1] // block_k
-    if causal:
-        n_kb = lax.min(
-            (((j + 1) * block_q + block_k - 1) // block_k), n_kb_total
-        )
-    else:
-        n_kb = n_kb_total
+    kb_first, n_kb = _k_blocks(j, block_q, block_k,
+                               k_ref.shape[1] // block_k, causal, window)
     q_pos = j * block_q + lax.broadcasted_iota(
         jnp.int32, (block_q, block_k), 0
     )
@@ -328,21 +417,45 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         for q_h, do_h, lse, delta in zip(qs, dos, lses, deltas):
             s_ij = scale * _dot(q_h, k_blk, _NT)
             if causal:
-                s_ij = jnp.where(q_pos >= k_pos, s_ij, NEG_INF)
+                s_ij = jnp.where(_keep(q_pos, k_pos, window), s_ij, NEG_INF)
             p = jnp.exp(s_ij - lse)
             dp = _dot(do_h, v_blk, _NT)
             ds = (p * (dp - delta)).astype(k_blk.dtype)
             dqs.append(_dot(ds, k_blk, _NN))
         return dq + scale * _by_head(dqs, dq.shape)
 
-    dq = lax.fori_loop(0, n_kb, body,
+    dq = lax.fori_loop(kb_first, n_kb, body,
                        jnp.zeros((block_q, lanes), jnp.float32))
     dq_ref[0] = dq.astype(dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _dkv_call(b, s, heads, kv_heads, d, block_k, tensor, stat_rows):
+    """``(grid, in_specs, out_specs, scratch)`` of the dK/dV call over
+    ``heads`` query and ``kv_heads`` key / value blocks of ``d`` lanes.
+    Without grouping: a (query = key) head a grid row, k blocks inner.  With
+    it: a KEY / VALUE head a grid row, k blocks, and innermost the group's
+    query heads, over which the k block's dK / dV block stays resident
+    while each member's whole-sequence Q / dO and statistics come in."""
+    group = heads // kv_heads
+    if group == 1:
+        in_specs = [tensor(s), tensor(block_k), tensor(block_k), tensor(s),
+                    stat_rows, stat_rows]
+        return ((b * heads, s // block_k), in_specs,
+                [tensor(block_k), tensor(block_k)], [])
+    vmem = functools.partial(pl.BlockSpec, memory_space=pltpu.VMEM)
+    whole = vmem((1, s, d), lambda i, j, m: (i // kv_heads, 0,
+                                             (i % kv_heads) * group + m))
+    block = vmem((1, block_k, d), lambda i, j, m: (i // kv_heads, j,
+                                                   i % kv_heads))
+    rows = vmem((1, 1, s), lambda i, j, m: (i * group + m, 0, 0))
+    return ((b * kv_heads, s // block_k, group),
+            [whole, block, block, whole, rows, rows], [block, block],
+            [pltpu.VMEM((block_k, d), jnp.float32)] * 2)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 12))
 def _bwd(q, k, v, o, lse, do, heads, causal, block_q, block_k, interpret,
-         dlse=None):
+         dlse=None, window=None):
     """``lse``: [b * heads / g, g, s] f32 (the head rows of the forward's
     stripe).
 
@@ -353,7 +466,8 @@ def _bwd(q, k, v, o, lse, do, heads, causal, block_q, block_k, interpret,
     """
     b, s, hd = q.shape
     d = hd // heads
-    g, tensor, stat_rows = _specs(s, heads, d)
+    kv_heads = k.shape[2] // d
+    g, tensor, kv_tensor, stat_rows = _specs(s, heads, d, kv_heads)
     delta = (
         (do.astype(jnp.float32) * o.astype(jnp.float32))
         .reshape(b, s, heads, d)
@@ -365,35 +479,37 @@ def _bwd(q, k, v, o, lse, do, heads, causal, block_q, block_k, interpret,
         delta = delta - dlse.astype(jnp.float32)
 
     scale = 1.0 / (d ** 0.5)
+    grid, in_specs, out_specs, scratch = _dkv_call(
+        b, s, heads // g, kv_heads // g, g * d, block_k, tensor, stat_rows)
     dk, dv = pl.pallas_call(
         functools.partial(
             _bwd_dkv_kernel, causal=causal, block_q=block_q, scale=scale,
-            heads=g,
+            heads=g, window=window, group=heads // kv_heads,
         ),
-        grid=(b * heads // g, s // block_k),
-        in_specs=[tensor(s), tensor(block_k), tensor(block_k), tensor(s),
-                  stat_rows, stat_rows],
-        out_specs=[tensor(block_k), tensor(block_k)],
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
         out_shape=[
-            jax.ShapeDtypeStruct((b, s, hd), k.dtype),
-            jax.ShapeDtypeStruct((b, s, hd), v.dtype),
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
         ],
+        scratch_shapes=scratch,
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name="flash_bwd_dkv" if window is None else "flash_win_bwd_dkv",
     )(q, k, v, do, lse, delta)
 
     dq = pl.pallas_call(
         functools.partial(
             _bwd_dq_kernel, causal=causal, block_k=block_k, scale=scale,
-            heads=g,
+            heads=g, window=window,
         ),
         grid=(b * heads // g, s // block_q),
-        in_specs=[tensor(block_q), tensor(s), tensor(s), tensor(block_q),
-                  stat_rows, stat_rows],
+        in_specs=[tensor(block_q), kv_tensor(s), kv_tensor(s),
+                  tensor(block_q), stat_rows, stat_rows],
         out_specs=tensor(block_q),
         out_shape=jax.ShapeDtypeStruct((b, s, hd), q.dtype),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name="flash_bwd_dq" if window is None else "flash_win_bwd_dq",
     )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
@@ -403,11 +519,12 @@ def _bwd(q, k, v, o, lse, do, heads, causal, block_q, block_k, interpret,
 # ---------------------------------------------------------------------------
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash_lse(q, k, v, heads, causal, block_q, block_k, interpret):
-    """q/k/v [b, s, heads * d] -> (o like q, lse [b * heads / g, g, s] f32:
-    row-major that is [b, heads, s])."""
-    return _fwd(q, k, v, heads, causal, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_lse(q, k, v, heads, causal, block_q, block_k, interpret,
+               window=None):
+    """q [b, s, heads * d], k/v [b, s, kv_heads * d] -> (o like q, lse [b *
+    heads / g, g, s] f32: row-major that is [b, heads, s])."""
+    return _fwd(q, k, v, heads, causal, block_q, block_k, interpret, window)
 
 
 #: ``checkpoint_name`` tags on what the forward kernel made.  A remat policy
@@ -418,8 +535,10 @@ KEPT_O = "flash_o"
 KEPT_LSE = "flash_lse"
 
 
-def _flash_lse_fwd(q, k, v, heads, causal, block_q, block_k, interpret):
-    o, lse = _fwd(q, k, v, heads, causal, block_q, block_k, interpret)
+def _flash_lse_fwd(q, k, v, heads, causal, block_q, block_k, interpret,
+                   window):
+    o, lse = _fwd(q, k, v, heads, causal, block_q, block_k, interpret,
+                  window)
     # tagged HERE so the value returned and the residual are one variable
     # (a tag on the caller's side names a copy and the kernel is replayed);
     # the head rows, not the 8-sublane stripe the kernel writes
@@ -428,29 +547,55 @@ def _flash_lse_fwd(q, k, v, heads, causal, block_q, block_k, interpret):
     return (o, lse), (q, k, v, o, lse)
 
 
-def _flash_lse_bwd(heads, causal, block_q, block_k, interpret, res, cts):
+def _flash_lse_bwd(heads, causal, block_q, block_k, interpret, window, res,
+                   cts):
     q, k, v, o, lse = res
     do, dlse = cts
     return _bwd(q, k, v, o, lse, do, heads, causal, block_q, block_k,
-                interpret, dlse=dlse)
+                interpret, dlse, window)
 
 
 _flash_lse.defvjp(_flash_lse_fwd, _flash_lse_bwd)
 
 
-def _in_model_layout(q, k, v, causal, block_q, block_k, interpret):
-    """The kernels over the model's ``[b, s, h, d]`` q / k / v: the heads
-    merge into the last axis and split out of it again by reshape, no
-    element moves.  -> (o [b, s, h, d], lse [b, h, s] f32)."""
+def kv_grouping_supported(heads: int, kv_heads: int, head_dim: int) -> bool:
+    """Whether the kernels take ``kv_heads`` key / value heads under
+    ``heads`` query heads: as many (no grouping), or a divisor of them with
+    a head a 128-lane block — heads that share a block would need their kv
+    heads side by side in one too."""
+    if kv_heads == heads:
+        return True
+    return heads % kv_heads == 0 and heads_per_block(heads, head_dim) == 1
+
+
+def _in_model_layout(q, k, v, causal, block_q, block_k, interpret,
+                     window=None):
+    """The kernels over the model's ``[b, s, h, d]`` q and ``[b, s, kv_h,
+    d]`` k / v: the heads merge into the last axis and split out of it
+    again by reshape, no element moves.  -> (o [b, s, h, d], lse [b, h, s]
+    f32)."""
     b, s, h, d = q.shape
-    merged = lambda x: x.reshape(b, s, h * d)
+    merged = lambda x: x.reshape(b, s, x.shape[2] * d)
     o, lse = _flash_lse(merged(q), merged(k), merged(v), h, causal, block_q,
-                        block_k, interpret)
+                        block_k, interpret, window)
     return o.reshape(b, s, h, d), lse.reshape(b, h, s)
 
 
+def _band(window, causal: bool, seq: int):
+    """``window`` as the kernels take it: None where it cuts nothing (no
+    window, or one as long as the sequence: plain causal attention, under
+    the plain kernels' names)."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError(f"a window ({window}) is a causal band of at least "
+                         "the query's own position")
+    return None if window >= seq else int(window)
+
+
 def flash_attention_with_lse(q, k, v, *, causal: bool, block_q: int = 0,
-                             block_k: int = 0, interpret: bool = False):
+                             block_k: int = 0, interpret: bool = False,
+                             window: int | None = None):
     """Like :func:`flash_attention` but also returns the per-row logsumexp
     ([batch, heads, seq] f32) — the merge statistic for combining partial
     attentions over K/V blocks (ring attention).  No fallback: the caller
@@ -463,16 +608,19 @@ def flash_attention_with_lse(q, k, v, *, causal: bool, block_q: int = 0,
     assert k.shape[1] == s and v.shape[1] == s, (q.shape, k.shape, v.shape)
     block_q = block_q or pick_block(s)
     block_k = block_k or pick_block(s)
-    if s % block_q or s % block_k or not heads_per_block(h, d):
+    if (s % block_q or s % block_k or not heads_per_block(h, d)
+            or not kv_grouping_supported(h, k.shape[2], d)):
         # no silent fallback here (the caller gates on flash_supported):
         # a non-divisible grid would TRUNCATE the sequence
         raise ValueError(
             f"seq {s} is not a multiple of block sizes "
-            f"({block_q}, {block_k}), or {h} heads of {d} do not fill "
+            f"({block_q}, {block_k}), or {h} heads of {d} (over "
+            f"{k.shape[2]} key / value heads) do not fill "
             "128-lane blocks; flash_attention_with_lse has no reference "
             "fallback"
         )
-    o, lse = _in_model_layout(q, k, v, causal, block_q, block_k, interpret)
+    o, lse = _in_model_layout(q, k, v, causal, block_q, block_k, interpret,
+                              _band(window, causal, s))
     return o.astype(jnp.float32), lse
 
 
@@ -489,12 +637,13 @@ MIN_FLASH_SEQ = 1024
 
 
 def flash_supported(seq: int, heads: int, head_dim: int,
-                    block: int = _LANE) -> bool:
+                    block: int = _LANE, kv_heads: int | None = None) -> bool:
     """Whether the fused kernel pays: on-TPU, sequence long enough that the
     [seq, seq] HBM materialization hurts (measured crossover ~1k on v5p),
     block-aligned, heads that fill 128-lane blocks of the model's layout
-    (:func:`heads_per_block`), and K/V + Q/dO fitting the per-step VMEM
-    budget."""
+    (:func:`heads_per_block`; grouped key / value heads:
+    :func:`kv_grouping_supported`), and K/V + Q/dO fitting the per-step
+    VMEM budget."""
     if not _enabled():
         return False
     if jax.default_backend() != "tpu":
@@ -502,6 +651,8 @@ def flash_supported(seq: int, heads: int, head_dim: int,
     if seq < MIN_FLASH_SEQ or seq % block:
         return False
     if not heads_per_block(heads, head_dim):
+        return False
+    if not kv_grouping_supported(heads, kv_heads or heads, head_dim):
         return False
     # each kernel keeps 2 full-sequence operands resident (K+V fwd and dQ,
     # Q+dO in the dK/dV pass), a block's heads wide — 128 lanes, or
@@ -512,10 +663,12 @@ def flash_supported(seq: int, heads: int, head_dim: int,
 
 def flash_attention(q, k, v, dtype=None, *, causal: bool = True,
                     block_q: int = 0, block_k: int = 0,
-                    interpret: bool = False, force: bool = False):
-    """Drop-in for :func:`reference_attention`: ``q/k/v`` are
-    [batch, seq, heads, head_dim], returns [batch, seq, heads, head_dim] in
-    ``dtype`` (default: q.dtype).
+                    interpret: bool = False, force: bool = False,
+                    window: int | None = None):
+    """Drop-in for :func:`reference_attention`: ``q`` is [batch, seq, heads,
+    head_dim], ``k/v`` the same or with fewer (key / value) heads, returns
+    [batch, seq, heads, head_dim] in ``dtype`` (default: q.dtype).
+    ``window``: query ``i`` sees keys ``i - window < j <= i`` only.
 
     ``force`` skips the platform and sequence-length checks (tests run the
     kernel in interpret mode on CPU); a shape no grid covers — a sequence
@@ -525,13 +678,19 @@ def flash_attention(q, k, v, dtype=None, *, causal: bool = True,
     from .tiles import pick_block
 
     b, s, h, d = q.shape
+    kv_h = k.shape[2]
     dtype = dtype or q.dtype
     block_q = block_q or pick_block(s)
     block_k = block_k or pick_block(s)
-    if not force and not flash_supported(s, h, d, max(block_q, block_k)):
-        return reference_attention(q, k, v, dtype, causal=causal)
-    if s % block_q or s % block_k or not heads_per_block(h, d):
-        return reference_attention(q, k, v, dtype, causal=causal)
+    if not force and not flash_supported(s, h, d, max(block_q, block_k),
+                                         kv_h):
+        return reference_attention(q, k, v, dtype, causal=causal,
+                                   window=window)
+    if (s % block_q or s % block_k or not heads_per_block(h, d)
+            or not kv_grouping_supported(h, kv_h, d)):
+        return reference_attention(q, k, v, dtype, causal=causal,
+                                   window=window)
     # lse is discarded; its zero cotangent enters the backward as a no-op
-    o, _ = _in_model_layout(q, k, v, causal, block_q, block_k, interpret)
+    o, _ = _in_model_layout(q, k, v, causal, block_q, block_k, interpret,
+                            _band(window, causal, s))
     return o.astype(dtype)

@@ -33,13 +33,23 @@ from .mesh import axis_bound as _axis_bound
 
 
 class _ScanBlock(nn.Module):
-    """Block adapter with scan signature (carry, _) -> (carry, None)."""
+    """Block adapter with scan signature (carry, _) -> (carry, None).  One
+    traced block stands for every layer of the stack, so the layers must
+    all be of one kind."""
 
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x, _):
-        return Block(self.cfg, name="block")(x), None
+        cfg = self.cfg
+        kinds = {(cfg.layer_window(i), cfg.layer_rotary(i))
+                 for i in range(cfg.n_layers)}
+        if len(kinds) > 1:
+            raise NotImplementedError(
+                "the pipelined stack scans ONE block over its layers: "
+                f"window_layers={cfg.window_layers} / rope_layers="
+                f"{cfg.rope_layers} ask for layers of {len(kinds)} kinds")
+        return Block(cfg, name="block")(x), None
 
 
 class PipelinedTransformerLM(nn.Module):

@@ -47,6 +47,10 @@ def make_ring_attention(sp_size: int, axis_name: str = "sp",
 
     def attn_fn(q, k, v, dtype):
         b, s, h, d = q.shape
+        if k.shape[2] != h:
+            raise NotImplementedError(
+                f"ring attention takes one key / value head a query head: "
+                f"got {k.shape[2]} for {h} (n_kv_heads)")
         scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
         from .mesh import axis_bound
 

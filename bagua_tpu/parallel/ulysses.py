@@ -36,6 +36,11 @@ def make_ulysses_attention(
         inner = inner_attn or causal_attention
         from .mesh import axis_bound
 
+        if k.shape[2] != q.shape[2]:
+            raise NotImplementedError(
+                f"ulysses attention takes one key / value head a query "
+                f"head: got {k.shape[2]} for {q.shape[2]} (n_kv_heads)")
+
         if not axis_bound(axis_name):
             # outside shard_map (e.g. model.init): plain local attention
             return inner(q, k, v, dtype)

@@ -289,12 +289,16 @@ def test_tags_leave_the_lse_cotangent_path_as_it_was(kept, monkeypatch):
     # the package exports the function under the module's name
     fa = importlib.import_module("bagua_tpu.ops.flash_attention")
 
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-    def untagged(q, k, v, heads, causal, block_q, block_k, interpret):
-        return fa._fwd(q, k, v, heads, causal, block_q, block_k, interpret)
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+    def untagged(q, k, v, heads, causal, block_q, block_k, interpret,
+                 window):
+        return fa._fwd(q, k, v, heads, causal, block_q, block_k, interpret,
+                       window)
 
-    def untagged_fwd(q, k, v, heads, causal, block_q, block_k, interpret):
-        o, lse = untagged(q, k, v, heads, causal, block_q, block_k, interpret)
+    def untagged_fwd(q, k, v, heads, causal, block_q, block_k, interpret,
+                     window):
+        o, lse = untagged(q, k, v, heads, causal, block_q, block_k, interpret,
+                          window)
         return (o, lse), (q, k, v, o, lse)
 
     untagged.defvjp(untagged_fwd, fa._flash_lse_bwd)
@@ -319,3 +323,115 @@ def test_tags_leave_the_lse_cotangent_path_as_it_was(kept, monkeypatch):
     for got, want, name in zip(grads(fa._flash_lse), grads(untagged), "qkv"):
         assert jnp.abs(want).max() > 0
         np.testing.assert_array_equal(got, want, err_msg=f"d{name}")
+
+
+# ---------------------------------------------------------------------------
+# grouped key / value heads and the causal window
+# ---------------------------------------------------------------------------
+
+#: (window, what it is against the 512-token sequence)
+WINDOWS = [(None, "none"), (200, "shorter, inside a block pair"),
+           (256, "shorter, block-aligned"), (1, "the query alone"),
+           (512, "equal"), (700, "longer")]
+
+
+def _grouped(key, group, s=512, kv_heads=1, d=128):
+    kq, kk, kv, kg = jax.random.split(key, 4)
+    q = jax.random.normal(kq, (1, s, kv_heads * group, d), jnp.float32)
+    k = jax.random.normal(kk, (1, s, kv_heads, d), jnp.float32)
+    v = jax.random.normal(kv, (1, s, kv_heads, d), jnp.float32)
+    return q, k, v, jax.random.normal(kg, q.shape, jnp.float32)
+
+
+def _kernel_and_reference(window, block_q=128, block_k=128):
+    ref_fn = lambda q, k, v: reference_attention(q, k, v, jnp.float32,
+                                                 window=window)
+    fl_fn = lambda q, k, v: flash_attention(
+        q, k, v, jnp.float32, window=window, block_q=block_q,
+        block_k=block_k, interpret=True, force=True)
+    return fl_fn, ref_fn
+
+
+@pytest.mark.parametrize("window", [w for w, _ in WINDOWS],
+                         ids=[name for _, name in WINDOWS])
+@pytest.mark.parametrize("group,kv_heads", [(1, 2), (7, 1), (2, 2)])
+def test_grouped_windowed_forward_matches_reference(group, kv_heads, window):
+    q, k, v, _ = _grouped(jax.random.PRNGKey(20), group, kv_heads=kv_heads)
+    fl_fn, ref_fn = _kernel_and_reference(window)
+    np.testing.assert_allclose(fl_fn(q, k, v), ref_fn(q, k, v), atol=2e-5,
+                               rtol=2e-5)
+
+
+@pytest.mark.parametrize("grad", ["dq", "dk", "dv"])
+@pytest.mark.parametrize("window", [w for w, _ in WINDOWS],
+                         ids=[name for _, name in WINDOWS])
+@pytest.mark.parametrize("group,kv_heads", [(1, 2), (7, 1)])
+def test_grouped_windowed_gradients_match_reference(group, kv_heads, window,
+                                                    grad):
+    """dK / dV of a key / value head are the sums over its group's query
+    heads (inside the kernel, in float32); the band's bounds hold from both
+    sides (k blocks under a q block, q blocks over a k block)."""
+    q, k, v, g = _grouped(jax.random.PRNGKey(21), group, kv_heads=kv_heads)
+    i = ["dq", "dk", "dv"].index(grad)
+    got, want = (
+        jax.grad(lambda q, k, v: (fn(q, k, v) * g).sum(), argnums=i)(q, k, v)
+        for fn in _kernel_and_reference(window))
+    assert got.shape == (q, k, v)[i].shape
+    np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("block_q,block_k", [(128, 256), (256, 128),
+                                             (512, 512)])
+def test_the_band_holds_under_mismatched_blocks(block_q, block_k):
+    q, k, v, g = _grouped(jax.random.PRNGKey(22), 2, kv_heads=2)
+    fl_fn, ref_fn = _kernel_and_reference(300, block_q, block_k)
+    np.testing.assert_allclose(fl_fn(q, k, v), ref_fn(q, k, v), atol=2e-5,
+                               rtol=2e-5)
+    grads = lambda fn: jax.grad(lambda q, k, v: (fn(q, k, v) * g).sum(),
+                                argnums=(0, 1, 2))(q, k, v)
+    for got, want, name in zip(grads(fl_fn), grads(ref_fn), "qkv"):
+        np.testing.assert_allclose(got, want, atol=1e-4, rtol=1e-4,
+                                   err_msg=f"d{name}")
+
+
+def _kernel_names(fn, *operands):
+    return [eqn.params["name"] for eqn in _eqns(jax.make_jaxpr(fn)(*operands))
+            if eqn.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("window,prefix", [(None, "flash_"), (512, "flash_"),
+                                           (700, "flash_"),
+                                           (200, "flash_win_")])
+def test_windowed_calls_carry_names_of_their_own(window, prefix):
+    """A window that cuts nothing (as long as the sequence, or longer) is
+    plain causal attention under the plain kernels' names, which the gpt2
+    cell's readers key on."""
+    q, k, v, g = _grouped(jax.random.PRNGKey(23), 7)
+    fl_fn, _ = _kernel_and_reference(window)
+    names = _kernel_names(jax.grad(lambda q, k, v: (fl_fn(q, k, v) * g).sum(),
+                                   argnums=(0, 1, 2)), q, k, v)
+    assert sorted(names) == sorted(
+        prefix + kernel for kernel in ("fwd", "bwd_dkv", "bwd_dq"))
+
+
+def test_grouped_heads_below_a_lane_block_take_the_reference():
+    """Two heads of 64 share a 128-lane block; their key / value heads would
+    have to sit side by side in one too: no kernel, the reference's result."""
+    from bagua_tpu.ops.flash_attention import kv_grouping_supported
+
+    assert kv_grouping_supported(28, 4, 128) and kv_grouping_supported(4, 4, 64)
+    assert not kv_grouping_supported(4, 2, 64)
+    assert not kv_grouping_supported(28, 8, 128)
+    q, k, v, _ = _grouped(jax.random.PRNGKey(24), 2, kv_heads=2, d=64)
+    fl_fn, ref_fn = _kernel_and_reference(100)
+    assert _kernel_names(fl_fn, q, k, v) == []
+    np.testing.assert_array_equal(fl_fn(q, k, v), ref_fn(q, k, v))
+
+
+def test_a_window_needs_causal_attention():
+    q, k, v, _ = _grouped(jax.random.PRNGKey(25), 1)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, causal=False, window=64, interpret=True,
+                        force=True)
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, k, v, window=0, interpret=True, force=True)

@@ -1,8 +1,10 @@
 """The three flash kernels compiled at the benchmark's real shapes for a
 described (not attached) v5e: what interpret mode cannot show — that Mosaic
 takes the ``BlockSpec``s over ``[batch, seq, heads * head_dim]``, the
-two-heads-a-block bodies at head_dim 64 and the whole-sequence operands'
-VMEM.  Nothing runs; no time comes out of this.  The topology is described
+two-heads-a-block bodies at head_dim 64, the whole-sequence operands'
+VMEM, grouped key / value heads with the dK/dV kernel's float32 scratch and
+the window's loop bounds (SmallThinker's 28 heads over 4 at 8,192 tokens),
+and the grouped matmuls at one rank's share of the rows.  Nothing runs; no time comes out of this.  The topology is described
 inside a fixture, never at import (one process at a time may load libtpu)."""
 
 import re
@@ -13,6 +15,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from bagua_tpu.ops.flash_attention import flash_attention_with_lse
+from bagua_tpu.ops.gmm import gmm_padded, padded_layout
 
 
 @pytest.fixture(scope="module")
@@ -51,3 +54,62 @@ def test_the_kernels_compile_for_the_described_v5e(b, s, h, d, one_chip):
         assert first.group(1) == f"{b},{s},{h * d}", line
         assert first.group(2) == "2,1,0", line
     assert f"bf16[{b * h},{s},{d}]" not in text
+
+
+@pytest.mark.parametrize("window,prefix", [(None, "flash_"),
+                                           (4096, "flash_win_")])
+def test_grouped_and_windowed_kernels_compile_for_the_described_v5e(
+        window, prefix, one_chip):
+    """smallthinker-21b-a3b.pretrain8192-dp1: 28 query heads over 4 key /
+    value heads of 128 at 8,192 tokens; K / V enter and dK / dV leave as
+    [b, s, 4 * 128] — never repeated to the 28 query heads."""
+    b, s, h, kv_h, d = 1, 8192, 28, 4, 128
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, kv_h, d), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        o, lse = flash_attention_with_lse(q, k, v, causal=True, window=window)
+        return o.sum() + lse.sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = {re.search(r"%(\w+?)\.\d+ = ", line).group(1): line
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    assert sorted(calls) == sorted(
+        prefix + kernel for kernel in ("fwd", "bwd_dq", "bwd_dkv"))
+    for name, line in calls.items():
+        operands = re.search(
+            r"operand_layout_constraints=\{([^{}]*(?:\{[^{}]*\}[^{}]*)*)\}",
+            line).group(1)
+        # q first, then k and v at the key / value heads' width
+        assert re.findall(r"bf16\[([\d,]+)\]", operands)[:3] == [
+            f"{b},{s},{h * d}", f"{b},{s},{kv_h * d}", f"{b},{s},{kv_h * d}"]
+        first = re.search(r"= \(?bf16\[([\d,]+)\]", line).group(1)
+        assert first == (f"{b},{s},{kv_h * d}" if name.endswith("dkv")
+                         else f"{b},{s},{h * d}"), line
+
+
+def test_the_grouped_matmuls_compile_at_a_ranks_share(one_chip):
+    """The SmallThinker cell's expert layer: 16 held experts of [2560, 768]
+    over a layout made for all 49,152 routed pairs (400 row blocks, about
+    a quarter of them holding rows), forward and both gradients."""
+    rows, groups, d, f = 8192 * 6, 16, 2560, 768
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    layout = jax.eval_shape(
+        lambda sizes: padded_layout(sizes, rows), spec((groups,), jnp.int32))
+    padded = layout.src.shape[0]
+    assert padded == 400 * 128
+
+    def loss(x_p, w, layout):
+        return jnp.square(gmm_padded(x_p, w, layout).astype(jnp.float32)).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        spec((padded, d), jnp.bfloat16), spec((groups, d, f), jnp.bfloat16),
+        jax.tree.map(lambda x: spec(x.shape, x.dtype), layout),
+    ).compile().as_text()
+    names = [re.search(r"(gmm_\w+?)\)*/pallas_call", line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(names) == ["gmm_bwd_drhs", "gmm_fwd", "gmm_fwd"]
