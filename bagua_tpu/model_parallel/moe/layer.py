@@ -225,6 +225,10 @@ class MoEMLP(nn.Module):
             counters.set_gauge("moe/padded_rows_per_step", x_p.shape[0])
             counters.set_gauge("moe/padded_resident_layers",
                                int(layout.block_rows > 1))
+            if layout.block_rows > 1:
+                # transposed copies of an expert matrix stack that the
+                # backward pass writes: d_lhs reads the matrices as stored
+                counters.set_gauge("moe/dlhs_transposed_copies", 0)
 
         @jax.checkpoint
         def down(up, gate, wo):

@@ -17,13 +17,19 @@ with no masking.
 What each kernel keeps resident and what it streams (Pallas copies no block
 whose index the grid step before had; a group's row blocks are consecutive):
 
-``gmm_fwd`` (the forward product, and d_lhs on the transposed matrices)
+``gmm_fwd`` (the forward product, and d_lhs on the matrices as stored)
     grid ``(f / bf, row blocks)``, rows inner.  RESIDENT: the group's
     ``[d, bf]`` slab of its matrix, fetched once per group and ``f`` block —
     the matrices are read once a call.  STREAMED: a ``[128, d]`` row block
     in and a ``[128, bf]`` result block out every step, so the rows are read
     ``f / bf`` times; ``bf`` is the widest block of ``f`` that fits VMEM
     (the whole ``f`` at OLMoE's widths: a 4 MB slab, rows read once).
+    d_lhs = cotangent x matrices^T is the same call in its
+    transposed-operand form: the stack stays ``[G, d, f]``, the resident
+    slab is ``[bd, f]`` of it and the product contracts both last axes —
+    the MXU takes a transposed right operand where it lies, so no
+    transposed copy of the stack is ever written (``d`` and ``f`` in each
+    other's place in everything above).
 ``gmm_bwd_drhs`` (d_rhs, the grouped outer product)
     grid ``(d / bd, f / bf, row blocks)``, rows innermost.  RESIDENT: the
     group's float32 ``[bd, bf]`` output block, zeroed at the group's first
@@ -46,10 +52,11 @@ whose transpose is a gather through the layout's inverse map), multiplies
 in padded space (:func:`gmm_padded`) and moves the result out once
 (:func:`unpad_rows`).
 ``gmm_padded``'s custom VJP stays in padded space: d_lhs is the forward
-kernel on the transposed matrices, d_rhs the grouped outer-product kernel,
-neither with a scatter or a gather.  Padding rows are zero going in, so
-they come out zero and contribute nothing to any reduction.  ``gmm`` is
-pad -> ``gmm_padded`` -> unpad for one product.
+kernel contracting the stored matrices' last axis, d_rhs the grouped
+outer-product kernel, neither with a scatter, a gather or a transpose.
+Padding rows are zero going in, so they come out zero and contribute
+nothing to any reduction.  ``gmm`` is pad -> ``gmm_padded`` -> unpad for one
+product.
 """
 
 from __future__ import annotations
@@ -250,38 +257,53 @@ def _drhs_blocks(d, f, block_rows, itemsize, cap, vmem_limit):
                key=lambda s: (1 / s[0] + 1 / s[1], -s[1]))
 
 
-def _fwd_kernel(gid_ref, lhs_ref, rhs_ref, out_ref):
-    out_ref[:] = jnp.dot(
-        lhs_ref[:], rhs_ref[0], preferred_element_type=jnp.float32
+def _fwd_kernel(gid_ref, lhs_ref, rhs_ref, out_ref, *, transpose_rhs):
+    """One row block times its group's matrix block: ``lhs @ rhs``, or
+    with ``transpose_rhs`` ``lhs @ rhs^T``, contracting both last axes (the
+    MXU takes a transposed right operand as it lies, as in every
+    ``q @ k^T`` of the flash kernels)."""
+    out_ref[:] = jax.lax.dot_general(
+        lhs_ref[:], rhs_ref[0],
+        (((1,), (1 if transpose_rhs else 0,)), ((), ())),
+        preferred_element_type=jnp.float32,
     ).astype(out_ref.dtype)
 
 
-def _fwd_grid_spec(padded_rows, d, f, block_rows, bf):
+def _fwd_grid_spec(padded_rows, d, f, block_rows, bf, transpose_rhs=False):
     """``f`` blocks outer, row blocks inner: over a group's consecutive row
     blocks the matrix index ``(gid[i], 0, j)`` does not change, and Pallas
     copies no block whose index the step before had — a group's ``[d, bf]``
-    slab is fetched once per ``f`` block, not once per row block."""
+    slab is fetched once per ``f`` block, not once per row block.  With
+    ``transpose_rhs`` the matrices are ``[G, f, d]`` as stored and the slab
+    is ``[bf, d]`` at ``(gid[i], j, 0)``: the same walk."""
+    rhs_spec = (pl.BlockSpec((1, bf, d), lambda j, i, gid: (gid[i], j, 0))
+                if transpose_rhs else
+                pl.BlockSpec((1, d, bf), lambda j, i, gid: (gid[i], 0, j)))
     return pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(f // bf, padded_rows // block_rows),
         in_specs=[
             pl.BlockSpec((block_rows, d), lambda j, i, gid: (i, 0)),
-            pl.BlockSpec((1, d, bf), lambda j, i, gid: (gid[i], 0, j)),
+            rhs_spec,
         ],
         out_specs=pl.BlockSpec((block_rows, bf), lambda j, i, gid: (i, j)),
     )
 
 
-def _gmm_padded(lhs_p, rhs, g_of_block, block_rows, block_f, interpret):
-    """lhs_p: [padded_rows, d] (group-blocked), rhs: [G, d, f]."""
+def _gmm_padded(lhs_p, rhs, g_of_block, block_rows, block_f, interpret,
+                transpose_rhs=False):
+    """lhs_p: [padded_rows, d] (group-blocked) times rhs: [G, d, f], or
+    with ``transpose_rhs`` times the transposes of rhs: [G, f, d], read as
+    stored.  Either way the result is [padded_rows, f]."""
     padded_rows, d = lhs_p.shape
-    _, _, f = rhs.shape
+    f = rhs.shape[1 if transpose_rhs else 2]
     vmem_limit = _vmem_limit()
     bf = _fwd_block_f(d, f, block_rows, lhs_p.dtype.itemsize, block_f or f,
                       vmem_limit)
     return pl.pallas_call(
-        _fwd_kernel,
-        grid_spec=_fwd_grid_spec(padded_rows, d, f, block_rows, bf),
+        functools.partial(_fwd_kernel, transpose_rhs=transpose_rhs),
+        grid_spec=_fwd_grid_spec(padded_rows, d, f, block_rows, bf,
+                                 transpose_rhs),
         out_shape=jax.ShapeDtypeStruct((padded_rows, f), lhs_p.dtype),
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem_limit),
         interpret=interpret,
@@ -350,10 +372,11 @@ def _gmm_padded_fwd(lhs_p, rhs, layout, block_f, interpret):
 def _gmm_padded_bwd(block_f, interpret, res, gout_p):
     lhs_p, rhs, layout = res
     n_groups, d, f = rhs.shape
-    # d_lhs = gout @ rhs^T (same grouped structure)
+    # d_lhs = gout @ rhs^T (same grouped structure), the matrices read as
+    # they are stored: no transposed copy of the stack is written
     dlhs_p = _gmm_padded(
-        gout_p, jnp.swapaxes(rhs, 1, 2).astype(gout_p.dtype),
-        layout.g_of_block, layout.block_rows, block_f, interpret,
+        gout_p, rhs.astype(gout_p.dtype), layout.g_of_block,
+        layout.block_rows, block_f, interpret, transpose_rhs=True,
     )
     drhs = _gmm_drhs_padded(lhs_p, gout_p, n_groups, d, f, layout.g_of_block,
                             layout.block_rows, block_f, interpret)
@@ -372,10 +395,11 @@ def gmm_padded(lhs_p, rhs, layout: PaddedLayout, *,
     by ``layout`` (padding rows zero) times ``rhs`` [G, d, f] -> [padded
     rows, f], padding rows zero.  Differentiable in ``lhs_p`` and ``rhs``
     without leaving the layout; a cotangent's padding rows add nothing to
-    d_rhs (``lhs_p`` is zero there).  ``block_f`` caps the kernels' blocks
-    of ``d`` and ``f`` (default: as wide as VMEM holds).  On an unpadded
-    layout (``block_rows == 1``: :func:`kernel_layout` where no kernel runs)
-    it is the dense reference."""
+    d_rhs (``lhs_p`` is zero there), and d_lhs reads ``rhs`` as it is
+    stored (no transposed copy of it is made).  ``block_f`` caps the
+    kernels' blocks of ``d`` and ``f`` (default: as wide as VMEM holds).  On
+    an unpadded layout (``block_rows == 1``: :func:`kernel_layout` where no
+    kernel runs) it is the dense reference."""
     if layout.block_rows == 1:
         return gmm_reference(lhs_p, rhs, layout.sizes)
     return _gmm_padded_vjp(lhs_p, rhs, layout, block_f, interpret)
@@ -391,9 +415,10 @@ def _use_kernel(rows: int, d: int, f: int, block_rows: int) -> bool:
 
 
 def kernel_layout(group_sizes, rows: int, d: int, f: int) -> PaddedLayout:
-    """The layout a run of ``[rows, d] x [G, d, f]`` products (and their
-    transposes) lives in: block-aligned where the kernels run, the sorted
-    rows themselves (``block_rows=1``) where the dense fallback does."""
+    """The layout a run of ``[rows, d] x [G, d, f]`` products (and of
+    ``[rows, f] x [G, d, f]^T``, their d_lhs and an FFN's way back) lives
+    in: block-aligned where the kernels run, the sorted rows themselves
+    (``block_rows=1``) where the dense fallback does."""
     block = _BLOCK_ROWS if _use_kernel(rows, d, f, _BLOCK_ROWS) else 1
     return padded_layout(group_sizes, rows, block_rows=block)
 

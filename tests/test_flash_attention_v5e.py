@@ -4,8 +4,11 @@ takes the ``BlockSpec``s over ``[batch, seq, heads * head_dim]``, the
 two-heads-a-block bodies at head_dim 64, the whole-sequence operands'
 VMEM, grouped key / value heads with the dK/dV kernel's float32 scratch and
 the window's loop bounds (SmallThinker's 28 heads over 4 at 8,192 tokens),
-and the grouped matmuls at one rank's share of the rows.  Nothing runs; no time comes out of this.  The topology is described
-inside a fixture, never at import (one process at a time may load libtpu)."""
+the grouped matmuls at one rank's share of the rows, and the d_lhs product
+reading the expert matrices as they are stored (contracting their last axis
+inside ``gmm_fwd``).  Nothing runs; no time comes out of this.  The topology
+is described inside a fixture, never at import (one process at a time may
+load libtpu)."""
 
 import re
 
@@ -15,7 +18,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from bagua_tpu.ops.flash_attention import flash_attention_with_lse
-from bagua_tpu.ops.gmm import gmm_padded, padded_layout
+from bagua_tpu.ops.gmm import _gmm_padded, gmm_padded, padded_layout
 
 
 @pytest.fixture(scope="module")
@@ -113,3 +116,31 @@ def test_the_grouped_matmuls_compile_at_a_ranks_share(one_chip):
              for line in text.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     assert sorted(names) == ["gmm_bwd_drhs", "gmm_fwd", "gmm_fwd"]
+
+
+@pytest.mark.parametrize("rows,groups,d,f", [
+    (73728, 64, 2048, 1024),    # olmoe-1b-7b: d_lhs of the up / gate products
+    (73728, 64, 1024, 2048),    # ... and of the down product
+    (51200, 16, 2560, 768),     # smallthinker-21b-a3b, one rank's share
+    (51200, 16, 768, 2560),
+])
+def test_d_lhs_compiles_on_the_stored_matrices(rows, groups, d, f, one_chip):
+    """``gmm_fwd`` in its transposed-operand form, cotangent [R, f] times
+    the stack [G, d, f] as stored -> [R, d]: Mosaic takes the product that
+    contracts both last axes with the whole matrix resident, and the
+    compiled text holds the kernel alone: no ``transpose`` or ``copy`` of
+    the stack in front of it."""
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    text = jax.jit(
+        lambda g_p, w, gid: _gmm_padded(g_p, w, gid, 128, None, False,
+                                        transpose_rhs=True)
+    ).lower(spec((rows, f), jnp.bfloat16), spec((groups, d, f), jnp.bfloat16),
+            spec((rows // 128,), jnp.int32)).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert len(calls) == 1 and "gmm_fwd" in calls[0]
+    assert re.search(rf"= bf16\[{rows},{d}\]", calls[0])
+    stack = rf"bf16\[{groups},\d+,\d+\]"
+    assert not [line for line in text.splitlines()
+                if re.search(rf"= {stack}\S* (copy|transpose)\(", line)]

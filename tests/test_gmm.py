@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from bagua_tpu.ops import gmm as G
 from bagua_tpu.ops.gmm import (
     gmm, gmm_padded, gmm_reference, kernel_layout, pad_rows, padded_layout,
     take_or_zero, unpad_rows,
@@ -311,15 +312,27 @@ def _products_of(shape, dtype):
                     lhs, rhs, g)
     want = all_three(gmm_reference, *(x.astype(jnp.float32)
                                       for x in (lhs, rhs, g)))
-    return got, want
+    # the transposed-operand form by itself: rows [R, f] times the stack
+    # [G, d, f] as stored, against the reference on the transposed stack
+    layout = padded_layout(gs, rows)
+    on_stored = G._gmm_padded(
+        take_or_zero(g, layout.src), rhs, layout.g_of_block, 128, block_f,
+        True, transpose_rhs=True)[layout.pos]
+    on_transposed = gmm_reference(
+        g.astype(jnp.float32), jnp.swapaxes(rhs, 1, 2).astype(jnp.float32),
+        gs)
+    return got + (on_stored,), want + (on_transposed,)
 
 
-@pytest.mark.parametrize("product", ["forward", "d_lhs", "d_rhs"])
+PRODUCTS = ["forward", "d_lhs", "d_rhs", "transposed_operand"]
+
+
+@pytest.mark.parametrize("product", PRODUCTS)
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("shape", list(SHAPES))
 def test_each_product_matches_the_reference(shape, dtype, product):
     got, want = _products_of(shape, dtype)
-    i = ["forward", "d_lhs", "d_rhs"].index(product)
+    i = PRODUCTS.index(product)
     assert got[i].dtype == jnp.dtype(dtype) and got[i].shape == want[i].shape
     tol = 1e-4 if dtype == "float32" else 2e-2
     np.testing.assert_allclose(
@@ -328,6 +341,68 @@ def test_each_product_matches_the_reference(shape, dtype, product):
     if product == "d_rhs":      # the group with no rows: exact zeros
         empty = np.asarray(SHAPES[shape][2]) == 0
         assert not np.asarray(got[i].astype(jnp.float32))[empty].any()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("shape", list(SHAPES))
+def test_d_lhs_is_the_product_on_a_transposed_copy_without_the_copy(shape,
+                                                                    dtype):
+    """What the backward pass did before it read the stack as stored: the
+    forward form on ``swapaxes(rhs, 1, 2)``.  The same operands in the same
+    float32 accumulation: equal to round-off, zero rows included."""
+    d, f, sizes, block_f = SHAPES[shape]
+    rows = int(np.sum(sizes))
+    _, rhs, gs = _case(jax.random.PRNGKey(17), rows, d, f, sizes)
+    rhs = (rhs / np.sqrt(d)).astype(dtype)
+    layout = padded_layout(gs, rows)
+    g_p = take_or_zero(
+        jax.random.normal(jax.random.PRNGKey(18), (rows, f)).astype(dtype),
+        layout.src)
+    lhs_p = jnp.zeros((g_p.shape[0], d), dtype)
+    _, vjp = jax.vjp(lambda l_p: gmm_padded(l_p, rhs, layout, block_f=block_f,
+                                            interpret=True), lhs_p)
+    (got,) = vjp(g_p)
+    before = G._gmm_padded(g_p, jnp.swapaxes(rhs, 1, 2), layout.g_of_block,
+                           128, block_f, True)
+    assert got.dtype == before.dtype and got.shape == before.shape
+    np.testing.assert_allclose(got.astype(jnp.float32),
+                               before.astype(jnp.float32), rtol=1e-6,
+                               atol=1e-6)
+    assert not np.asarray(got.astype(jnp.float32))[
+        np.asarray(layout.src) == rows].any()
+
+
+@pytest.mark.parametrize("gated", [False, True], ids=["ungated", "gated"])
+def test_the_gradient_transposes_no_matrix_stack(gated):
+    """The test that keeps the copy from coming back: in the jaxpr of an
+    expert FFN's gradient (two or three products in the layout) no
+    ``transpose`` takes a rank-3 ``[G, ., .]`` operand — d_lhs contracts
+    the stored matrices' last axis inside ``gmm_fwd``."""
+    keys = jax.random.split(jax.random.PRNGKey(19), 4)
+    x = jax.random.normal(keys[0], (256, 128))
+    w_gate, w_up = (jax.random.normal(k, (4, 128, 256)) for k in keys[1:3])
+    w_down = jax.random.normal(keys[3], (4, 256, 128))
+    layout = padded_layout(jnp.array([100, 60, 0, 96], jnp.int32), 256)
+    mm = lambda l, r: gmm_padded(l, r, layout, interpret=True)
+
+    def loss(x, wg, wu, wd):
+        x_p = pad_rows(x, layout.src, layout.pos[:, None])
+        up = mm(x_p, wu)
+        h = jax.nn.silu(mm(x_p, wg)) * up if gated else jax.nn.silu(up)
+        return unpad_rows(mm(h, wd), layout.pos, layout.src).sum()
+
+    ops = primitives(jax.grad(loss, argnums=(0, 1, 2, 3)), x, w_gate, w_up,
+                     w_down)
+    names = [name for name, _ in ops]
+    products = 3 if gated else 2
+    assert names.count("gmm_fwd") == 2 * products
+    assert names.count("gmm_bwd_drhs") == products
+    assert not [(name, shapes) for name, shapes in ops
+                if name == "transpose" and any(len(s) == 3 for s in shapes)]
+    # the d_lhs calls take the stack in the forward call's own shape
+    stacks = [shapes[2] for name, shapes in ops if name == "gmm_fwd"]
+    assert sorted(stacks) == sorted(
+        2 * ([(4, 128, 256)] * (products - 1) + [(4, 256, 128)]))
 
 
 def _olmoe_blocks(seed=0):
@@ -348,20 +423,25 @@ def _fetches(grid, index_map, gid):
     return 1 + sum(a != b for a, b in zip(walk, walk[1:]))
 
 
+@pytest.mark.parametrize("transpose_rhs", [False, True],
+                         ids=["rhs", "rhs_transposed"])
 @pytest.mark.parametrize("d,f,block_f", [
     (2048, 1024, None), (1024, 2048, None), (2048, 1024, 512),
     (1024, 2048, 512), (2048, 1024, 128),
 ])
-def test_a_groups_matrix_slab_is_fetched_once_per_f_block(d, f, block_f):
+def test_a_groups_matrix_slab_is_fetched_once_per_f_block(d, f, block_f,
+                                                          transpose_rhs):
     """No kernel runs: the forward grid walked through its index maps.  The
     matrix operand changes ``groups x f/bf`` times, not ``row blocks x
-    f/bf`` (2.4 GB of weights a call at OLMoE's shapes, PR 31)."""
-    from bagua_tpu.ops import gmm as G
+    f/bf`` (2.4 GB of weights a call at OLMoE's shapes, PR 31) — in the
+    transposed-operand form (d_lhs on the stored ``[G, f, d]``) as well."""
     gid = _olmoe_blocks()
     bf = G._fwd_block_f(d, f, 128, 2, block_f or f, 64 << 20)
     assert bf == (block_f or f)
-    spec = G._fwd_grid_spec(576 * 128, d, f, 128, bf)
+    spec = G._fwd_grid_spec(576 * 128, d, f, 128, bf, transpose_rhs)
     assert spec.grid == (f // bf, 576)
+    assert spec.in_specs[1].block_shape == (
+        (1, bf, d) if transpose_rhs else (1, d, bf))
     assert _fetches(spec.grid, spec.in_specs[1].index_map, gid) == 64 * f // bf
     # rows and result: every block of each, once per ``f`` block
     assert _fetches(spec.grid, spec.in_specs[0].index_map, gid) == 576 * f // bf
@@ -375,7 +455,6 @@ def test_a_groups_matrix_slab_is_fetched_once_per_f_block(d, f, block_f):
 def test_a_groups_gradient_block_stays_resident_over_its_rows(d, f, block_f):
     """The d_rhs grid walked through its index maps: the float32 output
     block changes (is written back) ``groups x d/bd x f/bf`` times."""
-    from bagua_tpu.ops import gmm as G
     gid = _olmoe_blocks(1)
     bd, bf = G._drhs_blocks(d, f, 128, 2, block_f or max(d, f), 64 << 20)
     assert (bd, bf) == ((block_f, block_f) if block_f else (d, f))
@@ -398,7 +477,6 @@ def test_a_groups_gradient_block_stays_resident_over_its_rows(d, f, block_f):
 ])
 def test_the_blocks_are_the_widest_that_fit_the_vmem_limit(vmem_limit, fwd,
                                                            drhs):
-    from bagua_tpu.ops import gmm as G
     assert G._fwd_block_f(2048, 1024, 128, 2, 1024, vmem_limit) == fwd
     assert G._drhs_blocks(2048, 1024, 128, 2, 2048, vmem_limit) == drhs
 
