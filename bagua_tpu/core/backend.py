@@ -37,7 +37,9 @@ from ..communication import (
     BaguaCommunicator, ReduceOp, abort, check_abort, collapse_trivial_axes,
 )
 from ..faults import inject as _inject
-from ..obs.spans import phase_scope, trace_span, trace_step_span
+from ..obs.spans import (
+    ACCUM_SCOPE, phase_scope, trace_span, trace_step_span,
+)
 from ..obs.step_observer import StepObserver
 from ..parallel.mesh import build_mesh, hierarchical_mesh, mesh_axis_size
 from ..telemetry import counters
@@ -1404,20 +1406,29 @@ class BaguaTrainer:
                         )
                     return x.reshape((accum, x.shape[0] // accum) + x.shape[1:])
 
-                microbatches = jax.tree.map(reshape_mb, batch)
+                # what the accumulation itself costs on the device names
+                # itself (a plain scope, no ``bagua.*`` phase): the
+                # micro-batch views, the carry's zeros, the adds and the
+                # final division — not the micro-steps' loss, which reads
+                # as forward / backward under ``bagua.loss`` like any other
+                with phase_scope(ACCUM_SCOPE):
+                    microbatches = jax.tree.map(reshape_mb, batch)
 
                 def micro_step(carry, mb):
                     loss_sum, grad_sum = carry
                     l, g = jax.value_and_grad(loss_on)(params, mb)
-                    return (loss_sum + l, jax.tree.map(jnp.add, grad_sum, g)), None
+                    with phase_scope(ACCUM_SCOPE):
+                        return (loss_sum + l,
+                                jax.tree.map(jnp.add, grad_sum, g)), None
 
                 # carry dtype must match micro_step's promoted loss dtype
                 mb0 = jax.tree.map(lambda x: x[0], microbatches)
                 loss_dtype = jax.eval_shape(loss_on, params, mb0).dtype
-                zero = (
-                    jnp.zeros((), loss_dtype),
-                    jax.tree.map(jnp.zeros_like, params),
-                )
+                with phase_scope(ACCUM_SCOPE):
+                    zero = (
+                        jnp.zeros((), loss_dtype),
+                        jax.tree.map(jnp.zeros_like, params),
+                    )
                 if overlap:
                     # Overlap scheduler: peel the LAST microbatch out of
                     # the scan.  A scan is one opaque while-op whose
@@ -1430,16 +1441,18 @@ class BaguaTrainer:
                     # while later buckets are still being computed.  The
                     # gradient sum order is unchanged, so the peeled and
                     # scanned constructions are bit-identical.
-                    head = jax.tree.map(lambda x: x[:-1], microbatches)
-                    tail = jax.tree.map(lambda x: x[-1], microbatches)
+                    with phase_scope(ACCUM_SCOPE):
+                        head = jax.tree.map(lambda x: x[:-1], microbatches)
+                        tail = jax.tree.map(lambda x: x[-1], microbatches)
                     (loss, grads), _ = jax.lax.scan(micro_step, zero, head)
                     (loss, grads), _ = micro_step((loss, grads), tail)
                 else:
                     (loss, grads), _ = jax.lax.scan(
                         micro_step, zero, microbatches
                     )
-                loss = loss / accum
-                grads = jax.tree.map(lambda g: g / accum, grads)
+                with phase_scope(ACCUM_SCOPE):
+                    loss = loss / accum
+                    grads = jax.tree.map(lambda g: g / accum, grads)
             else:
                 loss, grads = jax.value_and_grad(loss_on)(params, batch)
             if poison_specs:

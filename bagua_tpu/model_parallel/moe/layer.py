@@ -225,10 +225,6 @@ class MoEMLP(nn.Module):
             counters.set_gauge("moe/padded_rows_per_step", x_p.shape[0])
             counters.set_gauge("moe/padded_resident_layers",
                                int(layout.block_rows > 1))
-            if layout.block_rows > 1:
-                # transposed copies of an expert matrix stack that the
-                # backward pass writes: d_lhs reads the matrices as stored
-                counters.set_gauge("moe/dlhs_transposed_copies", 0)
 
         @jax.checkpoint
         def down(up, gate, wo):
@@ -512,16 +508,14 @@ def globalize_expert_params(params, rng, ep_size: int, is_expert=None):
 def moe_lm_loss_fn(model, aux_loss_weight: float = 0.01):
     """Next-token loss + load-balancing aux loss collected from every MoE
     layer (the reference accumulates ``l_aux`` per gate, sharded_moe.py:354)."""
-    import optax
+    from ...models.transformer import loss_tail
 
     def loss_fn(params, batch):
         tokens = batch["tokens"]
         logits, mutated = model.apply(
             {"params": params}, tokens[:, :-1], mutable=["intermediates"]
         )
-        nll = optax.softmax_cross_entropy_with_integer_labels(
-            logits, tokens[:, 1:]
-        ).mean()
+        nll = loss_tail(logits, tokens[:, 1:])
         aux = jnp.zeros((), jnp.float32)
         for leaf in jax.tree.leaves(mutated.get("intermediates", {})):
             aux = aux + jnp.sum(leaf)

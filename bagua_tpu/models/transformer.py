@@ -23,7 +23,7 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-
+from ..obs.spans import LOSS_TAIL_SCOPE, POS_EMBED_SCOPE, phase_scope
 from ..parallel.mesh import axis_bound as _axis_bound
 from ..utils import remat_wrap
 
@@ -561,36 +561,42 @@ class TransformerLM(nn.Module):
                 (cfg.max_seq_len, cfg.d_model), cfg.param_dtype,
             )
             s = tokens.shape[1]
-            if cfg.decode and cfg.page_size > 0:
-                # paged decode: every slot sits at its OWN position (continuous
-                # batching admits requests mid-flight), so the position comes
-                # from the scheduler's per-slot lengths, not a shared counter.
-                # During init (no slots yet) position 0 stands in.
-                if slots is None:
-                    pos_ids = jnp.zeros((tokens.shape[0], s), jnp.int32)
+            # the table is this module's own parameter, under no sub-module's
+            # name: its slice and add name themselves
+            with phase_scope(POS_EMBED_SCOPE):
+                if cfg.decode and cfg.page_size > 0:
+                    # paged decode: every slot sits at its OWN position
+                    # (continuous batching admits requests mid-flight), so
+                    # the position comes from the scheduler's per-slot
+                    # lengths, not a shared counter.  During init (no slots
+                    # yet) position 0 stands in.
+                    if slots is None:
+                        pos_ids = jnp.zeros((tokens.shape[0], s), jnp.int32)
+                    else:
+                        pos_ids = (slots["lengths"][:, None]
+                                   + jnp.arange(s, dtype=jnp.int32)[None, :])
+                    # pos[idx] equals the dense path's dynamic_slice row for
+                    # the same position — elementwise identical, per slot
+                    pos_slice = jnp.take(pos, pos_ids, axis=0)  # [b, s, d]
+                    x = x + pos_slice.astype(cfg.dtype)
                 else:
-                    pos_ids = (slots["lengths"][:, None]
-                               + jnp.arange(s, dtype=jnp.int32)[None, :])
-                # pos[idx] equals the dense path's dynamic_slice row for the
-                # same position — elementwise identical, per slot
-                pos_slice = jnp.take(pos, pos_ids, axis=0)  # [b, s, d_model]
-                x = x + pos_slice.astype(cfg.dtype)
-            else:
-                start = 0
-                if cfg.sp_axis is not None and _axis_bound(cfg.sp_axis):
-                    start = jax.lax.axis_index(cfg.sp_axis) * s
-                if cfg.decode:
-                    # autoregressive position counter (mirrors the attention
-                    # cache; same init-pass guard — see Attention._decode_attend)
-                    advance = self.has_variable("cache", "pos_index")
-                    pos_index = self.variable(
-                        "cache", "pos_index", lambda: jnp.zeros((), jnp.int32)
-                    )
-                    if advance:
-                        start = pos_index.value
-                        pos_index.value = start + s
-                pos_slice = jax.lax.dynamic_slice_in_dim(pos, start, s, axis=0)
-                x = x + pos_slice[None].astype(cfg.dtype)
+                    start = 0
+                    if cfg.sp_axis is not None and _axis_bound(cfg.sp_axis):
+                        start = jax.lax.axis_index(cfg.sp_axis) * s
+                    if cfg.decode:
+                        # autoregressive position counter (mirrors the
+                        # attention cache; same init-pass guard — see
+                        # Attention._decode_attend)
+                        advance = self.has_variable("cache", "pos_index")
+                        pos_index = self.variable(
+                            "cache", "pos_index",
+                            lambda: jnp.zeros((), jnp.int32))
+                        if advance:
+                            start = pos_index.value
+                            pos_index.value = start + s
+                    pos_slice = jax.lax.dynamic_slice_in_dim(pos, start, s,
+                                                             axis=0)
+                    x = x + pos_slice[None].astype(cfg.dtype)
         if not self.is_initializing() and not cfg.decode:
             # trace-time facts of this model's step, for the operator and
             # the benchmark's readers of the windowed kernels
@@ -619,7 +625,10 @@ class TransformerLM(nn.Module):
             cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="lm_head",
         )(x)
-        return logits.astype(jnp.float32)
+        # the cast is the head's work (1.2 GB of float32 logits where XLA
+        # leaves it a pass of its own): it reads under the head's name
+        with phase_scope("lm_head"):
+            return logits.astype(jnp.float32)
 
 
 #: dotted-name suffix -> (sharded dim of the GLOBAL kernel, contracting
@@ -657,17 +666,37 @@ def tp_param_fan_in_dims(name: str):
     return None
 
 
+@jax.jit
+def _mean_cross_entropy(logits, targets):
+    # jitted for the lowered module's sake, not the trace's: JAX keys its
+    # persistent compile cache on the module WITHOUT metadata, so a step
+    # that differs from an older build's in scope names alone is handed
+    # that build's executable, old names and all.  A called function
+    # (``@_mean_cross_entropy``) is part of the key; XLA inlines it, and
+    # the optimized step is the inline form's, instruction for instruction
+    import optax
+
+    return optax.softmax_cross_entropy_with_integer_labels(
+        logits, targets
+    ).mean()
+
+
+def loss_tail(logits, targets):
+    """Mean cross-entropy of ``logits`` [b, s, vocab] against the integer
+    ``targets`` [b, s]: what every LM loss function runs after the model.
+    No module names it, so it names itself: the plain scope ``loss_tail``
+    (``obs.spans.area_of`` reads it, forward and backward, as the head)."""
+    with phase_scope(LOSS_TAIL_SCOPE):
+        return _mean_cross_entropy(logits, targets)
+
+
 def lm_loss_fn(model: TransformerLM):
     """Next-token cross-entropy; batch = dict(tokens=[b, s+1])."""
 
     def loss_fn(params, batch):
-        import optax
-
         tokens = batch["tokens"]
         logits = model.apply({"params": params}, tokens[:, :-1])
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits, tokens[:, 1:]
-        ).mean()
+        return loss_tail(logits, tokens[:, 1:])
 
     return loss_fn
 
@@ -683,8 +712,6 @@ def sp_lm_loss_fn(model: TransformerLM, sp_size: int, sp_axis: str = "sp"):
     """
 
     def loss_fn(params, batch):
-        import optax
-
         tokens = batch["tokens"]
         seq_global = tokens.shape[1] - 1
         assert seq_global % sp_size == 0, (seq_global, sp_size)
@@ -693,8 +720,6 @@ def sp_lm_loss_fn(model: TransformerLM, sp_size: int, sp_axis: str = "sp"):
         inputs = jax.lax.dynamic_slice_in_dim(tokens, start, s_local, axis=1)
         targets = jax.lax.dynamic_slice_in_dim(tokens, start + 1, s_local, axis=1)
         logits = model.apply({"params": params}, inputs)
-        return optax.softmax_cross_entropy_with_integer_labels(
-            logits, targets
-        ).mean()
+        return loss_tail(logits, targets)
 
     return loss_fn

@@ -148,11 +148,6 @@ _declare("moe/padded_resident_layers", "gauge",
          "that padded layout from dispatch to combine (one gather in, the "
          "expert FFN on padded rows, one gather out): the kernels run.  0 "
          "where the layer runs the dense fallback on the sorted rows.")
-_declare("moe/dlhs_transposed_copies", "gauge",
-         "Transposed copies of an expert matrix stack that the backward "
-         "pass of that layer writes where the kernels run: 0 since the "
-         "d_lhs product contracts the stored matrices' last axis inside "
-         "gmm_fwd (one a product, 3 a gated layer, in builds before that).")
 _declare("comm/aborts", "counter",
          "Cooperative abort flag raises (watchdog fire, grad-guard abort, "
          "user abort()).")

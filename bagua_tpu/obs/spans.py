@@ -47,8 +47,9 @@ from typing import Any, Dict, List, Optional
 
 from .. import env as _env
 
-__all__ = ["trace_span", "trace_step_span", "phase_scope", "recorder",
-           "span_ring", "SpanRecorder", "enabled", "set_enabled",
+__all__ = ["trace_span", "trace_step_span", "phase_scope", "area_of",
+           "AREAS", "LOSS_TAIL_SCOPE", "ACCUM_SCOPE", "POS_EMBED_SCOPE",
+           "recorder", "span_ring", "SpanRecorder", "enabled", "set_enabled",
            "set_current_step", "set_ledger_sink"]
 
 #: prefix of every span's mirror on the profiler's host plane
@@ -351,12 +352,72 @@ def phase_scope(scope: str, span: Optional[str] = None, **attrs):
     ``op_name`` path of every instruction traced inside, so a device trace
     and the optimized HLO text can be read by phase (``bagua.loss``,
     ``bagua.layout``, ``bagua.comm/bucket_<i>``, ``bagua.optimizer``,
-    ``bagua.guard``) — and, where ``span`` is given, the trace-time ring
-    span of the same site (launch order and byte accounting of the
-    schedule).  One construct for both, so the schedule and the program
-    cannot name different things.  The scope is unconditional: the program
-    is the same with ``BAGUA_OBS`` on or off."""
+    ``bagua.guard``) and by area (``bagua.moe/<part>`` and the plain
+    scopes of the table below, which no reader of the phases matches) —
+    and, where ``span`` is given, the trace-time ring span of the same
+    site (launch order and byte accounting of the schedule).  One
+    construct for both, so the schedule and the program cannot name
+    different things.  The scope is unconditional: the program is the
+    same with ``BAGUA_OBS`` on or off."""
     import jax  # tracing code only: jax is imported by whoever traces
 
     with trace_span(span, **attrs) if span else _NULL, jax.named_scope(scope):
         yield
+
+
+# ---- the model's areas in the compiled step ---------------------------------
+#
+# Beside the phases, a compiled instruction's ``op_name`` says which part of
+# the model it is: flax writes the module path that ``models/transformer.py``
+# fixes with ``name=`` (the parameter tree's names, which checkpoints hold
+# stable), ``MoEMLP`` opens ``bagua.moe/<part>``, and three plain scopes name
+# what no module does.  None of the three matches ``bagua\.\w+``: a reader of
+# the phases (innermost ``bagua.*`` component) does not see them.
+
+#: the cross-entropy and mean after the logits (``models.transformer.loss_tail``)
+LOSS_TAIL_SCOPE = "loss_tail"
+#: the micro-batch accumulation of ``accum_steps > 1``: the carry's zeros, the
+#: micro-batch reshape and slices, the gradient adds, the final division
+ACCUM_SCOPE = "grad_accum"
+#: slice and add of ``TransformerLM``'s own learned position table
+POS_EMBED_SCOPE = "pos_embed"
+#: the scope whose NEXT component is the part of an expert layer
+MOE_SCOPE = "bagua.moe"
+MOE_PARTS = ("route", "dispatch", "experts", "combine")
+
+#: component of an ``op_name`` path -> the area it names.  The contract
+#: ``area_of`` reads; ``tests/test_step_scopes.py`` holds the compiled step
+#: to it and ``docs/observability.md`` has it as a table.
+AREA_COMPONENTS = {
+    "embed": "embed", POS_EMBED_SCOPE: "embed",
+    "attn_norm": "attn", "attn": "attn",
+    "mlp_norm": "mlp", "mlp": "mlp",
+    "final_norm": "head", "lm_head": "head", LOSS_TAIL_SCOPE: "head",
+    ACCUM_SCOPE: "accum",
+}
+AREAS = ("embed", "attn", "mlp") + tuple(
+    f"moe/{part}" for part in MOE_PARTS) + ("head", "accum")
+
+
+def area_of(op_name: Optional[str]) -> Optional[str]:
+    """The area of the model an instruction's ``op_name`` path names, one
+    of :data:`AREAS`, or None where no component names one (the optimizer,
+    the bucket layout, a residual add under a bare ``block_<i>``).  A
+    ``bagua.moe`` component decides wherever it sits (an expert layer's
+    router may read another module's input); otherwise the innermost
+    component of :data:`AREA_COMPONENTS` does.  JAX's wrappers
+    (``jvp(...)``, ``transpose(...)``, ``checkpoint/rematted_computation``)
+    are components of their own, so forward, backward and replay of an
+    area read alike.  Pure: works on a path copied out of a profile viewer."""
+    if not op_name:
+        return None
+    parts = op_name.split("/")
+    if MOE_SCOPE in parts:
+        inner = len(parts) - parts[::-1].index(MOE_SCOPE)  # what follows it
+        part = parts[inner] if inner < len(parts) else None
+        return f"moe/{part}" if part in MOE_PARTS else None
+    for part in reversed(parts):
+        area = AREA_COMPONENTS.get(part)
+        if area is not None:
+            return area
+    return None

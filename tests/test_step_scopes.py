@@ -146,6 +146,217 @@ def test_one_chip_world_exchanges_no_bucket():
     assert has([p for _, _, p in op_names(text)], "bagua.optimizer")
 
 
+# ---- A2: the model's areas in the compiled step -----------------------------
+
+AREA_PATHS = [
+    # forward, backward and replay of an area read alike
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_3/attn/q/"
+     "dot_general", "attn"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/block_3/"
+     "attn/flash_bwd_dq/pallas_call", "attn"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/"
+     "jvp(bagua.loss)/TransformerLM/checkpoint/rematted_computation/"
+     "block_0/attn_norm/rsqrt", "attn"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_0/mlp/wi_gate/"
+     "dot_general", "mlp"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_0/mlp_norm/mul",
+     "mlp"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/embed/jit(_take)/gather",
+     "embed"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/pos_embed/add", "embed"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/final_norm/mul", "head"),
+    ("transpose(jvp(bagua.loss))/TransformerLM/lm_head/dot_general", "head"),
+    ("jit(bagua_step)/jvp(bagua.loss)/loss_tail/reduce_max", "head"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/loss_tail/"
+     "jit(take_along_axis)/scatter-add", "head"),
+    ("jit(bagua_step)/while/body/closed_call/grad_accum/add", "accum"),
+    # an expert layer's scope decides, whatever module it sits under
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_1/mlp/bagua.moe/"
+     "experts/gmm_fwd/pallas_call", "moe/experts"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_1/attn/bagua.moe/"
+     "route/router/dot_general", "moe/route"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/block_1/mlp/"
+     "bagua.moe/dispatch/checkpoint/rematted_computation/sort",
+     "moe/dispatch"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_1/mlp/bagua.moe/"
+     "combine/mul", "moe/combine"),
+    # no area: the optimizer, the layout, a bare block, nothing
+    ("jit(bagua_step)/bagua.optimizer/mul", None),
+    ("jit(bagua_step)/jvp(bagua.loss)/bagua.layout/slice", None),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_0/add", None),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_1/mlp/bagua.moe",
+     None),
+    ("", None),
+    (None, None),
+]
+
+
+@pytest.mark.parametrize("path, area", AREA_PATHS)
+def test_area_of(path, area):
+    assert obs_spans.area_of(path) == area
+    assert area is None or area in obs_spans.AREAS
+
+
+def lm_trainer(kind, **trainer_kw):
+    """A two-layer LM of ``kind`` under a trainer on the 8-device mesh."""
+    from bagua_tpu.model_parallel.moe import MoEMLP, moe_lm_loss_fn
+    from bagua_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, lm_loss_fn,
+    )
+
+    cfg = dict(vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+               max_seq_len=16)
+    if kind == "remat":
+        cfg.update(remat=True, remat_policy="dots_no_batch")
+    if kind == "moe":
+        cfg.update(rope_theta=10000.0)
+        moe = lambda: MoEMLP(n_experts=4, d_ff=32, k=2, dropless=True,
+                             gated=True, name="mlp")
+        model = TransformerLM(TransformerConfig(**cfg),
+                              mlp_factory=lambda _i: moe)
+        loss_fn = moe_lm_loss_fn(model)
+    else:
+        model = TransformerLM(TransformerConfig(**cfg))
+        loss_fn = lm_loss_fn(model)
+    rows = N_DEVICES * trainer_kw.get("accum_steps", 1)
+    tokens = jnp.zeros((rows, 9), jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens[:1, :8])["params"]
+    trainer = BaguaTrainer(loss_fn, optax.sgd(0.1),
+                           GradientAllReduceAlgorithm(),
+                           mesh=build_mesh({"dp": N_DEVICES}), autotune=False,
+                           **trainer_kw)
+    state = trainer.init(params)
+    return trainer, state, trainer.shard_batch({"tokens": tokens})
+
+
+DENSE_AREAS = {"embed", "attn", "mlp", "head"}
+MOE_AREAS = {"moe/route", "moe/dispatch", "moe/experts", "moe/combine"}
+
+
+@pytest.mark.parametrize("kind, trainer_kw, areas", [
+    ("dense", {}, DENSE_AREAS),
+    ("remat", {}, DENSE_AREAS),
+    ("moe", {}, DENSE_AREAS | MOE_AREAS),
+    ("dense", {"accum_steps": 4, "overlap": "on"}, DENSE_AREAS | {"accum"}),
+    ("dense", {"accum_steps": 4, "overlap": "off"}, DENSE_AREAS | {"accum"}),
+], ids=["dense", "remat", "moe", "accum4-overlap", "accum4-serial"])
+def test_compiled_step_names_its_areas(kind, trainer_kw, areas):
+    trainer, state, batch = lm_trainer(kind, **trainer_kw)
+    paths = [p for _, _, p in
+             op_names(trainer.compiled_step(state, batch).as_text())]
+    # every area of the table that this model has, and no other
+    assert {obs_spans.area_of(p) for p in paths} - {None} == areas
+    if kind == "remat":
+        assert has(paths, "rematted_computation", "/attn/")
+    if kind == "dense":
+        # the learned position table is no module's: its own plain scope
+        assert has(paths, f"/{obs_spans.POS_EMBED_SCOPE}/")
+    # the float32 cast of the logits is the head's, not bare TransformerLM's
+    assert has(paths, "/lm_head/convert_element_type")
+    assert not has(paths, "TransformerLM/convert_element_type")
+    # the loss tail reads forward and backward, inside the one loss scope
+    tail = [p for p in paths
+            if obs_spans.LOSS_TAIL_SCOPE in p.split("/")]
+    assert has(tail, "jvp(bagua.loss)", without=("transpose(",))
+    assert has(tail, "transpose(jvp(bagua.loss))")
+    accum = [p for p in paths if obs_spans.ACCUM_SCOPE in p.split("/")]
+    assert bool(accum) == ("accum_steps" in trainer_kw)
+    # neither plain scope is a phase to a reader of ``bagua.*`` components:
+    # the tail stays in the loss, the accumulation stays unattributed
+    assert {m for p in tail for m in re.findall(r"bagua\.\w+", p)} == {
+        "bagua.loss"}
+    assert not any(re.search(r"bagua\.\w+", p) for p in accum)
+
+
+def test_the_plain_scopes_are_no_phase_scopes():
+    for scope in (obs_spans.LOSS_TAIL_SCOPE, obs_spans.ACCUM_SCOPE,
+                  obs_spans.POS_EMBED_SCOPE):
+        assert not re.search(r"bagua\.\w+", scope)
+        assert obs_spans.AREA_COMPONENTS[scope] in obs_spans.AREAS
+
+
+def parent_tail(logits, targets):
+    """The two lines each loss function wrote out before ``loss_tail``."""
+    return optax.softmax_cross_entropy_with_integer_labels(
+        logits, targets).mean()
+
+
+def assert_bit_equal(got, want):
+    got, want = jax.tree.leaves(got), jax.tree.leaves(want)
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and bool(jnp.array_equal(g, w))
+
+
+@pytest.mark.parametrize("kind", ["lm", "moe", "sp"])
+def test_loss_functions_are_bit_equal_to_their_written_out_form(kind):
+    from jax.sharding import PartitionSpec as P
+
+    from bagua_tpu.model_parallel.moe import MoEMLP, moe_lm_loss_fn
+    from bagua_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, lm_loss_fn, sp_lm_loss_fn,
+    )
+
+    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
+                            d_ff=64, max_seq_len=16, rope_theta=10000.0)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (4, 17), 0, 64)
+    batch = {"tokens": tokens}
+    if kind == "moe":
+        moe = lambda: MoEMLP(n_experts=4, d_ff=32, k=2, dropless=True,
+                             gated=True, name="mlp")
+        model = TransformerLM(cfg, mlp_factory=lambda _i: moe)
+
+        def written_out(params, batch):
+            logits, mutated = model.apply(
+                {"params": params}, batch["tokens"][:, :-1],
+                mutable=["intermediates"])
+            aux = jnp.zeros((), jnp.float32)
+            for leaf in jax.tree.leaves(mutated.get("intermediates", {})):
+                aux = aux + jnp.sum(leaf)
+            return parent_tail(logits, batch["tokens"][:, 1:]) + 0.01 * aux
+
+        loss_fn = moe_lm_loss_fn(model)
+    else:
+        model = TransformerLM(cfg)
+
+        def written_out(params, batch):
+            logits = model.apply({"params": params}, batch["tokens"][:, :-1])
+            return parent_tail(logits, batch["tokens"][:, 1:])
+
+        loss_fn = lm_loss_fn(model)
+    params = model.init(jax.random.PRNGKey(4), tokens[:1, :8])["params"]
+    if kind == "sp":
+        sp = 2
+
+        def written_out(params, batch):
+            start = jax.lax.axis_index("sp") * 8
+            tokens = batch["tokens"]
+            logits = model.apply(
+                {"params": params},
+                jax.lax.dynamic_slice_in_dim(tokens, start, 8, axis=1))
+            return parent_tail(
+                logits,
+                jax.lax.dynamic_slice_in_dim(tokens, start + 1, 8, axis=1))
+
+        mesh = build_mesh({"sp": sp}, jax.devices()[:sp])
+
+        def sharded(fn):
+            def per_shard(params, batch):
+                loss, grads = jax.value_and_grad(fn)(params, batch)
+                return loss[None], jax.tree.map(lambda g: g[None], grads)
+            return jax.jit(jax.shard_map(
+                per_shard, mesh=mesh, in_specs=(P(), P()),
+                out_specs=(P("sp"), P("sp")), check_vma=False))
+
+        got = sharded(sp_lm_loss_fn(model, sp_size=sp))(params, batch)
+        want = sharded(written_out)(params, batch)
+    else:
+        got = jax.jit(jax.value_and_grad(loss_fn))(params, batch)
+        want = jax.jit(jax.value_and_grad(written_out))(params, batch)
+    assert_bit_equal(got, want)
+    assert float(jnp.ravel(got[0])[0]) > 0
+
+
 # ---- B: program spans on the profiler's clock --------------------------------
 
 
