@@ -625,8 +625,9 @@ class TransformerLM(nn.Module):
             cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
             param_dtype=cfg.param_dtype, name="lm_head",
         )(x)
-        # the cast is the head's work (1.2 GB of float32 logits where XLA
-        # leaves it a pass of its own): it reads under the head's name
+        # the cast is the head's work and reads under the head's name.  No
+        # float32 array comes of it: ``loss_tail`` has no reader that needs
+        # the logits in HBM, so the cast fuses into the softmax's passes
         with phase_scope("lm_head"):
             return logits.astype(jnp.float32)
 
@@ -674,18 +675,35 @@ def _mean_cross_entropy(logits, targets):
     # that build's executable, old names and all.  A called function
     # (``@_mean_cross_entropy``) is part of the key; XLA inlines it, and
     # the optimized step is the inline form's, instruction for instruction
-    import optax
-
-    return optax.softmax_cross_entropy_with_integer_labels(
-        logits, targets
-    ).mean()
+    #
+    # optax's ``softmax_cross_entropy_with_integer_labels`` term for term,
+    # but for how the target's logit is picked: not ``take_along_axis``.  A
+    # gather's operand must exist in HBM (the float32 logits, 1.2-1.65 GB a
+    # step, written beside the bf16 ones for 8,192 scalars) and its
+    # transpose is a scatter of scalars (at batch 1 into a float32 flat that
+    # takes three more passes to get back into tiles).  A compare against
+    # an iota and a sum fuse into the passes that read the logits anyway,
+    # forward and backward; the sum of one logit and zeros is that logit
+    vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    hit = vocab == targets[..., None]
+    label_logits = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+    return (jax.nn.logsumexp(logits, axis=-1) - label_logits).mean()
 
 
 def loss_tail(logits, targets):
     """Mean cross-entropy of ``logits`` [b, s, vocab] against the integer
     ``targets`` [b, s]: what every LM loss function runs after the model.
     No module names it, so it names itself: the plain scope ``loss_tail``
-    (``obs.spans.area_of`` reads it, forward and backward, as the head)."""
+    (``obs.spans.area_of`` reads it, forward and backward, as the head).
+
+    The target's logit is a masked sum over the vocabulary axis, not a
+    gather: the operand of a gather must exist in HBM and its transpose is
+    a scatter, while the compare-and-sum fuses into the softmax's own
+    passes (optax's terms: value and gradient equal to its to the bit,
+    operation for operation).  A target outside ``[0, vocab)`` matches no
+    column: its label logit is 0 and the loss stays finite, where
+    ``take_along_axis`` wrapped a negative target around and filled in NaN
+    past the end."""
     with phase_scope(LOSS_TAIL_SCOPE):
         return _mean_cross_entropy(logits, targets)
 

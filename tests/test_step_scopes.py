@@ -9,6 +9,7 @@ wrapped by JAX's own ``jvp(...)`` / ``transpose(...)`` / ``rematted_computation`
 """
 
 import ast
+import contextlib
 import glob
 import os
 import pathlib
@@ -16,6 +17,7 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import optax
 import pytest
 
@@ -288,8 +290,13 @@ def assert_bit_equal(got, want):
         assert g.dtype == w.dtype and bool(jnp.array_equal(g, w))
 
 
-@pytest.mark.parametrize("kind", ["lm", "moe", "sp"])
-def test_loss_functions_are_bit_equal_to_their_written_out_form(kind):
+# the last two: batch 1 and a vocabulary that is no multiple of 128, rows that
+# fill no sublane tile (the loss tail's compare-and-sum, PR 38)
+@pytest.mark.parametrize("kind,vocab,rows", [
+    ("lm", 64, 4), ("moe", 64, 4), ("sp", 64, 4),
+    ("lm", 1187, 1), ("lm", 130, 2)])
+def test_loss_functions_are_bit_equal_to_their_written_out_form(kind, vocab,
+                                                                rows):
     from jax.sharding import PartitionSpec as P
 
     from bagua_tpu.model_parallel.moe import MoEMLP, moe_lm_loss_fn
@@ -297,9 +304,10 @@ def test_loss_functions_are_bit_equal_to_their_written_out_form(kind):
         TransformerConfig, TransformerLM, lm_loss_fn, sp_lm_loss_fn,
     )
 
-    cfg = TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=2,
-                            d_ff=64, max_seq_len=16, rope_theta=10000.0)
-    tokens = jax.random.randint(jax.random.PRNGKey(3), (4, 17), 0, 64)
+    cfg = TransformerConfig(vocab_size=vocab, d_model=32, n_heads=2,
+                            n_layers=2, d_ff=64, max_seq_len=16,
+                            rope_theta=10000.0)
+    tokens = jax.random.randint(jax.random.PRNGKey(3), (rows, 17), 0, vocab)
     batch = {"tokens": tokens}
     if kind == "moe":
         moe = lambda: MoEMLP(n_experts=4, d_ff=32, k=2, dropless=True,
@@ -355,6 +363,98 @@ def test_loss_functions_are_bit_equal_to_their_written_out_form(kind):
         want = jax.jit(jax.value_and_grad(written_out))(params, batch)
     assert_bit_equal(got, want)
     assert float(jnp.ravel(got[0])[0]) > 0
+
+
+# the loss tail picks the target's logit by compare-and-sum (PR 38): batch 1
+# with a vocabulary that is no multiple of 128 (the SmallThinker share's kind
+# of slice), a lane-aligned one, and rows that fill no sublane tile
+TAIL_SHAPES = [(1, 64, 1187), (2, 16, 256), (8, 12, 130)]
+
+
+def tail_case(shape, seed=5):
+    """bf16-rounded float32 logits (what the head hands the tail), targets."""
+    k_logits, k_targets = jax.random.split(jax.random.PRNGKey(seed))
+    logits = 4.0 * jax.random.normal(k_logits, shape, jnp.float32)
+    logits = logits.astype(jnp.bfloat16).astype(jnp.float32)
+    targets = jax.random.randint(k_targets, shape[:-1], 0, shape[-1])
+    return logits, targets
+
+
+@pytest.mark.parametrize("shape", TAIL_SHAPES, ids=lambda s: "x".join(map(str, s)))
+@pytest.mark.parametrize("how", ["op_by_op", "jit"])
+def test_loss_tail_is_bit_equal_to_optax(shape, how):
+    from bagua_tpu.models.transformer import loss_tail
+
+    # ``jax.jit`` is the identity under ``disable_jit``: one rounding an
+    # operation, on both sides
+    mode = jax.disable_jit() if how == "op_by_op" else contextlib.nullcontext()
+    ours = jax.jit(jax.value_and_grad(loss_tail))
+    theirs = jax.jit(jax.value_and_grad(parent_tail))
+    cases = [tail_case(shape, seed) for seed in range(5, 17)]
+    with mode:
+        results = [(ours(*case), theirs(*case)) for case in cases]
+    for (_, targets), ((loss, grad), (want_loss, want_grad)) in zip(
+            cases, results):
+        # the sum of one logit and zeros is that logit
+        assert_bit_equal(loss, want_loss)
+        assert grad.shape == shape and float(loss) > 0
+        if how == "op_by_op":
+            # the same two terms (softmax, minus one-hot), and two-term
+            # sums commute
+            assert_bit_equal(grad, want_grad)
+            continue
+        # under jit the compiler is free to round the two-term sum once
+        # (softmax * g - g as one fused multiply-add) on either side: equal
+        # to the bit wherever no target sits, within one unit in the last
+        # place in a target's own column
+        hit = np.arange(shape[-1]) == np.asarray(targets)[..., None]
+        got, want = np.asarray(grad), np.asarray(want_grad)
+        assert np.array_equal(got[~hit], want[~hit])
+        np.testing.assert_array_max_ulp(got[hit], want[hit], maxulp=1)
+
+
+@pytest.mark.parametrize("shape", TAIL_SHAPES[:2],
+                         ids=lambda s: "x".join(map(str, s)))
+def test_loss_tail_holds_no_gather_and_no_scatter(shape):
+    # the operand of a gather must exist in HBM (the float32 logits) and its
+    # transpose is a scatter (a float32 d-logits flat at batch 1): neither
+    # may come back, in the trace or in what is handed to the compiler
+    from bagua_tpu.models.transformer import loss_tail
+
+    logits, targets = tail_case(shape)
+    grad = jax.value_and_grad(loss_tail)
+    for text in (str(jax.make_jaxpr(grad)(logits, targets)),
+                 jax.jit(grad).lower(logits, targets).as_text()):
+        assert "gather" not in text and "scatter" not in text
+    # the lens sees them where they are: optax's form holds both
+    parent = jax.jit(jax.value_and_grad(parent_tail)).lower(
+        logits, targets).as_text()
+    assert "gather" in parent and "scatter" in parent
+
+
+def test_a_target_outside_the_vocabulary_reads_a_label_logit_of_zero():
+    from bagua_tpu.models.transformer import loss_tail
+
+    logits, targets = tail_case((2, 16, 256))
+    outside = targets.at[0, 3].set(256).at[1, 5].set(-1)
+    loss, grad = jax.value_and_grad(loss_tail)(logits, outside)
+    # no column matches: the row's loss is its log-normaliser alone and its
+    # gradient the softmax with no one-hot taken off
+    labels = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
+    rows = jax.nn.logsumexp(logits, axis=-1) - jnp.where(
+        outside == targets, labels, 0.0)
+    assert bool(jnp.isfinite(loss)) and bool(jnp.isfinite(grad).all())
+    assert jnp.allclose(loss, rows.mean(), rtol=1e-6)
+    assert jnp.allclose(grad[0, 3].sum() * targets.size, 1.0, rtol=1e-5)
+    assert jnp.allclose(grad[0, 4].sum(), 0.0, atol=1e-7)
+    # optax's gather fills a NaN past the end and wraps a negative target
+    # around to the last columns; the docstring says what differs
+    assert bool(jnp.isnan(parent_tail(logits, targets.at[0, 3].set(256))))
+    assert_bit_equal(parent_tail(logits, targets.at[1, 5].set(-1)),
+                     parent_tail(logits, targets.at[1, 5].set(255)))
+    doc = " ".join(loss_tail.__doc__.split())
+    assert "outside ``[0, vocab)``" in doc and "label logit is 0" in doc
+    assert "gather" in doc and "scatter" in doc
 
 
 # ---- B: program spans on the profiler's clock --------------------------------
