@@ -23,7 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
-from ..obs.spans import LOSS_TAIL_SCOPE, POS_EMBED_SCOPE, phase_scope
+from ..obs.spans import (
+    EXIT_SCOPE, LOOP_SCOPE, LOSS_TAIL_SCOPE, POS_EMBED_SCOPE, phase_scope,
+)
 from ..parallel.mesh import axis_bound as _axis_bound
 from ..utils import remat_wrap
 
@@ -119,6 +121,22 @@ class TransformerConfig:
     #: and its experts the post-attention norm: ``Block`` hands a custom MLP
     #: that input as ``route_x`` (``MoEMLP``)
     route_before_attention: bool = False
+    #: passes over the stack (a looped, depth-shared decoder): the parameter
+    #: tree holds each of the ``n_layers`` blocks ONCE, the blocks run
+    #: ``n_passes`` times in order over the same weights, ``final_norm``
+    #: closes every pass and its output is what the next pass reads.  Every
+    #: pass rotates q and k at positions ``0 .. s - 1``.  The decode paths
+    #: do not implement it (a key / value cache per pass)
+    n_passes: int = 1
+    #: a second RMSNorm behind each sub-layer, on its output before the
+    #: residual add ("sandwich" norm): ``x + N'(Attn(N(x)))``
+    post_norms: bool = False
+    #: the model ends every pass in a head and an exit gate (``exit_gate``:
+    #: ``Dense(1)`` with bias, float32 like the routers, on the pass's normed
+    #: state) and hands back what ``looped_lm_loss_fn`` weighs: ``(logits
+    #: [n_passes, batch, seq, vocab], gate logits [batch, seq, n_passes])``.
+    #: Off, a looped model returns the last pass's logits like any other
+    exit_gate: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -519,9 +537,15 @@ class Block(nn.Module):
                     name="attn_norm")(x)
         attn = Attention(cfg, self.attn_fn, cfg.layer_window(self.layer),
                          cfg.layer_rotary(self.layer), name="attn")
+        # the sub-layer's output through its own norm where the
+        # configuration has one
+        post = lambda name, t: RMSNorm(
+            cfg.dtype, cfg.param_dtype, cfg.norm_eps, name=name,
+        )(t) if cfg.post_norms else t
         # dense/training call sites keep their exact one-arg form (the
         # goldens pin those programs); only paged decode threads slots
-        x = x + (attn(y) if slots is None else attn(y, slots))
+        x = x + post("attn_post_norm",
+                     attn(y) if slots is None else attn(y, slots))
         y = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
                     name="mlp_norm")(x)
         mlp = self.mlp() if self.mlp is not None else MLPBlock(cfg, name="mlp")
@@ -530,8 +554,8 @@ class Block(nn.Module):
                 raise ValueError(
                     "route_before_attention names a router: it needs an "
                     "expert MLP (mlp_factory) that takes `route_x`")
-            return x + mlp(y, route_x=block_in)
-        x = x + mlp(y)
+            return x + post("mlp_post_norm", mlp(y, route_x=block_in))
+        x = x + post("mlp_post_norm", mlp(y))
         return x
 
 
@@ -608,28 +632,84 @@ class TransformerLM(nn.Module):
             counters.set_gauge("attn/window", cfg.window or 0)
             counters.set_gauge("attn/window_layers", windowed)
             counters.set_gauge("attn/full_layers", cfg.n_layers - windowed)
-        for i in range(cfg.n_layers):
-            mlp = self.mlp_factory(i) if self.mlp_factory is not None else None
-            block_cls = Block
-            if cfg.remat:
-                # a custom MLP tags nothing: its matmuls keep the dots rule
-                own = (KEPT_QKV, KEPT_FFN_IN) if mlp is None else ()
-                block_cls = remat_wrap(Block, cfg.remat_policy, own)
-            blk = block_cls(cfg, self.attn_fn, mlp, i, name=f"block_{i}")
-            x = blk(x) if slots is None else blk(x, slots)
-        x = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
-                    name="final_norm")(x)
-        if not self.head:
-            return x.astype(jnp.float32)
-        logits = nn.Dense(
-            cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype, name="lm_head",
-        )(x)
-        # the cast is the head's work and reads under the head's name.  No
-        # float32 array comes of it: ``loss_tail`` has no reader that needs
-        # the logits in HBM, so the cast fuses into the softmax's passes
-        with phase_scope("lm_head"):
-            return logits.astype(jnp.float32)
+            if cfg.n_passes > 1:
+                counters.set_gauge("loop/passes", cfg.n_passes)
+                counters.set_gauge("loop/shared_layers", cfg.n_layers)
+        if cfg.decode and cfg.n_passes > 1:
+            raise NotImplementedError(
+                "n_passes > 1 is not implemented for the decode paths (a "
+                "key / value cache per pass)")
+
+        def stack(x):
+            """The ``n_layers`` blocks, once through."""
+            for i in range(cfg.n_layers):
+                mlp = (self.mlp_factory(i) if self.mlp_factory is not None
+                       else None)
+                block_cls = Block
+                if cfg.remat:
+                    # a custom MLP tags nothing: its matmuls keep the dots rule
+                    own = (KEPT_QKV, KEPT_FFN_IN) if mlp is None else ()
+                    block_cls = remat_wrap(Block, cfg.remat_policy, own)
+                blk = block_cls(cfg, self.attn_fn, mlp, i, name=f"block_{i}")
+                x = blk(x) if slots is None else blk(x, slots)
+            return x
+
+        def final_norm(x):
+            return RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
+                           name="final_norm")(x)
+
+        def lm_head(x):
+            return nn.Dense(
+                cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype, name="lm_head",
+            )(x)
+
+        def float32_logits(logits):
+            # the cast is the head's work and reads under the head's name.
+            # No float32 array comes of it: ``loss_tail`` has no reader that
+            # needs the logits in HBM, so the cast fuses into the softmax's
+            # passes
+            with phase_scope("lm_head"):
+                return logits.astype(jnp.float32)
+
+        if cfg.n_passes == 1 and not cfg.exit_gate:
+            x = final_norm(stack(x))
+            if not self.head:
+                return x.astype(jnp.float32)
+            return float32_logits(lm_head(x))
+
+        # the looped stack, a scan over the passes with the weights
+        # broadcast: ONE body holds each block once, so the parameters are
+        # the same in every pass, the tree has ``n_layers`` blocks, and the
+        # step compiles one pass however many run.  ``final_norm`` closes a
+        # pass and the NORMED state goes on.  With the exit gate the scan
+        # hands back every pass's state, [n_passes, batch, seq, d_model],
+        # and head and gate read all of them OUTSIDE the body: a head
+        # inside it would hand its logits' cotangent to the backward scan
+        # as an array, where the head's own backward matmuls rebuild it in
+        # their operand fusions (``loss_tail``)
+        def one_pass(mdl, x, _):
+            with phase_scope(LOOP_SCOPE):
+                x = stack(x)
+            x = final_norm(x)
+            return x, x if cfg.exit_gate else None
+
+        x, states = nn.scan(
+            one_pass, variable_broadcast="params",
+            split_rngs={"params": False}, length=cfg.n_passes)(self, x, None)
+        read = states if cfg.exit_gate else x
+        out = float32_logits(lm_head(read)) if self.head else (
+            read.astype(jnp.float32))
+        if not cfg.exit_gate:
+            return out
+        # the cast in front of the gate and the re-layout behind it are the
+        # gate's work and read under its name
+        with phase_scope("exit_gate"):
+            gates = nn.Dense(
+                1, use_bias=True, dtype=jnp.float32,
+                param_dtype=cfg.param_dtype, name="exit_gate",
+            )(states.astype(jnp.float32))[..., 0]
+            return out, jnp.moveaxis(gates, 0, -1)
 
 
 #: dotted-name suffix -> (sharded dim of the GLOBAL kernel, contracting
@@ -706,6 +786,78 @@ def loss_tail(logits, targets):
     past the end."""
     with phase_scope(LOSS_TAIL_SCOPE):
         return _mean_cross_entropy(logits, targets)
+
+
+@jax.jit
+def _token_cross_entropy(logits, targets):
+    # ``_mean_cross_entropy`` without its mean, for a loss that weighs each
+    # token's cross-entropy before it averages (``looped_lm_loss_fn``): the
+    # same compare-and-sum for the target's logit, so nothing here needs the
+    # float32 logits in HBM either, forward or backward, and a cotangent that
+    # differs by token is one more factor in the fusions that rebuild
+    # softmax - one-hot.  A function of its own, and jitted for the same
+    # reason: the older function's name and body are part of the six older
+    # steps' compile-cache keys and stay as they are
+    vocab = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    hit = vocab == targets[..., None]
+    label_logits = jnp.sum(jnp.where(hit, logits, 0.0), axis=-1)
+    return jax.nn.logsumexp(logits, axis=-1) - label_logits
+
+
+def token_loss_tail(logits, targets):
+    """Cross-entropy of ``logits`` [b, s, vocab] against the integer
+    ``targets`` [b, s], token by token ([b, s], float32): ``loss_tail``
+    before its mean, under the same scope (the head's area) and by the same
+    compare-and-sum."""
+    with phase_scope(LOSS_TAIL_SCOPE):
+        return _token_cross_entropy(logits, targets)
+
+
+def exit_distribution(gate_logits):
+    """The exit distribution of a looped model over its ``T`` passes, from
+    the gate logits ``[..., T]`` (float32): with ``lambda_t =
+    sigmoid(g_t)``, ``p_t = lambda_t * prod_{j<t} (1 - lambda_j)`` for ``t <
+    T`` and ``p_T = prod_{j<T} (1 - lambda_j)``, the mass that is left (the
+    last gate's own logit is not read).  Returns ``(p, log p)``, computed in
+    log space: the products are sums of ``log_sigmoid``, so no ``p_t`` that
+    underflows takes its logarithm with it."""
+    gates = gate_logits.astype(jnp.float32)
+    g = gates[..., :-1]                                # the gates that are read
+    # log prod_{j<t} (1 - lambda_j) for t = 1 .. T: zero, then the running sum
+    stayed = jnp.cumsum(jnp.concatenate(
+        [jnp.zeros_like(gates[..., :1]), jax.nn.log_sigmoid(-g)], axis=-1),
+        axis=-1)
+    log_p = jnp.concatenate(
+        [jax.nn.log_sigmoid(g) + stayed[..., :-1], stayed[..., -1:]], axis=-1)
+    return jnp.exp(log_p), log_p
+
+
+def looped_lm_loss_fn(model: TransformerLM, beta: float = 0.05):
+    """Next-token loss of a looped model with an exit gate
+    (``TransformerConfig(n_passes=T, exit_gate=True)``); batch =
+    dict(tokens=[b, s+1]).  The expected cross-entropy over the exits less
+    ``beta`` times the exit distribution's entropy, a mean over the tokens:
+
+        mean_i [ sum_t p_t(i) * CE(logits_t(i), target_i) - beta * H(p(i)) ]
+
+    (the entropy-regularised objective of looped language models'
+    pre-training, arXiv:2510.25741).  Each pass's cross-entropy stays per
+    token until it is weighed (``token_loss_tail``); the exit distribution,
+    its entropy and the weighting are float32 under the plain scope
+    ``exit_dist`` (area ``exit``).  With one pass this is ``lm_loss_fn``'s
+    number."""
+
+    def loss_fn(params, batch):
+        tokens = batch["tokens"]
+        logits, gates = model.apply({"params": params}, tokens[:, :-1])
+        nll = token_loss_tail(logits, tokens[None, :, 1:])  # [passes, b, s]
+        with phase_scope(EXIT_SCOPE):
+            p, log_p = exit_distribution(gates)
+            expected = jnp.sum(p * jnp.moveaxis(nll, 0, -1), axis=-1)
+            entropy = -jnp.sum(p * log_p, axis=-1)
+            return jnp.mean(expected - beta * entropy)
+
+    return loss_fn
 
 
 def lm_loss_fn(model: TransformerLM):

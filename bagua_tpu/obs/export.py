@@ -128,6 +128,14 @@ _declare("attn/window_layers", "gauge",
 _declare("attn/full_layers", "gauge",
          "Layers of that model with full causal attention (kernels "
          "flash_fwd / flash_bwd_dq / flash_bwd_dkv).")
+# -- looped stack (set when a TransformerLM step with n_passes > 1 is traced) --
+_declare("loop/passes", "gauge",
+         "Passes the looped model last traced makes over its stack of "
+         "layers (TransformerConfig.n_passes): the compiled step holds ONE "
+         "scanned body, which runs this many times a step.")
+_declare("loop/shared_layers", "gauge",
+         "Layers in that stack: each held once in the parameter tree and "
+         "run in every pass over the same weights.")
 # -- mixture of experts (set when a step with a dropless MoEMLP is traced) --
 _declare("moe/experts", "gauge",
          "Experts held by this rank in the MoE layer last traced.")

@@ -48,7 +48,8 @@ from typing import Any, Dict, List, Optional
 from .. import env as _env
 
 __all__ = ["trace_span", "trace_step_span", "phase_scope", "area_of",
-           "AREAS", "LOSS_TAIL_SCOPE", "ACCUM_SCOPE", "POS_EMBED_SCOPE",
+           "in_loop", "AREAS", "LOSS_TAIL_SCOPE", "ACCUM_SCOPE",
+           "POS_EMBED_SCOPE", "EXIT_SCOPE", "LOOP_SCOPE",
            "recorder", "span_ring", "SpanRecorder", "enabled", "set_enabled",
            "set_current_step", "set_ledger_sink"]
 
@@ -381,6 +382,14 @@ LOSS_TAIL_SCOPE = "loss_tail"
 ACCUM_SCOPE = "grad_accum"
 #: slice and add of ``TransformerLM``'s own learned position table
 POS_EMBED_SCOPE = "pos_embed"
+#: the exit distribution of a looped model over its passes, its entropy and
+#: the weighting of the passes' cross-entropies (``looped_lm_loss_fn``)
+EXIT_SCOPE = "exit_dist"
+#: the plain scope around the blocks of a looped model's pass
+#: (``TransformerConfig.n_passes``): a component of the path that names no
+#: area (the modules inside it do) and that no reader of the phases matches.
+#: The passes are one scanned body, so it names the body and not the pass
+LOOP_SCOPE = "loop_body"
 #: the scope whose NEXT component is the part of an expert layer
 MOE_SCOPE = "bagua.moe"
 MOE_PARTS = ("route", "dispatch", "experts", "combine")
@@ -390,13 +399,22 @@ MOE_PARTS = ("route", "dispatch", "experts", "combine")
 #: to it and ``docs/observability.md`` has it as a table.
 AREA_COMPONENTS = {
     "embed": "embed", POS_EMBED_SCOPE: "embed",
-    "attn_norm": "attn", "attn": "attn",
-    "mlp_norm": "mlp", "mlp": "mlp",
+    "attn_norm": "attn", "attn": "attn", "attn_post_norm": "attn",
+    "mlp_norm": "mlp", "mlp": "mlp", "mlp_post_norm": "mlp",
     "final_norm": "head", "lm_head": "head", LOSS_TAIL_SCOPE: "head",
+    "exit_gate": "exit", EXIT_SCOPE: "exit",
     ACCUM_SCOPE: "accum",
 }
 AREAS = ("embed", "attn", "mlp") + tuple(
-    f"moe/{part}" for part in MOE_PARTS) + ("head", "accum")
+    f"moe/{part}" for part in MOE_PARTS) + ("head", "exit", "accum")
+
+
+def in_loop(op_name: Optional[str]) -> bool:
+    """Whether an instruction's ``op_name`` path lies inside a looped
+    model's pass (a ``loop_body`` component), forward, backward or replay;
+    False for the heads, the exit gate and a model that is not looped.
+    Pure, like :func:`area_of`."""
+    return LOOP_SCOPE in (op_name or "").split("/")
 
 
 def area_of(op_name: Optional[str]) -> Optional[str]:

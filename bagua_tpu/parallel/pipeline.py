@@ -74,6 +74,12 @@ class PipelinedTransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens):
         cfg = self.cfg
+        if cfg.n_passes > 1 or cfg.exit_gate:
+            raise NotImplementedError(
+                "the pipelined stack runs its layers once and ends in one "
+                f"head: n_passes={cfg.n_passes} / exit_gate={cfg.exit_gate} "
+                "(a looped stack's passes under pipeline stages) are not "
+                "implemented")
         assert cfg.n_layers % self.pp_size == 0, (cfg.n_layers, self.pp_size)
         n_local = cfg.n_layers // self.pp_size
 

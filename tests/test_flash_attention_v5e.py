@@ -144,3 +144,31 @@ def test_d_lhs_compiles_on_the_stored_matrices(rows, groups, d, f, one_chip):
     stack = rf"bf16\[{groups},\d+,\d+\]"
     assert not [line for line in text.splitlines()
                 if re.search(rf"= {stack}\S* (copy|transpose)\(", line)]
+
+
+def test_the_weighed_token_tail_writes_no_float32_logits(one_chip):
+    """A looped model's head and per-token loss tail at Ouro's shape
+    (4,096 tokens x 49,152 rows), the cross-entropy weighed token by token
+    before the mean as ``looped_lm_loss_fn`` weighs it, forward and
+    backward: the compiled program holds the bf16 logits and no float32
+    array of their size, no gather and no scatter."""
+    from bagua_tpu.models.transformer import token_loss_tail
+
+    b, s, d, vocab = 1, 4096, 2048, 49152
+    shaped = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                       sharding=one_chip)
+
+    def loss(x, head, targets, weights):
+        logits = jnp.dot(x, head.astype(jnp.bfloat16)).astype(jnp.float32)
+        return jnp.mean(weights * token_loss_tail(logits, targets))
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        shaped((b, s, d), jnp.bfloat16), shaped((d, vocab), jnp.float32),
+        shaped((b, s), jnp.int32), shaped((b, s), jnp.float32),
+    ).compile().as_text()
+    entry = text[text.index("ENTRY "):]
+    results = re.findall(r"= \(?(\w+)\[([\d,]+)\]", entry)
+    logits_sized = {dtype for dtype, shape in results
+                    if shape.replace(",", "").endswith(f"{s}{vocab}")}
+    assert logits_sized == {"bf16"}, logits_sized
+    assert not re.search(r" (gather|scatter)\(", text)

@@ -172,6 +172,23 @@ AREA_PATHS = [
     ("jit(bagua_step)/transpose(jvp(bagua.loss))/loss_tail/"
      "jit(take_along_axis)/scatter-add", "head"),
     ("jit(bagua_step)/while/body/closed_call/grad_accum/add", "accum"),
+    # a looped model: the pass's scope names no area and hides none; the
+    # norm that closes a pass, the heads and the exits lie outside it
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/while/body/loop_body/"
+     "block_3/attn/q/dot_general", "attn"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/while/body/"
+     "loop_body/checkpoint/rematted_computation/block_3/mlp_post_norm/mul",
+     "mlp"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/while/body/loop_body/"
+     "block_0/attn_post_norm/rsqrt", "attn"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/while/body/loop_body/"
+     "block_0/add", None),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/while/body/final_norm/"
+     "mul", "head"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/exit_gate/dot_general",
+     "exit"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/exit_dist/jit(log_sigmoid)/"
+     "logistic", "exit"),
     # an expert layer's scope decides, whatever module it sits under
     ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_1/mlp/bagua.moe/"
      "experts/gmm_fwd/pallas_call", "moe/experts"),
@@ -210,7 +227,14 @@ def lm_trainer(kind, **trainer_kw):
                max_seq_len=16)
     if kind == "remat":
         cfg.update(remat=True, remat_policy="dots_no_batch")
-    if kind == "moe":
+    if kind == "looped":
+        from bagua_tpu.models.transformer import looped_lm_loss_fn
+
+        cfg.update(rope_theta=1e6, n_passes=3, post_norms=True,
+                   exit_gate=True, remat=True)
+        model = TransformerLM(TransformerConfig(**cfg))
+        loss_fn = looped_lm_loss_fn(model)
+    elif kind == "moe":
         cfg.update(rope_theta=10000.0)
         moe = lambda: MoEMLP(n_experts=4, d_ff=32, k=2, dropless=True,
                              gated=True, name="mlp")
@@ -241,7 +265,9 @@ MOE_AREAS = {"moe/route", "moe/dispatch", "moe/experts", "moe/combine"}
     ("moe", {}, DENSE_AREAS | MOE_AREAS),
     ("dense", {"accum_steps": 4, "overlap": "on"}, DENSE_AREAS | {"accum"}),
     ("dense", {"accum_steps": 4, "overlap": "off"}, DENSE_AREAS | {"accum"}),
-], ids=["dense", "remat", "moe", "accum4-overlap", "accum4-serial"])
+    ("looped", {}, DENSE_AREAS | {"exit"}),
+], ids=["dense", "remat", "moe", "accum4-overlap", "accum4-serial",
+        "looped"])
 def test_compiled_step_names_its_areas(kind, trainer_kw, areas):
     trainer, state, batch = lm_trainer(kind, **trainer_kw)
     paths = [p for _, _, p in
@@ -253,6 +279,21 @@ def test_compiled_step_names_its_areas(kind, trainer_kw, areas):
     if kind == "dense":
         # the learned position table is no module's: its own plain scope
         assert has(paths, f"/{obs_spans.POS_EMBED_SCOPE}/")
+    inside = [p for p in paths if obs_spans.in_loop(p)]
+    assert bool(inside) == (kind == "looped")
+    if kind == "looped":
+        # the passes are one body: forward in one loop, replay and backward
+        # in another; what is inside is the trunk and only the trunk
+        assert has(inside, "jvp(bagua.loss)", without=("transpose(",))
+        assert has(inside, "transpose(jvp(bagua.loss))",
+                   "rematted_computation")
+        assert {obs_spans.area_of(p) for p in inside} == {"attn", "mlp",
+                                                          None}
+        exits = [p for p in paths if obs_spans.area_of(p) == "exit"]
+        assert has(exits, "/exit_gate/") and has(
+            exits, f"/{obs_spans.EXIT_SCOPE}/")
+        assert {m for p in inside + exits
+                for m in re.findall(r"bagua\.\w+", p)} == {"bagua.loss"}
     # the float32 cast of the logits is the head's, not bare TransformerLM's
     assert has(paths, "/lm_head/convert_element_type")
     assert not has(paths, "TransformerLM/convert_element_type")
@@ -272,9 +313,12 @@ def test_compiled_step_names_its_areas(kind, trainer_kw, areas):
 
 def test_the_plain_scopes_are_no_phase_scopes():
     for scope in (obs_spans.LOSS_TAIL_SCOPE, obs_spans.ACCUM_SCOPE,
-                  obs_spans.POS_EMBED_SCOPE):
+                  obs_spans.POS_EMBED_SCOPE, obs_spans.EXIT_SCOPE):
         assert not re.search(r"bagua\.\w+", scope)
         assert obs_spans.AREA_COMPONENTS[scope] in obs_spans.AREAS
+    # a pass's scope is no phase and no area: the modules inside it name one
+    assert not re.search(r"bagua\.\w+", obs_spans.LOOP_SCOPE)
+    assert obs_spans.LOOP_SCOPE not in obs_spans.AREA_COMPONENTS
 
 
 def parent_tail(logits, targets):
