@@ -177,6 +177,34 @@ def bert_large_config(**kw) -> TransformerConfig:
     return TransformerConfig(**defaults)
 
 
+class TokenEmbed(nn.Module):
+    """The token table: ``nn.Embed``'s parameter (``embedding``, ``[vocab,
+    features]``, the same initialiser: a tree or checkpoint made with
+    ``nn.Embed`` under the same name loads as it is) and its forward to the
+    bit, by :func:`bagua_tpu.ops.embed_grad.token_lookup`: the float32 rows
+    are gathered and THEN rounded to ``dtype``, where ``nn.Embed`` casts the
+    whole table for a few thousand rows of it, a decode step's one row too.
+    Where the kernel runs (on the TPU, whole lane tiles) the table's
+    gradient is the ``embed_grad`` segment product, float32 sums rounded
+    once, and not XLA's row-by-row ``scatter`` with its bf16 adds; elsewhere
+    ``jnp.take``'s own transpose."""
+    num_embeddings: int
+    features: int
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.float32
+
+    @nn.compact
+    def __call__(self, tokens):
+        from ..ops.embed_grad import token_lookup
+
+        if not jnp.issubdtype(tokens.dtype, jnp.integer):
+            raise ValueError("tokens must be integers")
+        table = self.param(
+            "embedding", nn.linear.default_embed_init,
+            (self.num_embeddings, self.features), self.param_dtype)
+        return token_lookup(table, tokens, self.dtype)
+
+
 class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
@@ -575,7 +603,7 @@ class TransformerLM(nn.Module):
                 "`slots` is only meaningful for paged decode configs "
                 "(decode=True, page_size > 0)"
             )
-        x = nn.Embed(
+        x = TokenEmbed(
             cfg.vocab_size, cfg.d_model, name="embed",
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
         )(tokens)
@@ -624,6 +652,7 @@ class TransformerLM(nn.Module):
         if not self.is_initializing() and not cfg.decode:
             # trace-time facts of this model's step, for the operator and
             # the benchmark's readers of the windowed kernels
+            from ..ops.embed_grad import grad_kernel_supported
             from ..telemetry import counters
 
             windowed = sum(cfg.layer_window(i) is not None
@@ -632,6 +661,10 @@ class TransformerLM(nn.Module):
             counters.set_gauge("attn/window", cfg.window or 0)
             counters.set_gauge("attn/window_layers", windowed)
             counters.set_gauge("attn/full_layers", cfg.n_layers - windowed)
+            # 1: this step's token-table gradient is the ``embed_grad``
+            # kernel; 0: it fell back to the gather's own transpose
+            counters.set_gauge("embed/grad_kernel",
+                               int(grad_kernel_supported(cfg.d_model)))
             if cfg.n_passes > 1:
                 counters.set_gauge("loop/passes", cfg.n_passes)
                 counters.set_gauge("loop/shared_layers", cfg.n_layers)

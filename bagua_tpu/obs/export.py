@@ -128,6 +128,12 @@ _declare("attn/window_layers", "gauge",
 _declare("attn/full_layers", "gauge",
          "Layers of that model with full causal attention (kernels "
          "flash_fwd / flash_bwd_dq / flash_bwd_dkv).")
+# -- token table (set when a TransformerLM step is traced) --
+_declare("embed/grad_kernel", "gauge",
+         "1 where the token table's gradient in the model last traced is "
+         "the embed_grad Pallas kernel (a segment product over the sorted "
+         "tokens: on the TPU, a table of whole 128-lane rows), 0 where it "
+         "fell back to the row gather's own transpose, XLA's scatter-add.")
 # -- looped stack (set when a TransformerLM step with n_passes > 1 is traced) --
 _declare("loop/passes", "gauge",
          "Passes the looped model last traced makes over its stack of "

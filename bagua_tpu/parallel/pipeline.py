@@ -28,7 +28,9 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..models.transformer import Block, RMSNorm, TransformerConfig
+from ..models.transformer import (
+    Block, RMSNorm, TokenEmbed, TransformerConfig,
+)
 from .mesh import axis_bound as _axis_bound
 
 
@@ -83,8 +85,8 @@ class PipelinedTransformerLM(nn.Module):
         assert cfg.n_layers % self.pp_size == 0, (cfg.n_layers, self.pp_size)
         n_local = cfg.n_layers // self.pp_size
 
-        embed = nn.Embed(cfg.vocab_size, cfg.d_model, name="embed",
-                         dtype=cfg.dtype, param_dtype=cfg.param_dtype)
+        embed = TokenEmbed(cfg.vocab_size, cfg.d_model, name="embed",
+                           dtype=cfg.dtype, param_dtype=cfg.param_dtype)
         pos = self.param("pos_embed", nn.initializers.normal(0.02),
                          (cfg.max_seq_len, cfg.d_model), cfg.param_dtype)
         block_cls = nn.remat(_ScanBlock) if cfg.remat else _ScanBlock

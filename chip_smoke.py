@@ -97,6 +97,7 @@ def sizes(dryrun: bool) -> dict:
             batch_per_chip=2,
             flash=dict(b=1, s=256, h=2, d=64),
             gmm=dict(rows=512, d=128, f=256, groups=4),
+            embed=dict(vocab=640, tokens=300, d=128),
             # (elements per chunk) one fused-path and one tiled-path size
             codec_chunks=(4096, 2048 * 128 + 4096),
             ring_chunk_bytes=256,
@@ -110,6 +111,7 @@ def sizes(dryrun: bool) -> dict:
         batch_per_chip=8,
         flash=dict(b=2, s=4096, h=16, d=64),          # bench_longctx
         gmm=dict(rows=8192, d=512, f=2048, groups=8),  # bench_moe_dropless
+        embed=dict(vocab=30528, tokens=3072, d=1024),  # BERT-Large's table
         # 1 MiB f32 chunks (fused, the gate's floor) and 2.5 MiB (tiled: a
         # 10 MiB bucket over 4 ranks)
         codec_chunks=(1 << 18, 5 << 17),
@@ -249,6 +251,29 @@ def leg_kernels(sz: dict, dryrun: bool) -> None:
         f"G={g['groups']}: max rel err = {[round(e, 4) for e in errs]}  "
         f"({time.perf_counter() - t0:.1f}s)")
     assert all(np.isfinite(e) and e < 3e-2 for e in errs), errs
+
+    # ---- token table gradient: sort + gather + segment product ----------
+    from bagua_tpu.ops.embed_grad import embed_grad
+
+    e = sz["embed"]
+    ki, kr = jax.random.split(jax.random.PRNGKey(SEED + 2))
+    # a tenth of the table: most tokens share their row with another
+    ids = jax.random.randint(ki, (e["tokens"],), 0, e["vocab"] // 10,
+                             jnp.int32)
+    rows = jax.random.normal(kr, (e["tokens"], e["d"]), jnp.bfloat16)
+    kern = lambda ids, rows: embed_grad(ids, rows, vocab=e["vocab"],
+                                        interpret=interp)
+    assert uses_pallas(kern, ids, rows)
+    t0 = time.perf_counter()
+    got = jax.jit(kern)(ids, rows)
+    want = jnp.zeros((e["vocab"], e["d"]), jnp.float32).at[ids].add(
+        rows.astype(jnp.float32))
+    err = rel_err(got, want)
+    log(f"kernel embed_grad vocab={e['vocab']} tokens={e['tokens']} "
+        f"d={e['d']}: max rel err = {err:.5f}  "
+        f"({time.perf_counter() - t0:.1f}s)")
+    # one bf16 rounding of the float32 sum: at most 2^-8 of the largest
+    assert np.isfinite(err) and err < 2.0 ** -7, err
 
     # ---- codec kernels, fused and tiled, f32 and bf16 --------------------
     n_chunks = 4
