@@ -238,6 +238,22 @@ def rope_rotate(x, theta: float, start=0):
     return (x32 * cos + rotated * sin).astype(x.dtype)
 
 
+def rotates_by_kernel(cfg: TransformerConfig, seq: int, attn_fn=None) -> bool:
+    """Whether a rotary layer of ``cfg`` rotates q and k at ``seq`` positions
+    by the ``rope`` kernel (:mod:`bagua_tpu.ops.rope`): where the flash
+    kernels run, one pass over the ``[b, s, h * d]`` that ``HeadsDense``
+    writes and they read, on heads of whole 128-lane tiles.  Everywhere else
+    (off the TPU, short or ragged sequences, head_dim 64, the einsum path,
+    an ``attn_fn`` drop-in, decode) :func:`rope_rotate`."""
+    from ..ops.flash_attention import flash_supported
+    from ..ops.rope import rope_supported
+
+    return (attn_fn is None and not cfg.decode
+            and rope_supported(seq, cfg.head_dim)
+            and flash_supported(seq, cfg.n_heads // cfg.tp_size, cfg.head_dim,
+                                kv_heads=cfg.kv_heads // cfg.tp_size))
+
+
 def causal_attention(q, k, v, dtype, window=None):
     """Causal attention; softmax in f32, matmuls in ``dtype``.
 
@@ -387,8 +403,10 @@ class Attention(nn.Module):
             start = 0
             if cfg.sp_axis is not None and _axis_bound(cfg.sp_axis):
                 start = jax.lax.axis_index(cfg.sp_axis) * q.shape[1]
-            q = rope_rotate(q, cfg.rope_theta, start)
-            k = rope_rotate(k, cfg.rope_theta, start)
+            rotate = rope_rotate
+            if rotates_by_kernel(cfg, q.shape[1], self.attn_fn):
+                from ..ops.rope import rope as rotate
+            q, k = (rotate(t, cfg.rope_theta, start) for t in (q, k))
         if cfg.decode and cfg.page_size > 0:
             o = self._paged_decode_attend(q, k, v, slots)
         elif cfg.decode:
@@ -661,6 +679,10 @@ class TransformerLM(nn.Module):
             counters.set_gauge("attn/window", cfg.window or 0)
             counters.set_gauge("attn/window_layers", windowed)
             counters.set_gauge("attn/full_layers", cfg.n_layers - windowed)
+            # the rotary layers whose rotation is the ``rope`` kernel
+            by_kernel = rotates_by_kernel(cfg, tokens.shape[1], self.attn_fn)
+            counters.set_gauge("attn/rope_kernel_layers", by_kernel * sum(
+                cfg.layer_rotary(i) for i in range(cfg.n_layers)))
             # 1: this step's token-table gradient is the ``embed_grad``
             # kernel; 0: it fell back to the gather's own transpose
             counters.set_gauge("embed/grad_kernel",

@@ -128,6 +128,12 @@ _declare("attn/window_layers", "gauge",
 _declare("attn/full_layers", "gauge",
          "Layers of that model with full causal attention (kernels "
          "flash_fwd / flash_bwd_dq / flash_bwd_dkv).")
+_declare("attn/rope_kernel_layers", "gauge",
+         "Rotary layers of that model's step whose rotation of q and k is "
+         "the rope Pallas kernel (one pass over [batch, seq, heads * "
+         "head_dim] where the flash kernels run, heads of whole 128-lane "
+         "tiles); a looped model's scanned body counts once.  0 where "
+         "every rotary layer took rope_rotate, or none rotates.")
 # -- token table (set when a TransformerLM step is traced) --
 _declare("embed/grad_kernel", "gauge",
          "1 where the token table's gradient in the model last traced is "

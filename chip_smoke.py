@@ -98,6 +98,7 @@ def sizes(dryrun: bool) -> dict:
             flash=dict(b=1, s=256, h=2, d=64),
             gmm=dict(rows=512, d=128, f=256, groups=4),
             embed=dict(vocab=640, tokens=300, d=128),
+            rope=dict(b=1, s=256, h=2, d=128),
             # (elements per chunk) one fused-path and one tiled-path size
             codec_chunks=(4096, 2048 * 128 + 4096),
             ring_chunk_bytes=256,
@@ -112,6 +113,7 @@ def sizes(dryrun: bool) -> dict:
         flash=dict(b=2, s=4096, h=16, d=64),          # bench_longctx
         gmm=dict(rows=8192, d=512, f=2048, groups=8),  # bench_moe_dropless
         embed=dict(vocab=30528, tokens=3072, d=1024),  # BERT-Large's table
+        rope=dict(b=1, s=4096, h=16, d=128),           # Ouro's q and k
         # 1 MiB f32 chunks (fused, the gate's floor) and 2.5 MiB (tiled: a
         # 10 MiB bucket over 4 ranks)
         codec_chunks=(1 << 18, 5 << 17),
@@ -274,6 +276,33 @@ def leg_kernels(sz: dict, dryrun: bool) -> None:
         f"({time.perf_counter() - t0:.1f}s)")
     # one bf16 rounding of the float32 sum: at most 2^-8 of the largest
     assert np.isfinite(err) and err < 2.0 ** -7, err
+
+    # ---- RoPE's rotation: one pass over [b, s, h * d], and its VJP ------
+    from bagua_tpu.models.transformer import rope_rotate
+    from bagua_tpu.ops.rope import rope
+
+    r = sz["rope"]
+    kx, kg = jax.random.split(jax.random.PRNGKey(SEED + 3))
+    x = jax.random.normal(kx, (r["b"], r["s"], r["h"], r["d"]), jnp.bfloat16)
+    gw = jax.random.normal(kg, x.shape, jnp.float32)
+
+    def rope_loss(rotate):
+        def loss(x):
+            o = rotate(x)
+            return (o.astype(jnp.float32) * gw).sum(), o
+        return loss
+
+    kern = rope_loss(lambda x: rope(x, 1e6, 7, interpret=interp))
+    ref = rope_loss(lambda x: rope_rotate(x, 1e6, 7))
+    assert uses_pallas(lambda x: kern(x)[0], x)
+    t0 = time.perf_counter()
+    (_, o_k), g_k = jax.jit(jax.value_and_grad(kern, has_aux=True))(x)
+    (_, o_r), g_r = jax.jit(jax.value_and_grad(ref, has_aux=True))(x)
+    errs = [rel_err(o_k, o_r), rel_err(g_k, g_r)]
+    log(f"kernel rope fwd+vjp {x.shape}: max rel err (o, dx) = "
+        f"{[round(e, 5) for e in errs]}  ({time.perf_counter() - t0:.1f}s)")
+    # one bf16 rounding either way: a bf16 ulp of the largest element
+    assert all(np.isfinite(e) and e < 2.0 ** -7 for e in errs), errs
 
     # ---- codec kernels, fused and tiled, f32 and bf16 --------------------
     n_chunks = 4

@@ -79,6 +79,8 @@ MODULES = [
     "bagua_tpu.serve.schema",
     "bagua_tpu.ops.flash_attention",
     "bagua_tpu.ops.gmm",
+    "bagua_tpu.ops.embed_grad",
+    "bagua_tpu.ops.rope",
     "bagua_tpu.ops.tiles",
     "bagua_tpu.compression.codecs",
     "bagua_tpu.compression.minmax_uint8",

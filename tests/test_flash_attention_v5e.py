@@ -172,3 +172,81 @@ def test_the_weighed_token_tail_writes_no_float32_logits(one_chip):
                     if shape.replace(",", "").endswith(f"{s}{vocab}")}
     assert logits_sized == {"bf16"}, logits_sized
     assert not re.search(r" (gather|scatter)\(", text)
+
+
+def _unfused(text):
+    """``(name, shape, opcode, op_name)`` of the instructions outside every
+    fused computation: what the chip runs as instructions of their own."""
+    fused = set(re.findall(r"calls=%([\w.\-]+)", text))
+    computation, out = None, []
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(", line)
+        if head:
+            computation = head.group(1)
+        found = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        if found and computation not in fused:
+            op_name = re.search(r'op_name="([^"]*)"', line)
+            out.append((*found.groups(), op_name.group(1) if op_name else "",
+                        line))
+    return out
+
+
+@pytest.mark.parametrize("s, h, kv_h, d_model", [
+    (4096, 16, 16, 2048),   # ouro-2.6b.pretrain4096-b1-dp1 (and OLMoE's)
+    (8192, 28, 4, 2560),    # smallthinker-21b-a3b.pretrain8192-dp1: grouped
+])
+def test_a_rotary_layer_rotates_q_and_k_as_the_projections_write_them(
+        s, h, kv_h, d_model, one_chip, monkeypatch):
+    """One rotary ``Attention`` layer, forward and backward: the rotation
+    is four ``rope`` calls (q, k; each way) on the row-major ``[b, s, h *
+    d]`` between the projections and the flash kernels, and the program
+    holds no instruction of its own that re-lays q or k around them — the
+    slice / negate / ``concatenate`` form left a dozen bare float32
+    ``copy`` / ``reshape`` / ``broadcast`` a layer and made the projections
+    write sequence-minor (PERF.md §6, PR 44)."""
+    import importlib
+
+    from bagua_tpu.models.transformer import Attention, TransformerConfig
+
+    # flash_supported asks jax.default_backend(), which is still the CPU
+    # here: steered in the test, not by an option of the program
+    flash = importlib.import_module("bagua_tpu.ops.flash_attention")
+    monkeypatch.setattr(flash.jax, "default_backend", lambda: "tpu")
+    layer = Attention(TransformerConfig(
+        vocab_size=128, d_model=d_model, n_heads=h, n_kv_heads=kv_h,
+        d_head=128, n_layers=1, d_ff=128, max_seq_len=s, rope_theta=1e6))
+    shaped = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                            sharding=one_chip)
+    x = jax.ShapeDtypeStruct((1, s, d_model), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(shaped, jax.eval_shape(
+        layer.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        return jnp.sum(layer.apply(params, x).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    ops = _unfused(text)
+    by_name = {name: shape for name, shape, *_ in ops}
+    calls = [(shape, line) for _, shape, opcode, op_name, line in ops
+             if opcode == "custom-call" and op_name.endswith(
+                 "jit(_rotate)/rope/pallas_call")]
+    assert len(calls) == 4
+    row_major = lambda shape: re.search(r"\{([\d,]+)", shape).group(1) in (
+        "2,1,0", "1,0")
+    for shape, line in calls:
+        lanes = int(re.match(r"bf16\[1,\d+,(\d+)\]", shape).group(1))
+        assert lanes in (h * 128, kv_h * 128), shape
+        assert row_major(shape), line
+        # what the call reads: a projection's (or the flash backward's)
+        # result as it was written, through bitcasts alone
+        source = re.search(r"custom-call\(%([\w.\-]+)", line).group(1)
+        assert row_major(by_name[source]), (source, by_name[source])
+    # nothing of its own re-lays an activation (a tensor with the sequence
+    # among its axes) around the rotation: what is left bare belongs to the
+    # flash kernels' statistic rows
+    bare = [(opcode, shape, op_name) for _, shape, opcode, op_name, _ in ops
+            if opcode in ("copy", "reshape", "broadcast", "transpose")
+            and str(s) in re.match(r"\w+\[([\d,]*)\]", shape).group(1).split(",")
+            and not re.search(r"jit\(_(fwd|bwd)\)", op_name)]
+    assert not bare, bare

@@ -189,6 +189,22 @@ AREA_PATHS = [
      "exit"),
     ("jit(bagua_step)/transpose(jvp(bagua.loss))/exit_dist/jit(log_sigmoid)/"
      "logistic", "exit"),
+    # the rope kernel (ops/rope.py): forward, replay and backward of a
+    # looped, rematted step as the TPU compiler names them (Ouro's), and
+    # of a plain one (OLMoE's)
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/while/body/closed_call/"
+     "TransformerLM.one_pass/loop_body/block_3/attn/jit(_rotate)/rope/"
+     "pallas_call", "attn"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/while/body/"
+     "closed_call/TransformerLM.one_pass/loop_body/TransformerLM.one_pass/"
+     "loop_body/checkpoint/rematted_computation/block_3/attn/jit(_rotate)/"
+     "rope/pallas_call", "attn"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/while/body/"
+     "closed_call/TransformerLM.one_pass/loop_body/TransformerLM.one_pass/"
+     "loop_body/checkpoint/block_3/attn/jit(_rotate)/rope/pallas_call",
+     "attn"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/block_0/attn/"
+     "jit(_rotate)/rope/pallas_call", "attn"),
     # an expert layer's scope decides, whatever module it sits under
     ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_1/mlp/bagua.moe/"
      "experts/gmm_fwd/pallas_call", "moe/experts"),
@@ -612,7 +628,7 @@ def test_a_span_without_jax_mirrors_nothing(obs_on, monkeypatch):
 
 KERNEL_FILES = ("bagua_tpu/ops/flash_attention.py", "bagua_tpu/ops/gmm.py",
                 "bagua_tpu/compression/pallas_codec.py",
-                "bagua_tpu/ops/embed_grad.py")
+                "bagua_tpu/ops/embed_grad.py", "bagua_tpu/ops/rope.py")
 
 
 def pallas_call_names(path):
@@ -635,7 +651,7 @@ def pallas_call_names(path):
     return names
 
 
-@pytest.mark.parametrize("path, count", zip(KERNEL_FILES, (6, 2, 9, 1)))
+@pytest.mark.parametrize("path, count", zip(KERNEL_FILES, (6, 2, 9, 1, 1)))
 def test_every_pallas_call_has_a_literal_name(path, count):
     names = pallas_call_names(path)
     assert len(names) == count
@@ -648,6 +664,45 @@ def test_every_pallas_call_has_a_literal_name(path, count):
         assert names == ["flash_fwd", "flash_win_fwd", "flash_bwd_dkv",
                          "flash_win_bwd_dkv", "flash_bwd_dq",
                          "flash_win_bwd_dq"]
+
+
+#: configuration -> rotary layers of its step on the ``rope`` kernel: the
+#: four architectures of the benchmark's cells at tiny widths, heads of 128
+ROPE_KERNEL_LAYERS = [
+    ("ouro", dict(n_layers=8, rope_theta=1e6, n_passes=4, post_norms=True,
+                  exit_gate=True, remat=True), 8),
+    ("smallthinker", dict(n_layers=4, rope_theta=1.5e6, n_kv_heads=1,
+                          window=64, window_layers=(0, 1, 1, 1),
+                          rope_layers=(0, 1, 1, 1), remat=True,
+                          remat_policy="dots_no_batch"), 3),
+    ("olmoe", dict(n_layers=1, rope_theta=1e4, qk_norm=True), 1),
+    ("gpt2", dict(n_layers=2), 0),
+]
+
+
+@pytest.mark.parametrize("forced", [True, False], ids=["forced", "off-tpu"])
+@pytest.mark.parametrize("name, kw, layers", ROPE_KERNEL_LAYERS,
+                         ids=[c[0] for c in ROPE_KERNEL_LAYERS])
+def test_the_gauge_counts_the_layers_on_the_rope_kernel(name, kw, layers,
+                                                        forced, monkeypatch):
+    """``attn/rope_kernel_layers``, set where the step is traced: a looped
+    model's scanned body counts once, a NoPE layer not at all, and off the
+    TPU no layer reaches the kernel.  Traced, not run (``eval_shape``)."""
+    import importlib
+
+    from bagua_tpu.models.transformer import TransformerConfig, TransformerLM
+
+    if forced:    # the gate's platform test, steered in the test
+        flash = importlib.import_module("bagua_tpu.ops.flash_attention")
+        monkeypatch.setattr(flash, "flash_supported", lambda *a, **kw: True)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, d_model=128, n_heads=2, d_head=128, d_ff=128,
+        max_seq_len=128, **kw))
+    tokens = jnp.zeros((1, 128), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    counters.set_gauge("attn/rope_kernel_layers", -1)
+    jax.eval_shape(model.apply, params, tokens)
+    assert counters.get("attn/rope_kernel_layers") == layers * forced
 
 
 def test_no_pallas_call_outside_the_named_files():
