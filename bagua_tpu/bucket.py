@@ -21,8 +21,12 @@ out of the flat) and backward (the gradient's ravel-and-pad back into it) —
 (PERF.md, PR 33).  Buckets exist to fuse SMALL tensors into one collective, so:
 
 - :func:`split_bucket_by_bucket_size` gives a tensor of at least
-  ``bucket_size`` bytes a bucket of its own — packing it with a norm scale
-  buys the collective nothing;
+  ``min(bucket_size, LONE_TENSOR_BYTES)`` bytes (1 MiB, unless the caller's
+  ``bucket_size`` is smaller) a bucket of its own — packing it with a norm
+  scale buys the collective nothing, and XLA's combiner re-fuses the
+  declared buckets into the all-reduces it wants whatever their number
+  (bert-large on four chips: 125 declared buckets compile to 16
+  all-reduces, 220 to 15, the wire's bytes equal; PERF.md, PR 45);
 - a bucket of one tensor and no padding is *shaped*
   (:attr:`BucketSpec.shaped`): its buffer IS that tensor, in the tensor's own
   shape.  Parameters, gradients and optimizer state held bucket-flat keep it;
@@ -59,6 +63,17 @@ from .tensor import NamedParam, leaves_by_name
 from .utils import from_bagua_datatype
 
 
+#: A tensor of this many bytes stands alone whatever ``bucket_size`` says.
+#: Buckets exist to fuse SMALL tensors into one collective; a tensor of a
+#: megabyte fills one by itself, XLA's combiner re-fuses the declared buckets
+#: whatever their number, and what a flat costs such a tensor on a TPU is a
+#: re-tiling copy each way, an update pass of its own and, under
+#: ``accum_steps > 1``, an accumulation add of its own (module docstring).
+#: :class:`TensorDeclaration` carries bytes and no shape, so the rule reads
+#: bytes; a ``bucket_size`` under the floor is the threshold itself, as ever.
+LONE_TENSOR_BYTES = 1 << 20
+
+
 def split_bucket_by_bucket_size(
     tensor_list: List[TensorDeclaration],
     bucket_size: int,
@@ -68,10 +83,12 @@ def split_bucket_by_bucket_size(
     (autotune_task_manager.py:86-119): iterate dtypes in sorted order, fill a
     bucket until it reaches ``bucket_size`` bytes, then start a new one.
 
-    One departure: a tensor of at least ``bucket_size`` bytes closes the open
-    bucket first and stands alone.  It already fills a collective; glued to
-    its small neighbours it could not keep its own shape (module docstring)."""
+    One departure: a tensor of at least ``min(bucket_size,
+    LONE_TENSOR_BYTES)`` bytes closes the open bucket first and stands alone.
+    It already fills a collective; glued to its small neighbours it could not
+    keep its own shape (module docstring)."""
     param_group_info = param_group_info or {}
+    lone_bytes = min(bucket_size, LONE_TENSOR_BYTES)
     dtypes = sorted({TensorDtype(t.dtype).value for t in tensor_list})
     buckets: List[List[TensorDeclaration]] = []
     for dtype in dtypes:
@@ -81,7 +98,7 @@ def split_bucket_by_bucket_size(
         tmp: List[TensorDeclaration] = []
         tmp_bytes = 0
         for td in [t for t in tensor_list if TensorDtype(t.dtype).value == dtype]:
-            if td.nbytes >= bucket_size:
+            if td.nbytes >= lone_bytes:
                 if tmp:
                     buckets.append(tmp)
                 buckets.append([td])

@@ -761,3 +761,147 @@ def test_shaped_bytes_share_gauge_is_set_with_the_step_program():
     trainer.train_step(state, _batches(1)[0])
     assert not _shaped(trainer)
     assert counters.get("comm/shaped_bytes_share") == 0
+
+
+# ---- a tensor over the floor stands alone under any bucket_bytes -----------
+# (bucket.LONE_TENSOR_BYTES is a megabyte; the toys' matrices are hundreds of
+# bytes, so the tests lower the floor under them — and lift it out of reach
+# for "the plan the old rule built")
+
+OLD_RULE = 1 << 62   # min(bucket_size, floor) == bucket_size: PR 33's rule
+
+
+def _set_floor(monkeypatch, nbytes):
+    from bagua_tpu import bucket
+
+    monkeypatch.setattr(bucket, "LONE_TENSOR_BYTES", nbytes)
+
+
+def _named_state(trainer, state):
+    """Parameters and both moments as named tensors, whatever the plan."""
+    plan = trainer._plan
+    named = {}
+    for kind, tree in (("p", state.params), ("mu", state.opt_state[0].mu),
+                       ("nu", state.opt_state[0].nu)):
+        for name, x in plan.unflatten_to_named(tree["flats"]).items():
+            named[kind + ":" + name] = np.asarray(x)
+    return named
+
+
+@pytest.mark.parametrize("saved_under", ["old_rule", "new_rule"])
+def test_restore_across_the_lone_tensor_floor(tmp_path, monkeypatch,
+                                              saved_under):
+    """A flat-resident state saved under a plan the old rule built (both MLP
+    kernels inside one 1-D flat with the biases) restores under the new
+    plan (each kernel a buffer in its own shape) to the same named tensors
+    — parameters and both moments — through the changed-plan path the
+    sidecar's layout drives; and a state saved now restores now, into the
+    identical plan, to the same program's bits."""
+
+    def make(floor):
+        _set_floor(monkeypatch, floor)
+        trainer = BaguaTrainer(
+            _loss_fn, optax.adam(1e-2), GradientAllReduceAlgorithm(),
+            bucket_bytes=4096, autotune=False, flat_resident="on")
+        return trainer, trainer.init(_params())
+
+    batches = _batches(6)
+    t_ref, s_ref = make(256)
+    base = []
+    for b in batches:
+        s_ref, loss = t_ref.train_step(s_ref, b)
+        base.append(float(loss))
+
+    t1, s1 = make(OLD_RULE if saved_under == "old_rule" else 256)
+    for b in batches[:3]:
+        s1, _ = t1.train_step(s1, b)
+    mgr = BaguaCheckpointManager(str(tmp_path / "ckpt"), async_save=False)
+    assert t1.save_checkpoint(mgr, 3, s1)
+    mgr.wait()
+    saved = _named_state(t1, s1)
+
+    t2, s2_init = make(256)
+    # (a bias left alone between two lone kernels is its own 1-D buffer)
+    assert {"dense_0.kernel", "dense_1.kernel"} <= set(_shaped(t2))
+    if saved_under == "old_rule":
+        assert not _shaped(t1) and len(t1._plan.buckets) == 1
+        assert t1._plan.signature() != t2._plan.signature()
+    else:
+        assert t1._plan.signature() == t2._plan.signature()
+    step, s2 = t2.restore_checkpoint(mgr, s2_init)
+    assert step == 3
+    assert [f.shape for f in s2.params["flats"]] == [
+        b.buffer_shape for b in t2._plan.buckets]
+    restored = _named_state(t2, s2)
+    assert sorted(restored) == sorted(saved)
+    for name, want in saved.items():
+        np.testing.assert_array_equal(restored[name], want, err_msg=name)
+    tail = []
+    for b in batches[3:]:
+        s2, loss = t2.train_step(s2, b)
+        tail.append(float(loss))
+    if saved_under == "new_rule":
+        np.testing.assert_array_equal(np.array(tail), np.array(base[3:]))
+    else:  # the first three steps ran another program
+        np.testing.assert_allclose(np.array(tail), np.array(base[3:]),
+                                   rtol=1e-6, atol=0)
+    mgr.close()
+
+
+def _attention_toy():
+    from bagua_tpu.models.transformer import (
+        TransformerConfig, TransformerLM, lm_loss_fn,
+    )
+
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, d_model=32, n_heads=2, n_layers=2, d_ff=64,
+        max_seq_len=16, dtype=jnp.float32))
+    params = model.init(jax.random.PRNGKey(0),
+                        jnp.zeros((1, 8), jnp.int32))["params"]
+    return lm_loss_fn(model), params
+
+
+@pytest.mark.parametrize("accum", [1, 4])
+@pytest.mark.parametrize("family", ["allreduce", "zero"])
+def test_the_lone_tensor_plan_trains_the_old_rules_trajectory(
+        monkeypatch, family, accum):
+    """A two-layer LM whose ``[32, 2, 16]`` attention kernels (4 KiB) cross
+    a floor lowered to 4 KiB: under 64 KiB buckets the old rule packs them
+    into flats with the norm scales, the new plan holds each in its own
+    shape.  Three steps under either plan are the same training run to
+    float32 round-off — through the plain step and the ``lax.scan``
+    accumulation (with its one-time readiness re-bucketing), for the fused
+    all-reduce and for ZeRO, which cuts every bucket into per-rank chunks
+    and ravels a shaped buffer where it cuts."""
+    loss_fn, params = _attention_toy()
+    rng = np.random.default_rng(5)
+    batches = [{"tokens": rng.integers(0, 64, size=(N * accum, 9)).astype(
+        np.int32)} for _ in range(3)]
+
+    def run(floor):
+        _set_floor(monkeypatch, floor)
+        if family == "zero":
+            trainer = BaguaTrainer(
+                loss_fn, None, ZeroOptimizerAlgorithm(optax.adam(1e-2)),
+                bucket_bytes=1 << 16, accum_steps=accum, autotune=False)
+        else:
+            trainer = BaguaTrainer(
+                loss_fn, optax.adam(1e-2), GradientAllReduceAlgorithm(),
+                bucket_bytes=1 << 16, accum_steps=accum, autotune=False,
+                flat_resident="on")
+        state = trainer.init(params)
+        losses = []
+        for b in batches:
+            state, loss = trainer.train_step(state, b)
+            losses.append(float(loss))
+        return np.array(losses), state, trainer
+
+    l_old, s_old, t_old = run(OLD_RULE)
+    l_new, s_new, t_new = run(4096)
+    kernels = {n for n in _shaped(t_new) if ".attn." in n}
+    assert len(kernels) == 8  # q, k, v, o of both layers
+    assert not any(".attn." in n for n in _shaped(t_old))
+    assert len(t_new._plan.buckets) > len(t_old._plan.buckets)
+    assert t_new._overlap_ordered == (accum > 1 and family == "allreduce")
+    np.testing.assert_allclose(l_new, l_old, rtol=1e-6, atol=0)
+    _leaf_allclose(t_new, s_new, t_old, s_old, rtol=1e-5, atol=1e-7)
