@@ -255,7 +255,7 @@ class AsyncModelAverageAlgorithm(Algorithm):
         Done-once per param avals: ``.lower().compile()`` bypasses the jit
         cache and re-lowers every call, so without the guard each periodic
         recalibration (``recalibrate_rounds``) re-paid three compiles on
-        unchanged shapes (ADVICE.md).  The key read is metadata-only
+        unchanged shapes.  The key read is metadata-only
         (``jnp.result_type``, never ``asarray``): materializing every leaf
         just to spell its dtype would copy whole buffers to the host."""
         key = tuple(

@@ -535,9 +535,9 @@ class Algorithm:
     #: pipeline) keep False and always run serialized.
     supports_overlap: bool = False
     #: Whether ``overlap="auto"`` may pick the overlap path for this family
-    #: (explicit ``overlap="on"`` always wins).  Set False where the
-    #: measured record (BENCH_OVERLAP.json) shows the serialized path
-    #: faster despite the family supporting the contract.
+    #: (explicit ``overlap="on"`` always wins).  Set from a cpu-sim
+    #: record (8-device mesh, toy widths; deleted in PR 46), never
+    #: measured on the chip: ROADMAP Queue 3 item 3.
     overlap_auto: bool = True
     #: Flat-resident contract: when True the trainer may keep params /
     #: grads / optimizer state as bucket-flat buffers across steps
@@ -548,8 +548,8 @@ class Algorithm:
     #: always run the leaf layout.
     supports_flat_resident: bool = False
     #: Whether ``flat_resident="auto"`` may pick the resident layout for
-    #: this family (explicit ``flat_resident="on"`` always wins) — the
-    #: measured-record gate, like :attr:`overlap_auto` (BENCH_FLAT.json).
+    #: this family (explicit ``flat_resident="on"`` always wins) — set
+    #: from a cpu-sim record like :attr:`overlap_auto` (Queue 3 item 3).
     flat_resident_auto: bool = True
     #: Straggler coupling: True when every train step synchronizes with
     #: every rank (a per-step gradient collective), so a slow peer gates

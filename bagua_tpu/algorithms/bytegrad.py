@@ -27,14 +27,14 @@ class ByteGradAlgorithm(Algorithm):
     name = "bytegrad"
     supports_overlap = True
     #: the codec pipeline already runs on flat buckets, so the resident
-    #: layout feeds it with zero repacking (BENCH_FLAT.json)
+    #: layout feeds it with zero repacking
     supports_flat_resident = True
-    #: measured (BENCH_OVERLAP.json, 8-dev cpu-sim mesh): the overlap
-    #: restructure was never clearly faster for the codec pipeline
-    #: (0.69-0.95x in early block runs, noise-bound under interleaved
-    #: A/B), so ``auto`` keeps bytegrad serialized; opt in with
-    #: ``overlap="on"`` (worth re-measuring on a real multi-chip ICI/DCN
-    #: mesh, where the quantize sits on the critical comm path)
+    #: set from a cpu-sim record (8-dev mesh; deleted in PR 46), never
+    #: measured on the chip: the overlap restructure was never clearly
+    #: faster for the codec pipeline there (0.69-0.95x, noise-bound under
+    #: interleaved A/B), so ``auto`` keeps bytegrad serialized; opt in
+    #: with ``overlap="on"``.  Owed a cell on a real multi-chip mesh, where
+    #: the quantize sits on the critical comm path: ROADMAP Queue 3 item 3
     overlap_auto = False
     #: non-hierarchical path wire format (the compressed scatter-gather):
     #: the byte-accounting default for ``bucket_tier_bytes``

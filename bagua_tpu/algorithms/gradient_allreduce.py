@@ -28,9 +28,9 @@ class GradientAllReduceAlgorithm(Algorithm):
     name = "gradient_allreduce"
     supports_overlap = True
     #: the per-bucket allreduce consumes resident bucket buffers directly
-    #: (zero repacking; shaped or 1-D alike) — measured on-par-to-faster
-    #: than the leaf layout on the cpu-sim mesh (BENCH_FLAT.json), so
-    #: ``auto`` takes it
+    #: (zero repacking; shaped or 1-D alike); ``auto`` takes it on the
+    #: word of a cpu-sim record, though all seven cells of the benchmark
+    #: run this layout and none the leaf one (ROADMAP Queue 3 item 3)
     supports_flat_resident = True
     #: reduced buckets are replicated (plain psum/ring sum — a NaN/Inf
     #: contribution from any rank survives into every rank's copy), so the

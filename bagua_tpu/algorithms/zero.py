@@ -79,13 +79,13 @@ class ZeroOptimizerAlgorithm(Algorithm):
     #: overlap window and ``optimizer_update`` consumes the pre-reduced
     #: chunks instead of running its own collective
     supports_overlap = True
-    #: measured (BENCH_OVERLAP.json, interleaved A/B on the 8-dev cpu-sim
-    #: mesh): the overlap restructure was never clearly faster — one
-    #: controlled run measured 0.89-0.94x of serialized in every trial
-    #: (splitting the reduce-scatter away from the chunk update defeats
-    #: XLA:CPU's fusion), the rest were noise-bound — so ``auto`` keeps
-    #: ZeRO serialized there; opt in with ``overlap="on"`` (re-measure on
-    #: real ICI, where the early reduce-scatter is the point)
+    #: set from a cpu-sim record (interleaved A/B on the 8-dev mesh;
+    #: deleted in PR 46), never measured on the chip: one controlled run
+    #: read 0.89-0.94x of serialized in every trial (splitting the
+    #: reduce-scatter away from the chunk update defeats XLA:CPU's
+    #: fusion), the rest were noise-bound — so ``auto`` keeps ZeRO
+    #: serialized; opt in with ``overlap="on"``.  Owed a cell on real ICI,
+    #: where the early reduce-scatter is the point: ROADMAP Queue 3 item 3
     overlap_auto = False
 
     def __init__(

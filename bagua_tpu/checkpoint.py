@@ -253,7 +253,7 @@ class BaguaCheckpointManager:
         # layout sidecars whose orbax save is not yet known-durable:
         # written only once the async save finishes (wait()/close()/next
         # save), so a crash mid-save can't leave a sidecar pointing at a
-        # checkpoint that never became readable (ADVICE.md)
+        # checkpoint that never became readable
         self._pending_layouts: dict = {}
         # steps whose durable files the chaos ``ckpt.write`` hook has not
         # yet had a chance to corrupt (same durability gating as sidecars)

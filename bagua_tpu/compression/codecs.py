@@ -43,7 +43,7 @@ purpose, so the gradient-health sentinel still sees the poison after a
 compressed collective.
 
 Pallas fast path: the min/max **reduction** is where a fused kernel pays
-(BENCH_COMM r5: +8% at 1 MiB chunks, 7x at 8 MiB); purely elementwise maps
+(round-5 profile: +8% at 1 MiB chunks, 7x at 8 MiB); purely elementwise maps
 (quantize against known bounds, every decompress, the fp8 cast) measured
 FASTER through the XLA lowering at every size, so only the reduction side
 gates on :data:`~bagua_tpu.compression.minmax_uint8._PALLAS_MIN_CHUNK_BYTES`.

@@ -66,21 +66,22 @@ def decompress_chunked(mn: jax.Array, mx: jax.Array, payload: jax.Array) -> jax.
     return vals.reshape(-1)
 
 
-# measured crossover (round-5 kernel-level codec profile, v5e, earlier code —
-# BENCH_COMM.json; not re-measured under jax 0.9): the fused
+# crossover from a round-5 kernel-level profile (v5e, earlier code; record
+# deleted in PR 46, not re-measured under jax 0.9 or through perfbench —
+# ROADMAP Queue 3 item 3): the fused
 # Pallas compress beats the XLA lowering from ~1 MiB chunks up (+9% kernel
 # time) but LOSES below (grid/dispatch overhead dominates at 128 KB chunks);
 # jnp decompress (one elementwise map, fully fused by XLA) beat the Pallas
 # decompress at every measured size.  The crossover is BYTE-based (it is
 # grid/dispatch overhead vs bytes streamed), so the gate scales by the
 # input itemsize — a bf16/f16 flat must reach the same 1 MiB of payload,
-# not half of it, before the Pallas path pays off (ADVICE.md).
+# not half of it, before the Pallas path pays off.
 _PALLAS_MIN_CHUNK_BYTES = 1 << 20  # 1 MiB
 
 
 def _codec(comm: BaguaCommunicator):
-    """Pick the codec implementation per MEASURED kernel profile (see
-    module docstring of :mod:`.pallas_codec` and ``BENCH_COMM.json``):
+    """Pick the codec implementation per the round-5 kernel profile (see
+    module docstring of :mod:`.pallas_codec`; ROADMAP Queue 3 item 3):
     Pallas compress on TPU for chunks ≥1 MiB, the XLA lowering otherwise
     and for every decompress.  ``BAGUA_DISABLE_PALLAS_CODEC=1`` forces the
     jnp path for A/B checks.  The gate itself is
@@ -132,9 +133,9 @@ def compressed_scatter_gather_allreduce(
     reduced chunk provably lies within the mean/sum of its sources'
     ``[mn, mx]`` bounds (each dequantized source is clamped to its own
     grid), so the second quantize runs against those derived bounds —
-    ONE min/max reduction pass per bucket instead of two, measurable on
+    ONE min/max reduction pass per bucket instead of two, which counts on
     large buckets where the reduction is the codec's memory-bound half
-    (BENCH_COMM r5).  Bound slack: a dequantized source can overshoot its
+    (round-5 profile).  Bound slack: a dequantized source can overshoot its
     bound by half a source grid step (``upper = round(mx·scale)``), and
     the derived grid is at most the mean source range wide — the clamp
     below absorbs both, keeping the error within one grid step of the

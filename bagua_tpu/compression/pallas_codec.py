@@ -8,8 +8,10 @@ pulls its chunk into VMEM once, computes the masked min/max on the VPU,
 quantizes in-register, and writes only the u8 payload + two scalars back to
 HBM.
 
-**Measured reality (kernel-level xplane profile, v5e, BENCH_COMM.json r5):**
-the picture is size-dependent, and at the two ends it is opposite:
+**What round 5 read (kernel-level xplane profile, v5e, the pre-chip
+yardstick's; its record is deleted, PR 46, and nothing here has been measured
+through perfbench — ROADMAP Queue 3 item 3):** size-dependent, and at the
+two ends opposite:
 
 - **small chunks (128 KiB)**: grid overhead dominates — Pallas compress
   LOSES to the XLA lowering (171 vs 219 GB/s), because XLA fuses the naive

@@ -246,8 +246,8 @@ class BaguaTrainer:
         with the remaining backward; buckets are re-ordered by observed
         gradient readiness (one-time, host-side) so the first-finalized
         bucket heads the comm sequence.  ``"auto"`` (default, or env
-        ``BAGUA_OVERLAP``) resolves to whichever path measured faster —
-        see BENCH_OVERLAP.json.  Supported families: gradient_allreduce,
+        ``BAGUA_OVERLAP``) resolves by ``Algorithm.overlap_auto`` (set
+        from a cpu-sim record).  Supported families: gradient_allreduce,
         bytegrad, and flat-resident ZeRO; others always run serialized.
 
         ``overlap_chunk_bytes``: target per-rank bytes of one independent
@@ -608,8 +608,8 @@ class BaguaTrainer:
         """Dispatch gate for the resident layout, resolved once per
         ``init()``.  Explicit on/off wins (``on`` on an unsupported
         configuration already raised at construction); ``auto`` takes the
-        resident layout wherever it is supported, the family's measured
-        record agrees (``Algorithm.flat_resident_auto``, BENCH_FLAT.json),
+        resident layout wherever it is supported, the family's flag
+        agrees (``Algorithm.flat_resident_auto``, set from cpu-sim),
         and the trainer optimizer commutes with flattening
         (:func:`_optimizer_flattens_safely` — shape-aware transforms fall
         back to the leaf layout instead of silently changing meaning)."""
@@ -651,10 +651,10 @@ class BaguaTrainer:
 
     def _overlap_active(self) -> bool:
         """Dispatch gate for the overlap scheduler.  Explicit on/off wins;
-        ``auto`` resolves to the path that measured faster
-        (BENCH_OVERLAP.json): overlap when there is an accumulation scan to
-        stream collectives into (the peel is bit-exact and measured
-        fastest), the serialized construction otherwise — at
+        ``auto`` resolves by a gate set from a cpu-sim record, never
+        measured on the chip: overlap when there is an accumulation scan to
+        stream collectives into (the peel is bit-exact), the serialized
+        construction otherwise (ROADMAP Queue 3 item 3) — at
         ``accum_steps == 1`` the backward already feeds the per-bucket
         collectives as open dataflow, so restructuring buys nothing unless
         ring chunking is explicitly requested."""
@@ -669,11 +669,11 @@ class BaguaTrainer:
             return False
         if self.overlap == "on":
             return True
-        # auto: measured dispatch gate (BENCH_OVERLAP.json, interleaved A/B
-        # trials on the 8-dev cpu-sim mesh): allreduce measured on-par-to-
-        # faster under overlap at accum>1 (best-trial 1.03x, noise-bound) —
-        # and the peel is bit-exact, so auto takes it; ZeRO and bytegrad
-        # measured slower (0.9x / 0.99x → overlap_auto=False on those
+        # auto: a gate set from a cpu-sim record (interleaved A/B trials on
+        # the 8-dev cpu-sim mesh, record deleted in PR 46), never measured
+        # on the chip (ROADMAP Queue 3 item 3): allreduce takes overlap at
+        # accum>1 — the peel is bit-exact; ZeRO and bytegrad read slower
+        # there (0.9x / 0.99x → overlap_auto=False on those
         # families, overridable with overlap="on").  accum==1 keeps the
         # serialized construction (the backward already feeds the bucket
         # collectives as open dataflow); an explicit chunk size is an
@@ -2117,9 +2117,9 @@ class BaguaTrainer:
 
     def step_cost_analysis(self, state: TrainState, batch) -> Dict[str, Any]:
         """XLA's cost model for the current compiled train step ("flops",
-        "bytes accessed", ...) — feeds bench.py's achieved-TFLOP/s and MFU
-        reporting, the per-step ``obs/mfu`` gauge, and the
-        physically-impossible-number sanity bound.  Cached per step-cache
+        "bytes accessed", ...) — feeds the per-step ``obs/mfu`` gauge
+        (``perfbench/drivers/train.py`` waits for its harvest inside
+        ``setup_s``: ROADMAP Queue 3 item 1).  Cached per step-cache
         key (the lower+compile+query round-trip is paid once per compiled
         program, not per call); the same pass harvests
         ``memory_analysis()`` for :meth:`step_memory_analysis`.  Returns {}

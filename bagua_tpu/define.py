@@ -57,7 +57,7 @@ class BaguaHyperparameter(BaseModel):
     is_hierarchical_reduce: bool = False
     bucket_size: int = 10 * 1024 ** 2
     #: algorithm family recommended by the autotuner ("" = keep current);
-    #: TPU extension over the reference — BASELINE.json requires the
+    #: TPU extension over the reference — the north star wants the
     #: centralized/decentralized/low-precision families to be selectable
     algorithm: str = ""
     #: overlap-scheduler dispatch gate ("auto"|"on"|"off"; "" = keep

@@ -53,8 +53,10 @@ _declare("BAGUA_DEFAULT_BUCKET_SIZE", "int", str(10 * 1024 ** 2),
 _declare("BAGUA_OVERLAP", "enum", "auto",
          "Overlap-scheduler dispatch gate: stream per-bucket gradient "
          "collectives into backward/accumulation compute (`on`), keep the "
-         "exact serialized step construction (`off`), or take whichever "
-         "path measured faster (`auto`, see BENCH_OVERLAP.json).",
+         "exact serialized step construction (`off`), or let the family's "
+         "`overlap_auto` flag decide (`auto`; the flags were set from a "
+         "cpu-sim record, never measured on the chip: ROADMAP Queue 3 "
+         "item 3).",
          choices=("auto", "on", "off"))
 _declare("BAGUA_OVERLAP_CHUNK_BYTES", "int", "0",
          "Target per-rank bytes of one independent ring sub-collective under "
@@ -105,7 +107,7 @@ _declare("BAGUA_EF_RESIDUAL", "enum", "on",
          "quantization error and folds it into the next step's gradient "
          "— the convergence contract of 1-bit compression; `off` lets the "
          "codec ride STATELESSLY (biased sign-SGD — diverges on real "
-         "tasks; the BENCH_COMPRESS honesty control).  Set before trainer "
+         "tasks; the control run of the convergence test).  Set before trainer "
          "construction: flipping it mid-run changes the train-state "
          "structure.", choices=("on", "off"))
 _declare("BAGUA_FLAT_RESIDENT", "enum", "auto",
@@ -113,7 +115,8 @@ _declare("BAGUA_FLAT_RESIDENT", "enum", "auto",
          "as bucket-flat buffers across steps (`on`), keep the leaf pytree "
          "layout (`off`), or engage it wherever the algorithm family "
          "supports it on a pure-data-parallel mesh (`auto`, see "
-         "docs/flat_layout.md and BENCH_FLAT.json).",
+         "docs/flat_layout.md; the families' flags were set from a "
+         "cpu-sim record: ROADMAP Queue 3 item 3).",
          choices=("auto", "on", "off"))
 _declare("BAGUA_MAX_EXCHANGE_PERIOD", "int", "128",
          "Largest step-pairing period precompiled into one program by "
@@ -664,9 +667,9 @@ def get_default_bucket_size() -> int:
 
 
 def get_overlap_mode() -> str:
-    """Overlap-scheduler dispatch gate: ``auto`` (default — the path that
-    measured faster, see BENCH_OVERLAP.json), ``on``, or ``off`` (the exact
-    serialized step construction)."""
+    """Overlap-scheduler dispatch gate: ``auto`` (default — the family's
+    ``overlap_auto`` flag, set from a cpu-sim record: ROADMAP Queue 3
+    item 3), ``on``, or ``off`` (the exact serialized step construction)."""
     return env_enum("BAGUA_OVERLAP")
 
 
@@ -713,7 +716,7 @@ def get_topk_ratio() -> float:
 
 def is_ef_residual_disabled() -> bool:
     """True when ``BAGUA_EF_RESIDUAL=off`` — the stateful codecs ride
-    statelessly (biased; the BENCH_COMPRESS honesty control)."""
+    statelessly (biased; the control run of the convergence test)."""
     return env_enum("BAGUA_EF_RESIDUAL") == "off"
 
 
@@ -794,8 +797,8 @@ def get_autotune_warmup_time_s() -> float:
 
 
 def is_autotune_algorithm_on() -> bool:
-    """Let the autotuner search over algorithm families too (TPU extension;
-    BASELINE.json wants centralized/low-precision selectable)."""
+    """Let the autotuner search over algorithm families too (TPU extension:
+    centralized, decentralized and low-precision families selectable)."""
     return env_bool("BAGUA_AUTOTUNE_ALGORITHM")
 
 

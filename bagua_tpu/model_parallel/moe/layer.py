@@ -81,10 +81,10 @@ class MoEMLP(nn.Module):
     #: capacity slots.
     #:
     #: Regime selection: the only speed record of capacity against
-    #: dropless is ``bench.py::bench_moe_dropless`` at TOY widths (E=8, k=2,
+    #: dropless was the pre-chip yardstick's at TOY widths (E=8, k=2,
     #: d_model 512; "capacity wins below ~12K tokens per shard per layer,
-    #: dropless above") — older than the benchmark, wrong source by PERF.md
-    #: §6 (PR 23), unverified since.  Measured at published widths, through
+    #: dropless above") — deleted in PR 46, never measured by perfbench
+    #: (ROADMAP Queue 3 item 3).  Measured at published widths, through
     #: ``perfbench`` (cell ``olmoe-1b-7b.pretrain4096-dp1``: E=64, k=8,
     #: d_model 2048, expert width 1024, 8,192 tokens a step on one v5e):
     #: PERF.md §5 / §6 (PR 28); no capacity run exists there (its [T, E, C]

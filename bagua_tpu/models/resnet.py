@@ -60,8 +60,8 @@ class ResNet(nn.Module):
     dtype: Any = jnp.bfloat16
     norm_dtype: Any = jnp.bfloat16  # f32 restores the conservative pre-norm cast
     norm_cls: Any = None  # override with SyncBatchNorm for cross-chip stats
-    #: rematerialize each bottleneck block in the backward pass.  Measured
-    #: on v5e (BENCH_RESNET_SWEEP.json r5): a LOSS for ResNet50 throughput
+    #: rematerialize each bottleneck block in the backward pass.  Round 5
+    #: read it on v5e (pre-chip yardstick; no cell runs it) as a LOSS
     #: — conv recompute re-reads activations/weights, ADDING HBM traffic
     #: (28.1 -> 33.0 GB/step at batch 128) for -18% img/s — so it stays
     #: off by default; use it only when activation memory, not speed, is

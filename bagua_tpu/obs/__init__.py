@@ -21,9 +21,6 @@ Plus the analysis layer on top of those signals:
 * :mod:`~bagua_tpu.obs.anomaly` — rolling median/MAD step-time anomaly
   detector: ``straggler_suspect`` phase breakdowns into the health beacon,
   throttled flight dumps, perf hints for the autotune service.
-* :mod:`~bagua_tpu.obs.regress` — bench-trend sentinel against the
-  committed ``BENCH_*.json``/``EFFICIENCY.json`` records
-  (``python -m bagua_tpu.obs.regress``).
 
 And the efficiency plane over all of it:
 
@@ -50,8 +47,8 @@ And the fleet-historical layer (ISSUE 14):
 
 Master switch: ``BAGUA_OBS`` (default on; ``off`` restores the exact
 pre-obs host behavior — the compiled step program is identical either way).
-Import-light: no jax anywhere in the package (``attribution``/``regress``
-import it lazily for parsing/probing only).
+Import-light: importing the package pulls in no jax (``memory``, ``spans``
+and ``step_observer`` import it lazily or are imported by tracing code only).
 """
 
 from .export import (  # noqa: F401
@@ -74,8 +71,7 @@ from .recorder import (  # noqa: F401
 # re-exported here, where it would shadow the ``obs.recorder`` submodule
 from .spans import SpanRecorder, span_ring, trace_span  # noqa: F401
 from .anomaly import StepAnomalyDetector, fleet_straggler_suspects  # noqa: F401,E402
-# NOTE: obs.timeline, obs.regress, and obs.ledger are NOT imported here —
-# all three are `python -m` entry points, and a package-level import would
-# leave a second copy of the module executing under runpy (the ledger
-# singleton and its validate_efficiency live in obs.ledger; consumers
-# import the module lazily)
+# NOTE: obs.timeline and obs.ledger are NOT imported here — both are
+# `python -m` entry points, and a package-level import would leave a second
+# copy of the module executing under runpy (the ledger singleton lives in
+# obs.ledger; consumers import the module lazily)

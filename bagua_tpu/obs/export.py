@@ -434,8 +434,7 @@ _declare("serve/pages_in_use", "gauge",
          "pages).")
 _declare("serve/ttft_last_s", "gauge",
          "Time-to-first-token of the most recently started request "
-         "(submit -> first sampled token); percentiles live in "
-         "BENCH_SERVE.json.")
+         "(submit -> first sampled token).")
 _declare("serve/tpot_last_s", "gauge",
          "Time-per-output-token of the most recently completed request "
          "(after its first token).")

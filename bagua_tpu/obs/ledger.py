@@ -39,9 +39,9 @@ host-side: the compiled step program is untouched (the ``BAGUA_OBS`` off
 switch and the jaxpr-equality pin keep holding).
 
 MFU accounting rides along: :data:`PEAK_TFLOPS_BF16` (per-chip silicon
-peaks, shared with ``bench.py``) turns the cached ``step_cost_analysis()``
-flops and the measured step cadence into a per-step ``obs/mfu`` gauge —
-null-with-rationale on cpu-sim, like ``trace_overlap``.
+peaks) turns the cached ``step_cost_analysis()`` flops and the measured
+step cadence into a per-step ``obs/mfu`` gauge — null-with-rationale on
+cpu-sim.
 
 CLI::
 
@@ -75,8 +75,7 @@ __all__ = [
     "LEDGER_CLASSES", "GOODPUT_CLASSES", "BADPUT_CLASSES", "SPAN_CLASS_MAP",
     "DRILL_BADPUT_EXPECTATIONS", "GoodputLedger",
     "ledger", "install", "PEAK_TFLOPS_BF16", "PEAK_HBM_GBPS",
-    "peak_flops_for_device_kind", "EFFICIENCY_SCHEMA", "validate_efficiency",
-    "load_ledger_reports", "main",
+    "peak_flops_for_device_kind", "load_ledger_reports", "main",
 ]
 
 #: every wall-clock second lands in exactly one of these (defined next to
@@ -133,8 +132,8 @@ DRILL_BADPUT_EXPECTATIONS = {
 }
 
 # Peak per-chip silicon specs for MFU / roofline reporting, keyed by
-# ``jax.devices()[0].device_kind`` (moved here from bench.py so the
-# trainer's per-step gauge and the bench share one table).
+# ``jax.devices()[0].device_kind`` (the trainer's per-step gauge and
+# chip_smoke.py share this one table).
 PEAK_TFLOPS_BF16 = {
     "TPU v4": 275.0,
     "TPU v5 lite": 197.0,       # v5e
@@ -362,67 +361,6 @@ def install() -> GoodputLedger:
             _spans.set_ledger_sink(ledger)
             _INSTALLED = True
     return ledger
-
-
-# ---- EFFICIENCY.json schema (benchmarks/efficiency_bench.py writes it) ----
-
-EFFICIENCY_SCHEMA = "bagua-efficiency-v1"
-
-
-def validate_efficiency(record: dict) -> List[str]:
-    """Schema problems with an EFFICIENCY.json record ([] = valid) — the
-    ``test_bench_sanity`` gate and the regress sentinel's admission check."""
-    problems: List[str] = []
-    if not isinstance(record, dict):
-        return ["not a JSON object"]
-    if record.get("schema") != EFFICIENCY_SCHEMA:
-        problems.append(f"schema != {EFFICIENCY_SCHEMA}")
-    for key, typ in (("time_unix", (int, float)), ("platform", str),
-                     ("n_devices", int), ("config", dict),
-                     ("ledger", dict), ("footprint", dict),
-                     ("mfu", dict), ("trend_records", list)):
-        if not isinstance(record.get(key), typ):
-            problems.append(f"missing/mistyped {key}")
-    led = record.get("ledger") or {}
-    classes = led.get("classes")
-    if not isinstance(classes, dict):
-        problems.append("ledger.classes missing")
-    else:
-        for cls in LEDGER_CLASSES:
-            if cls not in classes:
-                problems.append(f"ledger.classes missing {cls}")
-        wall = led.get("wall_s")
-        if not isinstance(wall, (int, float)) or wall <= 0:
-            problems.append("ledger.wall_s missing/nonpositive")
-        elif sum(classes.values()) > wall * 1.01 + 1e-6:
-            problems.append("ledger classes sum exceeds wall_s (+1%)")
-    if not isinstance(led.get("goodput_fraction"), (int, float)):
-        problems.append("ledger.goodput_fraction missing")
-    fp = record.get("footprint") or {}
-    for key in ("params_bytes", "opt_state_bytes", "algo_state_bytes",
-                "grad_flats_bytes", "total_bytes"):
-        if not isinstance(fp.get(key), int):
-            problems.append(f"footprint.{key} missing/mistyped")
-    if isinstance(fp.get("total_bytes"), int) and all(
-        isinstance(fp.get(k), int)
-        for k in ("params_bytes", "opt_state_bytes", "algo_state_bytes",
-                  "grad_flats_bytes")
-    ):
-        if fp["total_bytes"] != (fp["params_bytes"] + fp["opt_state_bytes"]
-                                 + fp["algo_state_bytes"]
-                                 + fp["grad_flats_bytes"]):
-            problems.append("footprint.total_bytes != sum of components")
-    mfu = record.get("mfu") or {}
-    if "available" not in mfu:
-        problems.append("mfu.available missing")
-    elif not mfu.get("available") and not mfu.get("rationale"):
-        problems.append("mfu unavailable without rationale")
-    for rec in record.get("trend_records") or []:
-        if not isinstance(rec, dict) or "metric" not in rec \
-                or "value" not in rec:
-            problems.append("trend_records entry missing metric/value")
-            break
-    return problems
 
 
 # ---- CLI: per-run report from metrics.jsonl + flight dumps ----------------

@@ -19,11 +19,11 @@ Three complementary views, cheapest first:
   high-water mark (:func:`peak_bytes` — NOT ``peak_bytes_in_use`` alone,
   which on a TPU never sees a running program's temporaries) and the
   headroom against ``bytes_limit``.  TPU runtimes expose it; cpu-sim
-  returns null-with-rationale, like ``trace_overlap``.
+  returns null-with-rationale.
 
 Footprint and headroom ride the per-rank obs summary → health beacon →
-fleet snapshot as gauges, and land in ``EFFICIENCY.json``.  Host-side
-only: nothing here touches the compiled step.
+fleet snapshot as gauges.  Host-side only: nothing here touches the
+compiled step.
 """
 
 from __future__ import annotations

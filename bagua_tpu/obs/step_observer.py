@@ -242,7 +242,7 @@ class StepObserver:
         """Per-step MFU gauge: the cost-model flops of the current compiled
         step over (measured step cadence x peak silicon FLOP/s).
         Null-with-rationale where the denominator is unknown (cpu-sim,
-        unlisted device kinds) — published once, like ``trace_overlap``."""
+        unlisted device kinds) — published once."""
         if not self.enabled:
             return
         if self.peak_flops is None:

@@ -630,9 +630,9 @@ def _enabled() -> bool:
     return env.is_flash_attention_enabled()
 
 
-# below this XLA's fused attention is already faster — re-validated r5 at
-# BERT-Large's seq 384: plain 104.7 vs forced-flash 99.2 seq/s at batch 8
-# (BENCH_BERT_SWEEP.json); the kernel pays from ~1k tokens (3.0x at 4096)
+# below this the plain XLA attention is taken.  Set in round 5 from a sweep
+# of the pre-chip yardstick (best-of-two walls, record deleted in PR 46),
+# never measured through perfbench: ROADMAP Queue 3 item 3 owes it a cell.
 MIN_FLASH_SEQ = 1024
 
 

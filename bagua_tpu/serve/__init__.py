@@ -15,8 +15,6 @@ The framework's end-state story — train, observe, heal, and now *serve*:
 * :mod:`~bagua_tpu.serve.loader` — integrity-verified weight loads
   through the checkpoint digest chain, with layout-sidecar-aware
   flat→serving-layout conversion.
-* :mod:`~bagua_tpu.serve.schema` — the ``BENCH_SERVE.json`` schema the
-  serving bench, CI smoke stage, and artifact gate share.
 
 Observability rides the existing planes: ``serve/*`` spans and counters,
 and the goodput ledger's serving classes (``prefill``/``decode`` count as
@@ -33,8 +31,3 @@ from .engine import (  # noqa: F401
     clear_serve_program_cache,
 )
 from .loader import load_serving_params, save_serving_artifact  # noqa: F401
-from .schema import (  # noqa: F401
-    SERVE_BENCH_SCHEMA,
-    SERVE_SPEEDUP_GATE,
-    validate_serve_bench,
-)

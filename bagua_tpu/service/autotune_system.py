@@ -3,8 +3,8 @@
 Counterpart of /root/reference/bagua/service/autotune_system.py:16+
 (``sysperf``: parallel-ssh to all hosts, each running the ``bagua_sys_perf``
 VGG16 probe, collecting per-host throughput to spot slow nodes before a
-training run).  Here the probe is the collective microbenchmark
-(benchmarks/collective_bench.py) or ``bench.py``, over plain ssh
+training run).  Here the probe is one cell of the repo's benchmark
+(``perfbench/run.py`` in the checkout named by ``--cwd``), over plain ssh
 subprocesses (``--ssh_cmd`` shim-able, as in ``baguarun``).
 
     bagua-tpu-sysperf --host_list 10.0.0.1,10.0.0.2
@@ -26,10 +26,7 @@ from typing import Dict, List
 
 logger = logging.getLogger("bagua_tpu.sysperf")
 
-PROBES = {
-    "collective": "benchmarks/collective_bench.py --sizes-mb 4",
-    "train": "bench.py",
-}
+PROBE = "perfbench/run.py --workload bert-large.squad384-dp1"
 
 
 def parse_args(argv=None):
@@ -37,7 +34,6 @@ def parse_args(argv=None):
     p.add_argument("--host_list", type=str, required=True)
     p.add_argument("--ssh_port", type=int, default=22)
     p.add_argument("--ssh_cmd", type=str, default="ssh -p {port} {host}")
-    p.add_argument("--probe", choices=sorted(PROBES), default="collective")
     p.add_argument("--python", type=str, default="python")
     p.add_argument("--cwd", type=str, default=None)
     p.add_argument("--timeout_s", type=float, default=1800)
@@ -48,7 +44,7 @@ def parse_args(argv=None):
 
 def probe_host(args, host: str) -> Dict:
     ssh = shlex.split(args.ssh_cmd.format(port=args.ssh_port, host=host))
-    cmd = f"{args.python} {PROBES[args.probe]}"
+    cmd = f"{args.python} {PROBE}"
     if args.cwd:
         cmd = f"cd {shlex.quote(args.cwd)} && {cmd}"
     try:
@@ -75,7 +71,7 @@ def probe_host(args, host: str) -> Dict:
 def _score(result: Dict) -> float:
     """One comparable throughput number per host."""
     vals = [
-        r.get("busbw_GBps") or r.get("value") or 0.0
+        r.get("metrics", {}).get("tokens_per_s_per_chip", {}).get("value", 0.0)
         for r in result.get("records", [])
     ]
     return float(max(vals)) if vals else 0.0

@@ -8,8 +8,8 @@ concrete buckets via :func:`split_bucket_by_bucket_size`.
 
 The search dimension gains one TPU-specific axis over the reference: the
 algorithm *family* is part of the tunable space when ``tune_algorithm`` is on
-(BASELINE.json requires the centralized / decentralized / low-precision
-families to be selectable by the autotuner).
+(the centralized / decentralized / low-precision families are selectable
+by the autotuner).
 
 Autotune v2 (ISSUE 19): when the trainer reports capabilities at tensor
 registration, :meth:`AutotuneTaskManager.configure_space` swaps the legacy

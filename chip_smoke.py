@@ -104,7 +104,7 @@ def sizes(dryrun: bool) -> dict:
             ring_chunk_bytes=256,
         )
     return dict(
-        # bench.py:bench_bert's shape, the BASELINE workload
+        # the bert-large.squad384 cells' shape (perfbench/configs/bert-large.json)
         full=bert_large_config(max_seq_len=384),
         # full d_model/d_ff/heads/vocab, 2 layers: bounds the compile time
         # of the six 4-chip trainers

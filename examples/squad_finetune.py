@@ -1,7 +1,7 @@
-"""BERT-Large SQuAD-style fine-tune — the BASELINE.json compressed-comm workload.
+"""BERT-Large SQuAD-style fine-tune — upstream's compressed-comm workload.
 
 Counterpart of /root/reference/examples/squad/main.py (BERT-Large SQuAD
-fine-tuning, the workload BASELINE.json names for ByteGrad/QAdam).  A span
+fine-tuning, the workload upstream benchmarks ByteGrad/QAdam on).  A span
 head (start/end logits) sits on the Transformer encoder; data is
 SQuAD-shaped synthetic by default (seq 384, span labels) — pass ``--dataset``
 with a tokenized .npz (input_ids, start_positions, end_positions) for real

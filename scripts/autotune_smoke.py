@@ -22,8 +22,8 @@ capability-gated v2 knob space, and scored the windows on fleet-min
 goodput rather than summed speed.  Exit code carries the verdict (the
 ci.sh autotune stage).
 
-Usage: python benchmarks/autotune_smoke.py        # on-chip, to completion
-       python benchmarks/autotune_smoke.py --ci   # cpu goodput-scored round
+Usage: python scripts/autotune_smoke.py        # on-chip, to completion
+       python scripts/autotune_smoke.py --ci   # cpu goodput-scored round
 """
 
 import os
@@ -245,7 +245,7 @@ result = {
         "CI twin)"
     ),
     "device": jax.devices()[0].device_kind,
-    "script": "benchmarks/autotune_smoke.py",
+    "script": "scripts/autotune_smoke.py",
 }
 if scores:
     result["score_trajectory"] = scores

@@ -19,7 +19,7 @@ deterministically:
 4. **NaN gradient → skip-and-continue**: ``grad.poison`` fires inside the
    compiled train step; ``BAGUA_GRAD_GUARD=skip`` rewinds the step and the
    final loss is BIT-IDENTICAL to a clean run of one fewer step on
-   ``bench.golden_task()`` (loss continuity).
+   ``golden.golden_task()`` (loss continuity).
 5. **collective hang → watchdog abort + reset recovery**: the waiter's
    readback wedges; the monitor fires, raises the abort flag, and after
    ``reset_abort`` training resumes — twice, proving re-arming.
@@ -42,7 +42,7 @@ Every fault-driven failure mode must also leave a **schema-valid
 flight-recorder dump** (``bagua_tpu.obs.recorder``) naming the firing
 fault point — asserted per drill and recorded in the matrix.
 
-Writes ``CHAOS_DRILL.json`` (schema-gated in ``tests/test_bench_sanity.py``);
+Writes ``CHAOS_DRILL.json`` (schema-gated in ``tests/test_drill_records.py``);
 exit code 0 iff every fault was detected AND recovered.
 
 Usage: python scripts/chaos_drill.py [--only DRILL ...]
@@ -96,6 +96,7 @@ DUMP_DIR = os.environ["BAGUA_OBS_DUMP_DIR"] = _early_dump_dir()
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.join(REPO, "tests"))  # golden.py
 
 import jax  # noqa: E402
 
@@ -125,7 +126,7 @@ def _counter_deltas(before):
 #: catch-up in `catchup_sync`, the fallback-restore walk in `checkpoint` —
 #: asserted as a class-delta across the drill, so an efficiency regression
 #: in a recovery path can't hide behind a passing recovery verdict.  One
-#: mapping, shared with the test_bench_sanity artifact gate.
+#: mapping, shared with the test_drill_records artifact gate.
 from bagua_tpu.obs.ledger import (  # noqa: E402
     DRILL_BADPUT_EXPECTATIONS as LEDGER_EXPECTATIONS,
 )
@@ -332,12 +333,12 @@ def drill_nan_grad_skip():
     of n steps must be bit-identical to a clean run of n-1 steps on the
     golden task (same batch every step ⇒ skipping one update IS running
     one fewer), proving exact loss continuity."""
-    import bench
+    import golden
     from bagua_tpu.algorithms import GradientAllReduceAlgorithm
     from bagua_tpu.core.backend import BaguaTrainer
     from bagua_tpu.parallel.mesh import build_mesh
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     mesh = build_mesh({"dp": 8})
 
     def run(n, guard="off", poison=None):
@@ -382,12 +383,12 @@ def drill_guard_on_goldens():
     same ``loss_goldens`` sweep) — the guard's selects pass healthy state
     through bitwise.  ``async`` is excluded: its final loss is
     host-timing-dependent even without the guard (see test_loss_goldens)."""
-    import bench
+    import golden
 
     def goldens(guard):
         os.environ["BAGUA_GRAD_GUARD"] = guard
         try:
-            return bench.loss_goldens()
+            return golden.loss_goldens()
         finally:
             os.environ.pop("BAGUA_GRAD_GUARD", None)
 
@@ -473,12 +474,12 @@ def drill_collective_hang():
 
 
 def _golden_trainer(algo, **kw):
-    import bench
+    import golden
     import optax
     from bagua_tpu.core.backend import BaguaTrainer
     from bagua_tpu.parallel.mesh import build_mesh
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     t = BaguaTrainer(loss_fn, optax.sgd(0.1), algo,
                      mesh=build_mesh({"dp": 8}), autotune=False, **kw)
     s = t.init(params)
@@ -548,8 +549,7 @@ def drill_straggler_throughput(tmp):
     """A 10× peer straggler gates every synchronous step: throughput
     degrades by roughly the dilation yet every step completes — while the
     async family under the SAME armed fault keeps its steps ungated and
-    pays only at negotiated boundaries (the BENCH_STRAGGLER measurement
-    in miniature).  The anomaly detector must additionally flag the slow
+    pays only at negotiated boundaries.  The anomaly detector must additionally flag the slow
     window on BOTH sides of the fault — collective-dominant on the gated
     peer, dispatch-dominant on the straggler itself — and the
     coordinator-side fleet snapshot must name the straggling rank from
@@ -1072,7 +1072,7 @@ def drill_autopilot_slo_ladder(tmp):
 
     import optax
 
-    import bench
+    import golden
     from bagua_tpu.algorithms import GradientAllReduceAlgorithm
     from bagua_tpu.autopilot import LADDER, default_engine_actuators
     from bagua_tpu.communication import get_hyperparameters_service_client
@@ -1098,7 +1098,7 @@ def drill_autopilot_slo_ladder(tmp):
                       MASTER_ADDR="127.0.0.1", BAGUA_AUTOTUNE="1")
     get_hyperparameters_service_client.cache_clear()
     try:
-        loss_fn, params, batch = bench.golden_task()
+        loss_fn, params, batch = golden.golden_task()
         trainer = BaguaTrainer(
             loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
             mesh=build_mesh({"dp": 8}), model_name=model,
@@ -1447,7 +1447,7 @@ def drill_autopilot_compress_codec(tmp):
 
     import optax
 
-    import bench
+    import golden
     from bagua_tpu.algorithms import GradientAllReduceAlgorithm
     from bagua_tpu.analysis.jaxpr_check import iter_collectives
     from bagua_tpu.autopilot import default_engine_actuators
@@ -1486,7 +1486,7 @@ def drill_autopilot_compress_codec(tmp):
                       MASTER_ADDR="127.0.0.1", BAGUA_AUTOTUNE="1")
     get_hyperparameters_service_client.cache_clear()
     try:
-        loss_fn, params, batch = bench.golden_task()
+        loss_fn, params, batch = golden.golden_task()
         trainer = BaguaTrainer(
             loss_fn, optax.sgd(0.1),
             GradientAllReduceAlgorithm(hierarchical=True),

@@ -4,12 +4,12 @@
 # virtual CPU mesh unless RUN_TPU_BENCH=1.
 #
 # Usage:  bash scripts/ci.sh            # lint + compile + tests + goldens
-#         RUN_TPU_BENCH=1 bash scripts/ci.sh   # + the TPU headline bench
+#         RUN_TPU_BENCH=1 bash scripts/ci.sh   # + one cell of perfbench/ on the chip
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
 echo "=== lint (syntax) ==="
-python -m compileall -q bagua_tpu tests examples bench.py __graft_entry__.py
+python -m compileall -q bagua_tpu tests examples __graft_entry__.py
 
 echo "=== bagua-lint (AST + jaxpr + concurrency + trace-coherence engines) ==="
 # All four engines (--engine all is the default): AST hot-path rules, the
@@ -40,7 +40,7 @@ echo "=== obs smoke trace (flight recorder on one live drill) ==="
 # drill itself asserts its flight-recorder dump exists, schema-validates,
 # names the firing fault point, and surfaces its badput class in the
 # goodput ledger (exit code carries the verdict).  The full-matrix
-# CHAOS_DRILL.json is schema-gated in test_bench_sanity.py.
+# CHAOS_DRILL.json is schema-gated in tests/test_drill_records.py.
 OBS_TMP="$(mktemp -d)"
 BAGUA_OBS_EXPORT_DIR="$OBS_TMP/export" BAGUA_OBS_EXPORT_INTERVAL_S=1 \
 python scripts/chaos_drill.py --only nan_grad_skip_loss_continuity \
@@ -91,7 +91,7 @@ echo "=== autopilot replay smoke (policy engine over a recorded fleet stream) ==
 # two SLO ladder rungs -> storage quarantine) must match the committed
 # expectation exactly — a policy change that re-orders or drops an action
 # fails here before it ships.  Full matrix actuation is chaos-drilled in
-# CHAOS_DRILL.json (schema-gated in test_bench_sanity.py); operators can
+# CHAOS_DRILL.json (schema-gated in test_drill_records.py); operators can
 # replay their own streams with `python -m bagua_tpu.autopilot --replay`.
 python -m bagua_tpu.autopilot \
   --replay tests/data/autopilot_fleet_stream.jsonl \
@@ -116,41 +116,9 @@ echo "=== autotune v2 smoke (goodput-scored search round, cpu mesh) ==="
 # One live v2 search round: a real trainer on the two-tier cpu-sim mesh
 # checks in with windowed goodput observations, the sidecar builds the
 # capability-gated knob space from the registration capabilities, and the
-# scored window MUST be fleet-min-goodput-scored (not summed speed).  The
-# committed convergence evidence (tuned >= default within the 24-window
-# cap) is BENCH_AUTOTUNE.json, schema-gated in tests/test_bench_sanity.py;
-# regenerate with `python benchmarks/autotune_bench.py`.
+# scored window MUST be fleet-min-goodput-scored (not summed speed).
 JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-python benchmarks/autotune_smoke.py --ci > /dev/null
-
-echo "=== serve smoke (continuous-batching engine, short synthetic trace) ==="
-# The serving plane end-to-end on the 8-dev cpu-sim image: weights loaded
-# through the integrity-verified serving loader, a short Poisson trace
-# through the paged-KV continuous-batching engine, the continuous-vs-
-# static A/B, and the schema validation serve_bench runs before writing
-# (an invalid record exits non-zero).  The committed full-trace
-# BENCH_SERVE.json is schema-gated in tests/test_bench_sanity.py.
-SERVE_TMP="$(mktemp -d)"
-JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-BAGUA_OBS_EXPORT_DIR="$SERVE_TMP/export" BAGUA_OBS_EXPORT_INTERVAL_S=1 \
-python benchmarks/serve_bench.py --smoke --out "$SERVE_TMP/BENCH_SERVE.json"
-
-echo "=== goodput ledger over the serve smoke's metrics export ==="
-# Conservation must hold with the serving classes aboard (prefill/decode
-# as serving goodput, batch_formation_idle/weight_load as named badput):
-# every class second accounted, classes sum to wall within 1%.
-python -m bagua_tpu.obs.ledger "$SERVE_TMP/export" --check
-rm -rf "$SERVE_TMP"
-
-echo "=== bench trend sentinel (advisory) ==="
-# Quick probe re-measured with the committed artifact's own protocol,
-# compared noise-bound-aware; refreshes BENCH_TREND.json (schema-gated in
-# test_bench_sanity.py).  Advisory: regressions print and are recorded in
-# the trend artifact, they do not fail CI — cpu-sim CI hosts are noisy and
-# the probe runs fewer trials than the committed record.
-JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-python -m bagua_tpu.obs.regress --out BENCH_TREND.json \
-  || echo "advisory: bench trend sentinel reported a problem (non-blocking)"
+python scripts/autotune_smoke.py --ci > /dev/null
 
 echo "=== scale smoke (4-process loopback pod drill) ==="
 # The pod simulator end to end with REAL worker processes: cold-start
@@ -158,7 +126,7 @@ echo "=== scale smoke (4-process loopback pod drill) ==="
 # collectives over loopback rings, lease-expiry shrink, standby regrow,
 # and an autopilot straggler fence — the full coordinator lifecycle at
 # world 4 under a tight timeout.  The committed 32/64/128-rank sweep
-# (BENCH_SCALE.json) is schema-gated in tests/test_bench_sanity.py;
+# (BENCH_SCALE.json) is schema-gated in tests/test_drill_records.py;
 # regenerate it with `python scripts/scale_drill.py`.
 timeout -k 10 120 python scripts/scale_drill.py --smoke > /dev/null
 
@@ -171,7 +139,7 @@ echo "=== failover smoke (SIGKILL the live coordinator process) ==="
 # member lease TTL, ZERO workers restart, and the autopilot/historian
 # state RESUMES from the replicated store.  The committed 32-rank fault
 # matrix (FAILOVER_DRILL.json) is schema-gated in
-# tests/test_bench_sanity.py; regenerate with
+# tests/test_drill_records.py; regenerate with
 # `python scripts/failover_drill.py`.
 timeout -k 10 150 python scripts/failover_drill.py --smoke > /dev/null
 
@@ -181,7 +149,7 @@ echo "=== compressed-ring smoke (1-bit EF codec over the loopback pod) ==="
 # + mean-abs sidecars (the numpy mirror of the jax codec) — the workers'
 # transport-integrity bounds must hold and the verdict records the codec.
 # The jaxpr-exact >=12x DCN byte pins and the EF convergence separation
-# live in BENCH_COMPRESS.json (schema-gated in tests/test_bench_sanity.py).
+# are asserted live in tests/test_compressed_ring.py and tests/test_ef_residual.py.
 timeout -k 10 120 env BAGUA_SCALE_DCN_CODEC=onebit_ef \
   python scripts/scale_drill.py --smoke > /dev/null
 
@@ -189,7 +157,7 @@ echo "=== chaos fast subset (fault injection -> detection -> recovery) ==="
 # The deterministic slice of scripts/chaos_drill.py: every injection point
 # fires, every detector sees it, every recovery completes.  The committed
 # CHAOS_DRILL.json full-matrix record is schema-gated in
-# tests/test_bench_sanity.py; regenerate it with scripts/chaos_drill.py.
+# tests/test_drill_records.py; regenerate it with scripts/chaos_drill.py.
 python -m pytest tests/test_faults.py -q
 
 echo "=== unit + integration tests (8-device CPU mesh) ==="
@@ -200,14 +168,10 @@ echo "=== multichip dryrun (virtual CPU mesh) ==="
 JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
 python -c "import __graft_entry__ as g; g.dryrun_multichip(8); print('dryrun OK')"
 
-echo "=== deterministic loss goldens (CPU) ==="
-JAX_PLATFORMS=cpu XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-python bench.py --goldens
-
 if [[ "${RUN_TPU_BENCH:-0}" == "1" ]]; then
-  echo "=== chip smoke, then the TPU headline bench ==="
+  echo "=== chip smoke, then one cell of the benchmark (BENCHMARK.json) ==="
   python chip_smoke.py
-  python bench.py
+  python3 perfbench/run.py --workload bert-large.squad384-dp1 --seed 0 --trace 0
 fi
 
 echo "CI green"

@@ -46,7 +46,6 @@ MODULES = [
     "bagua_tpu.obs.export",
     "bagua_tpu.obs.timeline",
     "bagua_tpu.obs.anomaly",
-    "bagua_tpu.obs.regress",
     "bagua_tpu.obs.ledger",
     "bagua_tpu.obs.memory",
     "bagua_tpu.obs.historian",
@@ -76,7 +75,6 @@ MODULES = [
     "bagua_tpu.serve.cache",
     "bagua_tpu.serve.engine",
     "bagua_tpu.serve.loader",
-    "bagua_tpu.serve.schema",
     "bagua_tpu.ops.flash_attention",
     "bagua_tpu.ops.gmm",
     "bagua_tpu.ops.embed_grad",

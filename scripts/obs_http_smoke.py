@@ -37,6 +37,7 @@ os.environ["JAX_PLATFORMS"] = "cpu"
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
+sys.path.insert(0, os.path.join(REPO, "tests"))  # golden.py
 
 
 def _get(url):
@@ -62,7 +63,7 @@ def main(argv=None):
     import numpy as np
     import optax
 
-    import bench
+    import golden
     from bagua_tpu.algorithms import GradientAllReduceAlgorithm
     from bagua_tpu.core.backend import BaguaTrainer
     from bagua_tpu.obs import export as obs_export
@@ -85,7 +86,7 @@ def main(argv=None):
     exporter = obs_export.MetricsExporter(export_dir, interval_s=3600)
     os.makedirs(export_dir, exist_ok=True)
     try:
-        loss_fn, params, batch = bench.golden_task()
+        loss_fn, params, batch = golden.golden_task()
         trainer = BaguaTrainer(
             loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
             mesh=build_mesh({"dp": 8}), autotune=False,

@@ -217,14 +217,14 @@ def test_trainer_flags_injected_straggle(clean_obs, monkeypatch):
     with the fleet plumbing)."""
     import optax
 
-    import bench
+    import golden
     from bagua_tpu.algorithms import GradientAllReduceAlgorithm
     from bagua_tpu.core.backend import BaguaTrainer
     from bagua_tpu.faults.inject import FaultSpec, fault_scope
     from bagua_tpu.parallel.mesh import build_mesh
 
     monkeypatch.setenv("BAGUA_OBS_ANOMALY_WARMUP", "4")
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     t = BaguaTrainer(loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
                      mesh=build_mesh({"dp": 8}), autotune=False)
     assert t.anomaly_detector is not None
@@ -254,13 +254,13 @@ def test_trainer_flags_injected_straggle(clean_obs, monkeypatch):
 def test_anomaly_off_knob(monkeypatch):
     import optax
 
-    import bench
+    import golden
     from bagua_tpu.algorithms import GradientAllReduceAlgorithm
     from bagua_tpu.core.backend import BaguaTrainer
     from bagua_tpu.parallel.mesh import build_mesh
 
     monkeypatch.setenv("BAGUA_OBS_ANOMALY", "off")
-    loss_fn, params, _ = bench.golden_task()
+    loss_fn, params, _ = golden.golden_task()
     t = BaguaTrainer(loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
                      mesh=build_mesh({"dp": 8}), autotune=False)
     assert t.anomaly_detector is None

@@ -295,10 +295,10 @@ def test_restore_resets_async_schedule(tmp_path):
     """Checkpoint restore (same world) runs the algorithm's ``on_restore``
     hook: no stale ``_pending``/``_anchor``/period crosses the restore —
     the resumed run opens a fresh calibration window."""
-    import bench
+    import golden
     from bagua_tpu.checkpoint import BaguaCheckpointManager
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     algo = AsyncModelAverageAlgorithm(warmup_steps=1, period_steps=3)
     trainer = BaguaTrainer(loss_fn, optax.sgd(0.1), algo, autotune=False)
     st = trainer.init(params)
@@ -337,11 +337,11 @@ def test_async_elastic_world_resize_restore(tmp_path):
     makes the stacked per-rank rows bit-identical, the dp8 save re-tiles
     onto a dp4 trainer through the stacked-resize restore path, and the
     resumed run opens a fresh calibration window."""
-    import bench
+    import golden
     from bagua_tpu.checkpoint import BaguaCheckpointManager
     from bagua_tpu.parallel.mesh import build_mesh
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     algo = AsyncModelAverageAlgorithm(warmup_steps=1, period_steps=3)
     tr8 = BaguaTrainer(loss_fn, optax.sgd(0.1), algo,
                        mesh=build_mesh({"dp": 8}), autotune=False)
@@ -385,11 +385,11 @@ def test_async_resize_restore_divergent_rows_raise(tmp_path):
     """A stacked checkpoint saved WITHOUT the pre-save sync (divergent
     per-rank rows) must refuse a cross-world restore actionably rather
     than silently picking one rank's replica."""
-    import bench
+    import golden
     from bagua_tpu.checkpoint import BaguaCheckpointManager
     from bagua_tpu.parallel.mesh import build_mesh
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     algo = AsyncModelAverageAlgorithm(warmup_steps=0, period_steps=100)
     tr8 = BaguaTrainer(loss_fn, optax.sgd(0.1), algo,
                        mesh=build_mesh({"dp": 8}), autotune=False)

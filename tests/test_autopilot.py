@@ -927,12 +927,12 @@ def test_service_autopilot_retune_reopens_completed_search():
 def golden_trainer():
     import optax
 
-    import bench
+    import golden
     from bagua_tpu.algorithms import GradientAllReduceAlgorithm
     from bagua_tpu.core.backend import BaguaTrainer
     from bagua_tpu.parallel.mesh import build_mesh
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     t = BaguaTrainer(loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
                      mesh=build_mesh({"dp": N_DEVICES}), autotune=False,
                      flat_resident="off")
@@ -994,13 +994,13 @@ def test_family_switch_stacks_rows_bit_identically(golden_trainer):
 def test_family_switch_refused_for_flat_resident():
     import optax
 
-    import bench
+    import golden
     from bagua_tpu.algorithms import GradientAllReduceAlgorithm
     from bagua_tpu.core.backend import BaguaTrainer
     from bagua_tpu.define import BaguaHyperparameter
     from bagua_tpu.parallel.mesh import build_mesh
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     t = BaguaTrainer(loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
                      mesh=build_mesh({"dp": N_DEVICES}), autotune=False,
                      flat_resident="on")

@@ -11,25 +11,24 @@ def test_probe_host_parses_json_lines(tmp_path):
     fake.write_text(
         'print("noise")\n'
         'import json\n'
-        'print(json.dumps({"collective": "allreduce", "busbw_GBps": 12.5}))\n'
+        'print(json.dumps({"metrics": {"tokens_per_s_per_chip": '
+        '{"value": 12.5}}}))\n'
     )
     args = parse_args([
         "--host_list", "hostA", "--ssh_cmd", "bash -c",
-        "--python", "python", "--probe", "collective",
+        "--python", "python",
     ])
     # redirect the probe command at the fake script
     from bagua_tpu.service import autotune_system
 
-    autotune_system.PROBES["collective"] = str(fake)
+    autotune_system.PROBE = str(fake)
     r = probe_host(args, "hostA")
-    assert r["ok"] and r["records"][0]["busbw_GBps"] == 12.5
+    assert r["ok"] and autotune_system._score(r) == 12.5
 
 
 def test_sysperf_flags_straggler(tmp_path, capfd):
     from bagua_tpu.service import autotune_system
 
-    fast = tmp_path / "fast.py"
-    fast.write_text('import json; print(json.dumps({"busbw_GBps": 100.0}))\n')
     args = parse_args([
         "--host_list", "h1,h2,h3",
         # each "host" runs the same probe; make h3 slow via hostname switch
@@ -39,9 +38,10 @@ def test_sysperf_flags_straggler(tmp_path, capfd):
     probe = tmp_path / "probe.py"
     probe.write_text(
         "import json, os\n"
-        "print(json.dumps({'busbw_GBps': 100.0}))\n"
+        "print(json.dumps({'metrics': {'tokens_per_s_per_chip': "
+        "{'value': 100.0}}}))\n"
     )
-    autotune_system.PROBES["collective"] = str(probe)
+    autotune_system.PROBE = str(probe)
     rc = sysperf(args)
     out, _ = capfd.readouterr()
     lines = [json.loads(l) for l in out.splitlines() if l.strip()]

@@ -138,6 +138,40 @@ def test_ef_state_created_and_trains(codec):
     assert all(bool(jnp.isfinite(b).all()) for b in _ef(state))
 
 
+@pytest.mark.parametrize("codec", ["onebit_ef", "topk"])
+def test_residual_closes_the_gap_to_the_uncompressed_run(codec, monkeypatch):
+    """What the residual is for: over 60 steps of the exact-loss gate's
+    task (tests/golden.py) the compensated run ends nearer the uncompressed
+    run's loss than the stateless control (``BAGUA_EF_RESIDUAL=off``), whose
+    gap is the quantization bias the residual cancels."""
+    import golden
+
+    loss_fn, params, batch = golden.golden_task()
+
+    def final_loss(compress_inter):
+        trainer = BaguaTrainer(
+            loss_fn, optax.sgd(0.1),
+            GradientAllReduceAlgorithm(hierarchical=True),
+            mesh=build_mesh({"inter": INTER, "intra": INTRA}),
+            bucket_bytes=65536, autotune=False, overlap="off",
+            **({} if compress_inter is None
+               else {"compress_inter": compress_inter}),
+        )
+        state = trainer.init(params)
+        data = trainer.shard_batch(batch)
+        for _ in range(60):
+            state, loss = trainer.train_step(state, data)
+        return float(loss)
+
+    uncompressed = final_loss(None)
+    compensated = final_loss(codec)
+    monkeypatch.setenv("BAGUA_EF_RESIDUAL", "off")
+    stateless = final_loss(codec)
+    assert abs(compensated - uncompressed) < abs(stateless - uncompressed), (
+        uncompressed, compensated, stateless)
+    assert compensated < stateless
+
+
 def test_no_codec_keeps_algo_state_none():
     trainer, state = _make(None)
     assert not trainer._ef_active()

@@ -1,7 +1,7 @@
 """Cross-topology elastic resume against the golden-gate harness: save at
 world size 4, elastic-restore at 2 and at 8, and require loss-trajectory
 continuity — the resumed run must land on the same final loss as the
-uninterrupted run of ``bench.golden_task()`` (the exact-loss gate's task,
+uninterrupted run of ``golden.golden_task()`` (the exact-loss gate's task,
 tests/test_loss_goldens.py).
 
 "World size" here is the dp mesh extent inside the single 8-virtual-device
@@ -15,7 +15,7 @@ import numpy as np
 import optax
 import pytest
 
-import bench
+import golden
 from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
 from bagua_tpu.checkpoint import BaguaCheckpointManager
 from bagua_tpu.core.backend import BaguaTrainer
@@ -45,7 +45,7 @@ def _run(trainer, state, batch, steps: int):
 
 @pytest.fixture(scope="module")
 def task():
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     # the uninterrupted 30-step trajectory this platform's golden gate
     # certifies (goldens are platform-specific; recompute, don't hardcode)
     trainer = _trainer(loss_fn, 4)

@@ -36,7 +36,7 @@ def one_chip():
 @pytest.mark.parametrize("b,s,h,d", [
     (8, 1024, 16, 64),    # gpt2-medium.pretrain1024-dp1: two heads a block
     (2, 4096, 16, 128),   # olmoe-1b-7b.pretrain4096-dp1: one head a block
-    (1, 8192, 8, 64),     # bench.py's long-context shape class
+    (1, 8192, 8, 64),     # a long-context shape class (s = 8192)
 ])
 def test_the_kernels_compile_for_the_described_v5e(b, s, h, d, one_chip):
     x = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)

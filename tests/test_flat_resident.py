@@ -12,7 +12,7 @@ Pinned contracts:
   (``relayout_flats``) without perturbing the trajectory;
 * checkpoints round-trip across layouts AND plans:
   save-flat -> restore-leaf -> restore-flat continuity against
-  ``bench.golden_task()``;
+  ``golden.golden_task()``;
 * ``flat_resident="off"`` reproduces the leaf construction exactly
   (leaf-pytree state, no flat containers anywhere in the step's HLO).
 """
@@ -479,9 +479,9 @@ def test_checkpoint_flat_leaf_flat_continuity(tmp_path):
     the uninterrupted golden-task trajectory: bit-equal while both sides
     run the same compiled program, within a few float32 ulp once the
     layout (and with it the program) differs."""
-    import bench
+    import golden
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
 
     def make(mode, bucket_bytes=256):
         return BaguaTrainer(
@@ -541,9 +541,9 @@ def test_checkpoint_flat_leaf_flat_continuity(tmp_path):
 def test_checkpoint_flat_to_flat_replan(tmp_path):
     """A flat checkpoint restores into a flat trainer with ANOTHER plan via
     flat->flat relayout — no leaf materialization on either side."""
-    import bench
+    import golden
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
 
     def make(bucket_bytes):
         return BaguaTrainer(
@@ -582,9 +582,9 @@ def test_checkpoint_flat_to_flat_replan(tmp_path):
 def test_checkpoint_zero_cross_plan_still_blocked(tmp_path):
     """Sharded-opt-state ZeRO keeps the actionable cross-plan error: its
     per-chunk optimizer states have no host-side conversion."""
-    import bench
+    import golden
 
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
 
     def make(bucket_bytes):
         return BaguaTrainer(

@@ -344,14 +344,28 @@ def _tier_wire_bytes(trainer, state, batch):
     return dcn, ici
 
 
-def test_dcn_bytes_reduced_to_shard():
+@pytest.mark.parametrize(
+    "algo_factory,optimizer,dcn_share",
+    [
+        # the pure shard: exactly 1/intra of the flat path's bytes
+        (lambda h: GradientAllReduceAlgorithm(hierarchical=h),
+         optax.sgd(0.1), 1.01 / INTRA),
+        # below the shard: the flat path's gather legs all cross the boundary
+        (lambda h: ZeroOptimizerAlgorithm(optax.sgd(0.1, momentum=0.9),
+                                          hierarchical=h), None, 0.5),
+        # the codec's per-rank min/max scales do not shrink with the shard,
+        # yet the slow link's bytes must still halve
+        (lambda h: ByteGradAlgorithm(hierarchical=h), optax.sgd(0.1), 0.5),
+    ],
+    ids=["gradient_allreduce", "zero", "bytegrad"],
+)
+def test_dcn_bytes_reduced_to_shard(algo_factory, optimizer, dcn_share):
     """The flat path moves every bucket's FULL bytes across the slice
     boundary; the two-level path moves the 1/intra_size shard (+ the
     4-byte loss reduction) — the acceptance ratio of ISSUE 11."""
     def build(hierarchical):
         trainer = BaguaTrainer(
-            _loss_fn, optax.sgd(0.1),
-            GradientAllReduceAlgorithm(hierarchical=hierarchical),
+            _loss_fn, optimizer, algo_factory(hierarchical),
             mesh=_hier_mesh(), bucket_bytes=256, autotune=False,
             overlap="off",
         )
@@ -370,8 +384,8 @@ def test_dcn_bytes_reduced_to_shard():
     dcn_two, ici_two = _tier_wire_bytes(*build(True))
     loss_scalar_bytes = 4
     assert dcn_two - loss_scalar_bytes <= (
-        (dcn_flat - loss_scalar_bytes) / INTRA
-    ) * 1.01 + 8  # +8: per-bucket intra-padding slack
+        (dcn_flat - loss_scalar_bytes) * dcn_share
+    ) + 8  # +8: per-bucket intra-padding slack
     # and the ICI tiers took over the heavy lifting
     assert ici_two > dcn_two
 

@@ -10,25 +10,22 @@ snapshot's efficiency rollup."""
 
 import json
 import os
-import sys
 import time
 
 import numpy as np
 import optax
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import bench  # noqa: E402
-
-from bagua_tpu import telemetry  # noqa: E402
-from bagua_tpu.algorithms import GradientAllReduceAlgorithm  # noqa: E402
-from bagua_tpu.core.backend import BaguaTrainer  # noqa: E402
-from bagua_tpu.faults.inject import FaultSpec, fault_scope  # noqa: E402
-from bagua_tpu.obs import export as obs_export  # noqa: E402
-from bagua_tpu.obs import ledger as obs_ledger  # noqa: E402
-from bagua_tpu.obs import memory as obs_memory  # noqa: E402
-from bagua_tpu.obs import spans as obs_spans  # noqa: E402
-from bagua_tpu.parallel.mesh import build_mesh  # noqa: E402
+import golden
+from bagua_tpu import telemetry
+from bagua_tpu.algorithms import GradientAllReduceAlgorithm
+from bagua_tpu.core.backend import BaguaTrainer
+from bagua_tpu.faults.inject import FaultSpec, fault_scope
+from bagua_tpu.obs import export as obs_export
+from bagua_tpu.obs import ledger as obs_ledger
+from bagua_tpu.obs import memory as obs_memory
+from bagua_tpu.obs import spans as obs_spans
+from bagua_tpu.parallel.mesh import build_mesh
 
 N_DEVICES = 8
 
@@ -52,7 +49,7 @@ def ledger_on():
 
 
 def _golden_trainer(**kw):
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     t = BaguaTrainer(loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
                      mesh=build_mesh({"dp": N_DEVICES}), autotune=False, **kw)
     s = t.init(params)
@@ -472,68 +469,3 @@ def test_timeline_ledger_counter_track(ledger_on, tmp_path):
     assert set(counter_events[-1]["args"]) == set(
         c for c in obs_ledger.LEDGER_CLASSES if c != "idle_other")
     assert trace["metadata"]["ranks"]["0"]["ledger_samples"] >= 3
-
-
-# ---- EFFICIENCY.json schema + regress consumption -------------------------
-
-
-def test_validate_efficiency_unit():
-    good = {
-        "schema": obs_ledger.EFFICIENCY_SCHEMA,
-        "time_unix": 1.0, "platform": "cpu-sim", "n_devices": 8,
-        "config": {"family": "gradient_allreduce"},
-        "ledger": {
-            "wall_s": 1.0,
-            "classes": {c: (0.5 if c == "productive_step" else 0.0)
-                        for c in obs_ledger.LEDGER_CLASSES},
-            "goodput_fraction": 0.5,
-        },
-        "footprint": {"params_bytes": 4, "opt_state_bytes": 0,
-                      "algo_state_bytes": 0, "grad_flats_bytes": 4,
-                      "total_bytes": 8},
-        "mfu": {"available": False, "rationale": "cpu"},
-        "trend_records": [{"metric": "m", "value": 1.0}],
-    }
-    assert obs_ledger.validate_efficiency(good) == []
-    bad = json.loads(json.dumps(good))
-    bad["ledger"]["classes"]["compile"] = 99.0  # classes >> wall
-    assert any("exceeds wall" in p
-               for p in obs_ledger.validate_efficiency(bad))
-    bad2 = json.loads(json.dumps(good))
-    bad2["footprint"]["total_bytes"] = 7
-    assert any("sum of components" in p
-               for p in obs_ledger.validate_efficiency(bad2))
-    bad3 = json.loads(json.dumps(good))
-    bad3["mfu"] = {"available": False}
-    assert any("rationale" in p for p in obs_ledger.validate_efficiency(bad3))
-
-
-def test_regress_direction_aware_comparison():
-    from bagua_tpu.obs.regress import compare_records
-
-    committed = [
-        {"metric": "efficiency_hbm_static_footprint_bytes", "value": 1000,
-         "unit": "bytes", "higher_better": False, "noise_bound": False},
-        {"metric": "efficiency_goodput_fraction", "value": 0.5,
-         "unit": "fraction", "higher_better": True, "noise_bound": True},
-    ]
-    # memory bloat on a lower-is-better metric must flag as regressed
-    fresh = [
-        {"metric": "efficiency_hbm_static_footprint_bytes", "value": 2000,
-         "unit": "bytes", "higher_better": False, "noise_bound": False},
-        {"metric": "efficiency_goodput_fraction", "value": 0.2,
-         "unit": "fraction", "higher_better": True, "noise_bound": True},
-    ]
-    verdicts = {c["metric"]: c for c in compare_records(fresh, committed)}
-    assert verdicts["efficiency_hbm_static_footprint_bytes"]["verdict"] \
-        == "regressed"
-    assert verdicts["efficiency_hbm_static_footprint_bytes"][
-        "higher_better"] is False
-    # the noise-bound goodput record can never produce a false regression
-    assert verdicts["efficiency_goodput_fraction"]["verdict"] \
-        == "noise_bound"
-    # a memory SHRINK on lower-is-better reads as improved
-    fresh[0]["value"] = 500
-    verdicts = {c["metric"]: c for c in compare_records(fresh, committed)}
-    assert verdicts["efficiency_hbm_static_footprint_bytes"]["verdict"] \
-        == "improved"

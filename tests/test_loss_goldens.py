@@ -7,19 +7,19 @@ seed/task/mesh (8-device CPU, conftest), proving the algorithm math is
 deterministic and unchanged; async (a host-timing-dependent algorithm) gets
 an upper bound, as in the reference (< 0.004 there).
 
-Regenerate after an intentional algorithm change: ``python bench.py --goldens``
-on the 8-device CPU mesh.
+After an intentional algorithm change, re-pin from this file's own failure
+output (each case prints the loss ``golden.loss_goldens()`` produced).
 """
 
 import numpy as np
 import pytest
 
-import bench
+import golden
 
-# python bench.py --goldens  (8-device CPU mesh, 30 steps; jax 0.9.0).
+# tests/golden.py::loss_goldens  (8-device CPU mesh, 30 steps; jax 0.9.0).
 # Goldens are toolchain- as well as platform-specific (jax.random's sampling
 # and XLA:CPU's reduction order both feed the last digits): after a
-# toolchain change, re-pin every family from one `--goldens` run.
+# toolchain change, re-pin every family from one run of this file.
 # bytegrad/qadam re-pinned in PR 22 — their previous values predated ISSUE
 # 15's one-pass allgather leg (0.888740 -> 0.888764, 1.180702 -> 1.181477);
 # the other families reproduced bit-unchanged.
@@ -40,7 +40,7 @@ ASYNC_BOUND = 1.0  # async final loss is timing-dependent; must still converge
 
 @pytest.fixture(scope="module")
 def final_losses():
-    return bench.loss_goldens()
+    return golden.loss_goldens()
 
 
 @pytest.mark.parametrize("family", sorted(GOLDENS))

@@ -7,7 +7,6 @@ import glob
 import json
 import os
 import re
-import sys
 import time
 
 import jax
@@ -15,18 +14,16 @@ import numpy as np
 import optax
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import bench  # noqa: E402
-
-import bagua_tpu  # noqa: E402
-from bagua_tpu import telemetry  # noqa: E402
-from bagua_tpu.algorithms import GradientAllReduceAlgorithm  # noqa: E402
-from bagua_tpu.core.backend import BaguaTrainer  # noqa: E402
-from bagua_tpu.faults.inject import FaultSpec, fault_scope  # noqa: E402
-from bagua_tpu.obs import export as obs_export  # noqa: E402
-from bagua_tpu.obs import recorder as obs_recorder  # noqa: E402
-from bagua_tpu.obs import spans as obs_spans  # noqa: E402
-from bagua_tpu.parallel.mesh import build_mesh  # noqa: E402
+import golden
+import bagua_tpu
+from bagua_tpu import telemetry
+from bagua_tpu.algorithms import GradientAllReduceAlgorithm
+from bagua_tpu.core.backend import BaguaTrainer
+from bagua_tpu.faults.inject import FaultSpec, fault_scope
+from bagua_tpu.obs import export as obs_export
+from bagua_tpu.obs import recorder as obs_recorder
+from bagua_tpu.obs import spans as obs_spans
+from bagua_tpu.parallel.mesh import build_mesh
 
 N_DEVICES = 8
 
@@ -44,7 +41,7 @@ def obs_on():
 
 
 def _golden_trainer(**kw):
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     t = BaguaTrainer(loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
                      mesh=build_mesh({"dp": N_DEVICES}), autotune=False, **kw)
     s = t.init(params)
@@ -164,19 +161,24 @@ def test_flight_dump_on_collective_hang(obs_on, tmp_path, monkeypatch):
 
     monkeypatch.setenv("BAGUA_OBS_DUMP_DIR", str(tmp_path))
     wd = HangWatchdog(timeout_s=0.3, action="abort")
+    hang = abort_dump = []
     try:
-        with fault_scope(FaultSpec("collective.hang", duration_s=1.5)):
+        # the hang outlasts the wait below and ends only with wd.stop(): the
+        # monitor fires while the section is wedged however loaded the host
+        # is, and the test waits for the two dumps, not for a clock
+        with fault_scope(FaultSpec("collective.hang", duration_s=300)):
             wd.watch_result(np.zeros(()), "wedged-step")
-            deadline = time.time() + 15
-            while not wd.fired.is_set() and time.time() < deadline:
+            deadline = time.time() + 120
+            while not (hang and abort_dump) and time.time() < deadline:
                 time.sleep(0.05)
-            assert wd.fired.is_set()
+                hang = _flight_dumps(str(tmp_path), trigger="fault_fire",
+                                     fault_point="collective.hang")
+                abort_dump = _flight_dumps(str(tmp_path),
+                                           trigger="watchdog_abort")
     finally:
         wd.stop()
         bagua_tpu.reset_abort()
-    hang = _flight_dumps(str(tmp_path), trigger="fault_fire",
-                         fault_point="collective.hang")
-    abort_dump = _flight_dumps(str(tmp_path), trigger="watchdog_abort")
+    assert wd.fired.is_set()
     assert hang and abort_dump, os.listdir(tmp_path)
     rec = abort_dump[0]
     assert obs_recorder.validate_flight_record(rec) == []

@@ -8,7 +8,6 @@ enabled, and the span-overhead budget re-asserted with both on."""
 import json
 import os
 import re
-import sys
 import threading
 import time
 import urllib.error
@@ -17,19 +16,17 @@ import urllib.request
 import optax
 import pytest
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-import bench  # noqa: E402
-
-from bagua_tpu import telemetry  # noqa: E402
-from bagua_tpu.algorithms import GradientAllReduceAlgorithm  # noqa: E402
-from bagua_tpu.core.backend import BaguaTrainer  # noqa: E402
-from bagua_tpu.obs import export as obs_export  # noqa: E402
-from bagua_tpu.obs import http as obs_http  # noqa: E402
-from bagua_tpu.obs import spans as obs_spans  # noqa: E402
-from bagua_tpu.obs.historian import Historian  # noqa: E402
-from bagua_tpu.obs.http import ObsHTTPServer  # noqa: E402
-from bagua_tpu.parallel.mesh import build_mesh  # noqa: E402
-from bagua_tpu.podsim.util import reserve_port  # noqa: E402
+import golden
+from bagua_tpu import telemetry
+from bagua_tpu.algorithms import GradientAllReduceAlgorithm
+from bagua_tpu.core.backend import BaguaTrainer
+from bagua_tpu.obs import export as obs_export
+from bagua_tpu.obs import http as obs_http
+from bagua_tpu.obs import spans as obs_spans
+from bagua_tpu.obs.historian import Historian
+from bagua_tpu.obs.http import ObsHTTPServer
+from bagua_tpu.parallel.mesh import build_mesh
+from bagua_tpu.podsim.util import reserve_port
 
 N_DEVICES = 8
 NOW = 1_754_000_000.0
@@ -55,7 +52,7 @@ def _series(prom_text):
 
 
 def _golden_trainer(**kw):
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     t = BaguaTrainer(loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
                      mesh=build_mesh({"dp": N_DEVICES}), autotune=False, **kw)
     s = t.init(params)

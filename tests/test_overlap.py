@@ -13,9 +13,9 @@ Pinned contracts:
 * ``overlap="off"`` restores the exact serialized step construction (HLO
   text identical to the ``auto``-resolved accum=1 default, no ring
   collective-permute chains);
-* the ``auto`` dispatch gate follows the measured record
-  (BENCH_OVERLAP.json) and the autotune recommendation path carries the
-  overlap knobs.
+* the ``auto`` dispatch gate follows ``Algorithm.overlap_auto`` (set from
+  a cpu-sim record, never measured on the chip: ROADMAP Queue 3 item 3) and
+  the autotune recommendation path carries the overlap knobs.
 """
 
 import jax
@@ -286,11 +286,11 @@ def test_auto_gate_follows_measurement():
         t.init(params)
         return t
 
-    # measured faster serialized at accum==1; overlap at accum>1
+    # the gate's setting: serialized at accum==1; overlap at accum>1
     assert not trainer_for(GradientAllReduceAlgorithm(), 1)._overlap_active()
     assert trainer_for(GradientAllReduceAlgorithm(), 4)._overlap_active()
-    # zero and bytegrad measured slower under overlap on this platform
-    # (BENCH_OVERLAP.json): auto stays serialized, explicit on still wins
+    # zero and bytegrad set overlap_auto=False (from a cpu-sim record):
+    # auto stays serialized, explicit on still wins
     assert not trainer_for(ZeroOptimizerAlgorithm(optax.adam(1e-2)),
                            4)._overlap_active()
     assert trainer_for(ZeroOptimizerAlgorithm(optax.adam(1e-2)), 4,

@@ -21,7 +21,7 @@ import numpy as np
 import optax
 import pytest
 
-import bench
+import golden
 from bagua_tpu.algorithms import (
     ByteGradAlgorithm, GradientAllReduceAlgorithm, ZeroOptimizerAlgorithm,
 )
@@ -56,7 +56,7 @@ def obs_on():
 
 
 def golden_trainer(algorithm="gradient_allreduce", **kw):
-    loss_fn, params, batch = bench.golden_task()
+    loss_fn, params, batch = golden.golden_task()
     # 600-byte buckets: the golden MLP's 1024-byte kernel stands alone in
     # its own shape, the three smaller leaves share a 1-D flat (at 256 every
     # leaf would be its own bucket and nothing would run under bagua.layout)
@@ -137,7 +137,7 @@ def test_remat_replay_is_named_by_jax_itself():
 
 
 def test_one_chip_world_exchanges_no_bucket():
-    loss_fn, params, batch = bench.golden_task(batch_size=8)
+    loss_fn, params, batch = golden.golden_task(batch_size=8)
     trainer = BaguaTrainer(
         loss_fn, optax.sgd(0.1), GradientAllReduceAlgorithm(),
         mesh=build_mesh({"dp": 1}, jax.devices()[:1]), autotune=False)
@@ -602,7 +602,7 @@ def test_prefetch_divides_the_wait_for_input(obs_on):
     from bagua_tpu.contrib.prefetch import prefetch_to_device
 
     trainer, _, _ = golden_trainer()
-    _, _, batch = bench.golden_task()
+    _, _, batch = golden.golden_task()
     batches = prefetch_to_device(iter([batch] * 3), trainer=trainer, size=1)
     obs_spans.set_current_step(None)
     assert len(list(batches)) == 3
