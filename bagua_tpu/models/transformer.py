@@ -24,7 +24,8 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ..obs.spans import (
-    EXIT_SCOPE, LOOP_SCOPE, LOSS_TAIL_SCOPE, POS_EMBED_SCOPE, phase_scope,
+    DIFFUSION_INPUT_SCOPE, EXIT_SCOPE, LOOP_SCOPE, LOSS_TAIL_SCOPE,
+    POS_EMBED_SCOPE, phase_scope,
 )
 from ..parallel.mesh import axis_bound as _axis_bound
 from ..utils import remat_wrap
@@ -95,8 +96,10 @@ class TransformerConfig:
     #: table).  The decode paths do not implement it.
     rope_theta: Optional[float] = None
     #: RMSNorm on the flat ``n_heads * head_dim`` wide q and k, before the
-    #: head split (OLMoE's ``q_norm`` / ``k_norm``)
-    qk_norm: bool = False
+    #: head split (OLMoE's ``q_norm`` / ``k_norm``).  ``"head"``: on each
+    #: head's ``head_dim`` lanes by itself, one ``[head_dim]`` scale for all
+    #: the heads (the Qwen3 family's)
+    qk_norm: Any = False
     #: epsilon of every RMSNorm of the model
     norm_eps: float = 1e-6
     #: key / value heads, each read by ``n_heads // n_kv_heads`` consecutive
@@ -137,6 +140,26 @@ class TransformerConfig:
     #: [n_passes, batch, seq, vocab], gate logits [batch, seq, n_passes])``.
     #: Off, a looped model returns the last pass's logits like any other
     exit_gate: bool = False
+    #: the mask of every layer: ``"causal"`` (cut to ``window`` where a layer
+    #: has one), or ``"block_diffusion"``, the training view of a block-
+    #: diffusion model: the model is handed ``[x ; x~]``, a clean sequence
+    #: and then its noised copy (``block_diffusion_loss_fn`` assembles it),
+    #: both halves sit at positions ``0 .. L - 1``, every layer attends
+    #: under ``ops.flash_attention.block_diffusion_mask``, and the head
+    #: reads the noised half alone: ``[b, 2 L]`` tokens -> ``[b, L, vocab]``
+    #: logits.  The decode paths, ``sp_axis``, pipeline stages and a looped
+    #: stack do not implement it
+    attention: str = "causal"
+    #: positions of a diffusion block: within one, noised rows see each
+    #: other in both directions.  Read with ``attention="block_diffusion"``
+    diffusion_block: int = 0
+
+    @property
+    def block_diffusion(self) -> bool:
+        if self.attention not in ("causal", "block_diffusion"):
+            raise ValueError(f"attention kind {self.attention!r}: "
+                             "'causal' or 'block_diffusion'")
+        return self.attention == "block_diffusion"
 
     @property
     def head_dim(self) -> int:
@@ -245,13 +268,20 @@ def rotates_by_kernel(cfg: TransformerConfig, seq: int, attn_fn=None) -> bool:
     writes and they read, on heads of whole 128-lane tiles.  Everywhere else
     (off the TPU, short or ragged sequences, head_dim 64, the einsum path,
     an ``attn_fn`` drop-in, decode) :func:`rope_rotate`."""
-    from ..ops.flash_attention import flash_supported
+    from ..ops.flash_attention import (
+        block_diffusion_supported, flash_supported,
+    )
     from ..ops.rope import rope_supported
 
+    heads, kv_heads = cfg.n_heads // cfg.tp_size, cfg.kv_heads // cfg.tp_size
+    if cfg.block_diffusion:
+        # ``seq`` rows are two halves, each rotated at ``0 .. seq / 2 - 1``
+        return (attn_fn is None and rope_supported(seq // 2, cfg.head_dim)
+                and block_diffusion_supported(seq, heads, cfg.head_dim,
+                                              kv_heads=kv_heads))
     return (attn_fn is None and not cfg.decode
             and rope_supported(seq, cfg.head_dim)
-            and flash_supported(seq, cfg.n_heads // cfg.tp_size, cfg.head_dim,
-                                kv_heads=cfg.kv_heads // cfg.tp_size))
+            and flash_supported(seq, heads, cfg.head_dim, kv_heads=kv_heads))
 
 
 def causal_attention(q, k, v, dtype, window=None):
@@ -372,10 +402,14 @@ class Attention(nn.Module):
         # the projections hand the attention what it reads: the flash
         # kernels [b, s, h * d] (HeadsDense), the plain einsums [b, s, h, d];
         # same parameters either way
-        from ..ops.flash_attention import flash_supported
+        from ..ops.flash_attention import (
+            block_diffusion_attention, block_diffusion_supported,
+            flash_supported,
+        )
 
-        if not cfg.decode and flash_supported(x.shape[1], h, d,
-                                              kv_heads=kv_h):
+        by_kernel = (block_diffusion_supported if cfg.block_diffusion
+                     else flash_supported)
+        if not cfg.decode and by_kernel(x.shape[1], h, d, kv_heads=kv_h):
             dense = lambda name, out=None, heads=h: HeadsDense(
                 heads, d, out, name=name, dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype)
@@ -387,7 +421,11 @@ class Attention(nn.Module):
         q, k, v = (checkpoint_name(
             dense(n, heads=h if n == "q" else kv_h)(x), KEPT_QKV)
             for n in "qkv")
-        if cfg.qk_norm:
+        if cfg.qk_norm == "head":
+            head_norm = lambda name: RMSNorm(
+                cfg.dtype, cfg.param_dtype, cfg.norm_eps, name=name)
+            q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
+        elif cfg.qk_norm:
             if _tp_active(cfg):
                 raise NotImplementedError(
                     "qk_norm normalizes over all heads; they are sharded "
@@ -406,11 +444,25 @@ class Attention(nn.Module):
             rotate = rope_rotate
             if rotates_by_kernel(cfg, q.shape[1], self.attn_fn):
                 from ..ops.rope import rope as rotate
-            q, k = (rotate(t, cfg.rope_theta, start) for t in (q, k))
+            if cfg.block_diffusion:
+                # positions restart: the noised half sits at 0 .. L - 1
+                # like the clean one.  [b, 2 L, h, d] -> [2 b, L, h, d] and
+                # back are free reshapes around the one rotation
+                halves = lambda t: rotate(
+                    t.reshape(2 * t.shape[0], t.shape[1] // 2, *t.shape[2:]),
+                    cfg.rope_theta, start).reshape(t.shape)
+                q, k = halves(q), halves(k)
+            else:
+                q, k = (rotate(t, cfg.rope_theta, start) for t in (q, k))
         if cfg.decode and cfg.page_size > 0:
             o = self._paged_decode_attend(q, k, v, slots)
         elif cfg.decode:
             o = self._decode_attend(q, k, v)
+        elif cfg.block_diffusion:
+            # the ``flash_bd_*`` kernels where they run, the plain jnp form
+            # under the same mask elsewhere
+            fn = self.attn_fn or block_diffusion_attention
+            o = fn(q, k, v, cfg.dtype, diffusion_block=cfg.diffusion_block)
         else:
             fn = self.attn_fn or causal_attention
             # a full layer keeps the four-argument call the sequence-
@@ -605,8 +657,39 @@ class Block(nn.Module):
         return x
 
 
+def _check_block_diffusion(cfg: TransformerConfig, rows: int) -> None:
+    """What ``attention="block_diffusion"`` asks of the configuration and of
+    the ``rows`` it is handed; the paths that cannot take the mask refuse
+    it here."""
+    if cfg.decode:
+        raise NotImplementedError(
+            "attention='block_diffusion' is the training view of a block-"
+            "diffusion model; generation by blocks is not implemented on the "
+            "decode paths")
+    if cfg.sp_axis is not None:
+        raise NotImplementedError(
+            "attention='block_diffusion' is not implemented under sp_axis: "
+            "a sequence shard would need the other half's keys and the "
+            "drop-ins' masks are causal")
+    if cfg.n_passes > 1 or cfg.exit_gate:
+        raise NotImplementedError(
+            "attention='block_diffusion' is not implemented for a looped "
+            "stack (n_passes > 1, exit_gate)")
+    if cfg.window is not None or cfg.rope_layers is not None:
+        raise NotImplementedError(
+            "attention='block_diffusion' is one kind of layer: no window, "
+            "no rope_layers pattern")
+    if cfg.diffusion_block < 1 or rows % 2 or (rows // 2) % cfg.diffusion_block:
+        raise ValueError(
+            f"attention='block_diffusion' reads [x ; x~], a sequence of "
+            f"whole diffusion blocks twice over; got {rows} rows at "
+            f"diffusion_block={cfg.diffusion_block}")
+
+
 class TransformerLM(nn.Module):
-    """Causal LM: token ids [batch, seq] -> logits [batch, seq, vocab]."""
+    """Causal LM: token ids [batch, seq] -> logits [batch, seq, vocab]
+    (``attention="block_diffusion"``: ``[x ; x~]`` [batch, 2 seq] -> the
+    noised half's logits [batch, seq, vocab])."""
 
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None
@@ -621,6 +704,8 @@ class TransformerLM(nn.Module):
                 "`slots` is only meaningful for paged decode configs "
                 "(decode=True, page_size > 0)"
             )
+        if cfg.block_diffusion:
+            _check_block_diffusion(cfg, tokens.shape[1])
         x = TokenEmbed(
             cfg.vocab_size, cfg.d_model, name="embed",
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -678,7 +763,9 @@ class TransformerLM(nn.Module):
             counters.set_gauge("attn/kv_heads", cfg.kv_heads // cfg.tp_size)
             counters.set_gauge("attn/window", cfg.window or 0)
             counters.set_gauge("attn/window_layers", windowed)
-            counters.set_gauge("attn/full_layers", cfg.n_layers - windowed)
+            counters.set_gauge(
+                "attn/full_layers",
+                0 if cfg.block_diffusion else cfg.n_layers - windowed)
             # the rotary layers whose rotation is the ``rope`` kernel
             by_kernel = rotates_by_kernel(cfg, tokens.shape[1], self.attn_fn)
             counters.set_gauge("attn/rope_kernel_layers", by_kernel * sum(
@@ -690,6 +777,15 @@ class TransformerLM(nn.Module):
             if cfg.n_passes > 1:
                 counters.set_gauge("loop/passes", cfg.n_passes)
                 counters.set_gauge("loop/shared_layers", cfg.n_layers)
+            if cfg.block_diffusion:
+                counters.set_gauge("attn/diffusion_block",
+                                   cfg.diffusion_block)
+                counters.set_gauge("attn/block_diffusion_layers",
+                                   cfg.n_layers)
+                # positions that can carry loss: the clean tokens, each
+                # trained through two rows
+                counters.set_gauge("diffusion/tokens_per_step",
+                                   tokens.shape[0] * tokens.shape[1] // 2)
         if cfg.decode and cfg.n_passes > 1:
             raise NotImplementedError(
                 "n_passes > 1 is not implemented for the decode paths (a "
@@ -728,7 +824,12 @@ class TransformerLM(nn.Module):
                 return logits.astype(jnp.float32)
 
         if cfg.n_passes == 1 and not cfg.exit_gate:
-            x = final_norm(stack(x))
+            x = stack(x)
+            if cfg.block_diffusion:
+                # the prediction for position i is read at the noised row
+                # i: logits of the clean half are never used
+                x = x[:, x.shape[1] // 2:]
+            x = final_norm(x)
             if not self.head:
                 return x.astype(jnp.float32)
             return float32_logits(lm_head(x))
@@ -911,6 +1012,65 @@ def looped_lm_loss_fn(model: TransformerLM, beta: float = 0.05):
             expected = jnp.sum(p * jnp.moveaxis(nll, 0, -1), axis=-1)
             entropy = -jnp.sum(p * log_p, axis=-1)
             return jnp.mean(expected - beta * entropy)
+
+    return loss_fn
+
+
+def block_diffusion_noise(tokens, rng, *, block: int, mask_id: int,
+                          eps: float = 1e-3) -> dict:
+    """A batch of ``block_diffusion_loss_fn`` from the clean ``tokens`` [b,
+    L], drawn on the host for an input pipeline: per diffusion block of
+    ``block`` positions a noise level ``t ~ U[eps, 1]`` (the linear
+    schedule: ``t`` is the probability of a mask), per position ``masked ~
+    Bernoulli(t)`` of its block.  ``rng``: a ``numpy.random.Generator``.
+    ``mask_id`` is the id the loss puts in the masked positions of the
+    noised copy; here it only must not occur among ``tokens``.  Publishes
+    how many positions of the batch carry loss
+    (``diffusion/masked_tokens_per_step``)."""
+    import numpy as np
+
+    from ..telemetry import counters
+
+    tokens = np.asarray(tokens)
+    b, seq = tokens.shape
+    if seq % block:
+        raise ValueError(f"{seq} positions are not whole blocks of {block}")
+    if (tokens == mask_id).any():
+        raise ValueError(f"mask_id {mask_id} occurs among the clean tokens")
+    t = rng.uniform(eps, 1.0, size=(b, seq // block)).astype(np.float32)
+    masked = rng.random(size=(b, seq)) < np.repeat(t, block, axis=1)
+    counters.set_gauge("diffusion/masked_tokens_per_step", int(masked.sum()))
+    return {"tokens": tokens, "masked": masked, "t": t}
+
+
+def block_diffusion_loss_fn(model: TransformerLM, mask_id: int):
+    """Masked block-diffusion loss of a ``TransformerConfig(attention=
+    "block_diffusion")`` model; batch = dict(tokens=[b, L] clean ids,
+    masked=[b, L] bool, t=[b, L // diffusion_block] float32), as
+    :func:`block_diffusion_noise` draws it.  The model reads ``[x ; x~]``,
+    ``x~`` the tokens with ``mask_id`` at the masked positions, and its
+    logits at noised row ``i`` predict token ``i`` itself (no shift):
+
+        (1 / (b L)) sum_i masked_i / t_block(i) * CE(logits_i, x_i)
+
+    (the 1 / t-weighted objective of masked diffusion under the linear
+    schedule, a block's own ``t``: arXiv:2503.09573).  The cross-entropy
+    stays per token until it is weighed (``token_loss_tail``: no float32
+    logits in HBM)."""
+    block = model.cfg.diffusion_block
+
+    def loss_fn(params, batch):
+        tokens, masked = batch["tokens"], batch["masked"]
+        with phase_scope(DIFFUSION_INPUT_SCOPE):
+            noised = jnp.where(masked, jnp.asarray(mask_id, tokens.dtype),
+                               tokens)
+            rows = jnp.concatenate([tokens, noised], axis=1)
+        logits = model.apply({"params": params}, rows)
+        nll = token_loss_tail(logits, tokens)                   # [b, L]
+        with phase_scope(LOSS_TAIL_SCOPE):
+            weight = masked / jnp.repeat(
+                batch["t"].astype(jnp.float32), block, axis=1)
+            return jnp.mean(weight * nll)
 
     return loss_fn
 

@@ -134,6 +134,24 @@ _declare("attn/rope_kernel_layers", "gauge",
          "head_dim] where the flash kernels run, heads of whole 128-lane "
          "tiles); a looped model's scanned body counts once.  0 where "
          "every rotary layer took rope_rotate, or none rotates.")
+_declare("attn/diffusion_block", "gauge",
+         "Positions of a diffusion block of the block-diffusion model last "
+         "traced (TransformerConfig.diffusion_block): its rows are a clean "
+         "sequence and its noised copy under the four-quadrant mask.")
+_declare("attn/block_diffusion_layers", "gauge",
+         "Layers of that model that attend under the block-diffusion mask "
+         "(kernels flash_bd_fwd / flash_bd_bwd_dq / flash_bd_bwd_dkv where "
+         "the flash kernels run): all of them.")
+# -- block-diffusion training --
+_declare("diffusion/tokens_per_step", "gauge",
+         "Clean positions of the block-diffusion step last traced on this "
+         "rank (batch x sequence): each runs through the trunk twice, as "
+         "itself and as its noised copy, and can carry loss.")
+_declare("diffusion/masked_tokens_per_step", "gauge",
+         "Positions of the batch block_diffusion_noise drew last that are "
+         "masked in the noised copy: the ones whose cross-entropy the loss "
+         "weighs; over diffusion/tokens_per_step about the mean noise "
+         "level, one half.")
 # -- token table (set when a TransformerLM step is traced) --
 _declare("embed/grad_kernel", "gauge",
          "1 where the token table's gradient in the model last traced is "

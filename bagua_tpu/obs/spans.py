@@ -50,6 +50,7 @@ from .. import env as _env
 __all__ = ["trace_span", "trace_step_span", "phase_scope", "area_of",
            "in_loop", "AREAS", "LOSS_TAIL_SCOPE", "ACCUM_SCOPE",
            "POS_EMBED_SCOPE", "EXIT_SCOPE", "LOOP_SCOPE",
+           "DIFFUSION_INPUT_SCOPE",
            "recorder", "span_ring", "SpanRecorder", "enabled", "set_enabled",
            "set_current_step", "set_ledger_sink"]
 
@@ -390,6 +391,10 @@ EXIT_SCOPE = "exit_dist"
 #: area (the modules inside it do) and that no reader of the phases matches.
 #: The passes are one scanned body, so it names the body and not the pass
 LOOP_SCOPE = "loop_body"
+#: the assembly of a block-diffusion model's input ``[x ; x~]`` from the
+#: batch's tokens and noise (``block_diffusion_loss_fn``): the mask id put
+#: in, the two halves laid end to end
+DIFFUSION_INPUT_SCOPE = "diffusion_input"
 #: the scope whose NEXT component is the part of an expert layer
 MOE_SCOPE = "bagua.moe"
 MOE_PARTS = ("route", "dispatch", "experts", "combine")
@@ -399,6 +404,7 @@ MOE_PARTS = ("route", "dispatch", "experts", "combine")
 #: to it and ``docs/observability.md`` has it as a table.
 AREA_COMPONENTS = {
     "embed": "embed", POS_EMBED_SCOPE: "embed",
+    DIFFUSION_INPUT_SCOPE: "embed",
     "attn_norm": "attn", "attn": "attn", "attn_post_norm": "attn",
     "mlp_norm": "mlp", "mlp": "mlp", "mlp_post_norm": "mlp",
     "final_norm": "head", "lm_head": "head", LOSS_TAIL_SCOPE: "head",

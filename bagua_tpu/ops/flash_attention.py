@@ -48,6 +48,13 @@ Design (FlashAttention-2 style, TPU-first):
   Windowed calls are named ``flash_win_fwd`` / ``flash_win_bwd_dq`` /
   ``flash_win_bwd_dkv``.
 
+- a block-diffusion mask (``diffusion_block``: the rows are a clean
+  sequence and its noised copy, ``[x ; x~]``, under the four-quadrant mask
+  of block-diffusion training, :func:`block_diffusion_mask`): kernels of
+  their own at the end of this file, ``flash_bd_fwd`` / ``flash_bd_bwd_dq``
+  / ``flash_bd_bwd_dkv``, whose loops visit the blocks that hold a visible
+  pair and no other.
+
 Falls back to the plain jnp implementation off-TPU, for tiny/ragged
 sequence lengths, for heads the 128-lane blocks cannot take (``heads *
 head_dim`` no multiple of 128, an odd head count at 64), and under
@@ -69,19 +76,43 @@ NEG_INF = -1e30
 _LANE = 128
 
 
+def block_diffusion_mask(seq: int, block: int):
+    """The ``[seq, seq]`` boolean mask of block-diffusion training over the
+    rows ``[x ; x~]``: a clean sequence of ``seq // 2`` positions and then
+    its noised copy, position ``i`` of either in diffusion block ``i //
+    block``.  A clean query sees the clean keys of its own block and the
+    blocks before it; a noised query the clean keys of the blocks before
+    its own and the noised keys of its own block; no clean query sees a
+    noised key.  Dense: the fallback's and the tests' form; the kernels
+    build it a visited block pair at a time, in registers."""
+    half = seq // 2
+    row = jnp.arange(seq)
+    noised = row >= half
+    blk = (row - half * noised) // block
+    q_n, k_n = noised[:, None], noised[None, :]
+    q_b, k_b = blk[:, None], blk[None, :]
+    return jnp.where(k_n, q_n & (k_b == q_b),
+                     jnp.where(q_n, k_b < q_b, k_b <= q_b))
+
+
 def reference_attention(q, k, v, dtype, causal: bool = True,
-                        window: int | None = None):
+                        window: int | None = None,
+                        diffusion_block: int | None = None):
     """Plain (materializing) attention; the fallback and the test golden.
     ``q``: [batch, seq, heads, head_dim]; ``k/v`` the same, or with fewer
     (key / value) heads, each shared by ``heads // kv_heads`` consecutive
     query heads.  ``window`` (causal only): query ``i`` sees the keys
-    ``i - window < j <= i``."""
+    ``i - window < j <= i``.  ``diffusion_block``: the rows are ``[x ;
+    x~]`` under :func:`block_diffusion_mask` in place of the causal one."""
     b, s, h, d = q.shape
     if k.shape[2] != h:
         k, v = (jnp.repeat(t, h // t.shape[2], axis=2) for t in (k, v))
     scale = 1.0 / jnp.sqrt(d).astype(jnp.float32)
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
-    if causal:
+    if diffusion_block is not None:
+        mask = block_diffusion_mask(s, diffusion_block)
+        logits = jnp.where(mask[None, None], logits, NEG_INF)
+    elif causal:
         mask = jnp.tril(jnp.ones((s, s), jnp.bool_))
         if window is not None:
             mask &= ~jnp.tril(jnp.ones((s, s), jnp.bool_), -window)
@@ -694,3 +725,401 @@ def flash_attention(q, k, v, dtype=None, *, causal: bool = True,
     o, _ = _in_model_layout(q, k, v, causal, block_q, block_k, interpret,
                             _band(window, causal, s))
     return o.astype(dtype)
+
+
+# ---------------------------------------------------------------------------
+# block diffusion: the rows [x ; x~] under the four-quadrant mask
+# ---------------------------------------------------------------------------
+#
+# ``half`` positions of a clean sequence and then of its noised copy, diffusion
+# blocks of ``block`` positions (:func:`block_diffusion_mask`).  Of the (2
+# half)^2 pairs ``half * (half + block)`` are visible.  Each kernel works out,
+# from scalars, which blocks of the other side hold a visible pair of its own
+# block, and which of those are visible WHOLE: two loops, one over the whole
+# blocks with no mask and one over the edges with the mask built in registers
+# from per-row (per-column) bounds.  Rows are taken by position alone, so a
+# kernel block may straddle the two halves (``half`` no multiple of it).
+# One head a 128-lane block (``head_dim`` a multiple of 128), grouped key /
+# value heads as in the causal kernels.
+
+
+def _cdiv(a, b):
+    return (a + b - 1) // b
+
+
+def _bd_k_segments(r0, block_q, block_k, half, block):
+    """``(whole, edges)``: the k blocks the q block of rows ``r0 .. r0 +
+    block_q - 1`` visits, as lists of ``(first, end)`` in ascending order —
+    ``whole`` those every row of it sees every key of, ``edges`` the others
+    that hold a visible pair (the clean blocks at its diagonal, then its
+    own noised block).  ``r0`` may be traced."""
+    r1 = r0 + block_q
+    has_clean, has_noised = r0 < half, r1 > half
+    i0, i1 = jnp.maximum(r0, half) - half, r1 - half   # its noised positions
+    # clean keys [0, seen) some row sees, [0, every) every row does
+    seen = jnp.maximum(
+        jnp.where(has_clean,
+                  ((jnp.minimum(r1, half) - 1) // block + 1) * block, 0),
+        jnp.where(has_noised, (i1 - 1) // block * block, 0))
+    every = jnp.minimum(
+        jnp.where(has_clean, (r0 // block + 1) * block, half),
+        jnp.where(has_noised, i0 // block * block, half))
+    clean_end = _cdiv(seen, block_k)
+    # noised keys of the diffusion blocks its noised rows lie in
+    first = jnp.maximum((half + i0 // block * block) // block_k, clean_end)
+    end = jnp.where(has_noised,
+                    _cdiv(half + ((i1 - 1) // block + 1) * block, block_k),
+                    first)
+    return ([(0, every // block_k)],
+            [(every // block_k, clean_end), (first, end)])
+
+
+def _bd_q_segments(c0, block_q, block_k, half, block):
+    """``(whole, edges)`` of the q blocks that visit the k block of columns
+    ``c0 .. c0 + block_k - 1``: :func:`_bd_k_segments` from the other side.
+    A clean key is seen by the clean rows from its diffusion block on and by
+    the noised rows behind it, a noised key by the noised rows of its own
+    block."""
+    c1 = c0 + block_k
+    n_qb = 2 * half // block_q
+    has_clean, has_noised = c0 < half, c1 > half
+    first_blk = c0 // block                       # of its first clean key
+    last_blk = (jnp.minimum(c1, half) - 1) // block       # of its last
+    j0, j1 = jnp.maximum(c0, half) - half, c1 - half  # its noised positions
+    half_end = _cdiv(half, block_q)
+    # the noised rows of its noised keys' diffusion blocks
+    own_lo = (half + j0 // block * block) // block_q
+    own_end = _cdiv(half + ((j1 - 1) // block + 1) * block, block_q)
+    clean_only = jnp.logical_and(has_clean, jnp.logical_not(has_noised))
+    e1_lo = jnp.where(has_clean, first_blk * block // block_q, own_lo)
+    w1_hi = jnp.where(has_clean,
+                      jnp.where(has_noised, half_end, half // block_q),
+                      own_lo)
+    w1_lo = jnp.where(clean_only,
+                      jnp.minimum(_cdiv(last_blk * block, block_q), w1_hi),
+                      w1_hi)
+    e2_end = jnp.where(has_noised, jnp.maximum(own_end, w1_hi), half_end)
+    e3_lo = jnp.where(
+        has_clean,
+        jnp.maximum((half + (first_blk + 1) * block) // block_q, e2_end),
+        n_qb)
+    w2_lo = jnp.where(clean_only,
+                      _cdiv(half + (last_blk + 1) * block, block_q), n_qb)
+    return ([(w1_lo, w1_hi), (w2_lo, n_qb)],
+            [(e1_lo, w1_lo), (w1_hi, e2_end), (e3_lo, w2_lo)])
+
+
+def _walk(segments, body, carry):
+    """``fori_loop`` of ``body(block index, carry)`` over the blocks of
+    ``segments`` in order: one loop, its counter mapped onto the segments by
+    scalar selects."""
+    starts, total = [], 0
+    for lo, hi in segments:
+        starts.append(total)
+        total = total + (hi - lo)
+
+    def at(t):
+        index = segments[-1][0] + t - starts[-1]
+        for k in range(len(segments) - 2, -1, -1):
+            index = jnp.where(t < starts[k + 1],
+                              segments[k][0] + t - starts[k], index)
+        return index
+
+    return lax.fori_loop(0, total, lambda t, c: body(at(t), c), carry)
+
+
+def _bd_row_bounds(rows, half, block):
+    """Per query row ``(clean_end, own_lo)``: it sees the clean keys ``c <
+    clean_end`` and the noised keys ``own_lo <= c < own_lo + block`` (none:
+    ``own_lo`` past every key, for a clean row)."""
+    noised = rows >= half
+    first = (rows - jnp.where(noised, half, 0)) // block * block
+    return (jnp.where(noised, first, first + block),
+            jnp.where(noised, half + first, 2 * half))
+
+
+def _bd_keep_rows(bounds, k_pos, block):
+    clean_end, own_lo = bounds
+    return (k_pos < clean_end) | ((k_pos >= own_lo) & (k_pos < own_lo + block))
+
+
+def _bd_col_bounds(cols, half, block):
+    """Per key column ``(lo, hi, behind)``: the rows ``lo <= r < hi`` and
+    ``r >= behind`` see it — a clean key its own and the later clean rows
+    and the noised rows behind its block, a noised key its block's noised
+    rows."""
+    noised = cols >= half
+    first = (cols - jnp.where(noised, half, 0)) // block * block
+    return (jnp.where(noised, half + first, first),
+            jnp.where(noised, half + first + block, half),
+            jnp.where(noised, 2 * half, half + first + block))
+
+
+def _bd_keep_cols(bounds, q_pos):
+    lo, hi, behind = bounds
+    return ((q_pos >= lo) & (q_pos < hi)) | (q_pos >= behind)
+
+
+def _bd_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k, scale,
+                   half, block):
+    block_q, lanes = q_ref.shape[1], q_ref.shape[2]
+    r0 = pl.program_id(1) * block_q
+    q = q_ref[0]
+    whole, edges = _bd_k_segments(r0, block_q, block_k, half, block)
+    bounds = _bd_row_bounds(
+        r0 + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0), half, block)
+
+    def visit(masked):
+        def body(kb, carry):
+            o, m, l = carry
+            k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
+            v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
+            logits = scale * _dot(q, k_blk, _NT)
+            if masked:
+                # a row none of whose keys this block holds accumulates
+                # exp(0) here; its own noised (or clean) key, in the last
+                # block it visits at the latest, rescales that to 0
+                k_pos = kb * block_k + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                logits = jnp.where(_bd_keep_rows(bounds, k_pos, block),
+                                   logits, NEG_INF)
+            m_new = jnp.maximum(m, logits.max(axis=-1, keepdims=True))
+            corr = jnp.exp(m - m_new)
+            p = jnp.exp(logits - m_new)
+            l = l * corr + p.sum(axis=-1, keepdims=True)
+            o = o * corr + _dot(p.astype(v_blk.dtype), v_blk, _NN)
+            return o, m_new, l
+        return body
+
+    carry = (jnp.zeros((block_q, lanes), jnp.float32),
+             jnp.full((block_q, 1), NEG_INF, jnp.float32),
+             jnp.zeros((block_q, 1), jnp.float32))
+    carry = _walk(whole, visit(False), carry)
+    o, m, l = _walk(edges, visit(True), carry)
+    l = jnp.maximum(l, 1e-30)
+    o_ref[0] = (o / l).astype(o_ref.dtype)
+    lse_ref[0] = jnp.broadcast_to((m + jnp.log(l)).reshape(1, block_q),
+                                  (8, block_q))
+
+
+def _bd_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, *, block_k, scale, half, block):
+    block_q, lanes = q_ref.shape[1], q_ref.shape[2]
+    r0 = pl.program_id(1) * block_q
+    q, do = q_ref[0], do_ref[0]
+    lse = _stat(lse_ref, 0, r0, block_q)
+    delta = _stat(delta_ref, 0, r0, block_q)
+    whole, edges = _bd_k_segments(r0, block_q, block_k, half, block)
+    bounds = _bd_row_bounds(
+        r0 + lax.broadcasted_iota(jnp.int32, (block_q, 1), 0), half, block)
+
+    def visit(masked):
+        def body(kb, dq):
+            k_blk = k_ref[0, pl.ds(kb * block_k, block_k), :]
+            v_blk = v_ref[0, pl.ds(kb * block_k, block_k), :]
+            s_ij = scale * _dot(q, k_blk, _NT)
+            if masked:
+                k_pos = kb * block_k + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 1)
+                s_ij = jnp.where(_bd_keep_rows(bounds, k_pos, block), s_ij,
+                                 NEG_INF)
+            p = jnp.exp(s_ij - lse)
+            dp = _dot(do, v_blk, _NT)
+            ds = (p * (dp - delta)).astype(k_blk.dtype)
+            return dq + _dot(ds, k_blk, _NN)
+        return body
+
+    dq = _walk(whole, visit(False), jnp.zeros((block_q, lanes), jnp.float32))
+    dq = _walk(edges, visit(True), dq)
+    dq_ref[0] = (scale * dq).astype(dq_ref.dtype)
+
+
+def _bd_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                       dk_ref, dv_ref, *sums, block_q, scale, half, block,
+                       group):
+    """One k block of one query head; the group's query heads on the
+    innermost grid axis, summed in ``sums`` as in ``_bwd_dkv_kernel``."""
+    block_k, lanes = k_ref.shape[1], k_ref.shape[2]
+    c0 = pl.program_id(1) * block_k
+    k, v = k_ref[0], v_ref[0]
+    whole, edges = _bd_q_segments(c0, block_q, block_k, half, block)
+    bounds = _bd_col_bounds(
+        c0 + lax.broadcasted_iota(jnp.int32, (1, block_k), 1), half, block)
+
+    def visit(masked):
+        def body(qb, carry):
+            dk, dv = carry
+            q_blk = q_ref[0, pl.ds(qb * block_q, block_q), :]
+            do_blk = do_ref[0, pl.ds(qb * block_q, block_q), :]
+            lse = _stat(lse_ref, 0, qb * block_q, block_q)
+            delta = _stat(delta_ref, 0, qb * block_q, block_q)
+            s_ij = scale * _dot(q_blk, k, _NT)
+            if masked:
+                q_pos = qb * block_q + lax.broadcasted_iota(
+                    jnp.int32, (block_q, block_k), 0)
+                s_ij = jnp.where(_bd_keep_cols(bounds, q_pos), s_ij, NEG_INF)
+            p = jnp.exp(s_ij - lse).astype(k.dtype)
+            dv = dv + _dot(p, do_blk, _TN)
+            dp = _dot(do_blk, v, _NT)
+            ds = (p.astype(jnp.float32) * (dp - delta)).astype(k.dtype)
+            return dk + _dot(ds, q_blk, _TN), dv
+        return body
+
+    zeros = jnp.zeros((block_k, lanes), jnp.float32)
+    carry = _walk(whole, visit(False), (zeros, zeros))
+    dk, dv = _walk(edges, visit(True), carry)
+    dk = scale * dk
+    if group == 1:
+        dk_ref[0] = dk.astype(dk_ref.dtype)
+        dv_ref[0] = dv.astype(dv_ref.dtype)
+        return
+    dk_sum, dv_sum = sums
+    member = pl.program_id(2)
+
+    @pl.when(member == 0)
+    def _():
+        dk_sum[...] = dk
+        dv_sum[...] = dv
+
+    @pl.when(member > 0)
+    def _():
+        dk_sum[...] += dk
+        dv_sum[...] += dv
+
+    @pl.when(member == group - 1)
+    def _():
+        dk_ref[0] = dk_sum[...].astype(dk_ref.dtype)
+        dv_ref[0] = dv_sum[...].astype(dv_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
+def _bd_fwd(q, k, v, heads, block, block_q, block_k, interpret):
+    """q: [b, 2 half, heads * d], k/v: [b, 2 half, kv_heads * d] -> (o like
+    q, lse [b * heads, 1, 2 half] f32)."""
+    b, s, hd = q.shape
+    d = hd // heads
+    _, tensor, kv_tensor, _ = _specs(s, heads, d, k.shape[2] // d)
+    o, stripe = pl.pallas_call(
+        functools.partial(_bd_fwd_kernel, block_k=block_k,
+                          scale=1.0 / (d ** 0.5), half=s // 2, block=block),
+        grid=(b * heads, s // block_q),
+        in_specs=[tensor(block_q), kv_tensor(s), kv_tensor(s)],
+        out_specs=[
+            tensor(block_q),
+            pl.BlockSpec((1, 8, block_q), lambda i, j: (i, 0, j),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_shape=[
+            jax.ShapeDtypeStruct((b, s, hd), q.dtype),
+            jax.ShapeDtypeStruct((b * heads, 8, s), jnp.float32),
+        ],
+        interpret=interpret,
+        name="flash_bd_fwd",
+    )(q, k, v)
+    return o, stripe[:, :1, :]
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
+def _bd_bwd(q, k, v, o, lse, do, heads, block, block_q, block_k, interpret):
+    b, s, hd = q.shape
+    d = hd // heads
+    kv_heads = k.shape[2] // d
+    _, tensor, kv_tensor, stat_rows = _specs(s, heads, d, kv_heads)
+    delta = (
+        (do.astype(jnp.float32) * o.astype(jnp.float32))
+        .reshape(b, s, heads, d)
+        .sum(axis=-1)
+        .transpose(0, 2, 1)
+        .reshape(lse.shape)
+    )
+    kind = dict(scale=1.0 / (d ** 0.5), half=s // 2, block=block)
+    grid, in_specs, out_specs, scratch = _dkv_call(
+        b, s, heads, kv_heads, d, block_k, tensor, stat_rows)
+    dk, dv = pl.pallas_call(
+        functools.partial(_bd_bwd_dkv_kernel, block_q=block_q,
+                          group=heads // kv_heads, **kind),
+        grid=grid,
+        in_specs=in_specs,
+        out_specs=out_specs,
+        out_shape=[
+            jax.ShapeDtypeStruct(k.shape, k.dtype),
+            jax.ShapeDtypeStruct(v.shape, v.dtype),
+        ],
+        scratch_shapes=scratch,
+        interpret=interpret,
+        name="flash_bd_bwd_dkv",
+    )(q, k, v, do, lse, delta)
+    dq = pl.pallas_call(
+        functools.partial(_bd_bwd_dq_kernel, block_k=block_k, **kind),
+        grid=(b * heads, s // block_q),
+        in_specs=[tensor(block_q), kv_tensor(s), kv_tensor(s),
+                  tensor(block_q), stat_rows, stat_rows],
+        out_specs=tensor(block_q),
+        out_shape=jax.ShapeDtypeStruct((b, s, hd), q.dtype),
+        interpret=interpret,
+        name="flash_bd_bwd_dq",
+    )(q, k, v, do, lse, delta)
+    return dq, dk, dv
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _flash_bd(q, k, v, heads, block, block_q, block_k, interpret):
+    return _bd_fwd(q, k, v, heads, block, block_q, block_k, interpret)[0]
+
+
+def _flash_bd_fwd(q, k, v, heads, block, block_q, block_k, interpret):
+    o, lse = _bd_fwd(q, k, v, heads, block, block_q, block_k, interpret)
+    # the causal kernels' tags: a remat policy that keeps theirs keeps these
+    o = checkpoint_name(o, KEPT_O)
+    lse = checkpoint_name(lse, KEPT_LSE)
+    return o, (q, k, v, o, lse)
+
+
+def _flash_bd_bwd(heads, block, block_q, block_k, interpret, res, do):
+    return _bd_bwd(*res, do, heads, block, block_q, block_k, interpret)
+
+
+_flash_bd.defvjp(_flash_bd_fwd, _flash_bd_bwd)
+
+
+def block_diffusion_supported(seq: int, heads: int, head_dim: int,
+                              block: int = _LANE,
+                              kv_heads: int | None = None) -> bool:
+    """:func:`flash_supported` for the ``flash_bd_*`` kernels over ``seq``
+    rows (both halves): the same gates, and one head a 128-lane block."""
+    return (head_dim % _LANE == 0
+            and flash_supported(seq, heads, head_dim, block, kv_heads))
+
+
+def block_diffusion_attention(q, k, v, dtype=None, *, diffusion_block: int,
+                              block_q: int = 0, block_k: int = 0,
+                              interpret: bool = False, force: bool = False):
+    """Attention of block-diffusion training: ``q`` is [batch, 2 half,
+    heads, head_dim], the rows a clean sequence and then its noised copy;
+    ``k/v`` the same or with fewer (key / value) heads; every row sees the
+    keys :func:`block_diffusion_mask` gives it, under one softmax over both
+    halves.  Returns [batch, 2 half, heads, head_dim] in ``dtype``.  The
+    kernels where :func:`block_diffusion_supported` (``force``: wherever a
+    grid covers the shape, for the interpret-mode tests), elsewhere
+    :func:`reference_attention` under the same mask."""
+    from .tiles import pick_block
+
+    b, s, h, d = q.shape
+    kv_h = k.shape[2]
+    dtype = dtype or q.dtype
+    if s % 2 or (s // 2) % diffusion_block:
+        raise ValueError(
+            f"{s} rows are no clean and noised copy of a sequence of whole "
+            f"diffusion blocks of {diffusion_block}")
+    block_q = block_q or pick_block(s)
+    block_k = block_k or pick_block(s)
+    covered = (s % block_q == 0 and s % block_k == 0 and d % _LANE == 0
+               and kv_grouping_supported(h, kv_h, d))
+    if not covered or not (force or block_diffusion_supported(
+            s, h, d, max(block_q, block_k), kv_h)):
+        return reference_attention(q, k, v, dtype,
+                                   diffusion_block=diffusion_block)
+    merged = lambda x: x.reshape(b, s, x.shape[2] * d)
+    o = _flash_bd(merged(q), merged(k), merged(v), h, int(diffusion_block),
+                  block_q, block_k, interpret)
+    return o.reshape(b, s, h, d).astype(dtype)

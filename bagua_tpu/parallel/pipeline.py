@@ -82,6 +82,12 @@ class PipelinedTransformerLM(nn.Module):
                 f"head: n_passes={cfg.n_passes} / exit_gate={cfg.exit_gate} "
                 "(a looped stack's passes under pipeline stages) are not "
                 "implemented")
+        if cfg.block_diffusion:
+            raise NotImplementedError(
+                "the pipelined stack attends causally over tokens[:, :-1]: "
+                "attention='block_diffusion' (the [x ; x~] rows, their mask, "
+                "the noised half's head) is not implemented under pipeline "
+                "stages")
         assert cfg.n_layers % self.pp_size == 0, (cfg.n_layers, self.pp_size)
         n_local = cfg.n_layers // self.pp_size
 

@@ -161,6 +161,10 @@ def test_residual_closes_the_gap_to_the_uncompressed_run(codec, monkeypatch):
         data = trainer.shard_batch(batch)
         for _ in range(60):
             state, loss = trainer.train_step(state, data)
+            # fenced: with several steps in flight on the 8-device virtual
+            # mesh XLA:CPU's rendezvous deadlocks on a loaded host (tier-1's
+            # -n 6: the process aborts; PR 31 saw the same in an example)
+            loss.block_until_ready()
         return float(loss)
 
     uncompressed = final_loss(None)

@@ -285,34 +285,64 @@ def _whole_layer(seed=3, tokens=40):
     }
 
 
-def _share_of(layer, ep_size, rank):
+def _sdar_reference():
+    path = (Path(__file__).resolve().parents[1] / "perfbench" / "reference"
+            / "sdar.py")
+    spec = importlib.util.spec_from_file_location("sdar_reference", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+#: the expert settings of the two architectures that run one rank's share:
+#: ``MoEMLP``'s options, whether the router reads another input than the
+#: experts, and the plain reference's expert layer over ALL the experts
+EXPERT_SETTINGS = {
+    # ReLU-gated, softmax over the winners, the router on the block's input
+    "smallthinker": dict(
+        options=dict(activation="relu"), routes_apart=True,
+        whole=lambda layer, tables: ref.moe(
+            layer["m"].reshape(-1, D), layer["route_x"].reshape(-1, D),
+            tables, {"experts_per_token": K, "first_expert": 0,
+                     "activation": jax.nn.relu})),
+    # SiLU-gated, softmax over all experts then the winners renormalised
+    "sdar": dict(
+        options=dict(activation="silu", norm_topk_prob=True),
+        routes_apart=False,
+        whole=lambda layer, tables: _sdar_reference().moe(
+            layer["m"].reshape(-1, D), tables,
+            {"experts_per_token": K, "first_expert": 0})),
+}
+
+
+def _share_of(layer, ep_size, rank, settings):
     """Rank ``rank``'s part of the layer's result, by ``MoEMLP`` holding
     its slice of the tables."""
     n_local = EXPERTS // ep_size
     held = slice(rank * n_local, (rank + 1) * n_local)
     moe = MoEMLP(n_experts=EXPERTS, d_ff=FF, k=K, ep_size=ep_size,
-                 ep_rank=rank, dropless=True, gated=True, activation="relu",
-                 dtype=jnp.float32)
+                 ep_rank=rank, dropless=True, gated=True,
+                 dtype=jnp.float32, **settings["options"])
     params = {"router": {"kernel": layer["router"]},
               "expert_wi": layer["wi"][held], "expert_wg": layer["wg"][held],
               "expert_wo": layer["wo"][held]}
-    return moe.apply({"params": params}, layer["m"],
-                     route_x=layer["route_x"])
+    route = {"route_x": layer["route_x"]} if settings["routes_apart"] else {}
+    return moe.apply({"params": params}, layer["m"], **route)
 
 
+@pytest.mark.parametrize("family", list(EXPERT_SETTINGS))
 @pytest.mark.parametrize("ep_size", [2, 4, 8])
-def test_the_ranks_shares_add_up_to_the_uncut_reference(ep_size):
+def test_the_ranks_shares_add_up_to_the_uncut_reference(ep_size, family):
     """Guide section 4: the parts of the result that all the shares give add
     up to what the uncut reference gives for the whole layer."""
-    layer = _whole_layer()
+    layer, settings = _whole_layer(), EXPERT_SETTINGS[family]
     flat = lambda t: t.reshape(-1, D)
-    hyper = {"experts_per_token": K, "first_expert": 0,
-             "activation": jax.nn.relu}
     with jax.default_matmul_precision("highest"):
-        whole = ref.moe(flat(layer["m"]), flat(layer["route_x"]), {
+        whole = settings["whole"](layer, {
             "router": {"kernel": layer["router"]}, "expert_wi": layer["wi"],
-            "expert_wg": layer["wg"], "expert_wo": layer["wo"]}, hyper)
-        shares = [flat(_share_of(layer, ep_size, r)) for r in range(ep_size)]
+            "expert_wg": layer["wg"], "expert_wo": layer["wo"]})
+        shares = [flat(_share_of(layer, ep_size, r, settings))
+                  for r in range(ep_size)]
     assert float(jnp.abs(whole).max()) > 0.1
     # no share is the whole, and none is nothing
     for share in shares:
@@ -350,7 +380,7 @@ def test_relu_gated_experts_routed_from_another_input_by_hand():
     """``down(relu(gate m) * up m)`` weighted by the softmax over the
     winners of ``route_x``'s logits, written out with loops."""
     layer = _whole_layer(seed=5, tokens=12)
-    got = _share_of(layer, 1, 0).reshape(-1, D)
+    got = _share_of(layer, 1, 0, EXPERT_SETTINGS["smallthinker"]).reshape(-1, D)
     m = np.asarray(layer["m"], np.float64).reshape(-1, D)
     r = np.asarray(layer["route_x"], np.float64).reshape(-1, D)
     logits = r @ np.asarray(layer["router"], np.float64)

@@ -651,7 +651,7 @@ def pallas_call_names(path):
     return names
 
 
-@pytest.mark.parametrize("path, count", zip(KERNEL_FILES, (6, 2, 9, 1, 1)))
+@pytest.mark.parametrize("path, count", zip(KERNEL_FILES, (9, 2, 9, 1, 1)))
 def test_every_pallas_call_has_a_literal_name(path, count):
     names = pallas_call_names(path)
     assert len(names) == count
@@ -660,10 +660,11 @@ def test_every_pallas_call_has_a_literal_name(path, count):
     assert len(set(everywhere)) == len(everywhere)
     if path.endswith("flash_attention.py"):
         # the three names the gpt2 cell's readers key on, each beside
-        # its windowed twin
+        # its windowed twin; then the block-diffusion kernels' own
         assert names == ["flash_fwd", "flash_win_fwd", "flash_bwd_dkv",
                          "flash_win_bwd_dkv", "flash_bwd_dq",
-                         "flash_win_bwd_dq"]
+                         "flash_win_bwd_dq", "flash_bd_fwd",
+                         "flash_bd_bwd_dkv", "flash_bd_bwd_dq"]
 
 
 #: configuration -> rotary layers of its step on the ``rope`` kernel: the
