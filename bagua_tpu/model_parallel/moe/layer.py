@@ -20,6 +20,7 @@ the data-parallel bucket plan by the trainer (the analog of
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any, Optional
 
@@ -269,6 +270,23 @@ class MoEMLP(nn.Module):
         hidden rows rebuilt in the backward pass), ``/combine`` (the
         weighted gather out; backward: the gather of the output's
         cotangent into the layout and the gates' gradient).
+
+        Which body moves the rows (:func:`_row_kernels`; the gauge
+        ``moe/row_kernel_sites``): the gather in is XLA's on every backend
+        (``ops.gmm.take_or_zero``).  The other three are the kernels of
+        :mod:`bagua_tpu.ops.moe_rows` where the grouped-matmul kernels run
+        (a TPU, ``d`` of whole lane tiles, bf16 or float32, a
+        block-aligned layout): ``rows_sum`` for the sum over a token's
+        ``k`` rows and for the weighted sum out — the layout's rows
+        streamed once into a float32 accumulator of the tokens that is
+        resident in VMEM, no ``[T, k, d]`` array written — and ``rows_in``
+        for the combine's transpose — the cotangent resident, each slot's
+        row times its gate and its product with the layout's row in one
+        pass.  Elsewhere the ``jnp`` bodies beside them (``y[slots]``
+        summed over ``k``, ``take_or_zero`` of the cotangent), which are
+        also their goldens (``tests/test_moe_rows.py``).  Inside a bound
+        ``ep`` axis the rows travel sorted, not laid out, and every move
+        is a ``jnp`` body.
         """
         from ...ops.gmm import kernel_layout, pad_rows
         from ...telemetry import counters
@@ -306,6 +324,8 @@ class MoEMLP(nn.Module):
         if inside_mesh:
             # the rows travel sorted; the combine reads them back as such
             slots, reader = rank.reshape(tokens, k), order
+            # rows sorted, not laid out: a token's rows may be neighbours
+            by_kernel = (False, False)
             with phase_scope("bagua.moe/dispatch"):
                 x_rows = pad_rows(x, order // k, slots)
             y = self._dropless_exchange(x_rows, flat_e[order], wi, wo, wg,
@@ -320,10 +340,16 @@ class MoEMLP(nn.Module):
                                        self.d_ff)
                 reader = _take_index(order, layout.src)
                 slots = layout.pos[rank].reshape(tokens, k)
-                x_p = pad_rows(x, reader // k, slots)
+                by_kernel = _row_kernels(tokens, k, layout, x.shape[1],
+                                         x.dtype)
+                x_p = pad_rows(x, reader // k, slots, by_kernel[0])
             y = self._experts(x_p, layout, wi, wo, wg)
+        if not self.is_initializing():
+            # rows_sum serves two of the four movements, rows_in one
+            counters.set_gauge("moe/row_kernel_sites",
+                               2 * by_kernel[0] + by_kernel[1])
         with phase_scope("bagua.moe/combine"):
-            return _combine(y, gates, slots, reader)
+            return _combine(y, gates, slots, reader, by_kernel)
 
     def _dropless_exchange(self, x_rows, e_rows, wi, wo, wg, n_local):
         """EP dispatch for dropless routing: [T*k, d] rows grouped by global
@@ -430,26 +456,60 @@ def _take_index(index, src):
     return jnp.take(index, src, mode="fill", fill_value=index.shape[0])
 
 
-@jax.custom_vjp
-def _combine(y, gates, slots, reader):
+def _row_kernels(tokens, k, layout, d, dtype):
+    """Which of the layer's row movements run ``ops/moe_rows.py``'s
+    kernels, ``(by rows_sum, by rows_in)``: ``rows_sum`` the dispatch's
+    transpose and the combine, ``rows_in`` the combine's transpose — decided
+    by what the call can see: the backend, the shapes, the dtype, and
+    whether the layout is block-aligned (a token then lies at most once in
+    a tile of slots, which ``rows_sum``'s batches rely on).  The dispatch
+    itself never does: XLA's gather out of the tokens is the faster one
+    (:func:`bagua_tpu.ops.gmm.take_or_zero`)."""
+    from ...ops.moe_rows import rows_in_supported, rows_sum_supported
+
+    rows = layout.src.shape[0]
+    return (k > 1 and layout.block_rows > 1
+            and rows_sum_supported(tokens, rows, d, dtype),
+            rows_in_supported(tokens, rows, d, dtype, with_dot=True))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _combine(y, gates, slots, reader, by_kernel=(False, False)):
     """``out[t] = sum_j gates[t, j] * y[slots[t, j]]``, accumulated in
     float32 and rounded once.  ``reader`` [len(y)] is the routed pair
     ``t * k + j`` that reads each row of ``y`` (``T * k``, one past the
     end, for a padding row): with it the transpose is a gather of ``out``'s
     cotangent into ``y``'s layout, where the gates' gradient is a row
-    sum."""
+    sum.  ``by_kernel`` = (forward, transpose), :func:`_row_kernels`': where
+    set, the forward is
+    ``ops/moe_rows.py::rows_sum`` over ``reader`` (``y`` read once, a
+    token's rows added in slot order, no ``[T, k, d]`` array) and the
+    transpose ``rows_in`` (the cotangent's rows times their gates, and
+    their products with ``y`` for the gates' gradient, in one pass)."""
+    if by_kernel[0]:
+        from ...ops.moe_rows import rows_sum
+
+        tokens, k = slots.shape
+        return rows_sum(y, reader // k, tokens, (gates, reader))
     rows = y[slots].astype(jnp.float32)
     return (rows * gates[..., None]).sum(1).astype(y.dtype)
 
 
-def _combine_fwd(y, gates, slots, reader):
-    return _combine(y, gates, slots, reader), (y, gates, slots, reader)
+def _combine_fwd(y, gates, slots, reader, by_kernel):
+    return (_combine(y, gates, slots, reader, by_kernel),
+            (y, gates, slots, reader))
 
 
-def _combine_bwd(res, g):
+def _combine_bwd(by_kernel, res, g):
     from ...ops.gmm import take_or_zero
 
     y, gates, slots, reader = res
+    if by_kernel[1]:
+        from ...ops.moe_rows import rows_in
+
+        d_y, products = rows_in(g, reader // slots.shape[1], (gates, reader),
+                                dot=y)
+        return d_y, products[slots].astype(gates.dtype), None, None
     g_rows = take_or_zero(g, reader // slots.shape[1]).astype(jnp.float32)
     w = take_or_zero(gates.reshape(-1), reader)
     d_gates = (g_rows * y.astype(jnp.float32)).sum(-1)[slots]

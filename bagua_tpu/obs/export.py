@@ -186,6 +186,13 @@ _declare("moe/padded_resident_layers", "gauge",
          "that padded layout from dispatch to combine (one gather in, the "
          "expert FFN on padded rows, one gather out): the kernels run.  0 "
          "where the layer runs the dense fallback on the sorted rows.")
+_declare("moe/row_kernel_sites", "gauge",
+         "How many of the four row movements of the dropless MoE layer "
+         "last traced (tokens into the padded layout, that move's "
+         "transpose, the layout's rows back to their tokens under the "
+         "gates, and its transpose) run the kernels of ops/moe_rows.py: 3 "
+         "where they run (the move in stays XLA's gather, which is the "
+         "faster one), 0 on the jnp bodies.")
 _declare("comm/aborts", "counter",
          "Cooperative abort flag raises (watchdog fire, grad-guard abort, "
          "user abort()).")

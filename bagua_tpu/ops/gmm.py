@@ -155,26 +155,41 @@ def take_or_zero(x, idx):
     past the end (a padding slot's source).  The zero is a row appended to
     ``x`` and not a select over the result, which on the TPU is a second
     pass over the gathered rows: ``x`` is the smaller side wherever a
-    layout is entered."""
+    layout is entered.  This is the body of every move INTO a layout that
+    carries the rows as they are (:func:`pad_rows`, :func:`unpad_rows`'
+    transpose) on every backend: XLA's gather out of a ``[T, d]`` source
+    writes the layout at 550 GB/s, which ``ops/moe_rows.py::rows_in`` does
+    not beat (PERF.md §6, PR 48); the weighted move in (the combine's
+    transpose) is ``rows_in``'s where it runs."""
     zero = jnp.zeros((1,) + x.shape[1:], x.dtype)
     return jnp.take(jnp.concatenate([x, zero]), idx, axis=0, mode="clip")
 
 
-@jax.custom_vjp
-def pad_rows(x, src, slots):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def pad_rows(x, src, slots, by_kernel=False):
     """Rows into a layout: ``out[p] = x[src[p]]``, zero where ``src[p]`` is
-    ``len(x)``.  ``slots`` [len(x), m] is the inverse map, the (in-range)
-    ``p`` that read each row of ``x``, so the transpose is a gather as
-    well, ``dx[n] = sum_j g[slots[n, j]]`` (in float32 where ``m > 1``):
-    no scatter is emitted in either direction."""
+    ``len(x)`` (:func:`take_or_zero`, on every backend).  ``slots``
+    [len(x), m] is the inverse map, the (in-range) ``p`` that read each row
+    of ``x``, so the transpose is a gather as well, ``dx[n] = sum_j
+    g[slots[n, j]]`` (in float32 where ``m > 1``): no scatter is emitted in
+    either direction.  With ``by_kernel`` (the caller's: ``m > 1``, the
+    layout block-aligned so that a row of ``x`` is read at most once a
+    tile of slots, and ``ops.moe_rows.rows_sum_supported``) the transpose
+    is ``ops/moe_rows.py::rows_sum`` over ``src``, which reads ``g`` once
+    and writes no ``[len(x), m, d]`` array."""
     return take_or_zero(x, src)
 
 
-def _pad_rows_fwd(x, src, slots):
-    return pad_rows(x, src, slots), slots
+def _pad_rows_fwd(x, src, slots, by_kernel):
+    return pad_rows(x, src, slots, by_kernel), (src, slots)
 
 
-def _pad_rows_bwd(slots, g):
+def _pad_rows_bwd(by_kernel, res, g):
+    src, slots = res
+    if by_kernel:
+        from .moe_rows import rows_sum
+
+        return rows_sum(g, src, slots.shape[0]), None, None
     read = g[slots]
     if slots.shape[1] == 1:
         return read[:, 0], None, None
@@ -204,16 +219,19 @@ def _unpad_rows_bwd(src, g):
 unpad_rows.defvjp(_unpad_rows_fwd, _unpad_rows_bwd)
 
 
+def _vmem_capacity() -> int:
+    """The core's VMEM; where Pallas knows no chip (interpret mode, a
+    compile for a described chip) the v5e's 128 MiB."""
+    try:
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:
+        return 128 << 20
+
+
 def _vmem_limit() -> int:
     """The scoped VMEM a call asks of Mosaic: half of the core's, never
-    under the compiler's own default of 16 MiB (all of a v4's).  Where
-    Pallas knows no chip (interpret mode, a compile for a described chip)
-    it is the v5e's 64 of 128 MiB."""
-    try:
-        capacity = pltpu.get_tpu_info().vmem_capacity_bytes
-    except ValueError:
-        capacity = 128 << 20
-    return max(capacity // 2, 16 << 20)
+    under the compiler's own default of 16 MiB (all of a v4's)."""
+    return max(_vmem_capacity() // 2, 16 << 20)
 
 
 def _fits(block_bytes: int, vmem_limit: int) -> bool:

@@ -79,6 +79,7 @@ MODULES = [
     "bagua_tpu.ops.gmm",
     "bagua_tpu.ops.embed_grad",
     "bagua_tpu.ops.rope",
+    "bagua_tpu.ops.moe_rows",
     "bagua_tpu.ops.tiles",
     "bagua_tpu.compression.codecs",
     "bagua_tpu.compression.minmax_uint8",

@@ -250,3 +250,37 @@ def test_a_rotary_layer_rotates_q_and_k_as_the_projections_write_them(
             and str(s) in re.match(r"\w+\[([\d,]*)\]", shape).group(1).split(",")
             and not re.search(r"jit\(_(fwd|bwd)\)", op_name)]
     assert not bare, bare
+
+
+@pytest.mark.parametrize("tokens,rows,k,d", [
+    (8192, 73728, 8, 2048),     # olmoe-1b-7b.pretrain4096-dp1
+    (8192, 51200, 6, 2560),     # smallthinker-21b-a3b.pretrain8192-dp1
+    (8192, 67584, 8, 2048),     # sdar-30b-a3b.blockdiff4096-b1-dp1
+])
+def test_the_row_kernels_compile_at_the_cells_shapes(tokens, rows, k, d,
+                                                     one_chip):
+    """The three row movements of a cell's expert layer that run
+    ``ops/moe_rows.py``: the dispatch's transpose and the combine
+    (``rows_sum``: the whole float32 accumulator resident, 64 / 80 MiB) and
+    the combine's transpose (``rows_in`` with the gates and the products:
+    the whole cotangent resident) — dynamic sublane indices into the 32-bit
+    view of bf16 rows, index vectors in SMEM by the block, more VMEM than
+    Mosaic's default scope."""
+    from bagua_tpu.ops.moe_rows import rows_in, rows_sum
+
+    spec = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    y, g = spec((rows, d), jnp.bfloat16), spec((tokens, d), jnp.bfloat16)
+    gates, reader = spec((tokens, k), jnp.float32), spec((rows,), jnp.int32)
+    for name, call, args in [
+        ("moe_rows_sum", lambda y, reader: rows_sum(y, reader // k, tokens),
+         (y, reader)),
+        ("moe_rows_sum", lambda y, gates, reader: rows_sum(
+            y, reader // k, tokens, (gates, reader)), (y, gates, reader)),
+        ("moe_rows_in", lambda g, y, gates, reader: rows_in(
+            g, reader // k, (gates, reader), dot=y), (g, y, gates, reader)),
+    ]:
+        text = jax.jit(call).lower(*args).compile().as_text()
+        calls = [line for line in text.splitlines()
+                 if 'custom_call_target="tpu_custom_call"' in line]
+        assert len(calls) == 1 and name in calls[0]

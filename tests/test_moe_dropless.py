@@ -193,3 +193,25 @@ def test_dropless_ep_one_step_matches_single_shard():
             np.asarray(leaf), np.asarray(got), atol=5e-5,
             err_msg=jax.tree_util.keystr(path),
         )
+
+
+# ---------------------------------------------------------------------------
+# the row kernels (ops/moe_rows.py): the layer on them is the fallback's layer
+# ---------------------------------------------------------------------------
+
+from internal.row_kernels import (  # noqa: E402
+    QUANTITIES, assert_the_same_layer, both_paths,
+)
+
+
+@pytest.fixture(scope="module")
+def row_kernel_paths():
+    """An ungated top-2 layer of 8 experts at the kernels' lane width."""
+    return both_paths(MoEMLP(n_experts=8, d_ff=128, k=2, dropless=True,
+                             dtype=jnp.float32))
+
+
+@pytest.mark.parametrize("quantity", QUANTITIES)
+def test_the_layer_on_the_row_kernels_is_the_fallback_layer(
+        row_kernel_paths, quantity):
+    assert_the_same_layer(*row_kernel_paths, quantity)

@@ -28,6 +28,7 @@ from bagua_tpu.models.transformer import (
 )
 from bagua_tpu.obs.spans import DIFFUSION_INPUT_SCOPE, area_of
 from bagua_tpu.telemetry import counters
+from internal import row_kernels
 
 flash = importlib.import_module("bagua_tpu.ops.flash_attention")
 ROOT = Path(__file__).resolve().parents[1]
@@ -712,6 +713,23 @@ def test_the_gauges_and_the_scopes_are_published():
                  "diffusion/tokens_per_step",
                  "diffusion/masked_tokens_per_step"):
         assert is_registered(name)
+
+
+@pytest.fixture(scope="module")
+def row_kernel_paths():
+    """Rank 3 of eight's share of SDAR's expert layer (SiLU-gated, eight of
+    32 experts a token, the winners renormalised) at the kernels' lane
+    width: seven eighths of a token's pairs enter no group."""
+    return row_kernels.both_paths(MoEMLP(n_experts=32, d_ff=128, k=8, ep_size=8,
+                             ep_rank=3, dropless=True, gated=True,
+                             activation="silu", norm_topk_prob=True,
+                             dtype=jnp.float32))
+
+
+@pytest.mark.parametrize("quantity", row_kernels.QUANTITIES)
+def test_a_share_on_the_row_kernels_is_the_fallbacks_share(
+        row_kernel_paths, quantity):
+    row_kernels.assert_the_same_layer(*row_kernel_paths, quantity)
 
 
 def test_the_model_where_the_kernels_run(monkeypatch):

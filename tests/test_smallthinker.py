@@ -20,6 +20,7 @@ import numpy as np
 import pytest
 
 from bagua_tpu.model_parallel.moe.layer import MoEMLP
+from internal import row_kernels
 from bagua_tpu.models.transformer import (
     Attention, Block, TransformerConfig, TransformerLM, lm_loss_fn,
     rope_rotate,
@@ -349,6 +350,22 @@ def test_the_ranks_shares_add_up_to_the_uncut_reference(ep_size, family):
         assert 1e-3 < float(jnp.abs(share).max())
         assert float(jnp.abs(share - whole).max()) > 1e-3
     np.testing.assert_allclose(sum(shares), whole, atol=2e-5, rtol=0)
+
+
+@pytest.fixture(scope="module")
+def row_kernel_paths():
+    """Rank 1 of four's share of a ReLU-gated layer, six experts a token,
+    at the kernels' lane width: three quarters of a token's pairs enter no
+    group, as in the cell."""
+    return row_kernels.both_paths(MoEMLP(n_experts=16, d_ff=128, k=6, ep_size=4,
+                             ep_rank=1, dropless=True, gated=True,
+                             activation="relu", dtype=jnp.float32))
+
+
+@pytest.mark.parametrize("quantity", row_kernels.QUANTITIES)
+def test_a_share_on_the_row_kernels_is_the_fallbacks_share(
+        row_kernel_paths, quantity):
+    row_kernels.assert_the_same_layer(*row_kernel_paths, quantity)
 
 
 def test_a_share_outside_the_axis_is_what_init_sees():
