@@ -28,6 +28,7 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
+import optax
 
 logger = logging.getLogger(__name__)
 
@@ -46,6 +47,18 @@ from ..obs.spans import phase_scope
 from ..communication import BaguaCommunicator, ReduceOp
 from ..define import TensorDeclaration
 from ..tensor import NamedParam
+
+
+def chunk_form(buf, n: int):
+    """The form in which a bucket buffer is cut into ``n`` per-rank chunks:
+    the buffer itself where its leading axis divides — rows of a shaped
+    bucket's tensor, runs of a 1-D flat; no ravel, which on a TPU is a
+    re-tiling copy of a matrix (bucket.py) — else its 1-D run (ZeRO alone
+    cuts such a bucket: its plan pads every bucket's numel to ``n``).  A
+    chunk of rows raveled IS the run chunk of the same rank, so the stages
+    that take 1-D runs (the rings, the codecs) ravel a chunk at their point
+    of use and lose nothing."""
+    return buf if buf.shape[0] % n == 0 else buf.reshape(-1)
 
 
 @dataclass
@@ -92,6 +105,11 @@ class AlgorithmContext:
     #: when ``BAGUA_EF_RESIDUAL=off`` (the stateless honesty control).
     #: :meth:`Algorithm.ef_codec` gates on it.
     ef_enabled: bool = False
+    #: the exact family's exchange is reduce-scatter -> update of the owned
+    #: chunk -> all-gather (``BaguaTrainer._mark_sharded_update`` says where):
+    #: optimizer state arrives as each bucket's owned chunk, and
+    #: :meth:`update_sharded` says bucket by bucket whether it is taken
+    sharded_update: bool = False
 
     def codec_for(self, link_class: str, family_default=None):
         """Resolve the wire codec for one link class: the tier's policy
@@ -220,24 +238,46 @@ class AlgorithmContext:
 
     # -- per-tier stage helpers (shared by allreduce/bytegrad/zero) --------
 
+    def _reduce_scatter_over(self, comm: BaguaCommunicator, link_class: str,
+                             buf, op: ReduceOp, codec):
+        """``buf`` reduced over ``comm``; returns this rank's chunk, rank r
+        owning the r-th chunk of :func:`chunk_form` (rows where the leading
+        axis divides).  Fused ``psum_scatter`` over the leading axis unless
+        the overlap scheduler set a ring chunk target for the link class or
+        ``codec`` names a wire codec: the rings take the 1-D run, and the
+        chunk has its rows back behind them."""
+        x = chunk_form(buf, comm.nranks())
+        k = self._comm_chunks(comm, x.size, x.dtype.itemsize, link_class)
+        if codec is None and k == 1:
+            return comm.reduce_scatter(x, op)
+        chunk = comm.ring_reduce_scatter(x.reshape(-1), op, num_chunks=k,
+                                         codec=codec)
+        return chunk.reshape((-1,) + x.shape[1:])
+
+    def _allgather_over(self, comm: BaguaCommunicator, link_class: str,
+                        chunk, codec):
+        """Inverse of :meth:`_reduce_scatter_over` (rank chunks in rank
+        order along the leading axis) under the same chunk gate, sized on
+        the whole buffer the chunk tiles, so the pair stays
+        layout-symmetric."""
+        k = self._comm_chunks(comm, chunk.size * comm.nranks(),
+                              chunk.dtype.itemsize, link_class)
+        if codec is None and k == 1:
+            return comm.allgather(chunk, axis=0, tiled=True)
+        full = comm.ring_allgather(chunk.reshape(-1), num_chunks=k,
+                                   codec=codec)
+        return full.reshape((-1,) + chunk.shape[1:])
+
     def tier_reduce_scatter(self, flat, op: ReduceOp, codec=None):
         """Slice-local (ICI) reduce-scatter of ``flat`` — this rank's
-        contiguous 1/intra chunk, ring-chunked against the ICI target.
-        The ICI codec policy resolves against ``codec`` as the family
-        default (full precision unless the knob names a codec — ICI bytes
-        are cheap)."""
+        1/intra chunk, ring-chunked against the ICI target.  The ICI codec
+        policy resolves against ``codec`` as the family default (full
+        precision unless the knob names a codec — ICI bytes are cheap)."""
         from ..communication import LINK_ICI
 
-        codec = self.codec_for(LINK_ICI, codec)
-        k = self._comm_chunks(self.intranode, flat.shape[0],
-                              flat.dtype.itemsize, LINK_ICI)
-        if codec is not None:
-            return self.intranode.ring_reduce_scatter(
-                flat, op, num_chunks=k, codec=codec
-            )
-        if k > 1:
-            return self.intranode.ring_reduce_scatter(flat, op, num_chunks=k)
-        return self.intranode.reduce_scatter(flat, op)
+        return self._reduce_scatter_over(
+            self.intranode, LINK_ICI, flat, op,
+            self.codec_for(LINK_ICI, codec))
 
     def tier_allreduce(self, chunk, op: ReduceOp, codec=None):
         """Cross-slice (DCN) allreduce of this rank's shard, ring-chunked
@@ -249,34 +289,21 @@ class AlgorithmContext:
         from ..communication import LINK_DCN
 
         codec = self.codec_for(LINK_DCN, codec)
-        k = self._comm_chunks(self.internode, chunk.shape[0],
+        k = self._comm_chunks(self.internode, chunk.size,
                               chunk.dtype.itemsize, LINK_DCN)
-        if codec is not None:
-            return self.internode.ring_allreduce(
-                chunk, op, num_chunks=k, codec=codec
-            )
-        if k > 1:
-            return self.internode.ring_allreduce(chunk, op, num_chunks=k)
-        return self.internode.allreduce(chunk, op)
+        if codec is None and k == 1:
+            return self.internode.allreduce(chunk, op)
+        return self.internode.ring_allreduce(
+            chunk.reshape(-1), op, num_chunks=k, codec=codec
+        ).reshape(chunk.shape)
 
     def tier_allgather(self, chunk, codec=None):
         """Slice-local (ICI) allgather of this rank's chunk back to the
-        full flat — same chunk gate as :meth:`tier_reduce_scatter` (sized
-        on the full flat the chunk tiles) so the pair stays
-        layout-symmetric."""
+        whole buffer — the inverse of :meth:`tier_reduce_scatter`."""
         from ..communication import LINK_ICI
 
-        codec = self.codec_for(LINK_ICI, codec)
-        k = self._comm_chunks(
-            self.intranode, chunk.shape[0] * self.intranode.nranks(),
-            chunk.dtype.itemsize, LINK_ICI,
-        )
-        if codec is not None:
-            return self.intranode.ring_allgather(chunk, num_chunks=k,
-                                                 codec=codec)
-        if k > 1:
-            return self.intranode.ring_allgather(chunk, num_chunks=k)
-        return self.intranode.allgather(chunk, axis=0, tiled=True)
+        return self._allgather_over(self.intranode, LINK_ICI, chunk,
+                                    self.codec_for(LINK_ICI, codec))
 
     def two_level_allreduce(self, flat, op: ReduceOp, dcn_codec=None):
         """The two-level hierarchical allreduce of one flat buffer:
@@ -359,36 +386,82 @@ class AlgorithmContext:
                 f, op, num_chunks=k))
         return self.hierarchical_allreduce(flat, op, hierarchical)
 
+    # -- this rank's chunk of a bucket (ZeRO, the exact family's sharded
+    # update) --------------------------------------------------------------
+
+    def owned_chunk(self, buf, comm: Optional[BaguaCommunicator] = None):
+        """This rank's chunk of a bucket buffer over ``comm`` (default: the
+        comm world): a slice of the leading axis of :func:`chunk_form` —
+        the chunk :meth:`bucket_reduce_scatter` hands this rank and
+        :meth:`bucket_allgather` puts back."""
+        comm = self.comm if comm is None else comm
+        x = chunk_form(buf, comm.nranks())
+        size = x.shape[0] // comm.nranks()
+        return jax.lax.dynamic_slice_in_dim(x, comm.rank() * size, size)
+
     def bucket_reduce_scatter(self, flat, op: ReduceOp):
-        """One bucket's reduce-scatter (ZeRO's grad half) under the active
-        comm config; chunk layout is identical between the ring and
-        ``psum_scatter`` paths (rank r owns the r-th contiguous slice).
-        A knob-forced flat codec compresses these rings too — every
-        family riding the flat tier honors the forced policy, so the byte
-        accounting's claim stays true for ZeRO's scatter/gather dance."""
-        codec = self.flat_ring_codec()
-        k = self._ring_chunks(flat.shape[0], flat.dtype.itemsize)
-        if codec is not None:
-            return self.comm.ring_reduce_scatter(flat, op, num_chunks=k,
-                                                 codec=codec)
-        if k > 1:
-            return self.comm.ring_reduce_scatter(flat, op, num_chunks=k)
-        return self.comm.reduce_scatter(flat, op)
+        """One bucket's reduce-scatter over the comm world under the active
+        comm config (ZeRO's grad half, and the exact family's under the
+        sharded update); the chunk layout is identical between the ring and
+        ``psum_scatter`` paths.  A knob-forced flat codec compresses these
+        rings too — every family riding the flat tier honors the forced
+        policy, so the byte accounting's claim stays true for the
+        scatter/gather dance."""
+        from ..communication import LINK_ICI
+
+        return self._reduce_scatter_over(self.comm, LINK_ICI, flat, op,
+                                         self.flat_ring_codec())
 
     def bucket_allgather(self, chunk):
-        """Re-replication half of ZeRO's dance (this rank's chunk -> full
-        flat), chunked-ring under the active comm config — same gate as
-        :meth:`bucket_reduce_scatter` (sized on the full flat the chunk
-        tiles) so the pair stays layout-symmetric."""
-        codec = self.flat_ring_codec()
-        k = self._ring_chunks(chunk.shape[0] * self.comm.nranks(),
-                              chunk.dtype.itemsize)
-        if codec is not None:
-            return self.comm.ring_allgather(chunk, num_chunks=k,
-                                            codec=codec)
-        if k > 1:
-            return self.comm.ring_allgather(chunk, num_chunks=k)
-        return self.comm.allgather(chunk, axis=0, tiled=True)
+        """Re-replication half of the dance (this rank's chunk -> the whole
+        buffer), the inverse of :meth:`bucket_reduce_scatter`."""
+        from ..communication import LINK_ICI
+
+        return self._allgather_over(self.comm, LINK_ICI, chunk,
+                                    self.flat_ring_codec())
+
+    def update_sharded(self, index: int) -> bool:
+        """Whether bucket ``index``'s update is taken by the rank that owns
+        its chunk (:attr:`sharded_update`): every bucket whose leading axis
+        the comm world divides — a shaped bucket by rows, a 1-D flat by
+        runs.  A bucket that does not divide keeps the all-reduce and a
+        replicated update: no plan is padded to the world for it, so that
+        the exact family's plans, and its checkpoints, stay the same at
+        every world size (an elastic resume restores them directly)."""
+        return (self.sharded_update
+                and self.plan.buckets[index].buffer_shape[0]
+                % self.comm.nranks() == 0)
+
+    def update_owned(self, optimizer, params, grads, opt_state):
+        """The trainer's optimizer stage under the sharded update
+        (flat-resident containers): ``grads`` and ``opt_state`` hold, for a
+        bucket :meth:`update_sharded` takes, this rank's chunk (the
+        reduce-scatter's result; the moments stored so) and the whole
+        buffer for every other.  Steps the matching chunk of the
+        parameters — an elementwise transform's arithmetic is the
+        replicated update's, a chunk at a time — and gathers the new chunks
+        into the resident buffers."""
+        taken = [i for i in range(len(self.plan.buckets))
+                 if self.update_sharded(i)]
+        owned = list(params["flats"])
+        for i in taken:
+            owned[i] = self.owned_chunk(owned[i])
+        owned = {"flats": tuple(owned), "local": params["local"]}
+        updates, opt_state = optimizer.update(grads, opt_state, owned)
+        owned = optax.apply_updates(owned, updates)
+        flats = list(owned["flats"])
+        for i in taken:
+            # (inside the trainer's bagua.optimizer scope the collective
+            # names itself bagua.comm/...: the innermost bagua.* scope wins).
+            # Handed the gather's result as the new parameter the TPU
+            # compiler copies every parameter twice, out of its donated
+            # buffer and back into it (11 ms of bert-large's dp4 step);
+            # writing the chunks into the resident buffer in place
+            # (dynamic_update_slice) trades that for a pass and a slower
+            # gather and read the same step: PERF.md §6, PR 49
+            with phase_scope(f"bagua.comm/bucket_{i}"):
+                flats[i] = self.bucket_allgather(flats[i])
+        return {"flats": tuple(flats), "local": owned["local"]}, opt_state
 
     # -- bandwidth-tier-aware launch schedule ------------------------------
 
@@ -586,6 +659,13 @@ class Algorithm:
     #: ZeRO chunks, QAdam's compressed-momentum pipeline) keep False and
     #: the trainer fuses their local verdicts with one tiny ``pmin``.
     grad_health_replicated: bool = False
+    #: Sharded-update contract: True when the family's gradient comm is an
+    #: exact per-bucket sum or average that :meth:`reduce_bucket_grad` can
+    #: hand back as this rank's chunk alone
+    #: (:meth:`AlgorithmContext.update_sharded`), so that the trainer's
+    #: optimizer steps the owned chunk and gathers the parameters
+    #: (:meth:`AlgorithmContext.update_owned`).
+    supports_sharded_update: bool = False
 
     def need_reset(self, step: int) -> bool:
         """Host-side: return True to rebuild buckets/recompile (reference

@@ -2,18 +2,43 @@
 
 Counterpart of /root/reference/bagua/torch_api/algorithms/gradient_allreduce.py:8-38
 plus its backing comm op
-(comm_ops/centralized_full_precision_synchronous.rs:16-56).  One fused
-``psum``/``pmean`` per bucket, issued where the bucket's gradient becomes
-ready.  That placement is all XLA gives on a TPU today: the all-reduces of
-the compiled step are *synchronous* — the compiler combines the per-bucket
-collectives (99 buckets -> 16 calls on four v5e chips) and none of them
-overlaps backward compute, so the whole exchange is exposed (32.5 ms of a
-120 ms BERT-Large step; PERF.md, "the dp4 exchange, read off the chip").
-The overlap the reference's Rust scheduler + dedicated CUDA stream bought is
-NOT had for free here; ROADMAP.md Queue 1 item 1 holds what was tried.
+(comm_ops/centralized_full_precision_synchronous.rs:16-56).  A bucket's
+gradient is exchanged where it becomes ready, in one of two forms:
 
-A bucket that is one tensor keeps the tensor's own shape (bucket.py): the
-fused ``psum`` takes it as it takes a 1-D flat.
+- **all-reduce, then every rank updates everything** — one fused
+  ``psum``/``pmean`` a bucket.  One chip's world, ``hierarchical=True``,
+  model-parallel meshes, a transform that is not elementwise, an
+  error-feedback codec on the wire, a ``comm_dtype`` narrower than the
+  parameters.
+- **reduce-scatter -> update of the owned chunk -> all-gather** — wherever
+  the trainer can take it (``BaguaTrainer._mark_sharded_update``: more than
+  one rank on a pure-dp mesh, bucket-flat state, an elementwise optimizer,
+  a wire as wide as the parameters; no option selects it).  An all-reduce
+  IS that pair of collectives, so the wire carries the same bytes and every
+  rank ends the step with the same parameters; between the two halves a rank holds the reduced gradient of
+  its own chunk alone, steps that chunk's parameters and moments, and
+  stores no other rank's moments (arXiv:2004.13336, the weight-update
+  sharding XLA does for replicated training).  A chunk is **rows of the
+  bucket**: the leading axis of a shaped bucket's tensor, a run of a 1-D
+  flat (``AlgorithmContext.update_sharded`` / ``owned_chunk``) — never a
+  ravel of a matrix, which on a TPU is a re-tiling copy (bucket.py).  The
+  reduce-scatter carries ``comm_dtype`` where one is set; the all-gather
+  always carries the parameters' own dtype, which is why a narrower wire
+  keeps the all-reduce: bfloat16 gradients over float32 parameters would
+  move 3/4 of the float32 exchange's bytes where the bfloat16 all-reduce
+  moves 1/2 (bert-large on four v5e chips: 31,633 against 34,328
+  tokens/s/chip, PERF.md §6, PR 49).
+
+Either way the collectives of the compiled step are *synchronous* on a TPU
+today: the compiler combines the per-bucket calls into a few large ones and
+none of them overlaps backward compute, so the exchange is exposed (PERF.md
+§5 and §6, "the dp4 exchange, read off the chip").  The overlap the
+reference's Rust scheduler + dedicated CUDA stream bought is NOT had for
+free here; ROADMAP.md Queue 1 item 1 holds what was tried.  What the second
+form saves is the update the exchange forces into the open — a pass over a
+1/world of the state instead of all of it — and (world − 1)/world of the
+moments' memory (bert-large, four chips: 27,048 -> 28,755 tokens/s/chip,
+10.31 -> 7.77 GB; PERF.md §5).
 """
 
 from __future__ import annotations
@@ -27,19 +52,24 @@ from .base import Algorithm, AlgorithmContext
 class GradientAllReduceAlgorithm(Algorithm):
     name = "gradient_allreduce"
     supports_overlap = True
-    #: the per-bucket allreduce consumes resident bucket buffers directly
+    #: the per-bucket exchange consumes resident bucket buffers directly
     #: (zero repacking; shaped or 1-D alike); ``auto`` takes it on the
-    #: word of a cpu-sim record, though all seven cells of the benchmark
+    #: word of a cpu-sim record, though all nine cells of the benchmark
     #: run this layout and none the leaf one (ROADMAP Queue 3 item 3)
     supports_flat_resident = True
     #: reduced buckets are replicated (plain psum/ring sum — a NaN/Inf
     #: contribution from any rank survives into every rank's copy), so the
-    #: gradient-health sentinel rides them with no extra collective
+    #: gradient-health sentinel rides them with no extra collective — except
+    #: under the sharded update, where a rank holds its own chunk of the sum
+    #: alone and the trainer reads the verdict off the gathered parameters
     grad_health_replicated = True
     #: the per-bucket flat reduction can carry an error-feedback residual
     #: when the codec policy forces a stateful codec (onebit_ef / topk)
     #: onto its rings
     supports_ef_state = True
+    #: the trainer may shard this family's update over the comm world
+    #: (module docstring; ``hierarchical=True`` keeps the all-reduce)
+    supports_sharded_update = True
 
     def __init__(
         self,
@@ -67,12 +97,16 @@ class GradientAllReduceAlgorithm(Algorithm):
 
     def reduce_bucket_grad(self, ctx: AlgorithmContext, index: int, flat):
         op = ReduceOp.AVG if self.average else ReduceOp.SUM
+        if ctx.update_sharded(index):
+            # this rank's chunk of the reduced bucket: the same values the
+            # all-reduce hands those rows, cast back from the wire dtype
+            def reduce(f):
+                return ctx.bucket_reduce_scatter(f, op)
+        else:
+            def reduce(f):
+                return ctx.bucket_allreduce(f, op, self.hierarchical)
         if self.comm_dtype is None:
-            return ctx.bucket_allreduce(flat, op, self.hierarchical)
-        orig = flat.dtype
-        flat = ctx.bucket_allreduce(
-            flat.astype(self.comm_dtype), op, self.hierarchical
-        )
-        return flat.astype(orig)
+            return reduce(flat)
+        return reduce(flat.astype(self.comm_dtype)).astype(flat.dtype)
 
     process_grads = Algorithm.process_grads_bucketed

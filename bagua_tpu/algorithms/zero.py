@@ -4,9 +4,8 @@ Additive capability — the reference has no ZeRO/FSDP analog (SURVEY.md §2.3
 lists it as absent; its closest relative is the flat-param
 ``contrib/fused_optimizer.py``).  On TPU this is the natural next step past
 plain DP: optimizer state is the largest per-chip memory consumer for Adam
-(2× params in f32), and the bucket flat buffers already partition evenly
-across ranks (world-size alignment), so the classic ZeRO-1 dance maps to two
-XLA collectives per bucket:
+(2× params in f32), and the classic ZeRO-1 dance maps to two XLA collectives
+per bucket:
 
     reduce_scatter(grads)  ->  shard-local optimizer update  ->  all_gather(params)
 
@@ -15,28 +14,51 @@ which costs exactly the same bytes on the wire as the allreduce it replaces
 byte audit in tests/test_hlo_comm_bytes.py), while storing only
 ``1/world_size`` of the optimizer state per chip.
 
+**Since PR 49 the exact family does this dance by itself** wherever it can
+(``GradientAllReduceAlgorithm`` with the trainer's own elementwise optimizer
+on a pure-dp mesh: gradient_allreduce.py's header), and the two share one
+implementation of "this rank's chunk of a bucket"
+(``base.chunk_form`` / ``AlgorithmContext.owned_chunk`` /
+``bucket_reduce_scatter`` / ``bucket_allgather``): **a chunk is rows of the
+bucket's buffer** — the leading axis of a shaped bucket's tensor, a run of a
+1-D flat — and the 1-D run of a ravel only for a shaped bucket whose rows
+the shard count does not divide (its numel does: this family's plan pads
+every bucket to the world).  A ravel of a matrix is a re-tiling copy on a
+TPU (bucket.py).  What is left that only this class does:
+
+- it **owns the optimizer** (``optimizer=``; the trainer's is ignored) and
+  keeps one optax state *per bucket chunk*, stacked ``[shards, *chunk]``
+  over the shard axis — keyed on bucket boundaries, so autotune rebucketing
+  is off and checkpoints are plan-locked (the exact family's sharded
+  moments are the replicated layout's arrays cut over the ranks, and are
+  neither);
+- **``clip_global_norm=``**: global-norm clipping of the *averaged*
+  gradient, assembled with one extra scalar psum over the already-sharded
+  chunks — the one norm-coupled transform everyone needs, which an
+  elementwise-only update cannot express;
+- **the staged layout** (``hierarchical=True``): state sharded over
+  ``intra`` only, so the cross-slice tier carries ``1/intra`` of the bytes;
+- **model-parallel compositions** (tp / pp / expert axes): leaves outside
+  the bucket plan step shard-locally under the ``"local"`` state;
+- the **overlap contract** over its reduce-scatter (``overlap="on"``).
+
 On pure-dp meshes the params are FLAT-RESIDENT: ``TrainState.params`` holds
-the bucket flat buffers across steps and the trainer differentiates the
-loss w.r.t. the flats directly — the forward materializes leaf views by
-slicing (a re-tiling copy on a TPU, except for a shaped bucket, whose buffer
-is the leaf: bucket.py) and autodiff's transpose IS the gradient flatten,
-so the per-step leaf->flat->leaf round trip the leaf layout paid is gone.
-Measured on one v5e chip (ResNet50, batch 128, comm a no-op, both families
-at the HBM roofline — 909 vs 920 GB/s): the leaf layout trailed plain
-allreduce by 7.7%; flat-resident trails by ~2% (2590 vs 2644 img/s, two
-runs), the residual being the per-step re-laying of updated flat segments
-into conv layouts.  That is the single-chip price of 1/world_size
-optimizer memory; on a real dp mesh the collective bytes are identical.
-Model-parallel compositions (tp/pp/ep) keep the leaf layout; leaf pytrees
-for eval/checkpoint/user code come from ``trainer.unstack_params(state)``.
+the bucket buffers across steps and the trainer differentiates the loss
+w.r.t. them directly — the forward materializes leaf views by slicing (a
+re-tiling copy on a TPU, except for a shaped bucket, whose buffer is the
+leaf: bucket.py) and autodiff's transpose IS the gradient flatten, so the
+per-step leaf->flat->leaf round trip the leaf layout paid is gone.
+Measured on one v5e chip long before the shaped buckets (ResNet50, batch
+128, comm a no-op, both families at the HBM roofline — 909 vs 920 GB/s):
+the leaf layout trailed plain allreduce by 7.7%; flat-resident trailed by
+~2%.  No benchmark cell runs this class on the chip today (ROADMAP Queue 2).
+Leaf pytrees for eval/checkpoint/user code come from
+``trainer.unstack_params(state)``.
 
 The wrapped optax transformation must be *elementwise* (adam, adamw, sgd,
 rmsprop, ...): the update for element ``i`` may depend only on gradient /
-param / state values at ``i``, because each rank updates its own flat chunk
-independently.  Global-norm gradient clipping — the one norm-coupled
-transform everyone needs — is built in (``clip_global_norm``): the norm of
-the *averaged* gradient is assembled with one extra scalar psum over the
-already-sharded chunks.
+param / state values at ``i``, because each rank updates its own chunk
+independently (:func:`is_elementwise`, probed at construction).
 """
 
 from __future__ import annotations
@@ -49,7 +71,51 @@ import optax
 
 from ..communication import ReduceOp
 from ..obs.spans import phase_scope
-from .base import Algorithm, AlgorithmContext
+from .base import Algorithm, AlgorithmContext, chunk_form
+
+
+def is_elementwise(optimizer: optax.GradientTransformation) -> bool:
+    """Whether every element's update depends on the gradient, parameter and
+    state at that element alone — the precondition for a rank to step its
+    own chunk of a bucket by itself (ZeRO here, and the exact family's
+    sharded update: ``BaguaTrainer._opt_elementwise``).  Probe: stepping a
+    2-vector must equal stepping its two halves independently.  Multiple
+    steps with gradients of VARYING norm are required — adam-family updates
+    are invariant to a per-element-constant gradient scale (m and sqrt v
+    scale together), so a single step cannot expose clipping.  Runs on the
+    CPU backend (tiny arrays; keeps TPU compile out of a constructor)."""
+    try:
+        # must be an ADDRESSABLE device: jax.devices("cpu")[0] is
+        # process 0's device, and committing the probe to it from any
+        # other process crashes that process alone — a divergent-dispatch
+        # hang (caught by tests/test_multiprocess_families.py[zero])
+        device = jax.local_devices(backend="cpu")[0]
+    except RuntimeError:
+        # CPU backend excluded (e.g. JAX_PLATFORMS=tpu): probe on the
+        # default device — two tiny compiles, still worth the guard
+        device = jax.local_devices()[0]
+    # (eager even where the caller is being traced: ``trainer.init`` under
+    # ``jax.eval_shape``)
+    with jax.default_device(device), jax.ensure_compile_time_eval():
+        # norms 5, 0.14, 2.2: the clip factor changes per step, and
+        # differs between the full vector and each half
+        gs = [jnp.asarray([3.0, -4.0]), jnp.asarray([0.1, 0.1]),
+              jnp.asarray([-1.0, 2.0])]
+        p_full = jnp.asarray([0.5, -1.5])
+        st_full = optimizer.init(p_full)
+        for g in gs:
+            up, st_full = optimizer.update(g, st_full, p_full)
+            p_full = optax.apply_updates(p_full, up)
+        halves = []
+        for i in range(2):
+            p = jnp.asarray([0.5, -1.5])[i:i + 1]
+            st = optimizer.init(p)
+            for g in gs:
+                up, st = optimizer.update(g[i:i + 1], st, p)
+                p = optax.apply_updates(p, up)
+            halves.append(p)
+        return bool(jnp.allclose(p_full, jnp.concatenate(halves),
+                                 rtol=1e-5, atol=1e-7))
 
 
 class ZeroOptimizerAlgorithm(Algorithm):
@@ -117,52 +183,18 @@ class ZeroOptimizerAlgorithm(Algorithm):
     def _check_elementwise(self) -> None:
         """Fail loudly at construction when the wrapped transform is not
         elementwise (e.g. ``optax.chain(clip_by_global_norm(...), adam(...))``):
-        each rank updates only its own flat chunk, so a norm-coupled update
-        would silently train on per-chunk norms.  Probe: stepping a 2-vector
-        must equal stepping its two halves independently.  Multiple steps
-        with gradients of VARYING norm are required — adam-family updates
-        are invariant to a per-element-constant gradient scale (m and sqrt v
-        scale together), so a single step cannot expose clipping.  Runs on
-        the CPU backend (tiny arrays; keeps TPU compile out of __init__)."""
-        try:
-            # must be an ADDRESSABLE device: jax.devices("cpu")[0] is
-            # process 0's device, and committing the probe to it from any
-            # other process crashes that process alone — a divergent-dispatch
-            # hang (caught by tests/test_multiprocess_families.py[zero])
-            device = jax.local_devices(backend="cpu")[0]
-        except RuntimeError:
-            # CPU backend excluded (e.g. JAX_PLATFORMS=tpu): probe on the
-            # default device — two tiny compiles, still worth the guard
-            device = jax.local_devices()[0]
-        with jax.default_device(device):
-            # norms 5, 0.14, 2.2: the clip factor changes per step, and
-            # differs between the full vector and each half
-            gs = [jnp.asarray([3.0, -4.0]), jnp.asarray([0.1, 0.1]),
-                  jnp.asarray([-1.0, 2.0])]
-            p_full = jnp.asarray([0.5, -1.5])
-            st_full = self.optimizer.init(p_full)
-            for g in gs:
-                up, st_full = self.optimizer.update(g, st_full, p_full)
-                p_full = optax.apply_updates(p_full, up)
-            halves = []
-            for i in range(2):
-                p = jnp.asarray([0.5, -1.5])[i:i + 1]
-                st = self.optimizer.init(p)
-                for g in gs:
-                    up, st = self.optimizer.update(g[i:i + 1], st, p)
-                    p = optax.apply_updates(p, up)
-                halves.append(p)
-            if not jnp.allclose(p_full, jnp.concatenate(halves),
-                                rtol=1e-5, atol=1e-7):
-                raise ValueError(
-                    "ZeroOptimizerAlgorithm requires an ELEMENTWISE optax "
-                    "transform (adam/adamw/sgd/rmsprop/...): updating a "
-                    "vector and updating its halves independently disagree, "
-                    "so the transform couples elements (global-norm "
-                    "clipping?).  Use the built-in clip_global_norm= for "
-                    "distributed clipping, or pass check_elementwise=False "
-                    "if the coupling is intentional."
-                )
+        each rank updates only its own chunk, so a norm-coupled update would
+        silently train on per-chunk norms (:func:`is_elementwise`)."""
+        if not is_elementwise(self.optimizer):
+            raise ValueError(
+                "ZeroOptimizerAlgorithm requires an ELEMENTWISE optax "
+                "transform (adam/adamw/sgd/rmsprop/...): updating a "
+                "vector and updating its halves independently disagree, "
+                "so the transform couples elements (global-norm "
+                "clipping?).  Use the built-in clip_global_norm= for "
+                "distributed clipping, or pass check_elementwise=False "
+                "if the coupling is intentional."
+            )
 
     def tensors_to_buckets(self, decl_buckets, named_params, world_size):
         from ..bucket import BucketPlan
@@ -197,19 +229,12 @@ class ZeroOptimizerAlgorithm(Algorithm):
         the full comm world otherwise."""
         return ctx.intranode if self._staged(ctx) else ctx.comm
 
-    def _chunk_size(self, ctx: AlgorithmContext, flat) -> int:
-        n = self._shard_comm(ctx).nranks()
-        assert flat.size % n == 0, (
-            f"bucket numel {flat.size} not divisible by shard count {n}"
-        )
-        return flat.size // n
-
     def _my_chunk(self, ctx: AlgorithmContext, flat):
-        # chunks are 1-D runs of the bucket: a shaped bucket (bucket.py)
-        # ravels here, at the point of use
-        size = self._chunk_size(ctx, flat)
-        start = self._shard_comm(ctx).rank() * size
-        return jax.lax.dynamic_slice(flat.reshape(-1), (start,), (size,))
+        """This rank's chunk of a bucket over the shard axis: rows where the
+        bucket's leading axis divides, else a run of its ravel
+        (``base.chunk_form`` — one implementation with the exact family's
+        sharded update)."""
+        return ctx.owned_chunk(flat, self._shard_comm(ctx))
 
     def _avg_scatter(self, ctx: AlgorithmContext, flat):
         """Average ``flat`` over the whole comm world and return this rank's
@@ -217,7 +242,6 @@ class ZeroOptimizerAlgorithm(Algorithm):
         reduce_scatter over intra, then allreduce the owned chunk over inter
         — the global average with only ``1/intra`` of the bytes crossing the
         inter tier (avg-of-avgs is exact: intra rows are equal-sized)."""
-        flat = flat.reshape(-1)  # (a shaped bucket: chunks are 1-D runs)
         if not self._staged(ctx):
             # chunked ring when the overlap scheduler set a chunk size,
             # fused psum_scatter otherwise (identical chunk layout)
@@ -314,8 +338,8 @@ class ZeroOptimizerAlgorithm(Algorithm):
         gchunks = []
         for i, gf in enumerate(gflats):
             with phase_scope(f"bagua.comm/bucket_{i}"):
-                gchunks.append(
-                    ctx.comm.reduce_scatter(gf.reshape(-1), ReduceOp.AVG))
+                gchunks.append(ctx.comm.reduce_scatter(
+                    chunk_form(gf, ctx.comm.nranks()), ReduceOp.AVG))
         local_g = self._local_named(ctx, grads)
 
         if self.clip_global_norm is not None:

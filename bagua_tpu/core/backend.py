@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from .. import env
 from ..algorithms.base import Algorithm, AlgorithmContext
+from ..algorithms.zero import is_elementwise
 from ..bucket import (BucketPlan, conform_flats,
                       split_bucket_by_bucket_size)
 from ..communication import (
@@ -568,7 +569,7 @@ class BaguaTrainer:
     # ---- plan management -----------------------------------------------
 
     def _ctx(self, plan: BucketPlan, overlap: bool = False) -> AlgorithmContext:
-        return AlgorithmContext(
+        return self._mark_sharded_update(AlgorithmContext(
             comm=self._comm,
             internode=self._inter,
             intranode=self._intra,
@@ -591,7 +592,7 @@ class BaguaTrainer:
             inter_codec=self.compress_inter,
             flat_resident=self._flat_resident,
             ef_enabled=self._ef_enabled,
-        )
+        ))
 
     def _flat_supported(self) -> bool:
         """Whether the flat-resident layout CAN hold this configuration:
@@ -916,9 +917,8 @@ class BaguaTrainer:
         migrations run first) — an autotune family switch immediately
         followed by its alignment rebucket must apply both, in order."""
         prev = self._pending_state_migration
-        self._pending_state_migration = (
-            fn if prev is None else (lambda state: fn(prev(state)))
-        )
+        self._pending_state_migration = lambda state: self._place_opt_state(
+            fn(state if prev is None else prev(state)))
 
     @staticmethod
     def _is_flat_container(x) -> bool:
@@ -1180,8 +1180,8 @@ class BaguaTrainer:
                                "local": {}},
                     out_shardings=replicated,
                 )(params)
-                opt_state = jax.jit(opt_init,
-                                    out_shardings=replicated)(zparams)
+                opt_state = jax.jit(opt_init, out_shardings=self._opt_state_shardings(
+                    plan, replicated))(zparams)
 
                 def init_fn(p):
                     return algo.init_state(ctx, p)
@@ -1328,7 +1328,7 @@ class BaguaTrainer:
         replicated_health = (
             algo.grad_health_replicated
             and self.expert_axis is None
-            and self._shard_axis is None
+            and self._shard_axis is None and not ctx.sharded_update
         )
         # gossip-style families keep PER-RANK weight replicas, so the guard
         # verdict is per-rank too: each rank rewinds its own replica (the
@@ -1539,6 +1539,12 @@ class BaguaTrainer:
                     params, opt_state, algo_state = algo.optimizer_update(
                         ctx, params, grads, opt_state, algo_state, step
                     )
+                elif ctx.sharded_update:
+                    # the comm stage left this rank its own chunk of each
+                    # reduced bucket: step that chunk of the parameters and
+                    # of the moments (stored as chunks), gather the rest
+                    params, opt_state = ctx.update_owned(
+                        self._opt, params, grads, opt_state)
                 else:
                     updates, opt_state = self._opt.update(grads, opt_state,
                                                           params)
@@ -1630,8 +1636,11 @@ class BaguaTrainer:
             # the EF residual (when an error-feedback codec is active) is
             # the one replicated-family algo state with a per-rank stacked
             # leading axis; shard_map slices each rank's [1, pad] row
+            # (sharded update: the moments' buffers are cut over the ranks)
             state_specs = TrainState(
-                step=P(), params=pspec, opt_state=pspec,
+                step=P(), params=pspec, opt_state=(
+                    self._opt_state_specs(plan) if ctx.sharded_update
+                    else pspec),
                 algo_state=algo.algo_state_specs(ctx, pspec,
                                                  P(self.comm_axes)),
             )
@@ -1702,6 +1711,9 @@ class BaguaTrainer:
             # flip bucket-flat residency live (_apply_flat_resident), and
             # the flat and leaf constructions are different programs
             self._flat_resident,
+            # ... and whether the update is sharded over the comm world (a
+            # flip of `hierarchical` or of a codec knob turns it off and on)
+            self._update_sharded(),
             # grad guard: "warn" and "abort" trace the same program (the
             # policy difference is host-side), "skip" adds the rewind
             # selects; armed traced faults compile into the step, so their
@@ -1731,19 +1743,7 @@ class BaguaTrainer:
                             buckets=len(self._plan.buckets),
                             overlap=self._overlap_active()):
                 self._step_cache[key] = self._make_step_fn(self._plan)
-            # what the plan asks of the wire per step; what XLA's combiner
-            # makes of it is a count over the compiled text
-            counters.set_gauge(
-                "comm/buckets_per_step",
-                len(self._plan.buckets) if self._comm.nranks() > 1 else 0)
-            # how much of the plan is held in its tensors' own shapes
-            # (bucket.py: a tensor as large as a bucket is its own bucket)
-            nbytes = [b.padded_numel * np.dtype(b.dtype).itemsize
-                      for b in self._plan.buckets]
-            counters.set_gauge(
-                "comm/shaped_bytes_share",
-                sum(n for n, b in zip(nbytes, self._plan.buckets)
-                    if b.shaped) / max(1, sum(nbytes)))
+            self._note_plan_gauges()
             # the wall window of the step that triggers this compile is
             # garbage-slow: no speed sample, no anomaly, booked to `compile`
             self._observer.note_window_class("compile")
@@ -3029,7 +3029,7 @@ class BaguaTrainer:
                 lambda s: self._restore_checkpoint_at(manager, state_like, s)
             )
         self.algorithm.on_restore(self)
-        return result
+        return result[0], self._place_opt_state(result[1])
 
     def _restore_checkpoint_at(self, manager, state_like: TrainState,
                                step: int):
@@ -3460,3 +3460,117 @@ class BaguaTrainer:
         """Manual override of the automatic per-step speed tracking
         (:meth:`StepObserver.record_speed`)."""
         self._observer.record_speed(n_samples)
+
+    # ---- the sharded update (gradient_allreduce.py's header) ---------------
+
+    #: (optimizer, verdict) of the last elementwise probe
+    _elementwise_probed = (None, False)
+
+    def _opt_elementwise(self) -> bool:
+        """Whether a rank may step its own chunk of a bucket by itself
+        (``zero.is_elementwise`` of the optimizer the step runs), probed
+        once an optimizer and only where the sharded update could engage at
+        all: one chip's trainer pays nothing for it."""
+        if self._elementwise_probed[0] is not self._opt:
+            self._elementwise_probed = (self._opt, is_elementwise(self._opt))
+        return self._elementwise_probed[1]
+
+    def _mark_sharded_update(self, ctx: AlgorithmContext) -> AlgorithmContext:
+        """Set :attr:`AlgorithmContext.sharded_update`: the exact family's
+        update is sharded over the comm world wherever what can be observed
+        allows it — no option selects it: more than one rank, every one of
+        them a data-parallel replica (the flat-resident layout implies no
+        tp / pp / ep axis), a flat exchange, an optimizer that steps a chunk
+        as it steps the whole, and no error-feedback residual riding whole
+        buckets — and a wire no narrower than the parameters: the gather
+        carries the parameters' own dtype, so under ``comm_dtype=bfloat16``
+        over float32 parameters the pair moves three quarters of the
+        float32 all-reduce's bytes where the bfloat16 all-reduce moves
+        half, and the chip read that 7.9 % slower (PERF.md §6, PR 49).
+        Everything else traces the all-reduce and the replicated update it
+        always did."""
+        algo = self.algorithm
+        wire = getattr(algo, "comm_dtype", None)
+        ctx.sharded_update = bool(
+            self.world_size > 1
+            and self._flat_resident
+            and algo.supports_sharded_update
+            and not algo.hierarchical
+            and self.seq_axis is None
+            and (wire is None or ctx.plan is None or all(
+                np.dtype(wire).itemsize >= np.dtype(b.dtype).itemsize
+                for b in ctx.plan.buckets))
+            and self._opt_elementwise()
+            and algo.ef_codec(ctx) is None
+        )
+        return ctx
+
+    def _update_sharded(self) -> bool:
+        """Whether the CURRENT configuration shards the update
+        (:attr:`AlgorithmContext.sharded_update`): the optimizer state is
+        then laid out over the comm axes (:meth:`_opt_state_specs`)."""
+        return self.world_size > 1 and self._ctx(self._plan).sharded_update
+
+    def _opt_state_specs(self, plan: BucketPlan):
+        """``shard_map`` specs of the optimizer state under the sharded
+        update, a pytree over ``self._opt``'s state: a bucket buffer whose
+        update is sharded is cut over the comm axes along its leading axis
+        — globally it is the buffer the replicated layout holds, in the same
+        shape; each rank stores its chunk of it — and everything else
+        (other buckets, counts) is replicated."""
+        ctx = self._ctx(plan)
+        flats = tuple(P(self.comm_axes) if ctx.update_sharded(i) else P()
+                      for i in range(len(plan.buckets)))
+        like = {"flats": tuple(jax.ShapeDtypeStruct(b.buffer_shape, b.dtype)
+                               for b in plan.buckets), "local": {}}
+        is_zp = self._is_flat_container
+        return jax.tree.map(
+            lambda x: {"flats": flats, "local": {}} if is_zp(x) else P(),
+            jax.eval_shape(self._opt.init, like), is_leaf=is_zp)
+
+    def _opt_state_shardings(self, plan: BucketPlan, replicated):
+        """``replicated``, or under the sharded update the pytree of
+        shardings :meth:`_opt_state_specs` describes (an elementwise
+        ``init`` of the whole, cut, is the init of the chunk)."""
+        if not self._update_sharded():
+            return replicated
+        return jax.tree.map(lambda spec: NamedSharding(self.mesh, spec),
+                            self._opt_state_specs(plan),
+                            is_leaf=lambda x: isinstance(x, P))
+
+    def _place_opt_state(self, state: TrainState) -> TrainState:
+        """``state`` with its optimizer state placed as the sharded update's
+        step takes it (every queued state migration and every restore ends
+        here).  A migration or a restore builds it replicated, or as the
+        compiler pleased: the step would accept that — the layouts differ
+        in placement, not in shape — at the price of a program of its own
+        for that one dispatch."""
+        shardings = self._opt_state_shardings(self._plan, None)
+        if shardings is None or jax.tree.structure(
+                shardings) != jax.tree.structure(state.opt_state):
+            return state  # (or a displaced family's state: not this layout's)
+        return state._replace(
+            opt_state=jax.device_put(state.opt_state, shardings))
+
+    def _note_plan_gauges(self) -> None:
+        """What the plan of the step program just built asks for."""
+        # what the plan asks of the wire per step; what XLA's combiner
+        # makes of it is a count over the compiled text
+        counters.set_gauge(
+            "comm/buckets_per_step",
+            len(self._plan.buckets) if self._comm.nranks() > 1 else 0)
+        # how much of the plan is held in its tensors' own shapes
+        # (bucket.py: a tensor as large as a bucket is its own bucket)
+        nbytes = [b.padded_numel * np.dtype(b.dtype).itemsize
+                  for b in self._plan.buckets]
+        counters.set_gauge(
+            "comm/shaped_bytes_share",
+            sum(n for n, b in zip(nbytes, self._plan.buckets)
+                if b.shaped) / max(1, sum(nbytes)))
+        # ... and how much of it is updated by the rank that owns its
+        # chunk alone (reduce-scatter -> update -> all-gather)
+        ctx = self._ctx(self._plan)
+        counters.set_gauge(
+            "comm/sharded_update_share",
+            sum(n for i, n in enumerate(nbytes)
+                if ctx.update_sharded(i)) / max(1, sum(nbytes)))

@@ -113,6 +113,14 @@ _declare("comm/shaped_bytes_share", "gauge",
          "shape as parameter, gradient and optimizer state (no re-tiling "
          "between a 1-D flat and a matrix).  Set when a step program is "
          "built.")
+_declare("comm/sharded_update_share", "gauge",
+         "Share of the bucket plan's parameter bytes whose update is taken "
+         "by the rank that owns their chunk alone: the exact family's "
+         "exchange is then reduce-scatter, update of the owned rows, "
+         "all-gather, and the optimizer state of those buckets is stored "
+         "as 1/world of it a rank.  0 on one chip and wherever the "
+         "all-reduce and the replicated update stand.  Set when a step "
+         "program is built.")
 # -- attention kinds (set when a TransformerLM step is traced) --
 _declare("attn/kv_heads", "gauge",
          "Key / value heads of the model last traced (on this tensor-"

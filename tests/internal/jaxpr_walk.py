@@ -4,17 +4,23 @@ jaxpr (custom-VJP bodies, ``checkpoint`` replays, ``pjit`` calls)."""
 import jax
 
 
-def _walk(jaxpr, found):
+def equations(jaxpr):
+    """Every equation of a jaxpr, those of its nested jaxprs included."""
     for eqn in jaxpr.eqns:
-        name = eqn.primitive.name
-        if name == "pallas_call":
-            name = eqn.params["name"]
-        found.append((name, [getattr(v.aval, "shape", ()) for v in eqn.invars]))
+        yield eqn
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 sub = getattr(sub, "jaxpr", sub)
                 if hasattr(sub, "eqns"):
-                    _walk(sub, found)
+                    yield from equations(sub)
+
+
+def _walk(jaxpr, found):
+    for eqn in equations(jaxpr):
+        name = eqn.primitive.name
+        if name == "pallas_call":
+            name = eqn.params["name"]
+        found.append((name, [getattr(v.aval, "shape", ()) for v in eqn.invars]))
     return found
 
 

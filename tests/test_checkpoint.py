@@ -7,6 +7,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
+import pytest
 
 from bagua_tpu.algorithms.gradient_allreduce import GradientAllReduceAlgorithm
 from bagua_tpu.checkpoint import BaguaCheckpointManager
@@ -482,4 +483,101 @@ def test_save_restore_hierarchical_zero_state(tmp_path):
         s2, loss = t2.train_step(s2, batch)
         resumed.append(float(loss))
     np.testing.assert_allclose(resumed, ref[3:], rtol=1e-6)
+    mgr.close()
+
+
+# ---- the exact family's sharded update: the same moments, cut over the
+# ranks (gradient_allreduce.py's header) ------------------------------------
+
+
+def _adam_setup(monkeypatch=None):
+    """``new_trainer(sharded)``: adam over a plan with one shaped bucket and
+    packed 1-D flats at bucket_bytes=300.  The replicated update over the
+    same 8 ranks is had by steering what the trainer observes (its
+    elementwise probe): nothing selects it."""
+    from bagua_tpu.core import backend
+
+    model = MLP(features=(16, 8))
+    mesh = build_mesh({"dp": N_DEVICES})
+    x = jax.random.normal(jax.random.PRNGKey(0), (16, 4))
+    y = jnp.argmax(x @ jax.random.normal(jax.random.PRNGKey(1), (4, 8)), -1)
+    params = model.init(jax.random.PRNGKey(2), x[:2])["params"]
+
+    def loss_fn(p, b):
+        logits = model.apply({"params": p}, b["x"])
+        return optax.softmax_cross_entropy_with_integer_labels(
+            logits, b["y"]
+        ).mean()
+
+    def new_trainer(sharded, **kw):
+        with monkeypatch.context() as m:
+            if not sharded:
+                m.setattr(backend, "is_elementwise", lambda optimizer: False)
+            trainer = BaguaTrainer(loss_fn, optax.adam(1e-2),
+                                   GradientAllReduceAlgorithm(), mesh=mesh,
+                                   bucket_bytes=300, **kw)
+            state = trainer.init(params)
+        assert trainer._update_sharded() == sharded
+        return trainer, state
+
+    return new_trainer, {"x": x, "y": y}
+
+
+@pytest.mark.parametrize("saved_sharded", [False, True],
+                         ids=["replicated_to_sharded", "sharded_to_replicated"])
+def test_moments_restore_across_the_sharded_and_replicated_layouts(
+        tmp_path, monkeypatch, saved_sharded):
+    """A checkpoint holds the moments whole whichever way a trainer lays
+    them out on its ranks, under one plan (nothing is padded to the world
+    for the sharded update): written by one layout it restores into the
+    other and training goes on as if never interrupted."""
+    from bagua_tpu.obs.memory import tree_device_bytes
+
+    new_trainer, batch = _adam_setup(monkeypatch)
+    ref, s = new_trainer(saved_sharded)
+    want = []
+    for _ in range(6):
+        s, loss = ref.train_step(s, batch)
+        want.append(float(loss))
+
+    t1, s1 = new_trainer(saved_sharded)
+    for _ in range(3):
+        s1, _ = t1.train_step(s1, batch)
+    mgr = BaguaCheckpointManager(str(tmp_path / "ckpt"), async_save=False)
+    assert t1.save_checkpoint(mgr, 3, s1)
+    mgr.wait()
+
+    t2, s2 = new_trainer(not saved_sharded)
+    assert t1._plan.signature() == t2._plan.signature()
+    fresh_bytes = tree_device_bytes(s2.opt_state)
+    step, s2 = t2.restore_checkpoint(mgr, s2)
+    assert step == 3
+    # placed as this trainer's step takes it: a rank's share of the moments
+    # is what a fresh state's is
+    assert tree_device_bytes(s2.opt_state) == fresh_bytes
+    got = []
+    for _ in range(3):
+        s2, loss = t2.train_step(s2, batch)
+        got.append(float(loss))
+    np.testing.assert_allclose(got, want[3:], rtol=1e-5)
+    mgr.close()
+
+
+def test_sharded_moments_round_trip_in_place(tmp_path, monkeypatch):
+    new_trainer, batch = _adam_setup(monkeypatch)
+    t1, s1 = new_trainer(True)
+    for _ in range(2):
+        s1, _ = t1.train_step(s1, batch)
+    mgr = BaguaCheckpointManager(str(tmp_path / "ckpt"), async_save=False)
+    assert t1.save_checkpoint(mgr, 2, s1)
+    mgr.wait()
+    t2, s2 = new_trainer(True)
+    step, s2 = t2.restore_checkpoint(mgr, s2)
+    for a, b in zip(jax.tree.leaves(s1.opt_state),
+                    jax.tree.leaves(s2.opt_state)):
+        assert a.sharding.is_equivalent_to(b.sharding, a.ndim)
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for a, b in zip(jax.tree.leaves(t1.unstack_params(s1)),
+                    jax.tree.leaves(t2.unstack_params(s2))):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     mgr.close()

@@ -6,11 +6,14 @@ elementwise.  DP with averaged grads over the full batch must equal
 single-worker training on the concatenated batch.
 """
 
+import hashlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from tests.internal.jaxpr_walk import equations
 
 from bagua_tpu import BaguaTrainer
 from bagua_tpu.algorithms import GradientAllReduceAlgorithm
@@ -165,3 +168,435 @@ def test_sum_vs_avg_scales_update():
     d_sum = jax.tree.map(lambda a, b: np.asarray(a - b), outs[False], params)
     for a, b in zip(jax.tree.leaves(d_avg), jax.tree.leaves(d_sum)):
         np.testing.assert_allclose(b, N * a, rtol=1e-4, atol=1e-5)
+
+
+# ---- the sharded update: reduce-scatter -> update of the owned chunk ->
+# all-gather (gradient_allreduce.py's header) --------------------------------
+#
+# Nothing selects it, so a test that wants the replicated update over the
+# same 8 ranks steers what the trainer observes: the elementwise probe.
+
+
+def _keep_replicated(monkeypatch):
+    from bagua_tpu.core import backend
+
+    monkeypatch.setattr(backend, "is_elementwise", lambda optimizer: False)
+
+
+def _mlp_task(features=(16, NCLASS), seed=0):
+    model = MLP(features=features)
+    params = model.init(jax.random.PRNGKey(seed), jnp.zeros((1, DIM)))["params"]
+    return _loss_fn(model), params
+
+
+def _run(trainer, params, xs, ys):
+    state = trainer.init(params)
+    losses = []
+    for s in range(xs.shape[0]):
+        state, loss = trainer.train_step(state, {"x": xs[s], "y": ys[s]})
+        losses.append(float(loss))
+    return state, losses
+
+
+_OPTIMIZERS = {
+    "adamw": lambda: optax.adamw(1e-2),
+    "sgd_momentum": lambda: optax.sgd(0.1, momentum=0.9),
+}
+
+
+@pytest.mark.parametrize("accum_steps", [1, 4])
+@pytest.mark.parametrize("comm_dtype", [None, jnp.float32, jnp.bfloat16],
+                         ids=["wire_as_is", "float32_wire", "bf16_wire"])
+@pytest.mark.parametrize("optimizer", sorted(_OPTIMIZERS))
+def test_sharded_update_trajectory_is_the_replicated_one(
+        optimizer, comm_dtype, accum_steps, monkeypatch):
+    """Every rank steps the chunk it owns and gathers the rest: the
+    parameters after 6 steps are the ones the all-reduce and 8 replicated
+    updates give.  bucket_bytes=600 leaves one packed 1-D flat beside the
+    shaped buckets, so both kinds of chunk are driven.  A wire narrower
+    than the parameters keeps the all-reduce (the gather would carry the
+    parameters' float32: more bytes than the bfloat16 all-reduce moves),
+    so that case is the same program twice."""
+    loss_fn, params = _mlp_task()
+    xs, ys = _data(steps=6, seed=3)
+
+    def make():
+        return BaguaTrainer(
+            loss_fn, _OPTIMIZERS[optimizer](),
+            GradientAllReduceAlgorithm(comm_dtype=comm_dtype),
+            bucket_bytes=600, accum_steps=accum_steps)
+
+    sharded = make()
+    st_a, losses_a = _run(sharded, params, xs, ys)
+    assert sharded._update_sharded() == (comm_dtype is not jnp.bfloat16)
+    assert sharded._overlap_active() == (accum_steps > 1)
+    _keep_replicated(monkeypatch)
+    replicated = make()
+    st_b, losses_b = _run(replicated, params, xs, ys)
+    assert not replicated._update_sharded()
+
+    # the same reduction and the same elementwise arithmetic; XLA:CPU is
+    # free to fuse the two programs differently
+    np.testing.assert_allclose(losses_a, losses_b, rtol=1e-6)
+    for a, b in zip(jax.tree.leaves(sharded.unstack_params(st_a)),
+                    jax.tree.leaves(replicated.unstack_params(st_b))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+    # the moments are the replicated layout's buffers, cut over the ranks
+    for a, b in zip(jax.tree.leaves(st_a.opt_state),
+                    jax.tree.leaves(st_b.opt_state)):
+        assert a.shape == b.shape
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+
+
+def test_sum_reduction_rides_the_sharded_update(monkeypatch):
+    loss_fn, params = _mlp_task()
+    xs, ys = _data(steps=3, seed=4)
+
+    def make():
+        return BaguaTrainer(loss_fn, optax.sgd(0.01),
+                            GradientAllReduceAlgorithm(average=False),
+                            bucket_bytes=600)
+
+    sharded = make()
+    st_a, _ = _run(sharded, params, xs, ys)
+    assert sharded._update_sharded()
+    _keep_replicated(monkeypatch)
+    replicated = make()
+    st_b, _ = _run(replicated, params, xs, ys)
+    for a, b in zip(jax.tree.leaves(sharded.unstack_params(st_a)),
+                    jax.tree.leaves(replicated.unstack_params(st_b))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+
+
+def test_chunks_are_rows_of_the_bucket_and_nothing_is_raveled():
+    """A shaped bucket is scattered over its leading axis, its owned rows
+    are a slice of that axis, the gather puts rows back: no reshape of a
+    bucket's buffer to 1-D anywhere in the step."""
+    loss_fn, params = _mlp_task()
+    trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                           GradientAllReduceAlgorithm(), bucket_bytes=256)
+    state = trainer.init(params)
+    plan = trainer._plan
+    shapes = {b.buffer_shape for b in plan.buckets}
+    assert all(b.shaped for b in plan.buckets) and (16, NCLASS) in shapes
+    xs, ys = _data(steps=1)
+    eqns = list(equations(trainer.trace_step(
+        state, trainer.shard_batch({"x": xs[0], "y": ys[0]})).jaxpr))
+
+    ctx = trainer._ctx(plan)
+    taken = [b.buffer_shape for i, b in enumerate(plan.buckets)
+             if ctx.update_sharded(i)]
+    assert (16, NCLASS) in taken and (DIM, 16) not in taken  # 12 rows / 8
+    scatters = [e for e in eqns if e.primitive.name == "reduce_scatter"]
+    gathers = [e for e in eqns if e.primitive.name == "all_gather"]
+    assert sorted(e.invars[0].aval.shape for e in scatters) == sorted(taken)
+    assert sorted(e.outvars[0].aval.shape for e in gathers) == sorted(taken)
+    assert all(e.params["scatter_dimension"] == 0 and e.params["tiled"]
+               for e in scatters)
+    assert all(e.params["all_gather_dimension"] == 0 and e.params["tiled"]
+               for e in gathers)
+    # the buckets left to the all-reduce are the ones that do not divide
+    psums = [e.invars[0].aval.shape for e in eqns
+             if e.primitive.name == "psum" and e.invars[0].aval.ndim]
+    assert sorted(psums) == sorted(shapes - set(taken))
+    # owned rows: a dynamic slice of the leading axis of the buffer itself
+    for shape in taken:
+        rows = (shape[0] // N,) + shape[1:]
+        assert any(e.primitive.name == "dynamic_slice"
+                   and e.invars[0].aval.shape == shape
+                   and e.outvars[0].aval.shape == rows for e in eqns)
+    for e in eqns:
+        if e.primitive.name == "reshape":
+            src, dst = e.invars[0].aval.shape, e.outvars[0].aval.shape
+            assert not (len(src) > 1 and len(dst) == 1
+                        and src in shapes), (src, dst)
+
+
+def test_a_rank_stores_a_world_th_of_the_moments(monkeypatch):
+    from bagua_tpu.obs.memory import tree_device_bytes
+
+    # 2,336 parameters in one packed flat: 8 ranks divide it
+    loss_fn, params = _mlp_task(features=(16, 64, 16))
+
+    def make():
+        trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                               GradientAllReduceAlgorithm(),
+                               bucket_bytes=1 << 20)
+        return trainer, trainer.init(params)
+
+    sharded, st_a = make()
+    _keep_replicated(monkeypatch)
+    replicated, st_b = make()
+    # the plan is the same at every world size and under either update
+    # (nothing is padded to the world: an elastic resume restores it as it
+    # is); every byte of this one is sharded
+    assert sharded._plan.signature() == replicated._plan.signature()
+    assert [(b.shaped, b.padding) for b in sharded._plan.buckets] == [
+        (False, 0)]
+    assert sharded._plan.buckets[0].padded_numel % N == 0
+    count = 4  # adamw's step count, replicated
+    assert tree_device_bytes(st_a.opt_state) - count == pytest.approx(
+        (tree_device_bytes(st_b.opt_state) - count) / N, rel=0.01)
+    assert tree_device_bytes(st_a.params) == pytest.approx(
+        tree_device_bytes(st_b.params), rel=0.01)
+
+
+def _one_rank_mesh():
+    from bagua_tpu.parallel.mesh import build_mesh
+
+    return build_mesh({"dp": 1}, jax.devices()[:1])
+
+
+_FALLBACKS = {
+    # global-norm clipping couples every element: a chunk's norm is not the
+    # gradient's
+    "not_elementwise": dict(
+        optimizer=lambda: optax.chain(optax.clip_by_global_norm(0.1),
+                                      optax.adam(1e-2))),
+    # (the two-level exchange and the codec's ring scatter and gather on
+    # their own account: the update behind them is whole all the same)
+    "hierarchical": dict(algorithm=dict(hierarchical=True), scatters=True),
+    # the gather would carry float32 where the all-reduce carries bfloat16
+    "wire_narrower_than_parameters": dict(
+        algorithm=dict(comm_dtype=jnp.bfloat16)),
+    # an error-feedback residual rides whole buckets
+    "error_feedback_codec": dict(trainer=dict(compress_intra="onebit_ef"),
+                                 scatters=True),
+    "one_rank": dict(trainer=dict(mesh=_one_rank_mesh)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_FALLBACKS))
+def test_falls_back_to_the_allreduce_and_the_replicated_update(case):
+    from bagua_tpu.telemetry import counters
+
+    spec = _FALLBACKS[case]
+    loss_fn, params = _mlp_task()
+    kwargs = {k: (v() if callable(v) else v)
+              for k, v in spec.get("trainer", {}).items()}
+    trainer = BaguaTrainer(
+        loss_fn, spec.get("optimizer", lambda: optax.adam(1e-2))(),
+        GradientAllReduceAlgorithm(**spec.get("algorithm", {})),
+        bucket_bytes=256, **kwargs)
+    state = trainer.init(params)
+    assert not trainer._update_sharded()
+    assert all(b.alignment == 1 for b in trainer._plan.buckets)
+    xs, ys = _data(steps=1)
+    batch = trainer.shard_batch({"x": xs[0][:4 * trainer.world_size],
+                                 "y": ys[0][:4 * trainer.world_size]})
+    names = {e.primitive.name for e in equations(
+        trainer.trace_step(state, batch).jaxpr)}
+    assert spec.get("scatters") or not names & {"reduce_scatter",
+                                                "all_gather"}
+    for leaf in jax.tree.leaves(state.opt_state):
+        assert leaf.sharding.is_fully_replicated
+    state, loss = trainer.train_step(state, batch)
+    assert np.isfinite(float(loss))
+    assert counters.get("comm/sharded_update_share") == 0
+
+
+def test_one_rank_traces_the_step_it_always_did(monkeypatch):
+    """World 1 is seven of the benchmark's nine cells: with the mechanism
+    in the tree its step is, equation for equation, the step of a trainer
+    that cannot shard at all, and holds no collective of the new kind."""
+    loss_fn, params = _mlp_task()
+    xs, ys = _data(steps=1)
+
+    def trace(**kw):
+        trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                               GradientAllReduceAlgorithm(),
+                               mesh=_one_rank_mesh(), bucket_bytes=600, **kw)
+        state = trainer.init(params)
+        batch = trainer.shard_batch({"x": xs[0][:4], "y": ys[0][:4]})
+        return trainer, str(trainer.trace_step(state, batch))
+
+    # sha256 of the parent commit's (PR 48, f67b1a2) jaxpr text for the same
+    # three constructions, taken from its checkout: a change that moves them
+    # moves seven cells' step programs — say so in the PR, then re-pin
+    parents = ("fe5b76f9524ce8de", "0de039d80ff17d96", "32305221dff90c27")
+    for kw, parent in zip(({}, {"accum_steps": 4}, {"grad_guard": "skip"}),
+                          parents):
+        trainer, ours = trace(**kw)
+        assert hashlib.sha256(ours.encode()).hexdigest()[:16] == parent
+        assert not trainer._update_sharded()
+        assert trainer._elementwise_probed[0] is None  # (not even probed)
+        with monkeypatch.context() as m:
+            m.setattr(GradientAllReduceAlgorithm, "supports_sharded_update",
+                      False)
+            _, never = trace(**kw)
+        assert ours == never
+        assert "reduce_scatter" not in ours and "all_gather" not in ours
+
+
+def test_gauge_reads_the_plans_sharded_share():
+    from bagua_tpu.obs import export
+    from bagua_tpu.telemetry import counters
+
+    assert export.is_registered("comm/sharded_update_share")
+    loss_fn, params = _mlp_task()
+    trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                           GradientAllReduceAlgorithm(), bucket_bytes=256)
+    state = trainer.init(params)
+    xs, ys = _data(steps=1)
+    trainer.train_step(state, {"x": xs[0], "y": ys[0]})
+    nbytes = {b.buffer_shape: b.padded_numel * 4
+              for b in trainer._plan.buckets}
+    # 8 ranks divide neither the 12 rows of the first kernel nor the 10
+    # logits' bias
+    assert set(nbytes) == {(DIM, 16), (16,), (16, NCLASS), (NCLASS,)}
+    want = (nbytes[(16,)] + nbytes[(16, NCLASS)]) / sum(nbytes.values())
+    assert counters.get("comm/sharded_update_share") == pytest.approx(want)
+    assert 0.4 < want < 1
+
+
+# ---- what the sharded state layout touches ---------------------------------
+
+
+def _sharded_leaves(trainer, state):
+    """(sharded, replicated) counts of the optimizer state's array leaves."""
+    leaves = [x for x in jax.tree.leaves(state.opt_state) if x.ndim]
+    cut = sum(not x.sharding.is_fully_replicated for x in leaves)
+    return cut, len(leaves) - cut
+
+
+def test_a_rebucket_keeps_the_moments_sharded_and_the_trajectory(monkeypatch):
+    """Autotune stays on for the default family (``BaguaTrainer`` switches
+    it off only for ZeRO's per-chunk states): a rebucket moves the sharded
+    moments as it moves replicated ones — globally they are the same
+    buffers — and the step is handed them cut over the ranks again."""
+    from bagua_tpu.bucket import split_bucket_by_bucket_size
+    from bagua_tpu.obs.memory import tree_device_bytes
+
+    loss_fn, params = _mlp_task(features=(16, 64, 16))
+    xs, ys = _data(steps=6, seed=8)
+
+    def run(rebucket_at):
+        trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                               GradientAllReduceAlgorithm(), bucket_bytes=600,
+                               autotune=False)
+        state = trainer.init(params)
+        held = tree_device_bytes(state.opt_state)
+        for s in range(xs.shape[0]):
+            if s == rebucket_at:
+                decls = [t.declaration() for b in trainer._plan.buckets
+                         for t in b.tensors]
+                before = trainer._plan.signature()
+                trainer.rebucket(split_bucket_by_bucket_size(decls, 1 << 20))
+                assert trainer._plan.signature() != before
+            state, _ = trainer.train_step(state, {"x": xs[s], "y": ys[s]})
+        assert trainer._update_sharded()
+        return trainer, state, held
+
+    plain, st_a, _ = run(None)
+    moved, st_b, held = run(3)
+    for a, b in zip(jax.tree.leaves(plain.unstack_params(st_a)),
+                    jax.tree.leaves(moved.unstack_params(st_b))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+    # one packed flat now (2,336 elements), every moment of it a 1/8 a rank
+    assert _sharded_leaves(moved, st_b) == (2, 0)
+    assert tree_device_bytes(st_b.opt_state) <= held
+
+
+def test_autotune_is_not_switched_off_for_the_sharded_update():
+    from bagua_tpu.algorithms import ZeroOptimizerAlgorithm
+
+    loss_fn, _ = _mlp_task()
+    keeps = BaguaTrainer(loss_fn, optax.adam(1e-2),
+                         GradientAllReduceAlgorithm(), autotune=True)
+    drops = BaguaTrainer(loss_fn, None,
+                         ZeroOptimizerAlgorithm(optax.adam(1e-2)),
+                         autotune=True)
+    assert keeps.autotune and not drops.autotune
+
+
+def test_switch_to_a_family_that_owns_its_optimizer_and_back():
+    """gradient_allreduce (sharded moments) -> qadam (its own, replicated)
+    -> gradient_allreduce: the stashed optax state comes back and is cut
+    over the ranks again."""
+    from bagua_tpu.algorithms.q_adam import QAdamOptState
+    from bagua_tpu.define import BaguaHyperparameter
+
+    loss_fn, params = _mlp_task()
+    xs, ys = _data(steps=1, seed=2)
+    batch = {"x": xs[0], "y": ys[0]}
+    trainer = BaguaTrainer(loss_fn, optax.adam(1e-2),
+                           GradientAllReduceAlgorithm(), bucket_bytes=600,
+                           autotune=False)
+    state = trainer.init(params)
+    layout = _sharded_leaves(trainer, state)
+    assert layout[0] > 0
+    losses = []
+
+    def steps(state, n):
+        for _ in range(n):
+            state, loss = trainer.train_step(state, batch)
+            losses.append(float(loss))
+        return state
+
+    def switch(family):
+        trainer._maybe_switch_algorithm(BaguaHyperparameter(
+            algorithm=family, is_hierarchical_reduce=False))
+        assert trainer.algorithm.name == family
+
+    state = steps(state, 3)
+    switch("qadam")
+    assert not trainer._update_sharded()
+    state = steps(state, 3)  # (the queued migration runs in train_step)
+    assert isinstance(state.opt_state, QAdamOptState)
+    switch("gradient_allreduce")
+    assert trainer._update_sharded()
+    state = steps(state, 3)
+    assert _sharded_leaves(trainer, state) == layout
+    assert all(np.isfinite(losses)) and losses[-1] < losses[0]
+
+
+def test_unstack_params_hands_back_whole_leaves():
+    loss_fn, params = _mlp_task()
+    trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                           GradientAllReduceAlgorithm(), bucket_bytes=600)
+    state = trainer.init(params)
+    assert trainer._update_sharded()
+    for a, b in zip(jax.tree.leaves(trainer.unstack_params(state)),
+                    jax.tree.leaves(params)):
+        assert a.shape == b.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("guard", ["skip", "warn"])
+def test_the_guards_verdict_is_rank_uniform_under_a_poisoned_gradient(guard):
+    """The reduced gradient a rank holds is its own chunk alone, so the
+    verdict is read off the gathered parameters, as ZeRO's is: one row,
+    the same on every rank; ``skip`` rewinds every rank alike."""
+    from bagua_tpu.faults.inject import FaultSpec, fault_scope
+
+    loss_fn, params = _mlp_task()
+    xs, ys = _data(steps=1, seed=6)
+    batch = {"x": xs[0], "y": ys[0]}
+    with fault_scope(FaultSpec("grad.poison", step=1)):
+        trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                               GradientAllReduceAlgorithm(), bucket_bytes=600,
+                               grad_guard=guard)
+        state = trainer.init(params)
+        assert trainer._update_sharded()
+        state, _ = trainer.train_step(state, batch)
+        assert float(trainer.step_metrics["grad_healthy"]) == 1.0
+        before = jax.tree.map(np.asarray, trainer.unstack_params(state))
+        moments = jax.tree.map(np.asarray, state.opt_state)
+        state, _ = trainer.train_step(state, batch)
+        health = trainer.step_metrics["grad_health_buckets"]
+        assert float(trainer.step_metrics["grad_healthy"]) == 0.0
+        assert health.sharding.is_fully_replicated
+        trainer.flush_grad_health()
+    after = jax.tree.map(np.asarray, trainer.unstack_params(state))
+    if guard == "skip":
+        for a, b in zip(jax.tree.leaves(before), jax.tree.leaves(after)):
+            np.testing.assert_array_equal(a, b)
+        for a, b in zip(jax.tree.leaves(moments),
+                        jax.tree.leaves(state.opt_state)):
+            np.testing.assert_array_equal(a, np.asarray(b))
+    else:
+        assert not all(np.isfinite(x).all() for x in jax.tree.leaves(after))

@@ -142,40 +142,98 @@ def test_zero_wire_bytes_equal_allreduce():
     )
 
 
+def _requested_payloads(stablehlo, op):
+    """Element types of the non-scalar payloads the program REQUESTS of one
+    StableHLO collective.  ``all_reduce`` / ``reduce_scatter`` carry a
+    reduction REGION; the type signature ") : (tensor<103072xbf16>) -> ..."
+    follows the region's close; ``all_gather`` has none."""
+    region = r".*?\}\)" if op != "all_gather" else r"[^\n]*?"
+    out = []
+    for m in re.finditer(
+        r"stablehlo\." + op + region + r" : "
+        r"\(tensor<(?:([0-9]+(?:x[0-9]+)*)x)?(bf16|f16|f32|f64)>\)",
+        stablehlo, re.DOTALL,
+    ):
+        dims, dtype = m.groups()
+        numel = 1
+        for d in (dims or "").split("x"):
+            if d:
+                numel *= int(d)
+        if numel > 1:  # skip the scalar loss allreduce
+            out.append((dtype, numel))
+    return out
+
+
 def test_bf16_comm_dtype_requests_bf16_collectives():
-    """comm_dtype=bf16 must put bf16 payloads into the collectives the
-    program REQUESTS.  Checked on the pre-optimization StableHLO: the XLA
-    *CPU* backend's collective runtime promotes narrow all-reduces to f32
-    during optimization (an artifact of this simulation platform), while the
-    TPU backend executes bf16 all-reduces natively — so the optimized-HLO
-    byte audit used elsewhere in this file would report the CPU promotion,
-    not the program's wire request."""
+    """comm_dtype=bf16 must put bf16 payloads into the gradient collectives
+    the program REQUESTS — all-reduces: a wire narrower than the parameters
+    keeps the all-reduce, since the sharded update's gather would carry
+    the parameters' float32.  Checked on the pre-optimization StableHLO: the XLA *CPU* backend's collective
+    runtime promotes narrow all-reduces to f32 during optimization (an
+    artifact of this simulation platform), while the TPU backend executes
+    bf16 all-reduces natively — so the optimized-HLO byte audit used
+    elsewhere in this file would report the CPU promotion, not the
+    program's wire request."""
     _, _, f32_st = _step_hlo(GradientAllReduceAlgorithm())
     _, _, bf_st = _step_hlo(GradientAllReduceAlgorithm(comm_dtype=jnp.bfloat16))
 
-    def payload_dtypes(stablehlo):
-        # the all_reduce op carries a reduction REGION; its type signature
-        # ") : (tensor<103072xbf16>) -> ..." follows the region's close
-        out = []
-        for m in re.finditer(
-            r"stablehlo\.all_reduce.*?\}\) : "
-            r"\(tensor<(?:([0-9]+(?:x[0-9]+)*)x)?(bf16|f16|f32|f64)>\)",
-            stablehlo, re.DOTALL,
-        ):
-            dims, dtype = m.groups()
-            numel = 1
-            for d in (dims or "").split("x"):
-                if d:
-                    numel *= int(d)
-            if numel > 1:  # skip the scalar loss allreduce
-                out.append(dtype)
-        return out
+    def gradient_payloads(stablehlo):
+        return [d for op in ("all_reduce", "reduce_scatter")
+                for d, _ in _requested_payloads(stablehlo, op)]
 
-    assert "bf16" not in payload_dtypes(f32_st)
-    bf_payloads = payload_dtypes(bf_st)
+    assert "bf16" not in gradient_payloads(f32_st)
+    bf_payloads = gradient_payloads(bf_st)
     assert bf_payloads and all(d == "bf16" for d in bf_payloads), (
-        f"expected every gradient allreduce payload in bf16, got {bf_payloads}"
+        f"expected every gradient collective's payload in bf16, got "
+        f"{bf_payloads}"
     )
+    assert not _requested_payloads(bf_st, "reduce_scatter")
+    assert not _requested_payloads(bf_st, "all_gather")
+    assert _requested_payloads(f32_st, "reduce_scatter")
+
+
+@pytest.mark.parametrize("comm_dtype", [None, jnp.bfloat16],
+                         ids=["float32", "bf16comm"])
+def test_sharded_update_asks_the_wire_for_the_allreduces_bytes(
+        comm_dtype, monkeypatch):
+    """reduce-scatter -> update of the owned chunk -> all-gather: the ring
+    carries what the all-reduce carried, (N-1)/N of the payload each way.
+    With a wire narrower than the parameters only the reduce-scatter's half
+    would shrink (the gather carries the parameters' own dtype: 3/4 of the
+    float32 exchange where the narrow all-reduce moves 1/2), so that
+    configuration keeps the all-reduce.  Counted on what the program
+    requests (see the test above for why not the CPU's HLO)."""
+    from bagua_tpu.core import backend
+
+    def ring_bytes(stablehlo):
+        n, width = N_DEVICES, {"bf16": 2, "f16": 2, "f32": 4, "f64": 8}
+        total = 0.0
+        for op, weight in (("all_reduce", 2 * (n - 1) / n),
+                           ("reduce_scatter", (n - 1) / n),  # operand bytes
+                           ("all_gather", n - 1)):  # operand = one chunk
+            total += sum(weight * numel * width[d]
+                         for d, numel in _requested_payloads(stablehlo, op))
+        return total
+
+    algo = lambda: GradientAllReduceAlgorithm(comm_dtype=comm_dtype)
+    opt = optax.adamw(1e-3)
+    sharded_hlo, n_params, sharded = _step_hlo(algo(), opt)
+    itemsize = 4 if comm_dtype is None else 2
+    ring = (N_DEVICES - 1) / N_DEVICES * n_params
+    if comm_dtype is not None:
+        assert not _requested_payloads(sharded, "reduce_scatter")
+        assert ring_bytes(sharded) == pytest.approx(2 * ring * itemsize)
+        return
+    assert "reduce-scatter" in sharded_hlo and "all-gather" in sharded_hlo
+    monkeypatch.setattr(backend, "is_elementwise", lambda optimizer: False)
+    replicated_hlo, _, replicated = _step_hlo(algo(), opt)
+    assert "reduce-scatter" not in replicated_hlo
+    assert not _requested_payloads(replicated, "reduce_scatter")
+
+    assert ring_bytes(replicated) == pytest.approx(2 * ring * itemsize)
+    # (a 1-D flat is padded to the world: under 8 elements a bucket)
+    assert ring_bytes(sharded) == pytest.approx(ring * (itemsize + 4),
+                                                rel=1e-3)
 
 
 def test_wire_parser_on_known_hlo():

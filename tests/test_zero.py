@@ -13,6 +13,7 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 import pytest
+from tests.internal.jaxpr_walk import equations
 
 from bagua_tpu import BaguaTrainer
 from bagua_tpu.algorithms import GradientAllReduceAlgorithm, ZeroOptimizerAlgorithm
@@ -83,12 +84,19 @@ def test_optimizer_state_is_sharded():
 
     total_padded = sum(b.padded_numel for b in trainer._plan.buckets)
     # adam: exp_avg (mu) + exp_avg_sq (nu) per bucket chunk; the stacked
-    # global view is [N, chunk] so each rank materializes chunk = padded/N
+    # global view is [N, *chunk] so each rank materializes chunk = padded/N:
+    # rows of a shaped bucket whose leading axis N divides, a 1-D run
+    # otherwise (base.chunk_form)
     buckets = state.opt_state["buckets"]
-    for bucket_state in buckets:
+    for b, bucket_state in zip(trainer._plan.buckets, buckets):
         adam_state = bucket_state[0]  # ScaleByAdamState
-        assert adam_state.mu.ndim == 2  # [N, chunk] stacked global view
-    chunk_elems = sum(bs[0].mu.shape[1] for bs in buckets)
+        by_rows = b.buffer_shape[0] % N == 0
+        assert adam_state.mu.shape[1:] == (
+            (b.buffer_shape[0] // N,) + b.buffer_shape[1:] if by_rows
+            else (b.padded_numel // N,))
+    assert any(b.shaped and b.buffer_shape[0] % N == 0
+               and len(b.buffer_shape) > 1 for b in trainer._plan.buckets)
+    chunk_elems = sum(bs[0].mu[0].size for bs in buckets)
     assert chunk_elems == total_padded // N
 
     # each per-rank shard holds only its chunk
@@ -395,7 +403,7 @@ def test_hierarchical_opt_state_sharded_intra_only():
     state = trainer.init(params)
     total_padded = sum(b.padded_numel for b in trainer._plan.buckets)
     buckets = state.opt_state["buckets"]
-    chunk_elems = sum(bs[0].mu.shape[1] for bs in buckets)
+    chunk_elems = sum(bs[0].mu[0].size for bs in buckets)
     assert chunk_elems == total_padded // 4  # intra, not world=8
     for bs in buckets:
         assert bs[0].mu.shape[0] == 4
@@ -470,3 +478,60 @@ def test_hierarchical_rejects_model_parallel():
         )
         tokens = jax.random.randint(jax.random.PRNGKey(0), (2, 9), 0, 32)
         trainer.init(model.init(jax.random.PRNGKey(1), tokens[:, :-1])["params"])
+
+
+# ---- chunks are rows, not runs (one implementation with the exact family's
+# sharded update: base.chunk_form / AlgorithmContext.owned_chunk) -----------
+
+
+@pytest.mark.parametrize("flat_resident", ["auto", "off"])
+def test_zero_cuts_a_shaped_bucket_by_rows(flat_resident):
+    """A shaped bucket whose leading axis the shard count divides is
+    scattered, sliced and gathered over that axis — no ravel of it, in the
+    flat-resident layout and in the leaf one; a bucket whose rows do not
+    divide (numel does: the plan pads it) is still cut as a 1-D run."""
+    model = MLP(features=(16, NCLASS))
+    params = model.init(jax.random.PRNGKey(0), jnp.zeros((1, DIM)))["params"]
+    trainer = BaguaTrainer(
+        _loss_fn(model), None, ZeroOptimizerAlgorithm(optax.adam(1e-2)),
+        bucket_bytes=256, flat_resident=flat_resident,
+    )
+    state = trainer.init(params)
+    by_rows = {b.buffer_shape for b in trainer._plan.buckets
+               if b.shaped and b.buffer_shape[0] % N == 0}
+    by_runs = {b.buffer_shape for b in trainer._plan.buckets} - by_rows
+    assert (16, NCLASS) in by_rows and (DIM, 16) in by_runs
+    xs, ys = _data(steps=1)
+    eqns = list(equations(trainer.trace_step(
+        state, trainer.shard_batch({"x": xs[0], "y": ys[0]})).jaxpr))
+    scattered = {e.invars[0].aval.shape for e in eqns
+                 if e.primitive.name == "reduce_scatter"}
+    assert by_rows <= scattered
+    assert (DIM * 16,) in scattered and (DIM, 16) not in scattered
+    raveled = {e.invars[0].aval.shape for e in eqns
+               if e.primitive.name == "reshape"
+               and e.outvars[0].aval.ndim == 1}
+    assert not raveled & by_rows
+
+
+def test_zero_row_chunks_train_like_the_replicated_update():
+    """The trajectory over a plan with row-cut and run-cut buckets side by
+    side is plain data parallelism's (float tolerance: XLA:CPU fuses the two
+    programs differently)."""
+    model = MLP(features=(16, NCLASS))
+    params = model.init(jax.random.PRNGKey(3), jnp.zeros((1, DIM)))["params"]
+    loss_fn = _loss_fn(model)
+    xs, ys = _data(steps=6, seed=12)
+    zero = BaguaTrainer(loss_fn, None,
+                        ZeroOptimizerAlgorithm(optax.adamw(1e-2)),
+                        bucket_bytes=256)
+    st_zero, _ = _train(zero, params, xs, ys)
+    plain = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                         GradientAllReduceAlgorithm(hierarchical=True),
+                         bucket_bytes=256)
+    st_plain, _ = _train(plain, params, xs, ys)
+    assert not plain._update_sharded()
+    for a, b in zip(jax.tree.leaves(zero.unstack_params(st_zero)),
+                    jax.tree.leaves(plain.unstack_params(st_plain))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=2e-5, atol=2e-6)
