@@ -34,6 +34,10 @@ from ...parallel.mesh import axis_bound as _axis_bound
 from .gating import top1_gating, top2_gating
 
 
+#: the experts' activations by ``MoEMLP.activation``; both keep a zero row zero
+_ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+
+
 class MoEMLP(nn.Module):
     """Drop-in MLP replacement: tokens [batch, seq, d_model] -> same.
 
@@ -116,6 +120,17 @@ class MoEMLP(nn.Module):
     #: the expert-parallel rank whose share is computed outside a bound
     #: ``axis_name`` axis (``dropless`` only; see the class docstring)
     ep_rank: int = 0
+    #: width of a SHARED expert beside the routed ones (0: none): every
+    #: token runs through it, its output is added to the routed sum.  Its
+    #: leaves (``shared_wg`` / ``shared_wi`` / ``shared_wo``, gated like the
+    #: routed experts) are no expert leaves: every rank holds and computes
+    #: it, expert-parallel or not, and nothing of it is exchanged.  Of the
+    #: ranks' shares of a layer it is the part all compute alike: counted
+    #: once when they are summed
+    shared_d_ff: int = 0
+    #: the shared expert's output is multiplied by ``sigmoid(x w_s)``, one
+    #: float32 scalar a token (leaf ``shared_gate``)
+    shared_gate: bool = False
 
     @nn.compact
     def __call__(self, x, route_x=None):
@@ -156,8 +171,10 @@ class MoEMLP(nn.Module):
             (n_local, d, self.d_ff), self.param_dtype,
         ) if self.gated else None
 
+        shared = self._shared(xt)
         if self.dropless:
-            return self._dropless(xt, logits, wi, wo, wg).reshape(b, s, d)
+            return (self._dropless(xt, logits, wi, wo, wg)
+                    + shared).reshape(b, s, d)
 
         if self.k > 2:
             raise ValueError(
@@ -206,7 +223,32 @@ class MoEMLP(nn.Module):
             )
 
         y = jnp.einsum("tec,ecd->td", combine.astype(self.dtype), out)
-        return y.reshape(b, s, d)
+        return (y + shared).reshape(b, s, d)
+
+    def _shared(self, xt):
+        """The shared expert on every token [T, d], under the scope
+        ``bagua.moe/shared``; 0 where the layer has none."""
+        if not self.shared_d_ff:
+            return 0
+        from ...telemetry import counters
+
+        if not self.is_initializing():
+            counters.set_gauge("moe/shared_width", self.shared_d_ff)
+        act = _ACTIVATIONS[self.activation]
+        dense = lambda name, features: nn.Dense(
+            features, use_bias=False, dtype=self.dtype,
+            param_dtype=self.param_dtype, name=name)
+        with phase_scope("bagua.moe/shared"):
+            up = dense("shared_wi", self.shared_d_ff)(xt)
+            h = (act(dense("shared_wg", self.shared_d_ff)(xt)) * up
+                 if self.gated else act(up))
+            y = dense("shared_wo", xt.shape[1])(h)
+            if self.shared_gate:
+                gate = nn.Dense(1, use_bias=False, dtype=jnp.float32,
+                                param_dtype=jnp.float32, name="shared_gate")(
+                                    xt.astype(jnp.float32))
+                y = (jax.nn.sigmoid(gate) * y).astype(y.dtype)
+            return y
 
     def _experts(self, x_p, layout, wi, wo, wg):
         """The expert FFN on rows resident in ``layout`` (grouped by local
@@ -218,7 +260,7 @@ class MoEMLP(nn.Module):
         from ...ops.gmm import gmm_padded
         from ...telemetry import counters
 
-        act = {"silu": nn.silu, "relu": nn.relu}[self.activation]
+        act = _ACTIVATIONS[self.activation]
 
         if not self.is_initializing():
             # what the kernels really multiply (under ``ep`` the rows of

@@ -153,6 +153,32 @@ class TransformerConfig:
     #: positions of a diffusion block: within one, noised rows see each
     #: other in both directions.  Read with ``attention="block_diffusion"``
     diffusion_block: int = 0
+    #: which layers mix tokens by LINEAR attention (the gated delta rule,
+    #: ``models.linear_attention.GatedDeltaNet``) in place of softmax
+    #: attention, a period like ``window_layers`` (``(1, 1, 1, 0)``: softmax
+    #: attention on every fourth layer); None: no layer.  Such a layer's
+    #: state is per sequence: the decode paths, ``sp_axis``, the tensor and
+    #: pipeline axes do not implement it
+    mixer_layers: Optional[tuple] = None
+    #: the linear-attention layers' key and value heads (a key head serves
+    #: ``value_heads // key_heads`` value heads), their widths, and the taps
+    #: of the causal depthwise convolution over q / k / v
+    linear_key_heads: int = 0
+    linear_value_heads: int = 0
+    linear_key_dim: int = 0
+    linear_value_dim: int = 0
+    linear_conv: int = 4
+    #: softmax attention's output gate: the q projection is twice as wide, a
+    #: head's second ``head_dim`` outputs are its gate, and the attention's
+    #: output is multiplied by their sigmoid before the out-projection
+    attn_gate: bool = False
+    #: lanes of a head that ``rope_theta`` rotates (rotate-half over the
+    #: first ``rotary_dim``, the rest unrotated); None: the whole head
+    rotary_dim: Optional[int] = None
+    #: every RMSNorm of the trunk (the blocks', the final one, ``qk_norm=
+    #: "head"``'s) multiplies by ``1 + scale`` with ``scale`` initialised to
+    #: zero (the Qwen3-Next family's zero-centred norm)
+    norm_zero_centered: bool = False
 
     @property
     def block_diffusion(self) -> bool:
@@ -187,6 +213,11 @@ class TransformerConfig:
             return False
         pattern = self.rope_layers
         return pattern is None or bool(pattern[layer % len(pattern)])
+
+    def layer_linear(self, layer: int) -> bool:
+        """Whether layer ``layer``'s mixer is linear attention."""
+        pattern = self.mixer_layers
+        return pattern is not None and bool(pattern[layer % len(pattern)])
 
 
 def bert_large_config(**kw) -> TransformerConfig:
@@ -232,15 +263,20 @@ class RMSNorm(nn.Module):
     dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     eps: float = 1e-6
+    #: multiply by ``1 + scale``, ``scale`` initialised to zero
+    zero_centered: bool = False
 
     @nn.compact
     def __call__(self, x):
         scale = self.param(
-            "scale", nn.initializers.ones, (x.shape[-1],), self.param_dtype
+            "scale", nn.initializers.zeros if self.zero_centered
+            else nn.initializers.ones, (x.shape[-1],), self.param_dtype
         )
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         y = x32 * jax.lax.rsqrt(var + self.eps)
+        if self.zero_centered:
+            scale = 1.0 + scale
         return (y * scale).astype(self.dtype)
 
 
@@ -274,6 +310,8 @@ def rotates_by_kernel(cfg: TransformerConfig, seq: int, attn_fn=None) -> bool:
     from ..ops.rope import rope_supported
 
     heads, kv_heads = cfg.n_heads // cfg.tp_size, cfg.kv_heads // cfg.tp_size
+    if cfg.rotary_dim not in (None, cfg.head_dim):
+        return False  # the kernel rotates whole heads
     if cfg.block_diffusion:
         # ``seq`` rows are two halves, each rotated at ``0 .. seq / 2 - 1``
         return (attn_fn is None and rope_supported(seq // 2, cfg.head_dim)
@@ -395,6 +433,10 @@ class Attention(nn.Module):
             raise NotImplementedError(
                 "grouped key / value heads and windows are not implemented "
                 "for the decode paths")
+        if cfg.decode and (cfg.attn_gate or cfg.rotary_dim is not None):
+            raise NotImplementedError(
+                "attn_gate and rotary_dim are not implemented for the decode "
+                "paths")
         if _tp_active(cfg):
             from ..parallel.tensor_parallel import tp_gather_grad
 
@@ -410,20 +452,25 @@ class Attention(nn.Module):
         by_kernel = (block_diffusion_supported if cfg.block_diffusion
                      else flash_supported)
         if not cfg.decode and by_kernel(x.shape[1], h, d, kv_heads=kv_h):
-            dense = lambda name, out=None, heads=h: HeadsDense(
-                heads, d, out, name=name, dtype=cfg.dtype,
+            dense = lambda name, out=None, heads=h, width=d: HeadsDense(
+                heads, width, out, name=name, dtype=cfg.dtype,
                 param_dtype=cfg.param_dtype)
         else:
-            dense = lambda name, out=None, heads=h: nn.DenseGeneral(
-                (heads, d) if out is None else out,
+            dense = lambda name, out=None, heads=h, width=d: nn.DenseGeneral(
+                (heads, width) if out is None else out,
                 axis=-1 if out is None else (-2, -1), name=name,
                 dtype=cfg.dtype, param_dtype=cfg.param_dtype, use_bias=False)
+        # with the output gate a head's q projection is [q | gate]
         q, k, v = (checkpoint_name(
-            dense(n, heads=h if n == "q" else kv_h)(x), KEPT_QKV)
-            for n in "qkv")
+            dense(n, heads=h if n == "q" else kv_h,
+                  width=2 * d if cfg.attn_gate and n == "q" else d)(x),
+            KEPT_QKV) for n in "qkv")
+        if cfg.attn_gate:
+            q, gate = q[..., :d], q[..., d:]
         if cfg.qk_norm == "head":
             head_norm = lambda name: RMSNorm(
-                cfg.dtype, cfg.param_dtype, cfg.norm_eps, name=name)
+                cfg.dtype, cfg.param_dtype, cfg.norm_eps,
+                cfg.norm_zero_centered, name=name)
             q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
         elif cfg.qk_norm:
             if _tp_active(cfg):
@@ -444,6 +491,11 @@ class Attention(nn.Module):
             rotate = rope_rotate
             if rotates_by_kernel(cfg, q.shape[1], self.attn_fn):
                 from ..ops.rope import rope as rotate
+            elif cfg.rotary_dim not in (None, d):
+                # the first rotary_dim lanes of a head, the rest as they are
+                rotate = lambda t, theta, start: jnp.concatenate(
+                    [rope_rotate(t[..., :cfg.rotary_dim], theta, start),
+                     t[..., cfg.rotary_dim:]], axis=-1)
             if cfg.block_diffusion:
                 # positions restart: the noised half sits at 0 .. L - 1
                 # like the clean one.  [b, 2 L, h, d] -> [2 b, L, h, d] and
@@ -469,6 +521,8 @@ class Attention(nn.Module):
             # parallel drop-ins take
             o = (fn(q, k, v, cfg.dtype) if self.window is None
                  else fn(q, k, v, cfg.dtype, window=self.window))
+        if cfg.attn_gate:
+            o = o * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(o.dtype)
         out = dense("o", cfg.d_model)(o)
         if _tp_active(cfg):
             from ..parallel.tensor_parallel import tp_reduce
@@ -631,21 +685,28 @@ class Block(nn.Module):
     def __call__(self, x, slots=None):
         cfg = self.cfg
         block_in = x
-        y = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
-                    name="attn_norm")(x)
-        attn = Attention(cfg, self.attn_fn, cfg.layer_window(self.layer),
-                         cfg.layer_rotary(self.layer), name="attn")
+        norm = lambda name: RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
+                                    cfg.norm_zero_centered, name=name)
+        if cfg.layer_linear(self.layer):
+            # the layer's mixer is linear attention; module and norm carry
+            # names of their own (area ``linattn``)
+            from .linear_attention import GatedDeltaNet
+
+            _check_linear_attention(cfg, slots)
+            y = norm("linear_attn_norm")(x)
+            attn = GatedDeltaNet(cfg, name="linear_attn")
+        else:
+            y = norm("attn_norm")(x)
+            attn = Attention(cfg, self.attn_fn, cfg.layer_window(self.layer),
+                             cfg.layer_rotary(self.layer), name="attn")
         # the sub-layer's output through its own norm where the
         # configuration has one
-        post = lambda name, t: RMSNorm(
-            cfg.dtype, cfg.param_dtype, cfg.norm_eps, name=name,
-        )(t) if cfg.post_norms else t
+        post = lambda name, t: norm(name)(t) if cfg.post_norms else t
         # dense/training call sites keep their exact one-arg form (the
         # goldens pin those programs); only paged decode threads slots
         x = x + post("attn_post_norm",
                      attn(y) if slots is None else attn(y, slots))
-        y = RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
-                    name="mlp_norm")(x)
+        y = norm("mlp_norm")(x)
         mlp = self.mlp() if self.mlp is not None else MLPBlock(cfg, name="mlp")
         if cfg.route_before_attention:
             if self.mlp is None:
@@ -655,6 +716,29 @@ class Block(nn.Module):
             return x + post("mlp_post_norm", mlp(y, route_x=block_in))
         x = x + post("mlp_post_norm", mlp(y))
         return x
+
+
+def _check_linear_attention(cfg: TransformerConfig, slots) -> None:
+    """The paths a linear-attention layer (``mixer_layers``) cannot take
+    refuse it here: its state runs over the whole sequence on one device."""
+    if cfg.decode or slots is not None:
+        raise NotImplementedError(
+            "mixer_layers (linear attention) is not implemented on the "
+            "decode paths: a recurrent state beside the key / value cache")
+    if cfg.sp_axis is not None:
+        raise NotImplementedError(
+            "mixer_layers (linear attention) is not implemented under "
+            "sp_axis: a sequence shard would need the state of the shard "
+            "before it")
+    if cfg.tp_axis is not None or cfg.tp_size > 1:
+        raise NotImplementedError(
+            "mixer_layers (linear attention) is not implemented under the "
+            "tensor-parallel axis (tp_axis / tp_size): its heads are not "
+            "sharded")
+    if cfg.n_passes > 1 or cfg.block_diffusion:
+        raise NotImplementedError(
+            "mixer_layers (linear attention) is not implemented for a "
+            "looped stack or under attention='block_diffusion'")
 
 
 def _check_block_diffusion(cfg: TransformerConfig, rows: int) -> None:
@@ -758,22 +842,35 @@ class TransformerLM(nn.Module):
             from ..ops.embed_grad import grad_kernel_supported
             from ..telemetry import counters
 
-            windowed = sum(cfg.layer_window(i) is not None
-                           for i in range(cfg.n_layers))
+            softmax = [i for i in range(cfg.n_layers)
+                       if not cfg.layer_linear(i)]
+            windowed = sum(cfg.layer_window(i) is not None for i in softmax)
             counters.set_gauge("attn/kv_heads", cfg.kv_heads // cfg.tp_size)
             counters.set_gauge("attn/window", cfg.window or 0)
             counters.set_gauge("attn/window_layers", windowed)
             counters.set_gauge(
                 "attn/full_layers",
-                0 if cfg.block_diffusion else cfg.n_layers - windowed)
+                0 if cfg.block_diffusion else len(softmax) - windowed)
             # the rotary layers whose rotation is the ``rope`` kernel
             by_kernel = rotates_by_kernel(cfg, tokens.shape[1], self.attn_fn)
             counters.set_gauge("attn/rope_kernel_layers", by_kernel * sum(
-                cfg.layer_rotary(i) for i in range(cfg.n_layers)))
+                cfg.layer_rotary(i) for i in softmax))
             # 1: this step's token-table gradient is the ``embed_grad``
             # kernel; 0: it fell back to the gather's own transpose
             counters.set_gauge("embed/grad_kernel",
                                int(grad_kernel_supported(cfg.d_model)))
+            if cfg.mixer_layers is not None:
+                counters.set_gauge("linattn/layers", sum(
+                    cfg.layer_linear(i) for i in range(cfg.n_layers)))
+                from ..ops.gated_delta import CHUNK
+
+                counters.set_gauge("linattn/chunk", CHUNK)
+                counters.set_gauge("linattn/key_heads", cfg.linear_key_heads)
+                counters.set_gauge("linattn/value_heads",
+                                   cfg.linear_value_heads)
+            if cfg.rope_theta is not None:
+                counters.set_gauge("attn/rotary_dim",
+                                   cfg.rotary_dim or cfg.head_dim)
             if cfg.n_passes > 1:
                 counters.set_gauge("loop/passes", cfg.n_passes)
                 counters.set_gauge("loop/shared_layers", cfg.n_layers)
@@ -807,7 +904,7 @@ class TransformerLM(nn.Module):
 
         def final_norm(x):
             return RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
-                           name="final_norm")(x)
+                           cfg.norm_zero_centered, name="final_norm")(x)
 
         def lm_head(x):
             return nn.Dense(

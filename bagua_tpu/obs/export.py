@@ -201,6 +201,29 @@ _declare("moe/row_kernel_sites", "gauge",
          "gates, and its transpose) run the kernels of ops/moe_rows.py: 3 "
          "where they run (the move in stays XLA's gather, which is the "
          "faster one), 0 on the jnp bodies.")
+_declare("moe/shared_width", "gauge",
+         "Width of the shared expert beside the routed ones in the MoE "
+         "layer last traced (MoEMLP.shared_d_ff; scope bagua.moe/shared): "
+         "every rank computes it on its own tokens, nothing of it is "
+         "exchanged.  Not set where the layer has none.")
+# -- linear attention (set when a TransformerLM step with mixer_layers is traced) --
+_declare("linattn/layers", "gauge",
+         "Layers of the model last traced whose mixer is linear attention "
+         "(TransformerConfig.mixer_layers; the gated delta rule, kernels "
+         "gdn_fwd / gdn_bwd where they run).")
+_declare("linattn/chunk", "gauge",
+         "Positions of a chunk of that model's chunked scan "
+         "(ops.gated_delta.CHUNK): inside a chunk matrix products "
+         "and one triangular solve, between chunks the carried state.")
+_declare("linattn/key_heads", "gauge",
+         "Key heads of that model's linear-attention layers.")
+_declare("linattn/value_heads", "gauge",
+         "Value heads of those layers (a key head serves value_heads / "
+         "key_heads of them): one [d_k, d_v] float32 state each.")
+_declare("attn/rotary_dim", "gauge",
+         "Lanes of a head that the rotary layers of the model last traced "
+         "rotate (TransformerConfig.rotary_dim; the head's width where "
+         "the whole head rotates).  Not set without rope_theta.")
 _declare("comm/aborts", "counter",
          "Cooperative abort flag raises (watchdog fire, grad-guard abort, "
          "user abort()).")

@@ -397,7 +397,7 @@ LOOP_SCOPE = "loop_body"
 DIFFUSION_INPUT_SCOPE = "diffusion_input"
 #: the scope whose NEXT component is the part of an expert layer
 MOE_SCOPE = "bagua.moe"
-MOE_PARTS = ("route", "dispatch", "experts", "combine")
+MOE_PARTS = ("route", "dispatch", "experts", "combine", "shared")
 
 #: component of an ``op_name`` path -> the area it names.  The contract
 #: ``area_of`` reads; ``tests/test_step_scopes.py`` holds the compiled step
@@ -406,12 +406,13 @@ AREA_COMPONENTS = {
     "embed": "embed", POS_EMBED_SCOPE: "embed",
     DIFFUSION_INPUT_SCOPE: "embed",
     "attn_norm": "attn", "attn": "attn", "attn_post_norm": "attn",
+    "linear_attn_norm": "linattn", "linear_attn": "linattn",
     "mlp_norm": "mlp", "mlp": "mlp", "mlp_post_norm": "mlp",
     "final_norm": "head", "lm_head": "head", LOSS_TAIL_SCOPE: "head",
     "exit_gate": "exit", EXIT_SCOPE: "exit",
     ACCUM_SCOPE: "accum",
 }
-AREAS = ("embed", "attn", "mlp") + tuple(
+AREAS = ("embed", "attn", "linattn", "mlp") + tuple(
     f"moe/{part}" for part in MOE_PARTS) + ("head", "exit", "accum")
 
 

@@ -88,6 +88,12 @@ class PipelinedTransformerLM(nn.Module):
                 "attention='block_diffusion' (the [x ; x~] rows, their mask, "
                 "the noised half's head) is not implemented under pipeline "
                 "stages")
+        if cfg.mixer_layers is not None or cfg.norm_zero_centered:
+            raise NotImplementedError(
+                "the pipelined stack scans ONE kind of block and closes in a "
+                "plain RMSNorm: mixer_layers (linear-attention layers by "
+                "period) and norm_zero_centered are not implemented under "
+                "pipeline stages")
         assert cfg.n_layers % self.pp_size == 0, (cfg.n_layers, self.pp_size)
         n_local = cfg.n_layers // self.pp_size
 

@@ -70,6 +70,7 @@ MODULES = [
     "bagua_tpu.models.resnet",
     "bagua_tpu.models.vgg",
     "bagua_tpu.models.transformer",
+    "bagua_tpu.models.linear_attention",
     "bagua_tpu.models.generate",
     "bagua_tpu.serve",
     "bagua_tpu.serve.cache",
@@ -80,6 +81,7 @@ MODULES = [
     "bagua_tpu.ops.embed_grad",
     "bagua_tpu.ops.rope",
     "bagua_tpu.ops.moe_rows",
+    "bagua_tpu.ops.gated_delta",
     "bagua_tpu.ops.tiles",
     "bagua_tpu.compression.codecs",
     "bagua_tpu.compression.minmax_uint8",

@@ -284,3 +284,68 @@ def test_the_row_kernels_compile_at_the_cells_shapes(tokens, rows, k, d,
         calls = [line for line in text.splitlines()
                  if 'custom_call_target="tpu_custom_call"' in line]
         assert len(calls) == 1 and name in calls[0]
+
+
+def test_the_flash_kernels_compile_at_head_dim_256_under_grouped_heads(
+        one_chip):
+    """qwen3-next-80b-a3b.pretrain4096-b2-dp1's full-attention layer: 16
+    query heads over 2 key / value heads of 256 at 4,096 rows — one head a
+    256-lane block, the first cell over 128 lanes a head."""
+    from bagua_tpu.ops.flash_attention import (
+        heads_per_block, kv_grouping_supported,
+    )
+
+    b, s, h, kv_h, d = 2, 4096, 16, 2, 256
+    assert heads_per_block(h, d) == 1 and kv_grouping_supported(h, kv_h, d)
+    # flash_supported's budget: two whole-sequence operands of a head,
+    # double-buffered, in 12 MiB: 6,144 rows at 256 lanes
+    assert 4 * s * d * 2 <= 12 * 2 ** 20 < 4 * 8192 * d * 2
+    q = jax.ShapeDtypeStruct((b, s, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, s, kv_h, d), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        o, lse = flash_attention_with_lse(q, k, v, causal=True)
+        return o.sum() + lse.sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile().as_text()
+    calls = {re.search(r"%(\w+?)\.\d+ = ", line).group(1): line
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    assert sorted(calls) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    for name, line in calls.items():
+        first = re.search(r"= \(?bf16\[([\d,]+)\]", line).group(1)
+        assert first == (f"{b},{s},{kv_h * d}" if name.endswith("dkv")
+                         else f"{b},{s},{h * d}"), line
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_the_gated_delta_kernels_compile_at_the_cells_shape(chunk, one_chip):
+    """qwen3-next-80b-a3b.pretrain4096-b2-dp1's linear-attention layers: 16
+    key and 32 value heads of 128 over 2 x 4,096 positions; q / k / v enter
+    and o / dq / dk / dv leave as [b, T, heads * 128], the per-chunk states
+    are kept once in bfloat16."""
+    from bagua_tpu.ops.gated_delta import gated_delta_rule
+
+    b, seq, hk, hv, d = 2, 4096, 16, 32, 128
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    args = (shape(b, seq, hk, d), shape(b, seq, hk, d), shape(b, seq, hv, d),
+            shape(b, seq, hv, dtype=jnp.float32),
+            shape(b, seq, hv, dtype=jnp.float32))
+
+    def loss(q, k, v, g, beta):
+        return gated_delta_rule(q, k, v, g, beta, chunk=chunk,
+                                force=True).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    calls = {re.search(r"%(\w+?)\.\d+ = ", line).group(1): line
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    assert sorted(calls) == ["gdn_bwd", "gdn_fwd"]
+    first = lambda line: re.search(r"= \(?bf16\[([\d,]+)\]", line).group(1)
+    assert first(calls["gdn_fwd"]) == f"{b},{seq},{hv * d}"      # o
+    assert first(calls["gdn_bwd"]) == f"{b},{seq},{hk * d}"      # dq
+    # the states the backward call reads: one [d_k, d_v] a chunk and head
+    assert f"bf16[{b},{hv},{seq // chunk},{d},{d}]" in calls["gdn_bwd"]
