@@ -267,11 +267,22 @@ class RMSNorm(nn.Module):
     zero_centered: bool = False
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, rope=None):
+        """``rope``: ``(theta, start)`` — ``x`` is ``[batch, seq, heads,
+        head_dim]``, and each normalised head is rotated as well, norm and
+        rotation in one pass over the merged rows
+        (:func:`bagua_tpu.ops.rope.norm_rope`; the caller gates on
+        :func:`rotates_by_kernel`)."""
         scale = self.param(
             "scale", nn.initializers.zeros if self.zero_centered
             else nn.initializers.ones, (x.shape[-1],), self.param_dtype
         )
+        if rope is not None:
+            from ..ops.rope import norm_rope
+
+            return norm_rope(
+                x, scale, *rope, eps=self.eps,
+                zero_centered=self.zero_centered).astype(self.dtype)
         x32 = x.astype(jnp.float32)
         var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
         y = x32 * jax.lax.rsqrt(var + self.eps)
@@ -302,8 +313,11 @@ def rotates_by_kernel(cfg: TransformerConfig, seq: int, attn_fn=None) -> bool:
     by the ``rope`` kernel (:mod:`bagua_tpu.ops.rope`): where the flash
     kernels run, one pass over the ``[b, s, h * d]`` that ``HeadsDense``
     writes and they read, on heads of whole 128-lane tiles.  Everywhere else
-    (off the TPU, short or ragged sequences, head_dim 64, the einsum path,
-    an ``attn_fn`` drop-in, decode) :func:`rope_rotate`."""
+    (off the TPU, short or ragged sequences, head_dim 64, a rotation of part
+    of a head, the einsum path, an ``attn_fn`` drop-in, decode)
+    :func:`rope_rotate`.  Where it is true and ``cfg.qk_norm == "head"``, the
+    per-head norm of q and k rides the same pass (``RMSNorm``'s ``rope=``),
+    and neither takes a float32 ``[b, s, h, d]`` form on the way."""
     from ..ops.flash_attention import (
         block_diffusion_supported, flash_supported,
     )
@@ -467,11 +481,19 @@ class Attention(nn.Module):
             KEPT_QKV) for n in "qkv")
         if cfg.attn_gate:
             q, gate = q[..., :d], q[..., d:]
+        rotary = cfg.rope_theta is not None and self.rotary
+        by_kernel = rotary and rotates_by_kernel(cfg, q.shape[1],
+                                                 self.attn_fn)
+        # where the ``rope`` kernel rotates, a per-head norm rides its pass:
+        # q and k stay the rows the projection wrote, all the way to the
+        # attention kernel
+        norm_rides = cfg.qk_norm == "head" and by_kernel
         if cfg.qk_norm == "head":
             head_norm = lambda name: RMSNorm(
                 cfg.dtype, cfg.param_dtype, cfg.norm_eps,
                 cfg.norm_zero_centered, name=name)
-            q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
+            if not norm_rides:
+                q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
         elif cfg.qk_norm:
             if _tp_active(cfg):
                 raise NotImplementedError(
@@ -481,7 +503,7 @@ class Attention(nn.Module):
                 cfg.dtype, cfg.param_dtype, cfg.norm_eps, name=name,
             )(t.reshape(*t.shape[:-2], t.shape[-2] * d)).reshape(t.shape)
             q, k = flat_norm("q_norm", q), flat_norm("k_norm", k)
-        if cfg.rope_theta is not None and self.rotary:
+        if rotary:
             if cfg.decode:
                 raise NotImplementedError(
                     "rope_theta is not implemented for the decode paths")
@@ -489,23 +511,40 @@ class Attention(nn.Module):
             if cfg.sp_axis is not None and _axis_bound(cfg.sp_axis):
                 start = jax.lax.axis_index(cfg.sp_axis) * q.shape[1]
             rotate = rope_rotate
-            if rotates_by_kernel(cfg, q.shape[1], self.attn_fn):
+            if by_kernel:
                 from ..ops.rope import rope as rotate
             elif cfg.rotary_dim not in (None, d):
                 # the first rotary_dim lanes of a head, the rest as they are
                 rotate = lambda t, theta, start: jnp.concatenate(
                     [rope_rotate(t[..., :cfg.rotary_dim], theta, start),
                      t[..., cfg.rotary_dim:]], axis=-1)
-            if cfg.block_diffusion:
-                # positions restart: the noised half sits at 0 .. L - 1
-                # like the clean one.  [b, 2 L, h, d] -> [2 b, L, h, d] and
-                # back are free reshapes around the one rotation
-                halves = lambda t: rotate(
-                    t.reshape(2 * t.shape[0], t.shape[1] // 2, *t.shape[2:]),
-                    cfg.rope_theta, start).reshape(t.shape)
-                q, k = halves(q), halves(k)
-            else:
-                q, k = (rotate(t, cfg.rope_theta, start) for t in (q, k))
+
+            def turn(name, t):
+                rows = t
+                if cfg.block_diffusion:
+                    # positions restart: the noised half sits at 0 .. L - 1
+                    # like the clean one.  [b, 2 L, h, d] -> [2 b, L, h, d]
+                    # and back are free reshapes around the one pass
+                    rows = t.reshape(2 * t.shape[0], t.shape[1] // 2,
+                                     *t.shape[2:])
+                if norm_rides:
+                    return head_norm(name)(
+                        rows, rope=(cfg.rope_theta, start)).reshape(t.shape)
+                return rotate(rows, cfg.rope_theta, start).reshape(t.shape)
+
+            q = turn("q_norm", q)
+            if norm_rides:
+                # Two ties that compute nothing, here for the step's memory
+                # alone.  With q and k off their float32 detour the TPU
+                # compiler's scheduler leaves the head's weight-gradient
+                # fusion, and the logits it reads, to the end of SDAR's step:
+                # + 0.21 GB at the peak, twice ``peak_hbm_gb``'s bound.  Of
+                # the orders tried this one keeps that fusion where the
+                # parent's step has it, for three bfloat16 re-tilings of q a
+                # layer (PERF.md §6 and §7, PR 51: it is a nudge, not a rule)
+                k, v = jax.lax.optimization_barrier((k, v))
+                q, v = jax.lax.optimization_barrier((q, v))
+            k = turn("k_norm", k)
         if cfg.decode and cfg.page_size > 0:
             o = self._paged_decode_attend(q, k, v, slots)
         elif cfg.decode:
@@ -855,6 +894,11 @@ class TransformerLM(nn.Module):
             by_kernel = rotates_by_kernel(cfg, tokens.shape[1], self.attn_fn)
             counters.set_gauge("attn/rope_kernel_layers", by_kernel * sum(
                 cfg.layer_rotary(i) for i in softmax))
+            # of those, the layers whose q / k norm rides the same pass
+            counters.set_gauge(
+                "attn/head_norm_kernel_layers",
+                by_kernel * (cfg.qk_norm == "head") * sum(
+                    cfg.layer_rotary(i) for i in softmax))
             # 1: this step's token-table gradient is the ``embed_grad``
             # kernel; 0: it fell back to the gather's own transpose
             counters.set_gauge("embed/grad_kernel",

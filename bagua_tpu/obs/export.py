@@ -142,6 +142,12 @@ _declare("attn/rope_kernel_layers", "gauge",
          "head_dim] where the flash kernels run, heads of whole 128-lane "
          "tiles); a looped model's scanned body counts once.  0 where "
          "every rotary layer took rope_rotate, or none rotates.")
+_declare("attn/head_norm_kernel_layers", "gauge",
+         "Of attn/rope_kernel_layers, the layers whose per-head RMSNorm of "
+         "q and k (qk_norm=\"head\") rides the same pass (ops.rope."
+         "norm_rope: norm and rotation in float32 with one rounding, q and "
+         "k never in a float32 [batch, seq, heads, head_dim] form).  0 "
+         "where the rotation is not the kernel's, or no head is normalised.")
 _declare("attn/diffusion_block", "gauge",
          "Positions of a diffusion block of the block-diffusion model last "
          "traced (TransformerConfig.diffusion_block): its rows are a clean "

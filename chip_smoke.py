@@ -304,6 +304,33 @@ def leg_kernels(sz: dict, dryrun: bool) -> None:
     # one bf16 rounding either way: a bf16 ulp of the largest element
     assert all(np.isfinite(e) and e < 2.0 ** -7 for e in errs), errs
 
+    # ---- the same pass with a per-head RMSNorm in front, and its VJP ------
+    from bagua_tpu.models.transformer import RMSNorm
+    from bagua_tpu.ops.rope import norm_rope
+
+    scale = 1.0 + 0.3 * jax.random.normal(jax.random.PRNGKey(SEED + 4),
+                                          (r["d"],), jnp.float32)
+
+    def norm_loss(normed):
+        def loss(x, scale):
+            o = normed(x, scale)
+            return (o.astype(jnp.float32) * gw).sum(), o
+        return loss
+
+    kern = norm_loss(lambda x, s: norm_rope(x, s, 1e6, 7, interpret=interp))
+    ref = norm_loss(lambda x, s: rope_rotate(
+        RMSNorm().apply({"params": {"scale": s}}, x), 1e6, 7))
+    assert uses_pallas(lambda x, s: kern(x, s)[0], x, scale)
+    t0 = time.perf_counter()
+    grad = lambda f: jax.jit(jax.value_and_grad(f, (0, 1), has_aux=True))
+    (_, o_k), (g_k, s_k) = grad(kern)(x, scale)
+    (_, o_r), (g_r, s_r) = grad(ref)(x, scale)
+    errs = [rel_err(o_k, o_r), rel_err(g_k, g_r), rel_err(s_k, s_r)]
+    log(f"kernel norm_rope fwd+vjp {x.shape}: max rel err (o, dx, dscale) = "
+        f"{[round(e, 5) for e in errs]}  ({time.perf_counter() - t0:.1f}s)")
+    # the two modules round the normalised tensor on the way: two bf16 ulps
+    assert all(np.isfinite(e) and e < 2.0 ** -6 for e in errs), errs
+
     # ---- codec kernels, fused and tiled, f32 and bf16 --------------------
     n_chunks = 4
     for chunk in sz["codec_chunks"]:

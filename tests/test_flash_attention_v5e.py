@@ -191,6 +191,34 @@ def _unfused(text):
     return out
 
 
+def _compiled_layer(one_chip, monkeypatch, s, d_model, **cfg):
+    """The optimized HLO text of one ``Attention`` layer's loss gradient
+    (parameters and input) at ``[1, s, d_model]`` in bfloat16, compiled for
+    the described chip, and the layer's abstract parameters."""
+    import importlib
+
+    from bagua_tpu.models.transformer import Attention, TransformerConfig
+
+    # flash_supported asks jax.default_backend(), which is still the CPU
+    # here: steered in the test, not by an option of the program
+    flash = importlib.import_module("bagua_tpu.ops.flash_attention")
+    monkeypatch.setattr(flash.jax, "default_backend", lambda: "tpu")
+    layer = Attention(TransformerConfig(
+        vocab_size=128, d_model=d_model, d_head=128, n_layers=1, d_ff=128,
+        max_seq_len=s, rope_theta=1e6, **cfg))
+    shaped = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                            sharding=one_chip)
+    x = jax.ShapeDtypeStruct((1, s, d_model), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(shaped, jax.eval_shape(
+        layer.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        return jnp.sum(layer.apply(params, x).astype(jnp.float32) ** 2)
+
+    return jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text(), params
+
+
 @pytest.mark.parametrize("s, h, kv_h, d_model", [
     (4096, 16, 16, 2048),   # ouro-2.6b.pretrain4096-b1-dp1 (and OLMoE's)
     (8192, 28, 4, 2560),    # smallthinker-21b-a3b.pretrain8192-dp1: grouped
@@ -204,28 +232,8 @@ def test_a_rotary_layer_rotates_q_and_k_as_the_projections_write_them(
     slice / negate / ``concatenate`` form left a dozen bare float32
     ``copy`` / ``reshape`` / ``broadcast`` a layer and made the projections
     write sequence-minor (PERF.md §6, PR 44)."""
-    import importlib
-
-    from bagua_tpu.models.transformer import Attention, TransformerConfig
-
-    # flash_supported asks jax.default_backend(), which is still the CPU
-    # here: steered in the test, not by an option of the program
-    flash = importlib.import_module("bagua_tpu.ops.flash_attention")
-    monkeypatch.setattr(flash.jax, "default_backend", lambda: "tpu")
-    layer = Attention(TransformerConfig(
-        vocab_size=128, d_model=d_model, n_heads=h, n_kv_heads=kv_h,
-        d_head=128, n_layers=1, d_ff=128, max_seq_len=s, rope_theta=1e6))
-    shaped = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
-                                            sharding=one_chip)
-    x = jax.ShapeDtypeStruct((1, s, d_model), jnp.bfloat16, sharding=one_chip)
-    params = jax.tree.map(shaped, jax.eval_shape(
-        layer.init, jax.random.PRNGKey(0), x))
-
-    def loss(params, x):
-        return jnp.sum(layer.apply(params, x).astype(jnp.float32) ** 2)
-
-    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
-        params, x).compile().as_text()
+    text, _ = _compiled_layer(one_chip, monkeypatch, s, d_model, n_heads=h,
+                              n_kv_heads=kv_h)
     ops = _unfused(text)
     by_name = {name: shape for name, shape, *_ in ops}
     calls = [(shape, line) for _, shape, opcode, op_name, line in ops
@@ -250,6 +258,46 @@ def test_a_rotary_layer_rotates_q_and_k_as_the_projections_write_them(
             and str(s) in re.match(r"\w+\[([\d,]*)\]", shape).group(1).split(",")
             and not re.search(r"jit\(_(fwd|bwd)\)", op_name)]
     assert not bare, bare
+
+
+def test_a_head_normed_layer_keeps_q_and_k_on_the_flat_rows(one_chip,
+                                                            monkeypatch):
+    """SDAR's ``Attention`` layer at its cell's shapes (8,192 rows of
+    ``[x ; x~]``, 32 query heads over 4 of 128, ``qk_norm="head"``), forward
+    and backward: norm and rotation are four ``rope`` calls — q and k, each
+    way, ``ops.rope.norm_rope`` — on the row-major ``[2, 4096, h * d]``
+    between the projections and the ``flash_bd_*`` kernels, and no float32
+    value with a ``[heads, 128]`` tail exists between them.  As XLA ops the
+    norm wrote, broadcast and re-tiled q and k in float32, 1.7 GB a layer
+    and step (PERF.md §6, PR 51)."""
+    s, h, kv_h = 8192, 32, 4
+    text, params = _compiled_layer(
+        one_chip, monkeypatch, s, 2048, n_heads=h, n_kv_heads=kv_h,
+        qk_norm="head", attention="block_diffusion", diffusion_block=4)
+    assert set(params["params"]) == {"q", "k", "v", "o", "q_norm", "k_norm"}
+    ops = _unfused(text)
+    # the backward calls return (d_x, the scale's partial sums): a tuple
+    calls = re.findall(
+        r"= \(?bf16\[2,4096,(\d+)\]\{([\d,]+)[^=]* custom-call\(.*"
+        r"Attention\)\)?/(\w+/jit\(\w+\))/rope/pallas_call", text)
+    assert sorted(name for _, _, name in calls) == sorted(
+        f"{norm}/jit({fn})" for norm in ("q_norm", "k_norm")
+        for fn in ("_norm_rotate", "_norm_unrotate")), calls
+    for lanes, layout, _ in calls:
+        assert int(lanes) in (h * 128, kv_h * 128) and layout == "2,1,0"
+    in_float32 = [(opcode, shape, op_name)
+                  for _, shape, opcode, op_name, _ in ops
+                  if re.match(r"\(?f32\[[\d,]*,(32|4),128\]", shape)
+                  and "/attn/" in op_name + "/"
+                  and not re.search(r"/(q|k|v|o)/dot_general", op_name)]
+    assert not in_float32, in_float32
+    # what is left bare around the passes is bfloat16: the re-layouts that
+    # ``Attention``'s barrier over q, k and v costs (PERF.md §6, PR 51)
+    bare = [(opcode, shape, op_name) for _, shape, opcode, op_name, _ in ops
+            if opcode in ("copy", "reshape", "broadcast", "transpose")
+            and re.match(r"\(?\w+\[(1,8192|8192|2,4096),", shape)
+            and not re.search(r"jit\(_bd_(fwd|bwd)\)", op_name)]
+    assert all(shape.startswith("bf16[") for _, shape, _ in bare), bare
 
 
 @pytest.mark.parametrize("tokens,rows,k,d", [

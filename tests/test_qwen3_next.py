@@ -759,4 +759,5 @@ def test_a_traced_step_carries_the_scopes_and_sets_the_gauges():
     assert counters.get("attn/rotary_dim") == ROTARY
     assert counters.get("attn/full_layers") == 1
     assert counters.get("attn/rope_kernel_layers") == 0
+    assert counters.get("attn/head_norm_kernel_layers") == 0
     assert counters.get("moe/shared_width") == FF

@@ -759,4 +759,6 @@ def test_the_model_where_the_kernels_run(monkeypatch):
         got = model.apply({"params": params}, tokens)
     assert "name=flash_bd_fwd" in text and "name=rope" in text
     assert counters.snapshot()["attn/rope_kernel_layers"] == 1
+    # and its q / k norm rides that pass
+    assert counters.snapshot()["attn/head_norm_kernel_layers"] == 1
     np.testing.assert_allclose(got, want, atol=5e-5)

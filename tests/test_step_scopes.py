@@ -651,13 +651,18 @@ def pallas_call_names(path):
     return names
 
 
-@pytest.mark.parametrize("path, count", zip(KERNEL_FILES, (9, 2, 9, 1, 1)))
+@pytest.mark.parametrize("path, count", zip(KERNEL_FILES, (9, 2, 9, 1, 2)))
 def test_every_pallas_call_has_a_literal_name(path, count):
     names = pallas_call_names(path)
     assert len(names) == count
     assert all(re.fullmatch(r"[a-z][a-z0-9_]*", n) for n in names)
     everywhere = [n for p in KERNEL_FILES for n in pallas_call_names(p)]
-    assert len(set(everywhere)) == len(everywhere)
+    # one name is shared on purpose: ``norm_rope``'s call is the rotation's
+    # pass with a norm operand, and the readers that count or time ``rope``
+    # calls take both (ops/rope.py)
+    assert sorted(everywhere) == sorted([*set(everywhere), "rope"])
+    if path.endswith("rope.py"):
+        assert names == ["rope", "rope"]
     if path.endswith("flash_attention.py"):
         # the three names the gpt2 cell's readers key on, each beside
         # its windowed twin; then the block-diffusion kernels' own
@@ -681,6 +686,30 @@ ROPE_KERNEL_LAYERS = [
 ]
 
 
+def rotation_gauges(kw, forced, monkeypatch, seq=128):
+    """``(attn/rope_kernel_layers, attn/head_norm_kernel_layers)`` as a
+    tiny ``TransformerLM(**kw)`` sets them where its step is traced (not
+    run: ``eval_shape``); ``forced``: the gate's platform test says yes,
+    steered here in the test."""
+    import importlib
+
+    from bagua_tpu.models.transformer import TransformerConfig, TransformerLM
+
+    if forced:
+        flash = importlib.import_module("bagua_tpu.ops.flash_attention")
+        monkeypatch.setattr(flash, "flash_supported", lambda *a, **kw: True)
+    model = TransformerLM(TransformerConfig(
+        vocab_size=64, d_model=128, n_heads=2, d_head=128, d_ff=128,
+        max_seq_len=seq, **kw))
+    tokens = jnp.zeros((1, seq), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
+    names = ("attn/rope_kernel_layers", "attn/head_norm_kernel_layers")
+    for name in names:
+        counters.set_gauge(name, -1)
+    jax.eval_shape(model.apply, params, tokens)
+    return tuple(counters.get(name) for name in names)
+
+
 @pytest.mark.parametrize("forced", [True, False], ids=["forced", "off-tpu"])
 @pytest.mark.parametrize("name, kw, layers", ROPE_KERNEL_LAYERS,
                          ids=[c[0] for c in ROPE_KERNEL_LAYERS])
@@ -688,22 +717,37 @@ def test_the_gauge_counts_the_layers_on_the_rope_kernel(name, kw, layers,
                                                         forced, monkeypatch):
     """``attn/rope_kernel_layers``, set where the step is traced: a looped
     model's scanned body counts once, a NoPE layer not at all, and off the
-    TPU no layer reaches the kernel.  Traced, not run (``eval_shape``)."""
-    import importlib
+    TPU no layer reaches the kernel; none of the four normalises each head."""
+    assert rotation_gauges(kw, forced, monkeypatch) == (layers * forced, 0)
 
-    from bagua_tpu.models.transformer import TransformerConfig, TransformerLM
 
-    if forced:    # the gate's platform test, steered in the test
-        flash = importlib.import_module("bagua_tpu.ops.flash_attention")
-        monkeypatch.setattr(flash, "flash_supported", lambda *a, **kw: True)
-    model = TransformerLM(TransformerConfig(
-        vocab_size=64, d_model=128, n_heads=2, d_head=128, d_ff=128,
-        max_seq_len=128, **kw))
-    tokens = jnp.zeros((1, 128), jnp.int32)
-    params = jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens)
-    counters.set_gauge("attn/rope_kernel_layers", -1)
-    jax.eval_shape(model.apply, params, tokens)
-    assert counters.get("attn/rope_kernel_layers") == layers * forced
+#: configuration -> (rotary layers on the ``rope`` kernel, those of them
+#: whose per-head norm of q and k rides the same pass)
+HEAD_NORM_KERNEL_LAYERS = [
+    ("sdar", dict(n_layers=3, rope_theta=1e6, qk_norm="head", n_kv_heads=1,
+                  attention="block_diffusion", diffusion_block=4, remat=True,
+                  remat_policy="dots_no_batch"), 3, 3),
+    ("qwen3-next", dict(n_layers=4, rope_theta=1e7, qk_norm="head",
+                        norm_zero_centered=True, rotary_dim=32,
+                        attn_gate=True), 0, 0),
+    ("head-norm-some-layers-nope", dict(
+        n_layers=4, rope_theta=1e6, qk_norm="head",
+        rope_layers=(0, 1, 1, 1)), 3, 3),
+    ("olmoe", dict(n_layers=2, rope_theta=1e4, qk_norm=True), 2, 0),
+]
+
+
+@pytest.mark.parametrize("forced", [True, False], ids=["forced", "off-tpu"])
+@pytest.mark.parametrize("name, kw, rotary, normed", HEAD_NORM_KERNEL_LAYERS,
+                         ids=[c[0] for c in HEAD_NORM_KERNEL_LAYERS])
+def test_the_gauge_counts_the_layers_whose_head_norm_rides_the_pass(
+        name, kw, rotary, normed, forced, monkeypatch):
+    """``attn/head_norm_kernel_layers`` beside ``attn/rope_kernel_layers``:
+    SDAR's every layer, none of a model whose rotation is partial
+    (Qwen3-Next's softmax layers) or whose norm is over all heads (OLMoE),
+    none off the TPU."""
+    assert rotation_gauges(kw, forced, monkeypatch, seq=256) == (
+        rotary * forced, normed * forced)
 
 
 def test_no_pallas_call_outside_the_named_files():
