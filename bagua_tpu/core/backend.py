@@ -1856,9 +1856,9 @@ class BaguaTrainer:
                                 name="bagua-obs-cost-analysis")
 
     def train_step(self, state: TrainState, batch) -> Tuple[TrainState, jax.Array]:
-        # the root span of the step (and the profiler's step annotation):
-        # everything the trainer does on the host for one step is inside
-        # it, and its self time is what the child spans below leave over
+        # the root span of the step (and the profiler's step annotation): all
+        # the trainer does on the host for one step, tiled by its five child
+        # spans; its self time is check_abort and begin_step alone
         with trace_step_span(self._step_counter + 1):
             return self._train_step(state, batch)
 
@@ -1879,58 +1879,58 @@ class BaguaTrainer:
                 gated=self.algorithm.straggler_gates_step,
             ))
             state = self.algorithm.host_pre_step(self, state)
-        if self.algorithm.need_reset(self._step_counter - 1):
-            self._phase += 1
-            # reference re-runs init_tensors + rebucketing at phase switches
-            # (distributed.py:427-435); plan shape is identical here, phase key
-            # selects the recompiled step.
-        if (
-            self.autotune
-            and not self._autotune_completed
-            and self._step_counter % 100 == 0
-        ):
-            self._autotune_step(state)
-        if (
-            self.autotune
-            and not self._autotune_completed
-            and not self._telemetry_reported
-            and env.get_autotune_level() >= 2
-        ):
-            self._report_tensor_execution_order(state, batch)
-        if (
-            not self._overlap_ordered
-            and self._overlap_active()
-            and not self.algorithm.sharded_opt_state
-            and not self.autotune
-        ):
-            # one-time readiness re-bucketing (reverse execution order);
-            # skipped under autotune — its recommendation path owns bucket
-            # order there (span-driven, _report_tensor_execution_order) and
-            # a trainer-local re-split would discard the recommended
-            # boundaries — and for sharded-opt-state families, whose chunk
-            # states are keyed on bucket boundaries (rebucket would orphan
-            # them)
-            self._overlap_ordered = True
-            self._reorder_plan_for_overlap(state, batch)
-        if self._pending_state_migration is not None:
-            # queued layout migrations (autotune family switch crossing the
-            # optimizer-ownership boundary, flat-resident relayout after a
-            # rebucket) convert the live state before the recompiled step
-            # consumes it; the span feeds the ledger's state_migration class
-            with trace_span("step/state_migration"):
-                state = self._pending_state_migration(state)
-            self._pending_state_migration = None
-            obs.note_window_class("state_migration")
-        fn = self._get_step_fn()
-        mfu_harvest = None
-        if obs.enabled:
-            mfu_harvest = self._maybe_prepare_mfu(state, batch)
-            obs.note_static_footprint(self, state)
-        # poison accounting reads the persisted state.step BEFORE dispatch:
-        # the buffers are donated to fn, and the compiled fault fires on
-        # state.step (which resumes from checkpoints), not the
-        # trainer-local call counter
-        self._note_traced_fault_fires(state)
+        with trace_span("step/prepare"):
+            if self.algorithm.need_reset(self._step_counter - 1):
+                self._phase += 1
+                # reference re-runs init_tensors + rebucketing at phase
+                # switches (distributed.py:427-435); plan shape is identical
+                # here, phase key selects the recompiled step.
+            if (
+                self.autotune
+                and not self._autotune_completed
+                and self._step_counter % 100 == 0
+            ):
+                self._autotune_step(state)
+            if (
+                self.autotune
+                and not self._autotune_completed
+                and not self._telemetry_reported
+                and env.get_autotune_level() >= 2
+            ):
+                self._report_tensor_execution_order(state, batch)
+            if (
+                not self._overlap_ordered
+                and self._overlap_active()
+                and not self.algorithm.sharded_opt_state
+                and not self.autotune
+            ):
+                # one-time readiness re-bucketing (reverse execution order);
+                # not under autotune, whose recommendation path owns bucket
+                # order (span-driven, _report_tensor_execution_order: a local
+                # re-split would discard its boundaries), nor for
+                # sharded-opt-state families, whose chunk states are keyed on
+                # bucket boundaries (a rebucket would orphan them)
+                self._overlap_ordered = True
+                self._reorder_plan_for_overlap(state, batch)
+            if self._pending_state_migration is not None:
+                # queued layout migrations (a family switch across the
+                # optimizer-ownership boundary, a relayout after a rebucket)
+                # convert the live state before the recompiled step takes it;
+                # the span feeds the ledger's state_migration class
+                with trace_span("step/state_migration"):
+                    state = self._pending_state_migration(state)
+                self._pending_state_migration = None
+                obs.note_window_class("state_migration")
+            fn = self._get_step_fn()
+            mfu_harvest = None
+            if obs.enabled:
+                mfu_harvest = self._maybe_prepare_mfu(state, batch)
+                obs.note_static_footprint(self, state)
+            # poison accounting reads state.step BEFORE the dispatch donates
+            # the buffers: the compiled fault fires on it (it resumes from
+            # checkpoints), not on the trainer-local call counter
+            self._note_traced_fault_fires(state)
+        paused = obs.pause_mark()
         try:
             with trace_span("step/dispatch") as dispatch:
                 out = fn(state, batch)
@@ -1941,8 +1941,9 @@ class BaguaTrainer:
                 mfu_harvest.start()
         if dispatch is not None:
             # the anomaly detector's phase breakdown reads the span's own
-            # clock pair (obs off: no span, and no detector to feed)
-            obs.note_phase_duration("dispatch", dispatch.dur_s)
+            # clock pair (obs off: no span, and no detector to feed), less
+            # the interpreter's pauses that fell inside the call
+            obs.note_dispatch(dispatch.dur_s, paused)
         if self.grad_guard != "off":
             new_state, loss, health_vec = out
             self.step_metrics = {
@@ -1961,7 +1962,15 @@ class BaguaTrainer:
                     out[1], f"train_step[{self._step_counter}]"
                 )
         # the only consumer of the speed tracker is the autotune check-in
-        obs.end_step(batch, track_speed=not self._autotune_completed)
+        # (every 2 s besides: the device-memory poll and the health beacon).
+        # With step/hooks, step/prepare (everything between the hooks and
+        # the dispatch: the reset check, the autotune and migration branches,
+        # the step-cache key, the MFU preparation, the static footprint, the
+        # fault accounting), step/dispatch and step/watchdog_handoff this
+        # span tiles the root span.  The lines down to `out = fn(...)` keep
+        # their numbers: the kernels' bodies embed them (ROADMAP).
+        with trace_span("step/end"):
+            obs.end_step(batch, track_speed=not self._autotune_completed)
         return out
 
     # ---- gradient-health sentinel (host-side policy) ---------------------

@@ -14,9 +14,14 @@ step lands far outside it:
   (trigger ``step_anomaly`` — the spans around the slow step are exactly
   the post-mortem an operator wants),
 * publishes a ``straggler_suspect`` phase breakdown
-  (dispatch / collective / optimizer / other) into the per-rank obs
-  summary, which rides the health beacon → lease heartbeat → coordinator
-  fleet snapshot (the "which rank, which phase, since when" answer), and
+  (dispatch / collective / optimizer / gc / blocked / trainer / caller /
+  other) into the per-rank obs summary, which rides the health beacon →
+  lease heartbeat → coordinator fleet snapshot (the "which rank, which
+  phase, since when" answer),
+* keeps the window as a rare span ``step/stall`` of the ring (attrs: the
+  phases' seconds, ``explained_s``, the detector's median at the time and —
+  where the window outlasted half a second and ``obs/pauses.py``'s
+  heartbeat could run — what every thread was doing while it lasted), and
 * feeds a bounded **perf hint** queue the autotune service consumes
   (``AutotuneClient.report_metrics(perf_hints=...)``) — the scorer's cue
   that measured step time moved for environmental reasons, not because the
@@ -28,8 +33,19 @@ device time, so a rank whose OWN device/host is slow shows a
 dispatch-dominant excess; ``collective`` is host-visible synchronization
 wait (async negotiate/catch-up boundaries, and gated straggler stalls —
 the wait a slow PEER inflicts); ``optimizer`` is the grad-guard verdict
-readback and other host-side optimizer-adjacent work; the residual is
-``other``.  Coordinator side, :func:`fleet_straggler_suspects` applies the
+readback and other host-side optimizer-adjacent work.  The step observer
+adds what the spans know: ``gc`` and ``blocked`` are the interpreter's
+pauses (``obs/pauses.py``: the collector's seconds, and the seconds no
+Python thread could run outside collections), ``trainer`` is the root span
+``step/train_step`` less everything above (hooks, the step-cache key, the
+beacon), ``caller`` is the window less the root span — the user's loop, the
+input pipeline, back-pressure — each net of the pauses that fell inside it,
+so that the phases never add up to more than the window.  The residual,
+``other``, is then what truly nothing explains.  ``explained_s`` sums the
+excess of every phase but ``caller`` and ``other``: a stall with
+``explained_s`` near 0 was spent waiting in the caller with nothing of ours
+running, which is itself the finding.  Coordinator side,
+:func:`fleet_straggler_suspects` applies the
 same logic across ranks: dispatch-dominant anomalies name the straggler,
 collective-dominant ones its victims.
 
@@ -57,8 +73,16 @@ __all__ = [
 ]
 
 #: the attributed phases of one host step window; anything unattributed
-#: lands in "other"
-PHASES = ("dispatch", "collective", "optimizer")
+#: lands in "other".  In the order in which the observer gives each its
+#: seconds when the clocks disagree: the pauses were timed whole, the noted
+#: waits by a clock pair of their own, ``trainer`` and ``caller`` by
+#: subtraction
+PHASES = ("gc", "blocked", "dispatch", "collective", "optimizer", "trainer",
+          "caller")
+_KNOWN = frozenset(PHASES) | {"_other"}
+#: the phases in which something of the program's, or the interpreter
+#: itself, was at work: ``explained_s`` sums their excess
+EXPLAINING_PHASES = PHASES[:-1]
 
 #: 1.4826 * MAD estimates the standard deviation for Gaussian data — the
 #: usual robust-z scaling
@@ -72,12 +96,19 @@ _MAD_SIGMA = 1.4826
 HINT_MIN_RATIO = 3.0
 
 
+def _median_of_sorted(xs: list) -> float:
+    mid = len(xs) // 2
+    return xs[mid] if len(xs) % 2 else (xs[mid - 1] + xs[mid]) / 2
+
+
 class StepAnomalyDetector:
     """Rolling median/MAD anomaly detector over raw step time.
 
-    ``observe(step, raw_dt, phases)`` once per step (host side, after the
-    cadence sample).  Returns the ``straggler_suspect`` dict when the step
-    is anomalous, else None.  A step is anomalous when, against the
+    ``observe(step, raw_dt, phases, sample)`` once per step (host side,
+    after the cadence sample; ``sample``: what ``obs/pauses.py``'s heartbeat
+    recorded of the threads while the window lasted, if it stalled).
+    Returns the ``straggler_suspect`` dict when the step is anomalous, else
+    None.  A step is anomalous when, against the
     rolling window of PRIOR samples (after ``warmup`` of them exist)::
 
         raw_dt > median + threshold * 1.4826 * MAD
@@ -109,7 +140,11 @@ class StepAnomalyDetector:
         self.dump_min_interval_s = float(dump_min_interval_s)
         self.rank = int(_env.get_rank()) if rank is None else int(rank)
         self._dts: deque = deque(maxlen=self.window)
-        self._phase_dts: Dict[str, deque] = {}
+        #: (median, MAD) of ``_dts`` once ``warmup`` samples exist: the
+        #: yardstick of the NEXT window, computed when a sample enters
+        self._baseline: Optional[tuple] = None
+        self._phase_dts: Dict[str, deque] = {
+            name: deque(maxlen=self.window) for name in PHASES + ("_other",)}
         self._last_dump_mono: Optional[float] = None
         #: bounded history of flagged suspects (newest last) — drills and
         #: operators read it; the beacon carries only the latest
@@ -117,33 +152,53 @@ class StepAnomalyDetector:
 
     # -- core -------------------------------------------------------------
 
+    def cut_s(self) -> Optional[float]:
+        """Seconds past which the window now open is anomalous (both
+        conditions of the class docstring); None during warm-up.  The
+        heartbeat of ``obs/pauses.py`` samples a window that outlasts it."""
+        if self._baseline is None:
+            return None
+        med, mad = self._baseline
+        return max(med + self.threshold * _MAD_SIGMA * mad,
+                   self.min_ratio * med)
+
     def observe(self, step: int, raw_dt: Optional[float],
-                phases: Optional[Dict[str, float]] = None
-                ) -> Optional[dict]:
+                phases: Optional[Dict[str, float]] = None,
+                sample: Optional[dict] = None) -> Optional[dict]:
         if raw_dt is None or raw_dt <= 0:
             return None
         phases = {k: float(v) for k, v in (phases or {}).items() if v > 0}
         other = max(0.0, raw_dt - sum(phases.values()))
         suspect = None
-        if len(self._dts) >= self.warmup:
-            base = sorted(self._dts)
-            med = median(base)
-            mad = median(abs(x - med) for x in base)
-            cut = med + self.threshold * _MAD_SIGMA * mad
-            if raw_dt > cut and raw_dt > self.min_ratio * med and med > 0:
-                suspect = self._flag(step, raw_dt, med, mad, phases, other)
+        if self._baseline is not None:
+            med, mad = self._baseline
+            if raw_dt > self.cut_s() and med > 0:
+                suspect = self._flag(step, raw_dt, med, mad, phases, other,
+                                     sample)
         self._dts.append(raw_dt)
+        if len(self._dts) >= self.warmup:
+            # (this runs in every begin_step, inside the root span's self
+            # time: two sorts of at most ``window`` floats, no generators)
+            base = sorted(self._dts)
+            med = _median_of_sorted(base)
+            self._baseline = (med, _median_of_sorted(
+                sorted([abs(x - med) for x in base])))
         # EVERY known phase gets a sample each step — a phase absent this
         # window contributed 0 s.  Without the zeros, a phase only seen
         # during anomalies (a straggler's collective wait) would have an
         # anomaly-sized baseline by its second occurrence and dominance
         # attribution would flip to whatever phase was still uncontaminated
-        for name in set(PHASES) | set(phases):
-            self._phase_dts.setdefault(
-                name, deque(maxlen=self.window)).append(
-                    phases.get(name, 0.0))
-        self._phase_dts.setdefault(
-            "_other", deque(maxlen=self.window)).append(other)
+        history = self._phase_dts
+        for name in PHASES:
+            history[name].append(phases.get(name, 0.0))
+        history["_other"].append(other)
+        if len(history) > len(PHASES) + 1 or not _KNOWN.issuperset(phases):
+            # a phase an algorithm names itself: rare, so off the fast path
+            for name in set(history).union(phases) - _KNOWN:
+                if name not in history:
+                    history[name] = deque([0.0] * (len(self._dts) - 1),
+                                          maxlen=self.window)
+                history[name].append(phases.get(name, 0.0))
         return suspect
 
     def _phase_baseline(self, name: str) -> float:
@@ -151,7 +206,8 @@ class StepAnomalyDetector:
         return median(hist) if hist else 0.0
 
     def _flag(self, step: int, raw_dt: float, med: float, mad: float,
-              phases: Dict[str, float], other: float) -> dict:
+              phases: Dict[str, float], other: float,
+              sample: Optional[dict]) -> dict:
         # phase breakdown of the EXCESS: each attributed phase's duration
         # minus its own rolling median (of PRIOR windows — this window's
         # samples enter the history only after flagging); the residual
@@ -174,15 +230,28 @@ class StepAnomalyDetector:
             "ratio": round(raw_dt / med, 3) if med else None,
             "dominant_phase": dominant,
             "phases": breakdown,
+            "explained_s": round(sum(
+                max(0.0, excess[name]) for name in EXPLAINING_PHASES), 6),
             "detected_at_unix": time.time(),
         }
+        if sample is not None:
+            # what every thread was doing while the window lasted
+            suspect["sampled_after_s"] = sample["sampled_after_s"]
+            suspect["stacks"] = sample["stacks"]
+            suspect["open_spans"] = sample["open_spans"]
         self.suspects.append(suspect)
         counters.incr("obs/step_anomalies")
         logger.warning(
             "step anomaly: rank %d step %d took %.4fs (baseline p50 "
-            "%.4fs, x%.1f) — dominant phase %r",
+            "%.4fs, x%.1f) — dominant phase %r (%s; explained %.3fs%s)",
             self.rank, step, raw_dt, med, suspect["ratio"] or 0.0, dominant,
+            ", ".join(f"{name} {seconds:.3f}s"
+                      for name, seconds in breakdown.items()
+                      if seconds >= 0.0005) or "no phase over 0.5 ms",
+            suspect["explained_s"],
+            "; threads sampled" if sample is not None else "",
         )
+        self._record_stall(suspect)
         # the fleet-view half: the latest suspect rides the obs summary
         # (beacon -> heartbeat -> coordinator snapshot)
         from . import export as _export
@@ -199,6 +268,25 @@ class StepAnomalyDetector:
             })
         self._maybe_dump(suspect)
         return suspect
+
+    @staticmethod
+    def _record_stall(suspect: dict) -> None:
+        """The flagged window as a rare span of the ring, ``step/stall``:
+        the record a later reader finds when the log is gone.  The window
+        closed a few microseconds before this clock read (the observer's
+        own, then the ledger's bookkeeping): ``t1`` is late by that much,
+        ``dur_s`` is the window's own."""
+        from . import spans as _spans
+
+        if not _spans.enabled():
+            return
+        t1 = time.monotonic()
+        attrs = {k: suspect[k] for k in (
+            "phases", "dominant_phase", "explained_s", "baseline_p50",
+            "sampled_after_s", "stacks", "open_spans") if k in suspect}
+        _spans.recorder.record_rare(_spans.finished_span(
+            "step/stall", t1 - suspect["step_dt"], t1, step=suspect["step"],
+            **attrs))
 
     def _maybe_dump(self, suspect: dict) -> None:
         """Throttled flight-recorder dump of the offending window: the ring

@@ -346,6 +346,19 @@ _declare("obs/step_anomalies", "counter",
 _declare("obs/perf_hints", "counter",
          "Perf hints published for the autotune service (anomaly "
          "detections and other environmental performance signals).")
+# -- the interpreter's pauses (obs/pauses.py, docs/observability.md) --
+_declare("host/gc_collections", "counter",
+         "Collections of Python's cyclic collector since the obs plane "
+         "hooked gc.callbacks (every generation).")
+_declare("host/gc_pause_s", "counter",
+         "Seconds those collections took, on whichever thread they ran: "
+         "the interpreter runs nothing else meanwhile.  One of generation "
+         "2, or any of 1 ms or more, is also a `host/gc` span.")
+_declare("host/blocked_s", "counter",
+         "Seconds by which the bagua-obs-heartbeat thread woke late "
+         "(50 ms or more at a time), outside collections: no Python "
+         "thread could run — a C call kept the interpreter lock, or the "
+         "process did not run.  Each is a `host/blocked` span.")
 # -- efficiency plane: goodput ledger + MFU + HBM accounting --
 for _cls in LEDGER_CLASSES:
     _declare(f"obs/ledger/{_cls}_s", "gauge",

@@ -22,7 +22,10 @@ Design constraints, in order:
   runtime.
 * **Bounded.**  Spans land in a ring buffer (``BAGUA_OBS_RING``, default
   512); the oldest drop and the drop count is kept, so a long run can crash
-  at step 10^6 and still leave a readable tail.
+  at step 10^6 and still leave a readable tail.  The few spans that happen
+  seldom and explain much (:data:`RARE_SPANS`: the interpreter's pauses of
+  ``obs/pauses.py``, a step's build, a stall record) are kept in a second
+  deque of 128 that the steps' chatter cannot push out.
 * **Import-light.**  No jax import: the launcher and the watchdog waiter
   thread open spans too.
 * **On the profiler's clock when there is one.**  A span mirrors itself to
@@ -39,6 +42,7 @@ flag read) — the default-compatible mode.
 from __future__ import annotations
 
 import contextlib
+import operator
 import sys
 import threading
 import time
@@ -52,12 +56,21 @@ __all__ = ["trace_span", "trace_step_span", "phase_scope", "area_of",
            "POS_EMBED_SCOPE", "EXIT_SCOPE", "LOOP_SCOPE",
            "DIFFUSION_INPUT_SCOPE",
            "recorder", "span_ring", "SpanRecorder", "enabled", "set_enabled",
-           "set_current_step", "set_ledger_sink"]
+           "set_current_step", "set_ledger_sink", "RARE_SPANS",
+           "RARE_CAPACITY", "finished_span"]
 
 #: prefix of every span's mirror on the profiler's host plane
 ANNOTATION_PREFIX = "bagua/"
 #: name of the per-step ``StepTraceAnnotation`` the root span opens
 STEP_ANNOTATION = "bagua_train"
+
+#: spans that happen seldom and explain much: the interpreter's pauses, a
+#: step's build, a stall record.  A steady step opens 7 spans into a ring of
+#: 512, so a pause at step 30 would be gone by step 110; these names are kept
+#: in a second bounded deque beside the ring (:class:`SpanRecorder`)
+RARE_SPANS = frozenset({"host/gc", "host/blocked", "step/build", "step/stall"})
+#: how many rare spans are kept (oldest drop)
+RARE_CAPACITY = 128
 
 #: resolved master switch; None = not yet read from BAGUA_OBS
 _ENABLED: Optional[bool] = None
@@ -127,7 +140,14 @@ class SpanRecorder:
     One per process (:data:`recorder`), like the telemetry counters; the
     flight recorder snapshots it on failure, the exporter may sample it.
     Capacity comes from ``BAGUA_OBS_RING`` lazily (the module imports
-    before test harnesses set their env)."""
+    before test harnesses set their env).
+
+    Spans named in :data:`RARE_SPANS` go to a second deque of
+    :data:`RARE_CAPACITY` that the steps' chatter cannot push out;
+    :meth:`snapshot` returns both, merged by ``t0``.  That deque is written
+    and read WITHOUT the lock (:meth:`record_rare`): the collector's
+    callback records into it, and a collection can start at any bytecode
+    boundary of a thread that already holds the lock."""
 
     def __init__(self, capacity: Optional[int] = None):
         self._lock = threading.Lock()
@@ -135,6 +155,7 @@ class SpanRecorder:
         self._spans: Optional[deque] = (
             deque(maxlen=capacity) if capacity else None
         )
+        self._rare: deque = deque(maxlen=RARE_CAPACITY)
         self._dropped = 0
         self._local = threading.local()
         #: spans currently OPEN (entered, not yet exited), keyed by the
@@ -182,15 +203,29 @@ class SpanRecorder:
         acquisition for both."""
         with self._lock:
             self._open.pop(key, None)
+            if span["name"] in RARE_SPANS:
+                self._rare.append(span)
+                return
             buf = self._buf()
             if len(buf) == buf.maxlen:
                 self._dropped += 1
             buf.append(span)
 
+    def record_rare(self, span: Dict[str, Any]) -> None:
+        """Keep a finished span (:func:`finished_span`) that was timed by
+        its recorder and not by a ``with`` block: a pause, a stall record.
+        Takes no lock (a deque's append is atomic), so the collector's
+        callback may call it."""
+        self._rare.append(span)
+
     def snapshot(self) -> List[Dict[str, Any]]:
-        """Copies of every retained (finished) span, oldest first."""
+        """Copies of every retained (finished) span, the ring's and the
+        rare ones, by start time."""
         with self._lock:
-            return [dict(s) for s in self._buf()]
+            # list() of a deque is one call: no callback appends under it
+            kept = list(self._buf()) + list(self._rare)
+        kept.sort(key=operator.itemgetter("t0"))
+        return [dict(s) for s in kept]
 
     def active_snapshot(self) -> List[Dict[str, Any]]:
         """Copies of spans currently in flight (entered, not exited),
@@ -208,8 +243,26 @@ class SpanRecorder:
         with self._lock:
             if self._spans is not None:
                 self._spans.clear()
+            self._rare.clear()
             self._open.clear()
             self._dropped = 0
+
+
+def finished_span(name: str, t0: float, t1: float,
+                  step: Optional[int] = None, **attrs) -> Dict[str, Any]:
+    """A span of the ring's shape from a clock pair its recorder took
+    itself (``time.monotonic()``, the ring's clock): top level, on the
+    calling thread, at the current step unless ``step`` is given."""
+    span = {
+        "name": name, "t0": t0, "t1": t1, "dur_s": t1 - t0,
+        "rank": _cached_rank(),
+        "step": _CURRENT_STEP if step is None else step,
+        "depth": 0, "parent": None,
+        "thread": threading.current_thread().name,
+    }
+    if attrs:
+        span["attrs"] = attrs
+    return span
 
 
 #: process-wide span ring (one per process, like ``telemetry.counters``);
@@ -219,11 +272,13 @@ recorder = SpanRecorder()
 span_ring = recorder
 
 
-def _open_annotations(name: str, step_num: Optional[int]) -> tuple:
+def _open_annotations(name: str, step_num: Optional[int],
+                      **metadata) -> tuple:
     """The span's mirror on the profiler's clock, entered: a
     ``TraceAnnotation("bagua/<name>")`` (inside a ``StepTraceAnnotation``
-    for the root span of a train step), innermost last.  Empty in a
-    process that has not imported jax — this module never does."""
+    for the root span of a train step), innermost last; ``metadata`` rides
+    the annotation.  Empty in a process that has not imported jax — this
+    module never does."""
     jax = sys.modules.get("jax")
     profiler = getattr(jax, "profiler", None)  # None while jax is importing
     if profiler is None:
@@ -236,7 +291,8 @@ def _open_annotations(name: str, step_num: Optional[int]) -> tuple:
                                             step_num=step_num)
         step.__enter__()
         opened = (step,)
-    annotation = profiler.TraceAnnotation(ANNOTATION_PREFIX + name)
+    annotation = profiler.TraceAnnotation(ANNOTATION_PREFIX + name,
+                                          **metadata)
     annotation.__enter__()
     return (annotation,) + opened
 

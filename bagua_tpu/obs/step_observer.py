@@ -20,7 +20,8 @@ begins (:meth:`begin_step` — which closes the previous step's wall window),
 the window now open holds a compile or a state migration
 (:meth:`note_window_class`), a stall was injected or reported
 (:meth:`note_injected_stall`), host seconds belong to a phase
-(:meth:`note_phase_duration`), the step ends (:meth:`end_step`).
+(:meth:`note_phase_duration`, :meth:`note_dispatch`), the step ends
+(:meth:`end_step`).
 
 With the plane off (``BAGUA_OBS=off``) this is the same class with its
 readers absent: the cadence is measured always —
@@ -45,6 +46,7 @@ from . import anomaly as _anomaly
 from . import export as _export
 from . import http as _http
 from . import memory as _memory
+from . import pauses as _pauses
 from . import recorder as _recorder
 from . import spans as _spans
 
@@ -87,6 +89,9 @@ class StepObserver:
             # exporter writes to metrics.prom
             _http.maybe_start_global_http_server()
             _recorder.maybe_install_signal_hook()
+            # the interpreter's pauses as spans of the ring (the collector's
+            # hook here, the heartbeat thread with the first begin_step)
+            _pauses.install()
             self.ledger = _ledger.install()
             self.peak_flops = _ledger.peak_flops_for_device_kind(
                 jax.devices()[0].device_kind
@@ -103,6 +108,16 @@ class StepObserver:
         #: (dispatch / collective / optimizer); harvested into the anomaly
         #: detector when the next cadence sample closes the window
         self._phase_durations: Dict[str, float] = {}
+        #: what the detector's phases ``gc`` / ``blocked`` / ``trainer`` /
+        #: ``caller`` are differenced from: the pauses' cumulative seconds
+        #: (``obs/pauses.py``) when the open window began, the seconds from
+        #: there to :meth:`end_step`'s last line — the root span
+        #: ``step/train_step`` of the step that opened it, but for
+        #: ``check_abort`` before and two span exits after — and the paused
+        #: seconds that fell inside them
+        self._gc_mark = self._blocked_mark = 0.0
+        self._root_s: Optional[float] = None
+        self._root_paused_s = 0.0
         #: THE window-class fact: the wall window the current step opened
         #: holds a trace+compile (``"compile"``) or a state migration
         #: (``"state_migration"``); None = productive.  Such a window is
@@ -145,7 +160,9 @@ class StepObserver:
             # every span opened while this step is driven (including the
             # watchdog waiter's) carries the step number
             _spans.set_current_step(step)
+            _pauses.ensure_heartbeat()
         now = time.monotonic()
+        gc_s, blocked_s = _pauses.gc_seconds(), _pauses.blocked_seconds()
         if self._last_step_mono is not None:
             raw = now - self._last_step_mono
             dt = raw - self._stall_s
@@ -174,13 +191,51 @@ class StepObserver:
                 # baseline.
                 phases, self._phase_durations = self._phase_durations, {}
                 if productive:
-                    self.anomaly_detector.observe(step - 1, raw, phases)
+                    self._add_span_phases(phases, raw, gc_s, blocked_s)
+                    self.anomaly_detector.observe(
+                        step - 1, raw, phases,
+                        _pauses.take_stall_sample(self._last_step_mono))
+        if self.anomaly_detector is not None:
+            # one reading closes a window and opens the next: no pause
+            # falls between two
+            self._gc_mark, self._blocked_mark = gc_s, blocked_s
+            self._root_s = None
+            # the heartbeat samples every thread once if this window stalls
+            _pauses.watch_window(now, self.anomaly_detector.cut_s())
         self._last_step_mono = now
         self._stall_s = 0.0
         if self.enabled:
             # fleet view: the per-rank step/step-dt summary the health
             # beacon (and the metrics exporter) publish
             _export.note_step(step, self._step_dt)
+
+    def _add_span_phases(self, phases: Dict[str, float], raw: float,
+                         gc_now: float, blocked_now: float) -> None:
+        """What the spans know of the closing window, beside the phases
+        noted while it lasted: the interpreter's pauses (``obs/pauses.py``'s
+        two cumulative floats as read now, differenced: no scan of the
+        ring), the trainer's own host time and the caller's — each net of
+        the pauses inside it, so that the phases add up to the window and
+        not to more."""
+        gc_s = min(raw, gc_now - self._gc_mark)
+        blocked_s = min(raw - gc_s, blocked_now - self._blocked_mark)
+        phases["gc"], phases["blocked"] = gc_s, blocked_s
+        if self._root_s is None:
+            return  # the step never ended: ``other`` keeps the rest
+        root_paused = min(self._root_paused_s, gc_s + blocked_s)
+        phases["trainer"] = max(
+            0.0, self._root_s - root_paused - sum(
+                phases.get(name, 0.0)
+                for name in ("dispatch", "collective", "optimizer")))
+        phases["caller"] = max(
+            0.0, raw - self._root_s - (gc_s + blocked_s - root_paused))
+        # a heartbeat that woke after the window it was late in had closed
+        # brings its seconds to this one: whatever the clocks disagree on,
+        # the pauses first and then each phase only what is left
+        left = raw
+        for name in _anomaly.PHASES:
+            phases[name] = min(phases.get(name, 0.0), left)
+            left -= phases[name]
 
     def note_window_class(self, cls: str) -> None:
         """The window the current step opened holds a ``compile`` or a
@@ -212,12 +267,30 @@ class StepObserver:
         """Attribute host seconds of the current step to a phase
         (``dispatch`` / ``collective`` / ``optimizer``) for the anomaly
         detector's ``straggler_suspect`` breakdown.  Algorithms call this
-        around their host-visible waits (async negotiate/catch-up)."""
+        around their host-visible waits (async negotiate/catch-up), inside
+        ``train_step``: ``trainer`` is the root span less these."""
         if self.anomaly_detector is None or seconds <= 0:
             return
         self._phase_durations[phase] = (
             self._phase_durations.get(phase, 0.0) + float(seconds)
         )
+
+    def pause_mark(self) -> float:
+        """Seconds the interpreter has been paused so far, to hand back to
+        :meth:`note_dispatch` (0.0 where no detector reads the phases)."""
+        if self.anomaly_detector is None:
+            return 0.0
+        return _pauses.paused_seconds()
+
+    def note_dispatch(self, seconds: float, mark: float) -> None:
+        """The compiled step's dispatch call took ``seconds`` (the span
+        ``step/dispatch``'s own clock pair); ``mark`` is :meth:`pause_mark`
+        from just before it.  A collection inside the call is the
+        collector's, not the dispatch's."""
+        if self.anomaly_detector is None:
+            return
+        paused = min(seconds, _pauses.paused_seconds() - mark)
+        self.note_phase_duration("dispatch", seconds - paused)
 
     def end_step(self, batch, track_speed: bool) -> None:
         """The step was dispatched.  ``track_speed``: someone will read the
@@ -235,6 +308,13 @@ class StepObserver:
                 self._last_beacon_write = now
                 self._maybe_poll_device_memory()
                 self.publish_health()
+        if self.anomaly_detector is not None \
+                and self._last_step_mono is not None:
+            # the trainer's part of the window ends here; what follows, up
+            # to the next begin_step, is the caller's
+            self._root_s = time.monotonic() - self._last_step_mono
+            self._root_paused_s = (_pauses.paused_seconds() - self._gc_mark
+                                   - self._blocked_mark)
 
     # ---- efficiency plane --------------------------------------------------
 

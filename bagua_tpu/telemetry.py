@@ -68,6 +68,19 @@ class TelemetryCounters:
             self._values[name] = self._values.get(name, 0) + n
             return self._values[name]
 
+    def add_nowait(self, name: str, n: Union[int, float]) -> bool:
+        """:meth:`incr` that never waits; False where the lock was taken
+        and nothing was added.  For the collector's callback
+        (``obs/pauses.py``): a collection starts at any bytecode boundary,
+        also on a thread that is inside one of the methods here."""
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
+            self._values[name] = self._values.get(name, 0) + n
+        finally:
+            self._lock.release()
+        return True
+
     def incr_many(self, updates: Dict[str, Union[int, float]]) -> None:
         """Batch increment under ONE lock acquisition — for writer loops
         (fault-plan arming, exporter self-accounting) that would otherwise

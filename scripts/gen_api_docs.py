@@ -51,6 +51,7 @@ MODULES = [
     "bagua_tpu.obs.historian",
     "bagua_tpu.obs.http",
     "bagua_tpu.obs.step_observer",
+    "bagua_tpu.obs.pauses",
     "bagua_tpu.autopilot.policy",
     "bagua_tpu.autopilot.engine",
     "bagua_tpu.podsim.util",

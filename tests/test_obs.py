@@ -3,10 +3,12 @@ metrics exporter, fleet view — plus the guards that tracing is free: the
 compiled step program is identical with obs on/off (jaxpr pin) and span
 overhead stays under 2% of the measured step time."""
 
+import gc
 import glob
 import json
 import os
 import re
+import threading
 import time
 
 import jax
@@ -21,6 +23,7 @@ from bagua_tpu.algorithms import GradientAllReduceAlgorithm
 from bagua_tpu.core.backend import BaguaTrainer
 from bagua_tpu.faults.inject import FaultSpec, fault_scope
 from bagua_tpu.obs import export as obs_export
+from bagua_tpu.obs import pauses as obs_pauses
 from bagua_tpu.obs import recorder as obs_recorder
 from bagua_tpu.obs import spans as obs_spans
 from bagua_tpu.parallel.mesh import build_mesh
@@ -101,6 +104,231 @@ def test_span_error_annotated(obs_on):
             raise ValueError("x")
     (span,) = obs_spans.recorder.snapshot()
     assert span["error"] == "ValueError"
+
+
+def test_a_rare_span_outlives_the_chatter(obs_on):
+    """A steady step opens 7 spans into a ring of 512: a pause at step 30
+    would be gone by step 110.  The rare names are kept beside the ring,
+    and the snapshot is one list by start time."""
+    with obs_spans.trace_span("step/build", phase=0):
+        pass
+    t0 = time.monotonic()
+    obs_spans.recorder.record_rare(
+        obs_spans.finished_span("host/gc", t0, t0 + 0.25, generation=2))
+    for i in range(2000):
+        with obs_spans.trace_span("step/dispatch", step=i):
+            pass
+    obs_spans.recorder.record_rare(
+        obs_spans.finished_span("host/blocked", t0 - 5.0, t0 - 4.8))
+    spans = obs_spans.recorder.snapshot()
+    names = [s["name"] for s in spans]
+    assert names.count("step/dispatch") == 512
+    assert obs_spans.recorder.dropped == 2000 - 512
+    assert {"step/build", "host/gc", "host/blocked"} <= set(names)
+    starts = [s["t0"] for s in spans]
+    assert starts == sorted(starts)
+    # recorded last, started first: merged by start, not by arrival
+    assert names[:3] == ["host/blocked", "step/build", "host/gc"]
+    gc_span = spans[names.index("host/gc")]
+    assert gc_span["attrs"] == {"generation": 2}
+    assert gc_span["dur_s"] == pytest.approx(0.25)
+    assert gc_span["thread"] == threading.current_thread().name
+
+
+def test_the_rare_deque_is_bounded_too(obs_on):
+    t0 = time.monotonic()
+    for i in range(obs_spans.RARE_CAPACITY + 40):
+        obs_spans.recorder.record_rare(
+            obs_spans.finished_span("host/gc", t0 + i, t0 + i + 0.5, step=i))
+    kept = obs_spans.recorder.snapshot()
+    assert len(kept) == obs_spans.RARE_CAPACITY
+    assert kept[0]["step"] == 40 and kept[-1]["step"] == (
+        obs_spans.RARE_CAPACITY + 39)
+    obs_spans.recorder.clear()
+    assert obs_spans.recorder.snapshot() == []
+
+
+# ---- the interpreter's pauses (obs/pauses.py) --------------------------------
+
+
+@pytest.fixture()
+def pauses_on(obs_on):
+    """The collector's hook and the heartbeat installed as the step
+    observer installs them, on a clean ring; both taken out afterwards."""
+    obs_pauses.uninstall()
+    obs_pauses.install()
+    obs_pauses.ensure_heartbeat()
+    yield obs_pauses
+    obs_pauses.uninstall()
+
+
+def _rare(name):
+    return [s for s in obs_spans.recorder.snapshot() if s["name"] == name]
+
+
+def _hold_the_interpreter(at_least_s):
+    """One C call that keeps the interpreter lock for ``at_least_s`` or
+    more on this box: ``sum(range(n))``, sized from a short probe.
+    Returns the call's (t0, t1, CPU seconds)."""
+    probe = time.perf_counter()
+    sum(range(1_000_000))
+    per_item = (time.perf_counter() - probe) / 1_000_000
+    n = int(1.5 * at_least_s / per_item)
+    cpu0, t0 = time.process_time(), time.monotonic()
+    sum(range(n))
+    return t0, time.monotonic(), time.process_time() - cpu0
+
+
+def test_a_forced_collection_is_a_span_and_two_counters(pauses_on):
+    before = telemetry.counters.snapshot()
+    total = obs_pauses.gc_seconds()
+    t0 = time.monotonic()
+    gc.collect()
+    t1 = time.monotonic()
+    full = [s for s in _rare("host/gc") if s["attrs"]["generation"] == 2
+            and t0 <= s["t0"] and s["t1"] <= t1]
+    assert len(full) == 1, _rare("host/gc")
+    (span,) = full
+    assert span["thread"] == threading.current_thread().name
+    assert span["attrs"]["collected"] >= 0
+    assert span["depth"] == 0 and span["parent"] is None
+    after = telemetry.counters.snapshot()
+    assert after["host/gc_collections"] >= before.get(
+        "host/gc_collections", 0) + 1
+    moved = after["host/gc_pause_s"] - before.get("host/gc_pause_s", 0.0)
+    assert moved >= span["dur_s"] > 0
+    # the cumulative seconds the step observer differences moved alike
+    assert obs_pauses.gc_seconds() - total >= span["dur_s"]
+
+
+def test_a_young_collection_counts_and_is_no_span(pauses_on):
+    """Every collection moves the counters; only a full one, or one of a
+    millisecond, is a span (about ten young ones a second would be the
+    chatter the rare deque is there to outlive)."""
+    gc.collect()  # nothing pending afterwards: the next one is cheap
+    obs_spans.recorder.clear()
+    before = telemetry.counters.get("host/gc_collections")
+    gc.collect(0)
+    assert telemetry.counters.get("host/gc_collections") == before + 1
+    assert all(s["dur_s"] >= obs_pauses.GC_SPAN_MIN_S
+               for s in _rare("host/gc"))
+    assert not [s for s in _rare("host/gc")
+                if s["attrs"]["generation"] == 2]
+
+
+def test_the_callback_never_waits_for_the_counters_lock(pauses_on):
+    """A collection starts at any bytecode boundary, also inside a method
+    of the counters that holds their lock: the callback adds what it can
+    and carries the rest to the next collection."""
+    gc.collect()
+    before = telemetry.counters.get("host/gc_collections")
+    with telemetry.counters._lock:
+        gc.collect()            # would deadlock if the callback waited
+        gc.collect()
+    assert telemetry.counters.get("host/gc_collections") == before
+    gc.collect()
+    assert telemetry.counters.get("host/gc_collections") == before + 3
+    assert telemetry.counters.add_nowait("host/gc_collections", 0) is True
+
+
+def test_a_held_interpreter_is_a_blocked_span(pauses_on):
+    """A C call that keeps the interpreter lock makes the heartbeat late:
+    a ``host/blocked`` span over the call, whose CPU seconds say that the
+    process itself was computing."""
+    time.sleep(0.1)  # a few beats on time first
+    before = obs_pauses.blocked_seconds()
+    t0, t1, cpu_held = _hold_the_interpreter(0.3)
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        over = [s for s in _rare("host/blocked")
+                if s["t0"] < t1 and s["t1"] > t0]
+        if over:
+            break
+        time.sleep(0.02)
+    assert over, _rare("host/blocked")
+    span = max(over, key=lambda s: s["dur_s"])
+    assert span["thread"] == obs_pauses.HEARTBEAT_THREAD
+    assert span["dur_s"] >= obs_pauses.BLOCKED_MIN_S
+    attrs = span["attrs"]
+    assert set(attrs) == {"cpu_s", "gc_s", "involuntary_switches",
+                          "major_faults"}
+    # of the same order as what the call itself burnt: we held the lock
+    # ourselves (a descheduled process would read about nothing)
+    assert attrs["cpu_s"] >= 0.5 * cpu_held > 0
+    assert obs_pauses.blocked_seconds() > before
+    assert telemetry.counters.get("host/blocked_s") > 0
+
+
+def test_a_collection_under_the_heartbeat_counts_once_as_gc(pauses_on):
+    """A full collection holds the interpreter lock, so the heartbeat is
+    late under it: the seconds both saw are the collector's."""
+    def slow_collector(phase, info):
+        # part of the collection as our hook times it (jax's own hook,
+        # ``_xla_gc_callback``, sits at the same place)
+        if phase == "stop" and info["generation"] == 2:
+            _hold_the_interpreter(0.3)
+
+    time.sleep(0.1)
+    gc.collect()
+    gc.callbacks.insert(0, slow_collector)
+    try:
+        t0 = time.monotonic()
+        gc.collect()
+        t1 = time.monotonic()
+    finally:
+        gc.callbacks.remove(slow_collector)
+    (full,) = [s for s in _rare("host/gc") if t0 <= s["t0"] and s["t1"] <= t1
+               and s["attrs"]["generation"] == 2]
+    deadline = time.monotonic() + 30
+    while time.monotonic() < deadline:
+        over = [s for s in _rare("host/blocked")
+                if s["t0"] < full["t1"] and s["t1"] > full["t0"]]
+        if over:
+            break
+        time.sleep(0.02)
+    assert over, _rare("host/blocked")
+    span = max(over, key=lambda s: s["dur_s"])
+    shared = min(span["t1"], full["t1"]) - max(span["t0"], full["t0"])
+    assert span["attrs"]["gc_s"] >= 0.5 * shared > 0
+
+
+@pytest.mark.parametrize("plane", ["off", "on"])
+def test_the_pauses_ride_the_master_switch(plane):
+    """``BAGUA_OBS=off``: ``gc.callbacks`` is as it was, no heartbeat
+    thread lives and a span is the shared null context; ``on``: whatever
+    installs the plane (the step observer) installs both."""
+    def heartbeats():
+        return [t for t in threading.enumerate()
+                if t.name == obs_pauses.HEARTBEAT_THREAD]
+
+    obs_pauses.uninstall()
+    callbacks = list(gc.callbacks)
+    assert heartbeats() == []
+    obs_spans.set_enabled(plane == "on")
+    try:
+        t, s, b = _golden_trainer()
+        for _ in range(3):
+            s, loss = t.train_step(s, b)
+            float(loss)
+        if plane == "off":
+            assert gc.callbacks == callbacks
+            assert heartbeats() == []
+            assert obs_spans.trace_span("step/prepare") is obs_spans._NULL
+            assert obs_spans.trace_step_span(4) is obs_spans._NULL
+            assert t._observer.pause_mark() == 0.0
+        else:
+            assert gc.callbacks == callbacks + [obs_pauses._on_gc]
+            (beat,) = heartbeats()
+            assert beat.daemon and beat.is_alive()
+            t2, s2, b2 = _golden_trainer()     # a second trainer: still one
+            t2.train_step(s2, b2)
+            assert gc.callbacks.count(obs_pauses._on_gc) == 1
+            assert heartbeats() == [beat]
+    finally:
+        obs_spans.set_enabled(None)
+        obs_pauses.uninstall()
+        obs_spans.recorder.clear()
+    assert gc.callbacks == callbacks and heartbeats() == []
 
 
 # ---- telemetry satellites -------------------------------------------------
@@ -426,8 +654,9 @@ def test_step_program_identical_obs_on_off():
 def test_span_overhead_under_two_percent(obs_on):
     """Span overhead budget: (spans per steady step) x (per-span cost),
     each factor bounded on its own (tests/span_budget.py): the count
-    exactly, the cost against an absolute ceiling — 5 x 50 us is 0.3 % of
-    the shortest step the benchmark measures, well under the 2 % budget."""
+    exactly, the cost against an absolute ceiling — 7 x 50 us is 0.5 % of
+    the shortest step the benchmark measures, well under the 2 % budget —
+    and the root span's children tile it."""
     from span_budget import assert_span_budget
 
     t, s, b = _golden_trainer()

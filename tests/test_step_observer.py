@@ -32,8 +32,11 @@ class _Detector:
     def __init__(self):
         self.seen = []
 
-    def observe(self, step, raw, phases):
+    def observe(self, step, raw, phases, sample=None):
         self.seen.append(step)
+
+    def cut_s(self):
+        return None
 
 
 class _Speed:
@@ -156,6 +159,22 @@ def test_plane_off_is_the_same_class_with_its_readers_absent(
         obs_spans.set_enabled(None)
         ledger.ledger.reset()
         export.reset_local_summary()
+
+
+def test_the_lines_that_kernel_bodies_embed_keep_their_numbers():
+    """A Mosaic kernel's body embeds the innermost ten frames that traced
+    it, file, line and column, and the compile cache keys on the body.  A
+    backward kernel (``embed_grad``, in every cell) is traced from a shallow
+    stack: its ten frames reach through ``bagua_step`` into ``_train_step``'s
+    dispatch line and ``train_step``'s call, so an edit that moves either
+    costs every benchmark cell one cold compile (PR 52 compiled gpt2's
+    lowered step with both files from one path: five payloads, one changed,
+    none once the two lines were back).  A PR that has to move them re-pins
+    the numbers here and says so (ROADMAP: a moved line is a cold compile)."""
+    lines = open(BACKEND).read().splitlines()
+    assert lines[1384 - 1].strip().startswith("def bagua_step(")
+    assert lines[1863 - 1] == "            return self._train_step(state, batch)"
+    assert lines[1936 - 1] == "                out = fn(state, batch)"
 
 
 def test_backend_imports_only_spans_and_the_observer_from_the_planes_above():
