@@ -22,6 +22,20 @@ a permutation of columns at load time and nothing to a seeded model.
 
 The state is per sequence and starts at zero: no decode path, no sequence or
 tensor parallel form yet (the callers refuse those).
+
+Which path runs where.  The two projections are ``nn.Dense`` everywhere.
+Between them, on a TPU and where their grids cover the shape
+(:func:`rows_by_kernel`: heads of whole 128-lane tiles, a sequence of whole
+128-row blocks, bfloat16 or float32), the rows are Pallas passes on the
+projection's own buffer around the ``gdn_fwd`` / ``gdn_bwd`` kernels
+(``ops/gated_delta_rows.py::gated_delta_rows``: ``gdn_mix`` for lines three
+and five above, ``gdn_gate`` for the seventh, float32 from the load to one
+rounding at the store, one VJP that writes the buffer's cotangent where it
+lands).  Everywhere else — the CPU, a ragged sequence, narrow heads — they
+are the ``jax.numpy`` code of this file (:func:`mix_rows`,
+:func:`gate_rows`) around ``ops.gated_delta.gated_delta_rule``, which picks
+between its kernels and its own ``jax.numpy`` chunks by itself; that code is
+also the golden the passes are tested against.
 """
 
 from __future__ import annotations
@@ -109,6 +123,46 @@ def _per_head(x, heads: int, reduce):
                           precision=exact)
 
 
+def mix_rows(qkvz, taps, dims):
+    """The fused projection's q | k | v columns through the convolution and
+    SiLU, q and k L2-normalised a head (``x / sqrt(sum x^2 + eps)``, on the
+    flat rows: ``_head_pool``) and q scaled by ``d_k^-1/2``: ``qkvz`` [b, s,
+    2 hk dk + 2 hv dv], ``dims`` ``(hk, hv, dk, dv)`` -> ``(q, k [b, s, hk
+    dk], v [b, s, hv dv])`` in ``qkvz.dtype``."""
+    hk, hv, dk, dv = dims
+    key_width, value_width = hk * dk, hv * dv
+    mixed = nn.silu(causal_depthwise_conv(
+        qkvz[..., :2 * key_width + value_width], taps))
+    unit = lambda t: _per_head(
+        t, hk, lambda mean: jax.lax.rsqrt(mean * dk + L2_EPS))
+    q = (unit(mixed[..., :key_width]) / math.sqrt(dk)).astype(qkvz.dtype)
+    k = unit(mixed[..., key_width:2 * key_width]).astype(qkvz.dtype)
+    return q, k, mixed[..., 2 * key_width:]
+
+
+def gate_rows(o, z, norm_scale, heads: int, eps: float):
+    """The gated norm, ``w_n * o / rms(o) * silu(z)`` over a value head's
+    lanes: float32, one rounding to the rows' dtype."""
+    o = _per_head(o, heads, lambda mean: jax.lax.rsqrt(mean + eps))
+    return (jnp.tile(norm_scale.astype(jnp.float32), heads) * o
+            * nn.silu(z.astype(jnp.float32))).astype(z.dtype)
+
+
+def _dims(cfg):
+    return (cfg.linear_key_heads, cfg.linear_value_heads,
+            cfg.linear_key_dim, cfg.linear_value_dim)
+
+
+def rows_by_kernel(cfg, seq: int) -> bool:
+    """Whether a layer of ``cfg`` over ``seq`` positions runs its rows
+    between the two projections as the Pallas passes of
+    ``ops/gated_delta_rows.py`` (on a TPU, where their grids cover the
+    shape) and not as :func:`mix_rows` / :func:`gate_rows`."""
+    from ..ops.gated_delta_rows import rows_supported
+
+    return rows_supported(seq, _dims(cfg), cfg.linear_conv, cfg.dtype)
+
+
 def decay_init(key, shape, dtype):
     """``A_log``: the log of ``A ~ U(0, 16)`` a value head (the family's
     initialisation; the lower end kept off zero)."""
@@ -125,8 +179,7 @@ class GatedDeltaNet(nn.Module):
         from ..ops.gated_delta import gated_delta_rule
 
         cfg = self.cfg
-        hk, hv = cfg.linear_key_heads, cfg.linear_value_heads
-        dk, dv = cfg.linear_key_dim, cfg.linear_value_dim
+        hk, hv, dk, dv = dims = _dims(cfg)
         if min(hk, hv, dk, dv, cfg.linear_conv) < 1 or hv % hk:
             raise ValueError(
                 "mixer_layers names linear-attention layers: they need "
@@ -152,27 +205,22 @@ class GatedDeltaNet(nn.Module):
         norm_scale = self.param("norm", nn.initializers.ones, (dv,),
                                 cfg.param_dtype)
 
-        mixed = nn.silu(causal_depthwise_conv(
-            qkvz[..., :2 * key_width + value_width], taps))
-        z = qkvz[..., 2 * key_width + value_width:]
-        # the L2 norm of q and k a head, x / sqrt(sum x^2 + eps), on the
-        # flat rows (``_head_pool``)
-        unit = lambda t: _per_head(
-            t, hk, lambda mean: jax.lax.rsqrt(mean * dk + L2_EPS))
-        q = (unit(mixed[..., :key_width]) / math.sqrt(dk)).astype(cfg.dtype)
-        k = unit(mixed[..., key_width:2 * key_width]).astype(cfg.dtype)
-        v = mixed[..., 2 * key_width:]
         beta = jax.nn.sigmoid(ba[..., :hv])
         g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
             ba[..., hv:] + dt_bias.astype(jnp.float32))
+        if rows_by_kernel(cfg, s):
+            # where the kernels run: the same rows as Pallas passes on the
+            # projection's buffer
+            from ..ops.gated_delta_rows import gated_delta_rows
 
-        o = gated_delta_rule(
-            q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk),
-            v.reshape(b, s, hv, dv), g, beta)
-
-        # the gated norm: a value head's lanes, float32, one rounding
-        o = _per_head(o.reshape(b, s, value_width), hv,
-                      lambda mean: jax.lax.rsqrt(mean + cfg.norm_eps))
-        y = (jnp.tile(norm_scale.astype(jnp.float32), hv) * o
-             * nn.silu(z.astype(jnp.float32))).astype(cfg.dtype)
+            y = gated_delta_rows(qkvz, taps, g, beta, norm_scale, dims,
+                                 l2_eps=L2_EPS, norm_eps=cfg.norm_eps)
+        else:
+            q, k, v = mix_rows(qkvz, taps, dims)
+            o = gated_delta_rule(
+                q.reshape(b, s, hk, dk), k.reshape(b, s, hk, dk),
+                v.reshape(b, s, hv, dv), g, beta)
+            y = gate_rows(o.reshape(b, s, value_width),
+                          qkvz[..., 2 * key_width + value_width:],
+                          norm_scale, hv, cfg.norm_eps)
         return dense("out_proj", cfg.d_model)(y)

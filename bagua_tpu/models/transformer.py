@@ -904,10 +904,16 @@ class TransformerLM(nn.Module):
             counters.set_gauge("embed/grad_kernel",
                                int(grad_kernel_supported(cfg.d_model)))
             if cfg.mixer_layers is not None:
-                counters.set_gauge("linattn/layers", sum(
-                    cfg.layer_linear(i) for i in range(cfg.n_layers)))
+                linear = sum(cfg.layer_linear(i) for i in range(cfg.n_layers))
+                counters.set_gauge("linattn/layers", linear)
                 from ..ops.gated_delta import CHUNK
+                from .linear_attention import rows_by_kernel
 
+                # of those, the layers whose rows between the projections
+                # are the ``gdn_mix`` / ``gdn_gate`` passes
+                counters.set_gauge(
+                    "linattn/row_kernel_layers",
+                    linear * rows_by_kernel(cfg, tokens.shape[1]))
                 counters.set_gauge("linattn/chunk", CHUNK)
                 counters.set_gauge("linattn/key_heads", cfg.linear_key_heads)
                 counters.set_gauge("linattn/value_heads",

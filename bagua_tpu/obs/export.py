@@ -226,6 +226,12 @@ _declare("linattn/key_heads", "gauge",
 _declare("linattn/value_heads", "gauge",
          "Value heads of those layers (a key head serves value_heads / "
          "key_heads of them): one [d_k, d_v] float32 state each.")
+_declare("linattn/row_kernel_layers", "gauge",
+         "Of those layers, the ones whose rows between the two projections "
+         "(convolution, SiLU, the L2 norms; the gated norm) are the Pallas "
+         "passes of ops/gated_delta_rows.py (gdn_mix / gdn_gate and their "
+         "transposes): all of them where the kernels run, 0 on the jnp "
+         "form.")
 _declare("attn/rotary_dim", "gauge",
          "Lanes of a head that the rotary layers of the model last traced "
          "rotate (TransformerConfig.rotary_dim; the head's width where "

@@ -50,6 +50,13 @@ chains are independent: the scheduler interleaves them) with the states in
 VMEM scratch across the steps of a sequence.  q / k / v / o are read and
 written as ``[batch, seq, heads * dim]``, the projections' own layout; the
 per-position scalars as ``[batch, value_heads, chunks, C]`` rows.
+
+Which path runs where: :func:`gated_delta_rule` runs the kernels where
+:func:`gated_delta_supported` says so (a TPU, heads of whole lane tiles) and
+the ``jax.numpy`` chunks elsewhere.  Where the layer's rows around the rule
+are Pallas passes too, ``ops/gated_delta_rows.py`` calls the rule's forward
+and backward (:func:`_forward`, :func:`_gated_delta_bwd`) from inside its own
+VJP, always by the kernels: the calls and their operands are the same.
 """
 
 from __future__ import annotations

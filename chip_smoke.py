@@ -99,6 +99,7 @@ def sizes(dryrun: bool) -> dict:
             gmm=dict(rows=512, d=128, f=256, groups=4),
             embed=dict(vocab=640, tokens=300, d=128),
             rope=dict(b=1, s=256, h=2, d=128),
+            gdn_rows=dict(b=2, s=256, dims=(1, 2, 128, 128), taps=4),
             # (elements per chunk) one fused-path and one tiled-path size
             codec_chunks=(4096, 2048 * 128 + 4096),
             ring_chunk_bytes=256,
@@ -114,6 +115,8 @@ def sizes(dryrun: bool) -> dict:
         gmm=dict(rows=8192, d=512, f=2048, groups=8),  # bench_moe_dropless
         embed=dict(vocab=30528, tokens=3072, d=1024),  # BERT-Large's table
         rope=dict(b=1, s=4096, h=16, d=128),           # Ouro's q and k
+        # qwen3-next's linear layers: the projection's [2, 4096, 12288]
+        gdn_rows=dict(b=2, s=4096, dims=(16, 32, 128, 128), taps=4),
         # 1 MiB f32 chunks (fused, the gate's floor) and 2.5 MiB (tiled: a
         # 10 MiB bucket over 4 ranks)
         codec_chunks=(1 << 18, 5 << 17),
@@ -329,6 +332,63 @@ def leg_kernels(sz: dict, dryrun: bool) -> None:
     log(f"kernel norm_rope fwd+vjp {x.shape}: max rel err (o, dx, dscale) = "
         f"{[round(e, 5) for e in errs]}  ({time.perf_counter() - t0:.1f}s)")
     # the two modules round the normalised tensor on the way: two bf16 ulps
+    assert all(np.isfinite(e) and e < 2.0 ** -6 for e in errs), errs
+
+    # ---- a Gated DeltaNet layer's rows between its projections -----------
+    from bagua_tpu.models import linear_attention as la
+    from bagua_tpu.ops import gated_delta_rows as gdn_rows
+
+    n = sz["gdn_rows"]
+    dims = n["dims"]
+    hk, hv, dk, dv = dims
+    kw, vw = hk * dk, hv * dv
+    keys = jax.random.split(jax.random.PRNGKey(SEED + 5), 8)
+    rows_of = lambda key, width: jax.random.normal(
+        key, (n["b"], n["s"], width), jnp.float32).astype(jnp.bfloat16)
+    qkvz = rows_of(keys[0], 2 * kw + 2 * vw)
+    taps = 0.5 * jax.random.normal(keys[1], (n["taps"], 2 * kw + vw))
+    cots = tuple(rows_of(k, w) for k, w in zip(keys[2:5], (kw, kw, vw)))
+    o, dy = rows_of(keys[5], vw), rows_of(keys[6], vw)
+    w_n = 1.0 + 0.3 * jax.random.normal(keys[7], (dv,), jnp.float32)
+    z_of = lambda a: a[..., 2 * kw + vw:]
+
+    def mixed(a, t, cots):
+        out, vjp = jax.vjp(lambda a, t: la.mix_rows(a, t, dims), a, t)
+        return (*out, *vjp(cots))
+
+    def gated(o, a, w, dy):
+        out, vjp = jax.vjp(
+            lambda o, a, w: la.gate_rows(o, z_of(a), w, hv, 1e-6), o, a, w)
+        return (out, *vjp(dy))
+
+    def by_passes(a, t, cots, o, w, dy):
+        q_k_v = gdn_rows.mix(a, t, dims, l2_eps=la.L2_EPS, interpret=interp)
+        y = gdn_rows.gate(o, a, w, dims, 1e-6, interp)
+        do, buffer, dw = gdn_rows.gate_bwd(dy, o, a, w, dims, 1e-6, interp)
+        dx, d_taps = gdn_rows.mix_bwd(*cots, a, t, buffer, dims,
+                                      l2_eps=la.L2_EPS, interpret=interp)
+        return q_k_v, dx, d_taps, y, do, dw
+
+    assert uses_pallas(by_passes, qkvz, taps, cots, o, w_n, dy)
+    t0 = time.perf_counter()
+    q_k_v, dx, d_taps, y, do, dw = jax.jit(by_passes)(qkvz, taps, cots, o,
+                                                      w_n, dy)
+    *want, dx_r, d_taps_r = jax.jit(mixed)(qkvz, taps, cots)
+    y_r, do_r, dz_r, dw_r = jax.jit(gated)(o, qkvz, w_n, dy)
+    through = 2 * kw + vw
+    errs = [*(rel_err(g, w) for g, w in zip(q_k_v, want)),
+            rel_err(dx[..., :through], dx_r[..., :through]),
+            rel_err(d_taps, d_taps_r)]
+    log(f"kernel gdn_mix fwd+vjp {qkvz.shape}: max rel err (q, k, v, dx, "
+        f"d_taps) = {[round(e, 5) for e in errs]}  "
+        f"({time.perf_counter() - t0:.1f}s)")
+    # the jnp form rounds the convolution's sum and the SiLU on the way, the
+    # passes once: a few bfloat16 ulps of the largest element
+    assert all(np.isfinite(e) and e < 2.0 ** -5 for e in errs), errs
+    errs = [rel_err(y, y_r), rel_err(do, do_r),
+            rel_err(z_of(dx), z_of(dz_r)), rel_err(dw, dw_r)]
+    log(f"kernel gdn_gate fwd+vjp {o.shape}: max rel err (y, do, dz, d_w_n) "
+        f"= {[round(e, 5) for e in errs]}")
     assert all(np.isfinite(e) and e < 2.0 ** -6 for e in errs), errs
 
     # ---- codec kernels, fused and tiled, f32 and bf16 --------------------

@@ -83,6 +83,7 @@ MODULES = [
     "bagua_tpu.ops.rope",
     "bagua_tpu.ops.moe_rows",
     "bagua_tpu.ops.gated_delta",
+    "bagua_tpu.ops.gated_delta_rows",
     "bagua_tpu.ops.tiles",
     "bagua_tpu.compression.codecs",
     "bagua_tpu.compression.minmax_uint8",

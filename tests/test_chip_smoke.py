@@ -32,7 +32,8 @@ def test_cpu_dryrun_rehearses_every_leg_and_never_passes():
     assert out.stdout.rstrip().endswith("DRYRUN ok")
     assert PASS_LINE not in out.stdout
     for leg in ("kernel flash", "kernel gmm", "kernel embed_grad",
-                "kernel rope", "kernel norm_rope", "kernel codec tiled",
+                "kernel rope", "kernel norm_rope", "kernel gdn_mix",
+                "kernel gdn_gate", "kernel codec tiled",
                 "leg A (gradient_allreduce): losses",
                 "leg B (bytegrad): losses", "4-chip dp4: losses",
                 "4-chip two-tier staged ZeRO: losses",

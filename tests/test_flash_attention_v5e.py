@@ -397,3 +397,57 @@ def test_the_gated_delta_kernels_compile_at_the_cells_shape(chunk, one_chip):
     assert first(calls["gdn_bwd"]) == f"{b},{seq},{hk * d}"      # dq
     # the states the backward call reads: one [d_k, d_v] a chunk and head
     assert f"bf16[{b},{hv},{seq // chunk},{d},{d}]" in calls["gdn_bwd"]
+
+
+def test_a_linear_layer_runs_its_rows_as_passes_on_the_projections_buffer(
+        one_chip, monkeypatch):
+    """qwen3-next-80b-a3b's ``GatedDeltaNet`` layer at its cell's shape,
+    forward and backward: between ``in_proj_qkvz`` and ``out_proj`` the rows
+    are the passes of ``ops/gated_delta_rows.py`` around the ``gdn_*``
+    kernels — one ``gdn_mix`` / ``gdn_mix_bwd`` call a part (q, k, v), one
+    ``gdn_gate`` / ``gdn_gate_bwd`` — and the ``[2, 4096, 12288]`` buffer
+    and its cotangent are read and written where they lie: no slice, pad,
+    concatenate, add or copy of an array that wide (as XLA ops the buffer
+    was sliced into q / k / v / z by copies and its cotangent padded back
+    together: PERF.md §6, PR 53)."""
+    import importlib
+
+    from bagua_tpu.models.linear_attention import GatedDeltaNet
+    from bagua_tpu.models.transformer import TransformerConfig
+
+    rows = importlib.import_module("bagua_tpu.ops.gated_delta_rows")
+    monkeypatch.setattr(rows, "_on_tpu", lambda: True)
+    b, s, d_model, hk, hv, d = 2, 4096, 2048, 16, 32, 128
+    layer = GatedDeltaNet(TransformerConfig(
+        vocab_size=128, d_model=d_model, n_heads=16, d_head=128, n_layers=1,
+        d_ff=128, max_seq_len=s, mixer_layers=(1,), linear_key_heads=hk,
+        linear_value_heads=hv, linear_key_dim=d, linear_value_dim=d,
+        linear_conv=4))
+    shaped = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                            sharding=one_chip)
+    x = jax.ShapeDtypeStruct((b, s, d_model), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(shaped, jax.eval_shape(
+        layer.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        return jnp.sum(layer.apply(params, x).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls = [re.search(r"/(\w+)/pallas_call", line).group(1)
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    assert sorted(calls) == sorted(
+        ["gdn_fwd", "gdn_bwd", "gdn_gate", "gdn_gate_bwd"]
+        + 3 * ["gdn_mix", "gdn_mix_bwd"]), calls
+    wide = f"[{b},{s},{2 * hk * d + 2 * hv * d}]"
+    moved = [line.strip()[:200] for line in text.splitlines()
+             if re.search(r" = \(?\w+" + re.escape(wide)
+                          + r"[^=]* (slice|dynamic-slice|pad|concatenate|add"
+                          r"|copy)\(", line)]
+    assert not moved, moved
+    # q, k and v reach the ``gdn_*`` kernels as the passes wrote them (the
+    # slices left are of the float32 ``[b, s, 2 hv]`` gates)
+    sliced = [line.strip()[:200] for line in text.splitlines()
+              if re.search(r" = bf16\[\d+,\d+,\d+\][^=]* slice\(", line)]
+    assert not sliced, sliced

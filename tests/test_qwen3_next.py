@@ -756,6 +756,8 @@ def test_a_traced_step_carries_the_scopes_and_sets_the_gauges():
     assert counters.get("linattn/chunk") == gd.CHUNK == 64
     assert counters.get("linattn/key_heads") == LIN_K
     assert counters.get("linattn/value_heads") == LIN_V
+    # the CPU: the rows between the projections are the jax.numpy form
+    assert counters.get("linattn/row_kernel_layers") == 0
     assert counters.get("attn/rotary_dim") == ROTARY
     assert counters.get("attn/full_layers") == 1
     assert counters.get("attn/rope_kernel_layers") == 0
