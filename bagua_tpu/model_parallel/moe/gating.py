@@ -55,7 +55,8 @@ def top1_gating(
 
 def topk_routing(
     logits: jax.Array, k: int, *, renormalize: bool = True,
-    balance_over_topk: bool = False,
+    balance_over_topk: bool = False, score: str = "softmax",
+    choice_bias=None, scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """Dropless top-k routing for any ``k``: no capacity, no dispatch tensor.
 
@@ -71,17 +72,39 @@ def topk_routing(
     the balance loss over all ``k`` assignments of a token, as HF's
     ``load_balancing_loss_func`` does: ``n_experts * sum_e (assignments on
     e / tokens) * mean_t probs[t, e]``.
+
+    ``score="sigmoid"``: the scores are ``sigmoid(logits)``, each expert's by
+    itself and not normalised over the experts (the DeepSeek-V3 / Nemotron-H
+    routers); the winners' are renormalised as ``s / (sum s + 1e-20)``.
+    ``choice_bias`` [n_experts]: added to the scores for the CHOICE of the
+    ``k`` winners only — the weights are the unbiased scores at the winners,
+    so the bias has no gradient (it enters only the indices; the families
+    move it by a load-balance rule outside the gradient).  ``scale``
+    multiplies the final weights (``routed_scaling_factor``).
     """
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    if score not in ("softmax", "sigmoid"):
+        raise ValueError(f"router score {score!r}: 'softmax' or 'sigmoid'")
+    logits = logits.astype(jnp.float32)
+    probs = (jax.nn.sigmoid(logits) if score == "sigmoid"
+             else jax.nn.softmax(logits, axis=-1))
     n_experts = probs.shape[-1]
-    gates, eidx = jax.lax.top_k(probs, k)
+    if choice_bias is None:
+        gates, eidx = jax.lax.top_k(probs, k)
+    else:
+        _, eidx = jax.lax.top_k(
+            probs + choice_bias.astype(jnp.float32), k)
+        gates = jnp.take_along_axis(probs, eidx, axis=-1)
     if balance_over_topk:
         mask = jax.nn.one_hot(eidx, n_experts, dtype=jnp.float32).sum(axis=1)
     else:
         mask = jax.nn.one_hot(eidx[:, 0], n_experts, dtype=jnp.float32)
     l_aux = _load_balancing_loss(probs, mask)
     if k > 1 and renormalize:
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        total = gates.sum(-1, keepdims=True)
+        gates = gates / (total + 1e-20 if score == "sigmoid"
+                         else jnp.maximum(total, 1e-9))
+    if scale != 1.0:
+        gates = gates * scale
     return eidx.astype(jnp.int32), gates, l_aux
 
 

@@ -34,8 +34,9 @@ from ...parallel.mesh import axis_bound as _axis_bound
 from .gating import top1_gating, top2_gating
 
 
-#: the experts' activations by ``MoEMLP.activation``; both keep a zero row zero
-_ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu}
+#: the experts' activations by ``MoEMLP.activation``; all keep a zero row zero
+_ACTIVATIONS = {"silu": nn.silu, "relu": nn.relu,
+                "relu2": lambda x: jnp.square(nn.relu(x))}
 
 
 class MoEMLP(nn.Module):
@@ -113,9 +114,10 @@ class MoEMLP(nn.Module):
     #: ``load_balancing_loss_func``) instead of the top-1 (GShard eq. 4).
     #: ``dropless`` only
     balance_over_topk: bool = False
-    #: the experts' activation: ``silu`` (``silu(x wg) * (x wi)``) or
-    #: ``relu`` (ReLU-gated experts, SmallThinker's sparse ReGLU).  Both
-    #: keep a zero row zero.  ``dropless`` only
+    #: the experts' activation: ``silu`` (``silu(x wg) * (x wi)``), ``relu``
+    #: (ReLU-gated experts, SmallThinker's sparse ReGLU) or ``relu2``
+    #: (``relu(x)^2``, Nemotron-H's, on ungated experts: ``relu(x wi)^2
+    #: wo``).  All keep a zero row zero.  ``dropless`` only
     activation: str = "silu"
     #: the expert-parallel rank whose share is computed outside a bound
     #: ``axis_name`` axis (``dropless`` only; see the class docstring)
@@ -131,6 +133,21 @@ class MoEMLP(nn.Module):
     #: the shared expert's output is multiplied by ``sigmoid(x w_s)``, one
     #: float32 scalar a token (leaf ``shared_gate``)
     shared_gate: bool = False
+    #: the router's scores: ``softmax`` over the experts, or ``sigmoid`` of
+    #: each expert's logit by itself (not normalised over the experts; the
+    #: winners' renormalised where ``norm_topk_prob``).  ``dropless`` only
+    router_score: str = "softmax"
+    #: a per-expert bias (leaf ``score_bias``, float32 ``[n_experts]``) added
+    #: to the scores for the CHOICE of the ``k`` winners and not for their
+    #: weights: its gradient is zero (the families move it by a load-balance
+    #: rule outside the gradient; here only the optimizer's decay touches
+    #: it).  Drawn at ``score_bias_std``.  ``dropless`` only
+    score_bias: bool = False
+    score_bias_std: float = 0.0
+    #: the routed experts' weights are multiplied by it after the
+    #: renormalisation (``routed_scaling_factor``); the shared expert is
+    #: not.  ``dropless`` only
+    routed_scale: float = 1.0
 
     @nn.compact
     def __call__(self, x, route_x=None):
@@ -186,6 +203,12 @@ class MoEMLP(nn.Module):
                 "gated experts, norm_topk_prob=False, balance_over_topk="
                 "True, an activation other than silu and a rank's share "
                 "(ep_rank) are options of the dropless path: set "
+                "dropless=True")
+        if (self.router_score != "softmax" or self.score_bias
+                or self.routed_scale != 1.0):
+            raise ValueError(
+                "router_score other than softmax, score_bias and "
+                "routed_scale are options of the dropless path: set "
                 "dropless=True")
         capacity = max(1, math.ceil(self.k * tokens * self.capacity_factor
                                     / self.n_experts))
@@ -261,6 +284,15 @@ class MoEMLP(nn.Module):
         from ...telemetry import counters
 
         act = _ACTIVATIONS[self.activation]
+        extra = _whole_tiles(self.d_ff) - self.d_ff
+        if extra and layout.block_rows > 1:
+            # the kernels take whole lane tiles of the hidden width (1,856
+            # is 14.5): zero columns behind wi (and wg), zero rows behind
+            # wo.  ``act(0) * 0`` is zero, so the padding adds nothing, and
+            # its cotangent is sliced off again by the pad's transpose
+            wi, wg = (w if w is None else jnp.pad(
+                w, ((0, 0), (0, 0), (0, extra))) for w in (wi, wg))
+            wo = jnp.pad(wo, ((0, 0), (0, extra), (0, 0)))
 
         if not self.is_initializing():
             # what the kernels really multiply (under ``ep`` the rows of
@@ -337,11 +369,18 @@ class MoEMLP(nn.Module):
         n_local = self.n_experts // self.ep_size
         tokens, k = xt.shape[0], self.k
         with phase_scope("bagua.moe/route"):
+            bias = self.param(
+                "score_bias", nn.initializers.normal(self.score_bias_std),
+                (self.n_experts,), jnp.float32) if self.score_bias else None
             eidx, gates, l_aux = topk_routing(
                 logits, k, renormalize=self.norm_topk_prob,
-                balance_over_topk=self.balance_over_topk)
+                balance_over_topk=self.balance_over_topk,
+                score=self.router_score, choice_bias=bias,
+                scale=self.routed_scale)
         self.sow("intermediates", "l_aux", l_aux)
         if not self.is_initializing():
+            counters.set_gauge("moe/routed_scale", self.routed_scale)
+            counters.set_gauge("moe/score_bias", int(self.score_bias))
             # trace-time facts of this layer's step (not of ``init``'s stub
             # batch), for the operator and the benchmark's moe_padding_share
             counters.set_gauge("moe/experts", n_local)
@@ -379,7 +418,7 @@ class MoEMLP(nn.Module):
                 sizes = (flat_e[:, None] == jnp.arange(n_local)[None, :]).sum(
                     0, dtype=jnp.int32)
                 layout = kernel_layout(sizes, tokens * k, x.shape[1],
-                                       self.d_ff)
+                                       _whole_tiles(self.d_ff))
                 reader = _take_index(order, layout.src)
                 slots = layout.pos[rank].reshape(tokens, k)
                 by_kernel = _row_kernels(tokens, k, layout, x.shape[1],
@@ -461,7 +500,7 @@ class MoEMLP(nn.Module):
         from ...ops.gmm import kernel_layout, pad_rows, unpad_rows
 
         local_order = jnp.argsort(lid_recv)             # sorted row -> slot
-        layout = kernel_layout(sizes, cap, d, self.d_ff)
+        layout = kernel_layout(sizes, cap, d, _whole_tiles(self.d_ff))
         reader = _take_index(local_order, layout.src)   # padded -> receive
         slots = layout.pos[_inverse_permutation(local_order)]
         x_p = pad_rows(x_recv, reader, slots[:, None])
@@ -483,6 +522,15 @@ class MoEMLP(nn.Module):
             y_local.reshape(ep, tk, d), ax, 0, 0, tiled=False
         ).reshape(cap, d)
         return y_back[slot]
+
+
+def _whole_tiles(width: int) -> int:
+    """``width`` rounded up to whole 128-lane tiles: the hidden width the
+    grouped-matmul kernels are handed (``MoEMLP._experts`` pads the expert
+    stacks with zeros up to it where they run)."""
+    from ...ops.tiles import LANE
+
+    return -(-width // LANE) * LANE
 
 
 def _inverse_permutation(perm):

@@ -179,6 +179,27 @@ class TransformerConfig:
     #: "head"``'s) multiplies by ``1 + scale`` with ``scale`` initialised to
     #: zero (the Qwen3-Next family's zero-centred norm)
     norm_zero_centered: bool = False
+    #: the kind of every layer over the whole depth, ``"ssm"`` (a Mamba-2
+    #: state-space mixer, ``models.state_space.Mamba2``), ``"attn"`` (softmax
+    #: attention) or ``"moe"`` (what ``mlp_factory`` makes), as an aperiodic
+    #: pattern states them (``("ssm", "moe", "ssm", "moe", "ssm", "attn",
+    #: ...)``).  Where set, a block is ONE norm and ONE sub-layer of that
+    #: kind, ``x + F(N(x))`` (``models.single_block.SingleBlock``); None: the
+    #: two-sub-layer ``Block``.  The decode paths, ``sp_axis``, the tensor
+    #: and pipeline axes, a looped stack and the block-diffusion mask do not
+    #: implement it
+    layer_kinds: Optional[tuple] = None
+    #: the state-space layers' heads and their width (``d_inner = ssm_heads
+    #: * ssm_head_dim``), the groups that share one B / C pair (head ``h``
+    #: reads group ``h // (ssm_heads / ssm_groups)``), the state's size, the
+    #: taps of the causal depthwise convolution (with bias) over x / B / C
+    #: and the positions of a chunk of the scan
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
 
     @property
     def block_diffusion(self) -> bool:
@@ -218,6 +239,12 @@ class TransformerConfig:
         """Whether layer ``layer``'s mixer is linear attention."""
         pattern = self.mixer_layers
         return pattern is not None and bool(pattern[layer % len(pattern)])
+
+    def layer_softmax(self, layer: int) -> bool:
+        """Whether layer ``layer`` holds softmax attention."""
+        if self.layer_kinds is not None:
+            return self.layer_kinds[layer] == "attn"
+        return not self.layer_linear(layer)
 
 
 def bert_large_config(**kw) -> TransformerConfig:
@@ -882,7 +909,7 @@ class TransformerLM(nn.Module):
             from ..telemetry import counters
 
             softmax = [i for i in range(cfg.n_layers)
-                       if not cfg.layer_linear(i)]
+                       if cfg.layer_softmax(i)]
             windowed = sum(cfg.layer_window(i) is not None for i in softmax)
             counters.set_gauge("attn/kv_heads", cfg.kv_heads // cfg.tp_size)
             counters.set_gauge("attn/window", cfg.window or 0)
@@ -918,6 +945,12 @@ class TransformerLM(nn.Module):
                 counters.set_gauge("linattn/key_heads", cfg.linear_key_heads)
                 counters.set_gauge("linattn/value_heads",
                                    cfg.linear_value_heads)
+            if cfg.layer_kinds is not None:
+                counters.set_gauge("ssm/layers",
+                                   cfg.layer_kinds.count("ssm"))
+                for size in ("chunk", "heads", "head_dim", "groups", "state"):
+                    counters.set_gauge(f"ssm/{size}",
+                                       getattr(cfg, f"ssm_{size}"))
             if cfg.rope_theta is not None:
                 counters.set_gauge("attn/rotary_dim",
                                    cfg.rotary_dim or cfg.head_dim)
@@ -944,10 +977,12 @@ class TransformerLM(nn.Module):
                 mlp = (self.mlp_factory(i) if self.mlp_factory is not None
                        else None)
                 block_cls = Block
+                if cfg.layer_kinds is not None:
+                    from .single_block import SingleBlock as block_cls
                 if cfg.remat:
                     # a custom MLP tags nothing: its matmuls keep the dots rule
                     own = (KEPT_QKV, KEPT_FFN_IN) if mlp is None else ()
-                    block_cls = remat_wrap(Block, cfg.remat_policy, own)
+                    block_cls = remat_wrap(block_cls, cfg.remat_policy, own)
                 blk = block_cls(cfg, self.attn_fn, mlp, i, name=f"block_{i}")
                 x = blk(x) if slots is None else blk(x, slots)
             return x

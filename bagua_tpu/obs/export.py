@@ -232,6 +232,32 @@ _declare("linattn/row_kernel_layers", "gauge",
          "passes of ops/gated_delta_rows.py (gdn_mix / gdn_gate and their "
          "transposes): all of them where the kernels run, 0 on the jnp "
          "form.")
+_declare("ssm/layers", "gauge",
+         "Layers of the model last traced that are state-space mixers "
+         "(TransformerConfig.layer_kinds == 'ssm'; Mamba-2, kernels "
+         "ssd_fwd / ssd_bwd where they run).")
+_declare("ssm/chunk", "gauge",
+         "Positions of a chunk of that model's chunked scan "
+         "(TransformerConfig.ssm_chunk): inside a chunk matrix products, "
+         "between chunks the carried state.")
+_declare("ssm/heads", "gauge",
+         "Heads of that model's state-space layers: one [head_dim, state] "
+         "float32 state each.")
+_declare("ssm/head_dim", "gauge",
+         "Width of a state-space head (d_inner = heads x head_dim).")
+_declare("ssm/groups", "gauge",
+         "Groups of those layers: a group's heads / groups heads share one "
+         "B / C pair, and a group is a grid step of the ssd kernels.")
+_declare("ssm/state", "gauge",
+         "Size N of a state-space head's state ([head_dim, N]).")
+_declare("moe/routed_scale", "gauge",
+         "What the expert layer last traced multiplies its routed "
+         "experts' weights by after the renormalisation "
+         "(MoEMLP.routed_scale; 1.0: nothing).")
+_declare("moe/score_bias", "gauge",
+         "1 where that layer's router adds a per-expert bias to the scores "
+         "for the CHOICE of the winners and not for their weights "
+         "(MoEMLP.score_bias), else 0.")
 _declare("attn/rotary_dim", "gauge",
          "Lanes of a head that the rotary layers of the model last traced "
          "rotate (TransformerConfig.rotary_dim; the head's width where "

@@ -463,12 +463,13 @@ AREA_COMPONENTS = {
     DIFFUSION_INPUT_SCOPE: "embed",
     "attn_norm": "attn", "attn": "attn", "attn_post_norm": "attn",
     "linear_attn_norm": "linattn", "linear_attn": "linattn",
+    "ssm_norm": "ssm", "ssm": "ssm",
     "mlp_norm": "mlp", "mlp": "mlp", "mlp_post_norm": "mlp",
     "final_norm": "head", "lm_head": "head", LOSS_TAIL_SCOPE: "head",
     "exit_gate": "exit", EXIT_SCOPE: "exit",
     ACCUM_SCOPE: "accum",
 }
-AREAS = ("embed", "attn", "linattn", "mlp") + tuple(
+AREAS = ("embed", "attn", "linattn", "ssm", "mlp") + tuple(
     f"moe/{part}" for part in MOE_PARTS) + ("head", "exit", "accum")
 
 

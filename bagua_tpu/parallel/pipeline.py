@@ -94,6 +94,11 @@ class PipelinedTransformerLM(nn.Module):
                 "plain RMSNorm: mixer_layers (linear-attention layers by "
                 "period) and norm_zero_centered are not implemented under "
                 "pipeline stages")
+        if cfg.layer_kinds is not None:
+            raise NotImplementedError(
+                "the pipelined stack scans ONE kind of two-sub-layer block: "
+                "layer_kinds (one sub-layer a block, a kind a layer) is not "
+                "implemented under pipeline stages")
         assert cfg.n_layers % self.pp_size == 0, (cfg.n_layers, self.pp_size)
         n_local = cfg.n_layers // self.pp_size
 

@@ -1,0 +1,16 @@
+"""Share of its roofline that the state-space-scan kernel ``ssd_fwd`` reaches:
+the FLOP of the RECURRENT form of the scan — 5 P N a position and head, which
+the chunked kernel's own work exceeds — and the least HBM bytes a call can
+move (perfbench/kernel_costs_ssd.py), over ``ssd_fwd_ms``, over min(peak
+bf16 FLOP/s, FLOP/byte x HBM bytes/s) of perfbench/peaks.json."""
+
+from perfbench import kernel_costs_ssd
+
+LAYER = "kernels"
+UNIT = "%"
+MOVES = "tokens_per_s_per_chip"
+SOURCE = "device_trace"
+
+
+def reduce(ctx):
+    return kernel_costs_ssd.roofline(ctx, "ssd_fwd")

@@ -72,6 +72,8 @@ MODULES = [
     "bagua_tpu.models.vgg",
     "bagua_tpu.models.transformer",
     "bagua_tpu.models.linear_attention",
+    "bagua_tpu.models.state_space",
+    "bagua_tpu.models.single_block",
     "bagua_tpu.models.generate",
     "bagua_tpu.serve",
     "bagua_tpu.serve.cache",
@@ -84,6 +86,7 @@ MODULES = [
     "bagua_tpu.ops.moe_rows",
     "bagua_tpu.ops.gated_delta",
     "bagua_tpu.ops.gated_delta_rows",
+    "bagua_tpu.ops.ssd",
     "bagua_tpu.ops.tiles",
     "bagua_tpu.compression.codecs",
     "bagua_tpu.compression.minmax_uint8",

@@ -451,3 +451,39 @@ def test_a_linear_layer_runs_its_rows_as_passes_on_the_projections_buffer(
     sliced = [line.strip()[:200] for line in text.splitlines()
               if re.search(r" = bf16\[\d+,\d+,\d+\][^=]* slice\(", line)]
     assert not sliced, sliced
+
+
+@pytest.mark.parametrize("seq", [8192, 8000])
+def test_the_state_space_kernels_compile_at_the_cells_shape(seq, one_chip):
+    """nemotron-3-nano-30b-a3b.pretrain8192-b1-dp1's Mamba-2 layers: 64
+    heads of 64 (two a lane tile) over 8 groups and a state of 128, chunks
+    of 128 over 8,192 positions (and a ragged length, padded to whole blocks
+    of chunks); x and y as [b, T, 4096], B and C as [b, T, 1024], the
+    per-chunk states kept once in bfloat16, a group's eight heads a grid
+    step."""
+    from bagua_tpu.ops.ssd import ssd_scan
+
+    b, h, p, g, n, chunk = 1, 64, 64, 8, 128, 128
+    shape = lambda *dims, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        dims, dtype, sharding=one_chip)
+    args = (shape(b, seq, h, p), shape(b, seq, h, dtype=jnp.float32),
+            shape(h, dtype=jnp.float32), shape(b, seq, g, n),
+            shape(b, seq, g, n), shape(h, dtype=jnp.float32))
+
+    def loss(x, dt, a, bm, cm, d):
+        return ssd_scan(x, dt, a, bm, cm, d, chunk=chunk,
+                        force=True).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(6)))).lower(
+        *args).compile().as_text()
+    calls = {re.search(r"%(\w+?)\.\d+ = ", line).group(1): line
+             for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line}
+    assert sorted(calls) == ["ssd_bwd", "ssd_fwd"]
+    padded = -(-seq // (8 * chunk)) * 8 * chunk
+    first = lambda line: re.search(r"= \(?bf16\[([\d,]+)\]", line).group(1)
+    assert first(calls["ssd_fwd"]) == f"{b},{padded},{h * p}"      # y
+    assert first(calls["ssd_bwd"]) == f"{b},{padded},{h * p}"      # dx
+    # the states the backward call reads: one [8 x 64, 128] a chunk and group
+    assert f"bf16[{b},{g},{padded // chunk},{h // g * p},{n}]" in calls[
+        "ssd_bwd"]
