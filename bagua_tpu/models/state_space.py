@@ -23,13 +23,21 @@ result — the step sizes, the decays and the state are float32 from there on.
 The state is per sequence and starts at zero: no decode path, no sequence or
 tensor parallel form yet (the callers refuse those).
 
-Which path runs where: the projections are plain matmuls everywhere; the
-scan is :func:`bagua_tpu.ops.ssd.ssd_scan`, which picks between its kernels
-(``ssd_fwd`` / ``ssd_bwd``, on a TPU) and its ``jax.numpy`` chunks by itself;
-the rows around it — the convolution with its bias and SiLU, the gate and the
-grouped norm — are ``jax.numpy`` (:func:`conv_bias_silu`, one VJP so that
-each direction is one pass over arrays in the rows' dtype, and
-:func:`gated_group_norm`).
+Which path runs where: the projections are plain matmuls everywhere.  Where
+:func:`rows_by_kernel` says so (a TPU; what the ``ssd_*`` kernels cover — a
+group's heads and the state of whole 128-lane tiles —; a sequence of whole
+row blocks that the scan does not pad; bfloat16 or float32) everything
+between the two projections is ``ops/ssd_rows.py::ssd_rows``: the Pallas row
+passes ``ssd_mix`` (lines two and three above, reading the projection's
+buffer where it lies) and ``ssd_gate`` (the gate and the grouped norm)
+around the ``ssd_fwd`` kernel, their transposes ``ssd_gate_bwd`` /
+``ssd_mix_bwd`` around ``ssd_bwd`` writing the buffer's cotangent in place.
+Everywhere else (the CPU, a ragged sequence, odd widths) the scan is
+:func:`bagua_tpu.ops.ssd.ssd_scan`, which picks between its kernels and its
+``jax.numpy`` chunks by itself, and the rows around it are ``jax.numpy``
+(:func:`conv_bias_silu`, one VJP so that each direction is one pass over
+arrays in the rows' dtype, and :func:`gated_group_norm`): the fallback, and
+the tests' golden.  The choice is read off the input; nothing sets it.
 """
 
 from __future__ import annotations
@@ -101,6 +109,21 @@ def gated_group_norm(y, z, scale, groups: int, eps: float):
     return (scale.astype(jnp.float32) * normed).astype(z.dtype)
 
 
+def _dims(cfg):
+    return (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups, cfg.ssm_state)
+
+
+def rows_by_kernel(cfg, seq: int) -> bool:
+    """Whether a layer of ``cfg`` over ``seq`` positions runs its rows
+    between the two projections as the Pallas passes of ``ops/ssd_rows.py``
+    (on a TPU, where their grids cover the shape) and not as
+    :func:`conv_bias_silu` / :func:`gated_group_norm` around ``ssd_scan``."""
+    from ..ops.ssd_rows import rows_supported
+
+    return rows_supported(seq, _dims(cfg), cfg.ssm_conv, cfg.ssm_chunk,
+                          cfg.dtype)
+
+
 #: the step sizes ``dt_bias`` starts from: log-uniform in this range, then
 #: floored (the family's ``time_step_min`` / ``time_step_max`` /
 #: ``time_step_floor`` defaults, which the published configs keep)
@@ -145,8 +168,7 @@ class Mamba2(nn.Module):
         from ..ops.ssd import ssd_scan
 
         cfg = self.cfg
-        h, p, groups, n = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_groups,
-                           cfg.ssm_state)
+        h, p, groups, n = dims = _dims(cfg)
         if min(h, p, groups, n, cfg.ssm_conv, cfg.ssm_chunk) < 1 or h % groups:
             raise ValueError(
                 "layer_kinds names state-space layers: they need ssm_heads "
@@ -175,15 +197,26 @@ class Mamba2(nn.Module):
         norm_scale = self.param("norm", nn.initializers.ones, (inner,),
                                 cfg.param_dtype)
 
-        xbc = conv_bias_silu(zxbc[..., inner:], taps, conv_bias)
-        delta = jax.nn.softplus(dt + dt_bias.astype(jnp.float32))
-        y = ssd_scan(
-            xbc[..., :inner].reshape(b, s, h, p), delta,
-            -jnp.exp(a_log.astype(jnp.float32)),
-            xbc[..., inner:inner + maps].reshape(b, s, groups, n),
-            xbc[..., inner + maps:].reshape(b, s, groups, n), skip,
-            chunk=cfg.ssm_chunk)
-        o = gated_group_norm(y.reshape(b, s, inner), zxbc[..., :inner],
-                             norm_scale, groups, cfg.norm_eps)
+        if rows_by_kernel(cfg, s):
+            # where the kernels run: the same rows as Pallas passes on the
+            # projection's buffer
+            from ..ops.ssd_rows import ssd_rows
+
+            o = ssd_rows(
+                zxbc, taps, conv_bias,
+                jax.nn.softplus(dt + dt_bias.astype(jnp.float32)),
+                -jnp.exp(a_log.astype(jnp.float32)), skip, norm_scale, dims,
+                chunk=cfg.ssm_chunk, norm_eps=cfg.norm_eps)
+        else:
+            xbc = conv_bias_silu(zxbc[..., inner:], taps, conv_bias)
+            delta = jax.nn.softplus(dt + dt_bias.astype(jnp.float32))
+            y = ssd_scan(
+                xbc[..., :inner].reshape(b, s, h, p), delta,
+                -jnp.exp(a_log.astype(jnp.float32)),
+                xbc[..., inner:inner + maps].reshape(b, s, groups, n),
+                xbc[..., inner + maps:].reshape(b, s, groups, n), skip,
+                chunk=cfg.ssm_chunk)
+            o = gated_group_norm(y.reshape(b, s, inner), zxbc[..., :inner],
+                                 norm_scale, groups, cfg.norm_eps)
         return nn.Dense(d, use_bias=False, dtype=cfg.dtype,
                         param_dtype=cfg.param_dtype, name="out_proj")(o)

@@ -946,8 +946,15 @@ class TransformerLM(nn.Module):
                 counters.set_gauge("linattn/value_heads",
                                    cfg.linear_value_heads)
             if cfg.layer_kinds is not None:
-                counters.set_gauge("ssm/layers",
-                                   cfg.layer_kinds.count("ssm"))
+                ssm = cfg.layer_kinds.count("ssm")
+                counters.set_gauge("ssm/layers", ssm)
+                from .state_space import rows_by_kernel as ssm_rows_by_kernel
+
+                # of those, the layers whose rows between the projections
+                # are the ``ssd_mix`` / ``ssd_gate`` passes
+                counters.set_gauge(
+                    "ssm/row_kernel_layers",
+                    ssm * ssm_rows_by_kernel(cfg, tokens.shape[1]))
                 for size in ("chunk", "heads", "head_dim", "groups", "state"):
                     counters.set_gauge(f"ssm/{size}",
                                        getattr(cfg, f"ssm_{size}"))

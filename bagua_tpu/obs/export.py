@@ -236,6 +236,12 @@ _declare("ssm/layers", "gauge",
          "Layers of the model last traced that are state-space mixers "
          "(TransformerConfig.layer_kinds == 'ssm'; Mamba-2, kernels "
          "ssd_fwd / ssd_bwd where they run).")
+_declare("ssm/row_kernel_layers", "gauge",
+         "Of those layers, the ones whose rows between the two projections "
+         "(the convolution with its bias and SiLU; the gate and the grouped "
+         "norm) are the Pallas passes of ops/ssd_rows.py (ssd_mix / "
+         "ssd_gate and their transposes, on the projection's own buffer): "
+         "all of them where the kernels run, 0 on the jnp form.")
 _declare("ssm/chunk", "gauge",
          "Positions of a chunk of that model's chunked scan "
          "(TransformerConfig.ssm_chunk): inside a chunk matrix products, "

@@ -87,6 +87,7 @@ MODULES = [
     "bagua_tpu.ops.gated_delta",
     "bagua_tpu.ops.gated_delta_rows",
     "bagua_tpu.ops.ssd",
+    "bagua_tpu.ops.ssd_rows",
     "bagua_tpu.ops.tiles",
     "bagua_tpu.compression.codecs",
     "bagua_tpu.compression.minmax_uint8",

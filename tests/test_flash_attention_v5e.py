@@ -487,3 +487,76 @@ def test_the_state_space_kernels_compile_at_the_cells_shape(seq, one_chip):
     # the states the backward call reads: one [8 x 64, 128] a chunk and group
     assert f"bf16[{b},{g},{padded // chunk},{h // g * p},{n}]" in calls[
         "ssd_bwd"]
+
+
+@pytest.mark.parametrize("remat", [None, "dots_no_batch"])
+def test_a_state_space_layer_runs_its_rows_as_passes_on_the_projections_buffer(
+        remat, one_chip, monkeypatch):
+    """nemotron-3-nano-30b-a3b's ``Mamba2`` layer at its cell's shape,
+    forward and backward (and under the cell's remat policy): between the
+    in-projection and ``out_proj`` the rows are the passes of
+    ``ops/ssd_rows.py`` around the ``ssd_*`` kernels — one ``ssd_mix`` call
+    a part (x, B, C) forward and again where the backward pass reads them,
+    one ``ssd_mix_bwd`` a part, ``ssd_gate`` (again in the replay: it makes
+    the out-projection's operand) and ``ssd_gate_bwd`` — and the ``[1,
+    8192, 10240]`` buffer and its cotangent are read and written where they
+    lie: no slice, pad, concatenate, add or copy of an array that wide or as
+    wide as its x | B | C part (as XLA ops: the convolution's four shifted
+    float32 slices a direction, the three slices for the kernels and the
+    concatenate and pads that put the cotangents back, PERF.md §6, PR 55).
+    The ``ssd_*`` calls keep the operands ``perfbench/kernel_costs_ssd.py``
+    recognises them by: rank-3 x, B, C of 4096 / 1024 / 1024 lanes over the
+    same rows, then the rank-4 scalars."""
+    import importlib
+
+    from bagua_tpu.models.state_space import Mamba2
+    from bagua_tpu.models.transformer import TransformerConfig
+    from bagua_tpu.utils import remat_wrap
+
+    rows = importlib.import_module("bagua_tpu.ops.ssd_rows")
+    monkeypatch.setattr(rows, "_on_tpu", lambda: True)
+    b, s, d_model, h, p, g, n = 1, 8192, 2688, 64, 64, 8, 128
+    layer = (remat_wrap(Mamba2, remat) if remat else Mamba2)(
+        TransformerConfig(
+            vocab_size=128, d_model=d_model, n_heads=32, d_head=128,
+            n_layers=1, d_ff=128, max_seq_len=s, layer_kinds=("ssm",),
+            ssm_heads=h, ssm_head_dim=p, ssm_groups=g, ssm_state=n,
+            ssm_conv=4, ssm_chunk=128))
+    shaped = lambda t: jax.ShapeDtypeStruct(t.shape, t.dtype,
+                                            sharding=one_chip)
+    x = jax.ShapeDtypeStruct((b, s, d_model), jnp.bfloat16, sharding=one_chip)
+    params = jax.tree.map(shaped, jax.eval_shape(
+        layer.init, jax.random.PRNGKey(0), x))
+
+    def loss(params, x):
+        return jnp.sum(layer.apply(params, x).astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    mosaic = [line for line in text.splitlines()
+              if 'custom_call_target="tpu_custom_call"' in line]
+    calls = [re.search(r"/(\w+)/pallas_call", line).group(1)
+             for line in mosaic]
+    assert sorted(calls) == sorted(
+        ["ssd_fwd", "ssd_bwd", "ssd_gate_bwd"] + 6 * ["ssd_mix"]
+        + 3 * ["ssd_mix_bwd"] + (2 if remat else 1) * ["ssd_gate"]), calls
+    inner, maps = h * p, g * n
+    for wide in (f"[{b},{s},{2 * inner + 2 * maps}]",
+                 f"[{b},{s},{inner + 2 * maps}]"):
+        moved = [line.strip()[:200] for line in text.splitlines()
+                 if re.search(r" = \(?\w+" + re.escape(wide)
+                              + r"[^=]* (slice|dynamic-slice|pad|concatenate"
+                              r"|add|copy)\(", line)]
+        assert not moved, moved
+    # x, B and C reach the ``ssd_*`` kernels as the passes wrote them
+    sliced = [line.strip()[:200] for line in text.splitlines()
+              if re.search(r" = bf16\[\d+,\d+,\d+\][^=]* slice\(", line)]
+    assert not sliced, sliced
+    for line, name in zip(mosaic, calls):
+        if name not in ("ssd_fwd", "ssd_bwd"):
+            continue
+        operands = re.findall(r"\w+\[([\d,]*)\]\{", line.split(
+            "operand_layout_constraints={")[1].split("frontend_attributes")[0])
+        assert operands[:4] == [
+            f"{b},{s},{inner}", f"{b},{s},{maps}", f"{b},{s},{maps}",
+            f"{b},{h},{s // 128},128"], (name, operands)

@@ -800,6 +800,11 @@ def test_the_capacity_path_refuses_the_new_options_by_name(options):
      "ssm/jit(_kernel_bwd)/ssd_bwd/pallas_call", "ssm"),
     ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_4/ssm_norm/mul",
      "ssm"),
+    # the row passes of ops/ssd_rows.py sit inside the layer's module
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_0/ssm/"
+     "jit(_mix_part)/ssd_mix/pallas_call", "ssm"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/block_7/"
+     "ssm/jit(gate_bwd)/ssd_gate_bwd/pallas_call", "ssm"),
     ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_5/attn/q/"
      "dot_general", "attn"),
     ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_1/mlp/"
@@ -819,6 +824,8 @@ def test_a_traced_step_carries_the_scopes_and_sets_the_gauges():
         params, {"tokens": tokens}).as_text(debug_info=True)
     assert "block_0/ssm" in text and "bagua.moe/shared" in text
     assert counters.get("ssm/layers") == 2
+    # the CPU: the rows between the projections are the jax.numpy form
+    assert counters.get("ssm/row_kernel_layers") == 0
     assert counters.get("ssm/chunk") == CHUNK
     assert (counters.get("ssm/heads"), counters.get("ssm/head_dim"),
             counters.get("ssm/groups"), counters.get("ssm/state")) == (
@@ -830,3 +837,33 @@ def test_a_traced_step_carries_the_scopes_and_sets_the_gauges():
     assert counters.get("attn/full_layers") == 1
     assert counters.get("attn/kv_heads") == KV_HEADS
     assert counters.get("attn/rope_kernel_layers") == 0
+
+
+def test_the_gauge_counts_the_cells_four_layers_on_the_row_passes(
+        monkeypatch):
+    """At the cell's widths and pattern (``MEMEM*EME``: four Mamba-2 blocks
+    of nine) with the predicate forced, every state-space layer runs the
+    passes of ``ops/ssd_rows.py``: ``ssm/row_kernel_layers`` 4 beside
+    ``ssm/layers`` 4; off the TPU, or at a length no row block divides, 0."""
+    from bagua_tpu.ops import ssd_rows
+
+    model, _ = nemotron(
+        pattern="MEMEM*EME", dtype=jnp.bfloat16, max_seq_len=256,
+        ssm_heads=64, ssm_head_dim=64, ssm_groups=8, ssm_state=128,
+        ssm_chunk=128)
+    tokens = jnp.zeros((1, 257), jnp.int32)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0),
+                            tokens[:, :-1])["params"]
+    # a fresh function a trace: ``eval_shape`` keeps the traces it made
+    trace = lambda tokens: jax.eval_shape(
+        lambda params, batch: lm_loss_fn(model)(params, batch), params,
+        {"tokens": tokens})
+    trace(tokens)
+    assert (counters.get("ssm/layers"),
+            counters.get("ssm/row_kernel_layers")) == (4, 0)
+    monkeypatch.setattr(ssd_rows, "_on_tpu", lambda: True)
+    trace(tokens)
+    assert (counters.get("ssm/layers"),
+            counters.get("ssm/row_kernel_layers")) == (4, 4)
+    trace(tokens[:, :201])
+    assert counters.get("ssm/row_kernel_layers") == 0

@@ -750,6 +750,32 @@ def test_the_gauge_counts_the_layers_whose_head_norm_rides_the_pass(
         rotary * forced, normed * forced)
 
 
+#: the chunked scans and the row passes around them: file -> its calls
+SCAN_FILES = {
+    "bagua_tpu/ops/gated_delta.py": ["gdn_fwd", "gdn_bwd"],
+    "bagua_tpu/ops/gated_delta_rows.py": ["gdn_mix", "gdn_mix_bwd",
+                                          "gdn_gate", "gdn_gate_bwd"],
+    "bagua_tpu/ops/ssd.py": ["ssd_fwd", "ssd_bwd"],
+    "bagua_tpu/ops/ssd_rows.py": ["ssd_mix", "ssd_mix_bwd", "ssd_gate",
+                                  "ssd_gate_bwd"],
+}
+
+
+@pytest.mark.parametrize("path", list(SCAN_FILES))
+def test_the_scans_and_their_row_passes_are_named_apart(path):
+    """Literal names, and each of them once across every file of the
+    package that holds a ``pallas_call``: ``perfbench/scopes.py::kernel_of``
+    matches a call's exact last path element, so ``ssd_mix`` must not be
+    ``ssd_fwd`` and no second file may write an ``ssd_fwd``."""
+    names = pallas_call_names(path)
+    assert names == SCAN_FILES[path]
+    everywhere = [
+        n for p in (ROOT / "bagua_tpu").rglob("*.py")
+        if "pallas_call(" in p.read_text()
+        for n in pallas_call_names(str(p.relative_to(ROOT)))]
+    assert [everywhere.count(n) for n in names] == [1] * len(names)
+
+
 def test_no_pallas_call_outside_the_named_files():
     for path in (ROOT / "bagua_tpu").rglob("*.py"):
         rel = str(path.relative_to(ROOT))
