@@ -1,14 +1,17 @@
 """Gated DeltaNet — the linear-attention token mixer of the hybrid decoders
-(Qwen3-Next: three layers of four), a drop-in for ``Attention`` inside
-:class:`bagua_tpu.models.transformer.Block` where the configuration's
+(Qwen3-Next, Olmo-Hybrid: three layers of four), a drop-in for ``Attention``
+inside :class:`bagua_tpu.models.transformer.Block` where the configuration's
 ``mixer_layers`` pattern says so.
 
-From the block's normed input ``u``:
+From the block's input ``u`` (normed, or — Olmo-Hybrid's trunk norms a
+sub-layer's output instead — as it comes):
 
     [q, k, v, z] = u W_qkvz                  one fused projection
     [b, a]       = u W_ba                    two scalars a value head, float32
     [q, k, v]    = silu(conv(q, k, v))       causal, depthwise, no bias
-    beta = sigmoid(b);  g = -exp(A_log) * softplus(a + dt_bias)
+    beta = sigmoid(b)  (``linear_neg_eigval``: 2 sigmoid(b), a write strength
+                        in (0, 2), the transition's eigenvalues in (-1, 1))
+    g    = -exp(A_log) * softplus(a + dt_bias)
     q, k L2-normalised a head, q scaled by d_k^-1/2
     o    = gated_delta_rule(q, k, v, g, beta)          (ops/gated_delta.py)
     y    = w_n * o / rms(o) * silu(z)        a value head's lanes, plain scale
@@ -25,13 +28,16 @@ tensor parallel form yet (the callers refuse those).
 
 Which path runs where.  The two projections are ``nn.Dense`` everywhere.
 Between them, on a TPU and where their grids cover the shape
-(:func:`rows_by_kernel`: heads of whole 128-lane tiles, a sequence of whole
-128-row blocks, bfloat16 or float32), the rows are Pallas passes on the
+(:func:`rows_by_kernel`: heads that are whole 128-lane tiles alone — 128 x
+128 — or in blocks of up to four — 96-lane keys under 192-lane values, any
+head count —, a sequence of whole 128-row blocks, bfloat16 or float32), the
+rows are Pallas passes on the
 projection's own buffer around the ``gdn_fwd`` / ``gdn_bwd`` kernels
 (``ops/gated_delta_rows.py::gated_delta_rows``: ``gdn_mix`` for lines three
 and five above, ``gdn_gate`` for the seventh, float32 from the load to one
 rounding at the store, one VJP that writes the buffer's cotangent where it
-lands).  Everywhere else — the CPU, a ragged sequence, narrow heads — they
+lands).  Everywhere else — the CPU, a ragged sequence, heads of which more
+than four make whole tiles (16-lane keys) — they
 are the ``jax.numpy`` code of this file (:func:`mix_rows`,
 :func:`gate_rows`) around ``ops.gated_delta.gated_delta_rule``, which picks
 between its kernels and its own ``jax.numpy`` chunks by itself; that code is
@@ -157,7 +163,9 @@ def rows_by_kernel(cfg, seq: int) -> bool:
     """Whether a layer of ``cfg`` over ``seq`` positions runs its rows
     between the two projections as the Pallas passes of
     ``ops/gated_delta_rows.py`` (on a TPU, where their grids cover the
-    shape) and not as :func:`mix_rows` / :func:`gate_rows`."""
+    shape: heads of whole 128-lane tiles alone or in blocks of up to four —
+    128 x 128, 96 x 192 —, a sequence of whole 128-row blocks) and not as
+    :func:`mix_rows` / :func:`gate_rows`."""
     from ..ops.gated_delta_rows import rows_supported
 
     return rows_supported(seq, _dims(cfg), cfg.linear_conv, cfg.dtype)
@@ -184,7 +192,10 @@ class GatedDeltaNet(nn.Module):
             raise ValueError(
                 "mixer_layers names linear-attention layers: they need "
                 "linear_key_heads, linear_value_heads (a multiple of the key "
-                "heads), linear_key_dim, linear_value_dim and linear_conv; "
+                "heads), linear_key_dim, linear_value_dim and linear_conv "
+                "(any positive widths: the kernels take heads that are "
+                "whole 128-lane tiles alone or in blocks of up to four, 128 "
+                "x 128 as 96 x 192, the jax.numpy form every other); "
                 f"got {hk} / {hv} / {dk} / {dv} / {cfg.linear_conv}")
         b, s, _ = x.shape
         key_width, value_width = hk * dk, hv * dv
@@ -206,6 +217,8 @@ class GatedDeltaNet(nn.Module):
                                 cfg.param_dtype)
 
         beta = jax.nn.sigmoid(ba[..., :hv])
+        if cfg.linear_neg_eigval:
+            beta = 2.0 * beta
         g = -jnp.exp(a_log.astype(jnp.float32)) * jax.nn.softplus(
             ba[..., hv:] + dt_bias.astype(jnp.float32))
         if rows_by_kernel(cfg, s):

@@ -134,6 +134,11 @@ class TransformerConfig:
     #: a second RMSNorm behind each sub-layer, on its output before the
     #: residual add ("sandwich" norm): ``x + N'(Attn(N(x)))``
     post_norms: bool = False
+    #: the RMSNorm in FRONT of each sub-layer.  Off together with
+    #: ``post_norms``, a block norms a sub-layer's output only: ``x +
+    #: N(Mixer(x))``, ``x + N(MLP(x))`` (the Olmo 2 / 3 trunk's reordered
+    #: norm); a block without any norm is refused
+    pre_norms: bool = True
     #: the model ends every pass in a head and an exit gate (``exit_gate``:
     #: ``Dense(1)`` with bias, float32 like the routers, on the pass's normed
     #: state) and hands back what ``looped_lm_loss_fn`` weighs: ``(logits
@@ -168,6 +173,10 @@ class TransformerConfig:
     linear_key_dim: int = 0
     linear_value_dim: int = 0
     linear_conv: int = 4
+    #: the delta rule's write strength is ``2 sigmoid(b)`` in (0, 2) and not
+    #: ``sigmoid(b)``: a state's transition ``I - beta k k^T`` then has
+    #: eigenvalues in (-1, 1) (``linear_allow_neg_eigval``)
+    linear_neg_eigval: bool = False
     #: softmax attention's output gate: the q projection is twice as wide, a
     #: head's second ``head_dim`` outputs are its gate, and the attention's
     #: output is multiplied by their sigmoid before the out-projection
@@ -751,28 +760,32 @@ class Block(nn.Module):
     def __call__(self, x, slots=None):
         cfg = self.cfg
         block_in = x
+        if not (cfg.pre_norms or cfg.post_norms):
+            raise ValueError("a block needs pre_norms, post_norms or both")
         norm = lambda name: RMSNorm(cfg.dtype, cfg.param_dtype, cfg.norm_eps,
                                     cfg.norm_zero_centered, name=name)
+        # a sub-layer's input through its norm, and its output through its
+        # own, where the configuration has each
+        pre = lambda name, t: norm(name)(t) if cfg.pre_norms else t
+        post = lambda name, t: norm(name)(t) if cfg.post_norms else t
         if cfg.layer_linear(self.layer):
-            # the layer's mixer is linear attention; module and norm carry
+            # the layer's mixer is linear attention; module and norms carry
             # names of their own (area ``linattn``)
             from .linear_attention import GatedDeltaNet
 
             _check_linear_attention(cfg, slots)
-            y = norm("linear_attn_norm")(x)
-            attn = GatedDeltaNet(cfg, name="linear_attn")
+            mixer = "linear_attn"
+            attn = GatedDeltaNet(cfg, name=mixer)
         else:
-            y = norm("attn_norm")(x)
+            mixer = "attn"
             attn = Attention(cfg, self.attn_fn, cfg.layer_window(self.layer),
-                             cfg.layer_rotary(self.layer), name="attn")
-        # the sub-layer's output through its own norm where the
-        # configuration has one
-        post = lambda name, t: norm(name)(t) if cfg.post_norms else t
+                             cfg.layer_rotary(self.layer), name=mixer)
+        y = pre(f"{mixer}_norm", x)
         # dense/training call sites keep their exact one-arg form (the
         # goldens pin those programs); only paged decode threads slots
-        x = x + post("attn_post_norm",
+        x = x + post(f"{mixer}_post_norm",
                      attn(y) if slots is None else attn(y, slots))
-        y = norm("mlp_norm")(x)
+        y = pre("mlp_norm", x)
         mlp = self.mlp() if self.mlp is not None else MLPBlock(cfg, name="mlp")
         if cfg.route_before_attention:
             if self.mlp is None:
@@ -945,6 +958,10 @@ class TransformerLM(nn.Module):
                 counters.set_gauge("linattn/key_heads", cfg.linear_key_heads)
                 counters.set_gauge("linattn/value_heads",
                                    cfg.linear_value_heads)
+                counters.set_gauge("linattn/key_dim", cfg.linear_key_dim)
+                counters.set_gauge("linattn/value_dim", cfg.linear_value_dim)
+                counters.set_gauge("linattn/neg_eigval",
+                                   int(cfg.linear_neg_eigval))
             if cfg.layer_kinds is not None:
                 ssm = cfg.layer_kinds.count("ssm")
                 counters.set_gauge("ssm/layers", ssm)

@@ -226,6 +226,16 @@ _declare("linattn/key_heads", "gauge",
 _declare("linattn/value_heads", "gauge",
          "Value heads of those layers (a key head serves value_heads / "
          "key_heads of them): one [d_k, d_v] float32 state each.")
+_declare("linattn/key_dim", "gauge",
+         "Lanes of a key head of those layers (d_k: the state's rows).")
+_declare("linattn/value_dim", "gauge",
+         "Lanes of a value head of those layers (d_v: the state's "
+         "columns).  Heads that are no whole 128-lane tile (96 / 192) run "
+         "the kernels in blocks of up to four heads.")
+_declare("linattn/neg_eigval", "gauge",
+         "1 where those layers' write strength is 2 sigmoid(b), in (0, 2) "
+         "(TransformerConfig.linear_neg_eigval: a transition's eigenvalues "
+         "in (-1, 1)); 0 where it is sigmoid(b).")
 _declare("linattn/row_kernel_layers", "gauge",
          "Of those layers, the ones whose rows between the two projections "
          "(convolution, SiLU, the L2 norms; the gated norm) are the Pallas "

@@ -463,6 +463,7 @@ AREA_COMPONENTS = {
     DIFFUSION_INPUT_SCOPE: "embed",
     "attn_norm": "attn", "attn": "attn", "attn_post_norm": "attn",
     "linear_attn_norm": "linattn", "linear_attn": "linattn",
+    "linear_attn_post_norm": "linattn",
     "ssm_norm": "ssm", "ssm": "ssm",
     "mlp_norm": "mlp", "mlp": "mlp", "mlp_post_norm": "mlp",
     "final_norm": "head", "lm_head": "head", LOSS_TAIL_SCOPE: "head",

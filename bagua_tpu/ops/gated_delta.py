@@ -1,11 +1,13 @@
 """Gated delta rule — the linear-attention recurrence of Gated DeltaNet
-(Qwen3-Next's three layers of four) in its chunked form, forward and
-backward, as two Pallas TPU kernels (``gdn_fwd`` / ``gdn_bwd``), the same
-mathematics in ``jax.numpy`` where the kernels do not run, and the per-token
-scan as golden.
+(three layers of four of Qwen3-Next and of Olmo-Hybrid) in its chunked form,
+forward and backward, as two Pallas TPU kernels (``gdn_fwd`` / ``gdn_bwd``),
+the same mathematics in ``jax.numpy`` where the kernels do not run, and the
+per-token scan as golden.
 
 Per value head, with a ``[d_k, d_v]`` state ``S`` (``S_0 = 0``), a decay
-``alpha_t = exp(g_t)`` (``g_t <= 0``) and a write strength ``beta_t``:
+``alpha_t = exp(g_t)`` (``g_t <= 0``) and a write strength ``beta_t`` (in (0,
+1) as ``sigmoid(b)``, in (0, 2) as Olmo-Hybrid's ``2 sigmoid(b)``, whose
+transitions have eigenvalues down to -1):
 
     S_t = alpha_t S_{t-1} + beta_t k_t (v_t - alpha_t S_{t-1}^T k_t)^T
     o_t = S_t^T q_t
@@ -42,18 +44,31 @@ form — ``C - 1`` steps ``T -= A[:, j] T[j, :]`` on the rows below ``j`` of a
 inside the kernels, where ``A`` never leaves VMEM.  A Neumann series (``(I - A)(I + A^2)(I + A^4)..``)
 would be all matrix products but loses every digit on strongly correlated
 keys (``A^k`` grows binomially while the inverse stays O(1)); substitution
-is backward-stable.
+is backward-stable.  At write strengths near 2 ``A``'s entries double and
+the inverse's are of size 2 with alternating signs all over the chunk:
+substitution still holds the scan's numbers there
+(``tests/test_qwen3_next.py``'s ``test_the_solve_survives_keys_that_are_
+nearly_one_vector``, ``tests/test_olmo_hybrid.py::test_the_rule_holds_the_
+probe``, and on the chip the fourth comparison of the Olmo-Hybrid cell).
 
-Kernels: a grid over (batch, key head, blocks of 8 chunks); a grid step
-walks its chunks in order for each of the key head's value heads (their
+Kernels: a grid over (batch, block of key heads, blocks of 8 chunks); a grid
+step walks its chunks in order for each value head of its key heads (their
 chains are independent: the scheduler interleaves them) with the states in
 VMEM scratch across the steps of a sequence.  q / k / v / o are read and
 written as ``[batch, seq, heads * dim]``, the projections' own layout; the
-per-position scalars as ``[batch, value_heads, chunks, C]`` rows.
+per-position scalars as ``[batch, value_heads, chunks, C]`` rows.  A
+``BlockSpec`` addresses the lanes of such an array by whole 128-lane tiles,
+so a block of key heads is the fewest whose lanes (and their value heads')
+are whole tiles (:func:`heads_per_block`): one head of 128 lanes, four of 96
+under values of 192 — three tiles of keys, six of values — with each head a
+static lane slice inside the block; a head count that is no multiple (30 =
+7 x 4 + 2) ends in a ragged block whose absent heads are skipped.  No row is
+copied or padded in HBM for it and no width is widened.
 
 Which path runs where: :func:`gated_delta_rule` runs the kernels where
-:func:`gated_delta_supported` says so (a TPU, heads of whole lane tiles) and
-the ``jax.numpy`` chunks elsewhere.  Where the layer's rows around the rule
+:func:`gated_delta_supported` says so (a TPU, heads that are whole lane
+tiles alone or in blocks of up to four) and the ``jax.numpy`` chunks
+elsewhere.  Where the layer's rows around the rule
 are Pallas passes too, ``ops/gated_delta_rows.py`` calls the rule's forward
 and backward (:func:`_forward`, :func:`_gated_delta_bwd`) from inside its own
 VJP, always by the kernels: the calls and their operands are the same.
@@ -62,6 +77,7 @@ VJP, always by the kernels: the calls and their operands are the same.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -338,21 +354,39 @@ def _jnp_bwd(q, k, v, gamma, beta, states, do, c):
 # ---------------------------------------------------------------------------
 
 
+def _heads_of_block(body, per_block, key_heads):
+    """``chunk(i, rows)`` that runs ``body(ki, i, rows)`` for each key head
+    of this grid step's block of heads that exists: the last block of a head
+    count that is no multiple of the block is ragged."""
+    first = pl.program_id(1) * per_block    # (read outside the chunks' loop)
+
+    def chunk(i, rows):
+        for ki in range(per_block):
+            if key_heads % per_block:
+                pl.when(first + ki < key_heads)(
+                    functools.partial(body, ki, i, rows))
+            else:
+                body(ki, i, rows)
+
+    return chunk
+
+
 def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, c, group,
-                d_v):
-    """A block of chunks of one key head's value heads, in order."""
+                d_k, d_v, key_heads):
+    """A block of chunks of a block of key heads' value heads, in order."""
     states_ref = rest[0] if len(rest) == 2 else None
     state_ref = rest[-1]
+    per_block = q_ref.shape[-1] // d_k
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         state_ref[...] = jnp.zeros_like(state_ref)
 
-    def chunk(i, carry):
-        rows = pl.ds(pl.multiple_of(i * c, c), c)
-        q, k = q_ref[0, rows, :], k_ref[0, rows, :]
+    def key_head(ki, i, rows):
+        at = slice(ki * d_k, (ki + 1) * d_k)
+        q, k = q_ref[0, rows, at], k_ref[0, rows, at]
         products = _key_products(q, k)
-        for gi in range(group):
+        for gi in range(ki * group, (ki + 1) * group):
             lanes = slice(gi * d_v, (gi + 1) * d_v)
             state = state_ref[gi]
             if states_ref is not None:
@@ -361,6 +395,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, c, group,
                 q, k, v_ref[0, rows, lanes], g_ref[0, gi, pl.ds(i, 1), :],
                 b_ref[0, gi, pl.ds(i, 1), :], state, products)
             o_ref[0, rows, lanes] = o.astype(o_ref.dtype)
+
+    heads = _heads_of_block(key_head, per_block, key_heads)
+
+    def chunk(i, carry):
+        heads(i, pl.ds(pl.multiple_of(i * c, c), c))
         return carry
 
     lax.fori_loop(0, g_ref.shape[2], chunk, 0)
@@ -368,9 +407,10 @@ def _fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, o_ref, *rest, c, group,
 
 def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
                 dk_ref, dv_ref, dg_ref, db_ref, d_state_ref, *, c, group,
-                d_v):
+                d_k, d_v, key_heads):
     """The same block, its chunks from the last to the first; the grid
     walks the blocks from the last to the first too."""
+    per_block = q_ref.shape[-1] // d_k
 
     @pl.when(pl.program_id(2) == 0)
     def _():
@@ -378,13 +418,12 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
 
     n = g_ref.shape[2]
 
-    def chunk(step, carry):
-        i = n - 1 - step
-        rows = pl.ds(pl.multiple_of(i * c, c), c)
-        q, k = q_ref[0, rows, :], k_ref[0, rows, :]
+    def key_head(ki, i, rows):
+        at = slice(ki * d_k, (ki + 1) * d_k)
+        q, k = q_ref[0, rows, at], k_ref[0, rows, at]
         products = _key_products(q, k)
         dq = dk = 0.0
-        for gi in range(group):
+        for gi in range(ki * group, (ki + 1) * group):
             lanes = slice(gi * d_v, (gi + 1) * d_v)
             dq_g, dk_g, dv, d_g, d_b, d_state_ref[gi] = _chunk_bwd(
                 q, k, v_ref[0, rows, lanes], g_ref[0, gi, pl.ds(i, 1), :],
@@ -394,25 +433,45 @@ def _bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, s_ref, do_ref, dq_ref,
             dv_ref[0, rows, lanes] = dv.astype(dv_ref.dtype)
             dg_ref[0, gi, pl.ds(i, 1), :] = d_g
             db_ref[0, gi, pl.ds(i, 1), :] = d_b
-        dq_ref[0, rows, :] = dq.astype(dq_ref.dtype)
-        dk_ref[0, rows, :] = dk.astype(dk_ref.dtype)
+        dq_ref[0, rows, at] = dq.astype(dq_ref.dtype)
+        dk_ref[0, rows, at] = dk.astype(dk_ref.dtype)
+
+    heads = _heads_of_block(key_head, per_block, key_heads)
+
+    def chunk(step, carry):
+        i = n - 1 - step
+        heads(i, pl.ds(pl.multiple_of(i * c, c), c))
         return carry
 
     lax.fori_loop(0, n, chunk, 0)
 
 
+def heads_per_block(d_k: int, d_v: int, group: int) -> int:
+    """Key heads a grid step takes: the fewest whose lanes (and their value
+    heads') are whole 128-lane tiles — one where a head is, four at 96-lane
+    keys under 192-lane values.  A ``BlockSpec`` addresses ``[b, T, heads *
+    dim]`` by whole tiles; inside the block a head is a static lane slice."""
+    whole = lambda width: LANE // math.gcd(width, LANE)
+    return math.lcm(whole(d_k), whole(group * d_v))
+
+
 def _specs(block_chunks, c, d_k, d_v, group, blocks, reverse):
     """``BlockSpec``s over [b, T, heads * dim] tensors, the [b, hv, chunks,
-    C] scalars and the [b, hv, chunks, dk, dv] states, for grid (batch, key
-    head, block of chunks)."""
+    C] scalars and the [b, hv, chunks, dk, dv] states, for grid (batch, block
+    of key heads, block of chunks).  Where the key heads are no whole number
+    of blocks the last block is ragged: what it reads past the heads is not
+    used, what it writes there is dropped."""
     at = (lambda n: blocks - 1 - n) if reverse else (lambda n: n)
     rows = block_chunks * c
-    key = pl.BlockSpec((1, rows, d_k), lambda b, h, n: (b, at(n), h))
-    value = pl.BlockSpec((1, rows, group * d_v),
+    per_block = heads_per_block(d_k, d_v, group)
+    values = per_block * group
+    key = pl.BlockSpec((1, rows, per_block * d_k),
+                       lambda b, h, n: (b, at(n), h))
+    value = pl.BlockSpec((1, rows, values * d_v),
                          lambda b, h, n: (b, at(n), h))
-    scalar = pl.BlockSpec((1, group, block_chunks, c),
+    scalar = pl.BlockSpec((1, values, block_chunks, c),
                           lambda b, h, n: (b, h, at(n), 0))
-    states = pl.BlockSpec((1, group, block_chunks, d_k, d_v),
+    states = pl.BlockSpec((1, values, block_chunks, d_k, d_v),
                           lambda b, h, n: (b, h, at(n), 0, 0))
     return key, value, scalar, states
 
@@ -428,6 +487,18 @@ def _params():
     return pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"),
         vmem_limit_bytes=_vmem_limit())
+
+
+def _grid(b, key_heads, d_k, d_v, group, blocks):
+    """What the two calls share beside their specs: the grid (batch, blocks
+    of key heads, blocks of chunks), the states' scratch of a block of heads
+    and the compiler's parameters."""
+    per_block = heads_per_block(d_k, d_v, group)
+    return dict(
+        grid=(b, -(-key_heads // per_block), blocks),
+        scratch_shapes=[pltpu.VMEM((per_block * group, d_k, d_v),
+                                   jnp.float32)],
+        compiler_params=_params())
 
 
 # jitted, as the flash kernels' wrappers are: the layers of a model share
@@ -448,12 +519,12 @@ def _kernel_fwd(q, k, v, gamma, beta, key_heads, block_chunks, keep_states,
                                               k.dtype))
         out_specs.append(states)
     out = pl.pallas_call(
-        functools.partial(_fwd_kernel, c=c, group=group, d_v=d_v),
-        grid=(b, key_heads, chunks // block_chunks),
+        functools.partial(_fwd_kernel, c=c, group=group, d_k=d_k, d_v=d_v,
+                          key_heads=key_heads),
         in_specs=[key, key, value, scalar, scalar],
-        out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=[pltpu.VMEM((group, d_k, d_v), jnp.float32)],
-        compiler_params=_params(), interpret=interpret, name="gdn_fwd",
+        out_specs=out_specs, out_shape=out_shape, interpret=interpret,
+        name="gdn_fwd",
+        **_grid(b, key_heads, d_k, d_v, group, chunks // block_chunks),
     )(q, k, v, gamma, beta)
     return tuple(out) if keep_states else (out[0], None)
 
@@ -466,8 +537,8 @@ def _kernel_bwd(q, k, v, gamma, beta, states, do, key_heads, block_chunks,
     key, value, scalar, state_spec = _specs(block_chunks, c, d_k, d_v, group,
                                             chunks // block_chunks, True)
     return pl.pallas_call(
-        functools.partial(_bwd_kernel, c=c, group=group, d_v=d_v),
-        grid=(b, key_heads, chunks // block_chunks),
+        functools.partial(_bwd_kernel, c=c, group=group, d_k=d_k, d_v=d_v,
+                          key_heads=key_heads),
         in_specs=[key, key, value, scalar, scalar, state_spec, value],
         out_specs=[key, key, value, scalar, scalar],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
@@ -475,8 +546,8 @@ def _kernel_bwd(q, k, v, gamma, beta, states, do, key_heads, block_chunks,
                    jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(gamma.shape, jnp.float32),
                    jax.ShapeDtypeStruct(gamma.shape, jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((group, d_k, d_v), jnp.float32)],
-        compiler_params=_params(), interpret=interpret, name="gdn_bwd",
+        interpret=interpret, name="gdn_bwd",
+        **_grid(b, key_heads, d_k, d_v, group, chunks // block_chunks),
     )(q, k, v, gamma, beta, states, do)
 
 
@@ -485,17 +556,35 @@ def _kernel_bwd(q, k, v, gamma, beta, states, do, key_heads, block_chunks,
 # ---------------------------------------------------------------------------
 
 
-def gated_delta_supported(key_heads: int, value_heads: int, d_k: int,
-                          d_v: int, dtype=jnp.bfloat16) -> bool:
-    """Whether the kernels take the call: on a TPU, heads of whole 128-lane
-    tiles (a head is a ``BlockSpec``'s lanes of ``[b, T, heads * dim]``),
-    value heads a multiple of the key heads, bfloat16 or float32 operands.
-    Any sequence length: the rows are padded to whole blocks of chunks."""
-    return (jax.default_backend() == "tpu"
-            and d_k % LANE == 0 and d_v % LANE == 0
-            and value_heads % key_heads == 0
+#: key heads a grid step unrolls, at most: what :func:`heads_per_block` may
+#: ask for (four at 96-lane keys; a narrower head would ask for eight or more
+#: bodies a chunk and takes the ``jax.numpy`` chunks)
+MAX_HEADS_PER_BLOCK = 4
+
+
+def gated_delta_covered(key_heads: int, value_heads: int, d_k: int,
+                        d_v: int, dtype=jnp.bfloat16) -> bool:
+    """Whether the kernels' grid covers the heads: value heads a multiple of
+    the key heads, bfloat16 or float32 operands, and heads of which at most
+    ``MAX_HEADS_PER_BLOCK`` make whole 128-lane tiles — 128-lane heads one
+    by one, 96-lane keys under 192-lane values four to a block (the last
+    block ragged where the head count is no multiple: 30 heads are seven
+    blocks and a half).  Any head count, any sequence length (the rows are
+    padded to whole blocks of chunks)."""
+    return (value_heads % key_heads == 0 and d_k % 8 == 0
+            and heads_per_block(d_k, d_v, value_heads // key_heads)
+            <= MAX_HEADS_PER_BLOCK
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32)))
+
+
+def gated_delta_supported(key_heads: int, value_heads: int, d_k: int,
+                          d_v: int, dtype=jnp.bfloat16) -> bool:
+    """Whether the kernels take the call: on a TPU, heads that
+    :func:`gated_delta_covered` (whole lane tiles alone or in blocks of up
+    to four: 128 x 128 and 96 x 192 do, 16-lane keys do not)."""
+    return (jax.default_backend() == "tpu" and gated_delta_covered(
+        key_heads, value_heads, d_k, d_v, dtype))
 
 
 def _padded_chunks(seq: int, c: int) -> int:
@@ -606,9 +695,8 @@ def gated_delta_rule(q, k, v, g, beta, *, chunk: int = CHUNK,
     covers still take the ``jax.numpy`` form."""
     hk, d_k = q.shape[2:]
     hv, d_v = v.shape[2:]
-    covered = (d_k % LANE == 0 and d_v % LANE == 0 and hv % hk == 0
-               and k.dtype == v.dtype == q.dtype)
-    by_kernel = covered and (force or gated_delta_supported(
-        hk, hv, d_k, d_v, k.dtype))
+    covered = (k.dtype == v.dtype == q.dtype
+               and gated_delta_covered(hk, hv, d_k, d_v, k.dtype))
+    by_kernel = covered and (force or jax.default_backend() == "tpu")
     return _gated_delta(q, k, v, g, beta, int(chunk), bool(by_kernel),
                         bool(interpret))

@@ -6,7 +6,16 @@ dv]`` buffer (columns ``[q | k | v | z]``, ``models/linear_attention.py``):
   columns — the L2 norm a head (q also scaled by ``d_k^-1/2``), one call a
   part, each reading its columns of the buffer where they lie (the
   ``BlockSpec``'s lane-block index: no slice is copied) and writing the
-  ``[b, s, heads * dim]`` array the ``gdn_fwd`` kernel reads;
+  ``[b, s, heads * dim]`` array the ``gdn_fwd`` kernel reads.  A column
+  block is whole heads and whole 128-lane tiles (384 lanes at 96- or
+  192-lane heads), a head a static lane slice inside it.  Where the k
+  columns start inside such a block (Olmo-Hybrid: 30 heads of 96 lanes are
+  7.5 blocks) no block index addresses them alone, and q | k go through as
+  ONE part whose heads are scaled in front of the k columns only
+  (:func:`_merged`); its two halves are then sliced apart for the rule's
+  kernels and the two cotangents concatenated for ``gdn_mix_bwd`` — the one
+  copy of rows the unaligned start costs (47 MB a layer and pass at 8,192
+  rows), unpadded;
 - ``gdn_gate``: ``y = w_n * o / rms(o) * silu(z)`` a value head, ``z`` read
   from the buffer's last columns in place;
 - ``gdn_mix_bwd`` / ``gdn_gate_bwd``: their transposes, which recompute the
@@ -77,18 +86,36 @@ def _widths(dims):
     return hk * dk, hv * dv
 
 
+def _unit(head: int) -> int:
+    """Lanes of the narrowest column block of heads of ``head`` lanes: whole
+    heads and whole 128-lane tiles (a head of whole tiles by itself, 384
+    lanes at 96- or 192-lane heads)."""
+    return math.lcm(head, LANE)
+
+
+def _merged(dims) -> bool:
+    """Whether q and k go through ``gdn_mix`` as ONE part: where the k
+    columns do not start at a whole block of key heads (30 heads of 96 lanes
+    end half a 384-lane block in), no ``BlockSpec`` addresses them alone; q |
+    k together, ``2 hk dk`` lanes from column 0, are whole blocks."""
+    hk, _, dk, _ = dims
+    return (hk * dk) % _unit(dk) != 0
+
+
 def rows_covered(seq: int, dims, taps: int, dtype) -> bool:
-    """Whether the passes' grids cover the layer: heads of whole 128-lane
-    tiles, a sequence of whole row blocks, the v and z columns starting at a
-    whole value head (a part's columns are addressed by lane-block index), a
-    convolution that reaches no further than a tile of rows, bfloat16 or
-    float32 rows."""
+    """Whether the passes' grids cover the layer: heads the rule's kernels
+    cover (``gated_delta_covered``: whole 128-lane tiles alone or in blocks
+    of up to four, 128 x 128 as 96 x 192), a sequence of whole row blocks,
+    the q | k columns and the v columns each whole blocks of their heads
+    with v and z starting at a whole block of value heads (a part's columns
+    are addressed by lane-block index; inside a block a head is a static
+    lane slice), a convolution that reaches no further than a tile of rows,
+    bfloat16 or float32 rows."""
     hk, hv, dk, dv = dims
-    return (dk % LANE == 0 and dv % LANE == 0 and hv % hk == 0
-            and seq % _CANDIDATES[-1] == 0 and (2 * hk * dk) % dv == 0
-            and 1 <= taps <= _TILE + 1
-            and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
-                                     jnp.dtype(jnp.float32)))
+    return (gd.gated_delta_covered(hk, hv, dk, dv, dtype)
+            and (2 * hk * dk) % _unit(dk) == 0
+            and (2 * hk * dk) % _unit(dv) == 0 and (hv * dv) % _unit(dv) == 0
+            and seq % _CANDIDATES[-1] == 0 and 1 <= taps <= _TILE + 1)
 
 
 def rows_supported(seq: int, dims, taps: int, dtype=jnp.bfloat16) -> bool:
@@ -99,16 +126,18 @@ def rows_supported(seq: int, dims, taps: int, dtype=jnp.bfloat16) -> bool:
 
 def _blocks(seq, width, first, head, itemsize, tensors, caps):
     """``(rows, lanes)`` of a part's blocks: the widest multiple of a head
-    that divides the part's ``width`` and its ``first`` column, the tallest
+    (and of a lane tile: :func:`_unit`) that divides the part's ``width``
+    and its ``first`` column, the tallest
     of ``ops/tiles.py``'s candidates that divides ``seq`` and whose buffers
     fit the scoped VMEM — ``tensors`` blocks in and out, double-buffered, and
     a head's float32 working set.  ``caps``: ``(rows, lanes)`` a test holds
     them under, or None."""
     cap_rows, cap_lanes = caps or (None, None)
     whole = math.gcd(width, first)
-    lanes = max(w for w in range(head, whole + 1, head)
+    unit = _unit(head)
+    lanes = max(w for w in range(unit, whole + 1, unit)
                 if whole % w == 0 and (w <= (cap_lanes or _LANE_CAP)
-                                       or w == head))
+                                       or w == unit))
     limit = _vmem_limit()
     fitting = [r for r in _CANDIDATES
                if seq % r == 0 and r <= (cap_rows or r)]
@@ -182,16 +211,27 @@ def _block_front(front_ref, lanes, at_start):
     return front * jnp.where(at_start, 0.0, 1.0)
 
 
+def _scale_at(scale, split, block, lo, width):
+    """A unit head's scale: ``scale`` everywhere, or — q | k as one part —
+    only on the columns in front of ``split`` (the q heads; a k head is
+    scaled by 1).  ``block``: the column block's index, ``lo``: the head's
+    first lane inside it."""
+    if split is None:
+        return scale
+    return jnp.where(block * width + lo < split, scale, 1.0)
+
+
 def _mix_kernel(x_ref, front_ref, taps_ref, o_ref, *, head, unit, scale,
-                eps):
+                split, eps):
     """A head at a time (static lanes), and down its rows in chunks that
     stay in registers: a chunk hands the next its last tile of rows."""
     at_start = pl.program_id(1) == 0
     for lo in range(0, x_ref.shape[-1], head):
         lanes = slice(lo, lo + head)
         taps = _head_taps(taps_ref, lanes)
+        by = _scale_at(scale, split, pl.program_id(2), lo, x_ref.shape[-1])
 
-        def chunk(i, front, lanes=lanes, taps=taps):
+        def chunk(i, front, lanes=lanes, taps=taps, scale=by):
             rows = _chunk_rows(i)
             x = x_ref[0, rows, lanes].astype(jnp.float32)
             c = sum(tap * _behind(x, front, k) for k, tap in enumerate(taps))
@@ -207,7 +247,7 @@ def _mix_kernel(x_ref, front_ref, taps_ref, o_ref, *, head, unit, scale,
 
 
 def _mix_bwd_kernel(g_ref, x_ref, front_ref, taps_ref, _, dx_ref, dt_ref,
-                    carry_ref, *, head, unit, scale, eps):
+                    carry_ref, *, head, unit, scale, split, eps):
     """A sequence's row blocks from the last to the first, and a block's
     chunks of rows the same way: ``carry_ref`` holds the convolution's
     cotangent on the first rows of the block behind this one, ``dt_ref`` the
@@ -225,8 +265,10 @@ def _mix_bwd_kernel(g_ref, x_ref, front_ref, taps_ref, _, dx_ref, dt_ref,
         lanes = slice(lo, lo + head)
         taps = _head_taps(taps_ref, lanes)
         block_front = _block_front(front_ref, lanes, at_start)
+        by = _scale_at(scale, split, pl.program_id(1), lo, x_ref.shape[-1])
 
-        def chunk(at, carry, lanes=lanes, taps=taps, block_front=block_front):
+        def chunk(at, carry, lanes=lanes, taps=taps, block_front=block_front,
+                  scale=by):
             back, sums = carry
             i = chunks - 1 - at
             rows = _chunk_rows(i)
@@ -269,8 +311,8 @@ def _front_rows(rows: int):
     return lambda r: jnp.maximum(r * (rows // _HALO) - 1, 0)
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(2, 10)))
-def _mix_part(qkvz, taps, first, width, head, unit, scale, eps, caps,
+@functools.partial(jax.jit, static_argnums=tuple(range(2, 11)))
+def _mix_part(qkvz, taps, first, width, head, unit, scale, split, eps, caps,
               interpret):
     """One part's columns ``first .. first + width`` of the buffer through
     ``gdn_mix``.  Jitted: the layers of a model share one trace."""
@@ -280,7 +322,7 @@ def _mix_part(qkvz, taps, first, width, head, unit, scale, eps, caps,
     at, front_of = first // w, _front_rows(rows)
     return pl.pallas_call(
         functools.partial(_mix_kernel, head=head, unit=unit, scale=scale,
-                          eps=eps),
+                          split=split, eps=eps),
         grid=(b, s // rows, width // w),
         in_specs=[
             pl.BlockSpec((1, rows, w), lambda i, r, j: (i, r, at + j)),
@@ -298,9 +340,9 @@ def _mix_part(qkvz, taps, first, width, head, unit, scale, eps, caps,
     )(qkvz, qkvz, taps)
 
 
-@functools.partial(jax.jit, static_argnums=tuple(range(4, 11)))
-def _mix_bwd_part(g, qkvz, taps, buffer, first, head, unit, scale, eps, caps,
-                  interpret):
+@functools.partial(jax.jit, static_argnums=tuple(range(4, 12)))
+def _mix_bwd_part(g, qkvz, taps, buffer, first, head, unit, scale, split, eps,
+                  caps, interpret):
     """``g`` [b, s, width]: the cotangent of one part of ``gdn_mix``'s
     result -> (``buffer`` with the part's columns filled, the part's taps'
     gradient [b, n, width] float32)."""
@@ -313,7 +355,7 @@ def _mix_bwd_part(g, qkvz, taps, buffer, first, head, unit, scale, eps, caps,
                             lambda i, j, r: (i, back(r), at + j))
     return pl.pallas_call(
         functools.partial(_mix_bwd_kernel, head=head, unit=unit, scale=scale,
-                          eps=eps),
+                          split=split, eps=eps),
         grid=(b, width // w, blocks),
         in_specs=[
             pl.BlockSpec((1, rows, w), lambda i, j, r: (i, back(r), j)),
@@ -338,21 +380,32 @@ def _mix_bwd_part(g, qkvz, taps, buffer, first, head, unit, scale, eps, caps,
 
 
 def _parts(dims, l2_eps):
-    """``(first column, width, head, unit, scale, eps)`` of q, k and v."""
+    """``(first column, width, head, unit, scale, split, eps)`` of q, k and
+    v — or of q | k and v where :func:`_merged`: one part whose heads are
+    scaled in front of column ``split`` only."""
     _, _, dk, dv = dims
     kw, vw = _widths(dims)
-    return ((0, kw, dk, True, dk ** -0.5, l2_eps),
-            (kw, kw, dk, True, 1.0, l2_eps),
-            (2 * kw, vw, dv, False, 1.0, l2_eps))
+    value = (2 * kw, vw, dv, False, 1.0, None, l2_eps)
+    if _merged(dims):
+        return (0, 2 * kw, dk, True, dk ** -0.5, kw, l2_eps), value
+    return ((0, kw, dk, True, dk ** -0.5, None, l2_eps),
+            (kw, kw, dk, True, 1.0, None, l2_eps), value)
 
 
 def mix(qkvz, taps, dims, *, l2_eps, interpret=False, caps=None):
     """``silu(conv(.))`` of the buffer's q | k | v columns, q and k
     L2-normalised a head and q scaled: ``qkvz`` [b, s, 2 hk dk + 2 hv dv],
     ``taps`` [n, 2 hk dk + hv dv], ``dims`` ``(hk, hv, dk, dv)`` -> ``(q, k
-    [b, s, hk dk], v [b, s, hv dv])`` in the buffer's dtype."""
-    return tuple(_mix_part(qkvz, taps, *part, caps, interpret)
-                 for part in _parts(dims, float(l2_eps)))
+    [b, s, hk dk], v [b, s, hv dv])`` in the buffer's dtype.  Where q | k
+    went through as one part, q and k are its two halves: slices, which the
+    rule's kernels need as arrays of their own (the k half starts inside a
+    lane tile)."""
+    *keys, v = (_mix_part(qkvz, taps, *part, caps, interpret)
+                for part in _parts(dims, float(l2_eps)))
+    if _merged(dims):
+        kw = _widths(dims)[0]
+        keys = keys[0][..., :kw], keys[0][..., kw:]
+    return (*keys, v)
 
 
 def mix_bwd(dq, dk, dv, qkvz, taps, buffer, dims, *, l2_eps, interpret=False,
@@ -360,9 +413,10 @@ def mix_bwd(dq, dk, dv, qkvz, taps, buffer, dims, *, l2_eps, interpret=False,
     """:func:`mix`'s transpose: ``buffer`` (like ``qkvz``; its z columns are
     kept as they come) with the q | k | v columns of the buffer's cotangent
     written into it, and the taps' gradient [n, 2 hk dk + hv dv] float32."""
+    cotangents = ((jnp.concatenate([dq, dk], axis=-1), dv) if _merged(dims)
+                  else (dq, dk, dv))
     d_taps = []
-    for g, (first, _, *part) in zip((dq, dk, dv), _parts(dims,
-                                                         float(l2_eps))):
+    for g, (first, _, *part) in zip(cotangents, _parts(dims, float(l2_eps))):
         buffer, part_taps = _mix_bwd_part(g, qkvz, taps, buffer, first, *part,
                                           caps, interpret)
         d_taps.append(part_taps.sum(axis=0))
@@ -547,9 +601,11 @@ def gated_delta_rows(qkvz, taps, g, beta, w_n, dims, *, l2_eps: float,
     ``qkvz.dtype``.  The caller gates on :func:`rows_supported`."""
     if not rows_covered(qkvz.shape[1], dims, taps.shape[0], qkvz.dtype):
         raise ValueError(
-            f"gated_delta_rows covers heads of whole 128-lane tiles and "
-            f"sequences of whole {_CANDIDATES[-1]}-row blocks in bfloat16 or "
-            f"float32 under at most {_TILE + 1} taps, not seq {qkvz.shape[1]} "
+            f"gated_delta_rows covers heads that are whole 128-lane tiles "
+            f"alone or in blocks of up to {gd.MAX_HEADS_PER_BLOCK} (128 x 128 "
+            f"or 96 x 192) and sequences of whole {_CANDIDATES[-1]}-row blocks "
+            f"in bfloat16 or float32 under at most {_TILE + 1} taps, not seq "
+            f"{qkvz.shape[1]} "
             f"x (hk, hv, dk, dv) {dims} x {taps.shape[0]} taps in "
             f"{qkvz.dtype}; it has no fallback")
     return _rows(qkvz, taps, g, beta, w_n, tuple(dims), float(l2_eps),

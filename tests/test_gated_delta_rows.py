@@ -37,6 +37,15 @@ CASES = {
     "wide-key-heads": (1, 256, (1, 1, 256, 128), 3, jnp.float32,
                        (128, None)),
     "bfloat16": (2, 256, (1, 2, 128, 128), 4, jnp.bfloat16, (128, 256)),
+    # heads that are no whole lane tile (Olmo-Hybrid's 96-lane keys under
+    # 192-lane values, a head count that is no multiple of four): q | k go
+    # through as one part whose middle block of 384 lanes holds two q heads
+    # and two k heads; one column block a part, and several
+    "heads-96-192": (2, 256, (6, 6, 96, 192), 4, jnp.float32, (128, 384)),
+    "heads-96-192-wide-blocks": (1, 384, (6, 6, 96, 192), 4, jnp.float32,
+                                 None),
+    "heads-96-192-bfloat16": (2, 128, (2, 2, 96, 192), 4, jnp.bfloat16,
+                              None),
 }
 QUANTITIES = ["q", "k", "v", "dx", "d_taps", "y", "do", "dz", "d_w_n"]
 #: float32 against float32 the difference is the order of the sums; in
@@ -146,7 +155,13 @@ def test_the_passes_take_whole_tiles_on_a_tpu(monkeypatch):
     assert rows.rows_supported(128, CELL, 9)     # eight rows' reach: a tile
     assert not rows.rows_supported(128, CELL, 10)
     assert not rows.rows_supported(4096 + 64, CELL, 4)   # no whole row block
-    assert not rows.rows_supported(4096, (16, 32, 64, 128), 4)
+    # heads that are whole tiles in blocks of up to four: Olmo-Hybrid's 30
+    # heads of 96 / 192, 64-lane keys two to a tile; not eight to a tile
+    assert rows.rows_supported(8192, (30, 30, 96, 192), 4)
+    assert rows.rows_supported(4096, (16, 32, 64, 128), 4)
+    assert not rows.rows_supported(4096, (16, 32, 16, 128), 4)
+    # 5 heads of 96: q | k together are no whole number of 384-lane blocks
+    assert not rows.rows_supported(4096, (5, 5, 96, 192), 4)
     assert not rows.rows_supported(4096, (16, 32, 128, 192), 4)
     assert not rows.rows_supported(4096, (16, 24, 128, 128), 4)
     # v would start inside a 384-lane head: no lane-block index reaches it

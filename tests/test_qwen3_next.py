@@ -338,22 +338,25 @@ def l2_normalize(x):
 
 
 def delta_inputs(seed, seq, key_heads, value_heads, dim, log_decay,
-                 dtype=jnp.float32, correlated=False):
+                 dtype=jnp.float32, correlated=False, strength=1.0):
     """q, k L2-normalised (k with a common component: the keys of a
     SiLU-activated projection are correlated), v, g with a median of
-    ``-exp(log_decay)``, beta, and a cotangent."""
+    ``-exp(log_decay)``, beta (``strength`` times a sigmoid: 2 is
+    Olmo-Hybrid's), and a cotangent.  ``dim``: a head's lanes, or ``(d_k,
+    d_v)`` where keys and values differ."""
+    d_k, d_v = dim if isinstance(dim, tuple) else (dim, dim)
     keys = jax.random.split(jax.random.PRNGKey(seed), 6)
-    q = l2_normalize(jax.random.normal(keys[0], (2, seq, key_heads, dim)))
-    k = jax.random.normal(keys[1], (2, seq, key_heads, dim))
+    q = l2_normalize(jax.random.normal(keys[0], (2, seq, key_heads, d_k)))
+    k = jax.random.normal(keys[1], (2, seq, key_heads, d_k))
     k = l2_normalize(k * (0.05 if correlated else 1.0) + 0.5)
-    v = jax.random.normal(keys[2], (2, seq, value_heads, dim))
+    v = jax.random.normal(keys[2], (2, seq, value_heads, d_v))
     g = -jnp.exp(jax.random.normal(keys[3], (2, seq, value_heads))
                  + log_decay)
-    beta = jax.nn.sigmoid(2 * jax.random.normal(keys[4],
-                                                (2, seq, value_heads))
-                          + (4.0 if correlated else 0.0))
-    do = jax.random.normal(keys[5], (2, seq, value_heads, dim))
-    return ((q / math.sqrt(dim)).astype(dtype), k.astype(dtype),
+    beta = strength * jax.nn.sigmoid(
+        2 * jax.random.normal(keys[4], (2, seq, value_heads))
+        + (4.0 if correlated else 0.0))
+    do = jax.random.normal(keys[5], (2, seq, value_heads, d_v))
+    return ((q / math.sqrt(d_k)).astype(dtype), k.astype(dtype),
             v.astype(dtype), g, beta), do
 
 
@@ -365,14 +368,18 @@ def value_and_cotangents(fn, args, do):
 #: (sequence, key heads, value heads, head width, log of the median decay
 #: rate): whole chunks; a ragged length of more than one block of eight
 #: chunks; ragged lengths with decays near 1 (exp(-7): alpha = 0.999) and
-#: near 0 (exp(3): alpha = e^-20); and, at width 32, a shape the kernels'
-#: grid does not cover
+#: near 0 (exp(3): alpha = e^-20); at width 16, a shape the kernels' grid
+#: does not cover (eight heads to a lane tile); and heads that are no whole lane tile (Olmo-Hybrid's
+#: 96-lane keys under 192-lane values, write strengths up to 2): six heads,
+#: a block of four and a ragged one of two, over a ragged length of more
+#: than one block of chunks
 DELTA_CASES = {
     "whole_chunks": (128, 1, 2, 128, -1.0),
     "ragged_blocks": (600, 1, 1, 128, 0.0),
     "hardly_decays": (100, 2, 2, 128, -7.0),
     "forgets_at_once": (100, 1, 2, 128, 3.0),
-    "narrow_heads": (80, 2, 4, 32, -1.0),
+    "narrow_heads": (80, 2, 4, 16, -1.0),
+    "heads_96_192_ragged_blocks": (600, 6, 6, (96, 192), -1.0),
 }
 NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
 
@@ -380,7 +387,8 @@ NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
 @pytest.fixture(scope="module", params=list(DELTA_CASES))
 def delta_case(request):
     seq, hk, hv, dim, log_decay = DELTA_CASES[request.param]
-    args, do = delta_inputs(3, seq, hk, hv, dim, log_decay)
+    args, do = delta_inputs(3, seq, hk, hv, dim, log_decay,
+                            strength=2.0 if isinstance(dim, tuple) else 1.0)
     with jax.default_matmul_precision("highest"):
         want = value_and_cotangents(gd.reference_gated_delta_rule, args, do)
         by_jnp = value_and_cotangents(
@@ -412,32 +420,46 @@ def test_the_kernels_are_what_the_forced_call_runs():
     assert "gdn_fwd" in text
     plain = str(jax.make_jaxpr(lambda *a: gd.gated_delta_rule(*a))(*args))
     assert "pallas_call" not in plain           # the CPU takes the jnp chunks
-    narrow, _ = delta_inputs(0, 64, 1, 2, 32, -1.0)
+    narrow, _ = delta_inputs(0, 64, 1, 2, 16, -1.0)
     assert "pallas_call" not in str(jax.make_jaxpr(
         lambda *a: gd.gated_delta_rule(*a, force=True, interpret=True))(
             *narrow))
 
 
-def test_the_solve_survives_keys_that_are_nearly_one_vector():
+@pytest.mark.parametrize("path,heads,dim,strength", [
+    ("jnp", 1, 128, 1.0), ("jnp", 1, (96, 192), 2.0),
+    ("kernel", 1, 128, 2.0), ("kernel", 6, (96, 192), 2.0)])
+def test_the_solve_survives_keys_that_are_nearly_one_vector(path, heads, dim,
+                                                            strength):
     """Keys all but equal, beta near 1, hardly any decay: ``I + A`` is close
     to the lower-triangular matrix of ones, whose powers grow binomially (a
     Neumann series for the inverse loses every digit) while the inverse
-    stays bidiagonal.  Forward substitution holds the scan's numbers."""
-    args, do = delta_inputs(5, 128, 1, 1, 128, -9.0, correlated=True)
+    stays bidiagonal.  Forward substitution holds the scan's numbers — also
+    at write strengths near 2 (Olmo-Hybrid's), where ``A``'s entries double
+    and the inverse's alternate in sign at size 2 all over the chunk, in
+    the ``jax.numpy`` chunks and in the kernels, interpreted."""
+    args, do = delta_inputs(5, 128, heads, heads, dim, -9.0, correlated=True,
+                            strength=strength)
+    assert float(jnp.median(args[4])) > 0.95 * strength
     with jax.default_matmul_precision("highest"):
         want = value_and_cotangents(gd.reference_gated_delta_rule, args, do)
         got = value_and_cotangents(
-            lambda *a: gd.gated_delta_rule(*a, chunk=64), args, do)
+            lambda *a: gd.gated_delta_rule(*a, chunk=64,
+                                           force=path == "kernel",
+                                           interpret=True), args, do)
     for name, a, b in zip(NAMES, got, want):
         scale = float(jnp.abs(b).max())
         np.testing.assert_allclose(a, b, atol=1e-3 * scale, rtol=0,
                                    err_msg=name)
 
 
-def test_bfloat16_operands_stay_near_the_scan():
+@pytest.mark.parametrize("heads,dim,strength", [
+    ((1, 2), 128, 1.0), ((6, 6), (96, 192), 2.0)])
+def test_bfloat16_operands_stay_near_the_scan(heads, dim, strength):
     """The models' dtype: bfloat16 q / k / v into the products, the state
     and the decays float32."""
-    args, do = delta_inputs(7, 256, 1, 2, 128, -2.0, dtype=jnp.bfloat16)
+    args, do = delta_inputs(7, 256, *heads, dim, -2.0, dtype=jnp.bfloat16,
+                            strength=strength)
     want = value_and_cotangents(gd.reference_gated_delta_rule, args, do)
     got = value_and_cotangents(lambda *a: gd.gated_delta_rule(
         *a, force=True, interpret=True), args, do)
@@ -460,7 +482,10 @@ def test_the_kernels_take_whole_lane_tiles_on_a_tpu(monkeypatch):
     monkeypatch.setattr(gd.jax, "default_backend", lambda: "tpu")
     assert gd.gated_delta_supported(16, 32, 128, 128)
     assert gd.gated_delta_supported(2, 2, 128, 256, jnp.float32)
-    assert not gd.gated_delta_supported(16, 32, 64, 128)
+    # no whole tile a head, whole tiles four heads together; any head count
+    assert gd.gated_delta_supported(30, 30, 96, 192)
+    assert gd.gated_delta_supported(16, 32, 64, 128)
+    assert not gd.gated_delta_supported(16, 32, 16, 128)
     assert not gd.gated_delta_supported(16, 24, 128, 128)
     assert not gd.gated_delta_supported(16, 32, 128, 128, jnp.float16)
 

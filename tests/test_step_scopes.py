@@ -205,6 +205,16 @@ AREA_PATHS = [
      "attn"),
     ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/block_0/attn/"
      "jit(_rotate)/rope/pallas_call", "attn"),
+    # a block that norms its sub-layers' OUTPUT (Olmo-Hybrid's): each norm
+    # in its sub-layer's area, the linear mixer's under its own name
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_0/"
+     "linear_attn_post_norm/rsqrt", "linattn"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/block_0/"
+     "checkpoint/rematted_computation/linear_attn_post_norm/mul", "linattn"),
+    ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_3/attn_post_norm/"
+     "mul", "attn"),
+    ("jit(bagua_step)/transpose(jvp(bagua.loss))/TransformerLM/block_3/"
+     "mlp_post_norm/reduce_sum", "mlp"),
     # an expert layer's scope decides, whatever module it sits under
     ("jit(bagua_step)/jvp(bagua.loss)/TransformerLM/block_1/mlp/bagua.moe/"
      "experts/gmm_fwd/pallas_call", "moe/experts"),
@@ -250,6 +260,16 @@ def lm_trainer(kind, **trainer_kw):
                    exit_gate=True, remat=True)
         model = TransformerLM(TransformerConfig(**cfg))
         loss_fn = looped_lm_loss_fn(model)
+    elif kind == "output-norm hybrid":
+        # Olmo-Hybrid's block: one linear-attention layer, one full one, a
+        # norm behind each sub-layer and none in front, no positions
+        cfg.update(rope_theta=1e6, rope_layers=(0,), qk_norm=True,
+                   pre_norms=False, post_norms=True, mixer_layers=(1, 0),
+                   linear_key_heads=2, linear_value_heads=2,
+                   linear_key_dim=16, linear_value_dim=32,
+                   linear_neg_eigval=True)
+        model = TransformerLM(TransformerConfig(**cfg))
+        loss_fn = lm_loss_fn(model)
     elif kind == "moe":
         cfg.update(rope_theta=10000.0)
         moe = lambda: MoEMLP(n_experts=4, d_ff=32, k=2, dropless=True,
@@ -282,8 +302,9 @@ MOE_AREAS = {"moe/route", "moe/dispatch", "moe/experts", "moe/combine"}
     ("dense", {"accum_steps": 4, "overlap": "on"}, DENSE_AREAS | {"accum"}),
     ("dense", {"accum_steps": 4, "overlap": "off"}, DENSE_AREAS | {"accum"}),
     ("looped", {}, DENSE_AREAS | {"exit"}),
+    ("output-norm hybrid", {}, DENSE_AREAS | {"linattn"}),
 ], ids=["dense", "remat", "moe", "accum4-overlap", "accum4-serial",
-        "looped"])
+        "looped", "output-norm-hybrid"])
 def test_compiled_step_names_its_areas(kind, trainer_kw, areas):
     trainer, state, batch = lm_trainer(kind, **trainer_kw)
     paths = [p for _, _, p in
@@ -295,6 +316,18 @@ def test_compiled_step_names_its_areas(kind, trainer_kw, areas):
     if kind == "dense":
         # the learned position table is no module's: its own plain scope
         assert has(paths, f"/{obs_spans.POS_EMBED_SCOPE}/")
+    if kind == "output-norm hybrid":
+        # each output norm is there under its sub-layer's name, forward and
+        # backward, and no norm stands in front of a sub-layer
+        for name, area in (("linear_attn_post_norm", "linattn"),
+                           ("attn_post_norm", "attn"),
+                           ("mlp_post_norm", "mlp")):
+            named = [p for p in paths if f"/{name}/" in p]
+            assert has(named, "jvp(bagua.loss)", without=("transpose(",))
+            assert has(named, "transpose(jvp(bagua.loss))")
+            assert {obs_spans.area_of(p) for p in named} == {area}
+        assert not has(paths, "/linear_attn_norm/")
+        assert not has(paths, "/attn_norm/") and not has(paths, "/mlp_norm/")
     inside = [p for p in paths if obs_spans.in_loop(p)]
     assert bool(inside) == (kind == "looped")
     if kind == "looped":
