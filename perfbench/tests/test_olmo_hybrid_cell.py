@@ -229,7 +229,11 @@ def test_the_comparison_refuses_each_wrong_mechanism(driven, fault):
     trainer_losses = [5.5, 5.5, 5.5]
     losses = job.reference_losses(3, hyper=hyper)
     assert not job.losses_agree(trainer_losses, losses)
-    assert not reference.gradients_agree(job.gradient_distance)
+    # the small size's own limit: in float32 the sound system reads under
+    # 0.01 here; the cell's 0.5 is made for bfloat16 at the published
+    # widths, where these faults read 0.34-1.17 and 1.00-6.67 on every leaf
+    assert not reference.gradients_agree(job.gradient_distance, 0.1)
+    assert max(job.gradient_distance.values()) > 0.3
 
 
 def test_flops_per_token_counts_what_is_computed(builder):

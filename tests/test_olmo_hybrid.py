@@ -181,7 +181,7 @@ def test_the_comparison_tells_each_mechanism_from_its_absence(both, fault):
     distance = ref.gradient_distance(got, ref.watched(grads))
     assert set(distance) == set(got) and len(distance) == 6 * 10 + 2 * 9
     if fault == "none":
-        assert ref.gradients_agree(distance, 2e-3)
+        assert ref.gradients_agree(distance, 2e-3, ())  # every leaf held
         return
     assert not ref.gradients_agree(distance)
     where = {
@@ -217,6 +217,23 @@ def test_the_model_with_a_wrong_mechanism_is_refused_too(both):
     names = set(jax.eval_shape(
         lambda: builder.make_params(pre, 0))["block_0"])
     assert names == {"linear_attn_norm", "linear_attn", "mlp_norm", "mlp"}
+
+
+def test_the_decay_vectors_are_held_to_a_distance_not_to_a_number():
+    """``A_log`` and ``dt_bias`` (the seed's few slow heads: 0.16 to 0.82 on
+    the chip by the seed) must HAVE a first-gradient distance; every other
+    leaf is within the limit; the change comparison bounds whatever it does
+    not skip."""
+    read = {"block_0/linear_attn/A_log": 0.8, "block_0/linear_attn/conv": 0.2}
+    assert ref.gradients_agree(read)
+    assert not ref.gradients_agree(
+        {**read, "block_0/linear_attn/A_log": float("inf")})
+    assert not ref.gradients_agree({**read, "block_0/linear_attn/conv": 0.6})
+    assert not ref.gradients_agree(read, unbounded=())
+    assert not ref.gradients_agree({})
+    assert ref.changes_agree({"block_0/linear_attn/A_log": 0.9,
+                              "block_0/mlp/wi_gate/kernel": 0.4})
+    assert not ref.changes_agree({"block_0/mlp/wi_gate/kernel": 0.8})
 
 
 def test_a_block_needs_a_norm():
