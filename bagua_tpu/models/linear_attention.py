@@ -29,15 +29,17 @@ tensor parallel form yet (the callers refuse those).
 Which path runs where.  The two projections are ``nn.Dense`` everywhere.
 Between them, on a TPU and where their grids cover the shape
 (:func:`rows_by_kernel`: heads that are whole 128-lane tiles alone — 128 x
-128 — or in blocks of up to four — 96-lane keys under 192-lane values, any
-head count —, a sequence of whole 128-row blocks, bfloat16 or float32), the
+128 — or, key and value heads as many, in blocks of up to four — 96-lane
+keys under 192-lane values, any head count —, a sequence of whole 128-row
+blocks, bfloat16 or float32), the
 rows are Pallas passes on the
 projection's own buffer around the ``gdn_fwd`` / ``gdn_bwd`` kernels
 (``ops/gated_delta_rows.py::gated_delta_rows``: ``gdn_mix`` for lines three
 and five above, ``gdn_gate`` for the seventh, float32 from the load to one
 rounding at the store, one VJP that writes the buffer's cotangent where it
 lands).  Everywhere else — the CPU, a ragged sequence, heads of which more
-than four make whole tiles (16-lane keys) — they
+than four make whole tiles (16-lane keys), grouped heads that share a tile
+— they
 are the ``jax.numpy`` code of this file (:func:`mix_rows`,
 :func:`gate_rows`) around ``ops.gated_delta.gated_delta_rule``, which picks
 between its kernels and its own ``jax.numpy`` chunks by itself; that code is
@@ -163,8 +165,9 @@ def rows_by_kernel(cfg, seq: int) -> bool:
     """Whether a layer of ``cfg`` over ``seq`` positions runs its rows
     between the two projections as the Pallas passes of
     ``ops/gated_delta_rows.py`` (on a TPU, where their grids cover the
-    shape: heads of whole 128-lane tiles alone or in blocks of up to four —
-    128 x 128, 96 x 192 —, a sequence of whole 128-row blocks) and not as
+    shape: heads of whole 128-lane tiles alone or, key and value heads as
+    many, in blocks of up to four — 128 x 128, 96 x 192 —, a sequence of
+    whole 128-row blocks) and not as
     :func:`mix_rows` / :func:`gate_rows`."""
     from ..ops.gated_delta_rows import rows_supported
 
@@ -194,8 +197,9 @@ class GatedDeltaNet(nn.Module):
                 "linear_key_heads, linear_value_heads (a multiple of the key "
                 "heads), linear_key_dim, linear_value_dim and linear_conv "
                 "(any positive widths: the kernels take heads that are "
-                "whole 128-lane tiles alone or in blocks of up to four, 128 "
-                "x 128 as 96 x 192, the jax.numpy form every other); "
+                "whole 128-lane tiles alone or, key and value heads as many, "
+                "in blocks of up to four, 128 x 128 as 96 x 192, the "
+                "jax.numpy form every other); "
                 f"got {hk} / {hv} / {dk} / {dv} / {cfg.linear_conv}")
         b, s, _ = x.shape
         key_width, value_width = hk * dk, hv * dv

@@ -67,8 +67,8 @@ copied or padded in HBM for it and no width is widened.
 
 Which path runs where: :func:`gated_delta_rule` runs the kernels where
 :func:`gated_delta_supported` says so (a TPU, heads that are whole lane
-tiles alone or in blocks of up to four) and the ``jax.numpy`` chunks
-elsewhere.  Where the layer's rows around the rule
+tiles alone or, key and value heads as many, in blocks of up to four) and
+the ``jax.numpy`` chunks elsewhere.  Where the layer's rows around the rule
 are Pallas passes too, ``ops/gated_delta_rows.py`` calls the rule's forward
 and backward (:func:`_forward`, :func:`_gated_delta_bwd`) from inside its own
 VJP, always by the kernels: the calls and their operands are the same.
@@ -565,15 +565,21 @@ MAX_HEADS_PER_BLOCK = 4
 def gated_delta_covered(key_heads: int, value_heads: int, d_k: int,
                         d_v: int, dtype=jnp.bfloat16) -> bool:
     """Whether the kernels' grid covers the heads: value heads a multiple of
-    the key heads, bfloat16 or float32 operands, and heads of which at most
-    ``MAX_HEADS_PER_BLOCK`` make whole 128-lane tiles — 128-lane heads one
-    by one, 96-lane keys under 192-lane values four to a block (the last
-    block ragged where the head count is no multiple: 30 heads are seven
-    blocks and a half).  Any head count, any sequence length (the rows are
+    the key heads, bfloat16 or float32 operands, and heads that are whole
+    128-lane tiles one by one (128 x 128, any number of value heads a key
+    head) or, where key heads and value heads are as many, in blocks of at
+    most ``MAX_HEADS_PER_BLOCK`` — 96-lane keys under 192-lane values four
+    to a block, the last block ragged where the head count is no multiple
+    (30 heads are seven blocks and a half).  Grouped heads of which several
+    make a tile (64-lane keys under 128-lane values at 16 / 32 heads) take
+    the ``jax.numpy`` chunks: no configuration has them and no test runs
+    them on the chip.  Any head count, any sequence length (the rows are
     padded to whole blocks of chunks)."""
+    group = value_heads // max(key_heads, 1)
+    per_block = heads_per_block(d_k, d_v, group)
     return (value_heads % key_heads == 0 and d_k % 8 == 0
-            and heads_per_block(d_k, d_v, value_heads // key_heads)
-            <= MAX_HEADS_PER_BLOCK
+            and (per_block == 1
+                 or group == 1 and per_block <= MAX_HEADS_PER_BLOCK)
             and jnp.dtype(dtype) in (jnp.dtype(jnp.bfloat16),
                                      jnp.dtype(jnp.float32)))
 
@@ -581,8 +587,9 @@ def gated_delta_covered(key_heads: int, value_heads: int, d_k: int,
 def gated_delta_supported(key_heads: int, value_heads: int, d_k: int,
                           d_v: int, dtype=jnp.bfloat16) -> bool:
     """Whether the kernels take the call: on a TPU, heads that
-    :func:`gated_delta_covered` (whole lane tiles alone or in blocks of up
-    to four: 128 x 128 and 96 x 192 do, 16-lane keys do not)."""
+    :func:`gated_delta_covered` (whole lane tiles alone at any group, or in
+    blocks of up to four where key and value heads are as many: 128 x 128
+    and 96 x 192 do, 16-lane keys and grouped 64-lane keys do not)."""
     return (jax.default_backend() == "tpu" and gated_delta_covered(
         key_heads, value_heads, d_k, d_v, dtype))
 

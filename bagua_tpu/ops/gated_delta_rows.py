@@ -104,8 +104,9 @@ def _merged(dims) -> bool:
 
 def rows_covered(seq: int, dims, taps: int, dtype) -> bool:
     """Whether the passes' grids cover the layer: heads the rule's kernels
-    cover (``gated_delta_covered``: whole 128-lane tiles alone or in blocks
-    of up to four, 128 x 128 as 96 x 192), a sequence of whole row blocks,
+    cover (``gated_delta_covered``: whole 128-lane tiles alone or, key and
+    value heads as many, in blocks of up to four, 128 x 128 as 96 x 192), a
+    sequence of whole row blocks,
     the q | k columns and the v columns each whole blocks of their heads
     with v and z starting at a whole block of value heads (a part's columns
     are addressed by lane-block index; inside a block a head is a static

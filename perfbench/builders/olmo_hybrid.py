@@ -14,7 +14,9 @@ is 0.93 B parameters: no single chip holds its weights, a gradient and the
 moments): the first gradient of the model as timed is taken as the trainer's
 step takes it, one sequence a chip over the trainer's own mesh, and what is
 kept for a comparison waits on the host.  A fourth comparison holds the
-rule's own precision (``reference.rule_probe``).
+rule's own precision, forward and backward (``reference.rule_probe``: the
+``gdn_fwd`` / ``gdn_bwd`` kernels at the timed rows against the scan and its
+VJP).
 
 A program that predates the architecture's fields (the parent commit of the
 PR that brought them) is refused by ``check_program`` with a ``CellError``
@@ -24,6 +26,7 @@ before any weight is made.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 
 import jax
@@ -169,31 +172,37 @@ def timed_gradient(trainer, model: TransformerLM, seed: int, batch: dict,
 
 
 def system_change(trainer, model: TransformerLM, seed: int, batch: dict,
-                  steps: int, reference) -> dict:
+                  steps: int, reference) -> tuple[dict, list]:
     """The change of ``reference.watched``'s leaves (and its
     ``CHANGE_ALSO``) over ``steps`` updates of the trainer's own compiled
     step on ``batch``, from a fresh state of the same seed
-    (``builders/sdar.py::system_change``, with what is kept on the host)."""
+    (``builders/sdar.py::system_change``, with what is kept on the host),
+    and the losses those steps saw."""
     params = make_params(model, seed)
     start = reference.watched_copy(params)
     state = trainer.init(params)
     del params
     replay = trainer.shard_batch(batch)
+    losses = []
     for _ in range(steps):
-        state, _ = trainer.train_step(state, replay)
+        state, loss = trainer.train_step(state, replay)
+        losses.append(float(loss))
     after = reference.watched_copy(trainer.unstack_params(state))
-    return {name: after[name] - start[name] for name in start}
+    return {name: after[name] - start[name] for name in start}, losses
 
 
-def system_rule(reference, seed: int, seq: int, hyper: dict, dtype):
-    """The probe's rows through the rule as a layer of the model runs it:
-    ``ops.gated_delta.gated_delta_rule`` on operands of the model's dtype
-    (the ``gdn_fwd`` kernel on the chip)."""
+def system_rule(reference, seed: int, seq: int, hyper: dict, dtype) -> dict:
+    """The probe's rows through the rule as a layer of the model runs it,
+    forward and backward: ``ops.gated_delta.gated_delta_rule`` on operands
+    of the model's dtype (the ``gdn_fwd`` and ``gdn_bwd`` kernels on the
+    chip) under the probe's cotangent -> ``reference.RULE_QUANTITIES``."""
     from bagua_tpu.ops.gated_delta import gated_delta_rule
 
-    q, k, v, g, beta = reference.rule_probe(seed, seq, hyper)
+    q, k, v, g, beta, do = reference.rule_probe(seed, seq, hyper)
     q, k, v = (t.astype(dtype) for t in (q, k, v))
-    return jax.device_get(jax.jit(gated_delta_rule)(q, k, v, g, beta))
+    return jax.device_get(jax.jit(functools.partial(
+        reference.rule_with_cotangents, gated_delta_rule))(
+            q, k, v, g, beta, do))
 
 
 @dataclasses.dataclass
@@ -215,6 +224,11 @@ class Job(_olmoe.Job):
     rule_distance: dict = dataclasses.field(default_factory=dict)
     #: what the system gave (made once: a fault check asks again and again)
     _system: tuple | None = None
+    #: what the reference gave last, on the host: its first gradient and its
+    #: change on the compared leaves, and the losses of both sides (a
+    #: planted fault of the SYSTEM is read against them without another
+    #: replay)
+    wanted: dict = dataclasses.field(default_factory=dict)
 
     def reference_losses(self, steps: int, **probe) -> list[float]:
         """``probe``: ``hyper=`` / ``round_weights=`` of a reference with a
@@ -229,20 +243,18 @@ class Job(_olmoe.Job):
                 timed_gradient(self._replayer, self._model, self._seed,
                                self.replay_batch, reference),
                 system_change(self._replayer, self._model, self._seed,
-                              self.replay_batch, steps, reference),
+                              self.replay_batch, steps, reference)[0],
                 system_rule(reference, self._seed, seq, hyper,
                             self._model.cfg.dtype))
         got_gradient, got_change, got_rule = self._system
 
-        def distances(got: dict, want: dict) -> dict:
-            return {name: float(d) for name, d in
-                    reference.gradient_distance(got, want).items()}
-
         def compare_gradient(want: dict) -> None:
-            self.gradient_distance = distances(got_gradient, want)
+            self.wanted["gradient"] = want = jax.device_get(want)
+            self.gradient_distance = self.distances(got_gradient, want, True)
 
         def compare_change(want: dict) -> None:
-            self.change_distance = distances(got_change, want)
+            self.wanted["change"] = want
+            self.change_distance = self.distances(got_change, want)
 
         self.rule_distance = reference.rule_distance(
             got_rule, reference.rule_by_scan(
@@ -267,8 +279,20 @@ class Job(_olmoe.Job):
             "loss_limits": reference.LOSS_TOLERANCE}), flush=True)
         return losses
 
+    def distances(self, got: dict, want: dict, gate_halves=False) -> dict:
+        """Per compared leaf ``|got - want| / |want|``; ``gate_halves``: the
+        two halves of the gates' in-projection by themselves besides (the
+        first gradient's comparison)."""
+        reference = self._reference
+        if gate_halves:
+            got, want = (reference.with_gate_halves(t) for t in (got, want))
+        return {name: float(d) for name, d in
+                reference.gradient_distance(got, want).items()}
+
     def losses_agree(self, trainer_losses, reference_losses) -> bool:
         reference = self._reference
+        self.wanted["losses"] = list(reference_losses)
+        self.wanted["trainer_losses"] = list(trainer_losses)
         return (reference.agree(trainer_losses, reference_losses,
                                 reference.LOSS_TOLERANCE)
                 and reference.gradients_agree(self.gradient_distance)
@@ -300,9 +324,11 @@ def make_model(config: dict, traffic: dict) -> TransformerLM:
         **_kwargs(traffic.get("model", {}))))
 
 
-def make_trainer(cell: cells.Cell, traffic: dict, devices: list):
+def make_trainer(cell: cells.Cell, traffic: dict, devices: list,
+                 algorithm=None):
     """The model and its trainer over ``devices``, as the traffic mix
-    configures them; nothing is placed on a device yet."""
+    configures them (``algorithm``: another than the traffic's, a planted
+    fault's); nothing is placed on a device yet."""
     check_program()
     config = cell.config
     if int(traffic["seq_len"]) > int(config["max_position_embeddings"]):
@@ -312,8 +338,9 @@ def make_trainer(cell: cells.Cell, traffic: dict, devices: list):
     model = make_model(config, traffic)
     mesh = build_mesh(dict(traffic["mesh"]), devices)
     bagua_tpu.init_process_group(mesh=mesh)
-    algorithm = _import(traffic["algorithm"]["class"])(
-        **_kwargs(traffic["algorithm"].get("kwargs", {})))
+    if algorithm is None:
+        algorithm = _import(traffic["algorithm"]["class"])(
+            **_kwargs(traffic["algorithm"].get("kwargs", {})))
     optimizer = getattr(optax, traffic["optimizer"]["name"])(
         **traffic["optimizer"].get("kwargs", {}))
     trainer = bagua_tpu.BaguaTrainer(

@@ -62,6 +62,7 @@ from __future__ import annotations
 
 import functools
 import math
+import statistics
 
 import jax
 import jax.numpy as jnp
@@ -76,22 +77,24 @@ causal_conv, l2_normalize, causal_attention = (
     _qwen.causal_conv, _qwen.l2_normalize, _qwen.causal_attention)
 agree = _qwen.agree
 
-#: Largest |trainer loss - reference loss| accepted on the FIRST replayed
-#: step: ``reference/olmoe.py``'s, the limit of the harness's accepted
-#: next-token cells (uniform random targets over a slice of the vocabulary).
-#: Readings (my chip runs, PR 56, four chips, published widths, kernels on,
-#: five seeds; PERF.md section 6): the system differs by 0.0002 at most on
-#: the first step, fifteen times inside the limit.  The second and the third
-#: step are reported and not held (``reference/sdar.py::agree`` holds the
-#: steps that have a limit): this model's first updates move the loss by 3.6
-#: and 3.0 (9.93 -> 6.36 -> 3.40: it learns the one replay batch), the
-#: system's distance grows with them to 0.0003-0.0095 and 0.0040-0.0187 by
-#: the seed, and the accepted cells' limits for those steps (0.011, 0.03)
-#: would leave the largest reading 1.2 and 1.6 times of room where the rule
-#: for a limit asks for three.  What the later steps would hold — the
-#: precision of the weights, the moments and the updates — is held by
-#: ``CHANGE_TOLERANCE``, where weights rounded to bfloat16 are refused.
-LOSS_TOLERANCE = _shared.LOSS_TOLERANCE[:1]
+#: Largest |trainer loss - reference loss| accepted on the three replayed
+#: steps.  The first is ``reference/olmoe.py``'s, the limit of the harness's
+#: accepted next-token cells (uniform random targets over a slice of the
+#: vocabulary): the system differs by 0.0002 at most there, fifteen times
+#: inside it (my chip runs, PR 56, four chips, published widths, kernels on,
+#: seven seeds; PERF.md section 6).  The second and the third step have two
+#: readings of their own, and each limit lies between them: this model
+#: learns its one replay batch (9.93 -> 6.36 -> 3.40) and the system's
+#: distance grows with the steps to 0.0003-0.0095 and 0.0006-0.0187 by the
+#: seed, where the accepted cells' 0.011 and 0.03 would leave 1.2 and 1.6
+#: times of room; the reference with its weights rounded to bfloat16 at the
+#: start and after every update (the nearest precision below the float32
+#: the configuration states for them) reads 6.222 and 3.201 (seed
+#: 3000000033, the faults' call, which ran no sound replay) where the
+#: float32 reference reads 6.358-6.407 and 3.397-3.466 over the seven seeds:
+#: 0.136 and 0.196 away at the least.  Each limit lies 3.8 / 3.2 times over
+#: the sound largest and 3.8 / 3.3 times under the control's least.
+LOSS_TOLERANCE = (_shared.LOSS_TOLERANCE[0], 0.036, 0.06)
 
 #: Largest relative distance ``|g_system - g_reference| / |g_reference|``
 #: (Frobenius norms) accepted on a ``watched`` leaf of the FIRST gradient of
@@ -118,18 +121,40 @@ LOSS_TOLERANCE = _shared.LOSS_TOLERANCE[:1]
 #: largest reading and under half of each fault's.
 GRADIENT_TOLERANCE = 0.5
 
-#: held in the first gradient to HAVE a distance (a reference without the
-#: decay moves neither: no distance, refused) but to no number: the linear
-#: layers' 30-entry vectors ``A_log`` and ``dt_bias``.  A head's entry is the
-#: sum over the positions of what its log decay moves, next to nothing for
-#: the heads that forget within a few positions (most, at ``A ~ U(0, 16)``),
-#: so the vector is the seed's few slow heads, whose long memory is where
-#: the bfloat16 products' rounding adds up: the first block's read 0.18,
-#: 0.16, 0.44, 0.48 and 0.82 on five seeds (my chip runs, PR 56) while every
-#: other leaf moved in the third digit.  The decay's mechanism is held by
-#: the columns of ``in_proj_ba`` that feed it, by the rule's probe at decays
-#: of 0.999, and in float32 by the tier-1 tests on these two leaves
-GRADIENT_UNBOUNDED = ("linear_attn/A_log", "linear_attn/dt_bias")
+#: held in the first gradient by the MEDIAN over the layers (and each to
+#: have a finite distance: a reference without the decay moves neither): the
+#: linear layers' vectors of one entry a head, ``A_log`` and ``dt_bias``.
+#: A head's entry is the cotangent of its log decay SUMMED over the
+#: positions (times a factor that hardly varies at a fresh seed, which is
+#: why the two leaves read alike).  Readings (my chip runs, PR 56, eight
+#: seeds): the first block's pair 0.13, 0.13, 0.16, 0.18, 0.26, 0.44, 0.48
+#: and 0.82 by the seed, the second's 0.11-0.15 and once 0.35, the third's
+#: 0.07-0.11; the median over the three 0.11-0.15 on every seed.  Why
+#: (PERF.md section 6: the program at float32 against this file on the chip
+#: at the published widths, seed 3000000066, reads 0.0006 where bfloat16
+#: reads 0.87; the same at a small size on the CPU, and the kernels in
+#: float32, interpreted, at decays near 1 and a ragged block):
+#: in the first block, whose input is the embedding of independent random
+#: tokens, a head that forgets within a position moves the loss by 1e-2 to
+#: 1e-6 of what a slow one does (``A ~ U(0, 16)``: most do), so the vector
+#: is the seed's few slow heads (four of 30 at seed 3000000066), each a sum
+#: over 32,768 positions that all but cancels — and the rounding of the
+#: bfloat16 products, 0.2 of every position's term (what the ``a`` columns
+#: of ``GATES`` read, with nothing to cancel), does not cancel with it: the
+#: slowest head's error there is 2.7 times its entry (2.86 on the whole
+#: vector in the CPU witness, where float32 reads 0.0004).
+#: A limit on such a leaf by itself holds the seed's draw and nothing of
+#: the program; the median over the layers is off the limit by 3.3 times
+#: on every seed, and a fault of the ``dg`` path moves every layer
+#: (``beta = sigmoid(b)``: every leaf 0.34 and more).
+GRADIENT_MEDIAN_OF = ("linear_attn/A_log", "linear_attn/dt_bias")
+
+#: the in-projection of the two gates, compared by halves besides: its ``b``
+#: columns feed the write strength and its ``a`` columns the decay, whose
+#: gradient is the per-position cotangent of the log decay times the layer's
+#: input, the same cotangent whose SUM over the positions is a head's entry
+#: of ``A_log`` and ``dt_bias`` — with nothing to cancel
+GATES = "linear_attn/in_proj_ba/kernel"
 
 #: Largest relative distance accepted on a ``watched`` leaf (but those of
 #: ``CHANGE_SKIPPED``) and any leaf of ``CHANGE_ALSO`` between the system's
@@ -194,7 +219,29 @@ WATCHED_ENDS = (
 #: 0.012 on the strong, where every write overwrites what was there).  The
 #: weak limit lies eleven times over the system's reading and seven under
 #: the fault's; the strong one twice over the system's and far under 1.
-RULE_TOLERANCE = {"weak": 0.02, "strong": 0.15}
+#: The BACKWARD pass (``gdn_bwd`` reads the states the forward pass kept, in
+#: the operands' dtype) is held the same way: the cotangents of the five
+#: operands under a seeded cotangent of the output, against the scan's VJP.
+#: Readings at the timed rows (my chip run, PR 56, one chip, the ``gdn_fwd``
+#: and ``gdn_bwd`` kernels on 8,192 rows of 30 heads of 96 / 192, bfloat16
+#: operands, seed 3000000066; the ``jax.numpy`` chunks read the same to two
+#: digits on three seeds on the CPU): on the weak heads the system reads
+#: 0.0029 (dq), 0.0070 (dk), 0.0035 (dv), 0.0023 (dg), 0.0060 (dbeta) and the
+#: scan with its state in bfloat16 0.152, 1.47, 0.93, 0.63, 1.25: each limit
+#: seven to eighteen times over the one and as many under the other.  On the
+#: strong heads the system's own operands' rounding (0.043, 0.062, 0.061,
+#: 0.095, 0.093) is the fault's size (0.064, 0.20, 0.054, 0.22, 0.056), as on
+#: the output: limits three times the system's reading and far under 1,
+#: which hold the solve and nothing of the state.  On float32 operands the
+#: kernels read 0.0001 to 0.0032 there (the chip's highest-precision
+#: products are six bfloat16 passes), 1e-5 interpreted on the CPU (tier-1).
+RULE_TOLERANCE = {
+    "o/weak": 0.02, "o/strong": 0.15,
+    "dq/weak": 0.02, "dk/weak": 0.1, "dv/weak": 0.06, "dg/weak": 0.035,
+    "dbeta/weak": 0.085,
+    "dq/strong": 0.13, "dk/strong": 0.19, "dv/strong": 0.19,
+    "dg/strong": 0.28, "dbeta/strong": 0.28,
+}
 
 #: rows per chunk of the head's cross-entropy; positions per block of the
 #: delta rule's scan
@@ -509,15 +556,32 @@ def gradient_distance(got: dict, want: dict) -> dict:
     return out
 
 
+def with_gate_halves(leaves: dict) -> dict:
+    """``leaves`` and, for every in-projection of the two gates
+    (``GATES``, ``[d, b | a]``), its halves by themselves."""
+    out = dict(leaves)
+    for name, leaf in leaves.items():
+        if name.endswith(GATES):
+            heads = leaf.shape[1] // 2
+            out[name + "[b]"], out[name + "[a]"] = (leaf[:, :heads],
+                                                    leaf[:, heads:])
+    return out
+
+
 def gradients_agree(distances: dict,
                     tolerance: float = GRADIENT_TOLERANCE,
-                    unbounded: tuple = GRADIENT_UNBOUNDED) -> bool:
+                    median_of: tuple = GRADIENT_MEDIAN_OF) -> bool:
     """Whether every watched leaf of the system's first gradient has a
-    distance from the reference's (there is one, and all finite) and, but
-    for the leaves of ``unbounded``, one within ``tolerance``."""
-    return bool(distances) and all(
-        math.isfinite(d) and (d <= tolerance or name.endswith(unbounded))
-        for name, d in distances.items())
+    distance from the reference's (there is one, and all finite) within
+    ``tolerance`` — the leaves of a kind in ``median_of`` by the median over
+    their layers, every other by itself."""
+    if not distances or not all(map(math.isfinite, distances.values())):
+        return False
+    kinds: dict = {}
+    for name, d in distances.items():
+        kind = next((end for end in median_of if name.endswith(end)), name)
+        kinds.setdefault(kind, []).append(d)
+    return all(statistics.median(ds) <= tolerance for ds in kinds.values())
 
 
 def changes_agree(distances: dict,
@@ -533,19 +597,24 @@ def changes_agree(distances: dict,
 # ---- the rule by itself ------------------------------------------------------
 
 
+#: what the rule's probe compares: the output and the cotangents of the five
+#: operands under a seeded cotangent of the output
+RULE_QUANTITIES = ("o", "dq", "dk", "dv", "dg", "dbeta")
+
+
 def rule_probe(seed: int, seq: int, hyper: dict) -> tuple:
-    """``(q, k, v, g, beta)`` of one sequence on which the precision of the
-    rule's state and of its solve decides the result (``RULE_TOLERANCE``):
-    queries and keys that are nearly one vector a head, values around one
-    vector a head, and by the head's parity write strengths of ``2
-    sigmoid(N(4, 2))`` under log decays around ``-e^-7`` (even: strong) or
-    of ``2 sigmoid(N(-7, 1/2))`` under log decays around ``-e^-10`` (odd:
-    weak).  q / k: [1, seq, key_heads, d_k] float32, L2-normalised, q
-    scaled; v: [1, seq, value_heads, d_v]; g / beta: [1, seq,
-    value_heads]."""
+    """``(q, k, v, g, beta, do)`` of one sequence on which the precision of
+    the rule's state and of its solve decides the result
+    (``RULE_TOLERANCE``): queries and keys that are nearly one vector a
+    head, values around one vector a head, and by the head's parity write
+    strengths of ``2 sigmoid(N(4, 2))`` under log decays around ``-e^-7``
+    (even: strong) or of ``2 sigmoid(N(-7, 1/2))`` under log decays around
+    ``-e^-10`` (odd: weak); a standard normal cotangent of the output.  q /
+    k: [1, seq, key_heads, d_k] float32, L2-normalised, q scaled; v / do:
+    [1, seq, value_heads, d_v]; g / beta: [1, seq, value_heads]."""
     hk, hv = hyper["linear_key_heads"], hyper["linear_value_heads"]
     dk, dv = hyper["linear_key_dim"], hyper["linear_value_dim"]
-    keys = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 6)
+    keys = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 7)
     draw = lambda key, *shape: jax.random.normal(key, shape, jnp.float32)
     q = l2_normalize(0.3 * draw(keys[0], 1, seq, hk, dk) + 0.5) / math.sqrt(
         dk)
@@ -556,27 +625,44 @@ def rule_probe(seed: int, seq: int, hyper: dict) -> tuple:
     b = draw(keys[5], 1, seq, hv)
     beta = write_strength(jnp.where(weak, 0.5 * b - 7.0, 2.0 * b + 4.0),
                           {"neg_eigval": True})
-    return q, k, v, g, beta
+    return q, k, v, g, beta, draw(keys[6], 1, seq, hv, dv)
+
+
+def rule_with_cotangents(rule, q, k, v, g, beta, do) -> dict:
+    """``RULE_QUANTITIES`` of ``rule(q, k, v, g, beta)`` under the
+    cotangent ``do``: the way both sides of the comparison are taken."""
+    o, vjp = jax.vjp(rule, q, k, v, g, beta)
+    return dict(zip(RULE_QUANTITIES, (o, *vjp(do.astype(o.dtype)))))
 
 
 @functools.partial(jax.jit, static_argnames=("scan_dtype",))
-def rule_by_scan(q, k, v, g, beta, scan_dtype="float32"):
-    """The probe's rows through the per-position scan, float32 (or, the
-    fault, with the state and the decay kept in ``scan_dtype``)."""
+def rule_by_scan(q, k, v, g, beta, do, scan_dtype="float32") -> dict:
+    """The probe's rows through the per-position scan and its VJP, float32
+    (or, the fault, with the state and the decay kept in ``scan_dtype``: the
+    backward pass reads the rounded states)."""
     group = v.shape[2] // k.shape[2]
-    q, k = (jnp.repeat(t, group, axis=2) for t in (q, k))
-    with jax.default_matmul_precision("highest"):
+
+    def scan(q, k, v, g, beta):
+        q, k = (jnp.repeat(t, group, axis=2) for t in (q, k))
         return delta_rule(q, k, v, jnp.exp(g), beta, scan_dtype)
 
+    with jax.default_matmul_precision("highest"):
+        return rule_with_cotangents(scan, q, k, v, g, beta, do)
 
-def rule_distance(got, want) -> dict:
-    """``|got - want| / |want|`` over the probe's output [1, seq, heads,
-    d_v], the weak heads (odd) and the strong ones (even) apart."""
-    got, want = (np.asarray(t, np.float32) for t in (got, want))
+
+def rule_distance(got: dict, want: dict) -> dict:
+    """``|got - want| / |want|`` of each of ``RULE_QUANTITIES`` ([1, seq,
+    heads, ...]), the weak heads (odd) and the strong ones (even) apart:
+    ``{"o/weak": .., "o/strong": .., "dq/weak": ..}``."""
     heads = {"strong": slice(0, None, 2), "weak": slice(1, None, 2)}
-    return {kind: float(np.linalg.norm(got[:, :, at] - want[:, :, at])
-                        / np.linalg.norm(want[:, :, at]))
-            for kind, at in heads.items()}
+    out = {}
+    for name in RULE_QUANTITIES:
+        a, b = (np.asarray(t[name], np.float32) for t in (got, want))
+        for kind, at in heads.items():
+            out[f"{name}/{kind}"] = float(
+                np.linalg.norm(a[:, :, at] - b[:, :, at])
+                / np.linalg.norm(b[:, :, at]))
+    return out
 
 
 def rule_agrees(distances: dict, tolerance: dict = RULE_TOLERANCE) -> bool:

@@ -2,8 +2,9 @@
 the source's widths, it runs end to end through the ``train`` driver at small
 widths on four CPU devices, the hand counts behind ``mfu`` and the
 ``gdn96_*_roofline`` metrics, the new readers on a hand-made timeline, the
-comparison's refusal of each wrong mechanism through the builder's own job,
-the refusal of a program that lacks the architecture's fields, and the real
+comparison's refusal of each wrong mechanism through the builder's own job
+and of a step that leaves half its batch or the exchange out, the refusal of
+a program that lacks the architecture's fields, and the real
 four-chip step compiled for the described v5e (nothing runs there; no time
 comes out of it)."""
 
@@ -178,6 +179,8 @@ def driven():
         result = driver.run(cell, args, time.perf_counter())
     finally:
         cells.load_plugin = load
+    # (kept here: the tests of a wrong reference overwrite the job's)
+    result["trainer_losses"] = list(captured["job"].wanted["trainer_losses"])
     return result, captured["job"]
 
 
@@ -195,10 +198,15 @@ def test_the_cell_runs_end_to_end_through_the_train_driver(driven):
             gauges["linattn/key_dim"], gauges["linattn/value_dim"],
             gauges["linattn/neg_eigval"]) == (1, 64, 6, 6, 96, 192, 1)
     assert gauges["attn/full_layers"] == 1
-    # what the four comparisons read
-    assert len(job.gradient_distance) == 10 + 9
+    # what the four comparisons read (the gates' projection by halves too)
+    assert len(job.gradient_distance) == 10 + 9 + 2
     assert max(job.gradient_distance.values()) < 0.01
-    assert set(job.rule_distance) == {"weak", "strong"}
+    assert set(job.rule_distance) == {
+        f"{name}/{kind}" for name in job._reference.RULE_QUANTITIES
+        for kind in ("weak", "strong")}
+    # what the sound replay left for a planted fault to be read against
+    assert set(job.wanted) == {"gradient", "change", "losses",
+                               "trainer_losses"}
 
 
 @pytest.mark.parametrize("fault", ["beta_is_sigmoid", "norm_in_front",
@@ -224,7 +232,13 @@ def test_the_comparison_refuses_each_wrong_mechanism(driven, fault):
         want = reference.rule_by_scan(
             *reference.rule_probe(job._seed, 1024, hyper),
             scan_dtype="bfloat16")
-        assert not reference.rule_agrees(reference.rule_distance(got, want))
+        distance = reference.rule_distance(got, want)
+        assert not reference.rule_agrees(distance)
+        # by the output and, the state's precision in the backward pass, by
+        # the cotangents alone
+        assert not reference.rule_agrees(distance, {
+            k: v for k, v in reference.RULE_TOLERANCE.items()
+            if not k.startswith("o/")})
         return
     trainer_losses = [5.5, 5.5, 5.5]
     losses = job.reference_losses(3, hyper=hyper)
@@ -234,6 +248,50 @@ def test_the_comparison_refuses_each_wrong_mechanism(driven, fault):
     # widths, where these faults read 0.34-1.17 and 1.00-6.67 on every leaf
     assert not reference.gradients_agree(job.gradient_distance, 0.1)
     assert max(job.gradient_distance.values()) > 0.3
+
+
+@pytest.fixture(scope="module")
+def sound(driven):
+    """What a sound replay of the reference leaves on the job (the tests of
+    a wrong reference overwrite it): copies."""
+    result, job = driven
+    trainer_losses = result["trainer_losses"]
+    losses = job.reference_losses(3)
+    assert job.losses_agree(trainer_losses, losses)
+    return dict(job.wanted), dict(job.change_distance)
+
+
+@pytest.mark.parametrize("fault", ["half_batch", "no_exchange"])
+def test_the_comparison_refuses_a_step_that_leaves_its_work_out(driven, sound,
+                                                                fault):
+    """The faults a dp = 4 step can have, planted in the SYSTEM and read
+    against the sound reference by the comparisons that come from the
+    trainer's own step (the first gradient is the builder's own program and
+    sees neither): the step fed half of its batch twice, and the step with
+    every chip keeping its own gradient (no reduce-scatter).  The parameters'
+    change refuses both — here as at the published widths (PERF.md section
+    6 has the chips' readings) — and so do the replayed losses."""
+    _, job = driven
+    wanted, sound_change = sound
+    reference = job._reference
+    tool = cells.load_plugin("tools", "olmo_hybrid_reference_check")
+    change, losses = tool.planted(
+        fault, job, tiny_cell(),
+        cells.load_plugin("builders", "olmo_hybrid"), reference, 3)
+    distance = job.distances(change, wanted["change"])
+    assert not reference.changes_agree(distance)
+    held = [d for name, d in distance.items()
+            if not name.endswith(reference.CHANGE_SKIPPED)]
+    assert min(held) > 0.3 and max(held) > 1.0
+    assert not reference.agree(losses, wanted["losses"],
+                               reference.LOSS_TOLERANCE)
+    # the sound step on the same replay: within every limit
+    assert reference.changes_agree(sound_change)
+    assert reference.agree(wanted["trainer_losses"], wanted["losses"],
+                           reference.LOSS_TOLERANCE)
+    if fault == "no_exchange":
+        # a chip's first loss is computed before any exchange
+        assert losses[0] == pytest.approx(wanted["losses"][0], abs=1e-5)
 
 
 def test_flops_per_token_counts_what_is_computed(builder):

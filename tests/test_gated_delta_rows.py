@@ -155,10 +155,11 @@ def test_the_passes_take_whole_tiles_on_a_tpu(monkeypatch):
     assert rows.rows_supported(128, CELL, 9)     # eight rows' reach: a tile
     assert not rows.rows_supported(128, CELL, 10)
     assert not rows.rows_supported(4096 + 64, CELL, 4)   # no whole row block
-    # heads that are whole tiles in blocks of up to four: Olmo-Hybrid's 30
-    # heads of 96 / 192, 64-lane keys two to a tile; not eight to a tile
+    # heads that are whole tiles in blocks of up to four, key and value
+    # heads as many: Olmo-Hybrid's 30 heads of 96 / 192; not grouped heads
+    # two to a tile, not eight to a tile
     assert rows.rows_supported(8192, (30, 30, 96, 192), 4)
-    assert rows.rows_supported(4096, (16, 32, 64, 128), 4)
+    assert not rows.rows_supported(4096, (16, 32, 64, 128), 4)
     assert not rows.rows_supported(4096, (16, 32, 16, 128), 4)
     # 5 heads of 96: q | k together are no whole number of 384-lane blocks
     assert not rows.rows_supported(4096, (5, 5, 96, 192), 4)

@@ -219,21 +219,51 @@ def test_the_model_with_a_wrong_mechanism_is_refused_too(both):
     assert names == {"linear_attn_norm", "linear_attn", "mlp_norm", "mlp"}
 
 
-def test_the_decay_vectors_are_held_to_a_distance_not_to_a_number():
-    """``A_log`` and ``dt_bias`` (the seed's few slow heads: 0.16 to 0.82 on
-    the chip by the seed) must HAVE a first-gradient distance; every other
-    leaf is within the limit; the change comparison bounds whatever it does
-    not skip."""
-    read = {"block_0/linear_attn/A_log": 0.8, "block_0/linear_attn/conv": 0.2}
+def test_the_decay_vectors_are_held_by_the_median_over_the_layers():
+    """``A_log`` and ``dt_bias`` (one entry a head: the first block's is its
+    one slowest head's sum over the positions, which all but cancels, and
+    reads 0.13 to 0.82 on the chip by the seed) are held by the median over
+    the layers and each to have a distance; every other leaf by itself; the
+    change comparison bounds whatever it does not skip."""
+    decay = {f"block_{i}/linear_attn/A_log": d
+             for i, d in enumerate((0.8, 0.14, 0.07))}
+    read = {**decay, "block_0/linear_attn/conv": 0.2}
     assert ref.gradients_agree(read)
+    assert not ref.gradients_agree(read, median_of=())   # each by itself
     assert not ref.gradients_agree(
         {**read, "block_0/linear_attn/A_log": float("inf")})
+    # two layers of three past the limit: the median is
+    assert not ref.gradients_agree(
+        {**read, "block_1/linear_attn/A_log": 0.6})
     assert not ref.gradients_agree({**read, "block_0/linear_attn/conv": 0.6})
-    assert not ref.gradients_agree(read, unbounded=())
     assert not ref.gradients_agree({})
+    # one layer alone is its own median
+    assert not ref.gradients_agree({"block_0/linear_attn/dt_bias": 0.8})
     assert ref.changes_agree({"block_0/linear_attn/A_log": 0.9,
                               "block_0/mlp/wi_gate/kernel": 0.4})
     assert not ref.changes_agree({"block_0/mlp/wi_gate/kernel": 0.8})
+
+
+def test_the_gates_projection_is_compared_by_halves_too(both):
+    """The ``a`` columns of ``in_proj_ba`` carry the log decay's cotangent a
+    position (what ``A_log``'s entry sums): compared by themselves, and far
+    from a reference without the decay."""
+    name = "block_0/linear_attn/in_proj_ba/kernel"
+    got = {name: both["grads"][0][name]}
+    halves = ref.with_gate_halves(got)
+    assert set(halves) == {name, name + "[b]", name + "[a]"}
+    heads = TINY["linear_num_value_heads"]
+    np.testing.assert_array_equal(halves[name + "[a]"], got[name][:, heads:])
+    want = ref.with_gate_halves({name: both["grads"][1][name]})
+    distance = ref.gradient_distance(halves, want)
+    assert max(distance.values()) < 2e-3
+    with jax.default_matmul_precision("highest"):
+        _, grads = ref.loss_and_grads(
+            both["params"], both["tokens"],
+            {**both["hyper"], "decay": False}, jax.devices()[:1])
+    wrong = ref.gradient_distance(halves, ref.with_gate_halves(
+        {name: flat(grads)[name]}))
+    assert wrong[name + "[a]"] > 0.99       # no decay: no gradient there
 
 
 def test_a_block_needs_a_norm():
@@ -277,39 +307,50 @@ def probe():
 def test_the_rule_holds_the_probe(probe, path, dtype):
     """Keys that are nearly one vector, hardly any decay, write strengths
     near 2 on the even heads and near 0.002 on the odd ones: the chunked
-    rule — ``jax.numpy`` chunks and the kernels, interpreted, at 96 /
-    192-lane heads in a ragged block — stays within the cell's limits of the
-    per-position scan, operands in float32 and in the model's bfloat16."""
-    _, (q, k, v, g, beta), want = probe
+    rule and its chunked VJP — ``jax.numpy`` chunks and the kernels,
+    interpreted, at 96 / 192-lane heads in a ragged block — stay within the
+    cell's limits of the per-position scan and the scan's VJP, operands in
+    float32 and in the model's bfloat16."""
+    _, (q, k, v, g, beta, do), want = probe
     assert float(beta[..., ::2].max()) > 1.99
     assert float(jnp.median(beta[..., ::2])) > 1.9
     assert float(beta[..., 1::2].max()) < 0.02
     q, k, v = (t.astype(dtype) for t in (q, k, v))
-    got = gd.gated_delta_rule(q, k, v, g, beta, force=path == "kernel",
-                              interpret=True)
+    got = ref.rule_with_cotangents(
+        lambda *a: gd.gated_delta_rule(*a, force=path == "kernel",
+                                       interpret=True), q, k, v, g, beta, do)
     distance = ref.rule_distance(got, want)
+    assert set(distance) == {f"{name}/{kind}" for name in ref.RULE_QUANTITIES
+                             for kind in ("weak", "strong")}
     assert ref.rule_agrees(distance), distance
     if dtype == "float32":
         assert max(distance.values()) < 1e-4, distance
     else:
         # the limits' room: three times and more over the readings
-        assert distance["weak"] < ref.RULE_TOLERANCE["weak"] / 3
-        assert distance["strong"] < ref.RULE_TOLERANCE["strong"] / 1.5
+        assert distance["o/weak"] < ref.RULE_TOLERANCE["o/weak"] / 3
+        assert distance["o/strong"] < ref.RULE_TOLERANCE["o/strong"] / 1.5
 
 
 def test_the_probe_refuses_a_bfloat16_state(probe):
     """What the three comparisons of the step cannot see at a fresh model's
     decays: the scan with its state and decay kept in bfloat16 drops the
-    weak heads' increments and is past their limit, five times and more."""
+    weak heads' increments and is past their limit, five times and more, on
+    the output; the backward pass reads the rounded states, and the weak
+    heads' cotangents are past theirs."""
     _, args, want = probe
     rounded = ref.rule_by_scan(*args, scan_dtype="bfloat16")
     distance = ref.rule_distance(rounded, want)
     assert not ref.rule_agrees(distance), distance
-    assert distance["weak"] > 5 * ref.RULE_TOLERANCE["weak"]
+    assert distance["o/weak"] > 5 * ref.RULE_TOLERANCE["o/weak"]
+    backward = {k: v for k, v in ref.RULE_TOLERANCE.items()
+                if not k.startswith("o/")}
+    assert backward and not ref.rule_agrees(distance, backward), distance
 
 
 def test_the_builders_fourth_comparison_reads_the_systems_rule(probe):
     hyper, args, want = probe
     got = builder.system_rule(ref, 2 ** 31 + 7, 1024, hyper, jnp.bfloat16)
-    assert got.dtype == jnp.bfloat16 and got.shape == want.shape
+    assert set(got) == set(ref.RULE_QUANTITIES)
+    assert got["o"].dtype == jnp.bfloat16 and got["o"].shape == want["o"].shape
+    assert got["dg"].dtype == jnp.float32
     assert ref.rule_agrees(ref.rule_distance(got, want))

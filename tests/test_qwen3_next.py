@@ -372,7 +372,9 @@ def value_and_cotangents(fn, args, do):
 #: does not cover (eight heads to a lane tile); and heads that are no whole lane tile (Olmo-Hybrid's
 #: 96-lane keys under 192-lane values, write strengths up to 2): six heads,
 #: a block of four and a ragged one of two, over a ragged length of more
-#: than one block of chunks
+#: than one block of chunks — at decays of a position or two, and at decays
+#: near 1, the slow heads whose log decay's cotangent is a long sum (the
+#: leaf the Olmo-Hybrid cell's first gradient reads furthest from float32)
 DELTA_CASES = {
     "whole_chunks": (128, 1, 2, 128, -1.0),
     "ragged_blocks": (600, 1, 1, 128, 0.0),
@@ -380,6 +382,7 @@ DELTA_CASES = {
     "forgets_at_once": (100, 1, 2, 128, 3.0),
     "narrow_heads": (80, 2, 4, 16, -1.0),
     "heads_96_192_ragged_blocks": (600, 6, 6, (96, 192), -1.0),
+    "heads_96_192_hardly_decay": (600, 6, 6, (96, 192), -7.0),
 }
 NAMES = ("o", "dq", "dk", "dv", "dg", "dbeta")
 
@@ -484,8 +487,11 @@ def test_the_kernels_take_whole_lane_tiles_on_a_tpu(monkeypatch):
     assert gd.gated_delta_supported(2, 2, 128, 256, jnp.float32)
     # no whole tile a head, whole tiles four heads together; any head count
     assert gd.gated_delta_supported(30, 30, 96, 192)
-    assert gd.gated_delta_supported(16, 32, 64, 128)
+    assert gd.gated_delta_supported(6, 6, 64, 128)     # two heads to a block
+    # several heads to a block only where key and value heads are as many
+    assert not gd.gated_delta_supported(16, 32, 64, 128)
     assert not gd.gated_delta_supported(16, 32, 16, 128)
+    assert not gd.gated_delta_supported(8, 8, 16, 128)  # eight to a tile
     assert not gd.gated_delta_supported(16, 24, 128, 128)
     assert not gd.gated_delta_supported(16, 32, 128, 128, jnp.float16)
 
