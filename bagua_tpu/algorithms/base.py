@@ -28,7 +28,6 @@ from typing import Any, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
-import optax
 
 logger = logging.getLogger(__name__)
 
@@ -105,9 +104,10 @@ class AlgorithmContext:
     #: when ``BAGUA_EF_RESIDUAL=off`` (the stateless honesty control).
     #: :meth:`Algorithm.ef_codec` gates on it.
     ef_enabled: bool = False
-    #: the exact family's exchange is reduce-scatter -> update of the owned
-    #: chunk -> all-gather (``BaguaTrainer._mark_sharded_update`` says where):
-    #: optimizer state arrives as each bucket's owned chunk, and
+    #: the exact family's exchange is all-gather of the resident chunks ->
+    #: loss -> reduce-scatter -> update of the owned chunk
+    #: (``BaguaTrainer._mark_sharded_update`` says where): parameters and
+    #: optimizer state arrive as each bucket's owned chunk, and
     #: :meth:`update_sharded` says bucket by bucket whether it is taken
     sharded_update: bool = False
 
@@ -386,8 +386,8 @@ class AlgorithmContext:
                 f, op, num_chunks=k))
         return self.hierarchical_allreduce(flat, op, hierarchical)
 
-    # -- this rank's chunk of a bucket (ZeRO, the exact family's sharded
-    # update) --------------------------------------------------------------
+    # -- this rank's chunk of a bucket (ZeRO slices it out of a whole buffer;
+    # under the exact family's sharded update it is what rests) -------------
 
     def owned_chunk(self, buf, comm: Optional[BaguaCommunicator] = None):
         """This rank's chunk of a bucket buffer over ``comm`` (default: the
@@ -432,36 +432,21 @@ class AlgorithmContext:
                 and self.plan.buckets[index].buffer_shape[0]
                 % self.comm.nranks() == 0)
 
-    def update_owned(self, optimizer, params, grads, opt_state):
-        """The trainer's optimizer stage under the sharded update
-        (flat-resident containers): ``grads`` and ``opt_state`` hold, for a
-        bucket :meth:`update_sharded` takes, this rank's chunk (the
-        reduce-scatter's result; the moments stored so) and the whole
-        buffer for every other.  Steps the matching chunk of the
-        parameters — an elementwise transform's arithmetic is the
-        replicated update's, a chunk at a time — and gathers the new chunks
-        into the resident buffers."""
-        taken = [i for i in range(len(self.plan.buckets))
-                 if self.update_sharded(i)]
-        owned = list(params["flats"])
-        for i in taken:
-            owned[i] = self.owned_chunk(owned[i])
-        owned = {"flats": tuple(owned), "local": params["local"]}
-        updates, opt_state = optimizer.update(grads, opt_state, owned)
-        owned = optax.apply_updates(owned, updates)
-        flats = list(owned["flats"])
-        for i in taken:
-            # (inside the trainer's bagua.optimizer scope the collective
-            # names itself bagua.comm/...: the innermost bagua.* scope wins).
-            # Handed the gather's result as the new parameter the TPU
-            # compiler copies every parameter twice, out of its donated
-            # buffer and back into it (11 ms of bert-large's dp4 step);
-            # writing the chunks into the resident buffer in place
-            # (dynamic_update_slice) trades that for a pass and a slower
-            # gather and read the same step: PERF.md §6, PR 49
-            with phase_scope(f"bagua.comm/bucket_{i}"):
-                flats[i] = self.bucket_allgather(flats[i])
-        return {"flats": tuple(flats), "local": owned["local"]}, opt_state
+    def gather_resident(self, params):
+        """The whole parameter buffers of a flat-resident container whose
+        buckets rest as they are updated: a bucket :meth:`update_sharded`
+        takes is this rank's chunk of it (the reduce-scatter's chunk; the
+        moments rest so too) and is gathered, every other passes through.
+        The trainer calls it once at the top of a step, before anything
+        reads whole parameters — the gather's result is a temporary, never
+        the next step's resident buffer, so the compiler copies no parameter
+        around it (PERF.md §6, PR 57) — and the evaluation step likewise."""
+        flats = list(params["flats"])
+        for i in range(len(flats)):
+            if self.update_sharded(i):
+                with phase_scope(f"bagua.comm/bucket_{i}"):
+                    flats[i] = self.bucket_allgather(flats[i])
+        return {"flats": tuple(flats), "local": params["local"]}
 
     # -- bandwidth-tier-aware launch schedule ------------------------------
 
@@ -663,8 +648,10 @@ class Algorithm:
     #: exact per-bucket sum or average that :meth:`reduce_bucket_grad` can
     #: hand back as this rank's chunk alone
     #: (:meth:`AlgorithmContext.update_sharded`), so that the trainer's
-    #: optimizer steps the owned chunk and gathers the parameters
-    #: (:meth:`AlgorithmContext.update_owned`).
+    #: optimizer steps the owned chunk, which is what rests between steps
+    #: (:meth:`AlgorithmContext.gather_resident`).  Such a family leaves the
+    #: weights alone in :meth:`process_pre_step` / :meth:`process_post_step`:
+    #: the second is handed the updated chunks, not whole buffers.
     supports_sharded_update: bool = False
 
     def need_reset(self, step: int) -> bool:

@@ -10,35 +10,45 @@ gradient is exchanged where it becomes ready, in one of two forms:
   model-parallel meshes, a transform that is not elementwise, an
   error-feedback codec on the wire, a ``comm_dtype`` narrower than the
   parameters.
-- **reduce-scatter -> update of the owned chunk -> all-gather** — wherever
-  the trainer can take it (``BaguaTrainer._mark_sharded_update``: more than
-  one rank on a pure-dp mesh, bucket-flat state, an elementwise optimizer,
-  a wire as wide as the parameters; no option selects it).  An all-reduce
-  IS that pair of collectives, so the wire carries the same bytes and every
-  rank ends the step with the same parameters; between the two halves a rank holds the reduced gradient of
-  its own chunk alone, steps that chunk's parameters and moments, and
-  stores no other rank's moments (arXiv:2004.13336, the weight-update
-  sharding XLA does for replicated training).  A chunk is **rows of the
-  bucket**: the leading axis of a shaped bucket's tensor, a run of a 1-D
-  flat (``AlgorithmContext.update_sharded`` / ``owned_chunk``) — never a
-  ravel of a matrix, which on a TPU is a re-tiling copy (bucket.py).  The
-  reduce-scatter carries ``comm_dtype`` where one is set; the all-gather
-  always carries the parameters' own dtype, which is why a narrower wire
-  keeps the all-reduce: bfloat16 gradients over float32 parameters would
-  move 3/4 of the float32 exchange's bytes where the bfloat16 all-reduce
-  moves 1/2 (bert-large on four v5e chips: 31,633 against 34,328
-  tokens/s/chip, PERF.md §6, PR 49).
+- **all-gather of the resident chunks -> loss -> reduce-scatter -> update
+  of the owned chunk** — wherever the trainer can take it
+  (``BaguaTrainer._mark_sharded_update``: more than one rank on a pure-dp
+  mesh, bucket-flat state, an elementwise optimizer, a wire as wide as the
+  parameters; no option selects it).  An all-reduce IS that pair of
+  collectives; behind the reduce-scatter a rank holds the reduced gradient
+  of its own chunk alone, steps that chunk's parameters and moments, and
+  stores no other rank's (arXiv:2004.13336, the weight-update sharding XLA
+  does for replicated training, in ZeRO-3's order).  **Between steps
+  ``state.params`` holds each such bucket as a global array of unchanged
+  shape, cut over the comm axes along its leading axis like its moments**
+  (``BaguaTrainer._resident_specs``); the step gathers the buffers once, at
+  its top (``AlgorithmContext.gather_resident``), and returns the updated
+  chunks.  The gather's result is a temporary, never the donated parameter
+  buffer: a gather at the END of the step cost two whole-parameter copies
+  (11 ms of bert-large's 107 ms step on four chips), and in front of the
+  forward the compiler moves the cast the matrices are read through before
+  it, so the wire carries bfloat16 (PERF.md §6, PR 57).  A chunk is **rows
+  of the bucket**: the leading axis of a shaped bucket's tensor, a run of a
+  1-D flat (``AlgorithmContext.update_sharded``) — never a ravel of a
+  matrix, which on a TPU is a re-tiling copy (bucket.py).  The
+  reduce-scatter carries ``comm_dtype`` where one is set; the all-gather is
+  written in the parameters' own dtype, which is why a narrower wire still
+  keeps the all-reduce: with a float32 gather and the copies the sharded
+  form read 7.9 % slower there (bert-large on four v5e chips: 31,633
+  against 34,328 tokens/s/chip, PERF.md §6, PR 49; §7 has the arithmetic
+  with the bfloat16 gather).
 
 Either way the collectives of the compiled step are *synchronous* on a TPU
 today: the compiler combines the per-bucket calls into a few large ones and
 none of them overlaps backward compute, so the exchange is exposed (PERF.md
 §5 and §6, "the dp4 exchange, read off the chip").  The overlap the
 reference's Rust scheduler + dedicated CUDA stream bought is NOT had for
-free here; ROADMAP.md Queue 1 item 1 holds what was tried.  What the second
+free here; ROADMAP.md Queue 1 item 2 holds what was tried.  What the second
 form saves is the update the exchange forces into the open — a pass over a
 1/world of the state instead of all of it — and (world − 1)/world of the
-moments' memory (bert-large, four chips: 27,048 -> 28,755 tokens/s/chip,
-10.31 -> 7.77 GB; PERF.md §5).
+memory of parameters and moments between steps (bert-large, four chips:
+27,048 -> 28,755 tokens/s/chip, 10.31 -> 7.77 GB with the moments, PR 49;
+PERF.md §5 has the parameters', PR 57).
 """
 
 from __future__ import annotations
@@ -61,7 +71,8 @@ class GradientAllReduceAlgorithm(Algorithm):
     #: contribution from any rank survives into every rank's copy), so the
     #: gradient-health sentinel rides them with no extra collective — except
     #: under the sharded update, where a rank holds its own chunk of the sum
-    #: alone and the trainer reads the verdict off the gathered parameters
+    #: alone and the trainer reads the verdict off the updated chunks (one
+    #: tiny ``pmin`` makes it the same on every rank)
     grad_health_replicated = True
     #: the per-bucket flat reduction can carry an error-feedback residual
     #: when the codec policy forces a stateful codec (onebit_ef / topk)

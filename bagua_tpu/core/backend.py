@@ -917,7 +917,7 @@ class BaguaTrainer:
         migrations run first) — an autotune family switch immediately
         followed by its alignment rebucket must apply both, in order."""
         prev = self._pending_state_migration
-        self._pending_state_migration = lambda state: self._place_opt_state(
+        self._pending_state_migration = lambda state: self._place_state(
             fn(state if prev is None else prev(state)))
 
     @staticmethod
@@ -1175,13 +1175,13 @@ class BaguaTrainer:
                 # qadam): params live as the bucket flats; optimizer state
                 # is built directly IN flat layout, so the update runs on
                 # the flats natively — never a leaf-shaped moment in sight
+                resident, moments = self._state_shardings(plan, replicated)
                 zparams = jax.jit(
                     lambda p: {"flats": tuple(plan.flatten_tree(p)),
                                "local": {}},
-                    out_shardings=replicated,
+                    out_shardings=resident,
                 )(params)
-                opt_state = jax.jit(opt_init, out_shardings=self._opt_state_shardings(
-                    plan, replicated))(zparams)
+                opt_state = jax.jit(opt_init, out_shardings=moments)(zparams)
 
                 def init_fn(p):
                     return algo.init_state(ctx, p)
@@ -1335,9 +1335,9 @@ class BaguaTrainer:
         # next exchange re-syncs a skipped rank) and no health collective
         # is added
         local_health = not algo.replicated_params
-        mp_health = (
+        mp_health = (  # (or its own chunks of the parameters alone)
             self.expert_axis is not None or self._shard_axis is not None
-        )
+            or ctx.sharded_update)
         health_axes = tuple(a for a in mesh.axis_names if mesh.shape[a] > 1)
         replicated = algo.replicated_params
         expert = self.expert_axis
@@ -1382,9 +1382,7 @@ class BaguaTrainer:
         # shared with a checkout that predates the phase scopes would hand
         # back an executable without them under the old name
         def bagua_step(state: TrainState, batch):
-            params = state.params
-            opt_state = state.opt_state
-            algo_state = state.algo_state
+            step, params, opt_state, algo_state = state
             if stacked:
                 params, opt_state, algo_state = (
                     _unstack(params), _unstack(opt_state), _unstack(algo_state)
@@ -1393,7 +1391,9 @@ class BaguaTrainer:
                 opt_state = {"buckets": _unstack(opt_state["buckets"]),
                              "local": opt_state["local"]}
                 algo_state = _unstack(algo_state)
-            step = state.step
+            resident = params  # (sharded update: this rank's chunks, gathered
+            if ctx.sharded_update:  # once a step, outside the micro-batch loop)
+                params = ctx.gather_resident(resident)
 
             if self.accum_steps > 1:
                 accum = self.accum_steps
@@ -1539,16 +1539,16 @@ class BaguaTrainer:
                     params, opt_state, algo_state = algo.optimizer_update(
                         ctx, params, grads, opt_state, algo_state, step
                     )
-                elif ctx.sharded_update:
-                    # the comm stage left this rank its own chunk of each
-                    # reduced bucket: step that chunk of the parameters and
-                    # of the moments (stored as chunks), gather the rest
-                    params, opt_state = ctx.update_owned(
-                        self._opt, params, grads, opt_state)
                 else:
+                    # sharded update: the comm stage left this rank its own
+                    # chunk of each reduced bucket, and the parameters and
+                    # the moments rest as those chunks — an elementwise
+                    # transform steps a chunk as it steps the whole, and the
+                    # next step gathers what it reads
+                    owned = resident if ctx.sharded_update else params
                     updates, opt_state = self._opt.update(grads, opt_state,
-                                                          params)
-                    params = optax.apply_updates(params, updates)
+                                                          owned)
+                    params = optax.apply_updates(owned, updates)
                 params, algo_state = algo.process_post_step(
                     ctx, params, algo_state, step)
             if guard != "off" and not replicated_health:
@@ -1562,9 +1562,9 @@ class BaguaTrainer:
                 # must be — ZeRO's allgather spreads a poisoned chunk into
                 # every rank's params, QAdam's momentum allreduce is
                 # replicated, gossip replicas are per-rank by design (each
-                # rank rewinds its own).  Model-parallel slices live only
-                # on their shard, so those meshes fuse verdicts with one
-                # tiny pmin.
+                # rank rewinds its own).  Model-parallel slices, and the
+                # sharded update's resident chunks, live only on their
+                # shard, so those fuse verdicts with one tiny pmin.
                 with phase_scope("bagua.guard"):
                     health_vec = self._grad_health_vec(plan, params)
                     if mp_health and health_axes:
@@ -1636,11 +1636,11 @@ class BaguaTrainer:
             # the EF residual (when an error-feedback codec is active) is
             # the one replicated-family algo state with a per-rank stacked
             # leading axis; shard_map slices each rank's [1, pad] row
-            # (sharded update: the moments' buffers are cut over the ranks)
+            # (sharded update: parameters and moments cut over the ranks)
+            cut = ctx.sharded_update
             state_specs = TrainState(
-                step=P(), params=pspec, opt_state=(
-                    self._opt_state_specs(plan) if ctx.sharded_update
-                    else pspec),
+                step=P(), params=self._resident_specs(plan) if cut else pspec,
+                opt_state=self._opt_state_specs(plan) if cut else pspec,
                 algo_state=algo.algo_state_specs(ctx, pspec,
                                                  P(self.comm_axes)),
             )
@@ -2212,10 +2212,14 @@ class BaguaTrainer:
         else:
             loss_on = self.loss_fn
 
+        ctx = self._ctx(self._plan)
+
         def per_shard(state: TrainState, batch):
             params = state.params
             if stacked:
                 params = jax.tree.map(lambda x: x[0], params)
+            if ctx.sharded_update:
+                params = ctx.gather_resident(params)
             rows = jax.tree.leaves(batch)[0].shape[0]
             accum = self.accum_steps if rows % self.accum_steps == 0 else 1
             if accum > 1:
@@ -2251,7 +2255,8 @@ class BaguaTrainer:
         self._get_step_fn()
         key = (self._plan.signature(), self._phase,
                self.algorithm.hierarchical, type(self.algorithm).__name__,
-               self.algorithm.compile_key())  # eval has no comm-stage overlap
+               self.algorithm.compile_key(),  # eval has no comm-stage overlap
+               self._update_sharded())
         if getattr(self, "_eval_key", None) != key:
             self._eval_fn = self._make_eval_fn(self._state_specs,
                                                self._batch_spec())
@@ -3038,7 +3043,7 @@ class BaguaTrainer:
                 lambda s: self._restore_checkpoint_at(manager, state_like, s)
             )
         self.algorithm.on_restore(self)
-        return result[0], self._place_opt_state(result[1])
+        return result[0], self._place_state(result[1])
 
     def _restore_checkpoint_at(self, manager, state_like: TrainState,
                                step: int):
@@ -3491,13 +3496,16 @@ class BaguaTrainer:
         them a data-parallel replica (the flat-resident layout implies no
         tp / pp / ep axis), a flat exchange, an optimizer that steps a chunk
         as it steps the whole, and no error-feedback residual riding whole
-        buckets — and a wire no narrower than the parameters: the gather
-        carries the parameters' own dtype, so under ``comm_dtype=bfloat16``
-        over float32 parameters the pair moves three quarters of the
-        float32 all-reduce's bytes where the bfloat16 all-reduce moves
-        half, and the chip read that 7.9 % slower (PERF.md §6, PR 49).
-        Everything else traces the all-reduce and the replicated update it
-        always did."""
+        buckets — and a wire no narrower than the parameters: with a
+        float32 gather at the end of the step the pair moved three quarters
+        of the float32 all-reduce's bytes where the bfloat16 all-reduce
+        moves half, and the chip read that 7.9 % slower (PERF.md §6, PR 49;
+        whether the clause still earns its place with the gather at the top
+        is PERF.md §7's open question, PR 57).  Where it holds, the taken
+        buckets of ``state.params`` and of the moments rest between steps as
+        a chunk a rank (:meth:`_resident_specs`: global shapes unchanged)
+        and the step gathers the parameters at its top.  Everything else
+        traces the all-reduce and the replicated update it always did."""
         algo = self.algorithm
         wire = getattr(algo, "comm_dtype", None)
         ctx.sharded_update = bool(
@@ -3516,50 +3524,74 @@ class BaguaTrainer:
 
     def _update_sharded(self) -> bool:
         """Whether the CURRENT configuration shards the update
-        (:attr:`AlgorithmContext.sharded_update`): the optimizer state is
-        then laid out over the comm axes (:meth:`_opt_state_specs`)."""
+        (:attr:`AlgorithmContext.sharded_update`): parameters and optimizer
+        state are then laid out over the comm axes (:meth:`_resident_specs`,
+        :meth:`_opt_state_specs`)."""
         return self.world_size > 1 and self._ctx(self._plan).sharded_update
+
+    def _resident_specs(self, plan: BucketPlan):
+        """``shard_map`` specs of the flat-resident parameter container
+        under the sharded update: a bucket buffer whose update is sharded
+        (:meth:`AlgorithmContext.update_sharded`) is cut over the comm axes
+        along its leading axis — globally it is the buffer the replicated
+        layout holds, in the same shape; each rank stores its chunk of it,
+        the rows it updates, and the step gathers the rest at its top — and
+        every other bucket is replicated."""
+        ctx = self._ctx(plan)
+        return {"flats": tuple(
+            P(self.comm_axes) if ctx.update_sharded(i) else P()
+            for i in range(len(plan.buckets))), "local": {}}
 
     def _opt_state_specs(self, plan: BucketPlan):
         """``shard_map`` specs of the optimizer state under the sharded
-        update, a pytree over ``self._opt``'s state: a bucket buffer whose
-        update is sharded is cut over the comm axes along its leading axis
-        — globally it is the buffer the replicated layout holds, in the same
-        shape; each rank stores its chunk of it — and everything else
-        (other buckets, counts) is replicated."""
-        ctx = self._ctx(plan)
-        flats = tuple(P(self.comm_axes) if ctx.update_sharded(i) else P()
-                      for i in range(len(plan.buckets)))
+        update, a pytree over ``self._opt``'s state: every moment rests as
+        the parameters do (:meth:`_resident_specs`), and everything else
+        (counts) is replicated."""
+        resident = self._resident_specs(plan)
         like = {"flats": tuple(jax.ShapeDtypeStruct(b.buffer_shape, b.dtype)
                                for b in plan.buckets), "local": {}}
         is_zp = self._is_flat_container
         return jax.tree.map(
-            lambda x: {"flats": flats, "local": {}} if is_zp(x) else P(),
+            lambda x: resident if is_zp(x) else P(),
             jax.eval_shape(self._opt.init, like), is_leaf=is_zp)
 
-    def _opt_state_shardings(self, plan: BucketPlan, replicated):
-        """``replicated``, or under the sharded update the pytree of
-        shardings :meth:`_opt_state_specs` describes (an elementwise
-        ``init`` of the whole, cut, is the init of the chunk)."""
+    def _state_shardings(self, plan: BucketPlan, replicated):
+        """``(parameters', optimizer state's)`` shardings: ``replicated``
+        twice, or under the sharded update the pytrees of shardings that
+        :meth:`_resident_specs` and :meth:`_opt_state_specs` describe (an
+        elementwise ``init`` of the whole, cut, is the init of the chunk)."""
         if not self._update_sharded():
-            return replicated
-        return jax.tree.map(lambda spec: NamedSharding(self.mesh, spec),
-                            self._opt_state_specs(plan),
-                            is_leaf=lambda x: isinstance(x, P))
+            return replicated, replicated
 
-    def _place_opt_state(self, state: TrainState) -> TrainState:
-        """``state`` with its optimizer state placed as the sharded update's
-        step takes it (every queued state migration and every restore ends
-        here).  A migration or a restore builds it replicated, or as the
-        compiler pleased: the step would accept that — the layouts differ
-        in placement, not in shape — at the price of a program of its own
-        for that one dispatch."""
-        shardings = self._opt_state_shardings(self._plan, None)
-        if shardings is None or jax.tree.structure(
-                shardings) != jax.tree.structure(state.opt_state):
-            return state  # (or a displaced family's state: not this layout's)
-        return state._replace(
-            opt_state=jax.device_put(state.opt_state, shardings))
+        def named(specs):
+            return jax.tree.map(lambda spec: NamedSharding(self.mesh, spec),
+                                specs, is_leaf=lambda x: isinstance(x, P))
+
+        return (named(self._resident_specs(plan)),
+                named(self._opt_state_specs(plan)))
+
+    def _opt_state_shardings(self, plan: BucketPlan, replicated):
+        """The optimizer state's half of :meth:`_state_shardings`."""
+        return self._state_shardings(plan, replicated)[1]
+
+    def _place_state(self, state: TrainState) -> TrainState:
+        """``state`` with its parameters and its optimizer state placed as
+        the sharded update's step takes them (``init`` builds them so; every
+        queued state migration and every restore ends here).  A migration or
+        a restore builds them replicated, or as the compiler pleased: the
+        step would accept that — the layouts differ in placement, not in
+        shape — at the price of a program of its own for that one
+        dispatch."""
+        placed = {}
+        for field, shardings in zip(
+                ("params", "opt_state"),
+                self._state_shardings(self._plan, None)):
+            tree = getattr(state, field)
+            # (a displaced family's state is not this layout's)
+            if shardings is not None and jax.tree.structure(
+                    shardings) == jax.tree.structure(tree):
+                placed[field] = jax.device_put(tree, shardings)
+        return state._replace(**placed)
 
     def _note_plan_gauges(self) -> None:
         """What the plan of the step program just built asks for."""
@@ -3577,9 +3609,10 @@ class BaguaTrainer:
             sum(n for n, b in zip(nbytes, self._plan.buckets)
                 if b.shaped) / max(1, sum(nbytes)))
         # ... and how much of it is updated by the rank that owns its
-        # chunk alone (reduce-scatter -> update -> all-gather)
+        # chunk alone (all-gather -> loss -> reduce-scatter -> update), which
+        # is how much of it rests as a chunk a rank between steps
         ctx = self._ctx(self._plan)
-        counters.set_gauge(
-            "comm/sharded_update_share",
-            sum(n for i, n in enumerate(nbytes)
-                if ctx.update_sharded(i)) / max(1, sum(nbytes)))
+        share = sum(n for i, n in enumerate(nbytes)
+                    if ctx.update_sharded(i)) / max(1, sum(nbytes))
+        counters.set_gauge("comm/sharded_update_share", share)
+        counters.set_gauge("comm/params_sharded_share", share)

@@ -116,11 +116,18 @@ _declare("comm/shaped_bytes_share", "gauge",
 _declare("comm/sharded_update_share", "gauge",
          "Share of the bucket plan's parameter bytes whose update is taken "
          "by the rank that owns their chunk alone: the exact family's "
-         "exchange is then reduce-scatter, update of the owned rows, "
-         "all-gather, and the optimizer state of those buckets is stored "
-         "as 1/world of it a rank.  0 on one chip and wherever the "
-         "all-reduce and the replicated update stand.  Set when a step "
-         "program is built.")
+         "exchange is then all-gather of the resident chunks, loss, "
+         "reduce-scatter, update of the owned rows, and the optimizer "
+         "state of those buckets is stored as 1/world of it a rank.  0 on "
+         "one chip and wherever the all-reduce and the replicated update "
+         "stand.  Set when a step program is built.")
+_declare("comm/params_sharded_share", "gauge",
+         "Share of the bucket plan's parameter bytes that rest between "
+         "steps as 1/world of them a rank, cut along the leading axis "
+         "like their moments (state.params keeps its global shapes; the "
+         "step gathers the buffers at its top and returns the updated "
+         "chunks).  0 on one chip and wherever the parameters stay "
+         "replicated.  Set when a step program is built.")
 # -- attention kinds (set when a TransformerLM step is traced) --
 _declare("attn/kv_heads", "gauge",
          "Key / value heads of the model last traced (on this tensor-"

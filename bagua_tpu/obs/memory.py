@@ -79,7 +79,8 @@ def static_footprint(trainer, state) -> Dict[str, Any]:
     * ``params_bytes`` / ``opt_state_bytes`` / ``algo_state_bytes`` — the
       live :class:`TrainState` leaves' shard sizes.  Under the
       flat-resident layout the params/opt leaves ARE the bucket flats, so
-      this matches the ``BucketPlan`` avals exactly (pinned in
+      this matches the ``BucketPlan`` avals exactly — 1/world of a bucket
+      whose update is sharded, parameter and moment alike (pinned in
       ``tests/test_ledger.py``).
     * ``grad_flats_bytes`` — one set of per-bucket gradient flats
       (:func:`plan_flat_bytes`): the dominant transient the compiled step

@@ -273,8 +273,8 @@ def test_sum_reduction_rides_the_sharded_update(monkeypatch):
 
 def test_chunks_are_rows_of_the_bucket_and_nothing_is_raveled():
     """A shaped bucket is scattered over its leading axis, its owned rows
-    are a slice of that axis, the gather puts rows back: no reshape of a
-    bucket's buffer to 1-D anywhere in the step."""
+    are a chunk of that axis, the gather puts rows together: no reshape of
+    a bucket's buffer to 1-D anywhere in the step."""
     loss_fn, params = _mlp_task()
     trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
                            GradientAllReduceAlgorithm(), bucket_bytes=256)
@@ -302,12 +302,13 @@ def test_chunks_are_rows_of_the_bucket_and_nothing_is_raveled():
     psums = [e.invars[0].aval.shape for e in eqns
              if e.primitive.name == "psum" and e.invars[0].aval.ndim]
     assert sorted(psums) == sorted(shapes - set(taken))
-    # owned rows: a dynamic slice of the leading axis of the buffer itself
-    for shape in taken:
-        rows = (shape[0] // N,) + shape[1:]
-        assert any(e.primitive.name == "dynamic_slice"
-                   and e.invars[0].aval.shape == shape
-                   and e.outvars[0].aval.shape == rows for e in eqns)
+    # owned rows: what the step is handed (the parameters rest as chunks of
+    # the leading axis), so the gathers take rows and nothing slices a
+    # taken buffer
+    rows = sorted((shape[0] // N,) + shape[1:] for shape in taken)
+    assert sorted(e.invars[0].aval.shape for e in gathers) == rows
+    assert not any(e.primitive.name == "dynamic_slice"
+                   and e.invars[0].aval.shape in taken for e in eqns)
     for e in eqns:
         if e.primitive.name == "reshape":
             src, dst = e.invars[0].aval.shape, e.outvars[0].aval.shape
@@ -315,7 +316,8 @@ def test_chunks_are_rows_of_the_bucket_and_nothing_is_raveled():
                         and src in shapes), (src, dst)
 
 
-def test_a_rank_stores_a_world_th_of_the_moments(monkeypatch):
+def test_a_rank_stores_a_world_th_of_the_moments_and_the_parameters(
+        monkeypatch):
     from bagua_tpu.obs.memory import tree_device_bytes
 
     # 2,336 parameters in one packed flat: 8 ranks divide it
@@ -340,8 +342,9 @@ def test_a_rank_stores_a_world_th_of_the_moments(monkeypatch):
     count = 4  # adamw's step count, replicated
     assert tree_device_bytes(st_a.opt_state) - count == pytest.approx(
         (tree_device_bytes(st_b.opt_state) - count) / N, rel=0.01)
+    # ... and of the parameters, which rest as the moments do
     assert tree_device_bytes(st_a.params) == pytest.approx(
-        tree_device_bytes(st_b.params), rel=0.01)
+        tree_device_bytes(st_b.params) / N, rel=0.01)
 
 
 def _one_rank_mesh():
@@ -600,3 +603,381 @@ def test_the_guards_verdict_is_rank_uniform_under_a_poisoned_gradient(guard):
             np.testing.assert_array_equal(a, np.asarray(b))
     else:
         assert not all(np.isfinite(x).all() for x in jax.tree.leaves(after))
+
+
+# ---- the parameters rest as the moments do (PR 57) ---------------------------
+#
+# Wherever a bucket's update is sharded its parameter buffer rests between
+# steps as a chunk a rank, like its moments; the step gathers it at its top.
+# The parent's order — parameters replicated, a rank slices its chunk out,
+# steps it and gathers every chunk at the END of the step — lives on here as
+# the reference the new order is held to, bit for bit.
+
+
+def _dp_mesh(dp):
+    from bagua_tpu.parallel.mesh import build_mesh
+
+    return build_mesh({"dp": dp}, jax.devices()[:dp])
+
+
+def _gather_at_the_end(trainer):
+    """The parent's step (PR 49's ``update_owned``) over the trainer's own
+    plan, exchange and optimizer: ``(whole parameters, moments, batch) ->
+    (whole parameters, moments, loss)``."""
+    from jax.sharding import PartitionSpec as P
+
+    from bagua_tpu.communication import ReduceOp
+
+    plan = trainer._plan
+    ctx = trainer._ctx(plan)
+    taken = [i for i in range(len(plan.buckets)) if ctx.update_sharded(i)]
+
+    def loss_on(zp, batch):
+        return trainer.loss_fn(trainer._flat_leaf_view(zp), batch)
+
+    def step(params, opt_state, batch):
+        loss, grads = jax.value_and_grad(loss_on)(params, batch)
+        grads, _ = trainer.algorithm.process_grads(ctx, grads, params, None, 0)
+        owned = list(params["flats"])
+        for i in taken:
+            owned[i] = ctx.owned_chunk(owned[i])
+        owned = {"flats": tuple(owned), "local": params["local"]}
+        updates, opt_state = trainer._opt.update(grads, opt_state, owned)
+        owned = optax.apply_updates(owned, updates)
+        flats = list(owned["flats"])
+        for i in taken:
+            flats[i] = ctx.bucket_allgather(flats[i])
+        return ({"flats": tuple(flats), "local": owned["local"]}, opt_state,
+                ctx.comm.allreduce(loss, ReduceOp.AVG))
+
+    moments = trainer._opt_state_specs(plan)
+    return jax.jit(jax.shard_map(
+        step, mesh=trainer.mesh, in_specs=(P(), moments, trainer._batch_spec()),
+        out_specs=(P(), moments, P()), check_vma=False))
+
+
+def _whole(tree):
+    return [np.asarray(x) for x in jax.tree.leaves(tree)]
+
+
+@pytest.mark.parametrize("dp", [4, 8])
+@pytest.mark.parametrize("optimizer", sorted(_OPTIMIZERS))
+def test_gather_at_the_top_is_bitwise_the_gather_at_the_end(optimizer, dp):
+    """After 5 steps the gathered parameters, the moments and every loss are
+    the bits the parent's order gives: the same exchange, the same update of
+    the same rows, the gather moved from the end of one step to the top of
+    the next."""
+    loss_fn, params = _mlp_task()
+    xs, ys = _data(steps=5, seed=11)
+    trainer = BaguaTrainer(loss_fn, _OPTIMIZERS[optimizer](),
+                           GradientAllReduceAlgorithm(), mesh=_dp_mesh(dp),
+                           bucket_bytes=600)
+    state = trainer.init(params)
+    assert trainer._update_sharded()
+    parent = _gather_at_the_end(trainer)
+    # (copies: the trainer's step donates its state)
+    whole = jax.tree.map(np.asarray, state.params)
+    moments = jax.tree.map(jnp.copy, state.opt_state)
+    for s in range(xs.shape[0]):
+        batch = trainer.shard_batch({"x": xs[s][:4 * dp], "y": ys[s][:4 * dp]})
+        whole, moments, want = parent(whole, moments, batch)
+        state, loss = trainer.train_step(state, batch)
+        assert float(loss) == float(want)
+    for a, b in zip(_whole(state.params) + _whole(state.opt_state),
+                    _whole(whole) + _whole(moments)):
+        np.testing.assert_array_equal(a, b)
+
+
+def _flats_of(opt_state):
+    """The ``flats`` tuple of every parameter-shaped part of an optax
+    state."""
+    is_zp = BaguaTrainer._is_flat_container
+    found = []
+    jax.tree.map(lambda x: found.append(x["flats"]) if is_zp(x) else None,
+                 opt_state, is_leaf=is_zp)
+    return found
+
+
+def _rests_as_chunks(trainer, state):
+    """Each taken bucket of ``state`` is placed over the comm axes along its
+    leading axis and every other is replicated, parameter and moments alike,
+    in the plan's global shapes -> how many are taken."""
+    from jax.sharding import PartitionSpec as P
+
+    plan, ctx = trainer._plan, trainer._ctx(trainer._plan)
+    taken = [ctx.update_sharded(i) for i in range(len(plan.buckets))]
+    for flats in [state.params["flats"]] + _flats_of(state.opt_state):
+        for held, bucket, cut in zip(flats, plan.buckets, taken):
+            assert held.shape == bucket.buffer_shape
+            if cut:
+                assert held.sharding.spec == P(trainer.comm_axes)
+            else:
+                assert held.sharding.is_fully_replicated
+    return sum(taken)
+
+
+@pytest.mark.parametrize("comm_dtype", [None, jnp.float32],
+                         ids=["wire_as_is", "float32_wire"])
+def test_the_parameters_rest_as_their_moments_do(comm_dtype):
+    """With a wire as wide as the parameters every taken bucket of
+    ``state.params`` is placed over the comm axes, as ``init`` builds it and
+    as the step returns it, and ``comm/params_sharded_share`` reads the
+    plan's share: 1.0 where the world divides every bucket (four ranks, 12
+    and 16 and 32 rows)."""
+    from bagua_tpu.obs import export
+    from bagua_tpu.telemetry import counters
+
+    assert export.is_registered("comm/params_sharded_share")
+    loss_fn, params = _mlp_task(features=(16, 32))
+    xs, ys = _data(steps=1)
+    trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                           GradientAllReduceAlgorithm(comm_dtype=comm_dtype),
+                           mesh=_dp_mesh(4), bucket_bytes=600)
+    state = trainer.init(params)
+    assert _rests_as_chunks(trainer, state) == len(trainer._plan.buckets)
+    state, _ = trainer.train_step(state, {"x": xs[0][:16], "y": ys[0][:16]})
+    assert _rests_as_chunks(trainer, state) == len(trainer._plan.buckets)
+    assert counters.get("comm/params_sharded_share") == 1.0
+    assert counters.get("comm/sharded_update_share") == 1.0
+
+
+@pytest.mark.parametrize("construction", ["plain", "accum4", "guard_skip"])
+def test_a_bfloat16_wire_traces_the_parents_step(construction):
+    """``comm_dtype=bfloat16`` over float32 parameters: the predicate says
+    no, the parameters stay replicated, the gauge reads 0.0 and the step
+    over 8 ranks is the parent's, digest for digest (sha256 of the jaxpr
+    text from the parent's checkout, 530cdab, for the three constructions PR
+    49 pinned at world 1): ``bert-large.squad384-bf16comm-dp4`` runs the
+    program it ran."""
+    from bagua_tpu.telemetry import counters
+
+    kw, parent = {
+        "plain": ({}, "0d89312b7bbbf84d"),
+        "accum4": ({"accum_steps": 4}, "3c7af3b57365c3bd"),
+        "guard_skip": ({"grad_guard": "skip"}, "d9c31a7a9f265296"),
+    }[construction]
+    loss_fn, params = _mlp_task()
+    xs, ys = _data(steps=1)
+    trainer = BaguaTrainer(
+        loss_fn, optax.adamw(1e-2),
+        GradientAllReduceAlgorithm(comm_dtype=jnp.bfloat16),
+        bucket_bytes=600, **kw)
+    state = trainer.init(params)
+    batch = trainer.shard_batch({"x": xs[0], "y": ys[0]})
+    ours = str(trainer.trace_step(state, batch))
+    assert hashlib.sha256(ours.encode()).hexdigest()[:16] == parent
+    state, _ = trainer.train_step(state, batch)
+    assert not trainer._update_sharded()
+    assert _rests_as_chunks(trainer, state) == 0
+    assert counters.get("comm/params_sharded_share") == 0.0
+
+
+def test_a_bucket_the_world_does_not_divide_stays_replicated():
+    """8 ranks divide neither the 12 rows of the first kernel nor the 10
+    logits' bias: those two rest whole on every rank beside the two that
+    rest as chunks, and only the latter are gathered."""
+    from bagua_tpu.telemetry import counters
+
+    loss_fn, params = _mlp_task()
+    xs, ys = _data(steps=1)
+    trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                           GradientAllReduceAlgorithm(), bucket_bytes=256)
+    state = trainer.init(params)
+    batch = trainer.shard_batch({"x": xs[0], "y": ys[0]})
+    gathered = sorted(
+        e.outvars[0].aval.shape
+        for e in equations(trainer.trace_step(state, batch).jaxpr)
+        if e.primitive.name == "all_gather")
+    assert gathered == [(16,), (16, NCLASS)]
+    state, _ = trainer.train_step(state, batch)
+    assert _rests_as_chunks(trainer, state) == 2
+    replicated = sorted(f.shape for f in state.params["flats"]
+                        if f.sharding.is_fully_replicated)
+    assert replicated == [(NCLASS,), (DIM, 16)]
+    assert 0.4 < counters.get("comm/params_sharded_share") < 1
+
+
+@pytest.mark.parametrize("overlap", ["auto", "off"])
+@pytest.mark.parametrize("accum_steps", [2, 4])
+def test_accumulation_gathers_a_bucket_once_a_step(accum_steps, overlap):
+    """The gather stands at the top of the step, outside the micro-batch
+    loop: one ``all_gather`` a taken bucket in the whole jaxpr, none in a
+    scan's body (nor in the peeled tail the overlap scheduler leaves)."""
+    loss_fn, params = _mlp_task()
+    xs, ys = _data(steps=1)
+    trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                           GradientAllReduceAlgorithm(), bucket_bytes=256,
+                           accum_steps=accum_steps, overlap=overlap)
+    state = trainer.init(params)
+    batch = trainer.shard_batch({"x": xs[0], "y": ys[0]})
+    jaxpr = trainer.trace_step(state, batch).jaxpr
+    assert trainer._overlap_active() == (overlap == "auto")
+    taken = _rests_as_chunks(trainer, state)
+    everywhere = [e for e in equations(jaxpr)]
+    assert sum(e.primitive.name == "all_gather" for e in everywhere) == taken
+    scans = [e for e in everywhere if e.primitive.name == "scan"]
+    assert scans
+    for scan in scans:
+        assert not any(e.primitive.name == "all_gather"
+                       for e in equations(scan.params["jaxpr"].jaxpr))
+
+
+# ---- ... and everything that reads them outside the step -------------------
+
+
+def _sharded_and_replicated(monkeypatch, optimizer=None, **task):
+    """Two trainers over the same 8 ranks and the same plan, the parameters
+    of the first resting as chunks, of the second (the parent's layout for
+    every reader outside the step) replicated."""
+    loss_fn, params = _mlp_task(**task)
+
+    def make():
+        trainer = BaguaTrainer(loss_fn, (optimizer or optax.adamw)(1e-2),
+                               GradientAllReduceAlgorithm(), bucket_bytes=600,
+                               autotune=False)
+        return trainer, trainer.init(params)
+
+    sharded = make()
+    with monkeypatch.context() as m:
+        _keep_replicated(m)
+        replicated = make()
+        assert not replicated[0]._update_sharded()
+    assert sharded[0]._update_sharded()
+    return sharded, replicated
+
+
+def _two_steps_then_the_same_arrays_whole(monkeypatch):
+    """The pair after two steps of the first, the second handed the same
+    global parameter arrays placed whole on every rank; a third batch."""
+    (ta, sa), (tb, sb) = _sharded_and_replicated(monkeypatch)
+    xs, ys = _data(steps=3, seed=5)
+    for s in range(2):
+        sa, _ = ta.train_step(sa, {"x": xs[s], "y": ys[s]})
+    sb = sb._replace(params=jax.device_put(
+        jax.tree.map(np.asarray, sa.params), sb.params["flats"][0].sharding))
+    return (ta, sa), (tb, sb), {"x": xs[2], "y": ys[2]}
+
+
+def test_eval_step_gathers_what_it_reads(monkeypatch):
+    (ta, sa), (tb, sb), batch = _two_steps_then_the_same_arrays_whole(
+        monkeypatch)
+    got = ta.eval_step(sa, ta.shard_batch(batch))
+    assert float(got) == float(tb.eval_step(sb, tb.shard_batch(batch)))
+    assert _rests_as_chunks(ta, sa)  # (untouched: nothing is donated)
+
+
+def test_unstack_params_reads_chunks_as_whole_leaves(monkeypatch):
+    (ta, sa), (tb, sb), _ = _two_steps_then_the_same_arrays_whole(monkeypatch)
+    for a, b, like in zip(jax.tree.leaves(ta.unstack_params(sa)),
+                          jax.tree.leaves(tb.unstack_params(sb)),
+                          jax.tree.leaves(_mlp_task()[1])):
+        assert a.shape == like.shape
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+def _switch(trainer, family):
+    from bagua_tpu.define import BaguaHyperparameter
+
+    trainer._maybe_switch_algorithm(BaguaHyperparameter(
+        algorithm=family, is_hierarchical_reduce=False))
+    assert trainer.algorithm.name == family
+
+
+def _rebucket(trainer):
+    from bagua_tpu.bucket import split_bucket_by_bucket_size
+
+    decls = [t.declaration() for b in trainer._plan.buckets
+             for t in b.tensors]
+    trainer.rebucket(split_bucket_by_bucket_size(decls, 1 << 20))
+
+
+@pytest.mark.parametrize("event", ["family_switch", "rebucket"])
+def test_a_migration_ends_in_the_placement_the_step_takes(event, monkeypatch):
+    """A switch to a family that keeps whole parameters (qadam) and back,
+    and an autotune rebucket: the trajectory is the one a trainer with
+    replicated parameters walks through the same events, and behind each
+    the parameters rest as chunks again."""
+    # (2,336 parameters: 8 ranks divide the one packed flat of the rebucket)
+    (ta, sa), (tb, sb) = _sharded_and_replicated(monkeypatch, optax.adam,
+                                                 features=(16, 64, 16))
+    xs, ys = _data(steps=9, seed=7)
+    losses = ([], [])
+
+    def steps(span):
+        nonlocal sa, sb
+        for s in span:
+            batch = {"x": xs[s], "y": ys[s]}
+            sa, la = ta.train_step(sa, batch)
+            sb, lb = tb.train_step(sb, batch)
+            losses[0].append(float(la))
+            losses[1].append(float(lb))
+
+    steps(range(3))
+    with monkeypatch.context() as m:
+        for trainer in (ta, tb):
+            if trainer is tb:
+                _keep_replicated(m)
+            if event == "rebucket":
+                _rebucket(trainer)
+            else:
+                _switch(trainer, "qadam")
+        steps(range(3, 6))
+        if event == "family_switch":
+            assert not ta._update_sharded()
+            assert all(f.sharding.is_fully_replicated
+                       for f in sa.params["flats"])
+            for trainer in (ta, tb):
+                _switch(trainer, "gradient_allreduce")
+        steps(range(6, 9))
+        assert ta._update_sharded() and not tb._update_sharded()
+    assert _rests_as_chunks(ta, sa)
+    np.testing.assert_allclose(losses[0], losses[1], rtol=1e-5)
+    for a, b in zip(jax.tree.leaves(ta.unstack_params(sa)),
+                    jax.tree.leaves(tb.unstack_params(sb))):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("dp_restore", [2, 4, 8])
+def test_a_checkpoint_holds_whole_buffers_at_every_world(tmp_path,
+                                                         dp_restore):
+    """Saved at dp 4 with the parameters resting as chunks, restored at dp
+    2, 4 and 8: the file holds the global arrays, the restored state is
+    placed as the new world's step takes it, and reads back the numbers
+    that were saved."""
+    from bagua_tpu.checkpoint import BaguaCheckpointManager
+
+    loss_fn, params = _mlp_task(features=(16, 32))
+    xs, ys = _data(steps=4, seed=9)
+
+    def make(dp):
+        trainer = BaguaTrainer(loss_fn, optax.adamw(1e-2),
+                               GradientAllReduceAlgorithm(),
+                               mesh=_dp_mesh(dp), bucket_bytes=600,
+                               autotune=False)
+        return trainer, trainer.init(params)
+
+    t4, state = make(4)
+    for s in range(3):
+        state, _ = t4.train_step(state, {"x": xs[s], "y": ys[s]})
+    saved = jax.tree.map(np.asarray, (t4.unstack_params(state),
+                                      state.opt_state))
+    mgr = BaguaCheckpointManager(str(tmp_path / "ckpt"), async_save=False)
+    assert t4.save_checkpoint(mgr, 3, state)
+    mgr.wait()
+    state, want = t4.train_step(state, {"x": xs[3], "y": ys[3]})
+
+    trainer, like = make(dp_restore)
+    assert trainer._plan.signature() == t4._plan.signature()
+    step, restored = trainer.restore_checkpoint(mgr, like)
+    assert step == 3
+    # (8 ranks do not divide the 12 rows of the first kernel)
+    assert _rests_as_chunks(trainer, restored) == sum(
+        b.buffer_shape[0] % dp_restore == 0 for b in trainer._plan.buckets)
+    for a, b in zip(_whole((trainer.unstack_params(restored),
+                            restored.opt_state)), jax.tree.leaves(saved)):
+        np.testing.assert_array_equal(a, b)
+    _, got = trainer.train_step(restored, {"x": xs[3], "y": ys[3]})
+    # (reduction orders differ between dp extents)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    mgr.close()

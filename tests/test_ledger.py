@@ -220,15 +220,20 @@ def test_mfu_null_with_rationale_on_cpu_sim(ledger_on):
 
 def test_static_footprint_matches_bucket_plan_exactly(ledger_on):
     """The acceptance pin: under the flat-resident layout the footprint's
-    params component equals the BucketPlan flats to the byte."""
+    params component equals the BucketPlan flats to the byte — a bucket
+    whose update is sharded rests as 1/world of it a device."""
     t, s, b = _golden_trainer(flat_resident="on")
     s, _ = t.train_step(s, b)
     fp = obs_memory.static_footprint(t, s)
     plan_bytes = obs_memory.plan_flat_bytes(t._plan)
-    manual = sum(bs.padded_numel * np.dtype(bs.dtype).itemsize
-                 for bs in t._plan.buckets)
-    assert plan_bytes == manual
-    assert fp["params_bytes"] == plan_bytes
+    sizes = [bs.padded_numel * np.dtype(bs.dtype).itemsize
+             for bs in t._plan.buckets]
+    assert plan_bytes == sum(sizes)
+    ctx = t._ctx(t._plan)
+    cut = [ctx.update_sharded(i) for i in range(len(sizes))]
+    assert cut == [True]  # (424 elements in one packed flat: 8 divide it)
+    assert fp["params_bytes"] == sum(
+        n // N_DEVICES if c else n for n, c in zip(sizes, cut))
     assert fp["grad_flats_bytes"] == plan_bytes
     assert fp["flat_resident"] is True
     assert fp["total_bytes"] == (
