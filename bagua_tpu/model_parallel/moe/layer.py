@@ -81,10 +81,11 @@ class MoEMLP(nn.Module):
     #: capacity-free routing: no token is ever dropped.  Tokens are sorted by
     #: expert and run through the grouped-matmul Pallas kernel
     #: (:mod:`bagua_tpu.ops.gmm`) instead of the dense [T,E,C] dispatch
-    #: einsum.  With ``ep_size > 1`` the inter-shard exchange is a ragged
-    #: all-to-all with exact per-destination counts (the reference's
-    #: ``alltoall_v``, communicators/mod.rs:632-676) instead of dense
-    #: capacity slots.
+    #: einsum.  With ``ep_size > 1`` the inter-shard exchange carries a
+    #: peer's rows in a fixed slot range with their exact counts beside them
+    #: (the reference's ``alltoall_v``, communicators/mod.rs:632-676; one
+    #: dense ``all_to_all`` over worst-case slots) instead of capacity slots
+    #: an expert.
     #:
     #: Regime selection: the only speed record of capacity against
     #: dropless was the pre-chip yardstick's at TOY widths (E=8, k=2,
@@ -99,11 +100,6 @@ class MoEMLP(nn.Module):
     #: (capacity drops overflow tokens; dropless never drops) — switching
     #: is the user's modelling decision.
     dropless: bool = False
-    #: dropless EP transfer via ``lax.ragged_all_to_all`` (exact counts on
-    #: the wire).  Off by default: XLA:CPU cannot execute the ragged HLO, so
-    #: the virtual-mesh test/dryrun environments use the dense-slot
-    #: ``all_to_all`` path; enable on real multi-chip TPU meshes.
-    use_ragged: bool = False
     #: gated experts (OLMoE, Mixtral, DeepSeek): ``(silu(x wg) * (x wi)) wo``
     #: with the extra leaf ``expert_wg``; False: ``silu(x wi) wo``
     gated: bool = False
@@ -329,8 +325,8 @@ class MoEMLP(nn.Module):
         take, the layout is the sorted rows themselves and the products are
         the dense reference's (:func:`bagua_tpu.ops.gmm.kernel_layout`).
 
-        With expert parallelism the exchange is a ragged all-to-all with
-        exact counts: rows sorted by global expert are already grouped by
+        With expert parallelism the exchange is an all-to-all of fixed slot
+        ranges with exact counts: rows sorted by global expert are already grouped by
         owning shard, so shard p receives only the rows routed to its
         experts (worst-case receive buffer: every peer routes all its rows
         here).  The receiver pads once from its receive buffer and unpads
@@ -441,10 +437,7 @@ class MoEMLP(nn.Module):
         ``p`` occupy the fixed slot range ``[p*tk, p*tk + count_p)`` of a
         worst-case send buffer, so the transfer is one dense ``all_to_all``
         (validatable on the virtual CPU mesh) and every downstream index is
-        slot-deterministic.  ``use_ragged=True`` swaps in
-        ``lax.ragged_all_to_all`` with exact counts over the same slot
-        layout — moving only the routed bytes on ICI — but XLA:CPU has no
-        ragged-all-to-all kernel, so it stays opt-in for real TPU meshes.
+        slot-deterministic.
         """
         ep, ax = self.ep_size, self.axis_name
         tk, d = x_rows.shape
@@ -479,20 +472,10 @@ class MoEMLP(nn.Module):
         )
         sizes = counts_recv.sum(0)                      # rows per local expert
 
-        if self.use_ragged:
-            my = lax.axis_index(ax)
-            recv_sizes = counts_recv.sum(-1)
-            out_offs = jnp.full((ep,), my * tk, jnp.int32)
-            x_recv = lax.ragged_all_to_all(
-                x_rows, jnp.zeros((cap, d), x_rows.dtype),
-                input_offsets, send_sizes, out_offs, recv_sizes,
-                axis_name=ax,
-            )
-        else:
-            x_send = jnp.zeros((cap, d), x_rows.dtype).at[slot].set(x_rows)
-            x_recv = lax.all_to_all(
-                x_send.reshape(ep, tk, d), ax, 0, 0, tiled=False
-            ).reshape(cap, d)
+        x_send = jnp.zeros((cap, d), x_rows.dtype).at[slot].set(x_rows)
+        x_recv = lax.all_to_all(
+            x_send.reshape(ep, tk, d), ax, 0, 0, tiled=False
+        ).reshape(cap, d)
 
         # group received rows by local expert, straight into the layout:
         # sentinel (empty-slot) rows sort last, fall outside the grouped
@@ -508,16 +491,6 @@ class MoEMLP(nn.Module):
         y_local = unpad_rows(y_p, slots, reader)
 
         # reverse transfer over the same slots, then gather my rows back
-        if self.use_ragged:
-            peer_in_offsets = lax.all_to_all(
-                input_offsets, ax, 0, 0, tiled=False
-            ).reshape(ep)
-            rev_in_offsets = jnp.arange(ep, dtype=jnp.int32) * tk
-            return lax.ragged_all_to_all(
-                y_local, jnp.zeros((tk, d), y_local.dtype),
-                rev_in_offsets, recv_sizes, peer_in_offsets, send_sizes,
-                axis_name=ax,
-            )
         y_back = lax.all_to_all(
             y_local.reshape(ep, tk, d), ax, 0, 0, tiled=False
         ).reshape(cap, d)

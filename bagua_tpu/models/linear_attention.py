@@ -59,12 +59,12 @@ import jax.numpy as jnp
 L2_EPS = 1e-6
 
 
-def _behind(x, k: int):
+def behind(x, k: int):
     """``x[t - k]`` along the sequence, zeros in front."""
     return x if k == 0 else jnp.pad(x, ((0, 0), (k, 0), (0, 0)))[:, :x.shape[1]]
 
 
-def _ahead(x, k: int):
+def ahead(x, k: int):
     """``x[t + k]`` along the sequence, zeros behind."""
     return x if k == 0 else jnp.pad(x, ((0, 0), (0, k), (0, 0)))[:, k:]
 
@@ -83,7 +83,7 @@ def causal_depthwise_conv(x, taps):
     float32 arrays as large as the input (1 GiB each at 8,192 rows of 8,192
     channels)."""
     n = taps.shape[0]
-    y = sum(_behind(x, n - 1 - j).astype(jnp.float32)
+    y = sum(behind(x, n - 1 - j).astype(jnp.float32)
             * taps[j].astype(jnp.float32) for j in range(n))
     return y.astype(x.dtype)
 
@@ -95,11 +95,11 @@ def _conv_fwd(x, taps):
 def _conv_bwd(res, dy):
     x, taps = res
     n = taps.shape[0]
-    dx = sum(_ahead(dy, n - 1 - j).astype(jnp.float32)
+    dx = sum(ahead(dy, n - 1 - j).astype(jnp.float32)
              * taps[j].astype(jnp.float32) for j in range(n))
     d_taps = jnp.stack([
         jnp.sum(dy.astype(jnp.float32)
-                * _behind(x, n - 1 - j).astype(jnp.float32), axis=(0, 1))
+                * behind(x, n - 1 - j).astype(jnp.float32), axis=(0, 1))
         for j in range(n)])
     return dx.astype(x.dtype), d_taps.astype(taps.dtype)
 
@@ -119,7 +119,7 @@ def _head_pool(heads: int, dim: int):
     return (lane == jnp.arange(heads)[None, :]).astype(jnp.float32)
 
 
-def _per_head(x, heads: int, reduce):
+def per_head(x, heads: int, reduce):
     """``x`` [..., heads * dim] times ``reduce(mean of x^2 over a head's
     lanes)`` laid back over those lanes; float32."""
     x = x.astype(jnp.float32)
@@ -141,7 +141,7 @@ def mix_rows(qkvz, taps, dims):
     key_width, value_width = hk * dk, hv * dv
     mixed = nn.silu(causal_depthwise_conv(
         qkvz[..., :2 * key_width + value_width], taps))
-    unit = lambda t: _per_head(
+    unit = lambda t: per_head(
         t, hk, lambda mean: jax.lax.rsqrt(mean * dk + L2_EPS))
     q = (unit(mixed[..., :key_width]) / math.sqrt(dk)).astype(qkvz.dtype)
     k = unit(mixed[..., key_width:2 * key_width]).astype(qkvz.dtype)
@@ -151,7 +151,7 @@ def mix_rows(qkvz, taps, dims):
 def gate_rows(o, z, norm_scale, heads: int, eps: float):
     """The gated norm, ``w_n * o / rms(o) * silu(z)`` over a value head's
     lanes: float32, one rounding to the rows' dtype."""
-    o = _per_head(o, heads, lambda mean: jax.lax.rsqrt(mean + eps))
+    o = per_head(o, heads, lambda mean: jax.lax.rsqrt(mean + eps))
     return (jnp.tile(norm_scale.astype(jnp.float32), heads) * o
             * nn.silu(z.astype(jnp.float32))).astype(z.dtype)
 
@@ -172,6 +172,23 @@ def rows_by_kernel(cfg, seq: int) -> bool:
     from ..ops.gated_delta_rows import rows_supported
 
     return rows_supported(seq, _dims(cfg), cfg.linear_conv, cfg.dtype)
+
+
+def set_gauges(cfg, layers: int, seq: int) -> None:
+    """The trace-time gauges of a step with ``layers`` linear-attention
+    layers over ``seq`` positions (``linattn/*``)."""
+    from ..ops.gated_delta import CHUNK
+    from ..telemetry import counters
+
+    counters.set_gauge("linattn/layers", layers)
+    # of those, the layers whose rows between the projections are the
+    # ``gdn_mix`` / ``gdn_gate`` passes
+    counters.set_gauge("linattn/row_kernel_layers",
+                       layers * rows_by_kernel(cfg, seq))
+    counters.set_gauge("linattn/chunk", CHUNK)
+    for size in ("key_heads", "value_heads", "key_dim", "value_dim"):
+        counters.set_gauge(f"linattn/{size}", getattr(cfg, f"linear_{size}"))
+    counters.set_gauge("linattn/neg_eigval", int(cfg.linear_neg_eigval))
 
 
 def decay_init(key, shape, dtype):

@@ -1,6 +1,7 @@
 """Mamba-2 — the state-space token mixer of the hybrid decoders (Nemotron-H:
 the ``M`` blocks of its pattern), the one sub-layer of a
-:class:`bagua_tpu.models.single_block.SingleBlock` of kind ``"ssm"``.
+:class:`bagua_tpu.models.transformer.Block` whose layer the plan
+(``TransformerConfig.layer_plan``) names ``"ssm"``.
 
 From the block's normed input ``u`` (``H`` heads of width ``P``, ``G`` groups,
 state ``N``; ``d_inner = H P``):
@@ -48,13 +49,13 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from .linear_attention import _ahead, _behind, _per_head
+from .linear_attention import ahead, behind, per_head
 
 
 def _pre_activation(x, taps, bias):
     """``sum_j taps[j] x_{t - (n - 1 - j)} + bias`` a channel, float32."""
     n = taps.shape[0]
-    return sum(_behind(x, n - 1 - j).astype(jnp.float32)
+    return sum(behind(x, n - 1 - j).astype(jnp.float32)
                * taps[j].astype(jnp.float32) for j in range(n)) + bias.astype(
                    jnp.float32)
 
@@ -85,10 +86,10 @@ def _conv_bwd(res, dy):
     gate = jax.nn.sigmoid(pre)
     # d silu = sigmoid(a) (1 + a (1 - sigmoid(a)))
     d_pre = dy.astype(jnp.float32) * gate * (1.0 + pre * (1.0 - gate))
-    dx = sum(_ahead(d_pre, n - 1 - j) * taps[j].astype(jnp.float32)
+    dx = sum(ahead(d_pre, n - 1 - j) * taps[j].astype(jnp.float32)
              for j in range(n))
     d_taps = jnp.stack([
-        jnp.sum(d_pre * _behind(x, n - 1 - j).astype(jnp.float32),
+        jnp.sum(d_pre * behind(x, n - 1 - j).astype(jnp.float32),
                 axis=(0, 1)) for j in range(n)])
     return (dx.astype(x.dtype), d_taps.astype(taps.dtype),
             jnp.sum(d_pre, axis=(0, 1)).astype(bias.dtype))
@@ -102,10 +103,10 @@ def gated_group_norm(y, z, scale, groups: int, eps: float):
     gate first, then RMSNorm over each of the ``groups`` runs of lanes by
     itself: ``y`` / ``z`` [..., d_inner], ``scale`` [d_inner]; float32, one
     rounding to ``z.dtype``.  The per-group mean is taken on the flat rows
-    (``linear_attention._per_head``: a ``[..., groups, lanes]`` view of a
+    (``linear_attention.per_head``: a ``[..., groups, lanes]`` view of a
     float32 value is another tiling on the TPU)."""
     g = y.astype(jnp.float32) * nn.silu(z.astype(jnp.float32))
-    normed = _per_head(g, groups, lambda mean: jax.lax.rsqrt(mean + eps))
+    normed = per_head(g, groups, lambda mean: jax.lax.rsqrt(mean + eps))
     return (scale.astype(jnp.float32) * normed).astype(z.dtype)
 
 
@@ -122,6 +123,20 @@ def rows_by_kernel(cfg, seq: int) -> bool:
 
     return rows_supported(seq, _dims(cfg), cfg.ssm_conv, cfg.ssm_chunk,
                           cfg.dtype)
+
+
+def set_gauges(cfg, layers: int, seq: int) -> None:
+    """The trace-time gauges of a step with ``layers`` state-space layers
+    over ``seq`` positions (``ssm/*``)."""
+    from ..telemetry import counters
+
+    counters.set_gauge("ssm/layers", layers)
+    # of those, the layers whose rows between the projections are the
+    # ``ssd_mix`` / ``ssd_gate`` passes
+    counters.set_gauge("ssm/row_kernel_layers",
+                       layers * rows_by_kernel(cfg, seq))
+    for size in ("chunk", "heads", "head_dim", "groups", "state"):
+        counters.set_gauge(f"ssm/{size}", getattr(cfg, f"ssm_{size}"))
 
 
 #: the step sizes ``dt_bias`` starts from: log-uniform in this range, then

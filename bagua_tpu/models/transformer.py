@@ -16,6 +16,8 @@ transformer family, designed TPU-first rather than ported:
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib
 from typing import Any, Callable, Optional
 
 import flax.linen as nn
@@ -32,7 +34,26 @@ from ..utils import remat_wrap
 
 
 @dataclasses.dataclass(frozen=True)
+class SubLayer:
+    """One sub-layer of a block, ``x + N'(F(N(x)))``: what ``F`` is
+    (``"attn"`` softmax attention, ``"linear_attn"`` the gated delta rule,
+    ``"ssm"`` a Mamba-2 mixer, ``"mlp"`` the feed-forward or what
+    ``mlp_factory`` makes) and, for softmax attention, the layer's causal
+    window (None: full) and whether it rotates q and k."""
+    kind: str
+    window: Optional[int] = None
+    rotary: bool = False
+
+
+#: the words of ``TransformerConfig.layer_kinds`` -> the sub-layer each names
+LAYER_KINDS = {"ssm": "ssm", "attn": "attn", "moe": "mlp"}
+
+
+@dataclasses.dataclass(frozen=True)
 class TransformerConfig:
+    """The decoder's sizes and options.  Which option which path (decode,
+    ``sp_axis``, ``tp_axis``, pipeline stages, a looped stack, the
+    block-diffusion mask) does not carry yet: ``NOT_BUILT``."""
     vocab_size: int = 32000
     d_model: int = 512
     n_heads: int = 8
@@ -93,7 +114,7 @@ class TransformerConfig:
     #: head).  None keeps the learned absolute ``pos_embed`` table; set,
     #: ``TransformerLM`` creates no table and ``Attention`` rotates q and k
     #: (sin/cos in f32, positions offset by the ``sp_axis`` chunk like the
-    #: table).  The decode paths do not implement it.
+    #: table)
     rope_theta: Optional[float] = None
     #: RMSNorm on the flat ``n_heads * head_dim`` wide q and k, before the
     #: head split (OLMoE's ``q_norm`` / ``k_norm``).  ``"head"``: on each
@@ -128,8 +149,7 @@ class TransformerConfig:
     #: tree holds each of the ``n_layers`` blocks ONCE, the blocks run
     #: ``n_passes`` times in order over the same weights, ``final_norm``
     #: closes every pass and its output is what the next pass reads.  Every
-    #: pass rotates q and k at positions ``0 .. s - 1``.  The decode paths
-    #: do not implement it (a key / value cache per pass)
+    #: pass rotates q and k at positions ``0 .. s - 1``
     n_passes: int = 1
     #: a second RMSNorm behind each sub-layer, on its output before the
     #: residual add ("sandwich" norm): ``x + N'(Attn(N(x)))``
@@ -152,8 +172,7 @@ class TransformerConfig:
     #: both halves sit at positions ``0 .. L - 1``, every layer attends
     #: under ``ops.flash_attention.block_diffusion_mask``, and the head
     #: reads the noised half alone: ``[b, 2 L]`` tokens -> ``[b, L, vocab]``
-    #: logits.  The decode paths, ``sp_axis``, pipeline stages and a looped
-    #: stack do not implement it
+    #: logits
     attention: str = "causal"
     #: positions of a diffusion block: within one, noised rows see each
     #: other in both directions.  Read with ``attention="block_diffusion"``
@@ -161,9 +180,7 @@ class TransformerConfig:
     #: which layers mix tokens by LINEAR attention (the gated delta rule,
     #: ``models.linear_attention.GatedDeltaNet``) in place of softmax
     #: attention, a period like ``window_layers`` (``(1, 1, 1, 0)``: softmax
-    #: attention on every fourth layer); None: no layer.  Such a layer's
-    #: state is per sequence: the decode paths, ``sp_axis``, the tensor and
-    #: pipeline axes do not implement it
+    #: attention on every fourth layer); None: no layer
     mixer_layers: Optional[tuple] = None
     #: the linear-attention layers' key and value heads (a key head serves
     #: ``value_heads // key_heads`` value heads), their widths, and the taps
@@ -193,10 +210,7 @@ class TransformerConfig:
     #: attention) or ``"moe"`` (what ``mlp_factory`` makes), as an aperiodic
     #: pattern states them (``("ssm", "moe", "ssm", "moe", "ssm", "attn",
     #: ...)``).  Where set, a block is ONE norm and ONE sub-layer of that
-    #: kind, ``x + F(N(x))`` (``models.single_block.SingleBlock``); None: the
-    #: two-sub-layer ``Block``.  The decode paths, ``sp_axis``, the tensor
-    #: and pipeline axes, a looped stack and the block-diffusion mask do not
-    #: implement it
+    #: kind, ``x + F(N(x))``; None: a mixer and an MLP a block
     layer_kinds: Optional[tuple] = None
     #: the state-space layers' heads and their width (``d_inner = ssm_heads
     #: * ssm_head_dim``), the groups that share one B / C pair (head ``h``
@@ -228,32 +242,60 @@ class TransformerConfig:
     def kv_heads(self) -> int:
         return self.n_heads if self.n_kv_heads is None else self.n_kv_heads
 
+    @functools.cache
+    def layer_plan(self) -> tuple:
+        """What each of the ``n_layers`` layers is: a tuple of
+        :class:`SubLayer` a layer, in the order its block runs them — ``(mixer,
+        mlp)``, or the one sub-layer that ``layer_kinds`` names.  The one
+        reader of the layer patterns (``window`` / ``window_layers``,
+        ``rope_theta`` / ``rope_layers``, ``mixer_layers``, ``layer_kinds``):
+        blocks, gauges, the pipelined stack and ``NOT_BUILT`` read this."""
+        def on(pattern, layer, default):
+            # a period of 0 / 1 repeated over the depth
+            return default if pattern is None else bool(
+                pattern[layer % len(pattern)])
+
+        def attention(layer):
+            windowed = self.window is not None and on(
+                self.window_layers, layer, True)
+            return SubLayer(
+                "attn", self.window if windowed else None,
+                self.rope_theta is not None and on(
+                    self.rope_layers, layer, True))
+
+        kinds = self.layer_kinds
+        if kinds is None:
+            return tuple(
+                (SubLayer("linear_attn") if on(self.mixer_layers, i, False)
+                 else attention(i), SubLayer("mlp"))
+                for i in range(self.n_layers))
+        if len(kinds) != self.n_layers or any(
+                k not in LAYER_KINDS for k in kinds):
+            raise ValueError(
+                f"layer_kinds names the kind of each of the {self.n_layers} "
+                f"layers, one of {tuple(LAYER_KINDS)}; got {kinds}")
+        if (self.mixer_layers is not None or self.post_norms
+                or self.route_before_attention):
+            raise ValueError(
+                "layer_kinds replaces the two-sub-layer block: mixer_layers, "
+                "post_norms and route_before_attention are that block's "
+                "options")
+        return tuple(
+            (attention(i) if k == "attn" else SubLayer(LAYER_KINDS[k]),)
+            for i, k in enumerate(kinds))
+
+    def _attention(self, layer: int) -> SubLayer:
+        subs = self.layer_plan()[layer % self.n_layers]
+        return next((s for s in subs if s.kind == "attn"), SubLayer("attn"))
+
     def layer_window(self, layer: int) -> Optional[int]:
         """The window of layer ``layer``'s attention, None where it is
         full."""
-        if self.window is None:
-            return None
-        pattern = self.window_layers
-        return self.window if (pattern is None
-                               or pattern[layer % len(pattern)]) else None
+        return self._attention(layer).window
 
     def layer_rotary(self, layer: int) -> bool:
         """Whether layer ``layer`` rotates q and k."""
-        if self.rope_theta is None:
-            return False
-        pattern = self.rope_layers
-        return pattern is None or bool(pattern[layer % len(pattern)])
-
-    def layer_linear(self, layer: int) -> bool:
-        """Whether layer ``layer``'s mixer is linear attention."""
-        pattern = self.mixer_layers
-        return pattern is not None and bool(pattern[layer % len(pattern)])
-
-    def layer_softmax(self, layer: int) -> bool:
-        """Whether layer ``layer`` holds softmax attention."""
-        if self.layer_kinds is not None:
-            return self.layer_kinds[layer] == "attn"
-        return not self.layer_linear(layer)
+        return self._attention(layer).rotary
 
 
 def bert_large_config(**kw) -> TransformerConfig:
@@ -462,6 +504,125 @@ def _tp_active(cfg) -> bool:
     )
 
 
+#: What is not built: rows of ``(features, consumers, why)``, names separated
+#: by spaces.  A feature is something a configuration or its layer plan has,
+#: a consumer a path that would have to carry it (:func:`refuse_not_built`
+#: reads both off the configuration; ``"pipeline"`` is named by
+#: ``PipelinedTransformerLM``).  The FIRST row that applies is raised, so the
+#: order is the precedence: the pipelined stack's own rows, the structural
+#: features (a mask, a looped stack, a block of one sub-layer, a recurrent
+#: mixer), then what one attention layer cannot do.  ``why`` is a format
+#: string over ``cfg`` and ``n``, the number of distinct layers in the plan.
+#: To build a pair, take it out of its row.
+NOT_BUILT = (
+    ("looped exit_gate", "pipeline",
+     "the pipelined stack runs its layers once and ends in one head: "
+     "n_passes={cfg.n_passes} / exit_gate={cfg.exit_gate} (a looped stack's "
+     "passes under pipeline stages) are not implemented"),
+    ("block_diffusion", "pipeline",
+     "the pipelined stack attends causally over tokens[:, :-1]: "
+     "attention='block_diffusion' (the [x ; x~] rows, their mask, the "
+     "noised half's head) is not implemented under pipeline stages"),
+    ("linear_attn norm_zero_centered", "pipeline",
+     "the pipelined stack scans ONE kind of block and closes in a plain "
+     "RMSNorm: mixer_layers (linear-attention layers by period) and "
+     "norm_zero_centered are not implemented under pipeline stages"),
+    ("single_sublayer", "pipeline",
+     "the pipelined stack scans ONE kind of two-sub-layer block: "
+     "layer_kinds (one sub-layer a block, a kind a layer) is not "
+     "implemented under pipeline stages"),
+    ("mixed_layers", "pipeline",
+     "the pipelined stack scans ONE block over its layers: the layer "
+     "patterns (windows, rotations) ask for layers of {n} kinds"),
+    ("block_diffusion", "decode",
+     "attention='block_diffusion' is the training view of a block-diffusion "
+     "model; generation by blocks is not implemented on the decode paths"),
+    ("block_diffusion", "sp_axis",
+     "attention='block_diffusion' is not implemented under sp_axis: a "
+     "sequence shard would need the other half's keys and the drop-ins' "
+     "masks are causal"),
+    ("block_diffusion", "looped",
+     "attention='block_diffusion' is not implemented for a looped stack "
+     "(n_passes > 1, exit_gate)"),
+    ("looped", "decode",
+     "n_passes > 1 is not implemented for the decode paths (a key / value "
+     "cache per pass)"),
+    ("single_sublayer", "decode",
+     "layer_kinds (one sub-layer a block, state-space layers) is not "
+     "implemented on the decode paths: a recurrent state and the "
+     "convolution's taps beside the key / value cache"),
+    ("single_sublayer", "sp_axis",
+     "layer_kinds is not implemented under sp_axis: a sequence shard of a "
+     "state-space layer would need the state of the shard before it"),
+    ("single_sublayer", "tp_axis",
+     "layer_kinds is not implemented under the tensor-parallel axis "
+     "(tp_axis / tp_size): the state-space heads and groups are not sharded"),
+    ("single_sublayer", "looped block_diffusion",
+     "layer_kinds is not implemented for a looped stack (n_passes > 1, "
+     "exit_gate) or under attention='block_diffusion'"),
+    ("linear_attn", "decode",
+     "mixer_layers (linear attention) is not implemented on the decode "
+     "paths: a recurrent state beside the key / value cache"),
+    ("linear_attn", "sp_axis",
+     "mixer_layers (linear attention) is not implemented under sp_axis: a "
+     "sequence shard would need the state of the shard before it"),
+    ("linear_attn", "tp_axis",
+     "mixer_layers (linear attention) is not implemented under the "
+     "tensor-parallel axis (tp_axis / tp_size): its heads are not sharded"),
+    ("linear_attn", "looped block_diffusion",
+     "mixer_layers (linear attention) is not implemented for a looped stack "
+     "or under attention='block_diffusion'"),
+    ("window mixed_layers", "block_diffusion",
+     "attention='block_diffusion' is one kind of layer: no window, no "
+     "rope_layers pattern"),
+    ("grouped_kv window", "decode",
+     "grouped key / value heads and windows are not implemented for the "
+     "decode paths"),
+    ("attn_gate rotary_dim", "decode",
+     "attn_gate and rotary_dim are not implemented for the decode paths"),
+    ("flat_qk_norm", "tp_axis",
+     "qk_norm normalizes over all heads; they are sharded under tensor "
+     "parallelism"),
+    ("rope", "decode", "rope_theta is not implemented for the decode paths"),
+)
+
+
+def refuse_not_built(cfg: TransformerConfig, plan=None, consumers=()) -> None:
+    """Raise the first row of ``NOT_BUILT`` with a feature that ``cfg`` has
+    (its layers being ``plan``; None: ``cfg.layer_plan()``) under a consumer
+    that it turns on or that ``consumers`` names.  Every model calls this
+    once, before it builds anything."""
+    plan = cfg.layer_plan() if plan is None else plan
+    subs = [sub for layer in plan for sub in layer]
+    have = {
+        "linear_attn": any(sub.kind == "linear_attn" for sub in subs),
+        "single_sublayer": any(len(layer) == 1 for layer in plan),
+        "mixed_layers": len(set(plan)) > 1,
+        "window": any(sub.window is not None for sub in subs),
+        "rope": any(sub.rotary for sub in subs),
+        "block_diffusion": cfg.block_diffusion,
+        "looped": cfg.n_passes > 1,
+        "exit_gate": cfg.exit_gate,
+        "grouped_kv": cfg.kv_heads != cfg.n_heads,
+        "attn_gate": cfg.attn_gate,
+        "rotary_dim": cfg.rotary_dim is not None,
+        "flat_qk_norm": bool(cfg.qk_norm) and cfg.qk_norm != "head",
+        "norm_zero_centered": cfg.norm_zero_centered,
+    }
+    under = {
+        "decode": cfg.decode,
+        "sp_axis": cfg.sp_axis is not None,
+        "tp_axis": cfg.tp_axis is not None or cfg.tp_size > 1,
+        "looped": cfg.n_passes > 1 or cfg.exit_gate,
+        "block_diffusion": cfg.block_diffusion,
+        **dict.fromkeys(consumers, True),
+    }
+    for features, paths, why in NOT_BUILT:
+        if (any(have[f] for f in features.split())
+                and any(under.get(c) for c in paths.split())):
+            raise NotImplementedError(why.format(cfg=cfg, n=len(set(plan))))
+
+
 class Attention(nn.Module):
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None
@@ -479,14 +640,11 @@ class Attention(nn.Module):
         assert cfg.n_heads % cfg.kv_heads == 0, (cfg.n_heads, cfg.kv_heads)
         h, d = cfg.n_heads // cfg.tp_size, cfg.head_dim  # local heads
         kv_h = cfg.kv_heads // cfg.tp_size
-        if cfg.decode and (kv_h != h or self.window is not None):
-            raise NotImplementedError(
-                "grouped key / value heads and windows are not implemented "
-                "for the decode paths")
-        if cfg.decode and (cfg.attn_gate or cfg.rotary_dim is not None):
-            raise NotImplementedError(
-                "attn_gate and rotary_dim are not implemented for the decode "
-                "paths")
+        rotary = cfg.rope_theta is not None and self.rotary
+        # built by itself, this layer is all the model there is: the plan of
+        # the one block that would hold it
+        refuse_not_built(cfg, ((SubLayer("attn", self.window, rotary),
+                                SubLayer("mlp")),))
         if _tp_active(cfg):
             from ..parallel.tensor_parallel import tp_gather_grad
 
@@ -517,7 +675,6 @@ class Attention(nn.Module):
             KEPT_QKV) for n in "qkv")
         if cfg.attn_gate:
             q, gate = q[..., :d], q[..., d:]
-        rotary = cfg.rope_theta is not None and self.rotary
         by_kernel = rotary and rotates_by_kernel(cfg, q.shape[1],
                                                  self.attn_fn)
         # where the ``rope`` kernel rotates, a per-head norm rides its pass:
@@ -531,18 +688,11 @@ class Attention(nn.Module):
             if not norm_rides:
                 q, k = head_norm("q_norm")(q), head_norm("k_norm")(k)
         elif cfg.qk_norm:
-            if _tp_active(cfg):
-                raise NotImplementedError(
-                    "qk_norm normalizes over all heads; they are sharded "
-                    "under tensor parallelism")
             flat_norm = lambda name, t: RMSNorm(
                 cfg.dtype, cfg.param_dtype, cfg.norm_eps, name=name,
             )(t.reshape(*t.shape[:-2], t.shape[-2] * d)).reshape(t.shape)
             q, k = flat_norm("q_norm", q), flat_norm("k_norm", k)
         if rotary:
-            if cfg.decode:
-                raise NotImplementedError(
-                    "rope_theta is not implemented for the decode paths")
             start = 0
             if cfg.sp_axis is not None and _axis_bound(cfg.sp_axis):
                 start = jax.lax.axis_index(cfg.sp_axis) * q.shape[1]
@@ -748,12 +898,30 @@ class MLPBlock(nn.Module):
         return out
 
 
+#: the token mixers that read a block's normed input alone, by sub-layer
+#: kind: the file of this package and the module in it (named after its kind
+#: in a block); the file's ``set_gauges`` sets its trace-time gauges
+MIXERS = {"linear_attn": ("linear_attention", "GatedDeltaNet"),
+          "ssm": ("state_space", "Mamba2")}
+
+
+def _mixer(kind: str):
+    """``(module class, set_gauges)`` of a mixer kind; its file is imported
+    by the first model that has such a layer."""
+    name, cls = MIXERS[kind]
+    module = importlib.import_module(f"{__package__}.{name}")
+    return getattr(module, cls), module.set_gauges
+
+
 class Block(nn.Module):
+    """Layer ``layer`` of the configuration's plan: its sub-layers in
+    order, each ``x + N'(F(N(x)))`` under the names ``<kind>_norm``,
+    ``<kind>``, ``<kind>_post_norm`` (a custom MLP under the name its
+    factory gives it)."""
     cfg: TransformerConfig
     attn_fn: Optional[Callable] = None
     mlp: Optional[Callable[[], nn.Module]] = None  # MoE drops in here
-    #: index of the layer: its attention's kind under the configuration's
-    #: layer patterns
+    #: index of the layer in ``cfg.layer_plan()``
     layer: int = 0
 
     @nn.compact
@@ -768,85 +936,32 @@ class Block(nn.Module):
         # own, where the configuration has each
         pre = lambda name, t: norm(name)(t) if cfg.pre_norms else t
         post = lambda name, t: norm(name)(t) if cfg.post_norms else t
-        if cfg.layer_linear(self.layer):
-            # the layer's mixer is linear attention; module and norms carry
-            # names of their own (area ``linattn``)
-            from .linear_attention import GatedDeltaNet
-
-            _check_linear_attention(cfg, slots)
-            mixer = "linear_attn"
-            attn = GatedDeltaNet(cfg, name=mixer)
-        else:
-            mixer = "attn"
-            attn = Attention(cfg, self.attn_fn, cfg.layer_window(self.layer),
-                             cfg.layer_rotary(self.layer), name=mixer)
-        y = pre(f"{mixer}_norm", x)
-        # dense/training call sites keep their exact one-arg form (the
-        # goldens pin those programs); only paged decode threads slots
-        x = x + post(f"{mixer}_post_norm",
-                     attn(y) if slots is None else attn(y, slots))
-        y = pre("mlp_norm", x)
-        mlp = self.mlp() if self.mlp is not None else MLPBlock(cfg, name="mlp")
-        if cfg.route_before_attention:
-            if self.mlp is None:
-                raise ValueError(
-                    "route_before_attention names a router: it needs an "
-                    "expert MLP (mlp_factory) that takes `route_x`")
-            return x + post("mlp_post_norm", mlp(y, route_x=block_in))
-        x = x + post("mlp_post_norm", mlp(y))
+        subs = cfg.layer_plan()[self.layer]
+        for sub in subs:
+            y = pre(f"{sub.kind}_norm", x)
+            if sub.kind == "mlp":
+                if self.mlp is None and (len(subs) == 1
+                                         or cfg.route_before_attention):
+                    raise ValueError(
+                        "layer_kinds names an expert layer ('moe'): it needs "
+                        "an mlp_factory that makes one" if len(subs) == 1 else
+                        "route_before_attention names a router: it needs an "
+                        "expert MLP (mlp_factory) that takes `route_x`")
+                mlp = (self.mlp() if self.mlp is not None
+                       else MLPBlock(cfg, name="mlp"))
+                out = (mlp(y, route_x=block_in) if cfg.route_before_attention
+                       else mlp(y))
+            elif sub.kind == "attn":
+                attn = Attention(cfg, self.attn_fn, sub.window, sub.rotary,
+                                 name="attn")
+                # dense/training call sites keep their exact one-arg form
+                # (the goldens pin those programs); only paged decode
+                # threads slots
+                out = attn(y) if slots is None else attn(y, slots)
+            else:
+                out = _mixer(sub.kind)[0](cfg, name=sub.kind)(y)
+            x = x + post(f"{sub.kind}_post_norm", out)
         return x
-
-
-def _check_linear_attention(cfg: TransformerConfig, slots) -> None:
-    """The paths a linear-attention layer (``mixer_layers``) cannot take
-    refuse it here: its state runs over the whole sequence on one device."""
-    if cfg.decode or slots is not None:
-        raise NotImplementedError(
-            "mixer_layers (linear attention) is not implemented on the "
-            "decode paths: a recurrent state beside the key / value cache")
-    if cfg.sp_axis is not None:
-        raise NotImplementedError(
-            "mixer_layers (linear attention) is not implemented under "
-            "sp_axis: a sequence shard would need the state of the shard "
-            "before it")
-    if cfg.tp_axis is not None or cfg.tp_size > 1:
-        raise NotImplementedError(
-            "mixer_layers (linear attention) is not implemented under the "
-            "tensor-parallel axis (tp_axis / tp_size): its heads are not "
-            "sharded")
-    if cfg.n_passes > 1 or cfg.block_diffusion:
-        raise NotImplementedError(
-            "mixer_layers (linear attention) is not implemented for a "
-            "looped stack or under attention='block_diffusion'")
-
-
-def _check_block_diffusion(cfg: TransformerConfig, rows: int) -> None:
-    """What ``attention="block_diffusion"`` asks of the configuration and of
-    the ``rows`` it is handed; the paths that cannot take the mask refuse
-    it here."""
-    if cfg.decode:
-        raise NotImplementedError(
-            "attention='block_diffusion' is the training view of a block-"
-            "diffusion model; generation by blocks is not implemented on the "
-            "decode paths")
-    if cfg.sp_axis is not None:
-        raise NotImplementedError(
-            "attention='block_diffusion' is not implemented under sp_axis: "
-            "a sequence shard would need the other half's keys and the "
-            "drop-ins' masks are causal")
-    if cfg.n_passes > 1 or cfg.exit_gate:
-        raise NotImplementedError(
-            "attention='block_diffusion' is not implemented for a looped "
-            "stack (n_passes > 1, exit_gate)")
-    if cfg.window is not None or cfg.rope_layers is not None:
-        raise NotImplementedError(
-            "attention='block_diffusion' is one kind of layer: no window, "
-            "no rope_layers pattern")
-    if cfg.diffusion_block < 1 or rows % 2 or (rows // 2) % cfg.diffusion_block:
-        raise ValueError(
-            f"attention='block_diffusion' reads [x ; x~], a sequence of "
-            f"whole diffusion blocks twice over; got {rows} rows at "
-            f"diffusion_block={cfg.diffusion_block}")
 
 
 class TransformerLM(nn.Module):
@@ -867,8 +982,14 @@ class TransformerLM(nn.Module):
                 "`slots` is only meaningful for paged decode configs "
                 "(decode=True, page_size > 0)"
             )
-        if cfg.block_diffusion:
-            _check_block_diffusion(cfg, tokens.shape[1])
+        refuse_not_built(cfg)
+        rows = tokens.shape[1]
+        if cfg.block_diffusion and (cfg.diffusion_block < 1 or rows % 2
+                                    or (rows // 2) % cfg.diffusion_block):
+            raise ValueError(
+                f"attention='block_diffusion' reads [x ; x~], a sequence of "
+                f"whole diffusion blocks twice over; got {rows} rows at "
+                f"diffusion_block={cfg.diffusion_block}")
         x = TokenEmbed(
             cfg.vocab_size, cfg.d_model, name="embed",
             dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -921,9 +1042,10 @@ class TransformerLM(nn.Module):
             from ..ops.embed_grad import grad_kernel_supported
             from ..telemetry import counters
 
-            softmax = [i for i in range(cfg.n_layers)
-                       if cfg.layer_softmax(i)]
-            windowed = sum(cfg.layer_window(i) is not None for i in softmax)
+            subs = [sub for layer in cfg.layer_plan() for sub in layer]
+            softmax = [sub for sub in subs if sub.kind == "attn"]
+            windowed = sum(sub.window is not None for sub in softmax)
+            rotary = sum(sub.rotary for sub in softmax)
             counters.set_gauge("attn/kv_heads", cfg.kv_heads // cfg.tp_size)
             counters.set_gauge("attn/window", cfg.window or 0)
             counters.set_gauge("attn/window_layers", windowed)
@@ -932,49 +1054,19 @@ class TransformerLM(nn.Module):
                 0 if cfg.block_diffusion else len(softmax) - windowed)
             # the rotary layers whose rotation is the ``rope`` kernel
             by_kernel = rotates_by_kernel(cfg, tokens.shape[1], self.attn_fn)
-            counters.set_gauge("attn/rope_kernel_layers", by_kernel * sum(
-                cfg.layer_rotary(i) for i in softmax))
+            counters.set_gauge("attn/rope_kernel_layers", by_kernel * rotary)
             # of those, the layers whose q / k norm rides the same pass
             counters.set_gauge(
                 "attn/head_norm_kernel_layers",
-                by_kernel * (cfg.qk_norm == "head") * sum(
-                    cfg.layer_rotary(i) for i in softmax))
+                by_kernel * (cfg.qk_norm == "head") * rotary)
             # 1: this step's token-table gradient is the ``embed_grad``
             # kernel; 0: it fell back to the gather's own transpose
             counters.set_gauge("embed/grad_kernel",
                                int(grad_kernel_supported(cfg.d_model)))
-            if cfg.mixer_layers is not None:
-                linear = sum(cfg.layer_linear(i) for i in range(cfg.n_layers))
-                counters.set_gauge("linattn/layers", linear)
-                from ..ops.gated_delta import CHUNK
-                from .linear_attention import rows_by_kernel
-
-                # of those, the layers whose rows between the projections
-                # are the ``gdn_mix`` / ``gdn_gate`` passes
-                counters.set_gauge(
-                    "linattn/row_kernel_layers",
-                    linear * rows_by_kernel(cfg, tokens.shape[1]))
-                counters.set_gauge("linattn/chunk", CHUNK)
-                counters.set_gauge("linattn/key_heads", cfg.linear_key_heads)
-                counters.set_gauge("linattn/value_heads",
-                                   cfg.linear_value_heads)
-                counters.set_gauge("linattn/key_dim", cfg.linear_key_dim)
-                counters.set_gauge("linattn/value_dim", cfg.linear_value_dim)
-                counters.set_gauge("linattn/neg_eigval",
-                                   int(cfg.linear_neg_eigval))
-            if cfg.layer_kinds is not None:
-                ssm = cfg.layer_kinds.count("ssm")
-                counters.set_gauge("ssm/layers", ssm)
-                from .state_space import rows_by_kernel as ssm_rows_by_kernel
-
-                # of those, the layers whose rows between the projections
-                # are the ``ssd_mix`` / ``ssd_gate`` passes
-                counters.set_gauge(
-                    "ssm/row_kernel_layers",
-                    ssm * ssm_rows_by_kernel(cfg, tokens.shape[1]))
-                for size in ("chunk", "heads", "head_dim", "groups", "state"):
-                    counters.set_gauge(f"ssm/{size}",
-                                       getattr(cfg, f"ssm_{size}"))
+            for kind in MIXERS:
+                layers = sum(sub.kind == kind for sub in subs)
+                if layers:
+                    _mixer(kind)[1](cfg, layers, tokens.shape[1])
             if cfg.rope_theta is not None:
                 counters.set_gauge("attn/rotary_dim",
                                    cfg.rotary_dim or cfg.head_dim)
@@ -990,10 +1082,6 @@ class TransformerLM(nn.Module):
                 # trained through two rows
                 counters.set_gauge("diffusion/tokens_per_step",
                                    tokens.shape[0] * tokens.shape[1] // 2)
-        if cfg.decode and cfg.n_passes > 1:
-            raise NotImplementedError(
-                "n_passes > 1 is not implemented for the decode paths (a "
-                "key / value cache per pass)")
 
         def stack(x):
             """The ``n_layers`` blocks, once through."""
@@ -1001,8 +1089,6 @@ class TransformerLM(nn.Module):
                 mlp = (self.mlp_factory(i) if self.mlp_factory is not None
                        else None)
                 block_cls = Block
-                if cfg.layer_kinds is not None:
-                    from .single_block import SingleBlock as block_cls
                 if cfg.remat:
                     # a custom MLP tags nothing: its matmuls keep the dots rule
                     own = (KEPT_QKV, KEPT_FFN_IN) if mlp is None else ()

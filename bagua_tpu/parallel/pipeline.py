@@ -29,29 +29,21 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..models.transformer import (
-    Block, RMSNorm, TokenEmbed, TransformerConfig,
+    Block, RMSNorm, TokenEmbed, TransformerConfig, refuse_not_built,
 )
 from .mesh import axis_bound as _axis_bound
 
 
 class _ScanBlock(nn.Module):
     """Block adapter with scan signature (carry, _) -> (carry, None).  One
-    traced block stands for every layer of the stack, so the layers must
-    all be of one kind."""
+    traced block stands for every layer of the stack: the layers of the
+    plan are all one (``NOT_BUILT``'s ``mixed_layers`` under ``pipeline``)."""
 
     cfg: TransformerConfig
 
     @nn.compact
     def __call__(self, x, _):
-        cfg = self.cfg
-        kinds = {(cfg.layer_window(i), cfg.layer_rotary(i))
-                 for i in range(cfg.n_layers)}
-        if len(kinds) > 1:
-            raise NotImplementedError(
-                "the pipelined stack scans ONE block over its layers: "
-                f"window_layers={cfg.window_layers} / rope_layers="
-                f"{cfg.rope_layers} ask for layers of {len(kinds)} kinds")
-        return Block(cfg, name="block")(x), None
+        return Block(self.cfg, name="block")(x), None
 
 
 class PipelinedTransformerLM(nn.Module):
@@ -76,29 +68,7 @@ class PipelinedTransformerLM(nn.Module):
     @nn.compact
     def __call__(self, tokens):
         cfg = self.cfg
-        if cfg.n_passes > 1 or cfg.exit_gate:
-            raise NotImplementedError(
-                "the pipelined stack runs its layers once and ends in one "
-                f"head: n_passes={cfg.n_passes} / exit_gate={cfg.exit_gate} "
-                "(a looped stack's passes under pipeline stages) are not "
-                "implemented")
-        if cfg.block_diffusion:
-            raise NotImplementedError(
-                "the pipelined stack attends causally over tokens[:, :-1]: "
-                "attention='block_diffusion' (the [x ; x~] rows, their mask, "
-                "the noised half's head) is not implemented under pipeline "
-                "stages")
-        if cfg.mixer_layers is not None or cfg.norm_zero_centered:
-            raise NotImplementedError(
-                "the pipelined stack scans ONE kind of block and closes in a "
-                "plain RMSNorm: mixer_layers (linear-attention layers by "
-                "period) and norm_zero_centered are not implemented under "
-                "pipeline stages")
-        if cfg.layer_kinds is not None:
-            raise NotImplementedError(
-                "the pipelined stack scans ONE kind of two-sub-layer block: "
-                "layer_kinds (one sub-layer a block, a kind a layer) is not "
-                "implemented under pipeline stages")
+        refuse_not_built(cfg, consumers=("pipeline",))
         assert cfg.n_layers % self.pp_size == 0, (cfg.n_layers, self.pp_size)
         n_local = cfg.n_layers // self.pp_size
 

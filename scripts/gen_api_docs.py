@@ -73,7 +73,6 @@ MODULES = [
     "bagua_tpu.models.transformer",
     "bagua_tpu.models.linear_attention",
     "bagua_tpu.models.state_space",
-    "bagua_tpu.models.single_block",
     "bagua_tpu.models.generate",
     "bagua_tpu.serve",
     "bagua_tpu.serve.cache",
